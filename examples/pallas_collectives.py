@@ -2,7 +2,7 @@
 
 coll/xla lets the XLA compiler lower every collective; coll/pallas
 (opt-in, priority 60) replaces the supported ones with explicit
-Pallas kernels — ``make_async_remote_copy`` double-buffered DMA rings
+Pallas kernels — ``make_async_remote_copy`` DMA rings
 on TPU, the identical chunk schedule in interpret mode + ``ppermute``
 hops everywhere else — and adds the two fused compute+comm kernels
 the backend exists for (ZeRO reduce_scatter+update, matmul-overlapped

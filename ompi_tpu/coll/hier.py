@@ -139,8 +139,7 @@ _dcn_dtype_var = cvar.register(
          "wire dtype + local upcast-sum; fp8 adds a per-launch scale "
          "factor agreed by pmax in the same program). Applies to SUM "
          "reductions of float payloads only; 'linear' determinism "
-         "and non-float dtypes always run exact. fp8 degrades to "
-         "bf16 on jax builds without fp8 lowerings. Unknown values "
+         "and non-float dtypes always run exact. Unknown values "
          "raise MPIError(ERR_ARG) at the first collective.", level=5)
 
 _dcn_dtype_op_vars = {
@@ -168,9 +167,7 @@ def _wire_dtype(kind: str, dtype: str, det: Optional[str],
     the bad-split contract. Compression is declined silently (exact
     lowering, no error) whenever the result must be bit-stable or the
     cast cannot help: 'linear' determinism, non-SUM ops, non-float
-    payloads, or a wire format no narrower than the input dtype.
-    Unavailable fp8 degrades to bf16 (the jaxcompat capability probe)
-    with a verbose note instead of failing."""
+    payloads, or a wire format no narrower than the input dtype."""
     v = _dcn_dtype_op_vars.get(kind)
     spec = v.get().strip().lower() if v is not None else ""
     if not spec:
@@ -192,13 +189,9 @@ def _wire_dtype(kind: str, dtype: str, det: Optional[str],
         return None
     if ndt.kind != "f":
         return None
-    wire = _jc.wire_degrade(spec)
-    if wire != spec:
-        _out.verbose(1, "coll_hier_dcn_dtype=%s unavailable on this "
-                        "jax: degrading to %s", spec, wire)
-    if _jc.wire_itemsize(wire) >= ndt.itemsize:
+    if _jc.wire_itemsize(spec) >= ndt.itemsize:
         return None  # the "compression" would not shrink the wire
-    return wire
+    return spec
 
 
 #: flat-path slots coll/pallas can serve (one priority level down)
@@ -350,6 +343,9 @@ def _inner_algo(kind: str, nbytes: int, dtype: str, opn,
     if dtype not in _pallas._SUPPORTED_DTYPES \
             or opn.name not in _pallas._SUPPORTED_OPS:
         return "xla"
+    if not _pallas._interpret() and not _pallas.dma_fits(
+            K.ring_vmem_bytes(plan.n_ici, nbytes)):
+        return "xla"  # past what the DMA kernels hold in VMEM
     if mode == "auto":
         if _pallas._enable_var.get() != "on":
             return "xla"
@@ -398,6 +394,10 @@ def _launch(launcher, op: str, plan: _Plan, comm=None, nbytes=0,
     launch funnel inside adds its own span) and a tune-plane sample
     under provider 'hier', mesh (n_dcn, n_ici), when the observatory
     is up."""
+    if not _pallas._interpret():
+        compiled = launcher
+        launcher = lambda: K.compiled_or_raise(  # noqa: E731
+            f"coll_hier {op}", compiled)
     obs = _tobs.OBSERVER
     if obs is not None:
         launcher = obs.timed("hier", op, "hier", comm, nbytes, dtype,

@@ -3,7 +3,7 @@
 A peer to :mod:`ompi_tpu.coll.xla` one priority level up: ring and
 bidirectional-ring reduce_scatter / allgather / allreduce implemented
 as explicit Pallas kernels (:mod:`ompi_tpu.coll.pallas_kernels` —
-``make_async_remote_copy`` double-buffered DMA rings on TPU, the same
+``make_async_remote_copy`` DMA rings on TPU, the same
 schedule as interpret-mode kernels + ``ppermute`` hops on CPU), plus
 the two fused compute+comm kernels the backend exists for:
 reduce_scatter fused with the ZeRO stage-1/2 shard update
@@ -104,11 +104,18 @@ _bidir_min_var = cvar.register(
          "entry overrides; below it the clockwise ring. -1 disables "
          "the bidirectional default.", level=5)
 _dma_max_var = cvar.register(
-    "coll_pallas_dma_max_bytes", 64 << 20, int,
-    help="Payload bound for the monolithic DMA kernels (whole-buffer "
-         "VMEM residency: payload + double-buffered chunk scratch "
-         "must fit); larger payloads fall through to coll/xla. Only "
-         "consulted on the TPU (non-interpret) path. 0 = unbounded.",
+    "coll_pallas_dma_max_bytes", 12 << 20, int,
+    help="VMEM a monolithic DMA kernel may keep resident. The ring, "
+         "fused-update, allgather-matmul and osc round kernels hold "
+         "their operands, one landing slot per hop and their outputs "
+         "whole in VMEM (a ring reduce_scatter on four chips: 2.25x "
+         "the payload; the fused ZeRO update with momentum: 3x the "
+         "bucket, so the default admits one 4 MiB bucket). A call "
+         "that needs more falls through one level down (counted in "
+         "pallas_fallthrough / osc_pallas_fallthrough). 12 MiB is the "
+         "largest size all four kernels were run at on a v5e (PR 21). "
+         "Only consulted on the TPU (non-interpret) path. "
+         "0 = unbounded.",
     level=6)
 _switch_var = cvar.register(
     "coll_pallas_switchpoints", "", str,
@@ -123,6 +130,8 @@ _switch_var = cvar.register(
 _SUPPORTED_DTYPES = frozenset(("float32", "bfloat16", "int32"))
 _SUPPORTED_OPS = frozenset(("MPI_SUM", "MPI_PROD", "MPI_MIN",
                             "MPI_MAX"))
+#: allgather_matmul operands (x and w alike): what Mosaic's matmul takes
+_MATMUL_DTYPES = frozenset(("float32", "bfloat16"))
 
 _BYTES_PVAR = {"ring": "pallas_ring_bytes",
                "bidir": "pallas_bidir_bytes",
@@ -140,6 +149,13 @@ def _interpret() -> bool:
     if mode == "off":
         return False
     return not jaxcompat.pallas_remote_dma_ok()
+
+
+def dma_fits(vmem_bytes: int) -> bool:
+    """Whether a DMA kernel that keeps ``vmem_bytes`` resident (the
+    kernel library's ``*_vmem_bytes``) is admitted on the TPU path."""
+    bound = _dma_max_var.get()
+    return bound <= 0 or vmem_bytes <= bound
 
 
 def _det_ok(deterministic: Optional[str]) -> Optional[str]:
@@ -222,8 +238,11 @@ def _select(kind: str, comm, sendbuf, det: Optional[str],
     nbytes = int(getattr(sendbuf, "nbytes", 0))
     if nbytes == 0 or nbytes < _min_bytes_var.get():
         return None
-    dma_max = _dma_max_var.get()
-    if not _interpret() and 0 < dma_max < nbytes:
+    # one rule for every kind: the ring reduce_scatter's residency
+    # (allgather and the linear fold hold less, and were proven on
+    # the chip no further than the ring)
+    if not _interpret() \
+            and not dma_fits(K.ring_vmem_bytes(comm.size, nbytes)):
         return None
     forced = _FORCE[kind].get()
     if forced == "xla":
@@ -253,6 +272,10 @@ def _launch(launcher, op: str, algo: str, comm=None, buf=None,
     algorithm (the xla launch funnel inside adds its own span) and a
     tune-plane sample under provider 'pallas' when the observatory
     is up (`nbytes` overrides `buf.nbytes` for multi-buffer ops)."""
+    if not _interpret():
+        compiled = launcher
+        launcher = lambda: K.compiled_or_raise(  # noqa: E731
+            f"coll_pallas {op}/{algo}", compiled)
     obs = _tobs.OBSERVER
     if obs is not None:
         launcher = obs.timed(
@@ -454,8 +477,10 @@ def fused_rs_update_dev(comm, grads, pshards, mshards, *,
     bucket and consumes the reduced chunk in-register with the
     average/momentum/SGD epilogue. Returns ``(new_pshards,
     new_mshards)`` ShardedStates, or **None** when any bucket is
-    unsupported — the caller (ZeroOptimizer) then runs the unfused
-    sequence, the same staged-fallthrough shape as the other slots.
+    unsupported or, on TPU, larger than the kernel can hold in VMEM
+    (``coll_pallas_dma_max_bytes``) — the caller (ZeroOptimizer) then
+    runs the unfused sequence, the same staged-fallthrough shape as
+    the other slots.
 
     Numerics: under ``deterministic='linear'`` (the reproducibility
     mode) only the reduce_scatter runs in-kernel; the epilogue replays
@@ -493,6 +518,17 @@ def fused_rs_update_dev(comm, grads, pshards, mshards, *,
     fnc = C.combine_fn(op_mod.SUM)
     interp = _interpret()
     lrf, muf = float(lr), float(mu)
+    # the kernel holds a whole bucket, its landing slots and (fused
+    # epilogue) the p/v shards with their outputs in VMEM
+    shard_bufs = 1 if det == "linear" else 4 if with_mom else 2
+    if not interp and not all(
+            dma_fits(K.ring_vmem_bytes(
+                comm.size,
+                plan.padded[b] * jaxcompat.np_dtype(dt).itemsize,
+                shard_bufs))
+            for b, dt in enumerate(plan.dtypes)):
+        pvar.record("pallas_fallthrough")
+        return None
 
     launches = []
     for b, idxs in enumerate(plan.buckets):
@@ -622,18 +658,20 @@ def allgather_matmul_dev(comm, x, w):
     """Tensor-parallel fused allgather@matmul: x is this rank's
     (m, d) row block, w the replicated (d, f) weight; returns the
     full (n*m, f) product with each arriving block multiplied while
-    the next ring hop is in flight. Unsupported cases compose the
-    plain device allgather with a local matmul (same result, no
-    overlap)."""
+    the next ring hop is in flight. Unsupported cases — and, on TPU,
+    operands the kernel cannot hold whole in VMEM
+    (``coll_pallas_dma_max_bytes``) — compose the plain device
+    allgather with a local matmul (same result, no overlap)."""
     import jax.numpy as jnp
 
     ok = (comm.size > 1
           and getattr(x, "ndim", 0) == 2
           and getattr(w, "ndim", 0) == 2
           and x.shape[1] == w.shape[0]
-          and str(x.dtype) in _SUPPORTED_DTYPES
-          and str(w.dtype) in _SUPPORTED_DTYPES
-          and _xla._ctx(comm).mesh2d is None)
+          and str(x.dtype) == str(w.dtype) in _MATMUL_DTYPES
+          and _xla._ctx(comm).mesh2d is None
+          and (_interpret() or dma_fits(K.matmul_vmem_bytes(
+              comm.size, x, w, x.dtype))))
     if not ok:
         pvar.record("pallas_fallthrough")
         gathered = _xla.allgather_dev(comm, x)
@@ -675,8 +713,8 @@ def zero3_gather_matmul_dev(comm, state, rhs):
           and len(plan.buckets[0]) == 1
           and plan.padded[0] == plan.elems[0]
           and getattr(rhs, "ndim", 0) == 2
-          and str(getattr(rhs, "dtype", "")) in _SUPPORTED_DTYPES
-          and str(plan.dtypes[0]) in _SUPPORTED_DTYPES)
+          and str(getattr(rhs, "dtype", "")) == str(plan.dtypes[0])
+          in _MATMUL_DTYPES)
     if ok:
         shape = state.metas[plan.buckets[0][0]][0]
         ok = (len(shape) == 2

@@ -13,29 +13,31 @@ equal to the ppermute rings and 'linear' results are bitwise equal to
 Transport gate (``interpret=``):
 
 - **TPU** (``interpret=False``): one monolithic ``pl.pallas_call``
-  per collective — double-buffered VMEM scratch, a DMA semaphore pair
-  per buffer slot, and ``pltpu.make_async_remote_copy`` to the ring
-  neighbor (the SNIPPETS exemplar pattern). A barrier-semaphore
-  handshake with both neighbors opens the kernel so no rank DMAs into
-  a peer that has not entered it. The fused kernels consume the final
-  combined chunk in-register (update epilogue / per-hop matmul)
-  instead of round-tripping HBM.
-- **CPU / interpret** (``interpret=True``): no jax release can
-  emulate inter-device DMA in the interpreter, so the *hop* is a
-  ``lax.ppermute`` while every *combine / fold / matmul / update*
-  runs as a ``pl.pallas_call(..., interpret=True)`` kernel. The
-  accumulation order is identical to the DMA schedule, which is what
-  lets tier-1 and the smoke lane prove ring correctness (and
-  bit-identity vs ``coll/xla``) without hardware.
+  per collective that moves data with
+  ``pltpu.make_async_remote_copy`` to the ring neighbor (protocol
+  under "monolithic DMA kernels" below). The fused kernels consume
+  the final combined chunk in VMEM (update epilogue / per-hop matmul)
+  instead of round-tripping HBM. Passing a ``pltpu.InterpretParams``
+  instead of ``False`` runs the SAME DMA kernels under the Pallas TPU
+  interpreter, which emulates remote copies and semaphores between
+  virtual CPU devices and can detect races — how tier-1 checks the
+  protocol without a chip.
+- **CPU** (``interpret=True``): the *hop* is a ``lax.ppermute`` while
+  every *combine / fold / matmul / update* runs as a
+  ``pl.pallas_call(..., interpret=True)`` kernel. The accumulation
+  order is identical to the DMA schedule, which is what lets tier-1
+  and the smoke lane prove ring correctness (and bit-identity vs
+  ``coll/xla``) without hardware.
 
-Real-TPU cycle numbers for the DMA path are a carry-over (ROADMAP);
-the schedule, buffering and semaphore protocol are validated here in
-interpret mode.
+Which DMA kernels Mosaic compiles on a v5e, and which it refuses, is
+recorded by ``chip_smoke.py`` (CHANGES.md, PR 21); no timing of this
+path has been taken on a chip.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Optional, Tuple
+import math
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -56,17 +58,25 @@ def _pltpu():
     return jaxcompat.pallas_tpu()
 
 
-def _compiler_params(pltpu, collective_id: int):
-    """TPU compiler params across jax versions (CompilerParams vs the
-    older TPUCompilerParams spelling); the barrier semaphore requires
-    a collective_id and the remote DMAs must not be DCE'd."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
+def compiled_or_raise(what: str, launch: Callable):
+    """Run ``launch`` — the dispatch of a COMPILED (non-interpret)
+    Pallas program. Whatever stops it — Mosaic refusing the kernel at
+    lowering or compile time, or a fault on the way there — leaves as
+    an ``MPIError`` that names the kernel and carries the original
+    message: on the chip a kernel either runs as written or fails
+    loudly, it never drops to interpret mode or to a reference
+    implementation."""
+    from ompi_tpu import errors
+
     try:
-        return cls(has_side_effects=True, collective_id=collective_id)
-    except TypeError:
-        return cls(collective_id=collective_id)
+        return launch()
+    except errors.MPIError:
+        raise
+    except Exception as exc:  # noqa: BLE001 — boundary: rename, keep
+        raise errors.MPIError(
+            errors.ERR_INTERN,
+            f"{what}: the compiled Pallas TPU kernel did not run "
+            f"({type(exc).__name__}): {exc}") from exc
 
 
 def _perm(n: int, d: int):
@@ -109,15 +119,23 @@ def _roll_body(x_ref, s_ref, o_ref):
     o_ref[...] = jnp.roll(x_ref[...], s_ref[0], axis=0)
 
 
+def _dot(x, w, out_dtype):
+    """x @ w accumulated in f32 (Mosaic's matmul takes no narrower
+    accumulator: bf16 operands with a bf16 result type are refused),
+    rounded once to ``out_dtype`` — float operands only."""
+    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(
+        out_dtype)
+
+
 def _matmul_body(out_dtype):
     def kernel(x_ref, w_ref, o_ref):
-        o_ref[...] = jnp.dot(x_ref[...], w_ref[...],
-                             preferred_element_type=out_dtype)
+        o_ref[...] = _dot(x_ref[...], w_ref[...], out_dtype)
 
     return kernel
 
 
-def _apply_update(g, p, v, lr: float, mu: float, inv: Optional[float]):
+def _apply_update(g, p, v, lr: float, mu: float, inv: Optional[float],
+                  barrier: bool = True):
     """The ZeroOptimizer.step shard update, constants cast to the
     shard dtype exactly as the unfused path does. The unfused
     sequence dispatches each elementwise op as its OWN program, so
@@ -127,15 +145,18 @@ def _apply_update(g, p, v, lr: float, mu: float, inv: Optional[float]):
     only keep the op ORDER fixed), so the fused epilogue is
     equivalent to the unfused step to within one ulp, not bitwise.
     coll/pallas therefore runs this epilogue eagerly (outside the
-    kernel) when ``deterministic='linear'`` demands bit-identity."""
+    kernel) when ``deterministic='linear'`` demands bit-identity.
+    ``barrier=False`` drops the barriers: Mosaic has no lowering for
+    them, and a compiled kernel body already keeps program order."""
+    ob = lax.optimization_barrier if barrier else (lambda t: t)
     if inv is not None:
-        g = lax.optimization_barrier(g * jnp.asarray(inv, g.dtype))
+        g = ob(g * jnp.asarray(inv, g.dtype))
     vn = None
     if v is not None:
-        t = lax.optimization_barrier(jnp.asarray(mu, v.dtype) * v)
-        vn = lax.optimization_barrier(t + g)
+        t = ob(jnp.asarray(mu, v.dtype) * v)
+        vn = ob(t + g)
         g = vn
-    step = lax.optimization_barrier(jnp.asarray(lr, p.dtype) * g)
+    step = ob(jnp.asarray(lr, p.dtype) * g)
     pn = p - step
     return pn, vn
 
@@ -176,35 +197,6 @@ def _fold_slice_body(n: int, k: int, fn):
     return kernel
 
 
-def _fold_slice_update_body(n: int, k: int, fn, lr, mu, inv,
-                            with_mom: bool):
-    """Linear fused kernel: rank-order fold, own-chunk slice, and the
-    ZeRO update epilogue in one pallas_call."""
-
-    if with_mom:
-        def kernel(g_ref, r_ref, p_ref, v_ref, po_ref, vo_ref):
-            full = g_ref[0]
-            for i in range(1, n):
-                full = fn(full, g_ref[i])
-            g = lax.dynamic_slice_in_dim(full, r_ref[0] * k, k, axis=0)
-            pn, vn = _apply_update(g, p_ref[...], v_ref[...],
-                                   lr, mu, inv)
-            po_ref[...] = pn
-            vo_ref[...] = vn
-
-        return kernel
-
-    def kernel(g_ref, r_ref, p_ref, po_ref):
-        full = g_ref[0]
-        for i in range(1, n):
-            full = fn(full, g_ref[i])
-        g = lax.dynamic_slice_in_dim(full, r_ref[0] * k, k, axis=0)
-        pn, _ = _apply_update(g, p_ref[...], None, lr, mu, inv)
-        po_ref[...] = pn
-
-    return kernel
-
-
 def _call(body, out_shape, *args):
     """interpret-mode pallas_call over whole-array blocks."""
     pl = _pl()
@@ -234,8 +226,9 @@ def ring_reduce_scatter(x, axis: str, fn: Callable, *,
     assert x.shape[0] % n == 0, (
         f"ring_reduce_scatter: dim0 {x.shape[0]} not divisible by {n}")
     k = x.shape[0] // n
-    if not interpret:
-        return _dma_reduce_scatter(x, axis, n, k, fn, direction)
+    if interpret is not True:
+        return _dma_reduce_scatter(x, axis, n, k, fn, direction,
+                                   interpret)
     chunks = x.reshape((n, k) + x.shape[1:])
     r = lax.axis_index(axis)
     carry = lax.dynamic_index_in_dim(chunks, (r - direction) % n,
@@ -286,13 +279,16 @@ def linear_reduce_scatter(x, axis: str, fn: Callable, *,
         return x
     k = x.shape[0] // n
     g = _gather_stack(x, axis, n, interpret)
+    if interpret is not True:
+        # fold only the own chunk: elementwise, so slice-then-fold is
+        # bitwise fold-then-slice, and Mosaic cannot slice a VALUE at
+        # a dynamic offset
+        own = lax.dynamic_slice_in_dim(g, lax.axis_index(axis) * k, k,
+                                       axis=1)
+        return _tiled_fold(own, n, fn, interpret)
     r = lax.axis_index(axis).astype(jnp.int32)[None]
-    body = _fold_slice_body(n, k, fn)
-    out_shape = _sds((k,) + x.shape[1:], x.dtype)
-    if interpret:
-        return _call(body, out_shape, g, r)
-    pl = _pl()
-    return pl.pallas_call(body, out_shape=out_shape)(g, r)
+    return _call(_fold_slice_body(n, k, fn),
+                 _sds((k,) + x.shape[1:], x.dtype), g, r)
 
 
 # ---------------------------------------------------------------------------
@@ -308,8 +304,8 @@ def ring_allgather(x, axis: str, *, interpret: bool = True,
     n = jaxcompat.axis_size(axis)
     if n == 1:
         return x
-    if not interpret:
-        return _dma_allgather(x, axis, n, direction)
+    if interpret is not True:
+        return _dma_allgather(x, axis, n, direction, interpret)
     r = lax.axis_index(axis)
     blocks = [x]
     blk = x
@@ -350,9 +346,10 @@ def _gather_stack(x, axis: str, n: int, interpret: bool):
     the 'linear' transport. Interpret mode uses lax.all_gather (the
     very op coll/xla's linear fold gathers with, so operands are
     bitwise identical); the DMA path rings the flat payload around."""
-    if interpret:
+    if interpret is True:
         return lax.all_gather(x, axis)
-    full = _dma_allgather(x.reshape((1,) + x.shape), axis, n, 1)
+    full = _dma_allgather(x.reshape((1,) + x.shape), axis, n, 1,
+                          interpret)
     return full.reshape((n,) + x.shape)
 
 
@@ -396,11 +393,9 @@ def linear_allreduce(x, axis: str, fn: Callable, *,
     if n == 1:
         return x
     g = _gather_stack(x, axis, n, interpret)
-    body = _fold_body(n, fn)
-    if interpret:
-        return _call(body, _sds(x.shape, x.dtype), g)
-    pl = _pl()
-    return pl.pallas_call(body, out_shape=_sds(x.shape, x.dtype))(g)
+    if interpret is not True:
+        return _tiled_fold(g, n, fn, interpret)
+    return _call(_fold_body(n, fn), _sds(x.shape, x.dtype), g)
 
 
 # ---------------------------------------------------------------------------
@@ -419,9 +414,10 @@ def ring_reduce_scatter_update(x, axis: str, fn: Callable, p, v, *,
     n = jaxcompat.axis_size(axis)
     k = x.shape[0] // n
     with_mom = v is not None
-    if not interpret:
+    if interpret is not True:
         return _dma_reduce_scatter_update(x, axis, n, k, fn, p, v,
-                                          lr=lr, mu=mu, inv=inv)
+                                          lr=lr, mu=mu, inv=inv,
+                                          interpret=interpret)
     chunks = x.reshape((n, k))
     r = lax.axis_index(axis)
     carry = lax.dynamic_index_in_dim(chunks, (r - 1) % n,
@@ -444,33 +440,6 @@ def ring_reduce_scatter_update(x, axis: str, fn: Callable, p, v, *,
     return pn, None
 
 
-def linear_reduce_scatter_update(x, axis: str, fn: Callable, p, v, *,
-                                 lr: float, mu: float,
-                                 inv: Optional[float],
-                                 interpret: bool = True):
-    """'linear' fused variant: rank-order fold + own-chunk slice +
-    update in one kernel — bit-identical to the unfused
-    reduce_scatter('linear') -> average -> momentum -> SGD sequence."""
-    n = jaxcompat.axis_size(axis)
-    k = x.shape[0] // n
-    with_mom = v is not None
-    g = _gather_stack(x, axis, n, interpret)
-    r = lax.axis_index(axis).astype(jnp.int32)[None]
-    body = _fold_slice_update_body(n, k, fn, lr, mu, inv, with_mom)
-    if with_mom:
-        out_shape = (_sds(p.shape, p.dtype), _sds(v.shape, v.dtype))
-        args = (g, r, p, v)
-    else:
-        out_shape = (_sds(p.shape, p.dtype),)
-        args = (g, r, p)
-    if interpret:
-        outs = _call(body, out_shape, *args)
-    else:
-        pl = _pl()
-        outs = pl.pallas_call(body, out_shape=out_shape)(*args)
-    return (outs[0], outs[1]) if with_mom else (outs[0], None)
-
-
 # ---------------------------------------------------------------------------
 # fused: matmul-overlapped allgather (tensor parallelism)
 
@@ -479,7 +448,8 @@ def allgather_matmul(x, w, axis: str, *, interpret: bool = True):
     """allgather(x) @ w with the per-block matmul overlapping the
     next ring hop (the tensor-parallel row-gather fusion): x is the
     local (m, d) block of a row-sharded activation, w the local
-    (d, f) weight; returns the full (n*m, f) product. Each arriving
+    (d, f) weight, both of one float dtype; returns the full
+    (n*m, f) product. Each arriving
     block is multiplied while the following block is in flight —
     never materializing the gathered (n*m, d) activation."""
     n = jaxcompat.axis_size(axis)
@@ -487,8 +457,9 @@ def allgather_matmul(x, w, axis: str, *, interpret: bool = True):
     if n == 1:
         return _call(_matmul_body(out_dtype),
                      _sds((x.shape[0], w.shape[1]), out_dtype), x, w)
-    if not interpret:
-        return _dma_allgather_matmul(x, w, axis, n, out_dtype)
+    if interpret is not True:
+        return _dma_allgather_matmul(x, w, axis, n, out_dtype,
+                                     interpret)
     m, f = x.shape[0], w.shape[1]
     r = lax.axis_index(axis)
     body = _matmul_body(out_dtype)
@@ -504,195 +475,270 @@ def allgather_matmul(x, w, axis: str, *, interpret: bool = True):
 
 
 # ---------------------------------------------------------------------------
-# monolithic DMA kernels (TPU path — interpret=False)
+# monolithic DMA kernels (TPU path — ``interpret`` is False, or a
+# pltpu.InterpretParams to run them under the TPU interpreter on CPU)
 #
-# Shared protocol: a barrier-semaphore handshake with both ring
-# neighbors opens every kernel; payload then moves through a
-# double-buffered VMEM scratch (2 slots, one DMA send/recv semaphore
-# pair each) via make_async_remote_copy to the +d neighbor. Slot s%2
-# alternation plus the blocking wait each step keeps reuse safe: a
-# slot is rewritten two steps after the neighbor consumed it.
+# Layout: Mosaic addresses whole (sublane, 128) tiles, so every payload
+# is flattened and zero-padded into [chunk, rows, 128] with ``rows`` a
+# whole number of native tiles (_to_tiles); a dynamic chunk number then
+# indexes the untiled leading dimension, never a tile interior.
+#
+# Protocol: a barrier-semaphore handshake with both ring neighbors
+# opens every kernel, so no rank DMAs into a peer that has not entered
+# it. No rank can LEAVE a ring kernel before every rank has passed that
+# handshake — its last hop carries data that was forwarded by all the
+# others — so a neighbor's signal for the next kernel can never be
+# taken for this one's. Every hop lands in a buffer (and signals a
+# semaphore pair) of its own: nothing a neighbor writes is written
+# twice, so a fast neighbor running steps ahead cannot overwrite data
+# that is still unread. allgather needs no arithmetic and copies
+# HBM -> HBM; every other kernel keeps its operands, landing slots and
+# outputs whole in VMEM. The *_vmem_bytes functions below say how much
+# that is; the callers hold it under coll_pallas_dma_max_bytes and send
+# what does not fit one level down (coll/pallas.dma_fits).
+
+_LANES = 128
 
 
-def _neighbor_handshake(pltpu, my, n: int, d: int):
+def ring_vmem_bytes(n: int, payload_bytes: int,
+                    extra_chunks: int = 1) -> int:
+    """VMEM a reducing ring kernel keeps resident for an n-chunk
+    payload: the payload, n-1 landing slots and the carry, plus
+    ``extra_chunks`` chunk-sized buffers — the output chunk (plain
+    reduce_scatter: 1) or the shard operands with an output each
+    (fused ZeRO update: 2, with momentum 4)."""
+    chunk = -(-payload_bytes // n)
+    return payload_bytes + (n + extra_chunks) * chunk
+
+
+def matmul_vmem_bytes(n: int, x, w, out_dtype) -> int:
+    """VMEM allgather_matmul keeps resident: the local block and its
+    n-1 landing slots, the weight, and the whole (n*m, f) product."""
+    return (n * x.nbytes + w.nbytes
+            + n * x.shape[0] * w.shape[1] * jnp.dtype(out_dtype).itemsize)
+
+
+def _to_tiles(chunks):
+    """[c, e] -> [c, rows, 128], zero-padded to whole native tiles
+    (8 sublanes of 32-bit, 16 of 16-bit elements)."""
+    c, e = chunks.shape
+    tile = 8 * (4 // chunks.dtype.itemsize) * _LANES
+    pad = (-e) % tile
+    if pad:
+        chunks = jnp.pad(chunks, ((0, 0), (0, pad)))
+    return chunks.reshape(c, (e + pad) // _LANES, _LANES)
+
+
+def _tile(x):
+    """One array as a single chunk of whole tiles: -> [rows, 128]."""
+    return _to_tiles(x.reshape(1, -1))[0]
+
+
+def _from_tiles(tiles, shape):
+    """Inverse of _to_tiles for ONE chunk: [rows, 128] -> shape."""
+    return tiles.reshape(-1)[:math.prod(shape)].reshape(shape)
+
+
+def _neighbor_handshake(axis: str, my, n: int, d: int):
+    pltpu = _pltpu()
     nxt = (my + d) % n
     prv = (my - d) % n
     barrier = pltpu.get_barrier_semaphore()
-    pltpu.semaphore_signal(barrier, 1, device_id=(nxt,),
-                           device_id_type=pltpu.DeviceIdType.MESH)
-    pltpu.semaphore_signal(barrier, 1, device_id=(prv,),
-                           device_id_type=pltpu.DeviceIdType.MESH)
+    for peer in (nxt, prv):
+        pltpu.semaphore_signal(
+            barrier, 1, device_id={axis: peer},
+            device_id_type=jaxcompat.pallas_device_id_type())
     pltpu.semaphore_wait(barrier, 2)
     return nxt
 
 
+def _hop_dma(axis: str, src, dst, send_sem, recv_sem, nxt):
+    """One ring hop: my ``src`` into the +d neighbor's ``dst``; the
+    descriptor's wait() covers my send AND the matching arrival from
+    the -d neighbor (every rank issues the same copy)."""
+    return _pltpu().make_async_remote_copy(
+        src_ref=src, dst_ref=dst, send_sem=send_sem, recv_sem=recv_sem,
+        device_id={axis: nxt},
+        device_id_type=jaxcompat.pallas_device_id_type())
+
+
+def _ring_scratch(pltpu, n: int, shape, dtype):
+    """n-1 landing slots, one carry buffer, a send and a receive DMA
+    semaphore per hop."""
+    return [pltpu.VMEM((n - 1,) + shape, dtype),
+            pltpu.VMEM(shape, dtype),
+            pltpu.SemaphoreType.DMA((n - 1,)),
+            pltpu.SemaphoreType.DMA((n - 1,))]
+
+
+def _ring_reduce(axis: str, n: int, d: int, fn, x_ref, land, acc,
+                 send_sem, recv_sem):
+    """The reduce_scatter ring over a VMEM-resident [n, rows, 128]
+    payload; returns the fully reduced own chunk as a value. Carry
+    starts at chunk my-d; step s folds ``fn(arrived, own)`` with own
+    chunk my-(s+2)d — the parallel/ring.py schedule."""
+    my = lax.axis_index(axis)
+    nxt = _neighbor_handshake(axis, my, n, d)
+    val = None
+    for s in range(n - 1):
+        src = x_ref.at[(my - d) % n] if s == 0 else acc
+        rdma = _hop_dma(axis, src, land.at[s], send_sem.at[s],
+                        recv_sem.at[s], nxt)
+        rdma.start()
+        rdma.wait()
+        val = fn(land[s], x_ref[(my - (s + 2) * d) % n])
+        if s < n - 2:
+            acc[...] = val
+    return val
+
+
 def _dma_reduce_scatter(x, axis: str, n: int, k: int, fn: Callable,
-                        d: int):
+                        d: int, interpret=False):
     pl, pltpu = _pl(), _pltpu()
     chunk_shape = (k,) + x.shape[1:]
+    tiles = _to_tiles(x.reshape(n, -1))
+    tile_shape = tiles.shape[1:]
 
-    def kernel(x_ref, o_ref, comm_buf, send_sem, recv_sem):
-        my = lax.axis_index(axis)
-        nxt = _neighbor_handshake(pltpu, my, n, d)
-        comm_buf[0] = x_ref[pl.ds(((my - d) % n) * k, k)]
-        for s in range(n - 1):
-            slot, nslot = s % 2, (s + 1) % 2
-            rdma = pltpu.make_async_remote_copy(
-                src_ref=comm_buf.at[slot],
-                dst_ref=comm_buf.at[nslot],
-                send_sem=send_sem.at[slot],
-                recv_sem=recv_sem.at[nslot],
-                device_id=(nxt,),
-                device_id_type=pltpu.DeviceIdType.MESH)
-            rdma.start()
-            rdma.wait()
-            own = x_ref[pl.ds(((my - (s + 2) * d) % n) * k, k)]
-            comm_buf[nslot] = fn(comm_buf[nslot], own)
-        o_ref[...] = comm_buf[(n - 1) % 2]
+    def kernel(x_ref, o_ref, *scratch):
+        o_ref[...] = _ring_reduce(axis, n, d, fn, x_ref, *scratch)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        out_shape=_sds(chunk_shape, x.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2,) + chunk_shape, x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        compiler_params=_compiler_params(pltpu, CID_RS),
-    )(x)
+        out_shape=_sds(tile_shape, x.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        scratch_shapes=_ring_scratch(pltpu, n, tile_shape, x.dtype),
+        compiler_params=jaxcompat.pallas_compiler_params(CID_RS),
+        interpret=interpret,
+    )(tiles)
+    return _from_tiles(out, chunk_shape)
 
 
-def _dma_allgather(x, axis: str, n: int, d: int):
+def _dma_allgather(x, axis: str, n: int, d: int, interpret=False):
     pl, pltpu = _pl(), _pltpu()
-    k = x.shape[0]
-    out_shape = (n * k,) + x.shape[1:]
+    tiles = _tile(x)
 
-    def kernel(x_ref, o_ref, comm_buf, send_sem, recv_sem):
+    def kernel(x_ref, o_ref, copy_sem, send_sem, recv_sem):
         my = lax.axis_index(axis)
-        nxt = _neighbor_handshake(pltpu, my, n, d)
-        o_ref[pl.ds(my * k, k)] = x_ref[...]
-        comm_buf[0] = x_ref[...]
+        nxt = _neighbor_handshake(axis, my, n, d)
+        own = pltpu.make_async_copy(x_ref, o_ref.at[my], copy_sem)
+        own.start()
+        own.wait()
         for s in range(n - 1):
-            slot, nslot = s % 2, (s + 1) % 2
-            rdma = pltpu.make_async_remote_copy(
-                src_ref=comm_buf.at[slot],
-                dst_ref=comm_buf.at[nslot],
-                send_sem=send_sem.at[slot],
-                recv_sem=recv_sem.at[nslot],
-                device_id=(nxt,),
-                device_id_type=pltpu.DeviceIdType.MESH)
+            # forward the block that arrived last hop (own at s=0)
+            # into the SAME slot of the neighbor's output
+            blk = o_ref.at[(my - s * d) % n]
+            rdma = _hop_dma(axis, blk, blk, send_sem.at[s],
+                            recv_sem.at[s], nxt)
             rdma.start()
             rdma.wait()
-            src = (my - (s + 1) * d) % n
-            o_ref[pl.ds(src * k, k)] = comm_buf[nslot]
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        out_shape=_sds(out_shape, x.dtype),
+        out_shape=_sds((n,) + tiles.shape, x.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((2, k) + x.shape[1:], x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA(()),
+            pltpu.SemaphoreType.DMA((n - 1,)),
+            pltpu.SemaphoreType.DMA((n - 1,)),
         ],
-        compiler_params=_compiler_params(pltpu, CID_AG),
-    )(x)
+        compiler_params=jaxcompat.pallas_compiler_params(CID_AG),
+        interpret=interpret,
+    )(tiles)
+    full = out.reshape(n, -1)[:, :x.size]
+    return full.reshape((n * x.shape[0],) + x.shape[1:])
+
+
+def _tiled_fold(g, n: int, fn: Callable, interpret=False):
+    """Rank-order fold of g[0..n-1] as a row-blocked Pallas kernel
+    (the 'linear' combine on TPU): [n, *shape] -> shape."""
+    pl, pltpu = _pl(), _pltpu()
+    shape = g.shape[1:]
+    tiles = _to_tiles(g.reshape(n, -1))
+    rows = tiles.shape[1]
+    block = min(rows, 512)
+    out = pl.pallas_call(
+        _fold_body(n, fn),
+        out_shape=_sds((rows, _LANES), g.dtype),
+        grid=(pl.cdiv(rows, block),),
+        in_specs=[pl.BlockSpec((n, block, _LANES),
+                               lambda i: (0, i, 0),
+                               memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((block, _LANES), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM),
+        interpret=interpret,
+    )(tiles)
+    return _from_tiles(out, shape)
 
 
 def _dma_reduce_scatter_update(x, axis: str, n: int, k: int,
-                               fn: Callable, p, v, *, lr, mu, inv):
+                               fn: Callable, p, v, *, lr, mu, inv,
+                               interpret=False):
     pl, pltpu = _pl(), _pltpu()
     with_mom = v is not None
+    tiles = _to_tiles(x.reshape(n, -1))
+    tile_shape = tiles.shape[1:]
+    shards = [_tile(t) for t in ((p, v) if with_mom else (p,))]
 
-    def body(x_ref, p_ref, v_ref, po_ref, vo_ref, comm_buf,
-             send_sem, recv_sem):
-        my = lax.axis_index(axis)
-        nxt = _neighbor_handshake(pltpu, my, n, 1)
-        comm_buf[0] = x_ref[pl.ds(((my - 1) % n) * k, k)]
-        for s in range(n - 1):
-            slot, nslot = s % 2, (s + 1) % 2
-            rdma = pltpu.make_async_remote_copy(
-                src_ref=comm_buf.at[slot],
-                dst_ref=comm_buf.at[nslot],
-                send_sem=send_sem.at[slot],
-                recv_sem=recv_sem.at[nslot],
-                device_id=(nxt,),
-                device_id_type=pltpu.DeviceIdType.MESH)
-            rdma.start()
-            rdma.wait()
-            own = x_ref[pl.ds(((my - 2 - s) % n) * k, k)]
-            comm_buf[nslot] = fn(comm_buf[nslot], own)
+    def kernel(x_ref, *refs):
+        ins, outs = refs[:len(shards)], refs[len(shards):2 * len(shards)]
+        g = _ring_reduce(axis, n, 1, fn, x_ref, *refs[2 * len(shards):])
         # fused epilogue: the reduced chunk never leaves VMEM
-        g = comm_buf[(n - 1) % 2]
-        pn, vn = _apply_update(g, p_ref[...],
-                               v_ref[...] if with_mom else None,
-                               lr, mu, inv)
-        po_ref[...] = pn
+        pn, vn = _apply_update(g, ins[0][...],
+                               ins[1][...] if with_mom else None,
+                               lr, mu, inv, barrier=False)
+        outs[0][...] = pn
         if with_mom:
-            vo_ref[...] = vn
+            outs[1][...] = vn
 
-    if with_mom:
-        def kernel(x_ref, p_ref, v_ref, po_ref, vo_ref, *scratch):
-            body(x_ref, p_ref, v_ref, po_ref, vo_ref, *scratch)
-
-        out_shape = (_sds(p.shape, p.dtype), _sds(v.shape, v.dtype))
-        args = (x, p, v)
-    else:
-        def kernel(x_ref, p_ref, po_ref, *scratch):
-            body(x_ref, p_ref, None, po_ref, None, *scratch)
-
-        out_shape = (_sds(p.shape, p.dtype),)
-        args = (x, p)
-
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     outs = pl.pallas_call(
         kernel,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((2, k), x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        compiler_params=_compiler_params(pltpu, CID_FUSED),
-    )(*args)
-    return (outs[0], outs[1]) if with_mom else (outs[0], None)
+        out_shape=tuple(_sds(tile_shape, t.dtype) for t in shards),
+        in_specs=[vmem] * (1 + len(shards)),
+        out_specs=tuple([vmem] * len(shards)),
+        scratch_shapes=_ring_scratch(pltpu, n, tile_shape, x.dtype),
+        compiler_params=jaxcompat.pallas_compiler_params(CID_FUSED),
+        interpret=interpret,
+    )(tiles, *shards)
+    pn = _from_tiles(outs[0], p.shape)
+    return (pn, _from_tiles(outs[1], v.shape)) if with_mom \
+        else (pn, None)
 
 
-def _dma_allgather_matmul(x, w, axis: str, n: int, out_dtype):
+def _dma_allgather_matmul(x, w, axis: str, n: int, out_dtype,
+                          interpret=False):
     pl, pltpu = _pl(), _pltpu()
     m, f = x.shape[0], w.shape[1]
 
-    def kernel(x_ref, w_ref, o_ref, comm_buf, send_sem, recv_sem):
+    def kernel(x_ref, w_ref, o_ref, land, send_sem, recv_sem):
         my = lax.axis_index(axis)
-        nxt = _neighbor_handshake(pltpu, my, n, 1)
-        comm_buf[0] = x_ref[...]
+        nxt = _neighbor_handshake(axis, my, n, 1)
         for s in range(n - 1):
-            slot, nslot = s % 2, (s + 1) % 2
-            rdma = pltpu.make_async_remote_copy(
-                src_ref=comm_buf.at[slot],
-                dst_ref=comm_buf.at[nslot],
-                send_sem=send_sem.at[slot],
-                recv_sem=recv_sem.at[nslot],
-                device_id=(nxt,),
-                device_id_type=pltpu.DeviceIdType.MESH)
+            blk = x_ref if s == 0 else land.at[s - 1]
+            rdma = _hop_dma(axis, blk, land.at[s], send_sem.at[s],
+                            recv_sem.at[s], nxt)
             rdma.start()
             # overlap: multiply the block that arrived last hop (own
             # block at s=0) while this hop's DMA is in flight
-            src = (my - s) % n
-            o_ref[pl.ds(src * m, m)] = jnp.dot(
-                comm_buf[slot], w_ref[...],
-                preferred_element_type=out_dtype)
+            o_ref[(my - s) % n] = _dot(blk[...], w_ref[...], out_dtype)
             rdma.wait()
-        last = (my - (n - 1)) % n
-        o_ref[pl.ds(last * m, m)] = jnp.dot(
-            comm_buf[(n - 1) % 2], w_ref[...],
-            preferred_element_type=out_dtype)
+        o_ref[(my - (n - 1)) % n] = _dot(land[n - 2], w_ref[...],
+                                         out_dtype)
 
-    return pl.pallas_call(
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
         kernel,
-        out_shape=_sds((n * m, f), out_dtype),
+        out_shape=_sds((n, m, f), out_dtype),
+        in_specs=[vmem, vmem],
+        out_specs=vmem,
         scratch_shapes=[
-            pltpu.VMEM((2,) + x.shape, x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((n - 1,) + x.shape, x.dtype),
+            pltpu.SemaphoreType.DMA((n - 1,)),
+            pltpu.SemaphoreType.DMA((n - 1,)),
         ],
-        compiler_params=_compiler_params(pltpu, CID_MATMUL),
+        compiler_params=jaxcompat.pallas_compiler_params(CID_MATMUL),
+        interpret=interpret,
     )(x, w)
+    return out.reshape((n * m, f))

@@ -7,9 +7,13 @@ SPSC ring (publish/consume with real acquire/release atomics instead
 of the x86-TSO+GIL assumption) and the datatype span gather/scatter
 (opal_datatype_pack.c's hot loop).
 
-Build-on-first-use (``make -C csrc``); every entry point degrades to
-the pure-Python implementation when no compiler is available, so the
-framework stays importable anywhere (the accelerator/null pattern).
+Build-on-first-use: the library is compiled when it is missing OR
+older than ``ompitpu_core.c`` (the .so is git-ignored, so a checkout
+switch or a copied tree can leave a stale one beside a newer source).
+Every entry point degrades to the pure-Python implementation when no
+compiler is available, so the framework stays importable anywhere
+(the accelerator/null pattern); :func:`status` says which one a
+process got, and why.
 """
 
 from __future__ import annotations
@@ -33,16 +37,26 @@ _enabled_var = cvar.register(
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_status = "not loaded yet"
 
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "csrc")
+_SRC = os.path.join(_CSRC, "ompitpu_core.c")
 _SO = os.path.join(_CSRC, "libompitpu_core.so")
+
+
+def _stale() -> bool:
+    """No library, or one built before the source was last written."""
+    try:
+        return os.path.getmtime(_SO) < os.path.getmtime(_SRC)
+    except OSError:
+        return True
 
 
 def lib() -> Optional[ctypes.CDLL]:
     """The native library, building it on first call; None if disabled
     or unbuildable."""
-    global _lib, _tried
+    global _lib, _tried, _status
     if _lib is not None or _tried:
         return _lib
     with _lock:
@@ -50,28 +64,33 @@ def lib() -> Optional[ctypes.CDLL]:
             return _lib
         _tried = True
         if not _enabled_var.get():
+            _status = "pure Python: disabled by --mca native 0"
             return None
-        if not os.path.exists(_SO) and not _build():
+        built = _stale()
+        why = _build() if built else None
+        if why is not None:
+            _status = f"pure Python: build failed ({why})"
             return None
-        L = None
         try:
-            L = _bind(ctypes.CDLL(_SO))
-        except OSError as exc:
-            _out.verbose(1, "native core unavailable: %s", exc)
-        except AttributeError:
-            # stale .so from an older checkout (gitignored, so it
-            # survives checkout switches): rebuild once, else fall
-            # back to pure Python
-            _out.verbose(1, "native core stale; rebuilding")
-            if _build():
-                try:
-                    L = _bind(ctypes.CDLL(_SO))
-                except (OSError, AttributeError) as exc:
-                    _out.verbose(1, "native rebuild unusable: %s", exc)
-        _lib = L
-        if L is not None:
+            _lib = _bind(ctypes.CDLL(_SO))
+        except (OSError, AttributeError) as exc:
+            _out.verbose(1, "native core unusable: %s", exc)
+            _status = f"pure Python: {_SO} unusable ({exc})"
+            return None
+        if _lib is None:
+            _status = "pure Python: native core ABI mismatch"
+        else:
+            _status = ("native: built in this run" if built
+                       else "native: loaded an up-to-date build")
             _out.verbose(2, "native core loaded: %s", _SO)
         return _lib
+
+
+def status() -> str:
+    """Which core this process runs on after :func:`lib` was tried:
+    ``native: ...`` or ``pure Python: <why>``."""
+    lib()
+    return _status
 
 
 def _bind(L: ctypes.CDLL) -> Optional[ctypes.CDLL]:
@@ -101,30 +120,29 @@ def _bind(L: ctypes.CDLL) -> Optional[ctypes.CDLL]:
     return L
 
 
-def _build() -> bool:
+def _build() -> Optional[str]:
     """Compile to a private temp file, then atomically publish — N
     ranks may race here on first use and each must either see no .so
     or a complete one (concurrent `make` on a shared output can be
     dlopened half-written)."""
     import tempfile
 
-    src = os.path.join(_CSRC, "ompitpu_core.c")
     cc = os.environ.get("CC", "cc")
     try:
         fd, tmp = tempfile.mkstemp(suffix=".so", dir=_CSRC)
         os.close(fd)
         r = subprocess.run(
-            [cc, "-O3", "-fPIC", "-std=c11", "-shared", src, "-o", tmp],
+            [cc, "-O3", "-fPIC", "-std=c11", "-shared", _SRC, "-o", tmp],
             capture_output=True, text=True, timeout=120)
         if r.returncode != 0:
             _out.verbose(1, "native build failed:\n%s", r.stderr)
             os.unlink(tmp)
-            return False
+            return f"{cc} exited {r.returncode}"
         os.replace(tmp, _SO)  # atomic: racers each publish a whole file
-        return True
+        return None
     except (OSError, subprocess.TimeoutExpired) as exc:
         _out.verbose(1, "native build unavailable: %s", exc)
-        return False
+        return repr(exc)
 
 
 def available() -> bool:
@@ -132,7 +150,8 @@ def available() -> bool:
 
 
 def reset_for_testing() -> None:
-    global _lib, _tried
+    global _lib, _tried, _status
     with _lock:
         _lib = None
         _tried = False
+        _status = "not loaded yet"

@@ -3,8 +3,9 @@
 The TPU-native rendering of the reference's osc/rdma component
 (osc_rdma_comm.c: Put/Get/Accumulate as NIC RDMA inside epochs): the
 window buffer is an HBM-resident jax array pinned at ``Win_create``,
-and every window mutation runs as a Pallas kernel over it
-(:mod:`ompi_tpu.osc.pallas_kernels`) instead of a host memcpy.
+and every window mutation is a device program over it
+(:mod:`ompi_tpu.osc.pallas_kernels`: an XLA update in place of the
+host memcpy, a Pallas DMA kernel for the fence transport).
 
 Division of labor per epoch family:
 
@@ -14,18 +15,18 @@ Division of labor per epoch family:
   rounds (the device_epoch/xla_neighbor machinery), moves each round
   with ``make_async_remote_copy`` DMA on TPU — semaphore-paced, the
   PR-10 discipline — or a compiled ``ppermute`` on CPU, and applies
-  landed payloads with the SAME interpret-capable kernels either way.
-  That sameness is the test story: tier-1 proves bit-identity against
-  the host window on 2/3/4-rank meshes without hardware, exactly how
+  landed payloads with the SAME update program either way. That
+  sameness is the test story: tier-1 proves bit-identity against the
+  host window on 2/3/4-rank meshes without hardware, exactly how
   coll/pallas is tested.
 - **PSCW and passive target** (Lock/Unlock/Flush): synchronization
   rides the host :class:`~ompi_tpu.osc.Window` active-message
   machinery this class subclasses — per-peer exposure via post/
   complete messages, the lock manager, flush acks — while the TARGET-
   side data path is overridden: payloads land in the device window
-  through the apply kernels under the inherited per-window mutex
+  through the apply layer under the inherited per-window mutex
   (``_local_mutex`` — the Accumulate atomicity discipline), and reads
-  are kernel slices. Per-pair FIFO delivery means a flush/unlock ack
+  are device slices. Per-pair FIFO delivery means a flush/unlock ack
   still implies every prior op is applied on device.
 
 Epoch discipline is ENFORCED here (the host window is permissive):
@@ -44,9 +45,13 @@ window elements (the device_epoch convention), and operands must
 match the window dtype — an Accumulate dtype mismatch raises
 ``MPIError(ERR_ARG)``.
 
-Real-TPU DMA-bandwidth validation is carried as bench debt
-(ROADMAP); ``bench.py --osc`` measures the kernel apply/read path
-and halo-exchange step times today.
+On TPU a round kernel Mosaic refuses raises ``MPIError`` carrying the
+compiler's message (``coll.pallas_kernels.compiled_or_raise``) — the
+window never drops to interpret mode or to the host path behind the
+caller's back. The round kernel keeps its payload whole in VMEM, so a
+round above ``coll_pallas_dma_max_bytes`` rides a compiled XLA
+``ppermute`` instead, counted in ``osc_pallas_fallthrough``. No timing
+of this plane has been taken on a chip.
 """
 
 from __future__ import annotations
@@ -61,6 +66,8 @@ from ompi_tpu.monitoring import algo as _algo
 from ompi_tpu.monitoring import matrix as _mon
 from ompi_tpu.osc import LOCK_EXCLUSIVE, Window, _is_dev
 from ompi_tpu.osc.device_epoch import GetHandle, _color
+from ompi_tpu.coll import pallas as _coll_pallas
+from ompi_tpu.coll.pallas_kernels import compiled_or_raise
 from ompi_tpu.osc import pallas_kernels as K
 from ompi_tpu.telemetry import flight as _flight
 from ompi_tpu.trace import recorder as _trace
@@ -72,7 +79,7 @@ _enable_var = cvar.register(
     "osc_pallas", "off", str,
     help="Enable the device-resident Pallas one-sided backend: 'on' "
          "serves win_create over a supported jax array with "
-         "PallasWindow (kernel-applied RMA, device-resident fence "
+         "PallasWindow (device-applied RMA, device-resident fence "
          "epochs); 'off' [default] keeps the host-staging window. "
          "Opt-in because it changes device-window semantics from "
          "documented host staging to device-authoritative.",
@@ -82,7 +89,7 @@ _interpret_var = cvar.register(
     "osc_pallas_interpret", "auto", str,
     help="Fence transport: 'auto' [default] uses the "
          "make_async_remote_copy DMA round kernel on real TPU and "
-         "the interpret-mode schedule (identical apply kernels + "
+         "the interpret-mode schedule (identical apply layer + "
          "ppermute hops) everywhere else; 'on' forces interpret even "
          "on TPU (debugging); 'off' forces the DMA kernel "
          "(fails off-TPU).",
@@ -93,8 +100,9 @@ _SUPPORTED_DTYPES = frozenset(("float32", "bfloat16", "int32"))
 
 FALLTHROUGH_EVENT = mpit_events.register_type(
     "osc_pallas_fallthrough",
-    "an osc/pallas window or operation fell through to the host path "
-    "(unsupported dtype/shape/op)",
+    "an osc/pallas window or operation fell through one level down: to "
+    "the host path (unsupported dtype/shape/op), or a fence round to "
+    "XLA's collective permute (payload past the DMA kernel's VMEM)",
     ("what", "reason"))
 
 _warned: set = set()
@@ -117,8 +125,8 @@ def _fallthrough_note(what: str, reason: str) -> None:
     key = (what, reason)
     if key not in _warned:
         _warned.add(key)
-        _out.verbose(0, "WARNING: osc_pallas %s falls through to the "
-                     "host path: %s", what, reason)
+        _out.verbose(0, "WARNING: osc_pallas %s falls through: %s",
+                     what, reason)
     if mpit_events.active("osc_pallas_fallthrough"):
         mpit_events.emit("osc_pallas_fallthrough", what=what,
                          reason=reason)
@@ -143,7 +151,7 @@ def _flight_exit(tok) -> None:
 class PallasWindow(Window):
     """Device-resident MPI window: the authoritative buffer is a flat
     jax array (``.array`` reshapes it back); all target-side RMA runs
-    as Pallas kernels; fence epochs lower to edge-colored ICI rounds.
+    as device programs; fence epochs lower to edge-colored ICI rounds.
 
     Created via ``osc.win_create`` under ``--mca osc_pallas on`` (see
     :func:`maybe_window`), or directly with
@@ -256,12 +264,12 @@ class PallasWindow(Window):
         kind = self._acc_kind(op)
         data = self._payload(buf, "Accumulate")
         if kind not in K.ELEMENTWISE:
-            # valid op, unsupported by the kernel plane: host-assist
+            # valid op, unsupported by the apply layer: host-assist
             # read-modify-write via the AM path (atomic under the
             # target's window mutex)
             _fallthrough_note(
                 "accumulate", f"op {getattr(op, 'name', op)!r} is "
-                "not elementwise")
+                "not elementwise; the host path serves it")
             pvar.record("osc_pallas_am_ops")
             super().Accumulate(np.asarray(data), target, disp, op)
             return
@@ -273,7 +281,7 @@ class PallasWindow(Window):
 
     def Get(self, buf, target: int, disp: int = 0):
         """Synchronous Get (host-window contract): the target-side
-        read is a kernel slice of its device window; the reply rides
+        read is a device slice of its window; the reply rides
         the AM plane. For device-resident fence-batched gets use
         :meth:`Get_epoch`."""
         pvar.record("osc_pallas_get")
@@ -321,7 +329,7 @@ class PallasWindow(Window):
                        op: op_mod.Op = op_mod.SUM) -> None:
         """Atomic fetch-and-accumulate: served through the AM plane
         (the target's service loop is the serialization point), with
-        the device window read/updated by kernels under the window
+        the device window read/updated on device under the window
         mutex."""
         pvar.record("osc_pallas_get_acc")
         self._epoch_for(target)
@@ -329,7 +337,7 @@ class PallasWindow(Window):
                 and getattr(op, "name", op) not in ("MPI_NO_OP",):
             _fallthrough_note(
                 "get_accumulate", f"op {getattr(op, 'name', op)!r} "
-                "is not elementwise")
+                "is not elementwise; the host path serves it")
         self._payload(origin, "Get_accumulate")
         pvar.record("osc_pallas_am_ops")
         super().Get_accumulate(origin, result, target, disp, op)
@@ -454,30 +462,35 @@ class PallasWindow(Window):
         finally:
             _flight_exit(tok)
 
-    # -- target-side data path (kernel applies) ---------------------------
+    # -- target-side data path (device applies) ---------------------------
     def _apply_local(self, data, disp: int, kind: str,
                      stride: int = 1) -> None:
         """Apply one landed payload to the device window via the
-        kernel plane. Caller holds ``_local_mutex`` (the per-window
+        apply layer. Caller holds ``_local_mutex`` (the per-window
         Accumulate atomicity discipline)."""
         import jax.numpy as jnp
 
         payload = jnp.asarray(np.asarray(data).reshape(-1)).astype(
             self._win.dtype)
+        self._apply(payload, disp, kind, stride)
+
+    def _apply(self, payload, disp: int, kind: str,
+               stride: int) -> None:
+        """One update of the device window (caller holds
+        ``_local_mutex``)."""
         self._win = K.apply(self._win, payload, int(disp), kind,
-                            int(stride), interpret=self._interp)
+                            int(stride))
         self._dirty = True
 
     def _target_view(self, disp: int, count: int, dtstr: str,
                      stride: int = 1):
-        """Kernel-read COPY of the window slice (element offsets —
-        PJRT buffers are immutable, so AM replies always carry
-        copies; mutations go through :meth:`_apply_local`)."""
+        """COPY of the window slice (element offsets — PJRT buffers
+        are immutable, so AM replies always carry copies; mutations
+        go through :meth:`_apply_local`)."""
         if count == 0:
             return np.empty(0, np.dtype(self._dtype))
         return np.asarray(K.read(self._win, int(disp), int(count),
-                                 int(stride),
-                                 interpret=self._interp))
+                                 int(stride)))
 
     def _target_put(self, disp: int, data: np.ndarray) -> None:
         with self._local_mutex:
@@ -498,7 +511,7 @@ class PallasWindow(Window):
                 return
             # host-assist: exotic op folds on host (same operand
             # order as the host window: np_fn(data, current)), the
-            # result replaces the slice via the put kernel
+            # result replaces the slice via a "replace" apply
             cur = self._target_view(disp, data.size, data.dtype.str)
             op = op_mod.BUILTIN[opname]
             self._apply_local(
@@ -510,7 +523,7 @@ class PallasWindow(Window):
 
     def _handle(self, msg: tuple, src: int) -> None:
         kind = msg[0]
-        if kind == "puts":  # strided put: kernel apply, not view[:]=
+        if kind == "puts":  # strided put: device apply, not view[:]=
             _, disp, stride, data = msg
             if data.size:
                 with self._local_mutex:
@@ -538,8 +551,9 @@ class PallasWindow(Window):
                 yield n, rnd
 
     def _permute(self, payload, perm, nelems: int):
-        """CPU transport: one compiled single-round ppermute (cached
-        per (nelems, perm))."""
+        """CPU transport, and the TPU one for a round too large for
+        the DMA kernel: one compiled single-round ppermute (cached per
+        (nelems, perm))."""
         from jax import lax
 
         from ompi_tpu.coll import xla as X
@@ -561,24 +575,43 @@ class PallasWindow(Window):
         round."""
         import jax.numpy as jnp
 
+        from ompi_tpu.coll import xla as X
+
         ctx = self._xctx
 
         def build():
+            # ONE pytree operand (payload, tgt, src), as every smap
+            # body takes; [0] drops each local block's comm axis
             return ctx.smap(
-                lambda a: K.dma_permute(a[0], a[1], a[2]),
+                lambda a: K.dma_permute(a[0][0], a[1][0], a[2][0],
+                                        X.AXIS, ctx.n),
                 out_varying=True)
 
         fn = ctx.compiled(
             ("osc_pallas_dma", int(payload.shape[0]), self._dtype),
             build)
-        return ctx.my_shard(fn(
-            ctx.to_global(payload),
-            ctx.to_global(jnp.asarray([tgt], jnp.int32)),
-            ctx.to_global(jnp.asarray([src], jnp.int32))))
+        operands = (ctx.to_global(payload),
+                    ctx.to_global(jnp.asarray([tgt], jnp.int32)),
+                    ctx.to_global(jnp.asarray([src], jnp.int32)))
+        return compiled_or_raise(
+            "osc_pallas dma_permute",
+            lambda: ctx.my_shard(fn(operands)))
 
     def _transport(self, payload, perm, nelems: int):
         pvar.record("osc_pallas_rounds")
+        if all(s == d for s, d in perm):
+            return payload  # a round of self-edges moves nothing
         if self._interp:
+            return self._permute(payload, perm, nelems)
+        if not _coll_pallas.dma_fits(
+                K.round_vmem_bytes(int(payload.nbytes))):
+            # every rank of the round sees the same nelems, so all
+            # take this branch together
+            _fallthrough_note(
+                "fence round",
+                "the payload does not fit the DMA kernel's VMEM "
+                "(coll_pallas_dma_max_bytes); XLA's collective "
+                "permute moves it")
             return self._permute(payload, perm, nelems)
         tgt = src = -1
         for s, d in perm:
@@ -646,10 +679,7 @@ class PallasWindow(Window):
             if my_in is not None:
                 disp, kind, stride = my_in
                 with self._local_mutex:
-                    self._win = K.apply(self._win, recvd, disp, kind,
-                                        stride,
-                                        interpret=self._interp)
-                    self._dirty = True
+                    self._apply(recvd, disp, kind, stride)
 
     def _run_fence_gets(self, gets, jnp) -> None:
         # data flows target -> origin: edges (src=target, dst=origin)
@@ -660,9 +690,8 @@ class PallasWindow(Window):
             payload = jnp.zeros(nelems, self._win.dtype)
             my_in: Optional[Tuple[int, int, int]] = None
             for s, d, disp, _n, stride in rnd:
-                if s == self.rank:  # I am the target: kernel-read
-                    payload = K.read(self._win, disp, nelems, stride,
-                                     interpret=self._interp)
+                if s == self.rank:  # I am the target: device read
+                    payload = K.read(self._win, disp, nelems, stride)
                 if d == self.rank:
                     my_in = (s, disp, stride)
             recvd = self._transport(payload, perm, nelems)
@@ -707,7 +736,8 @@ def maybe_window(comm, base, disp_unit: int = 1,
             "win_create",
             f"unsupported or rank-asymmetric window "
             f"(dtypes {reasons}; supported "
-            f"{sorted(_SUPPORTED_DTYPES)}, device arrays only)")
+            f"{sorted(_SUPPORTED_DTYPES)}, device arrays only); "
+            "the host window serves it")
         return None
     return PallasWindow(comm, base, disp_unit, info=info)
 

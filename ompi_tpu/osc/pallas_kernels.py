@@ -4,27 +4,30 @@ The kernel library under :mod:`ompi_tpu.osc.pallas`, following the
 coll/pallas_kernels transport discipline (PR 10):
 
 - **Apply layer** (both backends): every window mutation — Put,
-  elementwise Accumulate, their strided halo variants — and every
-  window read is a ``pl.pallas_call`` kernel over the flat window
-  array. Dynamic element offsets ride in as ``(1,)`` int32 operands
-  so one compiled kernel serves every displacement. The apply layer
-  is IDENTICAL on TPU (compiled) and CPU (``interpret=True``), which
-  is what lets tier-1 prove bit-identity against the host window
-  without hardware.
+  elementwise Accumulate, their strided halo variants — is one jitted
+  XLA update of the flat window array at the landed payload's
+  positions (:func:`apply`; displacement and stride are runtime
+  operands, so one compiled program serves them all), and every
+  window read an XLA slice (:func:`read`). Not Pallas: Mosaic
+  addresses whole (sublane, 128) tiles and cannot touch a ref at an
+  arbitrary element offset, so a kernel here could only select over
+  the whole window what XLA updates in place. The window is NOT
+  donated — ``PallasWindow.array`` and the active-message thread read
+  the same buffer — so an update copies the window once. The layer
+  is the same program on TPU and CPU, which is what lets tier-1 prove
+  bit-identity against the host window without hardware.
 - **Transport layer**: on TPU :func:`dma_permute` moves one
   edge-colored round's payloads with ``pltpu.make_async_remote_copy``
   into the receiver's VMEM landing scratch — semaphore-paced
-  (DMA send/recv pair), opened by a barrier-semaphore handshake with
-  the round's actual partners so no rank DMAs into a peer that has
-  not entered the kernel (``collective_id`` :data:`CID_RMA`; ids 1-5
-  belong to the coll/pallas ring kernels). On CPU the interpreter
-  cannot emulate inter-device DMA (``jaxcompat.pallas_remote_dma_ok``)
-  so the hop is a ``lax.ppermute`` built by the caller — same round
-  structure, same apply kernels, identical results.
+  (DMA send/recv pair), opened by a barrier-semaphore handshake
+  (``collective_id`` :data:`CID_RMA`; ids 1-5 belong to the
+  coll/pallas ring kernels). On CPU the hop is a ``lax.ppermute``
+  built by the caller — same round structure, same apply layer,
+  identical results.
 
-Real-TPU DMA bandwidth for this path is a ROADMAP carry-over; the
-round schedule, landing-buffer protocol and apply kernels are
-validated here in interpret mode.
+Which of these Mosaic compiles on a v5e is recorded by
+``chip_smoke.py`` (CHANGES.md, PR 21); no timing of this path has been
+taken on a chip.
 """
 
 from __future__ import annotations
@@ -35,7 +38,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ompi_tpu.coll.pallas_kernels import _compiler_params, _pl, _pltpu, _sds
+from ompi_tpu.coll.pallas_kernels import (
+    _from_tiles, _pl, _pltpu, _sds, _tile,
+)
 from ompi_tpu.util import jaxcompat
 
 #: barrier-semaphore collective id for the RMA round kernel
@@ -59,204 +64,128 @@ _COMBINE = {
 ELEMENTWISE = frozenset(_COMBINE)
 
 
-def _iota(n: int):
-    """1D iota via the TPU-safe 2D broadcast (guide pitfall #4)."""
-    return lax.broadcasted_iota(jnp.int32, (n, 1), 0).squeeze(-1)
-
-
-def _specs(pl, pltpu, n_tensor: int, n_scalar: int):
-    """VMEM tensor operands + SMEM scalar operands for the compiled
-    (TPU) path; interpret mode takes no specs."""
-    ins = [pl.BlockSpec(memory_space=pltpu.VMEM)] * n_tensor
-    ins += [pl.BlockSpec(memory_space=pltpu.SMEM)] * n_scalar
-    return ins, pl.BlockSpec(memory_space=pltpu.VMEM)
-
-
-def _pallas_call(body, out_shape, n_tensor: int, n_scalar: int,
-                 interpret: bool):
-    pl = _pl()
-    if interpret:
-        return pl.pallas_call(body, out_shape=out_shape,
-                              interpret=True)
-    pltpu = _pltpu()
-    in_specs, out_spec = _specs(pl, pltpu, n_tensor, n_scalar)
-    return pl.pallas_call(body, out_shape=out_shape,
-                          in_specs=in_specs, out_specs=out_spec)
-
-
 # ---------------------------------------------------------------------------
-# apply layer — window mutation / read kernels (shared TPU + interpret)
+# apply layer — window mutation and read (XLA, both backends)
 
 
 @lru_cache(maxsize=512)
-def _apply_fn(size: int, k: int, dtype: str, kind: str,
-              interpret: bool):
-    """window' = window with _COMBINE[kind](window[d:d+k], payload)
-    written back at dynamic offset d."""
-    pl = _pl()
+def _apply_fn(k: int, kind: str, strided: bool):
+    """window' = window with _COMBINE[kind](window[i], payload[j]) at
+    i = d + j*s for j < k (s = 1 unless ``strided``)."""
     fn = _COMBINE[kind]
 
-    def body(w_ref, p_ref, d_ref, o_ref):
-        d = d_ref[0]
-        cur = w_ref[pl.ds(d, k)]
-        o_ref[...] = w_ref[...]
-        o_ref[pl.ds(d, k)] = fn(cur, p_ref[...])
+    def run(w, p, d, s):
+        if strided:
+            idx = d + s * jnp.arange(k, dtype=jnp.int32)
+            return w.at[idx].set(fn(w[idx], p))
+        cur = lax.dynamic_slice(w, (d,), (k,))
+        return lax.dynamic_update_slice(w, fn(cur, p), (d,))
 
-    call = _pallas_call(body, _sds((size,), jnp.dtype(dtype)),
-                        n_tensor=2, n_scalar=1, interpret=interpret)
-    return jax.jit(lambda w, p, d: call(w, p, d))
-
-
-@lru_cache(maxsize=512)
-def _apply_strided_fn(size: int, k: int, dtype: str, kind: str,
-                      interpret: bool):
-    """Strided apply: window[d + i*s] combines payload[i] for
-    i < k — the halo-exchange column case. One masked whole-window
-    select instead of k scatters (stride and offset stay dynamic)."""
-    fn = _COMBINE[kind]
-
-    def body(w_ref, p_ref, d_ref, s_ref, o_ref):
-        w = w_ref[...]
-        d, s = d_ref[0], s_ref[0]
-        off = _iota(size) - d
-        hit = (off >= 0) & (off < k * s) & (off % s == 0)
-        src = jnp.clip(off // jnp.maximum(s, 1), 0, k - 1)
-        p = jnp.take(p_ref[...], src, axis=0)
-        o_ref[...] = jnp.where(hit, fn(w, p), w)
-
-    call = _pallas_call(body, _sds((size,), jnp.dtype(dtype)),
-                        n_tensor=2, n_scalar=2, interpret=interpret)
-    return jax.jit(lambda w, p, d, s: call(w, p, d, s))
+    return jax.jit(run)
 
 
-@lru_cache(maxsize=512)
-def _read_fn(size: int, k: int, dtype: str, stride: bool,
-             interpret: bool):
-    """window[d : d + k] (or window[d + i*s] strided) as a (k,)
-    payload — the Get / landing-zone read kernel."""
-    pl = _pl()
-
-    if stride:
-        def body(w_ref, d_ref, s_ref, o_ref):
-            idx = d_ref[0] + s_ref[0] * _iota(k)
-            o_ref[...] = jnp.take(w_ref[...], idx, axis=0)
-
-        call = _pallas_call(body, _sds((k,), jnp.dtype(dtype)),
-                            n_tensor=1, n_scalar=2,
-                            interpret=interpret)
-        return jax.jit(lambda w, d, s: call(w, d, s))
-
-    def body(w_ref, d_ref, o_ref):
-        o_ref[...] = w_ref[pl.ds(d_ref[0], k)]
-
-    call = _pallas_call(body, _sds((k,), jnp.dtype(dtype)),
-                        n_tensor=1, n_scalar=1, interpret=interpret)
-    return jax.jit(lambda w, d: call(w, d))
-
-
-def _i32(v) -> jnp.ndarray:
-    return jnp.asarray([v], jnp.int32)
-
-
-def apply(window, payload, disp: int, kind: str, stride: int = 1,
-          *, interpret: bool):
+def apply(window, payload, disp: int, kind: str, stride: int = 1):
     """Apply one RMA descriptor to the flat window array; returns the
     new window. ``kind`` is an :data:`ELEMENTWISE` name."""
-    k = int(payload.shape[0])
-    if stride == 1:
-        fn = _apply_fn(int(window.shape[0]), k, str(window.dtype),
-                       kind, interpret)
-        return fn(window, payload, _i32(disp))
-    fn = _apply_strided_fn(int(window.shape[0]), k,
-                           str(window.dtype), kind, interpret)
-    return fn(window, payload, _i32(disp), _i32(stride))
+    fn = _apply_fn(int(payload.shape[0]), kind, stride != 1)
+    return fn(window, payload, jnp.int32(disp), jnp.int32(stride))
 
 
-def read(window, disp: int, nelems: int, stride: int = 1,
-         *, interpret: bool):
-    """Read ``nelems`` window elements at ``disp`` (element stride
-    ``stride``) as a device payload — the Get-side kernel."""
-    if stride == 1:
-        fn = _read_fn(int(window.shape[0]), int(nelems),
-                      str(window.dtype), False, interpret)
-        return fn(window, _i32(disp))
-    fn = _read_fn(int(window.shape[0]), int(nelems),
-                  str(window.dtype), True, interpret)
-    return fn(window, _i32(disp), _i32(stride))
+@lru_cache(maxsize=512)
+def _read_fn(k: int, strided: bool):
+    if strided:
+        return jax.jit(lambda w, d, s: jnp.take(
+            w, d + s * jnp.arange(k, dtype=jnp.int32), axis=0))
+    return jax.jit(lambda w, d, s: lax.dynamic_slice(w, (d,), (k,)))
+
+
+def read(window, disp: int, nelems: int, stride: int = 1):
+    """``nelems`` window elements from ``disp`` (element stride
+    ``stride``) as a device payload — the Get-side read."""
+    return _read_fn(int(nelems), stride != 1)(
+        window, jnp.int32(disp), jnp.int32(stride))
 
 
 # ---------------------------------------------------------------------------
 # transport layer — the TPU DMA round kernel
 
 
-def dma_permute(payload, tgt, src):
+def round_vmem_bytes(payload_bytes: int) -> int:
+    """VMEM :func:`dma_permute` keeps resident: the payload, its
+    landing scratch and the landed output."""
+    return 3 * payload_bytes
+
+
+def dma_permute(payload, tgt, src, axis: str, n: int, interpret=False):
     """One edge-colored RMA round on TPU: DMA my (k,) ``payload`` into
     rank ``tgt``'s VMEM landing scratch, receive my own landing from
     rank ``src``; returns the landed payload (zeros when ``src`` is
     the -1 no-partner sentinel). ``tgt``/``src`` are (1,) int32 mesh
     coordinates — runtime operands, so ONE compiled kernel serves
     every round's pairing. Runs inside ``shard_map`` with the window
-    comm's mesh axis bound, like every coll/pallas DMA kernel.
+    comm's ``axis`` (size ``n``) bound, like every coll/pallas DMA
+    kernel.
 
-    Protocol: barrier-semaphore handshake with the round's ACTUAL
-    partners (each rank signals its tgt and src, then waits for
-    exactly as many signals as it has partners), then one
-    ``make_async_remote_copy`` per edge paced by a DMA send/recv
-    semaphore pair — the receiver blocks on ``recv_sem`` before
-    reading the landing scratch, giving per-edge completion exactly
-    where the reference's osc/rdma waits its BTL RDMA completions."""
+    Protocol: an all-ranks barrier-semaphore handshake opens the
+    round. A round's pairs do not depend on each other, so a
+    partners-only handshake is not enough: a rank that finished early
+    could signal its NEXT round's partner, be counted for this one,
+    and let that partner send to a rank that has not entered yet.
+    With every rank signalling every other, any signal from a later
+    round proves that all ranks passed this one's barrier. Then one
+    ``make_async_remote_copy`` per edge, paced by a DMA send/recv
+    semaphore pair — the sender waits its send, the receiver blocks
+    on the arrival before reading the landing scratch, giving
+    per-edge completion exactly where the reference's osc/rdma waits
+    its BTL RDMA completions."""
     pl, pltpu = _pl(), _pltpu()
-    did = jaxcompat.pallas_device_id_type(pltpu)
+    did = jaxcompat.pallas_device_id_type()
     k = int(payload.shape[0])
+    tiles = _tile(payload)
 
     def kernel(p_ref, t_ref, s_ref, o_ref, land, send_sem, recv_sem):
+        my = lax.axis_index(axis)
         barrier = pltpu.get_barrier_semaphore()
-        has_tgt = t_ref[0] >= 0
-        has_src = s_ref[0] >= 0
-
-        @pl.when(has_tgt)
-        def _signal_tgt():
-            pltpu.semaphore_signal(barrier, 1, device_id=(t_ref[0],),
+        for j in range(1, n):
+            pltpu.semaphore_signal(barrier, 1,
+                                   device_id={axis: (my + j) % n},
                                    device_id_type=did)
+        pltpu.semaphore_wait(barrier, n - 1)
 
-        @pl.when(has_src)
-        def _signal_src():
-            pltpu.semaphore_signal(barrier, 1, device_id=(s_ref[0],),
-                                   device_id_type=did)
+        def edge(peer):
+            return pltpu.make_async_remote_copy(
+                src_ref=p_ref, dst_ref=land, send_sem=send_sem,
+                recv_sem=recv_sem, device_id={axis: peer},
+                device_id_type=did)
 
-        expect = (has_tgt.astype(jnp.int32)
-                  + has_src.astype(jnp.int32))
-        pltpu.semaphore_wait(barrier, expect)
-
-        @pl.when(has_tgt)
+        @pl.when(t_ref[0] >= 0)
         def _send():
-            rdma = pltpu.make_async_remote_copy(
-                src_ref=p_ref, dst_ref=land,
-                send_sem=send_sem, recv_sem=recv_sem,
-                device_id=(t_ref[0],), device_id_type=did)
+            rdma = edge(t_ref[0])
             rdma.start()
-            rdma.wait()
+            rdma.wait_send()
 
-        o_ref[...] = jnp.zeros_like(p_ref[...])
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-        @pl.when(has_src)
+        @pl.when(s_ref[0] >= 0)
         def _recv():
-            pltpu.semaphore_wait(recv_sem, 1)
+            edge(s_ref[0]).wait_recv()
             o_ref[...] = land[...]
 
-    return pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
         kernel,
-        out_shape=_sds((k,), payload.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                  pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_shape=_sds(tiles.shape, payload.dtype),
+        in_specs=[vmem, smem, smem],
+        out_specs=vmem,
         scratch_shapes=[
-            pltpu.VMEM((k,), payload.dtype),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
+            pltpu.VMEM(tiles.shape, payload.dtype),
+            pltpu.SemaphoreType.DMA(()),
+            pltpu.SemaphoreType.DMA(()),
         ],
-        compiler_params=_compiler_params(pltpu, CID_RMA),
-    )(payload,
+        compiler_params=jaxcompat.pallas_compiler_params(CID_RMA),
+        interpret=interpret,
+    )(tiles,
       jnp.asarray(tgt, jnp.int32).reshape((1,)),
       jnp.asarray(src, jnp.int32).reshape((1,)))
+    return _from_tiles(out, (k,))

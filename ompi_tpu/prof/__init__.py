@@ -11,8 +11,8 @@ plane. Three sub-planes, all riding the existing substrate:
   bandwidth gauges and log2 size/latency histograms, emitted by the
   accelerator and ``_Ctx.to_global`` staging sites;
 - **compile observability**: `_Ctx` compile spans + hit/miss pvars,
-  jax's persistent compilation cache wired behind the
-  ``compile_cache_dir`` cvar with ``prof_compile_cache_{hits,misses}``
+  jax's persistent compilation cache placed by
+  :func:`wire_compile_cache` with ``prof_compile_cache_{hits,misses}``
   accounting, and the ``python -m ompi_tpu.prof`` attribution CLI.
 
 Enable with ``--mca prof_enable 1`` (or ``OMPI_TPU_PROF=1``); off by
@@ -21,8 +21,10 @@ default at the usual one-branch cost per instrumented site.
 
 from __future__ import annotations
 
-from typing import Optional
+import os
+import sys
 
+from ompi_tpu import errors
 from ompi_tpu.core import cvar, pvar
 from ompi_tpu.prof.ledger import (  # noqa: F401  (public re-exports)
     PROFILER, Profiler, current_phase, disable, enable,
@@ -31,10 +33,11 @@ from ompi_tpu.prof.ledger import (  # noqa: F401  (public re-exports)
 
 _cache_dir_var = cvar.register(
     "compile_cache_dir", "", str,
-    help="Directory for jax's persistent XLA compilation cache. When "
-         "set, runtime init points jax_compilation_cache_dir here and "
-         "accounts prof_compile_cache_{hits,misses} so repeat jobs "
-         "can prove the cold compile was skipped.",
+    help="Directory for jax's persistent XLA compilation cache, used "
+         "when the environment does not place it "
+         "(JAX_COMPILATION_CACHE_DIR wins); empty [default] = "
+         "<checkout>/.jax_cache. prof_compile_cache_{hits,misses} "
+         "let repeat jobs prove the cold compile was skipped.",
     level=4)
 _cache_min_var = cvar.register(
     "compile_cache_min_secs", -1.0, float,
@@ -56,34 +59,52 @@ def _on_cache_event(event: str, **kw) -> None:
         pvar.record("prof_compile_cache_misses")
 
 
-def wire_compile_cache() -> Optional[str]:
-    """Point jax's persistent compilation cache at the
-    ``compile_cache_dir`` cvar and hook hit/miss accounting.
+#: where the cache goes when neither the environment nor the cvar
+#: places it: a FIXED path beside the package (the path is part of
+#: jax's cache key — a directory that moves never hits)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    Called from runtime init (before the first device-plane compile);
-    idempotent; returns the cache dir when wired, None when the cvar
-    is unset or jax is unavailable. Failures are non-fatal — a broken
-    cache dir must never take down init."""
+
+def wire_compile_cache() -> str:
+    """Place jax's persistent compilation cache and hook hit/miss
+    accounting; returns the directory. Called from runtime init, before
+    anything compiles, so every rank of every job shares it.
+
+    Placement: ``JAX_COMPILATION_CACHE_DIR`` when the environment sets
+    it — jax reads that itself, so nothing is configured in code and
+    whoever runs the job owns the location; else the
+    ``compile_cache_dir`` cvar; else :data:`DEFAULT_CACHE_DIR`. A
+    directory that cannot be created raises ``MPIError``.
+
+    Never imports jax (2 s a rank that host-only jobs must not pay):
+    while jax is not loaded the placement is left in the environment
+    for its import to read, and the accounting waits for the next
+    call — the accelerator component repeats it on its lazy init."""
     global _CACHE_WIRED
-    d = str(_cache_dir_var.get() or "").strip()
-    if not d:
-        return None
-    if _CACHE_WIRED:
-        return d
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    d = (from_env or str(_cache_dir_var.get() or "").strip()
+         or DEFAULT_CACHE_DIR)
     try:
-        import os
-
-        import jax
-        from jax import monitoring as _jmon
-
         os.makedirs(d, exist_ok=True)
+    except OSError as exc:
+        raise errors.MPIError(
+            errors.ERR_OTHER,
+            f"persistent compile cache: cannot create {d!r} ({exc}); "
+            "set JAX_COMPILATION_CACHE_DIR or --mca compile_cache_dir "
+            "to a writable directory") from exc
+    jax = sys.modules.get("jax")
+    if jax is None:
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = d
+        return d
+    if not from_env:
         jax.config.update("jax_compilation_cache_dir", d)
+    if not _CACHE_WIRED:
         min_secs = float(_cache_min_var.get())
         if min_secs >= 0:
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", min_secs)
-        _jmon.register_event_listener(_on_cache_event)
+        jax.monitoring.register_event_listener(_on_cache_event)
         _CACHE_WIRED = True
-        return d
-    except Exception:
-        return None
+    return d

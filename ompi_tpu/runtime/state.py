@@ -62,12 +62,18 @@ def init_instance() -> None:
         try:
             if _prof.requested():
                 _prof.enable(rank=rte.rank)
-            cache_dir = _prof.wire_compile_cache()
-            if cache_dir:
-                _out.verbose(2, "persistent compile cache: %s",
-                             cache_dir)
         except Exception as exc:  # profiling must never sink init
             _out.verbose(0, "prof enable failed: %r", exc)
+        # one directory for every rank of every job started from this
+        # checkout; a directory that cannot be made IS an init error.
+        # A device-plane rank loads jax a few lines down anyway: load
+        # it first, so the cache accounting sees its first compile.
+        from ompi_tpu.runtime import device_plane
+
+        if device_plane.requested():
+            import jax  # noqa: F401
+        _out.verbose(2, "persistent compile cache: %s",
+                     _prof.wire_compile_cache())
 
         # accelerator selection happens during core init in the reference
         # (opal/runtime/opal_init.c:202-206)
@@ -88,9 +94,8 @@ def init_instance() -> None:
 
         # multi-controller device plane (opt-in; collective over the
         # world, must precede comm construction so coll/xla can qualify
-        # during any comm's coll table selection)
-        from ompi_tpu.runtime import device_plane
-
+        # during any comm's coll table selection). Raises on every
+        # rank when the requested plane did not come up.
         if device_plane.requested():
             device_plane.init_plane()
 

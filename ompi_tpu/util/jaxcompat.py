@@ -1,70 +1,65 @@
-"""jax version compatibility shims.
+"""The single import site for the jax surfaces that have moved between
+releases.
 
-The device plane targets the modern ``jax.shard_map`` surface
-(``check_vma=`` keyword, top-level export, jax >= 0.6). Older jax
-releases ship the same transform as ``jax.experimental.shard_map``
-with the varying-axes check spelled ``check_rep=``. Everything in
-ompi_tpu goes through :func:`shard_map` below so the rest of the tree
-can use the modern spelling unconditionally.
+The tree is built and checked against ONE installation — jax 0.9.0 /
+jaxlib 0.9.0 (the sandbox, the chip machine and the CI lane all carry
+it) — so nothing here branches on a version: each helper names where
+that release keeps the surface. When the installation moves, this is
+the only module to edit (ROADMAP ground rule: version drift lives
+here, never at call sites).
 """
 
 from __future__ import annotations
 
 
 def axis_size(axis) -> int:
-    """Static size of a named mesh axis inside an SPMD region.
-
-    ``jax.lax.axis_size`` is a late addition; on older jax the psum of
-    a Python literal constant-folds at trace time to the axis size, so
-    the result is a plain int in both cases (safe in shape arithmetic).
-    """
+    """Static size of a named mesh axis inside an SPMD region (a plain
+    int, safe in shape arithmetic)."""
     from jax import lax
 
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+    return lax.axis_size(axis)
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs, **kw):
-    """``jax.shard_map`` with fallback to the pre-0.6 experimental API.
-
-    Accepts the modern ``check_vma=`` keyword and translates it to
-    ``check_rep=`` when only the experimental entry point exists.
-    """
+    """``jax.shard_map`` (varying-axes check spelled ``check_vma=``)."""
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def pallas():
-    """The ``jax.experimental.pallas`` module across jax versions
-    (newer releases promote it to ``jax.pallas``)."""
-    try:
-        import jax.pallas as pl  # promoted surface, jax >= 0.8
-    except ImportError:
-        from jax.experimental import pallas as pl
+    """The Pallas core module (``pl``)."""
+    from jax.experimental import pallas as pl
+
     return pl
 
 
 def pallas_tpu():
     """The Pallas TPU extension module (``pltpu``: remote-DMA copies,
-    DMA/barrier semaphores, TPU memory spaces) across jax versions."""
-    try:
-        import jax.pallas.tpu as pltpu  # promoted surface
-    except ImportError:
-        from jax.experimental.pallas import tpu as pltpu
+    DMA/barrier semaphores, TPU memory spaces)."""
+    from jax.experimental.pallas import tpu as pltpu
+
     return pltpu
 
 
+def pallas_compiler_params(collective_id: int):
+    """Mosaic compiler params for a kernel that DMAs to other chips:
+    the barrier semaphore needs a ``collective_id``, and the remote
+    copies are side effects the compiler must not eliminate."""
+    return pallas_tpu().CompilerParams(
+        has_side_effects=True, collective_id=collective_id)
+
+
+def pallas_device_id_type():
+    """Mesh-coordinate addressing for ``make_async_remote_copy`` /
+    ``semaphore_signal`` (``device_id`` is a tuple of ``shard_map``
+    axis indices)."""
+    return pallas_tpu().DeviceIdType.MESH
+
+
 #: wire-format name -> (ml_dtypes attribute, bytes/element) for the
-#: compressed-DCN transports. bf16 ships with every ml_dtypes (a jax
-#: hard dep); the fp8 pair additionally needs this jax to cast through
-#: it — probed once below, so call sites never version-check inline.
+#: compressed-DCN transports.
 _WIRE_SPECS = (
     ("bf16", "bfloat16", 2),
     ("fp8_e4m3", "float8_e4m3fn", 1),
@@ -74,42 +69,22 @@ _WIRE_SPECS = (
 _wire_cache: dict = {}
 
 
-def _fp8_cast_ok(dt) -> bool:
-    """Can this jax round-trip f32 -> dt -> f32? False on old releases
-    whose XLA lacks the fp8 convert lowering — the degrade signal."""
-    try:
-        import jax.numpy as jnp
-
-        x = jnp.asarray([1.0], jnp.float32).astype(dt)
-        return bool(x.astype(jnp.float32)[0] == 1.0)
-    except Exception:  # noqa: BLE001 — any failure means "unsupported"
-        return False
-
-
 def _wire_table() -> dict:
-    """name -> numpy dtype of every wire format THIS stack supports,
-    built once (ml_dtypes lookup + the jax cast probe)."""
+    """name -> numpy dtype of every wire format, built once."""
     table = _wire_cache.get("table")
     if table is None:
         import ml_dtypes
         import numpy as np
 
-        table = {}
-        for name, attr, _isz in _WIRE_SPECS:
-            dt = getattr(ml_dtypes, attr, None)
-            if dt is None:
-                continue
-            if name.startswith("fp8") and not _fp8_cast_ok(dt):
-                continue
-            table[name] = np.dtype(dt)
+        table = {name: np.dtype(getattr(ml_dtypes, attr))
+                 for name, attr, _isz in _WIRE_SPECS}
         _wire_cache["table"] = table
     return table
 
 
 def wire_dtype(name: str):
     """numpy dtype for a compressed-DCN wire-format name ('bf16',
-    'fp8_e4m3', 'fp8_e5m2'), or None when this jax/ml_dtypes stack
-    cannot represent it."""
+    'fp8_e4m3', 'fp8_e5m2'), or None for any other name."""
     return _wire_table().get(name)
 
 
@@ -131,13 +106,6 @@ def wire_finfo_max(name: str) -> float:
     return float(ml_dtypes.finfo(_wire_table()[name]).max)
 
 
-def wire_degrade(name: str) -> str:
-    """The requested wire format when this stack supports it, else
-    'bf16' — old jax without fp8 lowerings degrades instead of raising
-    at the call site (the ROADMAP no-inline-version-checks rule)."""
-    return name if name in _wire_table() else "bf16"
-
-
 def np_dtype(name: str):
     """``np.dtype`` over the ml_dtypes-extended namespace: 'bfloat16'
     and the float8 spellings resolve like builtins (importing
@@ -148,24 +116,12 @@ def np_dtype(name: str):
     return np.dtype(name)
 
 
-def pallas_device_id_type(pltpu):
-    """The mesh-logical ``DeviceIdType`` member for
-    ``make_async_remote_copy``/``semaphore_signal`` across jax
-    versions: newer releases spell the mesh-coordinate addressing mode
-    ``MESH``, older ones only have ``LOGICAL`` (same semantics inside
-    ``shard_map``). osc/pallas_kernels and any future DMA kernel go
-    through here instead of version-checking at the call site."""
-    dt = pltpu.DeviceIdType
-    return getattr(dt, "MESH", None) or dt.LOGICAL
-
-
 def pallas_remote_dma_ok() -> bool:
-    """Whether this jax build can *execute* ``make_async_remote_copy``
-    kernels on the current default backend. True only on real TPU —
-    the CPU interpreter in every jax release to date cannot emulate
-    inter-device DMA, which is why :mod:`ompi_tpu.coll.pallas_kernels`
-    gates its transport (monolithic DMA kernel on TPU, per-step
-    interpret kernels + ``ppermute`` hops elsewhere)."""
+    """Whether ``make_async_remote_copy`` kernels can run on the
+    current default backend: only a real TPU moves data between chips
+    by DMA. Elsewhere :mod:`ompi_tpu.coll.pallas_kernels` and
+    :mod:`ompi_tpu.osc.pallas` run the same schedule as interpret-mode
+    compute kernels with ``ppermute`` hops."""
     import jax
 
     try:

@@ -290,7 +290,7 @@ class ErrorFeedback:
                 errors.ERR_ARG,
                 f"error_feedback={wire!r}: expected 'bf16', "
                 "'fp8_e4m3' or 'fp8_e5m2'")
-        self.wire = _jc.wire_degrade(wire)
+        self.wire = wire
         self.plan: Optional[ZeroPlan] = None
         self.residuals: List[object] = []
         self._active: Tuple[bool, ...] = ()

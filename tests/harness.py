@@ -152,9 +152,9 @@ def _must_isolate(body: str, mca: Dict[str, str]) -> bool:
 
 _PRELUDE = """
 # NOTE: no jax import or platform pinning here — the launcher already
-# sets JAX_PLATFORMS=cpu and skips the device plugin for rank
-# processes (launcher.build_env), and importing jax costs ~2s per rank
-# per test; bodies that need jax import it themselves.
+# pins rank processes to JAX_PLATFORMS=cpu (launcher.build_env; the
+# device_plane_platform cvar defaults to cpu), and importing jax costs
+# ~2s per rank per test; bodies that need jax import it themselves.
 import numpy as np
 from ompi_tpu import mpi
 comm = mpi.Init()

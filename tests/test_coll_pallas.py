@@ -279,3 +279,62 @@ def test_off_by_default():
     assert comm.coll.providers["allreduce_dev"] == "xla"
     assert "fused_rs_update_dev" not in comm.coll.fns
     """, 2, mca={"device_plane": "on"})
+
+
+def test_tpu_path_sends_what_vmem_cannot_hold_one_level_down():
+    """The DMA kernels keep their operands whole in VMEM. With the TPU
+    transport forced and a 4 KiB bound, every entry point that reaches
+    one must decline BEFORE it builds a kernel (none compiles on CPU),
+    count the fallthrough and still return the right answer."""
+    run_ranks("""
+    import jax.numpy as jnp
+    from ompi_tpu import osc
+    from ompi_tpu.coll import pallas as cp, pallas_kernels as K
+    from ompi_tpu.coll import xla as cx
+    from ompi_tpu.core import pvar
+    from ompi_tpu.osc.pallas import PallasWindow
+    from ompi_tpu.zero.optimizer import ZeroOptimizer
+    # a ring reduce_scatter on four chips holds 2.25x its payload
+    assert K.ring_vmem_bytes(4, 4 << 20) == 9 << 20
+    assert cp.dma_fits(4096) and not cp.dma_fits(4097)
+    s = pvar.session()
+    x = jnp.arange(1 << 14, dtype=jnp.float32) + rank
+    np.testing.assert_array_equal(
+        np.asarray(comm.Allreduce(x, deterministic="ring")),
+        np.asarray(cx.allreduce_dev(comm, x, deterministic="ring")))
+    assert s.read("pallas_fallthrough") == 1
+    params = {"w": jnp.ones((64, 64), jnp.float32)}
+    g = {"w": jnp.full((64, 64), rank + 1.0, jnp.float32)}
+    outs = []
+    for fused in (True, False):
+        opt = ZeroOptimizer(comm, params, lr=0.5, momentum=0.5,
+                            fused=fused)
+        outs.append(np.asarray(opt.step(g)["w"]))
+        opt.free()
+    np.testing.assert_array_equal(*outs)
+    assert s.read("pallas_fallthrough") == 2
+    xm = jnp.ones((32, 64), jnp.float32) + rank
+    w = jnp.ones((64, 32), jnp.float32)
+    got = np.asarray(comm.coll.allgather_matmul_dev(comm, xm, w))
+    full = np.concatenate([np.ones((32, 64), np.float32) + r
+                           for r in range(size)])
+    np.testing.assert_array_equal(got, full @ np.asarray(w))
+    assert s.read("pallas_fallthrough") == 3
+    assert s.read("pallas_launches") == 0
+    # an osc fence round past the bound rides XLA's collective permute
+    base = np.zeros(4096, np.float32)
+    put = np.arange(2000, dtype=np.float32) + rank
+    wd = osc.win_create(comm, jnp.asarray(base), disp_unit=4)
+    assert isinstance(wd, PallasWindow) and not wd._interp
+    wd.Fence()
+    wd.Put(jnp.asarray(put), (rank + 1) % size, disp=7)
+    wd.Fence()
+    want = base.copy()
+    want[7:2007] = np.arange(2000, dtype=np.float32) + (rank - 1) % size
+    np.testing.assert_array_equal(np.asarray(wd.array), want)
+    assert s.read("osc_pallas_fallthrough") == 1
+    wd.Free()
+    """, 2, mca={**MCA, "osc_pallas": "on",
+                "coll_pallas_interpret": "off",
+                "osc_pallas_interpret": "off",
+                "coll_pallas_dma_max_bytes": "4096"})

@@ -200,43 +200,7 @@ def test_ctx_compile_pvars_miss_then_hit(no_prof):
     assert s2.read("prof_compile_misses") == 0
 
 
-def test_compile_cache_wiring_and_accounting(tmp_path, no_prof):
-    import os
-
-    import jax
-    from jax import monitoring as jmon
-
-    from ompi_tpu import prof as prof_pkg
-
-    d = str(tmp_path / "xla_cache")
-    prof_pkg._cache_dir_var.set(d)
-    try:
-        assert prof_pkg.wire_compile_cache() == d
-        assert os.path.isdir(d)
-        assert jax.config.jax_compilation_cache_dir == d
-        assert prof_pkg.wire_compile_cache() == d   # idempotent
-        s = pvar.session()
-        # jax fires compile_requests_use_cache first, then (only on a
-        # hit) cache_hits — the listener reclassifies
-        jmon.record_event(
-            "/jax/compilation_cache/compile_requests_use_cache")
-        assert s.read("prof_compile_cache_misses") == 1
-        assert s.read("prof_compile_cache_hits") == 0
-        jmon.record_event(
-            "/jax/compilation_cache/compile_requests_use_cache")
-        jmon.record_event("/jax/compilation_cache/cache_hits")
-        assert s.read("prof_compile_cache_hits") == 1
-        assert s.read("prof_compile_cache_misses") == 1
-    finally:
-        prof_pkg._cache_dir_var.set("")
-        jax.config.update("jax_compilation_cache_dir", None)
-
-
-def test_wire_compile_cache_unset_is_none(no_prof):
-    from ompi_tpu import prof as prof_pkg
-
-    assert str(prof_pkg._cache_dir_var.get() or "") == ""
-    assert prof_pkg.wire_compile_cache() is None
+# compile-cache placement and hit/miss accounting: tests/test_bringup.py
 
 
 # -- watchdog phase attribution ------------------------------------------
