@@ -81,7 +81,7 @@ CATALOG: Dict[str, str] = {
         "shmem/, part/, ingest/, elastic/) — raise "
         "errors.MPIError(ERR_*) so "
         "the comm errhandler sees it (a bare ValueError bypasses "
-        "_with_errhandler dispatch)",
+        "mpi._api_entry's dispatch)",
     "unregistered-pvar":
         "pvar recorded under a literal name missing from "
         "pvar.WELL_KNOWN — tools/info and the OpenMetrics sampler "

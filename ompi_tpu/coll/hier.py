@@ -403,14 +403,11 @@ def _launch(launcher, op: str, plan: _Plan, comm=None, nbytes=0,
         launcher = obs.timed("hier", op, "hier", comm, nbytes, dtype,
                              launcher,
                              mesh=(plan.n_dcn, plan.n_ici))
-    rec = _trace.RECORDER
-    if rec is None:
+    if not _trace.active():
         return launcher()
-    t0 = _trace.now()
-    out = launcher()
-    rec.record("launch", "coll_hier", t0, _trace.now(),
-               {"op": op, "grid": f"{plan.n_dcn}x{plan.n_ici}"})
-    return out
+    with _trace.span("launch", "coll_hier", op=op,
+                     grid=f"{plan.n_dcn}x{plan.n_ici}"):
+        return launcher()
 
 
 def _itemsize(dtype: str) -> int:

@@ -283,14 +283,10 @@ def _launch(launcher, op: str, algo: str, comm=None, buf=None,
             int(getattr(buf, "nbytes", 0) if nbytes is None
                 else nbytes),
             str(getattr(buf, "dtype", "")), launcher)
-    rec = _trace.RECORDER
-    if rec is None:
+    if not _trace.active():
         return launcher()
-    t0 = _trace.now()
-    out = launcher()
-    rec.record("launch", "coll_pallas", t0, _trace.now(),
-               {"op": op, "algorithm": algo})
-    return out
+    with _trace.span("launch", "coll_pallas", op=op, algorithm=algo):
+        return launcher()
 
 
 def _account(kind: str, comm, sendbuf, algo: str) -> None:

@@ -29,6 +29,8 @@ the same slot signature, one priority level down.
 
 from __future__ import annotations
 
+import functools
+import threading
 from typing import Optional
 
 import numpy as np
@@ -166,6 +168,24 @@ def _observed(launcher, op: str, comm, nbytes, dtype: str,
                      int(nbytes), dtype, launcher)
 
 
+#: the name ``_Ctx.compiled`` chose for the program being built, for
+#: ``_Ctx.smap`` to give to the jitted body (per thread: builds nest
+#: under whichever thread misses the cache)
+_naming = threading.local()
+
+
+def program_name(key) -> str:
+    """The stable name of the program (or plan) behind a cache key:
+    ``ompi_<kind>``. Keys are ``(kind, ...)`` or ``_key(x, kind,
+    ...)`` = ``(shape, dtype, kind, ...)``. A fixed operand order the
+    key carries ('linear', 'ring': a deterministic mode, a Pallas
+    algorithm) is part of the name — ``ompi_allreduce_linear`` is
+    another program than ``ompi_allreduce``."""
+    kind = key[0] if isinstance(key[0], str) else key[2]
+    order = next((e for e in key[1:] if e in ("linear", "ring")), None)
+    return f"ompi_{kind}_{order}" if order else f"ompi_{kind}"
+
+
 class _Ctx:
     """Per-communicator compiled-collective state (the analog of the
     reference's per-comm coll module data)."""
@@ -202,6 +222,9 @@ class _Ctx:
         self.in_sharding = NamedSharding(self.mesh, P(AXIS))
         self.fns = {}  # (kind, shape, dtype, ...) -> compiled callable
         self.plans = {}  # fused-allreduce bucket plans per signature
+        self.programs = {}  # compiled callable -> its stable name
+        self._cold = set()  # built, never launched: the first launch
+        # is where jax compiles the program or loads it from the cache
         # hierarchical ICI x DCN mesh (rank-major rows = slices) when
         # the comm spans slices and ranks are slice-contiguous
         self.mesh2d = None
@@ -247,12 +270,22 @@ class _Ctx:
         Fast path: device_put is skipped when the buffer already
         lives on ``my`` — it runs on every collective call, and for
         resident arrays (the steady-state training case) it only adds
-        a dispatch round."""
+        a dispatch round. The ``x[None]`` below is a program of its
+        own on the device (``jit_broadcast_in_dim``, a full copy of
+        the buffer): span ``to_global`` is what a caller pays for the
+        global view."""
+        if not _trace.active():
+            return self._to_global(x, sharding, _trace.OFF)
+        with _trace.span("to_global", "coll_xla") as sp:
+            return self._to_global(x, sharding, sp)
+
+    def _to_global(self, x, sharding, sp):
         jax = self.jax
         try:
             resident = x.device == self.my
         except (AttributeError, ValueError):
             resident = False  # numpy / multi-shard input: stage it
+        sp.set(resident=int(resident))
         if resident:
             pvar.record("coll_xla_device_put_skipped")
         elif _prof.PROFILER is None:
@@ -269,30 +302,34 @@ class _Ctx:
 
     def my_shard(self, out):
         """This rank's shard of an AXIS-sharded result."""
-        return out.addressable_data(0)
+        if not _trace.active():
+            return out.addressable_data(0)
+        with _trace.span("my_shard", "coll_xla"):
+            return out.addressable_data(0)
 
     def compiled(self, key, build):
         """Get-or-build a compiled program. Hit/miss/size pvars make
         cache churn (shape-varying workloads recompiling every call)
         visible via MPI_T instead of only via wall time. Bounded LRU
         when cvar coll_xla_cache_max > 0 (insertion order IS recency:
-        hits reinsert)."""
+        hits reinsert).
+
+        ``build()`` returns a lazy ``jax.jit`` object: nothing compiles
+        here. The program is named here (``program_name(key)``, which
+        ``smap`` gives the jitted body) and marked cold; its first
+        ``launch`` is timed as the compile."""
         fn = self.fns.get(key)
-        rec = _trace.RECORDER
         if fn is None:
-            # cold path: always timed — prof_compile_ns is the
-            # numerator of the attribution story and two clock reads
-            # are noise against an XLA compile
             pvar.record("coll_xla_cache_misses")
-            t0 = _trace.now()
-            fn = self.fns[key] = build()
-            t1 = _trace.now()
             if _prof.PROFILER is not None:
                 pvar.record("prof_compile_misses")
-                pvar.record("prof_compile_ns", t1 - t0)
-            if rec is not None:
-                rec.record("compile", "coll_xla", t0, t1,
-                           {"cache": "miss", "key": repr(key)[:160]})
+            name = _naming.program = program_name(key)
+            try:
+                fn = self.fns[key] = build()
+            finally:
+                _naming.program = None
+            self.programs[fn] = name
+            self._cold.add(fn)
             pvar.record_hwm("coll_xla_fns_size", len(self.fns))
             self._evict(self.fns)
         else:
@@ -300,27 +337,21 @@ class _Ctx:
             if _prof.PROFILER is not None:
                 pvar.record("prof_compile_hits")
             self.fns[key] = self.fns.pop(key)  # LRU touch
-            if rec is not None:
-                rec.instant("cache_hit", "coll_xla",
-                            {"key": repr(key)[:160]})
         return fn
 
     def plan(self, key, build):
         """Get-or-build a fused-bucket plan (same contract as
         ``compiled`` — steady-state steps must pay zero re-planning)."""
         p = self.plans.get(key)
-        rec = _trace.RECORDER
         if p is None:
             pvar.record("coll_xla_plan_cache_misses")
             t0 = _trace.now()
-            p = self.plans[key] = build()
-            t1 = _trace.now()
+            with _trace.span("plan_build", "coll_xla",
+                             plan=program_name(key)):
+                p = self.plans[key] = build()
             if _prof.PROFILER is not None:
                 pvar.record("prof_compile_misses")
-                pvar.record("prof_compile_ns", t1 - t0)
-            if rec is not None:
-                rec.record("plan_build", "coll_xla", t0, t1,
-                           {"cache": "miss", "key": repr(key)[:160]})
+                pvar.record("prof_compile_ns", _trace.now() - t0)
             pvar.record_hwm("coll_xla_plans_size", len(self.plans))
             self._evict(self.plans)
         else:
@@ -328,33 +359,63 @@ class _Ctx:
             if _prof.PROFILER is not None:
                 pvar.record("prof_compile_hits")
             self.plans[key] = self.plans.pop(key)  # LRU touch
-            if rec is not None:
-                rec.instant("plan_cache_hit", "coll_xla",
-                            {"key": repr(key)[:160]})
         return p
 
-    @staticmethod
-    def _evict(cache) -> None:
+    def _evict(self, cache) -> None:
         mx = int(_cache_max_var.get())
         while mx > 0 and len(cache) > mx:
-            cache.pop(next(iter(cache)))  # oldest-touched first
+            old = cache.pop(next(iter(cache)))  # oldest-touched first
+            if cache is self.fns:
+                self.programs.pop(old, None)
+                self._cold.discard(old)
             pvar.record("coll_xla_cache_evictions")
 
     def launch(self, fn, *args):
         """Dispatch one compiled collective program. Every device-path
         dispatch funnels through here so the launch counter is exact —
-        the fusion regression tests assert on it. Tracing disabled
-        costs exactly one extra branch here (no span construction);
-        enabled, the span covers DISPATCH time only — PJRT execution
-        is asynchronous."""
+        the fusion regression tests assert on it. With no sink up a
+        warm launch costs the cold-set lookup and the ``active()``
+        guard; with one, span ``launch`` covers DISPATCH time only —
+        PJRT execution is asynchronous.
+
+        A program's FIRST launch is where jax compiles it or loads it
+        from the persistent cache: it is always timed (two clock reads
+        against a compile) into ``coll_xla_cold_launch_ns`` /
+        ``coll_xla_cold_launches`` and ``prof_compile_ns``, and is
+        span ``compile`` around a ``launch`` with ``cold=1``."""
         pvar.record("coll_xla_launches")
-        rec = _trace.RECORDER
-        if rec is None:
+        if fn in self._cold:
+            return self._launch_cold(fn, args)
+        if not _trace.active():
             return fn(*args)
+        with _trace.span("launch", "coll_xla",
+                         program=self.programs.get(fn, "?"),
+                         nbytes=self._nbytes(args), cold=0):
+            return fn(*args)
+
+    def _launch_cold(self, fn, args):
+        self._cold.discard(fn)
+        name = self.programs.get(fn, "?")
         t0 = _trace.now()
-        out = fn(*args)
-        rec.record("launch", "coll_xla", t0, _trace.now())
+        with _trace.span("compile", "coll_xla", program=name), \
+                _trace.span("launch", "coll_xla", program=name,
+                            nbytes=self._nbytes(args), cold=1):
+            out = fn(*args)
+        dt = _trace.now() - t0
+        pvar.record("coll_xla_cold_launches")
+        pvar.record("coll_xla_cold_launch_ns", dt)
+        if _prof.PROFILER is not None:
+            pvar.record("prof_compile_ns", dt)
         return out
+
+    def _nbytes(self, args) -> int:
+        """Operand bytes of one rank (the global views' bytes / n;
+        a fused bucket passes its leaves as one tuple)."""
+        total = 0
+        for a in args:
+            for x in (a if isinstance(a, (tuple, list)) else (a,)):
+                total += getattr(x, "nbytes", 0)
+        return total // self.n
 
     def release(self) -> None:
         """Drop the compiled-program and plan caches (comm destructor
@@ -362,18 +423,32 @@ class _Ctx:
         invisibly after the comm is freed)."""
         self.fns.clear()
         self.plans.clear()
+        self.programs.clear()
+        self._cold.clear()
 
     def smap(self, body, out_varying: bool, mesh=None, spec=None):
         """jit(shard_map(body)) over the comm mesh (or the 2-level
         ICI x DCN mesh when passed). Body sees the local (1, *shape)
-        block; out_varying selects the sharded vs replicated spec."""
+        block; out_varying selects the sharded vs replicated spec.
+
+        The program gets the stable name ``compiled`` chose for its
+        key (module ``jit_ompi_<kind>`` in a device trace, instead of
+        ``jit__lambda_``), and its collective sits under
+        ``jax.named_scope(<kind>)``: metadata, the HLO is the same."""
         from ompi_tpu.util import jaxcompat
 
         jax, P = self.jax, self.P
         spec = spec if spec is not None else P(AXIS)
         out_spec = spec if out_varying else P()
+        name = getattr(_naming, "program", None) or "ompi_program"
+
+        def program(*a):
+            with jax.named_scope(name[len("ompi_"):]):
+                return body(*a)
+
+        program.__name__ = program.__qualname__ = name
         return jax.jit(jaxcompat.shard_map(
-            body, mesh=mesh if mesh is not None else self.mesh,
+            program, mesh=mesh if mesh is not None else self.mesh,
             in_specs=spec, out_specs=out_spec, check_vma=False))
 
     def to_global_hier(self, x):
@@ -412,6 +487,23 @@ def _op_ok(op) -> bool:
 # slots — signatures match coll/accelerator's *_dev (the fallback)
 
 
+def _slot(op: str):
+    """Span ``ompi:coll_xla.<op>`` around a blocking slot: everything
+    the slot does on the host (op check, monitors, context, key and
+    cache lookup, observer, flight) is its self time; ``to_global``,
+    ``launch`` and ``my_shard`` are its children."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def slot(comm, buf, *args, **kwargs):
+            if not _trace.active():
+                return fn(comm, buf, *args, **kwargs)
+            with _trace.span(op, "coll_xla",
+                             nbytes=getattr(buf, "nbytes", 0)):
+                return fn(comm, buf, *args, **kwargs)
+        return slot
+    return deco
+
+
 def _allreduce_prep(comm, sendbuf, op=op_mod.SUM,
                     deterministic: Optional[str] = None):
     """Plan + compile + bind the allreduce NOW; returns a zero-arg
@@ -443,6 +535,7 @@ def _allreduce_prep(comm, sendbuf, op=op_mod.SUM,
     return lambda: ctx.my_shard(ctx.launch(fn, g))
 
 
+@_slot("allreduce")
 def allreduce_dev(comm, sendbuf, op=op_mod.SUM,
                   deterministic: Optional[str] = None):
     if not _op_ok(op):
@@ -652,6 +745,7 @@ def _bcast_prep(comm, buf, root: int = 0):
     return lambda: ctx.my_shard(ctx.launch(fn, g))
 
 
+@_slot("bcast")
 def bcast_dev(comm, buf, root: int = 0):
     pvar.record("coll_xla_device")
     if comm.size == 1:
@@ -694,6 +788,7 @@ def _allgather_prep(comm, sendbuf):
     return lambda: ctx.my_shard(ctx.launch(fn, g))
 
 
+@_slot("allgather")
 def allgather_dev(comm, sendbuf):
     pvar.record("coll_xla_device")
     if comm.size == 1:
@@ -759,6 +854,7 @@ def _alltoall_prep(comm, sendbuf):
     return lambda: ctx.my_shard(ctx.launch(fn, g))
 
 
+@_slot("alltoall")
 def alltoall_dev(comm, sendbuf):
     pvar.record("coll_xla_device")
     if comm.size == 1:
@@ -805,6 +901,7 @@ def _reduce_scatter_block_prep(comm, sendbuf, op=op_mod.SUM,
     return lambda: ctx.my_shard(ctx.launch(fn, g))
 
 
+@_slot("reduce_scatter_block")
 def reduce_scatter_block_dev(comm, sendbuf, op=op_mod.SUM,
                              deterministic: Optional[str] = None):
     if not _op_ok(op):
@@ -1157,6 +1254,7 @@ def alltoallv_dev(comm, sendbuf, scounts, rcounts, max_count=None, *,
         [cells[r, :rcounts[r]] for r in range(comm.size)], axis=0)
 
 
+@_slot("reduce_scatter")
 def reduce_scatter_dev(comm, sendbuf, counts, op=op_mod.SUM,
                        deterministic: Optional[str] = None):
     """Ragged MPI_Reduce_scatter on device: full on-device reduction
@@ -1168,7 +1266,7 @@ def reduce_scatter_dev(comm, sendbuf, counts, op=op_mod.SUM,
     counts = [int(c) for c in counts]
     # erroneous calls raise MPIError so the comm's errhandler sees
     # them (the MPI-4 convention part/host.py documents — a bare
-    # ValueError would bypass _with_errhandler dispatch)
+    # ValueError would bypass the API entry's errhandler dispatch)
     if len(counts) != comm.size:
         raise errors.MPIError(
             errors.ERR_COUNT,

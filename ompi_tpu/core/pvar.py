@@ -34,6 +34,16 @@ WELL_KNOWN = (
     "coll_xla_fused_bytes", "coll_xla_plan_cache_hits",
     "coll_xla_plan_cache_misses", "coll_xla_device_put_skipped",
     "coll_xla_cache_evictions",
+    # a program's FIRST launch, where jax compiles it or loads it from
+    # the persistent cache (always timed: once per cache key)
+    "coll_xla_cold_launches", "coll_xla_cold_launch_ns",
+    # the phases of mpi.Init(), once per job (runtime/state.py,
+    # runtime/device_plane.py; "import" also holds the import of
+    # ompi_tpu.mpi itself): they end before any profiler session can
+    # exist, so the always-on counters are their only record
+    "init_import_ns", "init_rte_ns", "init_accelerator_ns",
+    "init_distributed_ns", "init_client_ns", "init_fence_ns",
+    "init_pml_ns", "init_world_ns",
     # part/ (MPI-4 partitioned communication): host p2p epoch starts +
     # Pready/Parrived traffic; device Pallreduce bucket flushes, with
     # overlap_flushes counting buckets dispatched BEFORE the cycle's

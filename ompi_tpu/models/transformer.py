@@ -19,6 +19,14 @@ demonstration that the collective library carries real workloads:
 All axes are optional (None = that strategy off), so the same code runs
 single-device (``entry()``) and on any mesh factorization. bfloat16
 activations by default — MXU-native.
+
+Names on the device (``jax.named_scope``: metadata, the HLO is the
+same): the jitted step is module ``jit_ompi_train_step``; its ops carry
+``embed``, ``layer_<i>/{ln, attn_proj, attn_core, mlp}``,
+``head_loss`` (final LN, tied head, loss), ``grad_sync`` and
+``sgd_update`` in their op path, under the ``jvp(...)`` /
+``transpose(jvp(...))`` jax adds for forward and backward — so a trace
+reader finds a model part by name, not by XLA's fusion numbering.
 """
 
 from __future__ import annotations
@@ -185,9 +193,10 @@ def grad_extra_axes(cfg: Config, ax: Axes):
 
 
 def _ln(x, g, b):
-    mu = x.mean(-1, keepdims=True)
-    var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return (x - mu) * lax.rsqrt(var + 1e-5) * g + b
+    with jax.named_scope("ln"):
+        mu = x.mean(-1, keepdims=True)
+        var = ((x - mu) ** 2).mean(-1, keepdims=True)
+        return (x - mu) * lax.rsqrt(var + 1e-5) * g + b
 
 
 def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool):
@@ -199,61 +208,66 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool):
     b, t = h.shape[0], h.shape[1]
     x = _ln(h.astype(jnp.float32), lp["ln1"]["g"],
             lp["ln1"]["b"]).astype(dt)
-    if ax.tp:
-        x = region_enter(x, ax.tp)
-    q = x @ lp["wq"].astype(dt)   # [B,T,Hl*Dh] (tp-sharded cols)
-    k = x @ lp["wk"].astype(dt)
-    v = x @ lp["wv"].astype(dt)
-    hl = q.shape[-1] // cfg.head_dim  # local heads under tp
-    q = q.reshape(b, t, hl, cfg.head_dim)
-    k = k.reshape(b, t, hl, cfg.head_dim)
-    v = v.reshape(b, t, hl, cfg.head_dim)
-    if ax.sp:
-        if cfg.sp_schedule == "ulysses":
-            from ompi_tpu.ops.ulysses import ulysses_attention
+    with jax.named_scope("attn_proj"):
+        if ax.tp:
+            x = region_enter(x, ax.tp)
+        q = x @ lp["wq"].astype(dt)   # [B,T,Hl*Dh] (tp-sharded cols)
+        k = x @ lp["wk"].astype(dt)
+        v = x @ lp["wv"].astype(dt)
+        hl = q.shape[-1] // cfg.head_dim  # local heads under tp
+        q = q.reshape(b, t, hl, cfg.head_dim)
+        k = k.reshape(b, t, hl, cfg.head_dim)
+        v = v.reshape(b, t, hl, cfg.head_dim)
+    with jax.named_scope("attn_core"):  # scores, softmax, AV
+        if ax.sp:
+            if cfg.sp_schedule == "ulysses":
+                from ompi_tpu.ops.ulysses import ulysses_attention
 
-            o = ulysses_attention(q, k, v, ax.sp, causal=True)
-        elif cfg.sp_schedule == "ring":
-            o = ring_attention(q, k, v, ax.sp, causal=True)
+                o = ulysses_attention(q, k, v, ax.sp, causal=True)
+            elif cfg.sp_schedule == "ring":
+                o = ring_attention(q, k, v, ax.sp, causal=True)
+            else:
+                raise ValueError(
+                    f"sp_schedule={cfg.sp_schedule!r}: expected 'ring' "
+                    "or 'ulysses'")
         else:
-            raise ValueError(
-                f"sp_schedule={cfg.sp_schedule!r}: expected 'ring' "
-                "or 'ulysses'")
-    else:
-        # reference mha, not the pallas flash kernel: measured on the
-        # v5e at T=1024 the kernel is ~4% SLOWER (XLA's fused softmax
-        # wins while the T x T score tensor is small); att.mha_auto
-        # remains available for long-context single-device use where
-        # the score materialization dominates
-        o = att.mha(q, k, v, causal=True)
-    o = o.reshape(b, t, hl * cfg.head_dim)
-    o = o @ lp["wo"].astype(dt)   # row parallel: partial sums
-    if ax.tp:
-        o = region_exit(o, ax.tp)
-    h = h + o
+            # reference mha, not the pallas flash kernel: measured on
+            # the v5e at T=1024 the kernel is ~4% SLOWER (XLA's fused
+            # softmax wins while the T x T score tensor is small);
+            # att.mha_auto remains available for long-context
+            # single-device use where the score materialization
+            # dominates
+            o = att.mha(q, k, v, causal=True)
+    with jax.named_scope("attn_proj"):
+        o = o.reshape(b, t, hl * cfg.head_dim)
+        o = o @ lp["wo"].astype(dt)   # row parallel: partial sums
+        if ax.tp:
+            o = region_exit(o, ax.tp)
+        h = h + o
 
     x = _ln(h.astype(jnp.float32), lp["ln2"]["g"],
             lp["ln2"]["b"]).astype(dt)
-    if ax.tp:
-        x = region_enter(x, ax.tp)
-    if is_moe:
-        flat = x.reshape(b * t, cfg.d_model)
-        if ax.ep:
-            y = moe_mod.moe_ffn(
-                flat, lp["wg"].astype(dt), lp["w1"].astype(dt),
-                lp["w2"].astype(dt), ax.ep,
-                capacity_factor=cfg.capacity_factor)
+    with jax.named_scope("mlp"):
+        if ax.tp:
+            x = region_enter(x, ax.tp)
+        if is_moe:
+            flat = x.reshape(b * t, cfg.d_model)
+            if ax.ep:
+                y = moe_mod.moe_ffn(
+                    flat, lp["wg"].astype(dt), lp["w1"].astype(dt),
+                    lp["w2"].astype(dt), ax.ep,
+                    capacity_factor=cfg.capacity_factor)
+            else:
+                y = _moe_dense(flat, lp, cfg)
+            if ax.tp:
+                y = region_exit(y, ax.tp)
+            y = y.reshape(b, t, cfg.d_model)
         else:
-            y = _moe_dense(flat, lp, cfg)
-        if ax.tp:
-            y = region_exit(y, ax.tp)
-        y = y.reshape(b, t, cfg.d_model)
-    else:
-        u = jnp.maximum(x @ lp["w1"].astype(dt), 0)
-        y = u @ lp["w2"].astype(dt)
-        if ax.tp:
-            y = region_exit(y, ax.tp)
-    return h + y
+            u = jnp.maximum(x @ lp["w1"].astype(dt), 0)
+            y = u @ lp["w2"].astype(dt)
+            if ax.tp:
+                y = region_exit(y, ax.tp)
+        return h + y
 
 
 def forward_local(params, tokens, cfg: Config, ax: Axes):
@@ -267,22 +281,26 @@ def forward_local(params, tokens, cfg: Config, ax: Axes):
         t_off = lax.axis_index(ax.sp) * t
     else:
         t_off = 0
-    h = params["embed"].astype(dt)[tokens]
-    pos = lax.dynamic_slice_in_dim(params["pos"], t_off, t, axis=0) \
-        if ax.sp else params["pos"][:t]
-    h = h + pos.astype(dt)[None]
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(dt)[tokens]
+        pos = lax.dynamic_slice_in_dim(params["pos"], t_off, t, axis=0) \
+            if ax.sp else params["pos"][:t]
+        h = h + pos.astype(dt)[None]
 
     for i, lp in enumerate(params["layers"]):
-        h = layer_forward(lp, h, cfg, ax, _is_moe(cfg, i))
+        with jax.named_scope(f"layer_{i}"):
+            h = layer_forward(lp, h, cfg, ax, _is_moe(cfg, i))
 
-    h = _ln(h.astype(jnp.float32), params["ln_f"]["g"],
-            params["ln_f"]["b"])
-    # weight-tied head: bf16 operands at full MXU rate, f32 accumulation
-    # (the vocab matmul is the single largest matmul in the model; an
-    # f32xf32 product here runs at half the systolic-array throughput)
-    return jnp.einsum("btd,vd->btv", h.astype(dt),
-                      params["embed"].astype(dt),
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope("head_loss"):
+        h = _ln(h.astype(jnp.float32), params["ln_f"]["g"],
+                params["ln_f"]["b"])
+        # weight-tied head: bf16 operands at full MXU rate, f32
+        # accumulation (the vocab matmul is the single largest matmul
+        # in the model; an f32xf32 product here runs at half the
+        # systolic-array throughput)
+        return jnp.einsum("btd,vd->btv", h.astype(dt),
+                          params["embed"].astype(dt),
+                          preferred_element_type=jnp.float32)
 
 
 def _moe_dense(flat, lp, cfg: Config):
@@ -300,13 +318,14 @@ def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
     """Summed next-token CE over local tokens + local count (caller
     normalizes after cross-shard psum)."""
     logits = forward_local(params, tokens, cfg, ax)
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(
-        logits, labels[..., None], axis=-1)[..., 0]
-    mask = (labels >= 0).astype(jnp.float32)
-    nll = ((logz - gold) * mask).sum()
-    return nll, mask.sum()
+    with jax.named_scope("head_loss"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(
+            logits, labels[..., None], axis=-1)[..., 0]
+        mask = (labels >= 0).astype(jnp.float32)
+        nll = ((logz - gold) * mask).sum()
+        return nll, mask.sum()
 
 
 def grad_sync(grads, specs, ax: Axes, extra=None):
@@ -338,8 +357,9 @@ def grad_sync(grads, specs, ax: Axes, extra=None):
     s_leaves = treedef.flatten_up_to(specs)
     e_leaves = treedef.flatten_up_to(extra) if extra is not None \
         else [""] * len(g_leaves)
-    out = [reduce_one(g, s, e)
-           for g, s, e in zip(g_leaves, s_leaves, e_leaves)]
+    with jax.named_scope("grad_sync"):
+        out = [reduce_one(g, s, e)
+               for g, s, e in zip(g_leaves, s_leaves, e_leaves)]
     return jax.tree.unflatten(treedef, out)
 
 
@@ -349,19 +369,20 @@ def sgd_update(params, grads, scale):
     and bf16 params would otherwise promote to f32 — changing the
     jitted step's input signature and forcing an XLA recompile inside
     any steady-state loop (the artifact documented in BASELINE.md)."""
-    import jax
-
-    return jax.tree.map(
-        lambda p, g: (p - scale * g.astype(p.dtype)).astype(p.dtype),
-        params, grads)
+    with jax.named_scope("sgd_update"):
+        return jax.tree.map(
+            lambda p, g: (p - scale * g.astype(p.dtype)).astype(p.dtype),
+            params, grads)
 
 
 def make_train_step(cfg: Config, ax: Axes, specs, lr: float = 1e-2):
     """(params, tokens, labels) -> (new_params, loss). Call inside
-    shard_map over the mesh (or directly when all axes are None)."""
+    shard_map over the mesh (or directly when all axes are None).
+    Jitted as it is, the step is module ``jit_ompi_train_step`` in a
+    device trace."""
     extra = grad_extra_axes(cfg, ax)
 
-    def step(params, tokens, labels):
+    def ompi_train_step(params, tokens, labels):
         (nll, cnt), grads = jax.value_and_grad(
             lambda p: loss_local(p, tokens, labels, cfg, ax),
             has_aux=True)(params)
@@ -375,4 +396,4 @@ def make_train_step(cfg: Config, ax: Axes, specs, lr: float = 1e-2):
         new_params = sgd_update(params, grads, scale)
         return new_params, loss
 
-    return step
+    return ompi_train_step

@@ -11,9 +11,16 @@ Buffer specs for capitalized methods: ``array`` | ``(array, count)`` |
 
 from __future__ import annotations
 
-from typing import Any, List, Optional, Sequence, Tuple
+import time as _time
 
-import numpy as np
+# phase "import" of mpi.Init() (pvar init_import_ns) begins with the
+# import of this module — numpy is most of it — and goes on in
+# runtime/state.init_instance, where jax is loaded
+_T_IMPORT = _time.monotonic_ns()
+
+from typing import Any, List, Optional, Sequence, Tuple  # noqa: E402
+
+import numpy as np  # noqa: E402
 
 from ompi_tpu import errors, op as op_mod, pml
 from ompi_tpu.comm import Communicator, Group, UNDEFINED
@@ -25,6 +32,7 @@ from ompi_tpu.pml.request import (  # noqa: F401  (re-exports)
     ANY_SOURCE, ANY_TAG, PROC_NULL, Request, Status, wait_all, wait_any,
     wait_some, test_all, test_any,
 )
+from ompi_tpu.trace import recorder as _trace
 
 IN_PLACE = "MPI_IN_PLACE"
 
@@ -1390,21 +1398,39 @@ def _Get_info(self):
     return as_info(self.info)
 
 
-def _with_errhandler(fn):
-    """Route MPIErrors escaping an API binding through the comm's
-    errhandler (the reference's OMPI_ERRHANDLER_INVOKE at every
-    binding's error exit, e.g. allreduce.c). String modes re-raise;
-    a user-callback handler that returns makes the operation recover
-    (the call returns None)."""
-    def wrapped(self, *a, **kw):
-        try:
+def _api_entry(name: str, fn, errhandled: bool):
+    """What the API table attaches for a binding.
+
+    Every binding opens the one span source's ``ompi:api.<name>``
+    (trace/recorder.api_span: while a jax.profiler session or the ring
+    is up — one guard otherwise), which gives the call the sequence
+    number every span beneath it carries.
+
+    The ``_ERRHANDLED`` bindings also route MPIErrors escaping them
+    through the comm's errhandler (the reference's
+    OMPI_ERRHANDLER_INVOKE at every binding's error exit, e.g.
+    allreduce.c). String modes re-raise; a user-callback handler that
+    returns makes the operation recover (the call returns None)."""
+    def traced(self, *a, **kw):
+        if not _trace.active():
             return fn(self, *a, **kw)
+        with _trace.api_span(name):
+            return fn(self, *a, **kw)
+
+    def handled(self, *a, **kw):
+        try:
+            if not _trace.active():
+                return fn(self, *a, **kw)
+            with _trace.api_span(name):
+                return fn(self, *a, **kw)
         except errors.MPIError as exc:
             errors.dispatch(self, exc)  # raises unless a callback
             return None                 # handled it
-    wrapped.__name__ = fn.__name__
-    wrapped.__doc__ = fn.__doc__
-    return wrapped
+
+    entry = handled if errhandled else traced
+    entry.__name__ = fn.__name__
+    entry.__doc__ = fn.__doc__
+    return entry
 
 
 _pending_bsends: List[Tuple[rq.Request, int]] = []
@@ -1485,7 +1511,7 @@ _API = {
 
 for _name, _fn in _API.items():
     setattr(Communicator, _name,
-            _with_errhandler(_fn) if _name in _ERRHANDLED else _fn)
+            _api_entry(_name, _fn, _name in _ERRHANDLED))
 
 # topology API (Create_cart/Cart_sub/Neighbor_*) attaches its own
 # Communicator methods at import (ompi/mca/topo equivalent)
@@ -1689,3 +1715,6 @@ def __getattr__(name: str):
 
         return state.comm_self()
     raise AttributeError(name)
+
+
+pvar.record("init_import_ns", _time.monotonic_ns() - _T_IMPORT)

@@ -39,6 +39,7 @@ from typing import Dict, Optional
 from ompi_tpu import errors
 from ompi_tpu.core import cvar, output
 from ompi_tpu.runtime import rte
+from ompi_tpu.runtime.state import init_phase
 
 _out = output.stream("device_plane")
 
@@ -116,9 +117,29 @@ def device_for_world_rank(world_rank: int):
 
 def _bootstrap(platform: str) -> Optional[str]:
     """Point jax at ``platform``, join the world's jax.distributed
-    cluster and check what jax handed back. Returns None, or why this
-    rank has no device on that platform. Never raises: every rank must
-    reach the modex agreement, failed or not."""
+    cluster (phase ``distributed``) and check what jax handed back
+    (phase ``client``: the first ``jax.local_devices()`` makes the
+    backend's client). Returns None, or why this rank has no device on
+    that platform. Never raises: every rank must reach the modex
+    agreement, failed or not."""
+    with init_phase("devplane.distributed"):
+        why = _join_cluster(platform)
+    if why is not None:
+        return why
+    import jax
+
+    with init_phase("devplane.client"):
+        try:
+            dev = jax.local_devices()[0]
+        except Exception as exc:  # noqa: BLE001 — e.g. no TPU to attach
+            return f"no local {platform} device: {exc!r}"
+    if dev.platform != platform:
+        return (f"asked for platform {platform!r}, jax gave "
+                f"{dev.platform!r} ({dev.device_kind})")
+    return None
+
+
+def _join_cluster(platform: str) -> Optional[str]:
     why = None
     try:
         import jax
@@ -159,16 +180,7 @@ def _bootstrap(platform: str) -> Optional[str]:
                     initialization_timeout=_timeout.get())
             except Exception as exc:  # noqa: BLE001
                 why = f"jax.distributed bootstrap failed: {exc!r}"
-    if why is not None:
-        return why
-    try:
-        dev = jax.local_devices()[0]
-    except Exception as exc:  # noqa: BLE001 — e.g. no TPU to attach
-        return f"no local {platform} device: {exc!r}"
-    if dev.platform != platform:
-        return (f"asked for platform {platform!r}, jax gave "
-                f"{dev.platform!r} ({dev.device_kind})")
-    return None
+    return why
 
 
 def init_plane() -> None:
@@ -189,10 +201,13 @@ def init_plane() -> None:
             dev_id = jax.local_devices()[0].id
         else:
             _out.verbose(1, "device plane: rank %d: %s", rte.rank, why)
-        rte.modex_send("devplane", {"error": why, "device_id": dev_id})
-        rte.fence("devplane")
-        peers: Dict[int, dict] = {
-            r: rte.modex_recv("devplane", r) for r in rte.world_ranks()}
+        with init_phase("devplane.fence"):  # waiting for the slowest rank
+            rte.modex_send("devplane",
+                           {"error": why, "device_id": dev_id})
+            rte.fence("devplane")
+            peers: Dict[int, dict] = {
+                r: rte.modex_recv("devplane", r)
+                for r in rte.world_ranks()}
         bad = {r: (p or {}).get("error", "published nothing")
                for r, p in peers.items()
                if not p or p.get("error") is not None}

@@ -16,6 +16,7 @@ from typing import Any, Optional
 
 from ompi_tpu.core import output, registry
 from ompi_tpu.runtime import rte
+from ompi_tpu.trace import recorder as _trace
 
 _lock = threading.RLock()
 _initialized = False
@@ -35,6 +36,15 @@ def is_finalized() -> bool:
     return _finalized
 
 
+def init_phase(name: str):
+    """One phase of mpi.Init(): span ``ompi:init.<name>`` and, always,
+    pvar ``init_<name>_ns`` (``devplane.client`` -> ``init_client_ns``).
+    The phases tile init_instance(), device_plane.init_plane() and
+    init(): what one does not cover, no number covers."""
+    return _trace.timed(name, "init",
+                        "init_%s_ns" % name.rpartition(".")[2])
+
+
 def init_instance() -> None:
     """Bring up the INSTANCE — everything below the world model.
 
@@ -49,7 +59,8 @@ def init_instance() -> None:
     with _lock:
         if _instance_up:
             return
-        rte.init()
+        with init_phase("rte"):
+            rte.init()
         _out.verbose(2, "rte up: rank %d/%d job %s",
                      rte.rank, rte.size, rte.jobid)
 
@@ -57,128 +68,140 @@ def init_instance() -> None:
         # must be live BEFORE the accelerator/device plane so the very
         # first device_put and XLA compile are attributed, and the
         # compile-cache dir must be set before anything compiles
-        from ompi_tpu import prof as _prof
-
-        try:
-            if _prof.requested():
-                _prof.enable(rank=rte.rank)
-        except Exception as exc:  # profiling must never sink init
-            _out.verbose(0, "prof enable failed: %r", exc)
-        # one directory for every rank of every job started from this
-        # checkout; a directory that cannot be made IS an init error.
-        # A device-plane rank loads jax a few lines down anyway: load
-        # it first, so the cache accounting sees its first compile.
         from ompi_tpu.runtime import device_plane
 
-        if device_plane.requested():
-            import jax  # noqa: F401
-        _out.verbose(2, "persistent compile cache: %s",
-                     _prof.wire_compile_cache())
+        with init_phase("import"):
+            from ompi_tpu import prof as _prof
 
-        # accelerator selection happens during core init in the reference
-        # (opal/runtime/opal_init.c:202-206)
-        from ompi_tpu.accelerator import current as _accel_current
-        _accel_current()
-
-        # streaming ingest plane (cvar ingest_enable / OMPI_TPU_INGEST):
-        # right after accelerator selection so the upload stream pool
-        # and staging rings bind to the selected component, before any
-        # comm construction kicks off staging traffic
-        from ompi_tpu import ingest as _ingest
-
-        if _ingest.requested():
             try:
-                _ingest.start(rank=rte.rank)
-            except Exception as exc:  # ingest must never sink init
-                _out.verbose(0, "ingest enable failed: %r", exc)
+                if _prof.requested():
+                    _prof.enable(rank=rte.rank)
+            except Exception as exc:  # profiling must never sink init
+                _out.verbose(0, "prof enable failed: %r", exc)
+            # one directory for every rank of every job started from
+            # this checkout; a directory that cannot be made IS an init
+            # error. A device-plane rank loads jax a few lines down
+            # anyway: load it first, so the cache accounting sees its
+            # first compile.
+            if device_plane.requested():
+                import jax  # noqa: F401
+            _out.verbose(2, "persistent compile cache: %s",
+                         _prof.wire_compile_cache())
+
+        with init_phase("accelerator"):
+            # accelerator selection happens during core init in the
+            # reference (opal/runtime/opal_init.c:202-206)
+            from ompi_tpu.accelerator import current as _accel_current
+            _accel_current()
+
+            # streaming ingest plane (cvar ingest_enable /
+            # OMPI_TPU_INGEST): right after accelerator selection so
+            # the upload stream pool and staging rings bind to the
+            # selected component, before any comm construction kicks
+            # off staging traffic
+            from ompi_tpu import ingest as _ingest
+
+            if _ingest.requested():
+                try:
+                    _ingest.start(rank=rte.rank)
+                except Exception as exc:  # ingest must never sink init
+                    _out.verbose(0, "ingest enable failed: %r", exc)
 
         # multi-controller device plane (opt-in; collective over the
         # world, must precede comm construction so coll/xla can qualify
         # during any comm's coll table selection). Raises on every
-        # rank when the requested plane did not come up.
+        # rank when the requested plane did not come up. Its three
+        # phases (devplane.distributed/.client/.fence) are its own.
         if device_plane.requested():
             device_plane.init_plane()
 
-        from ompi_tpu import pml
-
-        pml.select()
-        # interposition layers stack over the selected PML before any
-        # traffic flows (reference: pml/monitoring wraps at select)
-        from ompi_tpu.pml import vprotocol as _pml_v
-
-        if _pml_v._enable_var.get():
-            _pml_v.install()
-        # traffic-monitoring plane (cvar monitoring_level /
-        # OMPI_TPU_MONITORING; --mca pml_monitoring compat-maps to
-        # level 1): matrix core + pml interposition shim, before any
-        # traffic flows
-        from ompi_tpu import monitoring as _monitoring
-
-        if _monitoring.requested():
-            try:
-                _monitoring.start(rank=rte.rank, nranks=rte.size)
-            except Exception as exc:  # monitoring must never sink init
-                _out.verbose(0, "monitoring enable failed: %r", exc)
-        # collective performance observatory (cvar tune_observe /
-        # OMPI_TPU_TUNE): load the PerfDB baseline and raise the
-        # OBSERVER guard before any collective dispatches
-        from ompi_tpu import tune as _tune
-
-        if _tune.requested():
-            try:
-                _tune.start(rank=rte.rank, nranks=rte.size)
-            except Exception as exc:  # observing must never sink init
-                _out.verbose(0, "tune enable failed: %r", exc)
-        # debugger hook: SIGUSR1 match-queue dump (MPIR analog)
-        from ompi_tpu.tools import msgq as _msgq
-
-        _msgq.install_signal_dump()
-        # tracing plane (cvar trace_enable / OMPI_TPU_TRACE): bring
-        # the span recorder up before any traffic flows and exchange
-        # wall-vs-monotonic clock offsets through the store so merged
-        # per-rank timelines share rank 0's timebase
-        from ompi_tpu.trace import recorder as _trace_rec
-
-        if _trace_rec.requested():
-            try:
-                _trace_rec.enable(rank=rte.rank)
-                _trace_rec.sync_clock()
-            except Exception as exc:  # tracing must never sink init
-                _out.verbose(0, "trace enable failed: %r", exc)
-        # telemetry plane (cvar telemetry_enable / OMPI_TPU_TELEMETRY):
-        # flight recorder + metrics sampler + hang watchdog — after
-        # tracing so dump-on-hang can flush the span ring
-        from ompi_tpu import telemetry as _telemetry
-
-        if _telemetry.requested():
-            try:
-                _telemetry.start(rank=rte.rank)
-            except Exception as exc:  # telemetry must never sink init
-                _out.verbose(0, "telemetry enable failed: %r", exc)
-        # skew plane (cvar skew_level / OMPI_TPU_SKEW): completed-
-        # collective ring + store clock sync — rides the flight
-        # recorder's entry/exit instrumentation, so after telemetry
-        # (start() enables FLIGHT itself when telemetry is off)
-        from ompi_tpu import skew as _skew
-
-        if _skew.requested():
-            try:
-                _skew.start(rank=rte.rank, nranks=rte.size)
-            except Exception as exc:  # observing must never sink init
-                _out.verbose(0, "skew enable failed: %r", exc)
-        # correctness plane (cvar check_level / OMPI_TPU_CHECK): the
-        # runtime sanitizer interposes on the API dispatch table, so
-        # it comes up last — after every plane that wraps methods —
-        # and validates calls before the PML/coll layers see them
-        from ompi_tpu import check as _check
-
-        if _check.requested():
-            try:
-                _check.start(rank=rte.rank)
-            except Exception as exc:  # checking must never sink init
-                _out.verbose(0, "check enable failed: %r", exc)
+        with init_phase("pml"):
+            _init_pml_and_planes()
         _instance_up = True
         atexit.register(_atexit_finalize)
+
+
+def _init_pml_and_planes() -> None:
+    """The tail of init_instance(), phase ``pml``: pml selection, then
+    every opt-in plane that wraps it or the API table."""
+    from ompi_tpu import pml
+
+    pml.select()
+    # interposition layers stack over the selected PML before any
+    # traffic flows (reference: pml/monitoring wraps at select)
+    from ompi_tpu.pml import vprotocol as _pml_v
+
+    if _pml_v._enable_var.get():
+        _pml_v.install()
+    # traffic-monitoring plane (cvar monitoring_level /
+    # OMPI_TPU_MONITORING; --mca pml_monitoring compat-maps to
+    # level 1): matrix core + pml interposition shim, before any
+    # traffic flows
+    from ompi_tpu import monitoring as _monitoring
+
+    if _monitoring.requested():
+        try:
+            _monitoring.start(rank=rte.rank, nranks=rte.size)
+        except Exception as exc:  # monitoring must never sink init
+            _out.verbose(0, "monitoring enable failed: %r", exc)
+    # collective performance observatory (cvar tune_observe /
+    # OMPI_TPU_TUNE): load the PerfDB baseline and raise the
+    # OBSERVER guard before any collective dispatches
+    from ompi_tpu import tune as _tune
+
+    if _tune.requested():
+        try:
+            _tune.start(rank=rte.rank, nranks=rte.size)
+        except Exception as exc:  # observing must never sink init
+            _out.verbose(0, "tune enable failed: %r", exc)
+    # debugger hook: SIGUSR1 match-queue dump (MPIR analog)
+    from ompi_tpu.tools import msgq as _msgq
+
+    _msgq.install_signal_dump()
+    # tracing plane (cvar trace_enable / OMPI_TPU_TRACE): bring
+    # the span recorder up before any traffic flows and exchange
+    # wall-vs-monotonic clock offsets through the store so merged
+    # per-rank timelines share rank 0's timebase
+    from ompi_tpu.trace import recorder as _trace_rec
+
+    if _trace_rec.requested():
+        try:
+            _trace_rec.enable(rank=rte.rank)
+            _trace_rec.sync_clock()
+        except Exception as exc:  # tracing must never sink init
+            _out.verbose(0, "trace enable failed: %r", exc)
+    # telemetry plane (cvar telemetry_enable / OMPI_TPU_TELEMETRY):
+    # flight recorder + metrics sampler + hang watchdog — after
+    # tracing so dump-on-hang can flush the span ring
+    from ompi_tpu import telemetry as _telemetry
+
+    if _telemetry.requested():
+        try:
+            _telemetry.start(rank=rte.rank)
+        except Exception as exc:  # telemetry must never sink init
+            _out.verbose(0, "telemetry enable failed: %r", exc)
+    # skew plane (cvar skew_level / OMPI_TPU_SKEW): completed-
+    # collective ring + store clock sync — rides the flight
+    # recorder's entry/exit instrumentation, so after telemetry
+    # (start() enables FLIGHT itself when telemetry is off)
+    from ompi_tpu import skew as _skew
+
+    if _skew.requested():
+        try:
+            _skew.start(rank=rte.rank, nranks=rte.size)
+        except Exception as exc:  # observing must never sink init
+            _out.verbose(0, "skew enable failed: %r", exc)
+    # correctness plane (cvar check_level / OMPI_TPU_CHECK): the
+    # runtime sanitizer interposes on the API dispatch table, so
+    # it comes up last — after every plane that wraps methods —
+    # and validates calls before the PML/coll layers see them
+    from ompi_tpu import check as _check
+
+    if _check.requested():
+        try:
+            _check.start(rank=rte.rank)
+        except Exception as exc:  # checking must never sink init
+            _out.verbose(0, "check enable failed: %r", exc)
 
 
 def _acquire() -> None:
@@ -288,23 +311,25 @@ def init(thread_level: int = 0):
         if _initialized:
             return _world
         _acquire()
-        from ompi_tpu.comm import build_world
+        with init_phase("world"):
+            from ompi_tpu.comm import build_world
 
-        _world, _self_comm = build_world()
+            _world, _self_comm = build_world()
 
-        # ULFM detector (opt-in: --mca ft 1); after comm construction so
-        # its progress callback can resolve cids (reference: detector
-        # starts from ompi_comm_init under OPAL_ENABLE_FT_MPI)
-        from ompi_tpu.ft import detector as _ft_detector
+            # ULFM detector (opt-in: --mca ft 1); after comm
+            # construction so its progress callback can resolve cids
+            # (reference: detector starts from ompi_comm_init under
+            # OPAL_ENABLE_FT_MPI)
+            from ompi_tpu.ft import detector as _ft_detector
 
-        if _ft_detector.enabled() and rte.size > 1:
-            _ft_detector.start()
-        # init hooks last: everything (comms, transports) is up
-        # (reference: hook framework callbacks at the end of
-        # ompi_mpi_init)
-        from ompi_tpu.core import hook as _hook
+            if _ft_detector.enabled() and rte.size > 1:
+                _ft_detector.start()
+            # init hooks last: everything (comms, transports) is up
+            # (reference: hook framework callbacks at the end of
+            # ompi_mpi_init)
+            from ompi_tpu.core import hook as _hook
 
-        _hook.run_init(_world)
+            _hook.run_init(_world)
         _initialized = True
         return _world
 

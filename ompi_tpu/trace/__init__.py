@@ -12,10 +12,17 @@ timeline with ``python -m ompi_tpu.trace merge``
 (:mod:`~ompi_tpu.trace.merge`), and log2-binned latency histograms
 ride the pvar plane so ``mpit`` sessions can read them.
 
-Cost model: one attribute load + one branch per instrumented site
-while disabled (``recorder.RECORDER is None`` — no span objects are
-ever constructed); enable with cvar ``trace_enable``, env
-``OMPI_TPU_TRACE``, or :func:`recorder.enable`.
+The device path, the API table and ``mpi.Init()`` record through ONE
+span source (:func:`recorder.span`) with two sinks: any live
+``jax.profiler`` session (spans land in its ``.xplane.pb`` as
+``ompi:<subsys>.<name>``, on the chip's clock line) and the ring.
+
+Cost model: one guard per instrumented site while no sink is up
+(``recorder.active()``: an attribute load, a branch and
+``TraceAnnotation.is_enabled()``; host-plane sites still branch on
+``recorder.RECORDER is None``) — no span objects are ever constructed.
+The ring: cvar ``trace_enable``, env ``OMPI_TPU_TRACE``, or
+:func:`recorder.enable`. The profiler sink needs nothing.
 """
 
 from ompi_tpu.trace import export, merge, recorder  # noqa: F401

@@ -9,11 +9,27 @@ pvar, span completion optionally raises a ``trace_span`` MPI-4 event
 log2 latency histogram (:func:`hist`) is plain pvar counters readable
 through ``pvar.snapshot()`` / ``mpit``.
 
-Hot-path contract (regression-tested): while disabled — the default —
-an instrumented site pays ONE attribute load + ONE branch
-(``recorder.RECORDER is None``) and constructs nothing. Everything
-else (locking, Span allocation, histogram math) happens only on the
-enabled path.
+ONE span source, two sinks. :func:`span` is the call every
+instrumented site on the device path and in ``mpi.Init()`` makes:
+
+- while a ``jax.profiler`` session is live it opens a
+  ``jax.profiler.TraceAnnotation`` named ``ompi:<subsys>.<name>``, so
+  the span lands on ``/host:CPU`` of the same ``.xplane.pb`` as the
+  chip's ``XLA Ops``/``XLA Modules`` lines, on the profiler's clock
+  (nothing to switch on: any profiler session carries the program's
+  spans);
+- while ``RECORDER`` is up (``trace_enable`` / ``OMPI_TPU_TRACE``, the
+  operator's path to a Perfetto JSON) it records into the ring;
+- otherwise it hands back one shared no-op and constructs nothing.
+
+Spans of one API call carry the same ``call`` sequence number
+(:func:`api_span` opens the call; every span inside it inherits it).
+
+Hot-path contract (regression-tested): while both sinks are down — the
+default — an instrumented site pays :func:`active` (one attribute
+load, one branch, one ``TraceAnnotation.is_enabled()`` read) and
+constructs nothing. Older host-plane sites still guard on
+``recorder.RECORDER is None`` and feed the ring alone.
 
 Clocks: spans carry ``time.monotonic_ns`` timestamps. At enable each
 rank samples ``wall - monotonic`` (``clock_offset_ns``);
@@ -24,7 +40,10 @@ merged timelines line up without wall-clock-quality cross-host sync.
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -52,8 +71,6 @@ TRACE_SPAN = events.register_type(
 #: ``if recorder.RECORDER is not None: ...`` — module attribute load
 #: plus one branch, nothing constructed on the None path.
 RECORDER: Optional["Recorder"] = None
-
-_api_handle: Optional[int] = None
 
 
 def now() -> int:
@@ -118,28 +135,6 @@ class Recorder:
         t = now()
         return self.record(name, subsys, t, t, args)
 
-    class _Open:
-        __slots__ = ("_rec", "_name", "_subsys", "_args", "_t0")
-
-        def __init__(self, rec, name, subsys, args):
-            self._rec = rec
-            self._name = name
-            self._subsys = subsys
-            self._args = args
-
-        def __enter__(self):
-            self._t0 = now()
-            return self
-
-        def __exit__(self, *exc):
-            self._rec.record(self._name, self._subsys, self._t0,
-                             now(), self._args)
-            return False
-
-    def span(self, name: str, subsys: str, **args) -> "_Open":
-        """``with rec.span("compile", "coll_xla", key=k): ...``"""
-        return self._Open(self, name, subsys, args or None)
-
     def spans(self) -> List[Span]:
         """Chronological (completion-order) snapshot."""
         with self._lock:
@@ -154,6 +149,152 @@ class Recorder:
             self._buf = [None] * self.capacity
             self._head = 0
             self._n = 0
+
+
+# -- the span source -----------------------------------------------------
+
+#: prefix of every program span in a profiler trace
+PREFIX = "ompi:"
+
+_annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
+_calls = itertools.count(1)
+_call_of: Dict[int, int] = {}  # thread id -> the API call it is in
+_thread_id = threading.get_ident
+
+
+def _profiler_live() -> bool:
+    """Is a jax.profiler session collecting? Until jax is imported
+    none can be; from then on this name IS
+    ``TraceAnnotation.is_enabled`` (a static C++ read)."""
+    global _annotation, _profiler_live
+    if "jax" not in sys.modules:
+        return False
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:  # jax is half way through its own import
+        return False
+    _annotation = TraceAnnotation
+    _profiler_live = TraceAnnotation.is_enabled
+    return _profiler_live()
+
+
+def active() -> bool:
+    """THE guard of a site on the one span source: is a sink up?"""
+    return RECORDER is not None or _profiler_live()
+
+
+class _Off:
+    """What :func:`span` hands back while no sink is up."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+
+#: the one no-op span (what sites pass on when they skipped `span()`)
+OFF = _Off()
+
+
+class _Span:
+    """One open region, fed to whichever sinks were up when it
+    opened. Kept lean: with a profiler session live this runs on the
+    path it measures (PERF.md gives the cost per span)."""
+
+    __slots__ = ("name", "subsys", "args", "t0", "_ann", "_outer")
+
+    def __init__(self, name: str, subsys: str, args: Dict[str, Any],
+                 live: bool, opens_call: bool) -> None:
+        self.name = name
+        self.subsys = subsys
+        self.args = args
+        # False: no profiler session; True until __enter__ puts the
+        # open TraceAnnotation here
+        self._ann = live
+        # the call this thread was in before an api span opened its
+        # own (0: none); None for a span that opens no call
+        self._outer = 0 if opens_call else None
+
+    def __enter__(self):
+        args = self.args
+        if self._outer is None:
+            call = _call_of.get(_thread_id())
+        else:
+            me = _thread_id()
+            self._outer = _call_of.get(me, 0)
+            call = _call_of[me] = next(_calls)
+        if call is not None:
+            args["call"] = call
+        if self._ann:
+            ann = self._ann = _annotation(
+                PREFIX + self.subsys + "." + self.name, **args)
+            ann.__enter__()
+        self.t0 = now()
+        return self
+
+    def set(self, **args) -> None:
+        """Arguments learned while the span is open."""
+        self.args.update(args)
+        if self._ann:
+            self._ann.set_metadata(**args)
+
+    def __exit__(self, etype, exc, tb):
+        t1 = now()
+        if etype is not None:
+            self.set(error=etype.__name__)
+        if self._ann:
+            self._ann.__exit__(etype, exc, tb)
+        rec = RECORDER
+        if rec is not None:
+            rec.record(self.name, self.subsys, self.t0, t1,
+                       self.args or None)
+        if self._outer is not None:
+            if self._outer:
+                _call_of[_thread_id()] = self._outer
+            else:
+                _call_of.pop(_thread_id(), None)
+        return False
+
+
+def span(name: str, subsys: str, **args):
+    """``with span("launch", "coll_xla", program=p) as sp: ...`` —
+    the one call (module docstring). ``sp.set(k=v)`` adds arguments
+    learned inside. Sites whose arguments cost something to compute
+    branch on :func:`active` first."""
+    return _open(name, subsys, args, False)
+
+
+def _open(name: str, subsys: str, args: Dict[str, Any], opens_call: bool):
+    live = _profiler_live()
+    if RECORDER is None and not live:
+        return OFF
+    return _Span(name, subsys, args, live, opens_call)
+
+
+@contextlib.contextmanager
+def timed(name: str, subsys: str, counter: str):
+    """A span whose nanoseconds ALSO go into pvar ``counter``, sink or
+    no sink: for regions that run once per job, so two clock reads
+    cost nothing — the phases of ``mpi.Init()``, which end before a
+    profiler session can exist."""
+    t0 = now()
+    try:
+        with span(name, subsys):
+            yield
+    finally:
+        pvar.record(counter, now() - t0)
+
+
+def api_span(name: str):
+    """The span of one MPI API call: opens a new ``call`` sequence
+    number that every span inside it carries."""
+    return _open(name, "api", {}, True)
 
 
 # -- log2 latency histogram (pvar-plane export) --------------------------
@@ -184,17 +325,15 @@ def requested() -> bool:
     return raw not in ("", "0", "false", "no", "off")
 
 
-def enable(capacity: Optional[int] = None, rank: Optional[int] = None,
-           api_spans: bool = True) -> Recorder:
-    """Turn the recorder on (idempotent). ``api_spans`` interposes an
-    entry/exit span tool on the MPI API through the PMPI chain
-    (profile.attach_tool) — subsystem "api"."""
+def enable(capacity: Optional[int] = None,
+           rank: Optional[int] = None) -> Recorder:
+    """Turn the recorder on (idempotent). The MPI API's entry/exit
+    spans (subsystem "api") come from the API table's own wrapper
+    (``mpi._api_entry`` -> :func:`api_span`), like every other span."""
     global RECORDER
     if RECORDER is None:
         RECORDER = Recorder(capacity,
                             rank=0 if rank is None else rank)
-        if api_spans:
-            _install_api_hook()
     elif rank is not None:
         RECORDER.rank = rank
     return RECORDER
@@ -202,39 +341,9 @@ def enable(capacity: Optional[int] = None, rank: Optional[int] = None,
 
 def disable() -> Optional[Recorder]:
     """Turn the recorder off; returns it (spans stay exportable)."""
-    global RECORDER, _api_handle
+    global RECORDER
     rec, RECORDER = RECORDER, None
-    if _api_handle is not None:
-        from ompi_tpu import profile
-
-        profile.detach_tool(_api_handle)
-        _api_handle = None
     return rec
-
-
-def _install_api_hook() -> None:
-    """API entry/exit spans via the PMPI interposition chain."""
-    global _api_handle
-    if _api_handle is not None:
-        return
-    from ompi_tpu import profile
-
-    stack: Dict[tuple, int] = {}
-
-    def pre(name, comm, args, kwargs):
-        if RECORDER is not None:
-            stack[id(comm), name, threading.get_ident()] = now()
-
-    def post(name, comm, result, error):
-        t0 = stack.pop((id(comm), name, threading.get_ident()), None)
-        rec = RECORDER
-        if rec is None or t0 is None:
-            return
-        rec.record(name, "api", t0, now(),
-                   {"error": type(error).__name__}
-                   if error is not None else None)
-
-    _api_handle = profile.attach_tool(pre, post)
 
 
 def sync_clock() -> None:

@@ -1,9 +1,17 @@
 """trace/ subsystem tests: ring bounding + drop accounting, log2
 histogram binning, Pready -> flush span attribution, Chrome export
-shape, cross-rank merge, the zero-cost disabled guard, and the
-events-plane concurrent drop accounting the recorder builds on."""
+shape, cross-rank merge, the events-plane concurrent drop accounting
+the recorder builds on — and the one span source under its three
+states: no sink (constructs nothing), the ring up, a jax.profiler
+session live (a real 4-rank CPU .xplane.pb); a program's cold launch;
+the phases of mpi.Init()."""
 
+import glob
 import json
+import os
+import subprocess
+import sys
+import tempfile
 import threading
 import types
 
@@ -13,6 +21,8 @@ from ompi_tpu.core import events, pvar
 from ompi_tpu.trace import export, merge, recorder
 from ompi_tpu.trace import __main__ as trace_cli
 from tests.harness import run_ranks
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
@@ -60,27 +70,302 @@ def test_ring_thread_safety_exact_accounting(no_recorder):
     assert s.read("trace_dropped") == n_threads * per - 16
 
 
+def _local_comm():
+    from ompi_tpu.coll import xla as cx
+
+    return types.SimpleNamespace(_coll_xla_ctx=cx._Ctx.local())
+
+
 def test_disabled_guard_constructs_nothing(monkeypatch, no_recorder):
-    """Default-off tracing must not build span objects anywhere on
-    the coll/xla hot path — the one-branch guard contract the fused
-    pvar regression tests depend on."""
+    """No sink up (the default): the one span source hands back ONE
+    shared no-op and builds no span object, ring record or
+    TraceAnnotation anywhere on the coll/xla hot path — the guard
+    contract the fused pvar regression tests depend on."""
     import jax.numpy as jnp
 
     from ompi_tpu.coll import xla as cx
 
-    assert recorder.RECORDER is None
+    assert recorder.RECORDER is None and not recorder.active()
 
     def boom(*a, **k):
-        raise AssertionError("Span constructed while tracing disabled")
+        raise AssertionError("span constructed while no sink is up")
 
     monkeypatch.setattr(recorder, "Span", boom)
-    ctx = cx._Ctx.local()
-    comm = types.SimpleNamespace(_coll_xla_ctx=ctx)
+    monkeypatch.setattr(recorder, "_Span", boom)
+    monkeypatch.setattr(recorder, "_annotation", boom)
+    assert recorder.span("launch", "coll_xla", cold=0) is recorder.OFF
+    assert recorder.api_span("Allreduce") is recorder.OFF
+    with recorder.span("x", "y") as sp:
+        sp.set(k=1)  # arguments learned inside cost nothing either
+    comm = _local_comm()
     s = pvar.session()
     launcher = cx._allreduce_prep(comm, jnp.ones(16, jnp.float32))
-    launcher()
+    launcher()  # cold: timed into the pvars, still no span object
     launcher()
     assert s.read("coll_xla_launches") >= 2  # the path really ran
+    assert s.read("coll_xla_cold_launches") == 1
+
+
+# -- the one span source: ring up ----------------------------------------
+
+def test_ring_records_the_device_path_names_once_cold(no_recorder):
+    """Ring up: to_global, launch and my_shard under one slot's
+    prep, with the program's stable name; `cold=1` exactly once per
+    key, inside a `compile` span."""
+    import jax.numpy as jnp
+
+    from ompi_tpu.coll import xla as cx
+
+    comm = _local_comm()
+    rec = recorder.enable(capacity=256)
+    try:
+        x = jnp.ones(32, jnp.float32)
+        for _ in range(3):
+            cx._allreduce_prep(comm, x)()
+        cx._allreduce_prep(comm, jnp.ones(8, jnp.float32))()  # new key
+    finally:
+        recorder.disable()
+    spans = [sp for sp in rec.spans() if sp.subsys == "coll_xla"]
+    names = [sp.name for sp in spans]
+    assert names.count("to_global") == 4
+    assert names.count("my_shard") == 4
+    assert names.count("launch") == 4
+    assert names.count("compile") == 2  # one per key
+    launches = [sp for sp in spans if sp.name == "launch"]
+    assert [sp.args["cold"] for sp in launches] == [1, 0, 0, 1]
+    assert {sp.args["program"] for sp in launches} == {"ompi_allreduce"}
+    assert launches[0].args["nbytes"] == 32 * 4
+    assert all(sp.args["resident"] == 1 for sp in spans
+               if sp.name == "to_global")
+    # the removed instants stay removed: nothing per warm call but
+    # the spans of the work
+    assert "cache_hit" not in names and "plan_cache_hit" not in names
+
+
+def test_api_span_gives_every_span_of_the_call_one_id(no_recorder):
+    """`api_span` opens a call; spans inside carry its number, the
+    next call gets another, and a nested API call its own."""
+    rec = recorder.enable(capacity=64)
+    try:
+        with recorder.api_span("Allreduce"):
+            with recorder.span("allreduce", "coll_xla", nbytes=4):
+                with recorder.span("launch", "coll_xla"):
+                    pass
+            with recorder.api_span("bcast"):  # an API call inside one
+                with recorder.span("launch", "coll_xla"):
+                    pass
+            with recorder.span("my_shard", "coll_xla"):
+                pass
+        with recorder.api_span("Allreduce"):
+            pass
+        with recorder.span("orphan", "coll_xla"):  # outside any call
+            pass
+    finally:
+        recorder.disable()
+    by = {}
+    for sp in rec.spans():
+        by.setdefault(sp.name, []).append((sp.args or {}).get("call"))
+    outer, second = by["Allreduce"]
+    assert outer is not None and second not in (None, outer)
+    assert by["allreduce"] == [outer] and by["my_shard"] == [outer]
+    inner = by["bcast"][0]
+    assert inner not in (outer, second)
+    assert sorted(by["launch"]) == sorted([outer, inner])
+    assert by["orphan"] == [None]
+
+
+def test_span_records_the_error_that_escaped(no_recorder):
+    rec = recorder.enable(capacity=8)
+    try:
+        with pytest.raises(KeyError):
+            with recorder.api_span("Recv"):
+                raise KeyError("x")
+    finally:
+        recorder.disable()
+    (sp,) = rec.spans()
+    assert (sp.name, sp.subsys, sp.args["error"]) == \
+        ("Recv", "api", "KeyError")
+
+
+def test_cold_launch_not_build_is_the_compile(no_recorder):
+    """`_Ctx.compiled` only wraps (`build()` returns a lazy jax.jit):
+    `prof_compile_ns` and the cold-launch pvars grow on the key's
+    FIRST launch, not on `build()`, and never again."""
+    import jax.numpy as jnp
+
+    from ompi_tpu import prof
+    from ompi_tpu.coll import xla as cx
+
+    ctx = cx._Ctx.local()
+    x = jnp.ones(24, jnp.float32)
+    key = cx._key(x, "allreduce", "MPI_SUM", None)
+    prof.enable(rank=0)
+    s = pvar.session()
+    try:
+        fn = ctx.compiled(key, lambda: ctx.smap(
+            lambda a: a[0] * 2, out_varying=True))
+        assert ctx.programs[fn] == "ompi_allreduce"
+        assert s.read("prof_compile_misses") == 1
+        assert s.read("prof_compile_ns") == 0  # nothing compiled yet
+        assert s.read("coll_xla_cold_launches") == 0
+        g = ctx.to_global(x)
+        ctx.launch(fn, g)
+        cold_ns = s.read("coll_xla_cold_launch_ns")
+        assert s.read("coll_xla_cold_launches") == 1 and cold_ns > 0
+        assert s.read("prof_compile_ns") == cold_ns
+        assert ctx.compiled(key, None) is fn  # warm: build not called
+        ctx.launch(fn, g)
+        assert s.read("coll_xla_cold_launches") == 1
+        assert s.read("coll_xla_cold_launch_ns") == cold_ns
+        assert s.read("prof_compile_ns") == cold_ns
+    finally:
+        prof.disable()
+
+
+@pytest.mark.parametrize("key, name", [
+    (((16,), "float32", "allreduce", "MPI_SUM", None), "ompi_allreduce"),
+    (((16,), "float32", "allreduce", "MPI_SUM", "linear"),
+     "ompi_allreduce_linear"),
+    (((4, 4), "int32", "bcast", 2), "ompi_bcast"),
+    (("fused_allreduce", ((8,),), "MPI_SUM", "ring", False),
+     "ompi_fused_allreduce_ring"),
+    (("barrier",), "ompi_barrier"),
+])
+def test_program_name_is_the_keys_kind(key, name):
+    from ompi_tpu.coll import xla as cx
+
+    assert cx.program_name(key) == name
+
+
+def test_evicted_program_is_forgotten(no_recorder):
+    """LRU eviction drops the name and the cold mark with the cache
+    entry; a rebuilt key is cold again."""
+    import jax.numpy as jnp
+
+    from ompi_tpu.coll import xla as cx
+    from ompi_tpu.core import cvar
+
+    comm = _local_comm()
+    ctx = comm._coll_xla_ctx
+    cvar.set("coll_xla_cache_max", 1)
+    s = pvar.session()
+    try:
+        cx._allreduce_prep(comm, jnp.ones(4, jnp.float32))()
+        cx._allreduce_prep(comm, jnp.ones(5, jnp.float32))()
+        assert len(ctx.fns) == 1 and len(ctx.programs) == 1
+        assert not ctx._cold
+        cx._allreduce_prep(comm, jnp.ones(4, jnp.float32))()
+        assert s.read("coll_xla_cold_launches") == 3
+    finally:
+        cvar.set("coll_xla_cache_max", 0)
+
+
+# -- the one span source: a jax.profiler session live --------------------
+
+def _host_events(path):
+    """[(name, start_ns, end_ns, stats)] of the ompi:/bench: events in
+    a profiler trace, by thread line."""
+    from jax.profiler import ProfileData
+
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats)) for e in line.events
+                   if e.name.startswith(("ompi:", "bench:"))]
+            if evs:
+                lines.append(evs)
+    return lines
+
+
+def test_profiler_session_carries_the_nested_program_spans():
+    """`--trace 1` switches nothing on in the program: any live
+    jax.profiler session gets `ompi:api.Allreduce` > `ompi:coll_xla.
+    allreduce` > {to_global, launch, my_shard}, one `call` id per API
+    call, inside the benchmark's own `bench:collective call`, in a
+    real 4-rank CPU .xplane.pb."""
+    p = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
+         "--workload", "osu-allreduce-4rank", "--seed", str(2**31 + 5),
+         "--seconds", "1", "--trace", "1", "--rehearsal", "1"],
+        capture_output=True, text=True, timeout=600, cwd=REPO)
+    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
+    found = sorted(glob.glob(os.path.join(
+        REPO, "chiprun_out", "benchmark",
+        "rehearsal-osu-allreduce-4rank", "trace", "plugins", "profile",
+        "*", "*.xplane.pb")))
+    assert found
+    (events,) = _host_events(found[-1])  # one thread made them all
+
+    def inside(outer, inner):
+        return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+    calls = [e for e in events if e[0] == "bench:collective call"]
+    apis = [e for e in events if e[0] == "ompi:api.Allreduce"]
+    assert len(calls) > 10 and len(apis) == len(calls)
+    seen = set()
+    for call, api in zip(calls, apis):
+        assert inside(call, api)
+        cid = api[3]["call"]
+        assert cid not in seen
+        seen.add(cid)
+        mine = [e for e in events if e[0].startswith("ompi:coll_xla.")
+                and e[3].get("call") == cid]
+        assert [e[0] for e in mine] == [
+            "ompi:coll_xla.allreduce", "ompi:coll_xla.to_global",
+            "ompi:coll_xla.launch", "ompi:coll_xla.my_shard"]
+        slot, to_global, launch, my_shard = mine
+        assert inside(api, slot)
+        assert all(inside(slot, c) for c in (to_global, launch,
+                                              my_shard))
+        assert to_global[2] <= launch[1] <= launch[2] <= my_shard[1]
+        assert launch[3]["program"] == "ompi_allreduce"
+        assert launch[3]["cold"] == 0 and to_global[3]["resident"] == 1
+    # the host-plane API calls between the passes are there too
+    assert any(e[0] == "ompi:api.Barrier" for e in events)
+
+
+# -- mpi.Init(): phases that tile it --------------------------------------
+
+PHASES = ("import", "rte", "accelerator", "distributed", "client",
+          "fence", "pml", "world")
+
+
+def test_init_phase_pvars_tile_mpi_init():
+    """Every `init_<phase>_ns` is non-zero after `mpi.Init()` of a
+    device-plane job, and together they come to within 10% of the
+    wall from the package's import to Init's return."""
+    with tempfile.NamedTemporaryFile("w", suffix=".py",
+                                     delete=False) as fh:
+        fh.write(
+            "import json, time\n"
+            "t0 = time.monotonic_ns()\n"
+            "from ompi_tpu import mpi\n"
+            "comm = mpi.Init()\n"
+            "wall = time.monotonic_ns() - t0\n"
+            "from ompi_tpu.core import pvar\n"
+            f"ph = {{p: pvar.read('init_%s_ns' % p) for p in {PHASES!r}}}\n"
+            "print('PHASES', comm.rank, json.dumps([wall, ph]),"
+            " flush=True)\n"
+            "mpi.Finalize()\n")
+    try:
+        r = subprocess.run(
+            [sys.executable, "-m", "ompi_tpu.runtime.launcher", "-n",
+             "2", "--timeout", "120", "--mca", "device_plane", "on",
+             fh.name],
+            capture_output=True, text=True, cwd=REPO, timeout=180)
+    finally:
+        os.unlink(fh.name)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    lines = [ln for ln in r.stdout.splitlines()
+             if ln.startswith("PHASES")]
+    assert len(lines) == 2, r.stdout[-2000:]
+    for ln in lines:
+        wall, ph = json.loads(ln.split(" ", 2)[2])
+        assert all(v > 0 for v in ph.values()), ph
+        assert abs(sum(ph.values()) - wall) <= 0.10 * wall, (wall, ph)
 
 
 # -- log2 histogram ------------------------------------------------------
@@ -126,7 +411,7 @@ def test_pready_flush_span_attribution(no_recorder):
     leaves, treedef = jax.tree.flatten(bufs)
     preq = cx.PartitionedAllreduceRequest(ctx, leaves, treedef,
                                           op_mod.SUM, None)
-    rec = recorder.enable(capacity=1024, api_spans=False)
+    rec = recorder.enable(capacity=1024)
     s = pvar.session()
     try:
         preq.start()
