@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import functools
 import threading
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -186,6 +186,23 @@ def program_name(key) -> str:
     return f"ompi_{kind}_{order}" if order else f"ompi_{kind}"
 
 
+class _Scalar(NamedTuple):
+    """``to_global``'s view of a 0-d operand: global shape ``(n,)``,
+    block ``(1,)``. A tuple type, so jax carries it through ``jit``
+    and ``shard_map`` as a one-leaf pytree and ``smap``'s program can
+    see, operand by operand, which block already has its rank axis."""
+    view: object
+
+
+def _is_scalar(a) -> bool:
+    return isinstance(a, _Scalar)
+
+
+def _block(a):
+    """Operand block -> the ``(1, *shape)`` block a body sees."""
+    return a.view if isinstance(a, _Scalar) else a.reshape((1,) + a.shape)
+
+
 class _Ctx:
     """Per-communicator compiled-collective state (the analog of the
     reference's per-comm coll module data)."""
@@ -264,16 +281,34 @@ class _Ctx:
 
     # -- plumbing ---------------------------------------------------------
     def to_global(self, x, sharding=None):
-        """Local device array -> global array sharded (n, *shape) on
-        the comm axis/axes (rank r's contribution at index r).
+        """Local device array -> this rank's part of the global array
+        a compiled collective takes: shape ``(n * x.shape[0],
+        *x.shape[1:])``, sharded on dimension 0 over the comm axis/
+        axes (rank r's contribution is block r) — the convention a
+        ``P(AXIS)`` OUTPUT already has. ``smap``'s program gives each
+        block its leading axis back, so a body sees ``(1, *x.shape)``.
 
-        Fast path: device_put is skipped when the buffer already
-        lives on ``my`` — it runs on every collective call, and for
-        resident arrays (the steady-state training case) it only adds
-        a dispatch round. The ``x[None]`` below is a program of its
-        own on the device (``jit_broadcast_in_dim``, a full copy of
-        the buffer): span ``to_global`` is what a caller pays for the
-        global view."""
+        The view is built from the caller's buffer itself: no
+        indexing, no device program, no copy. It therefore ALIASES
+        ``x``. jax arrays are immutable and no program of this
+        package donates an argument (tests/test_coll_xla_global_view
+        walks ``ctx.fns`` to pin that), so a launch never changes
+        ``x``; but a view does not outlive its operand's buffer — a
+        persistent or partitioned request binds its views once, and
+        a ``Start()`` after the user ``delete()``d the operand raises
+        jax's "Array has been deleted".
+
+        A 0-d operand has no dimension to shard: it is expanded
+        eagerly (``x[None]``: a device program and a copy, counted in
+        ``coll_xla_global_view_copies``) and comes back as a
+        :class:`_Scalar`, whose ``(1,)`` block is already what the
+        body expects.
+
+        device_put is skipped when the buffer already lives on
+        ``my`` — it runs on every collective call, and for resident
+        arrays (the steady-state training case) it only adds a
+        dispatch round. Span ``to_global`` is what a caller pays for
+        the global view."""
         if not _trace.active():
             return self._to_global(x, sharding, _trace.OFF)
         with _trace.span("to_global", "coll_xla") as sp:
@@ -296,9 +331,14 @@ class _Ctx:
             x.block_until_ready()
             _prof.PROFILER.xfer("h2d", getattr(x, "nbytes", 0), t0,
                                 _prof.now(), site="to_global")
+        sharding = sharding or self.in_sharding
+        shape = x.shape
+        if not shape:
+            pvar.record("coll_xla_global_view_copies")
+            return _Scalar(jax.make_array_from_single_device_arrays(
+                (self.n,), sharding, [x[None]]))
         return jax.make_array_from_single_device_arrays(
-            (self.n,) + x.shape, sharding or self.in_sharding,
-            [x[None]])
+            (self.n * shape[0],) + shape[1:], sharding, [x])
 
     def my_shard(self, out):
         """This rank's shard of an AXIS-sharded result."""
@@ -411,11 +451,8 @@ class _Ctx:
     def _nbytes(self, args) -> int:
         """Operand bytes of one rank (the global views' bytes / n;
         a fused bucket passes its leaves as one tuple)."""
-        total = 0
-        for a in args:
-            for x in (a if isinstance(a, (tuple, list)) else (a,)):
-                total += getattr(x, "nbytes", 0)
-        return total // self.n
+        return sum(getattr(x, "nbytes", 0)
+                   for x in self.jax.tree.leaves(args)) // self.n
 
     def release(self) -> None:
         """Drop the compiled-program and plan caches (comm destructor
@@ -431,6 +468,13 @@ class _Ctx:
         ICI x DCN mesh when passed). Body sees the local (1, *shape)
         block; out_varying selects the sharded vs replicated spec.
 
+        ``to_global`` hands in the rank's buffer as it is (block
+        ``shape``); the rank axis is put in front of every operand
+        block HERE, inside the traced program, where it is a bitcast
+        that XLA folds into the body's ``a[0]``. ``to_global`` and
+        ``smap`` are the one seam: every program fed by ``to_global``
+        is built by ``smap``.
+
         The program gets the stable name ``compiled`` chose for its
         key (module ``jit_ompi_<kind>`` in a device trace, instead of
         ``jit__lambda_``), and its collective sits under
@@ -443,6 +487,7 @@ class _Ctx:
         name = getattr(_naming, "program", None) or "ompi_program"
 
         def program(*a):
+            a = jax.tree.map(_block, a, is_leaf=_is_scalar)
             with jax.named_scope(name[len("ompi_"):]):
                 return body(*a)
 

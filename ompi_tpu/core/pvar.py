@@ -34,6 +34,10 @@ WELL_KNOWN = (
     "coll_xla_fused_bytes", "coll_xla_plan_cache_hits",
     "coll_xla_plan_cache_misses", "coll_xla_device_put_skipped",
     "coll_xla_cache_evictions",
+    # calls of _Ctx.to_global that still dispatched an eager program
+    # (and copied the operand) to build the global view: 0-d operands
+    # only — an operand with a dimension to shard is viewed in place
+    "coll_xla_global_view_copies",
     # a program's FIRST launch, where jax compiles it or loads it from
     # the persistent cache (always timed: once per cache key)
     "coll_xla_cold_launches", "coll_xla_cold_launch_ns",

@@ -243,11 +243,7 @@ class Dispatcher:
                 return routed_ffn(xb[0], wgb[0], w1b[0], w2b[0],
                                   axis=_xla.AXIS, capacity_factor=cf,
                                   policy=policy)
-            jax, P = ctx.jax, ctx.P
-            return jax.jit(jaxcompat.shard_map(
-                body, mesh=ctx.mesh, in_specs=P(_xla.AXIS),
-                out_specs=(P(_xla.AXIS), P(_xla.AXIS)),
-                check_vma=False))
+            return ctx.smap(body, out_varying=True)
 
         fn = ctx.compiled(key, build)
         gwg, gw1, gw2 = self._weights(ctx, "flat")
@@ -292,11 +288,7 @@ class Dispatcher:
                                jnp.int32(0), route.dropped, multi]),
                     route.counts])
                 return out, stats, kept_tok, picked, gate1
-            jax, P = ctx.jax, ctx.P
-            spec = P((H.DCN_AXIS, H.ICI_AXIS))
-            return jax.jit(jaxcompat.shard_map(
-                body, mesh=plan.mesh, in_specs=spec,
-                out_specs=(spec,) * 5, check_vma=False))
+            return _hier._smap(ctx, plan, body, out_varying=True)
 
         fn = ctx.compiled(key, build)
         gwg, gw1, gw2 = self._weights(ctx, "dcn", plan.sharding)

@@ -122,6 +122,7 @@ def test_strided_halo_bit_identity(n):
     wh.Put_strided(col, (rank + 1) % size, disp=7, stride=8)
     wh.Fence()
     bitcheck()
+    comm.barrier()  # a peer's lock-epoch put must not land mid-check
     # strided AM path under a lock epoch, same bit contract
     t = (rank + 1) % size
     wd.Lock(t); wd.Put_strided(jnp.asarray(col * 2), t, 0, 8)

@@ -67,12 +67,10 @@ def test_drop_bitwise_equal_to_moe_ffn():
     stats tail must not perturb the output graph."""
     run_ranks("""
     import jax, jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
     from ompi_tpu.coll import xla as cx
     from ompi_tpu.core import pvar
     from ompi_tpu.ops import moe
     from ompi_tpu.serve import Dispatcher, ZipfTraffic
-    from ompi_tpu.util import jaxcompat
     e_local, d, f = 2, 32, 16
     tr = ZipfTraffic(e_local * size, d, hotness=1.2, seed=3)
     rng = np.random.default_rng(100 + rank)
@@ -83,9 +81,7 @@ def test_drop_bitwise_equal_to_moe_ffn():
     ctx = cx._ctx(comm)
     def body(xb, wgb, w1b, w2b):
         return moe.moe_ffn(xb[0], wgb[0], w1b[0], w2b[0], cx.AXIS)
-    fn = jax.jit(jaxcompat.shard_map(
-        body, mesh=ctx.mesh, in_specs=P(cx.AXIS),
-        out_specs=P(cx.AXIS), check_vma=False))
+    fn = ctx.smap(body, out_varying=True)
     ref = np.asarray(ctx.my_shard(fn(
         ctx.to_global(jnp.asarray(x)),
         ctx.to_global(jnp.asarray(tr.wg)),

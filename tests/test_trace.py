@@ -117,6 +117,7 @@ def test_ring_records_the_device_path_names_once_cold(no_recorder):
     from ompi_tpu.coll import xla as cx
 
     comm = _local_comm()
+    s = pvar.session()
     rec = recorder.enable(capacity=256)
     try:
         x = jnp.ones(32, jnp.float32)
@@ -125,6 +126,8 @@ def test_ring_records_the_device_path_names_once_cold(no_recorder):
         cx._allreduce_prep(comm, jnp.ones(8, jnp.float32))()  # new key
     finally:
         recorder.disable()
+    # the span stays, it just gets short: no device program behind it
+    assert s.read("coll_xla_global_view_copies") == 0
     spans = [sp for sp in rec.spans() if sp.subsys == "coll_xla"]
     names = [sp.name for sp in spans]
     assert names.count("to_global") == 4
@@ -209,7 +212,12 @@ def test_cold_launch_not_build_is_the_compile(no_recorder):
         assert s.read("prof_compile_ns") == 0  # nothing compiled yet
         assert s.read("coll_xla_cold_launches") == 0
         g = ctx.to_global(x)
-        ctx.launch(fn, g)
+        # the view is the operand under the global shape (n = 1
+        # here), and the body above still saw its (1, 24) block
+        assert g.shape == (24,)
+        assert (g.addressable_data(0).unsafe_buffer_pointer()
+                == x.unsafe_buffer_pointer())
+        assert ctx.launch(fn, g).shape == (24,)
         cold_ns = s.read("coll_xla_cold_launch_ns")
         assert s.read("coll_xla_cold_launches") == 1 and cold_ns > 0
         assert s.read("prof_compile_ns") == cold_ns
