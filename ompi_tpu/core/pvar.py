@@ -197,11 +197,18 @@ WELL_KNOWN = (
     # over DCN with the byte meter the budget cvar bounds); latency
     # histograms ride the trace plane's dynamic
     # trace_hist_serve_decode_* families. serve_dropped_tokens is also
-    # fed by ops/moe.top1_routing's eager-mode metering, so
-    # capacity-factor tuning has drop data outside the serve loop
+    # fed by ops/moe.top1_routing's eager-mode metering (the
+    # capacity-based router of the expert-parallel and serve paths; a
+    # one-device MoE layer takes the drop-free sorted path and never
+    # reaches it: moe_dropped_assignments below is its counter)
     "serve_requests", "serve_tokens", "serve_dropped_tokens",
     "serve_rerouted_tokens", "serve_dcn_overflow_tokens",
     "serve_dcn_overflow_bytes",
+    # models/transformer.route_counts (a probe the host calls outside
+    # any timed window): token-expert assignments the MoE layers made,
+    # and those a capacity or an exchange lost — 0 on the sorted
+    # one-device path, counted so that no later path drops in silence
+    "moe_assignments", "moe_dropped_assignments",
     # fcoll aggregator writes retried after a short/partial result
     # (exhaustion raises MPIError(ERR_FILE) — satellites of the same
     # hardening pass)

@@ -160,6 +160,14 @@ def make_pp_train_step(cfg: tfm.Config, ax: tfm.Axes, specs,
             "(all dense) or 1 (all MoE) so layers stack")
     if ax.pp is None:
         raise ValueError("make_pp_train_step requires ax.pp")
+    if (cfg.pos != "learned" or cfg.norm != "layernorm"
+            or not cfg.tie_head or cfg.router_aux_weight
+            or cfg.router_z_weight):
+        raise NotImplementedError(
+            "the pipeline's first and last stage compute learned "
+            "positions, a final LayerNorm and the tied head, and its "
+            "stages collect no router loss: RoPE, RMSNorm, an untied "
+            "head and the router losses under ax.pp are ROADMAP R3")
     # stacked version of grad_extra_axes (homogeneous layers: every
     # layer's extra-psum tree is identical, so the first one stands in
     # for the stacked dim) — drops the tp psum on the MoE router wg

@@ -20,18 +20,37 @@ All axes are optional (None = that strategy off), so the same code runs
 single-device (``entry()``) and on any mesh factorization. bfloat16
 activations by default — MXU-native.
 
+The block is a DESCRIPTION, read from a model's published config, not a
+fork per model: :class:`Config` says which norm (LayerNorm / RMSNorm),
+which positions (learned / RoPE), QK-norm or not, dense or
+mixture-of-experts FFN (top-k, gated or not, which activation), tied
+or untied head, and the router's auxiliary-loss weights; there is ONE
+:func:`layer_forward`. The defaults are OPT's (pre-LN, ReLU MLP,
+learned positions, tied head: reference
+``benchmark/reference/opt_decoder.py``); OLMoE-1B-7B (RMSNorm, QK-norm
+over the whole projection, RoPE, top-8 of 64 SiLU-gated experts
+without renormalisation, untied head, load-balancing and router-z
+losses) is the second published model it computes (reference
+``benchmark/reference/olmoe_decoder.py``). Without an ``ep`` axis a
+MoE layer takes the drop-free sorted path of :mod:`ompi_tpu.ops.moe`.
+
 Names on the device (``jax.named_scope``: metadata, the HLO is the
 same): the jitted step is module ``jit_ompi_train_step``; its ops carry
 ``embed``, ``layer_<i>/{ln, attn_proj, attn_core, mlp}``,
-``head_loss`` (final LN, tied head, loss), ``grad_sync`` and
+``head_loss`` (final norm, head, loss), ``grad_sync`` and
 ``sgd_update`` in their op path, under the ``jvp(...)`` /
 ``transpose(jvp(...))`` jax adds for forward and backward — so a trace
 reader finds a model part by name, not by XLA's fusion numbering.
+Inside ``attn_proj``: ``qk_rope`` (QK-norm and RoPE); inside ``mlp`` of
+a MoE layer: ``moe_route`` (router matmul, softmax, top-k, the two
+losses), ``moe_dispatch`` (sort, gather), ``moe_experts`` (grouped
+matmuls, activation), ``moe_combine`` (un-sort, weighted sum).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional
 
@@ -40,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ompi_tpu.core import pvar
 from ompi_tpu.ops import attention as att
 from ompi_tpu.ops import moe as moe_mod
 from ompi_tpu.ops.ring_attention import ring_attention
@@ -56,7 +76,36 @@ class Config:
     max_seq: int = 1024
     moe_every: int = 0       # every k-th layer is MoE (0 = dense only)
     n_experts: int = 8
+    #: experts per token (the source's num_experts_per_tok); their
+    #: weights are the softmax probabilities as they are unless
+    #: norm_topk_prob
+    top_k: int = 1
+    norm_topk_prob: bool = False
+    #: slots per expert = capacity_factor * tokens / experts — the
+    #: expert-parallel path only (ops.moe.moe_ffn drops the overflow);
+    #: without an ep axis no token is ever dropped
     capacity_factor: float = 1.25
+    #: FFN and expert activation ("relu", "silu", "gelu"), and whether
+    #: it gates a second projection w3: act(x W1) * (x W3)
+    mlp_act: str = "relu"
+    mlp_gated: bool = False
+    #: "layernorm" (gain and bias) or "rmsnorm" (gain only)
+    norm: str = "layernorm"
+    norm_eps: float = 1e-5
+    #: "learned" (a table of max_seq rows, params["pos"]) or "rope"
+    #: (rotate-half pairing of dimensions i and i + head_dim / 2)
+    pos: str = "learned"
+    rope_theta: float = 10000.0
+    #: RMSNorm of q and k over the WHOLE projection (width d_model),
+    #: before the split into heads
+    qk_norm: bool = False
+    #: the head is the embedding's transpose, or params["head"]
+    tie_head: bool = True
+    #: weights of the router's load-balancing loss E * sum_e f_e P_e
+    #: and z-loss mean(logsumexp(logits)^2), averaged over MoE layers
+    #: and added to the mean next-token loss
+    router_aux_weight: float = 0.0
+    router_z_weight: float = 0.0
     dtype: Any = jnp.bfloat16
     #: parameter STORAGE dtype: float32 (default — full-precision
     #: master weights) or bfloat16 (halves weight HBM traffic per
@@ -106,34 +155,70 @@ def init_params(rng: np.random.Generator, cfg: Config) -> Dict:
 
     d, f, v = cfg.d_model, cfg.d_ff, cfg.vocab
     s_emb = 1.0 / math.sqrt(d)
-    params: Dict = {
-        "embed": normal(v, d, scale=s_emb),
-        "pos": normal(cfg.max_seq, d, scale=0.02),
-        "ln_f": {"g": np.ones(d, pdt),
-                 "b": np.zeros(d, pdt)},
-        "layers": [],
-    }
+
+    def norm():
+        gain = {"g": np.ones(d, pdt)}
+        return gain if cfg.norm == "rmsnorm" else dict(
+            gain, b=np.zeros(d, pdt))
+
+    params: Dict = {"embed": normal(v, d, scale=s_emb)}
+    if cfg.pos == "learned":
+        params["pos"] = normal(cfg.max_seq, d, scale=0.02)
+    if not cfg.tie_head:
+        params["head"] = normal(v, d, scale=s_emb)
+    params["ln_f"] = norm()
+    params["layers"] = []
     for i in range(cfg.n_layers):
         lp = {
-            "ln1": {"g": np.ones(d, pdt),
-                    "b": np.zeros(d, pdt)},
-            "ln2": {"g": np.ones(d, pdt),
-                    "b": np.zeros(d, pdt)},
+            "ln1": norm(), "ln2": norm(),
             "wq": normal(d, d, scale=s_emb),
             "wk": normal(d, d, scale=s_emb),
             "wv": normal(d, d, scale=s_emb),
             "wo": normal(d, d, scale=s_emb / math.sqrt(2 * cfg.n_layers)),
         }
-        if _is_moe(cfg, i):
+        if cfg.qk_norm:
+            lp["q_norm"] = {"g": np.ones(d, pdt)}
+            lp["k_norm"] = {"g": np.ones(d, pdt)}
+        # experts carry a leading [n_experts] dimension
+        ex = (cfg.n_experts,) if _is_moe(cfg, i) else ()
+        if ex:
             lp["wg"] = normal(d, cfg.n_experts, scale=s_emb)
-            lp["w1"] = normal(cfg.n_experts, d, f, scale=s_emb)
-            lp["w2"] = normal(cfg.n_experts, f, d,
-                              scale=1.0 / math.sqrt(f))
-        else:
-            lp["w1"] = normal(d, f, scale=s_emb)
-            lp["w2"] = normal(f, d, scale=1.0 / math.sqrt(f))
+        lp["w1"] = normal(*ex, d, f, scale=s_emb)
+        if cfg.mlp_gated:
+            lp["w3"] = normal(*ex, d, f, scale=s_emb)
+        lp["w2"] = normal(*ex, f, d, scale=1.0 / math.sqrt(f))
         params["layers"].append(lp)
     return params
+
+
+def _like_params(cfg: Config, leaf, wide, expert):
+    """A tree of init_params' structure: `leaf` for every replicated
+    leaf, `wide(name)` for the tp-sharded attention and dense-FFN
+    matrices, `expert(name)` for a MoE layer's wg / w1 / w3 / w2."""
+    def norm():
+        return {"g": leaf} if cfg.norm == "rmsnorm" else {"g": leaf,
+                                                          "b": leaf}
+
+    tree: Dict = {"embed": leaf}
+    if cfg.pos == "learned":
+        tree["pos"] = leaf
+    if not cfg.tie_head:
+        tree["head"] = leaf
+    tree["ln_f"] = norm()
+    tree["layers"] = []
+    ffn = ("w1", "w3", "w2") if cfg.mlp_gated else ("w1", "w2")
+    for i in range(cfg.n_layers):
+        lt = {"ln1": norm(), "ln2": norm()}
+        lt.update({n: wide(n) for n in ("wq", "wk", "wv", "wo")})
+        if cfg.qk_norm:
+            lt["q_norm"] = {"g": leaf}
+            lt["k_norm"] = {"g": leaf}
+        if _is_moe(cfg, i):
+            lt.update({n: expert(n) for n in ("wg",) + ffn})
+        else:
+            lt.update({n: wide(n) for n in ffn})
+        tree["layers"].append(lt)
+    return tree
 
 
 def param_specs(cfg: Config, ax: Axes):
@@ -145,28 +230,12 @@ def param_specs(cfg: Config, ax: Axes):
     """
     from jax.sharding import PartitionSpec as P
 
-    rep = P()
-    specs: Dict = {
-        "embed": rep, "pos": rep,
-        "ln_f": {"g": rep, "b": rep},
-        "layers": [],
-    }
-    for i in range(cfg.n_layers):
-        ls = {
-            "ln1": {"g": rep, "b": rep},
-            "ln2": {"g": rep, "b": rep},
-            "wq": P(None, ax.tp), "wk": P(None, ax.tp),
-            "wv": P(None, ax.tp), "wo": P(ax.tp, None),
-        }
-        if _is_moe(cfg, i):
-            ls["wg"] = rep
-            ls["w1"] = P(ax.ep, None, ax.tp)
-            ls["w2"] = P(ax.ep, ax.tp, None)
-        else:
-            ls["w1"] = P(None, ax.tp)
-            ls["w2"] = P(ax.tp, None)
-        specs["layers"].append(ls)
-    return specs
+    row = ("wo", "w2")  # row parallel: the input dim is sharded
+    return _like_params(
+        cfg, P(),
+        wide=lambda n: P(ax.tp, None) if n in row else P(None, ax.tp),
+        expert=lambda n: P() if n == "wg" else P(
+            ax.ep, ax.tp, None) if n in row else P(ax.ep, None, ax.tp))
 
 
 def grad_extra_axes(cfg: Config, ax: Axes):
@@ -179,17 +248,9 @@ def grad_extra_axes(cfg: Config, ax: Axes):
     # leaves are axis-name strings ("" = none): strings are pytree
     # leaves, so the tree composes with tree.flatten_up_to cleanly
     none = ""
-    extra: Dict = {"embed": none, "pos": none,
-                   "ln_f": {"g": none, "b": none}, "layers": []}
-    for i in range(cfg.n_layers):
-        le = {"ln1": {"g": none, "b": none},
-              "ln2": {"g": none, "b": none},
-              "wq": none, "wk": none, "wv": none, "wo": none,
-              "w1": none, "w2": none}
-        if _is_moe(cfg, i):
-            le["wg"] = ax.tp or none
-        extra["layers"].append(le)
-    return extra
+    return _like_params(
+        cfg, none, wide=lambda n: none,
+        expert=lambda n: (ax.tp or none) if n == "wg" else none)
 
 
 def _ln(x, g, b):
@@ -199,25 +260,113 @@ def _ln(x, g, b):
         return (x - mu) * lax.rsqrt(var + 1e-5) * g + b
 
 
-def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool):
-    """One transformer block on local shards: pre-LN attention (+tp
+def _rms(x, g, eps: float):
+    with jax.named_scope("ln"):
+        return x * lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * g
+
+
+def _norm(x, p, cfg: Config):
+    """The config's norm of a float32 x: LayerNorm (gain and bias) or
+    RMSNorm (gain only)."""
+    if cfg.norm == "layernorm":
+        return _ln(x, p["g"], p["b"])
+    if cfg.norm == "rmsnorm":
+        return _rms(x, p["g"], cfg.norm_eps)
+    raise ValueError(f"norm={cfg.norm!r}: expected 'layernorm' or "
+                     "'rmsnorm'")
+
+
+def rope(x, positions, theta: float):
+    """Rotary positions on x [B, T, H, Dh] at integer `positions` [T]:
+    the rotate-half pairing (dimension i with i + Dh/2), computed in
+    float32, returned in x's type."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _check_supported(cfg: Config, ax: Axes, is_moe: bool, pos_offset):
+    """What the config may ask for that an axis cannot give yet is an
+    error, never another function computed in silence."""
+    if is_moe and ax.ep and (cfg.top_k != 1 or cfg.mlp_gated
+                             or cfg.mlp_act != "relu"):
+        raise NotImplementedError(
+            "expert parallelism (ax.ep) runs capacity-based top-1 ReLU "
+            "experts only; top_k > 1 and gated experts over an ep axis "
+            "are ROADMAP R1b (the sorted dispatch with an exchange in "
+            "the middle)")
+    if cfg.pos == "rope" and ax.sp and pos_offset is None:
+        raise NotImplementedError(
+            "RoPE under sequence parallelism (ax.sp) needs the shard's "
+            "position offset, which this caller does not pass "
+            "(models/pipeline.py; ROADMAP R1b)")
+    if cfg.qk_norm and ax.tp:
+        raise NotImplementedError(
+            "QK-norm spans the whole projection, which tensor "
+            "parallelism (ax.tp) shards by columns: its sum of squares "
+            "over the tp axis is not written yet")
+
+
+def _moe_sorted(flat, lp, cfg: Config, aux):
+    """A MoE layer's FFN on one device: route in float32, then the
+    drop-free sorted path. `aux` collects this layer's (load-balancing
+    loss, z-loss, routing) where the caller wants them."""
+    dt = flat.dtype
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(flat.astype(jnp.float32),
+                         lp["wg"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        route = moe_mod.topk_routing(logits, cfg.top_k,
+                                     cfg.norm_topk_prob)
+        if aux is not None:
+            aux.append((moe_mod.load_balance_loss(route),
+                        moe_mod.router_z_loss(route), route))
+    w3 = lp["w3"].astype(dt) if cfg.mlp_gated else None
+    return moe_mod.sorted_moe_ffn(flat, route, lp["w1"].astype(dt), w3,
+                                  lp["w2"].astype(dt), cfg.mlp_act)
+
+
+def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
+                  pos_offset=None, aux=None):
+    """One transformer block on local shards: pre-norm attention (+tp
     Megatron f/g pair, +sp ring attention) then FFN or MoE. Shared by
     the layer loop below and the pipeline-parallel stage scan
-    (models/pipeline.py)."""
+    (models/pipeline.py). `pos_offset` is the global position of the
+    shard's first token (RoPE under sp needs it; None = not given);
+    `aux`, a list, receives a MoE layer's (load-balancing loss, z-loss,
+    routing: an ops.moe.TopKRoute)."""
+    _check_supported(cfg, ax, is_moe, pos_offset)
     dt = cfg.dtype
     b, t = h.shape[0], h.shape[1]
-    x = _ln(h.astype(jnp.float32), lp["ln1"]["g"],
-            lp["ln1"]["b"]).astype(dt)
+    x = _norm(h.astype(jnp.float32), lp["ln1"], cfg).astype(dt)
     with jax.named_scope("attn_proj"):
         if ax.tp:
             x = region_enter(x, ax.tp)
         q = x @ lp["wq"].astype(dt)   # [B,T,Hl*Dh] (tp-sharded cols)
         k = x @ lp["wk"].astype(dt)
         v = x @ lp["wv"].astype(dt)
+        if cfg.qk_norm:
+            with jax.named_scope("qk_rope"):
+                q = _rms(q.astype(jnp.float32), lp["q_norm"]["g"],
+                         cfg.norm_eps).astype(dt)
+                k = _rms(k.astype(jnp.float32), lp["k_norm"]["g"],
+                         cfg.norm_eps).astype(dt)
         hl = q.shape[-1] // cfg.head_dim  # local heads under tp
         q = q.reshape(b, t, hl, cfg.head_dim)
         k = k.reshape(b, t, hl, cfg.head_dim)
         v = v.reshape(b, t, hl, cfg.head_dim)
+        if cfg.pos == "rope":
+            with jax.named_scope("qk_rope"):
+                positions = jnp.arange(t) if pos_offset is None \
+                    else pos_offset + jnp.arange(t)
+                q = rope(q, positions, cfg.rope_theta)
+                k = rope(k, positions, cfg.rope_theta)
     with jax.named_scope("attn_core"):  # scores, softmax, AV
         if ax.sp:
             if cfg.sp_schedule == "ulysses":
@@ -245,8 +394,7 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool):
             o = region_exit(o, ax.tp)
         h = h + o
 
-    x = _ln(h.astype(jnp.float32), lp["ln2"]["g"],
-            lp["ln2"]["b"]).astype(dt)
+    x = _norm(h.astype(jnp.float32), lp["ln2"], cfg).astype(dt)
     with jax.named_scope("mlp"):
         if ax.tp:
             x = region_enter(x, ax.tp)
@@ -258,22 +406,25 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool):
                     lp["w2"].astype(dt), ax.ep,
                     capacity_factor=cfg.capacity_factor)
             else:
-                y = _moe_dense(flat, lp, cfg)
+                y = _moe_sorted(flat, lp, cfg, aux)
             if ax.tp:
                 y = region_exit(y, ax.tp)
             y = y.reshape(b, t, cfg.d_model)
         else:
-            u = jnp.maximum(x @ lp["w1"].astype(dt), 0)
+            u = moe_mod.activation(cfg.mlp_act)(x @ lp["w1"].astype(dt))
+            if cfg.mlp_gated:
+                u = u * (x @ lp["w3"].astype(dt))
             y = u @ lp["w2"].astype(dt)
             if ax.tp:
                 y = region_exit(y, ax.tp)
         return h + y
 
 
-def forward_local(params, tokens, cfg: Config, ax: Axes):
+def forward_local(params, tokens, cfg: Config, ax: Axes, aux=None):
     """Forward pass on local shards (inside shard_map when any axis is
     set). tokens: [B_local, T_local] int32 -> logits [B_local, T_local,
-    vocab] float32."""
+    vocab] float32. `aux`, a list, receives each MoE layer's
+    (load-balancing loss, z-loss, routing)."""
     dt = cfg.dtype
     b, t = tokens.shape
     # global sequence offset of this sp shard
@@ -283,41 +434,36 @@ def forward_local(params, tokens, cfg: Config, ax: Axes):
         t_off = 0
     with jax.named_scope("embed"):
         h = params["embed"].astype(dt)[tokens]
-        pos = lax.dynamic_slice_in_dim(params["pos"], t_off, t, axis=0) \
-            if ax.sp else params["pos"][:t]
-        h = h + pos.astype(dt)[None]
+        if cfg.pos == "learned":
+            pos = lax.dynamic_slice_in_dim(
+                params["pos"], t_off, t, axis=0) \
+                if ax.sp else params["pos"][:t]
+            h = h + pos.astype(dt)[None]
 
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope(f"layer_{i}"):
-            h = layer_forward(lp, h, cfg, ax, _is_moe(cfg, i))
+            h = layer_forward(lp, h, cfg, ax, _is_moe(cfg, i),
+                              pos_offset=t_off, aux=aux)
 
     with jax.named_scope("head_loss"):
-        h = _ln(h.astype(jnp.float32), params["ln_f"]["g"],
-                params["ln_f"]["b"])
-        # weight-tied head: bf16 operands at full MXU rate, f32
-        # accumulation (the vocab matmul is the single largest matmul
-        # in the model; an f32xf32 product here runs at half the
-        # systolic-array throughput)
-        return jnp.einsum("btd,vd->btv", h.astype(dt),
-                          params["embed"].astype(dt),
+        h = _norm(h.astype(jnp.float32), params["ln_f"], cfg)
+        # the head (tied: the embedding's transpose): bf16 operands at
+        # full MXU rate, f32 accumulation (the vocab matmul is the
+        # single largest matmul in the model; an f32xf32 product here
+        # runs at half the systolic-array throughput)
+        head = params["embed"] if cfg.tie_head else params["head"]
+        return jnp.einsum("btd,vd->btv", h.astype(dt), head.astype(dt),
                           preferred_element_type=jnp.float32)
-
-
-def _moe_dense(flat, lp, cfg: Config):
-    """Single-device MoE (no ep axis): dense einsum over all experts."""
-    cap = max(int(cfg.capacity_factor * flat.shape[0] / cfg.n_experts), 1)
-    route = moe_mod.top1_routing(flat @ lp["wg"].astype(flat.dtype), cap)
-    slots = jnp.einsum("tec,td->ecd", route.dispatch,
-                       flat.astype(jnp.float32))
-    hidden = jnp.maximum(jnp.einsum("ecd,edf->ecf", slots, lp["w1"]), 0)
-    out = jnp.einsum("ecf,efd->ecd", hidden, lp["w2"])
-    return jnp.einsum("tec,ecd->td", route.combine, out).astype(flat.dtype)
 
 
 def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
     """Summed next-token CE over local tokens + local count (caller
-    normalizes after cross-shard psum)."""
-    logits = forward_local(params, tokens, cfg, ax)
+    normalizes after cross-shard psum). Where the config weighs the
+    router's losses, their mean over the MoE layers times the local
+    count is in the sum, so the caller's nll / count is the mean CE
+    plus the weighted router losses."""
+    aux = [] if (cfg.router_aux_weight or cfg.router_z_weight) else None
+    logits = forward_local(params, tokens, cfg, ax, aux)
     with jax.named_scope("head_loss"):
         logits = logits.astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
@@ -325,7 +471,44 @@ def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
             logits, labels[..., None], axis=-1)[..., 0]
         mask = (labels >= 0).astype(jnp.float32)
         nll = ((logz - gold) * mask).sum()
+        if aux:
+            with jax.named_scope("moe_route"):
+                balance = sum(a[0] for a in aux) / len(aux)
+                z = sum(a[1] for a in aux) / len(aux)
+                nll = nll + mask.sum() * (cfg.router_aux_weight * balance
+                                          + cfg.router_z_weight * z)
         return nll, mask.sum()
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _route_probe(params, tokens, cfg: Config):
+    aux = []
+    forward_local(params, tokens, cfg, Axes(), aux)
+    return (jnp.stack([route.counts for _, _, route in aux]),
+            jnp.stack([route.experts for _, _, route in aux]))
+
+
+def route_counts(params, tokens, cfg: Config):
+    """int32 [MoE layers, n_experts]: how many of the batch's
+    `tokens * top_k` assignments each expert got in each MoE layer of
+    a one-device forward pass. A probe the host calls outside any
+    timed window (it waits for the result): what it counted goes to
+    the always-on counters `moe_assignments` and
+    `moe_dropped_assignments` — the second is what a capacity or an
+    exchange lost, and reads 0 on the sorted path by construction."""
+    counts = _route_probe(params, tokens, cfg)[0]
+    routed = int(counts.sum())
+    pvar.record("moe_assignments", routed)
+    pvar.record("moe_dropped_assignments",
+                counts.shape[0] * tokens.size * cfg.top_k - routed)
+    return counts
+
+
+def route_experts(params, tokens, cfg: Config):
+    """int32 [MoE layers, tokens, top_k]: the experts each token chose
+    (the same probe as :func:`route_counts`; top-k of many is discrete,
+    so this is what tells a re-routed token from a wrong one)."""
+    return _route_probe(params, tokens, cfg)[1]
 
 
 def grad_sync(grads, specs, ax: Axes, extra=None):

@@ -1,26 +1,42 @@
-"""Expert-parallel MoE dispatch/combine over all_to_all.
+"""Mixture-of-experts routing and the two ways the model runs experts.
 
+**One device (``ax.ep`` is None): the drop-free sorted path.**
+:func:`topk_routing` (float32 softmax over all experts, top-k, the
+weights as they are unless the config renormalises them, and the
+ingredients of the two router losses) and :func:`sorted_moe_ffn`: the
+``T * k`` token-expert assignments are sorted by expert (stable), the
+rows gathered into expert order, the experts run as ONE grouped matmul
+over the ragged groups (``lax.ragged_dot``: a native kernel on TPU,
+plain XLA on CPU), and the weighted rows gathered back and summed per
+token. Static shapes, no capacity, no token ever dropped, any ``k``,
+gated (``w3``) or plain ReLU experts; differentiable with respect to
+the rows, the expert weights and, through the weights, the router.
+Both permutations are gathers in the forward AND the backward pass
+(``custom_vjp``): the transpose of a permutation is its inverse, and a
+scatter-add is the slow way to say so on a TPU.
+
+**Expert parallel (``ax.ep``): capacity-based top-1 over all_to_all.**
 BASELINE.md config #5 is the MPI_Alltoall(v) MoE expert-dispatch
 pattern; the reference implements the transport (bruck/pairwise/linear
 alltoall, coll_base_alltoall.c:180-616) and leaves the model math to the
-application. TPU-native, the two fuse: dispatch = one-hot matmul (MXU)
-+ ``lax.all_to_all`` over the expert axis (ICI), experts run their FFN
-on dense [E_local, n*C, D] blocks, and combine is the inverse all_to_all
-weighted by the gates.
-
-Capacity-based top-1 (Switch-Transformer style) routing: static shapes
-(XLA requirement — no dynamic token counts), overflow tokens dropped.
-The drop is METERED: :class:`MoEDispatch` carries the drop count and
-the per-expert routed histogram, and an eager (non-traced) routing
-call records ``serve_dropped_tokens`` so capacity-factor tuning has
-data even outside the serve loop (``ompi_tpu.serve`` adds the
-overflow-handling policies on top of this router).
+application. Here the two fuse: dispatch = one-hot matmul (MXU) +
+``lax.all_to_all`` over the expert axis (ICI), experts run their FFN
+on dense [E_local, n*C, D] blocks, and combine is the inverse
+all_to_all weighted by the gates. Switch-Transformer routing
+(:func:`top1_routing`): static shapes, overflow tokens dropped. The
+drop is METERED: :class:`MoEDispatch` carries the drop count and the
+per-expert routed histogram, and an eager (non-traced) routing call
+records ``serve_dropped_tokens`` (``ompi_tpu.serve`` adds the
+overflow-handling policies on top of this router). ROADMAP R1b moves
+this path onto the sort above with an exchange in the middle.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import functools
+from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -123,3 +139,116 @@ def moe_ffn(x, wg, w1, w2, axis: str, capacity_factor: float = 1.25):
 
     route = top1_routing(x @ wg, cap)
     return ep_apply(route, x, w1, w2, axis)
+
+
+# -- one device: top-k routing and the drop-free sorted dispatch -------------
+
+class TopKRoute(NamedTuple):
+    experts: jnp.ndarray    # [T, k] int32, the chosen experts
+    weights: jnp.ndarray    # [T, k] float32, their routing weights
+    counts: jnp.ndarray     # [E] int32 assignments per expert
+    mean_prob: jnp.ndarray  # [E] P_e: mean router probability
+    lse: jnp.ndarray        # [T] logsumexp of the router logits
+
+    @property
+    def frac(self):
+        """[E] f_e: each expert's share of the T*k assignments."""
+        return self.counts.astype(jnp.float32) / self.experts.size
+
+
+def topk_routing(logits, k: int, renormalize: bool = False) -> TopKRoute:
+    """Softmax over ALL experts in float32, then the k largest.
+    logits: [T, E]. The weights are the k probabilities as they are
+    (they sum to less than 1) unless `renormalize`."""
+    logits = logits.astype(jnp.float32)
+    t, e = logits.shape
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    probs = jnp.exp(logits - lse[:, None])
+    weights, experts = lax.top_k(probs, k)
+    if renormalize:
+        weights = weights / weights.sum(-1, keepdims=True)
+    counts = (experts.reshape(t * k, 1)
+              == jnp.arange(e, dtype=experts.dtype)).sum(0, dtype=jnp.int32)
+    return TopKRoute(experts=experts, weights=weights, counts=counts,
+                     mean_prob=probs.mean(0), lse=lse)
+
+
+def load_balance_loss(route: TopKRoute):
+    """``E * sum_e f_e P_e`` (Switch / OLMoE): 1 when routing is
+    uniform; the gradient reaches the router through P_e alone."""
+    return route.counts.shape[0] * jnp.sum(route.frac * route.mean_prob)
+
+
+def router_z_loss(route: TopKRoute):
+    """``mean(logsumexp(logits) ** 2)`` (ST-MoE)."""
+    return jnp.mean(route.lse ** 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_expert_order(x, order, inv, k: int):
+    """Row i of the result is the token of the i-th assignment in
+    expert order: x[order // k]. Its transpose un-sorts and sums each
+    token's k rows — gathers both ways."""
+    return x[order // k]
+
+
+def _to_expert_order_fwd(x, order, inv, k):
+    return x[order // k], inv
+
+
+def _to_expert_order_bwd(k, inv, g):
+    return g[inv].reshape(-1, k, g.shape[-1]).sum(1), None, None
+
+
+_to_expert_order.defvjp(_to_expert_order_fwd, _to_expert_order_bwd)
+
+
+@jax.custom_vjp
+def _permute(x, perm, inv):
+    """x[perm] for a permutation and its inverse."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inv):
+    return x[perm], inv
+
+
+def _permute_bwd(inv, g):
+    return g[inv], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+_ACT = {"relu": lambda x: jnp.maximum(x, 0), "silu": jax.nn.silu,
+        "gelu": jax.nn.gelu}
+
+
+def activation(name: str):
+    """The FFN / expert activation a config names."""
+    if name not in _ACT:
+        raise ValueError(f"activation {name!r}: expected one of "
+                         f"{sorted(_ACT)}")
+    return _ACT[name]
+
+
+def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
+                   w2, act: str = "relu"):
+    """Drop-free MoE FFN on one device. x: [T, D] tokens; w1 (and the
+    gate's w3, or None for an ungated expert): [E, D, F]; w2:
+    [E, F, D]. Returns ``sum_k weight_k * expert_k(x)``, [T, D] in
+    x's type: ``act(x W1_e) * (x W3_e)`` through ``W2_e`` when gated,
+    ``act(x W1_e) W2_e`` when not."""
+    t, k = route.experts.shape
+    with jax.named_scope("moe_dispatch"):
+        order = jnp.argsort(route.experts.reshape(t * k), stable=True)
+        inv = jnp.argsort(order)
+        rows = _to_expert_order(x, order, inv, k)
+    with jax.named_scope("moe_experts"):
+        hidden = activation(act)(lax.ragged_dot(rows, w1, route.counts))
+        if w3 is not None:
+            hidden = hidden * lax.ragged_dot(rows, w3, route.counts)
+        out = lax.ragged_dot(hidden, w2, route.counts)
+    with jax.named_scope("moe_combine"):
+        out = _permute(out, inv, order).reshape(t, k, x.shape[-1])
+        return jnp.einsum("tkd,tk->td", out.astype(jnp.float32),
+                          route.weights).astype(x.dtype)
