@@ -41,6 +41,9 @@ WELL_KNOWN = (
     # a program's FIRST launch, where jax compiles it or loads it from
     # the persistent cache (always timed: once per cache key)
     "coll_xla_cold_launches", "coll_xla_cold_launch_ns",
+    # ops/attention.attention, once per TRACED attention (the choice
+    # is static inside jit): the blockwise kernel, or att.mha
+    "attn_blockwise_layers", "attn_reference_layers",
     # the phases of mpi.Init(), once per job (runtime/state.py,
     # runtime/device_plane.py; "import" also holds the import of
     # ompi_tpu.mpi itself): they end before any profiler session can

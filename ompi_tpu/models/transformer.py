@@ -345,16 +345,28 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
     dt = cfg.dtype
     b, t = h.shape[0], h.shape[1]
     x = _norm(h.astype(jnp.float32), lp["ln1"], cfg).astype(dt)
+    # The blockwise kernel takes q already scaled. Where it will run
+    # (the rule att.attention applies below), 1/sqrt(Dh) goes in where q
+    # is still float32 — the projection's accumulator or the QK-norm —
+    # so q is rounded to dt once, as it is for att.mha.
+    q_scale = cfg.head_dim ** -0.5 if not ax.sp and att.blockwise_tile(
+        jax.default_backend(), t, t, cfg.head_dim) else None
     with jax.named_scope("attn_proj"):
         if ax.tp:
             x = region_enter(x, ax.tp)
-        q = x @ lp["wq"].astype(dt)   # [B,T,Hl*Dh] (tp-sharded cols)
+        if q_scale and not cfg.qk_norm:
+            q = (jnp.dot(x, lp["wq"].astype(dt),
+                         preferred_element_type=jnp.float32)
+                 * q_scale).astype(dt)
+        else:
+            q = x @ lp["wq"].astype(dt)  # [B,T,Hl*Dh] (tp-sharded cols)
         k = x @ lp["wk"].astype(dt)
         v = x @ lp["wv"].astype(dt)
         if cfg.qk_norm:
             with jax.named_scope("qk_rope"):
                 q = _rms(q.astype(jnp.float32), lp["q_norm"]["g"],
-                         cfg.norm_eps).astype(dt)
+                         cfg.norm_eps)
+                q = (q * q_scale if q_scale else q).astype(dt)
                 k = _rms(k.astype(jnp.float32), lp["k_norm"]["g"],
                          cfg.norm_eps).astype(dt)
         hl = q.shape[-1] // cfg.head_dim  # local heads under tp
@@ -380,13 +392,8 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
                     f"sp_schedule={cfg.sp_schedule!r}: expected 'ring' "
                     "or 'ulysses'")
         else:
-            # reference mha, not the pallas flash kernel: measured on
-            # the v5e at T=1024 the kernel is ~4% SLOWER (XLA's fused
-            # softmax wins while the T x T score tensor is small);
-            # att.mha_auto remains available for long-context
-            # single-device use where the score materialization
-            # dominates
-            o = att.mha(q, k, v, causal=True)
+            o = att.attention(q, k, v, causal=True,
+                              scale=1.0 if q_scale else None)
     with jax.named_scope("attn_proj"):
         o = o.reshape(b, t, hl * cfg.head_dim)
         o = o @ lp["wo"].astype(dt)   # row parallel: partial sums

@@ -60,5 +60,5 @@ def ulysses_attention(q, k, v, axis: str, causal: bool = True,
                          concat_axis=2, tiled=True)
     # exact full-sequence attention on the head subset (global
     # positions are the natural ones after the gather)
-    oh = att.mha(qkv[0], qkv[1], qkv[2], causal=causal, scale=scale)
+    oh = att.attention(qkv[0], qkv[1], qkv[2], causal=causal, scale=scale)
     return _heads_to_seq(oh, axis).astype(q.dtype)

@@ -32,6 +32,7 @@ device job never continues on host staging behind the user's back.
 
 from __future__ import annotations
 
+import importlib
 import socket
 import threading
 from typing import Dict, Optional
@@ -128,6 +129,15 @@ def _bootstrap(platform: str) -> Optional[str]:
         return why
     import jax
 
+    if platform == "tpu":
+        # jax's import of its Pallas packages takes ~1 s of Python, paid
+        # at first use by whoever runs a kernel on this plane (the
+        # model's blockwise attention, coll/pallas, osc/pallas). The
+        # TPU client's start below waits 6-10 s outside the
+        # interpreter: the import runs beside it.
+        threading.Thread(target=importlib.import_module,
+                         args=("jax.experimental.pallas.tpu",),
+                         name="pallas-import", daemon=True).start()
     with init_phase("devplane.client"):
         try:
             dev = jax.local_devices()[0]
