@@ -39,8 +39,8 @@ comm = mpi.Init()
 rank, size = comm.rank, comm.size
 
 # phase ledger (no-op unless --mca prof_enable 1): setup/optimizer
-# construction is "staging", the step loop is "train" — the same
-# attribution bench.py reports and python -m ompi_tpu.prof merges
+# construction is "staging", the step loop is "train" — the
+# attribution python -m ompi_tpu.prof merges
 with prof.phase("staging"):
     params = {
         "embed": jnp.ones((256, 32), jnp.float32),

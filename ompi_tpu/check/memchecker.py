@@ -1,8 +1,7 @@
 """memchecker — buffer definedness shadow-tracking (race tooling).
 
-Lives in the check plane since the correctness-plane refactor (the
-former home, ``ompi_tpu/core/memchecker.py``, remains as a compat
-shim re-exporting this module).
+Lives in the check plane: the reference's opal/mca/memchecker is a
+correctness tool, not core infrastructure.
 
 Reference: opal/mca/memchecker/valgrind + the ``MEMCHECKER()``
 annotations every API binding carries (ompi/mpi/c/allreduce.c:52-66):

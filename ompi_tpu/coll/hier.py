@@ -50,6 +50,7 @@ monitoring report renders to answer "which level is the bottleneck".
 
 from __future__ import annotations
 
+import functools
 import json
 from typing import Dict, Optional
 
@@ -57,6 +58,7 @@ import numpy as np
 
 from ompi_tpu import errors, op as op_mod
 from ompi_tpu.coll import CollModule, framework
+from ompi_tpu.coll import dispatch as _dispatch
 from ompi_tpu.coll import pallas as _pallas
 from ompi_tpu.coll import pallas_kernels as K
 from ompi_tpu.coll import xla as _xla
@@ -64,7 +66,6 @@ from ompi_tpu.core import cvar, output, pvar
 from ompi_tpu.monitoring import algo as _algo
 from ompi_tpu.monitoring import matrix as _mon
 from ompi_tpu.parallel import hierarchical as H
-from ompi_tpu.telemetry import flight as _flight
 from ompi_tpu.trace import recorder as _trace
 from ompi_tpu.tune import observe as _tobs
 
@@ -388,26 +389,24 @@ def _smap(ctx, plan: _Plan, body, out_varying: bool):
                     spec=ctx.P((H.DCN_AXIS, H.ICI_AXIS)))
 
 
-def _launch(launcher, op: str, plan: _Plan, comm=None, nbytes=0,
-            dtype: str = ""):
-    """Dispatch, with a coll_hier trace span naming the grid (the xla
-    launch funnel inside adds its own span) and a tune-plane sample
-    under provider 'hier', mesh (n_dcn, n_ici), when the observatory
-    is up."""
+def _launch(launcher, op: str, plan: _Plan, comm, nbytes: int,
+            dtype: str, **levels):
+    """Account one two-level launch per level (``levels``: what
+    ``_account`` takes) and run the prepared launcher through the
+    dispatch seam (provider 'hier', mesh (n_dcn, n_ici)) under a
+    coll_hier trace span naming the grid (the xla launch funnel
+    inside adds its own span)."""
+    peers = _account(op, comm, nbytes, dtype, plan, **levels)
     if not _pallas._interpret():
         compiled = launcher
         launcher = lambda: K.compiled_or_raise(  # noqa: E731
             f"coll_hier {op}", compiled)
-    obs = _tobs.OBSERVER
-    if obs is not None:
-        launcher = obs.timed("hier", op, "hier", comm, nbytes, dtype,
-                             launcher,
-                             mesh=(plan.n_dcn, plan.n_ici))
-    if not _trace.active():
-        return launcher()
     with _trace.span("launch", "coll_hier", op=op,
                      grid=f"{plan.n_dcn}x{plan.n_ici}"):
-        return launcher()
+        return _dispatch.run(
+            "hier", op, comm, None, launcher, nbytes=nbytes,
+            dtype=dtype, algorithm="hier",
+            mesh=(plan.n_dcn, plan.n_ici), per_peer=peers)
 
 
 def _itemsize(dtype: str) -> int:
@@ -424,11 +423,12 @@ def _itemsize(dtype: str) -> int:
 
 def _account(kind: str, comm, nbytes: int, dtype: str, plan: _Plan,
              linear: bool = False, wire: Optional[str] = None,
-             parts=None) -> None:
-    """Per-level attribution: the launch and per-level byte pvars
-    (nominal DCN model + actual wire bytes), the link map split
-    across the ICI-axis and DCN-axis neighbor edges, and the
-    per-level totals the report renders. ``parts`` — a list of
+             parts=None) -> dict:
+    """Per-level attribution: the per-level byte pvars (nominal DCN
+    model + actual wire bytes), the per-level totals the report
+    renders, and — returned, for the dispatch seam to account — the
+    link map split across the ICI-axis and DCN-axis neighbor edges.
+    ``parts`` — a list of
     (nbytes, dtype, wire) — covers the fused multi path, whose
     dtype-segregated buckets can mix compressed float and exact int
     payloads in one launch; the models are linear in nbytes, so the
@@ -450,14 +450,13 @@ def _account(kind: str, comm, nbytes: int, dtype: str, plan: _Plan,
                 kind, comm.rank, plan.n_dcn, plan.n_ici, nb,
                 linear=linear, wire=w, itemsize=isz).items():
             peers[peer] = peers.get(peer, 0.0) + b
-    pvar.record("hier_launches")
     pvar.record("hier_ici_bytes", int(ici_b))
     pvar.record("hier_dcn_bytes", int(dcn_b))
     pvar.record("hier_dcn_wire_bytes", int(wire_b))
     tm = _mon.TRAFFIC
     if tm is not None:
-        tm.coll(kind, comm, nbytes, dtype=dtype, per_peer=peers)
         tm.hier(kind, ici_b, dcn_b, wire_b)
+    return peers
 
 
 # ---------------------------------------------------------------------------
@@ -542,8 +541,7 @@ def _allreduce_prep(comm, sendbuf, opn, det: Optional[str],
             _xla._key(sendbuf, "hier_allreduce", "split", opn.name,
                       plan.n_dcn, plan.n_ici, inner, interp, wire),
             build)
-    g = ctx.to_global(sendbuf, plan.sharding)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, sendbuf, plan.sharding)
 
 
 def allreduce_dev(comm, sendbuf, op=op_mod.SUM,
@@ -553,30 +551,18 @@ def allreduce_dev(comm, sendbuf, op=op_mod.SUM,
             or not hasattr(sendbuf, "shape"):
         return _fallthrough(comm, "allreduce_dev", sendbuf, op,
                             deterministic)
-    plan = _select("allreduce", comm, int(sendbuf.nbytes),
-                   str(sendbuf.dtype), det)
+    nb, dt = int(sendbuf.nbytes), str(sendbuf.dtype)
+    plan = _select("allreduce", comm, nb, dt, det)
     if plan is None:
         return _fallthrough(comm, "allreduce_dev", sendbuf, op,
                             deterministic)
     opn = op if isinstance(op, op_mod.Op) else op_mod.BUILTIN[op]
     # resolve the wire format BEFORE accounting: an unknown
     # coll_hier_dcn_dtype raises here, per call, with nothing counted
-    wire = _wire_dtype("allreduce", str(sendbuf.dtype), det, opn)
-    _account("allreduce", comm, int(sendbuf.nbytes),
-             str(sendbuf.dtype), plan, linear=det == "linear",
-             wire=wire)
-    launcher = _allreduce_prep(comm, sendbuf, opn, det, plan, wire)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return _launch(launcher, "allreduce", plan, comm,
-                       int(sendbuf.nbytes), str(sendbuf.dtype))
-    tok = fl.enter("allreduce_dev", getattr(comm, "cid", -1),
-                   getattr(sendbuf, "nbytes", 0))
-    try:
-        return _launch(launcher, "allreduce", plan, comm,
-                       int(sendbuf.nbytes), str(sendbuf.dtype))
-    finally:
-        fl.exit(tok)
+    wire = _wire_dtype("allreduce", dt, det, opn)
+    return _launch(_allreduce_prep(comm, sendbuf, opn, det, plan, wire),
+                   "allreduce", plan, comm, nb, dt,
+                   linear=det == "linear", wire=wire)
 
 
 def _bcast_prep(comm, buf, root: int, plan: _Plan):
@@ -591,30 +577,18 @@ def _bcast_prep(comm, buf, root: int, plan: _Plan):
 
     fn = ctx.compiled(_xla._key(buf, "hier_bcast", root, plan.n_dcn,
                                 plan.n_ici), build)
-    g = ctx.to_global(buf, plan.sharding)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, buf, plan.sharding)
 
 
 def bcast_dev(comm, buf, root: int = 0):
     if comm.size == 1 or not hasattr(buf, "shape"):
         return _fallthrough(comm, "bcast_dev", buf, root)
-    plan = _select("bcast", comm, int(buf.nbytes), str(buf.dtype),
-                   None)
+    nb, dt = int(buf.nbytes), str(buf.dtype)
+    plan = _select("bcast", comm, nb, dt, None)
     if plan is None:
         return _fallthrough(comm, "bcast_dev", buf, root)
-    _account("bcast", comm, int(buf.nbytes), str(buf.dtype), plan)
-    launcher = _bcast_prep(comm, buf, root, plan)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return _launch(launcher, "bcast", plan, comm,
-                       int(buf.nbytes), str(buf.dtype))
-    tok = fl.enter("bcast_dev", getattr(comm, "cid", -1),
-                   getattr(buf, "nbytes", 0))
-    try:
-        return _launch(launcher, "bcast", plan, comm,
-                       int(buf.nbytes), str(buf.dtype))
-    finally:
-        fl.exit(tok)
+    return _launch(_bcast_prep(comm, buf, root, plan), "bcast", plan,
+                   comm, nb, dt)
 
 
 def _allgather_prep(comm, sendbuf, plan: _Plan):
@@ -626,31 +600,18 @@ def _allgather_prep(comm, sendbuf, plan: _Plan):
 
     fn = ctx.compiled(_xla._key(sendbuf, "hier_allgather",
                                 plan.n_dcn, plan.n_ici), build)
-    g = ctx.to_global(sendbuf, plan.sharding)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, sendbuf, plan.sharding)
 
 
 def allgather_dev(comm, sendbuf):
     if comm.size == 1 or not hasattr(sendbuf, "shape"):
         return _fallthrough(comm, "allgather_dev", sendbuf)
-    plan = _select("allgather", comm, int(sendbuf.nbytes),
-                   str(sendbuf.dtype), None)
+    nb, dt = int(sendbuf.nbytes), str(sendbuf.dtype)
+    plan = _select("allgather", comm, nb, dt, None)
     if plan is None:
         return _fallthrough(comm, "allgather_dev", sendbuf)
-    _account("allgather", comm, int(sendbuf.nbytes),
-             str(sendbuf.dtype), plan)
-    launcher = _allgather_prep(comm, sendbuf, plan)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return _launch(launcher, "allgather", plan, comm,
-                       int(sendbuf.nbytes), str(sendbuf.dtype))
-    tok = fl.enter("allgather_dev", getattr(comm, "cid", -1),
-                   getattr(sendbuf, "nbytes", 0))
-    try:
-        return _launch(launcher, "allgather", plan, comm,
-                       int(sendbuf.nbytes), str(sendbuf.dtype))
-    finally:
-        fl.exit(tok)
+    return _launch(_allgather_prep(comm, sendbuf, plan), "allgather",
+                   plan, comm, nb, dt)
 
 
 def _alltoall_prep(comm, sendbuf, plan: _Plan):
@@ -664,8 +625,7 @@ def _alltoall_prep(comm, sendbuf, plan: _Plan):
 
     fn = ctx.compiled(_xla._key(sendbuf, "hier_alltoall",
                                 plan.n_dcn, plan.n_ici), build)
-    g = ctx.to_global(sendbuf, plan.sharding)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, sendbuf, plan.sharding)
 
 
 def alltoall_dev(comm, sendbuf):
@@ -674,24 +634,12 @@ def alltoall_dev(comm, sendbuf):
         # indivisible dim0 falls through: coll/xla raises the same
         # MPIError(ERR_COUNT) the flat contract specifies
         return _fallthrough(comm, "alltoall_dev", sendbuf)
-    plan = _select("alltoall", comm, int(sendbuf.nbytes),
-                   str(sendbuf.dtype), None)
+    nb, dt = int(sendbuf.nbytes), str(sendbuf.dtype)
+    plan = _select("alltoall", comm, nb, dt, None)
     if plan is None:
         return _fallthrough(comm, "alltoall_dev", sendbuf)
-    _account("alltoall", comm, int(sendbuf.nbytes),
-             str(sendbuf.dtype), plan)
-    launcher = _alltoall_prep(comm, sendbuf, plan)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return _launch(launcher, "alltoall", plan, comm,
-                       int(sendbuf.nbytes), str(sendbuf.dtype))
-    tok = fl.enter("alltoall_dev", getattr(comm, "cid", -1),
-                   getattr(sendbuf, "nbytes", 0))
-    try:
-        return _launch(launcher, "alltoall", plan, comm,
-                       int(sendbuf.nbytes), str(sendbuf.dtype))
-    finally:
-        fl.exit(tok)
+    return _launch(_alltoall_prep(comm, sendbuf, plan), "alltoall",
+                   plan, comm, nb, dt)
 
 
 def _reduce_scatter_block_prep(comm, sendbuf, opn,
@@ -710,8 +658,7 @@ def _reduce_scatter_block_prep(comm, sendbuf, opn,
 
     fn = ctx.compiled(_xla._key(sendbuf, "hier_rsb", opn.name, det,
                                 plan.n_dcn, plan.n_ici, wire), build)
-    g = ctx.to_global(sendbuf, plan.sharding)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, sendbuf, plan.sharding)
 
 
 def reduce_scatter_block_dev(comm, sendbuf, op=op_mod.SUM,
@@ -722,31 +669,17 @@ def reduce_scatter_block_dev(comm, sendbuf, op=op_mod.SUM,
             or sendbuf.shape[0] % comm.size:
         return _fallthrough(comm, "reduce_scatter_block_dev", sendbuf,
                             op, deterministic)
-    plan = _select("reduce_scatter_block", comm, int(sendbuf.nbytes),
-                   str(sendbuf.dtype), det)
+    nb, dt = int(sendbuf.nbytes), str(sendbuf.dtype)
+    plan = _select("reduce_scatter_block", comm, nb, dt, det)
     if plan is None:
         return _fallthrough(comm, "reduce_scatter_block_dev", sendbuf,
                             op, deterministic)
     opn = op if isinstance(op, op_mod.Op) else op_mod.BUILTIN[op]
-    wire = _wire_dtype("reduce_scatter_block", str(sendbuf.dtype),
-                       det, opn)
-    _account("reduce_scatter_block", comm, int(sendbuf.nbytes),
-             str(sendbuf.dtype), plan, linear=det == "linear",
-             wire=wire)
-    launcher = _reduce_scatter_block_prep(comm, sendbuf, opn, det,
-                                          plan, wire)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return _launch(launcher, "reduce_scatter_block", plan, comm,
-                       int(sendbuf.nbytes), str(sendbuf.dtype))
-    tok = fl.enter("reduce_scatter_block_dev",
-                   getattr(comm, "cid", -1),
-                   getattr(sendbuf, "nbytes", 0))
-    try:
-        return _launch(launcher, "reduce_scatter_block", plan, comm,
-                       int(sendbuf.nbytes), str(sendbuf.dtype))
-    finally:
-        fl.exit(tok)
+    wire = _wire_dtype("reduce_scatter_block", dt, det, opn)
+    return _launch(
+        _reduce_scatter_block_prep(comm, sendbuf, opn, det, plan, wire),
+        "reduce_scatter_block", plan, comm, nb, dt,
+        linear=det == "linear", wire=wire)
 
 
 # ---------------------------------------------------------------------------
@@ -872,19 +805,10 @@ def allreduce_multi_dev(comm, bufs, op=op_mod.SUM,
                             deterministic)
     opn = op if isinstance(op, op_mod.Op) else op_mod.BUILTIN[op]
     _, treedef = jax.tree.flatten(bufs)
-    _account("allreduce_multi", comm, nb, dt, plan,
-             linear=det == "linear",
-             parts=_multi_parts(leaves, det, opn))
-    launcher = _hier_fuse_prep(comm, leaves, treedef, opn, det, plan)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return _launch(launcher, "allreduce_multi", plan, comm, nb, dt)
-    tok = fl.enter("allreduce_multi_dev", getattr(comm, "cid", -1),
-                   nb)
-    try:
-        return _launch(launcher, "allreduce_multi", plan, comm, nb, dt)
-    finally:
-        fl.exit(tok)
+    return _launch(
+        _hier_fuse_prep(comm, leaves, treedef, opn, det, plan),
+        "allreduce_multi", plan, comm, nb, dt,
+        linear=det == "linear", parts=_multi_parts(leaves, det, opn))
 
 
 # ---------------------------------------------------------------------------
@@ -906,15 +830,10 @@ def _allreduce_pprep(comm, sendbuf, op=op_mod.SUM,
     # wire format resolves at init time, like the plan: a persistent
     # handle keeps the schedule it was built with across Start() calls
     wire = _wire_dtype("allreduce", str(sendbuf.dtype), det, opn)
-    raw = _allreduce_prep(comm, sendbuf, opn, det, plan, wire)
-    nb, dt = int(sendbuf.nbytes), str(sendbuf.dtype)
-
-    def run():
-        _account("allreduce", comm, nb, dt, plan,
-                 linear=det == "linear", wire=wire)
-        return raw()
-
-    return run
+    return functools.partial(
+        _launch, _allreduce_prep(comm, sendbuf, opn, det, plan, wire),
+        "allreduce", plan, comm, int(sendbuf.nbytes),
+        str(sendbuf.dtype), linear=det == "linear", wire=wire)
 
 
 def _allreduce_multi_pprep(comm, bufs, op=op_mod.SUM,
@@ -934,15 +853,10 @@ def _allreduce_multi_pprep(comm, bufs, op=op_mod.SUM,
     # per-bucket wire resolves inside _hier_fuse_prep at init time;
     # the accounting parts are captured alongside so every Start()
     # reports what the frozen schedule actually transmits
-    parts = _multi_parts(leaves, det, opn)
-    raw = _hier_fuse_prep(comm, leaves, treedef, opn, det, plan)
-
-    def run():
-        _account("allreduce_multi", comm, nb, dt, plan,
-                 linear=det == "linear", parts=parts)
-        return raw()
-
-    return run
+    return functools.partial(
+        _launch, _hier_fuse_prep(comm, leaves, treedef, opn, det, plan),
+        "allreduce_multi", plan, comm, nb, dt, linear=det == "linear",
+        parts=_multi_parts(leaves, det, opn))
 
 
 allreduce_init_dev = _xla._pprep(

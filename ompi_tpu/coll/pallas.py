@@ -19,8 +19,8 @@ measured switchpoints, coll_tuned_decision_fixed.c):
   (bit-identical to coll/xla's ring mode).
 - otherwise a forced ``coll_pallas_*_algorithm`` cvar wins, then a
   ``coll_pallas_switchpoints`` table entry keyed (op, log2-size,
-  dtype, mesh-shape) — the same key the monitoring plane records and
-  ``bench.py --pallas`` emits — then the built-in size threshold
+  dtype, mesh-shape) — the same key the monitoring and tune planes
+  record — then the built-in size threshold
   (bidirectional ring at/above ``coll_pallas_bidir_min_bytes``).
 
 Staged fallthrough: any unsupported (dtype, op, shape, mesh) case —
@@ -40,12 +40,11 @@ from typing import Optional
 
 from ompi_tpu import errors, op as op_mod
 from ompi_tpu.coll import CollModule, framework
+from ompi_tpu.coll import dispatch as _dispatch
 from ompi_tpu.coll import pallas_kernels as K
 from ompi_tpu.coll import xla as _xla
 from ompi_tpu.core import cvar, output, pvar
 from ompi_tpu.monitoring import algo as _algo
-from ompi_tpu.monitoring import matrix as _mon
-from ompi_tpu.telemetry import flight as _flight
 from ompi_tpu.trace import recorder as _trace
 from ompi_tpu.tune import observe as _tobs
 from ompi_tpu.util import jaxcompat
@@ -119,8 +118,8 @@ _dma_max_var = cvar.register(
     level=6)
 _switch_var = cvar.register(
     "coll_pallas_switchpoints", "", str,
-    help="Path to a measured switchpoint table (the JSON emitted by "
-         "`bench.py --pallas` under extra.pallas.switchpoints): a "
+    help="Path to a measured switchpoint table (the candidate JSON "
+         "`python -m ompi_tpu.tune report --tables` writes): a "
          "list of {op, dtype, mesh, log2, algorithm} rules; for each "
          "(op, dtype, mesh) the rule with the largest log2 <= the "
          "payload's log2 bucket wins ('xla' falls through). Empty "
@@ -266,39 +265,28 @@ def _select(kind: str, comm, sendbuf, det: Optional[str],
     return "ring"
 
 
-def _launch(launcher, op: str, algo: str, comm=None, buf=None,
+def _launch(launcher, op: str, algo: str, comm, buf, kind=None,
             nbytes=None):
-    """Dispatch, with a coll_pallas trace span naming the chosen
-    algorithm (the xla launch funnel inside adds its own span) and a
-    tune-plane sample under provider 'pallas' when the observatory
-    is up (`nbytes` overrides `buf.nbytes` for multi-buffer ops)."""
+    """Run a prepared kernel launcher through the dispatch seam
+    (provider 'pallas', the chosen algorithm, traffic by the
+    hand-rolled schedule's own per-peer model under ``kind``, the
+    collective the op moves its bytes as) under a coll_pallas trace
+    span naming the algorithm (the xla launch funnel inside adds its
+    own span). ``nbytes`` overrides ``buf.nbytes`` for multi-buffer
+    ops."""
+    kind = kind or op
+    nb = int(getattr(buf, "nbytes", 0) if nbytes is None else nbytes)
+    pvar.record(_BYTES_PVAR[algo], nb)
     if not _interpret():
         compiled = launcher
         launcher = lambda: K.compiled_or_raise(  # noqa: E731
             f"coll_pallas {op}/{algo}", compiled)
-    obs = _tobs.OBSERVER
-    if obs is not None:
-        launcher = obs.timed(
-            "pallas", op, algo, comm,
-            int(getattr(buf, "nbytes", 0) if nbytes is None
-                else nbytes),
-            str(getattr(buf, "dtype", "")), launcher)
-    if not _trace.active():
-        return launcher()
     with _trace.span("launch", "coll_pallas", op=op, algorithm=algo):
-        return launcher()
-
-
-def _account(kind: str, comm, sendbuf, algo: str) -> None:
-    nbytes = int(getattr(sendbuf, "nbytes", 0))
-    pvar.record("pallas_launches")
-    pvar.record(_BYTES_PVAR[algo], nbytes)
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        tm.coll(kind, comm, nbytes,
-                dtype=str(getattr(sendbuf, "dtype", "")),
-                per_peer=_algo.pallas_per_peer(
-                    kind, algo, comm.rank, comm.size, nbytes))
+        return _dispatch.run(
+            "pallas", op, comm, buf, launcher, nbytes=nb,
+            algorithm=algo, kind=kind,
+            per_peer=lambda: _algo.pallas_per_peer(
+                kind, algo, comm.rank, comm.size, nb))
 
 
 # ---------------------------------------------------------------------------
@@ -325,8 +313,7 @@ def _allreduce_prep(comm, sendbuf, opn, algo: str):
     fn = ctx.compiled(
         _xla._key(sendbuf, "pallas_allreduce", algo, opn.name, interp),
         build)
-    g = ctx.to_global(sendbuf)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, sendbuf)
 
 
 def allreduce_dev(comm, sendbuf, op=op_mod.SUM,
@@ -342,17 +329,8 @@ def allreduce_dev(comm, sendbuf, op=op_mod.SUM,
     if algo is None:
         return _fallthrough(_xla.allreduce_dev, comm, sendbuf, op,
                             deterministic)
-    _account("allreduce", comm, sendbuf, algo)
-    launcher = _allreduce_prep(comm, sendbuf, opn, algo)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return _launch(launcher, "allreduce", algo, comm, sendbuf)
-    tok = fl.enter("allreduce_dev", getattr(comm, "cid", -1),
-                   getattr(sendbuf, "nbytes", 0))
-    try:
-        return _launch(launcher, "allreduce", algo, comm, sendbuf)
-    finally:
-        fl.exit(tok)
+    return _launch(_allreduce_prep(comm, sendbuf, opn, algo),
+                   "allreduce", algo, comm, sendbuf)
 
 
 def _allgather_prep(comm, sendbuf, algo: str):
@@ -376,8 +354,7 @@ def _allgather_prep(comm, sendbuf, algo: str):
 
     fn = ctx.compiled(_xla._key(sendbuf, "pallas_allgather", algo,
                                 interp), build)
-    g = ctx.to_global(sendbuf)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, sendbuf)
 
 
 def allgather_dev(comm, sendbuf):
@@ -387,17 +364,8 @@ def allgather_dev(comm, sendbuf):
                    int(getattr(sendbuf, "size", 0)))
     if algo is None:
         return _fallthrough(_xla.allgather_dev, comm, sendbuf)
-    _account("allgather", comm, sendbuf, algo)
-    launcher = _allgather_prep(comm, sendbuf, algo)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return _launch(launcher, "allgather", algo, comm, sendbuf)
-    tok = fl.enter("allgather_dev", getattr(comm, "cid", -1),
-                   getattr(sendbuf, "nbytes", 0))
-    try:
-        return _launch(launcher, "allgather", algo, comm, sendbuf)
-    finally:
-        fl.exit(tok)
+    return _launch(_allgather_prep(comm, sendbuf, algo), "allgather",
+                   algo, comm, sendbuf)
 
 
 def _reduce_scatter_prep(comm, sendbuf, opn, algo: str):
@@ -423,8 +391,7 @@ def _reduce_scatter_prep(comm, sendbuf, opn, algo: str):
 
     fn = ctx.compiled(_xla._key(sendbuf, "pallas_rsb", algo, opn.name,
                                 interp), build)
-    g = ctx.to_global(sendbuf)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, sendbuf)
 
 
 def reduce_scatter_block_dev(comm, sendbuf, op=op_mod.SUM,
@@ -445,20 +412,8 @@ def reduce_scatter_block_dev(comm, sendbuf, op=op_mod.SUM,
     if algo is None:
         return _fallthrough(_xla.reduce_scatter_block_dev, comm,
                             sendbuf, op, deterministic)
-    _account("reduce_scatter_block", comm, sendbuf, algo)
-    launcher = _reduce_scatter_prep(comm, sendbuf, opn, algo)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return _launch(launcher, "reduce_scatter_block", algo, comm,
-                       sendbuf)
-    tok = fl.enter("reduce_scatter_block_dev",
-                   getattr(comm, "cid", -1),
-                   getattr(sendbuf, "nbytes", 0))
-    try:
-        return _launch(launcher, "reduce_scatter_block", algo, comm,
-                       sendbuf)
-    finally:
-        fl.exit(tok)
+    return _launch(_reduce_scatter_prep(comm, sendbuf, opn, algo),
+                   "reduce_scatter_block", algo, comm, sendbuf)
 
 
 # ---------------------------------------------------------------------------
@@ -584,19 +539,6 @@ def fused_rs_update_dev(comm, grads, pshards, mshards, *,
         vg = ctx.to_global(mshards.shards[b]) if with_mom else None
         launches.append((fn, (gs, pg, vg), b))
 
-    nbytes = plan.nbytes
-    pvar.record("pallas_launches")
-    pvar.record(_BYTES_PVAR["linear" if det == "linear" else "ring"],
-                int(nbytes))
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        tm.coll("reduce_scatter_multi", comm, nbytes,
-                dtype=str(plan.dtypes[0]) if plan.dtypes else "",
-                per_peer=_algo.pallas_per_peer(
-                    "reduce_scatter_multi",
-                    "linear" if det == "linear" else "ring",
-                    comm.rank, comm.size, nbytes))
-
     import numpy as np
 
     def run():
@@ -630,7 +572,8 @@ def fused_rs_update_dev(comm, grads, pshards, mshards, *,
         return ps, ms
 
     return _launch(run, "fused_rs_update", det or "ring", comm,
-                   leaves[0], nbytes=plan.nbytes)
+                   leaves[0], kind="reduce_scatter_multi",
+                   nbytes=plan.nbytes)
 
 
 def _allgather_matmul_prep(comm, x, w):
@@ -674,18 +617,10 @@ def allgather_matmul_dev(comm, x, w):
         full = jnp.asarray(gathered).reshape(
             (comm.size * x.shape[0],) + tuple(x.shape[1:]))
         return jnp.dot(full, w)
-    _account("allgather", comm, x, "ring")
     pvar.record("pallas_fused_launches")
-    launcher = _allgather_matmul_prep(comm, x, w)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return _launch(launcher, "allgather_matmul", "ring", comm, x)
-    tok = fl.enter("allgather_matmul_dev", getattr(comm, "cid", -1),
-                   getattr(x, "nbytes", 0))
-    try:
-        return _launch(launcher, "allgather_matmul", "ring", comm, x)
-    finally:
-        fl.exit(tok)
+    return _launch(_allgather_matmul_prep(comm, x, w),
+                   "allgather_matmul", "ring", comm, x,
+                   kind="allgather")
 
 
 def zero3_gather_matmul_dev(comm, state, rhs):

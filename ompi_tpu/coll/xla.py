@@ -37,12 +37,11 @@ import numpy as np
 
 from ompi_tpu import errors, op as op_mod
 from ompi_tpu.coll import CollModule, accelerator as staging, framework
+from ompi_tpu.coll import dispatch as _dispatch
 from ompi_tpu.core import cvar, output, pvar
-from ompi_tpu.monitoring import matrix as _mon
+from ompi_tpu.monitoring import expert_load as _expert_load
 from ompi_tpu.prof import ledger as _prof
-from ompi_tpu.telemetry import flight as _flight
 from ompi_tpu.trace import recorder as _trace
-from ompi_tpu.tune import observe as _tobs
 
 _out = output.stream("coll_xla")
 
@@ -155,17 +154,9 @@ def _det(deterministic: Optional[str]) -> Optional[str]:
     return _default_det.get() or None
 
 
-def _observed(launcher, op: str, comm, nbytes, dtype: str,
-              deterministic: Optional[str] = None):
-    """tune-plane hook on the slot's prepared launcher: when the
-    observatory is up, time this dispatch under provider 'xla' — the
-    backend that actually served after hier/pallas fallthrough. One
-    attribute load + one branch when off."""
-    obs = _tobs.OBSERVER
-    if obs is None:
-        return launcher
-    return obs.timed("xla", op, _det(deterministic) or "auto", comm,
-                     int(nbytes), dtype, launcher)
+#: every blocking slot here runs its prepared launcher through the one
+#: dispatch seam (counter, monitoring, tune, flight: coll/dispatch.py)
+_run = functools.partial(_dispatch.run, "xla")
 
 
 #: the name ``_Ctx.compiled`` chose for the program being built, for
@@ -346,6 +337,15 @@ class _Ctx:
             return out.addressable_data(0)
         with _trace.span("my_shard", "coll_xla"):
             return out.addressable_data(0)
+
+    def bind(self, fn, x, sharding=None):
+        """Bind operand ``x`` to the compiled program ``fn`` NOW (its
+        global view is built here, once): the zero-argument launcher
+        whose every call is one dispatch yielding this rank's shard —
+        what a blocking slot runs at once and a persistent request
+        holds."""
+        g = self.to_global(x, sharding)
+        return lambda: self.my_shard(self.launch(fn, g))
 
     def compiled(self, key, build):
         """Get-or-build a compiled program. Hit/miss/size pvars make
@@ -534,17 +534,19 @@ def _op_ok(op) -> bool:
 
 def _slot(op: str):
     """Span ``ompi:coll_xla.<op>`` around a blocking slot: everything
-    the slot does on the host (op check, monitors, context, key and
-    cache lookup, observer, flight) is its self time; ``to_global``,
-    ``launch`` and ``my_shard`` are its children."""
+    the slot does on the host (gates, context, key and cache lookup,
+    the dispatch seam) is its self time; ``to_global``, ``launch``
+    and ``my_shard`` are its children. ``nbytes`` is the first
+    operand's (a barrier has none: 0)."""
     def deco(fn):
         @functools.wraps(fn)
-        def slot(comm, buf, *args, **kwargs):
+        def slot(comm, *args, **kwargs):
             if not _trace.active():
-                return fn(comm, buf, *args, **kwargs)
+                return fn(comm, *args, **kwargs)
             with _trace.span(op, "coll_xla",
-                             nbytes=getattr(buf, "nbytes", 0)):
-                return fn(comm, buf, *args, **kwargs)
+                             nbytes=_dispatch.nbytes_of(args[0])
+                             if args else 0):
+                return fn(comm, *args, **kwargs)
         return slot
     return deco
 
@@ -575,9 +577,7 @@ def _allreduce_prep(comm, sendbuf, op=op_mod.SUM,
                         out_varying=False)
 
     fn = ctx.compiled(_key(sendbuf, "allreduce", opn.name, det), build)
-    to_g = ctx.to_global_hier if hier else ctx.to_global
-    g = to_g(sendbuf)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, sendbuf, ctx.in_sharding2d if hier else None)
 
 
 @_slot("allreduce")
@@ -585,26 +585,11 @@ def allreduce_dev(comm, sendbuf, op=op_mod.SUM,
                   deterministic: Optional[str] = None):
     if not _op_ok(op):
         return staging.allreduce_dev(comm, sendbuf, op)
-    pvar.record("coll_xla_device")
     if comm.size == 1:
         return sendbuf
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        tm.coll("allreduce", comm, getattr(sendbuf, "nbytes", 0),
-                dtype=str(getattr(sendbuf, "dtype", "")))
-    launcher = _observed(
-        _allreduce_prep(comm, sendbuf, op, deterministic),
-        "allreduce", comm, getattr(sendbuf, "nbytes", 0),
-        str(getattr(sendbuf, "dtype", "")), deterministic)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return launcher()
-    tok = fl.enter("allreduce_dev", getattr(comm, "cid", -1),
-                   getattr(sendbuf, "nbytes", 0))
-    try:
-        return launcher()
-    finally:
-        fl.exit(tok)
+    return _run("allreduce", comm, sendbuf,
+                _allreduce_prep(comm, sendbuf, op, deterministic),
+                algorithm=_det(deterministic))
 
 
 #: test/diagnostic hook: the last rooted schedule's per-round,
@@ -707,50 +692,18 @@ def _reduce_binomial(ctx, comm, x, opn, root: int):
     return acc if me == root else None
 
 
-def reduce_dev(comm, sendbuf, op=op_mod.SUM, root: int = 0,
-               deterministic: Optional[str] = None):
-    if not _op_ok(op):
-        return staging.reduce_dev(comm, sendbuf, op, root)
-    det = _det(deterministic)
-    n = comm.size
-    opn = op if isinstance(op, op_mod.Op) else op_mod.BUILTIN[op]
-    nbytes = int(sendbuf.size) * np.dtype(sendbuf.dtype).itemsize
-    # small buffers / deterministic modes keep the one-program full
-    # reduction (the rank-order contract needs the flat schedule
-    # anyway, and it is free for small buffers).
-    if n == 1 or det is not None or not _rooted(nbytes * n):
-        out = allreduce_dev(comm, sendbuf, op, deterministic)
-        return out if comm.rank == root else None
-    if opn.name != "MPI_SUM":
-        # non-SUM commutative: the binomial ppermute tree (O(bytes)
-        # non-roots; the SUM psum_scatter route below has no lowering
-        # for these ops)
-        from ompi_tpu.parallel.collectives import _JNP_FN
-
-        if opn.name not in _JNP_FN:
-            out = allreduce_dev(comm, sendbuf, op, deterministic)
-            return out if comm.rank == root else None
-        pvar.record("coll_xla_device")
-        tm = _mon.TRAFFIC
-        if tm is not None:
-            tm.coll("reduce", comm, nbytes, root=root,
-                    dtype=str(getattr(sendbuf, "dtype", "")))
-        return _reduce_binomial(_ctx(comm), comm, sendbuf, opn, root)
-    # rooted schedule: reduce_scatter leaves each rank ONE 1/n chunk
-    # (O(bytes/n) output), then the chunks ride single-pair ppermutes
-    # to the root — non-roots do O(bytes) HBM/ICI total, never the
-    # n-fold allreduce result (coll_base_reduce.c binomial role)
-    pvar.record("coll_xla_device")
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        tm.coll("reduce", comm, nbytes, root=root,
-                dtype=str(getattr(sendbuf, "dtype", "")))
+def _reduce_rooted_sum(ctx, comm, x, opn, root: int):
+    """SUM above the rooted threshold: reduce_scatter leaves each
+    rank ONE 1/n chunk (O(bytes/n) output), then the chunks ride
+    single-pair ppermutes to the root — non-roots do O(bytes) HBM/ICI
+    total, never the n-fold allreduce result (coll_base_reduce.c
+    binomial role)."""
     import jax.numpy as jnp
 
     from ompi_tpu.parallel import collectives as C
 
-    ctx = _ctx(comm)
-    flat = sendbuf.reshape(-1)
+    n = comm.size
+    flat = x.reshape(-1)
     pad = (-flat.size) % n
     if pad:
         flat = jnp.pad(flat, (0, pad))
@@ -766,7 +719,42 @@ def reduce_dev(comm, sendbuf, op=op_mod.SUM, root: int = 0,
     stacked = _gather_rooted(ctx, comm, chunk, root)
     if comm.rank != root:
         return None
-    return stacked.reshape(-1)[:sendbuf.size].reshape(sendbuf.shape)
+    return stacked.reshape(-1)[:x.size].reshape(x.shape)
+
+
+def _rooted_schedule(opn):
+    """SUM: reduce_scatter + chunks to the root. Another commutative
+    op: the binomial ppermute tree (O(bytes) non-roots; psum_scatter
+    has no lowering for it). None: the op has neither."""
+    if opn.name == "MPI_SUM":
+        return _reduce_rooted_sum
+    from ompi_tpu.parallel.collectives import _JNP_FN
+
+    return _reduce_binomial if opn.name in _JNP_FN else None
+
+
+@_slot("reduce")
+def reduce_dev(comm, sendbuf, op=op_mod.SUM, root: int = 0,
+               deterministic: Optional[str] = None):
+    if not _op_ok(op):
+        return staging.reduce_dev(comm, sendbuf, op, root)
+    n = comm.size
+    opn = op if isinstance(op, op_mod.Op) else op_mod.BUILTIN[op]
+    nbytes = int(sendbuf.size) * np.dtype(sendbuf.dtype).itemsize
+    # small buffers / deterministic modes keep the one-program full
+    # reduction (the rank-order contract needs the flat schedule
+    # anyway, and it is free for small buffers); so does an op with
+    # neither rooted schedule
+    rooted = None
+    if n > 1 and _det(deterministic) is None and _rooted(nbytes * n):
+        rooted = _rooted_schedule(opn)
+    if rooted is None:
+        out = allreduce_dev(comm, sendbuf, op, deterministic)
+        return out if comm.rank == root else None
+    ctx = _ctx(comm)
+    return _run("reduce", comm, sendbuf,
+                lambda: rooted(ctx, comm, sendbuf, opn, root),
+                nbytes=nbytes, root=root)
 
 
 def _bcast_prep(comm, buf, root: int = 0):
@@ -785,32 +773,15 @@ def _bcast_prep(comm, buf, root: int = 0):
         return ctx.smap(_bcast_body(root), out_varying=False)
 
     fn = ctx.compiled(_key(buf, "bcast", root), build)
-    to_g = ctx.to_global_hier if hier else ctx.to_global
-    g = to_g(buf)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, buf, ctx.in_sharding2d if hier else None)
 
 
 @_slot("bcast")
 def bcast_dev(comm, buf, root: int = 0):
-    pvar.record("coll_xla_device")
     if comm.size == 1:
         return buf
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        tm.coll("bcast", comm, getattr(buf, "nbytes", 0), root=root,
-                dtype=str(getattr(buf, "dtype", "")))
-    launcher = _observed(_bcast_prep(comm, buf, root), "bcast", comm,
-                         getattr(buf, "nbytes", 0),
-                         str(getattr(buf, "dtype", "")))
-    fl = _flight.FLIGHT
-    if fl is None:
-        return launcher()
-    tok = fl.enter("bcast_dev", getattr(comm, "cid", -1),
-                   getattr(buf, "nbytes", 0))
-    try:
-        return launcher()
-    finally:
-        fl.exit(tok)
+    return _run("bcast", comm, buf, _bcast_prep(comm, buf, root),
+                root=root)
 
 
 def _bcast_body(root: int):
@@ -829,33 +800,18 @@ def _allgather_prep(comm, sendbuf):
                         out_varying=False)
 
     fn = ctx.compiled(_key(sendbuf, "allgather"), build)
-    g = ctx.to_global(sendbuf)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, sendbuf)
 
 
 @_slot("allgather")
 def allgather_dev(comm, sendbuf):
-    pvar.record("coll_xla_device")
     if comm.size == 1:
         return sendbuf[None] if hasattr(sendbuf, "shape") else sendbuf
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        tm.coll("allgather", comm, getattr(sendbuf, "nbytes", 0),
-                dtype=str(getattr(sendbuf, "dtype", "")))
-    launcher = _observed(_allgather_prep(comm, sendbuf), "allgather",
-                         comm, getattr(sendbuf, "nbytes", 0),
-                         str(getattr(sendbuf, "dtype", "")))
-    fl = _flight.FLIGHT
-    if fl is None:
-        return launcher()
-    tok = fl.enter("allgather_dev", getattr(comm, "cid", -1),
-                   getattr(sendbuf, "nbytes", 0))
-    try:
-        return launcher()
-    finally:
-        fl.exit(tok)
+    return _run("allgather", comm, sendbuf,
+                _allgather_prep(comm, sendbuf))
 
 
+@_slot("gather")
 def gather_dev(comm, sendbuf, root: int = 0):
     n = comm.size
     nbytes = int(sendbuf.size) * np.dtype(sendbuf.dtype).itemsize
@@ -864,12 +820,10 @@ def gather_dev(comm, sendbuf, root: int = 0):
         return out if comm.rank == root else None
     # rooted: per-source ppermute-to-root rounds; non-roots allocate
     # one sendbuf-sized block per round, never the (n, ...) result
-    pvar.record("coll_xla_device")
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        tm.coll("gather", comm, nbytes, root=root,
-                dtype=str(getattr(sendbuf, "dtype", "")))
-    return _gather_rooted(_ctx(comm), comm, sendbuf, root)
+    ctx = _ctx(comm)
+    return _run("gather", comm, sendbuf,
+                lambda: _gather_rooted(ctx, comm, sendbuf, root),
+                nbytes=nbytes, root=root)
 
 
 def _alltoall_prep(comm, sendbuf):
@@ -894,32 +848,15 @@ def _alltoall_prep(comm, sendbuf):
                         out_varying=True)
 
     fn = ctx.compiled(_key(sendbuf, "alltoall"), build)
-    to_g = ctx.to_global_hier if hier else ctx.to_global
-    g = to_g(sendbuf)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, sendbuf, ctx.in_sharding2d if hier else None)
 
 
 @_slot("alltoall")
 def alltoall_dev(comm, sendbuf):
-    pvar.record("coll_xla_device")
     if comm.size == 1:
         return sendbuf
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        tm.coll("alltoall", comm, getattr(sendbuf, "nbytes", 0),
-                dtype=str(getattr(sendbuf, "dtype", "")))
-    launcher = _observed(_alltoall_prep(comm, sendbuf), "alltoall",
-                         comm, getattr(sendbuf, "nbytes", 0),
-                         str(getattr(sendbuf, "dtype", "")))
-    fl = _flight.FLIGHT
-    if fl is None:
-        return launcher()
-    tok = fl.enter("alltoall_dev", getattr(comm, "cid", -1),
-                   getattr(sendbuf, "nbytes", 0))
-    try:
-        return launcher()
-    finally:
-        fl.exit(tok)
+    return _run("alltoall", comm, sendbuf,
+                _alltoall_prep(comm, sendbuf))
 
 
 def _reduce_scatter_block_prep(comm, sendbuf, op=op_mod.SUM,
@@ -942,8 +879,7 @@ def _reduce_scatter_block_prep(comm, sendbuf, op=op_mod.SUM,
             out_varying=True)
 
     fn = ctx.compiled(_key(sendbuf, "rsb", opn.name, det), build)
-    g = ctx.to_global(sendbuf)
-    return lambda: ctx.my_shard(ctx.launch(fn, g))
+    return ctx.bind(fn, sendbuf)
 
 
 @_slot("reduce_scatter_block")
@@ -951,27 +887,12 @@ def reduce_scatter_block_dev(comm, sendbuf, op=op_mod.SUM,
                              deterministic: Optional[str] = None):
     if not _op_ok(op):
         return staging.reduce_scatter_block_dev(comm, sendbuf, op)
-    pvar.record("coll_xla_device")
     if comm.size == 1:
         return sendbuf
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        tm.coll("reduce_scatter_block", comm,
-                getattr(sendbuf, "nbytes", 0),
-                dtype=str(getattr(sendbuf, "dtype", "")))
-    launcher = _observed(
-        _reduce_scatter_block_prep(comm, sendbuf, op, deterministic),
-        "reduce_scatter_block", comm, getattr(sendbuf, "nbytes", 0),
-        str(getattr(sendbuf, "dtype", "")), deterministic)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return launcher()
-    tok = fl.enter("reduce_scatter_block_dev", getattr(comm, "cid", -1),
-                   getattr(sendbuf, "nbytes", 0))
-    try:
-        return launcher()
-    finally:
-        fl.exit(tok)
+    return _run("reduce_scatter_block", comm, sendbuf,
+                _reduce_scatter_block_prep(comm, sendbuf, op,
+                                           deterministic),
+                algorithm=_det(deterministic))
 
 
 def _scatter_meta(comm, key, root: int, root_meta):
@@ -1019,8 +940,8 @@ def _scatter_meta(comm, key, root: int, root_meta):
     return cached
 
 
+@_slot("scatter")
 def scatter_dev(comm, sendbuf, root: int = 0, like=None):
-    pvar.record("coll_xla_device")
     if comm.size == 1:
         return sendbuf
     # non-roots pass no data but SPMD needs same-shape operands on
@@ -1030,7 +951,7 @@ def scatter_dev(comm, sendbuf, root: int = 0, like=None):
     # metadata round per (comm, root).
     import jax.numpy as jnp
 
-    ctx0 = _ctx(comm)
+    ctx = _ctx(comm)
     # ``like`` is a collective argument (like counts): either every
     # rank passes its recvbuf template (zero-round, shape-dynamic
     # path) or none does (cached metadata round). Mixing hangs, as
@@ -1041,13 +962,13 @@ def scatter_dev(comm, sendbuf, root: int = 0, like=None):
                           (tuple(sendbuf.shape), str(sendbuf.dtype)))
         x = sendbuf
     elif like is not None:
-        x = ctx0.jax.device_put(
+        x = ctx.jax.device_put(
             jnp.zeros((comm.size * like.shape[0],) + tuple(
-                like.shape[1:]), like.dtype), ctx0.my)
+                like.shape[1:]), like.dtype), ctx.my)
     else:
         shape, dtype = _scatter_meta(comm, ("scatter", root), root,
                                      None)
-        x = ctx0.jax.device_put(jnp.zeros(shape, dtype), ctx0.my)
+        x = ctx.jax.device_put(jnp.zeros(shape, dtype), ctx.my)
     if x.shape[0] % comm.size:
         raise errors.MPIError(
             errors.ERR_COUNT,
@@ -1055,35 +976,29 @@ def scatter_dev(comm, sendbuf, root: int = 0, like=None):
             f"{comm.size}")
     from ompi_tpu.parallel import collectives as C
 
-    ctx = _ctx(comm)
-
     def build():
         return ctx.smap(lambda a: C.scatter(a[0], AXIS, root, 0),
                         out_varying=True)
 
     fn = ctx.compiled(_key(x, "scatter", root), build)
-    return ctx.my_shard(ctx.launch(fn, ctx.to_global(x)))
+    return _run("scatter", comm, x, ctx.bind(fn, x), root=root)
 
 
+@_slot("barrier")
 def barrier_dev(comm):
     """Device-plane barrier: a 1-element psum every member must enter
     before any member's program completes. Reference: coll/accelerator
     interposes every slot incl. barrier (ompi/mca/coll/accelerator/);
-    here the rendezvous itself rides ICI instead of the host."""
-    tm = _mon.TRAFFIC
-    if tm is not None and comm.size > 1:
-        tm.coll("barrier", comm, 0)
-    fl = _flight.FLIGHT
-    if fl is None:
-        ibarrier_dev(comm).wait()
+    here the rendezvous itself rides ICI instead of the host. The
+    launcher WAITS, so the flight entry covers the rendezvous and a
+    member that never arrives is the watchdog's to name."""
+    if comm.size == 1:
         return
-    tok = fl.enter("barrier_dev", getattr(comm, "cid", -1), 0)
-    try:
-        ibarrier_dev(comm).wait()
-    finally:
-        fl.exit(tok)
+    launch = _barrier_prep(comm)
+    _run("barrier", comm, None, lambda: DeviceRequest(launch()).wait())
 
 
+@_slot("scatterv")
 def scatterv_dev(comm, sendbuf, counts, root: int = 0, like=None):
     """Ragged scatter on device: root pads each segment to max(counts),
     a compiled bcast-from-root + static slice hands rank r its
@@ -1091,7 +1006,6 @@ def scatterv_dev(comm, sendbuf, counts, root: int = 0, like=None):
     MPI_Scatterv semantics), so shapes agree with zero host rounds;
     non-roots derive trailing dims/dtype from ``like`` (their recvbuf)
     or from the root metadata cache (see scatter_dev)."""
-    pvar.record("coll_xla_device")
     counts = tuple(int(c) for c in counts)
     if comm.size == 1:
         return sendbuf
@@ -1099,13 +1013,6 @@ def scatterv_dev(comm, sendbuf, counts, root: int = 0, like=None):
         raise errors.MPIError(
             errors.ERR_COUNT,
             f"scatterv: {len(counts)} counts for {comm.size} ranks")
-    tm = _mon.TRAFFIC
-    if tm is not None and comm.rank == root:
-        rowb = (sendbuf.nbytes / sendbuf.shape[0]
-                if sendbuf.shape[0] else 0.0)
-        tm.coll("scatterv", comm, getattr(sendbuf, "nbytes", 0),
-                root=root, counts=counts, row_bytes=rowb,
-                dtype=str(getattr(sendbuf, "dtype", "")))
     import jax.numpy as jnp
     from jax import lax
 
@@ -1143,10 +1050,12 @@ def scatterv_dev(comm, sendbuf, counts, root: int = 0, like=None):
         return ctx.smap(body, out_varying=True)
 
     fn = ctx.compiled(_key(x, "scatterv", counts, root), build)
+    # only the root's buffer is payload (a non-root's is undefined)
+    seg = _run("scatterv", comm, sendbuf if comm.rank == root else None,
+               ctx.bind(fn, x), dtype=dtype, root=root, counts=counts)
     # ragged trim is per-rank-local (outside the collective program:
     # sharded outputs must be uniform across devices)
-    return ctx.my_shard(
-        ctx.launch(fn, ctx.to_global(x)))[:counts[comm.rank]]
+    return seg[:counts[comm.rank]]
 
 
 def _nonroot_meta(comm, root, like, counts):
@@ -1160,6 +1069,7 @@ def _nonroot_meta(comm, root, like, counts):
     return tuple(rest), np.dtype(dtype)
 
 
+@_slot("allgatherv")
 def allgatherv_dev(comm, sendbuf, counts):
     """Ragged allgather on device: pad every block to max(counts),
     one compiled all_gather, then static slices reassemble the packed
@@ -1167,7 +1077,6 @@ def allgatherv_dev(comm, sendbuf, counts):
     accelerator path stages v-variants D2H; VERDICT r2 missing #4).
     counts is the full vector, identical on every rank, so the padded
     shapes agree with zero extra host rounds."""
-    pvar.record("coll_xla_device")
     counts = tuple(int(c) for c in counts)
     if comm.size == 1:
         return sendbuf
@@ -1193,14 +1102,16 @@ def allgatherv_dev(comm, sendbuf, counts):
         return ctx.smap(body, out_varying=False)
 
     fn = ctx.compiled(_key(x, "allgatherv", counts), build)
-    return ctx.my_shard(ctx.launch(fn, ctx.to_global(x)))
+    return _run("allgatherv", comm, sendbuf, ctx.bind(fn, x))
 
 
+@_slot("gatherv")
 def gatherv_dev(comm, sendbuf, counts, root: int = 0):
     out = allgatherv_dev(comm, sendbuf, counts)
     return out if comm.rank == root else None
 
 
+@_slot("alltoallv")
 def alltoallv_dev(comm, sendbuf, scounts, rcounts, max_count=None, *,
                   _expert_tokens: bool = True):
     """Ragged all-to-all on device: segments pad to a uniform cell
@@ -1261,21 +1172,11 @@ def alltoallv_dev(comm, sendbuf, scounts, rcounts, max_count=None, *,
                 errors.ERR_COUNT,
                 f"alltoallv: max_count {m} below local max "
                 f"{max(max(scounts), max(rcounts))}")
-    pvar.record("coll_xla_device")  # after the fallback decision, so
-    # the device-path counter never counts host-staged calls
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        # actual splits, not the padded cells: bytes to peer r =
-        # scounts[r] rows. This is also the EP dispatch site — each
-        # destination shard is an expert, so scounts IS the per-expert
-        # routed-token vector (ROADMAP item 5's imbalance feed).
-        rowb = (sendbuf.nbytes / sendbuf.shape[0]
-                if sendbuf.shape[0] else 0.0)
-        tm.coll("alltoallv", comm, getattr(sendbuf, "nbytes", 0),
-                dtype=str(getattr(sendbuf, "dtype", "")),
-                counts=scounts, row_bytes=rowb)
-        if _expert_tokens:
-            tm.expert_tokens(scounts)
+    if _expert_tokens:
+        # the EP dispatch site — each destination shard is an expert,
+        # so scounts IS the per-expert routed-token vector (ROADMAP
+        # item 5's imbalance feed)
+        _expert_load(scounts)
     rest = sendbuf.shape[1:]
     rows = []
     off = 0
@@ -1292,7 +1193,11 @@ def alltoallv_dev(comm, sendbuf, scounts, rcounts, max_count=None, *,
         return ctx.smap(body, out_varying=True)
 
     fn = ctx.compiled(_key(x, "alltoallv", m), build)
-    cells = ctx.my_shard(ctx.launch(fn, ctx.to_global(x)))  # (n, m, *rest)
+    # accounted by the actual splits, not the padded cells: bytes to
+    # peer r = scounts[r] rows (after the fallback decision, so the
+    # device path never counts a host-staged call)
+    cells = _run("alltoallv", comm, sendbuf, ctx.bind(fn, x),
+                 counts=scounts)  # (n, m, *rest)
     # ragged repack is per-rank-local (outside the collective program:
     # sharded outputs must be uniform across devices)
     return jnp.concatenate(
@@ -1328,53 +1233,45 @@ def reduce_scatter_dev(comm, sendbuf, counts, op=op_mod.SUM,
     return full[off:off + counts[comm.rank]]
 
 
-def scan_dev(comm, sendbuf, op=op_mod.SUM,
-             deterministic: Optional[str] = None):
-    """Inclusive prefix over comm ranks (lax.associative_scan under
-    shard_map — log-depth on device)."""
-    if not _op_ok(op):
-        return staging.scan_dev(comm, sendbuf, op)
-    pvar.record("coll_xla_device")
-    if comm.size == 1:
-        return sendbuf
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        tm.coll("scan", comm, getattr(sendbuf, "nbytes", 0),
-                dtype=str(getattr(sendbuf, "dtype", "")))
+def _prefix_dev(kind: str, comm, sendbuf, op):
+    """A prefix over comm ranks (lax.associative_scan under shard_map
+    — log-depth on device), ``kind`` 'scan' or 'exscan'."""
     from ompi_tpu.parallel import collectives as C
 
     ctx = _ctx(comm)
     opn = op if isinstance(op, op_mod.Op) else op_mod.BUILTIN[op]
+    prefix = {"scan": C.scan, "exscan": C.exscan}[kind]
 
     def build():
-        return ctx.smap(lambda a: C.scan(a[0], AXIS, opn),
+        return ctx.smap(lambda a: prefix(a[0], AXIS, opn),
                         out_varying=True)
 
-    fn = ctx.compiled(_key(sendbuf, "scan", opn.name), build)
-    return ctx.my_shard(ctx.launch(fn, ctx.to_global(sendbuf)))
+    fn = ctx.compiled(_key(sendbuf, kind, opn.name), build)
+    return _run(kind, comm, sendbuf, ctx.bind(fn, sendbuf))
 
 
+@_slot("scan")
+def scan_dev(comm, sendbuf, op=op_mod.SUM,
+             deterministic: Optional[str] = None):
+    """Inclusive prefix over comm ranks."""
+    if not _op_ok(op):
+        return staging.scan_dev(comm, sendbuf, op)
+    if comm.size == 1:
+        return sendbuf
+    return _prefix_dev("scan", comm, sendbuf, op)
+
+
+@_slot("exscan")
 def exscan_dev(comm, sendbuf, op=op_mod.SUM,
                deterministic: Optional[str] = None):
     """Exclusive prefix; rank 0 gets zeros (MPI leaves it undefined)."""
     if not _op_ok(op):
         return staging.exscan_dev(comm, sendbuf, op)
-    pvar.record("coll_xla_device")
     if comm.size == 1:
         import jax.numpy as jnp
 
         return jnp.zeros_like(sendbuf)
-    from ompi_tpu.parallel import collectives as C
-
-    ctx = _ctx(comm)
-    opn = op if isinstance(op, op_mod.Op) else op_mod.BUILTIN[op]
-
-    def build():
-        return ctx.smap(lambda a: C.exscan(a[0], AXIS, opn),
-                        out_varying=True)
-
-    fn = ctx.compiled(_key(sendbuf, "exscan", opn.name), build)
-    return ctx.my_shard(ctx.launch(fn, ctx.to_global(sendbuf)))
+    return _prefix_dev("exscan", comm, sendbuf, op)
 
 
 # ---------------------------------------------------------------------------
@@ -1509,6 +1406,7 @@ def _allreduce_multi_prep(comm, bufs, op=op_mod.SUM,
                       _det(deterministic))
 
 
+@_slot("allreduce_multi")
 def allreduce_multi_dev(comm, bufs, op=op_mod.SUM,
                         deterministic: Optional[str] = None):
     """Fused allreduce over a list/pytree of device buffers: flatten
@@ -1520,32 +1418,15 @@ def allreduce_multi_dev(comm, bufs, op=op_mod.SUM,
     if not _op_ok(op):
         return staging.allreduce_multi_dev(comm, bufs, op,
                                            deterministic=deterministic)
-    pvar.record("coll_xla_device")
     import jax
 
-    if comm.size == 1 or not jax.tree.leaves(bufs):
-        return bufs
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        leaves = jax.tree.leaves(bufs)
-        tm.coll("allreduce_multi", comm,
-                sum(getattr(b, "nbytes", 0) for b in leaves),
-                dtype=str(getattr(leaves[0], "dtype", "")))
     leaves = jax.tree.leaves(bufs)
-    nb = sum(getattr(b, "nbytes", 0) for b in leaves)
-    launcher = _observed(
-        _allreduce_multi_prep(comm, bufs, op, deterministic),
-        "allreduce_multi", comm, nb,
-        str(getattr(leaves[0], "dtype", "")), deterministic)
-    fl = _flight.FLIGHT
-    if fl is None:
-        return launcher()
-    tok = fl.enter("allreduce_multi_dev", getattr(comm, "cid", -1),
-                   nb)
-    try:
-        return launcher()
-    finally:
-        fl.exit(tok)
+    if comm.size == 1 or not leaves:
+        return bufs
+    return _run("allreduce_multi", comm, bufs,
+                _allreduce_multi_prep(comm, bufs, op, deterministic),
+                dtype=getattr(leaves[0], "dtype", ""),
+                algorithm=_det(deterministic))
 
 
 # ---------------------------------------------------------------------------
@@ -1619,12 +1500,7 @@ class DeviceRequest:
         return self.status
 
 
-def ibarrier_dev(comm):
-    """Nonblocking device barrier: the 1-element psum is dispatched;
-    the request completes when every plane member has entered."""
-    pvar.record("coll_xla_device")
-    if comm.size == 1:
-        return DeviceRequest(None)
+def _barrier_prep(comm):
     import jax.numpy as jnp
 
     from ompi_tpu.parallel import collectives as C
@@ -1636,9 +1512,17 @@ def ibarrier_dev(comm):
                         out_varying=False)
 
     fn = ctx.compiled(("barrier",), build)
-    token = ctx.jax.device_put(jnp.ones((1,), jnp.int32), ctx.my)
-    return DeviceRequest(
-        ctx.my_shard(ctx.launch(fn, ctx.to_global(token))))
+    return ctx.bind(
+        fn, ctx.jax.device_put(jnp.ones((1,), jnp.int32), ctx.my))
+
+
+def ibarrier_dev(comm):
+    """Nonblocking device barrier: the 1-element psum is dispatched;
+    the request completes when every plane member has entered."""
+    if comm.size == 1:
+        return DeviceRequest(None)
+    return DeviceRequest(_run("barrier", comm, None,
+                              _barrier_prep(comm)))
 
 
 class PersistentDeviceRequest:
@@ -1926,6 +1810,7 @@ def _reduce_scatter_multi_prep(comm, bufs, op=op_mod.SUM,
     return launch
 
 
+@_slot("reduce_scatter_multi")
 def reduce_scatter_multi_dev(comm, bufs, op=op_mod.SUM,
                              deterministic: Optional[str] = None):
     """Bucketed reduce_scatter over a pytree of device buffers (the
@@ -1938,9 +1823,6 @@ def reduce_scatter_multi_dev(comm, bufs, op=op_mod.SUM,
     if not _op_ok(op):
         return staging.reduce_scatter_multi_dev(
             comm, bufs, op, deterministic=deterministic)
-    pvar.record("coll_xla_device")
-    import jax
-
     if comm.size == 1:
         # reducing over one rank is the identity: the shard is a
         # local pack+slice, no plane/collective needed (the same
@@ -1948,26 +1830,14 @@ def reduce_scatter_multi_dev(comm, bufs, op=op_mod.SUM,
         from ompi_tpu.zero import layout as _zl
 
         return _zl.ShardedState.from_full(comm, bufs)
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        leaves = jax.tree.leaves(bufs)
-        tm.coll("reduce_scatter_multi", comm,
-                sum(getattr(b, "nbytes", 0) for b in leaves),
-                dtype=str(getattr(leaves[0], "dtype", ""))
-                if leaves else "")
-    fl = _flight.FLIGHT
-    if fl is None:
-        return _reduce_scatter_multi_prep(comm, bufs, op,
-                                          deterministic)()
-    tok = fl.enter("reduce_scatter_multi_dev",
-                   getattr(comm, "cid", -1),
-                   sum(getattr(b, "nbytes", 0)
-                       for b in jax.tree.leaves(bufs)))
-    try:
-        return _reduce_scatter_multi_prep(comm, bufs, op,
-                                          deterministic)()
-    finally:
-        fl.exit(tok)
+    import jax
+
+    leaves = jax.tree.leaves(bufs)
+    return _run("reduce_scatter_multi", comm, bufs,
+                _reduce_scatter_multi_prep(comm, bufs, op,
+                                           deterministic),
+                dtype=getattr(leaves[0], "dtype", "") if leaves else "",
+                algorithm=_det(deterministic))
 
 
 def _zero_state_check(comm, state) -> None:
@@ -2061,12 +1931,12 @@ def _allgather_multi_prep(comm, state):
     return launch
 
 
+@_slot("allgather_multi")
 def allgather_multi_dev(comm, state):
     """Bucketed allgather of a ShardedState back to the full pytree
     (the ZeRO parameter-rebuild step): ONE compiled tiled all_gather
     per bucket, rank-order concat (= the pack order), pad tail
     dropped, leaf shapes restored."""
-    pvar.record("coll_xla_device")
     _zero_state_check(comm, state)
     if not state.shards:
         import jax
@@ -2075,22 +1945,12 @@ def allgather_multi_dev(comm, state):
     if comm.size == 1:
         # n=1 shards ARE the full padded buckets: unpack locally
         return state.unpack(state.shards)
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        tm.coll("allgather_multi", comm, state.plan.nbytes,
-                dtype=state.plan.dtypes[0]
-                if state.plan.dtypes else "")
-    fl = _flight.FLIGHT
-    if fl is None:
-        return _allgather_multi_prep(comm, state)()
-    tok = fl.enter("allgather_multi_dev", getattr(comm, "cid", -1),
-                   state.plan.nbytes)
-    try:
-        return _allgather_multi_prep(comm, state)()
-    finally:
-        fl.exit(tok)
+    return _run("allgather_multi", comm, state,
+                _allgather_multi_prep(comm, state),
+                dtype=state.plan.dtypes[0])
 
 
+@_slot("allgather_multi_bucket")
 def allgather_multi_bucket_dev(comm, state, b: int):
     """Gather ONE bucket of a ShardedState: the member leaves (in
     ``plan.buckets[b]`` order) of the full tree, through the same
@@ -2099,7 +1959,6 @@ def allgather_multi_bucket_dev(comm, state, b: int):
     buckets whose shards did not change this step reuse the previous
     cycle's gathered leaves instead of relaunching (the
     ``zero_ag_skipped`` accounting lives with the caller)."""
-    pvar.record("coll_xla_device")
     _zero_state_check(comm, state)
     plan, metas = state.plan, state.metas
     if not 0 <= b < len(plan.buckets):
@@ -2123,9 +1982,17 @@ def allgather_multi_bucket_dev(comm, state, b: int):
     ctx = _ctx(comm)
     fn = _zero_ag_fn(ctx, metas, idxs, plan.elems[b],
                      plan.padded[b] - plan.elems[b])
-    res = ctx.launch(fn, ctx.to_global(state.shards[b]))
-    pvar.record("zero_ag_launches")
-    return [ctx.my_shard(r) for r in res]
+    g = ctx.to_global(state.shards[b])
+
+    def launch():
+        res = ctx.launch(fn, g)
+        pvar.record("zero_ag_launches")
+        return [ctx.my_shard(r) for r in res]
+
+    # one bucket of an allgather_multi, and accounted as that
+    return _run("allgather_multi_bucket", comm, None, launch,
+                nbytes=sum(metas[i][2] for i in idxs),
+                dtype=plan.dtypes[b], kind="allgather_multi")
 
 
 def _multi_state_empty(comm, state, *a, **k) -> bool:
@@ -2144,6 +2011,40 @@ allgather_multi_init_dev = _pprep(
 
 # ---------------------------------------------------------------------------
 # partitioned fused allreduce (MPI-4 part/ subsystem, device payoff)
+
+
+def _flush_bucket(req, b: int, trigger: Optional[int], op: str,
+                  span: str, subsys: str) -> bool:
+    """Dispatch bucket ``b`` of a partitioned cycle: the bucket's
+    program IS an ``op`` launch, run through the dispatch seam and
+    attributed to the part context so overlap traffic stays
+    separable (a request over a bare context has nobody to tell).
+    The flush span carries the Pready that triggered it, so a
+    timeline shows WHICH partition released each bucket (the Pready
+    -> flush causality the overlap design rests on). Returns whether
+    the dispatch overlapped the producer."""
+    fn, idxs = req._buckets[b]
+    overlap = req._n_ready < req._n
+    nb = sum(req._metas[i][2] for i in idxs)
+    bound = tuple(req._bound[i] for i in idxs)
+
+    flush = functools.partial(req._ctx.launch, fn, bound)
+    if req._comm is not None:
+        flush = functools.partial(_run, op, req._comm, None, flush,
+                                  nbytes=nb, ctx="part",
+                                  dtype=req._metas[idxs[0]][1])
+    rec = _trace.RECORDER
+    if rec is None:
+        req._results[b] = flush()
+    else:
+        t0 = _trace.now()
+        req._results[b] = flush()
+        t1 = _trace.now()
+        rec.record(span, subsys, t0, t1,
+                   {"bucket": b, "trigger_partition": trigger,
+                    "overlap": overlap, "nbytes": nb})
+        _trace.hist(span, nb, t1 - t0)
+    return overlap
 
 
 class PartitionedAllreduceRequest:
@@ -2217,9 +2118,8 @@ class PartitionedAllreduceRequest:
         self._n_ready = 0
         self._pending = [len(idxs) for _fn, idxs in self._buckets]
         self._results = [None] * len(self._buckets)
-        fl = _flight.FLIGHT
-        self._fl_tok = None if fl is None else fl.enter(
-            "pallreduce_cycle", -1, self.nbytes)
+        self._fl_tok = _dispatch.cycle_enter(
+            "pallreduce_cycle", self._comm, self.nbytes)
 
     def Pready(self, idx: int, value=None) -> None:
         if self._ready is None:
@@ -2262,35 +2162,9 @@ class PartitionedAllreduceRequest:
             self.Pready(i)
 
     def _flush(self, b: int, trigger: Optional[int] = None) -> None:
-        fn, idxs = self._buckets[b]
-        overlap = self._n_ready < self._n
-        rec = _trace.RECORDER
-        if rec is None:
-            self._results[b] = self._ctx.launch(
-                fn, tuple(self._bound[i] for i in idxs))
-        else:
-            # the flush span carries the Pready that triggered it, so
-            # a timeline shows WHICH partition released each bucket
-            # (the Pready -> flush causality the overlap design rests
-            # on) and whether the dispatch overlapped the producer
-            t0 = _trace.now()
-            self._results[b] = self._ctx.launch(
-                fn, tuple(self._bound[i] for i in idxs))
-            t1 = _trace.now()
-            nb = sum(self._metas[i][2] for i in idxs)
-            rec.record("part_bucket_flush", "part", t0, t1,
-                       {"bucket": b, "trigger_partition": trigger,
-                        "overlap": overlap, "nbytes": nb})
-            _trace.hist("part_bucket_flush", nb, t1 - t0)
-        tm = _mon.TRAFFIC
-        if tm is not None and self._comm is not None:
-            # the bucket's psum IS an allreduce launch; attributed to
-            # the part context so overlap traffic stays separable
-            tm.coll("allreduce", self._comm,
-                    sum(self._metas[i][2] for i in idxs),
-                    dtype=self._metas[idxs[0]][1], ctx="part")
         pvar.record("part_bucket_flushes")
-        if overlap:
+        if _flush_bucket(self, b, trigger, "allreduce",
+                         "part_bucket_flush", "part"):
             # dispatched while later partitions are still pending:
             # this bucket's wire time is hidden behind the producer
             pvar.record("part_overlap_flushes")
@@ -2333,10 +2207,7 @@ class PartitionedAllreduceRequest:
         self._arr = jax.tree.unflatten(self._treedef, outs)
         self._ready = None  # cycle closed: back to inactive
         tok, self._fl_tok = self._fl_tok, None
-        if tok is not None:
-            fl = _flight.FLIGHT
-            if fl is not None:
-                fl.exit(tok)
+        _dispatch.cycle_exit(tok)
 
     def wait(self, timeout=None):
         if self._ready is None:
@@ -2556,10 +2427,8 @@ class PartitionedReduceScatterRequest:
         self._n_ready = 0
         self._pending = [len(idxs) for _fn, idxs in self._buckets]
         self._results = [None] * len(self._buckets)
-        fl = _flight.FLIGHT
-        self._fl_tok = None if fl is None else fl.enter(
-            "preduce_scatter_cycle", getattr(self._comm, "cid", -1),
-            self.nbytes)
+        self._fl_tok = _dispatch.cycle_enter(
+            "preduce_scatter_cycle", self._comm, self.nbytes)
 
     def Pready(self, idx: int, value=None) -> None:
         if self._ready is None:
@@ -2602,29 +2471,9 @@ class PartitionedReduceScatterRequest:
             self.Pready(i)
 
     def _flush(self, b: int, trigger: Optional[int] = None) -> None:
-        fn, idxs = self._buckets[b]
-        overlap = self._n_ready < self._n
-        rec = _trace.RECORDER
-        if rec is None:
-            self._results[b] = self._ctx.launch(
-                fn, tuple(self._bound[i] for i in idxs))
-        else:
-            t0 = _trace.now()
-            self._results[b] = self._ctx.launch(
-                fn, tuple(self._bound[i] for i in idxs))
-            t1 = _trace.now()
-            nb = sum(self._metas[i][2] for i in idxs)
-            rec.record("zero_bucket_flush", "zero", t0, t1,
-                       {"bucket": b, "trigger_partition": trigger,
-                        "overlap": overlap, "nbytes": nb})
-            _trace.hist("zero_bucket_flush", nb, t1 - t0)
-        tm = _mon.TRAFFIC
-        if tm is not None:
-            tm.coll("reduce_scatter", self._comm,
-                    sum(self._metas[i][2] for i in idxs),
-                    dtype=self._metas[idxs[0]][1], ctx="part")
         pvar.record("zero_rs_launches")
-        if overlap:
+        if _flush_bucket(self, b, trigger, "reduce_scatter",
+                         "zero_bucket_flush", "zero"):
             pvar.record("zero_overlap_flushes")
 
     @property
@@ -2663,10 +2512,7 @@ class PartitionedReduceScatterRequest:
             self._comm.rank, self._ctx.n)
         self._ready = None
         tok, self._fl_tok = self._fl_tok, None
-        if tok is not None:
-            fl = _flight.FLIGHT
-            if fl is not None:
-                fl.exit(tok)
+        _dispatch.cycle_exit(tok)
 
     def wait(self, timeout=None):
         if self._ready is None:

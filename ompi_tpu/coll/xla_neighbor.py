@@ -31,8 +31,7 @@ from typing import List, Tuple
 import numpy as np
 
 from ompi_tpu import errors
-from ompi_tpu.core import pvar
-from ompi_tpu.monitoring import matrix as _mon
+from ompi_tpu.coll import xla as X
 from ompi_tpu.pml.request import PROC_NULL
 
 
@@ -150,9 +149,7 @@ def _place(out, recvd, slot_np, tgt_np, ctx):
     import jax.numpy as jnp
     from jax import lax
 
-    from ompi_tpu.coll.xla import AXIS
-
-    me = lax.axis_index(AXIS)
+    me = lax.axis_index(X.AXIS)
     slot = jnp.asarray(slot_np)[me]
     is_tgt = jnp.asarray(tgt_np)[me]
     upd = lax.dynamic_update_slice_in_dim(out, recvd[None], slot,
@@ -160,29 +157,26 @@ def _place(out, recvd, slot_np, tgt_np, ctx):
     return jnp.where(is_tgt, upd, out)
 
 
+def _per_peer(topo, rank: int, nbytes: float):
+    """Send-side traffic by graph edge, not an algo model: ``nbytes``
+    to every (non-PROC_NULL) out-neighbor, once per edge."""
+    per = {}
+    for p in topo.out_neighbors(rank):
+        if p != PROC_NULL:
+            per[p] = per.get(p, 0.0) + nbytes
+    return per
+
+
+@X._slot("neighbor_allgather")
 def neighbor_allgather_dev(comm, sendbuf):
     """Device MPI_Neighbor_allgather: returns (n_in, *sendbuf.shape)
     — row k is in-neighbor k's sendbuf (zeros for PROC_NULL slots)."""
     from jax import lax
 
-    from ompi_tpu.coll import xla as X
-
-    pvar.record("coll_xla_device")
     topo = _global_topo(comm)
     ctx = X._ctx(comm)
     n = ctx.n
     my_rows = len(topo.in_neighbors(comm.rank))
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        # graph edges, not an algo model: the full sendbuf goes to
-        # every (non-PROC_NULL) out-neighbor
-        nb = getattr(sendbuf, "nbytes", 0)
-        per = {}
-        for p in topo.out_neighbors(comm.rank):
-            if p != PROC_NULL:
-                per[p] = per.get(p, 0.0) + nb
-        tm.coll("neighbor_allgather", comm, nb, per_peer=per,
-                dtype=str(getattr(sendbuf, "dtype", "")))
 
     def build():
         import jax.numpy as jnp
@@ -210,10 +204,15 @@ def neighbor_allgather_dev(comm, sendbuf):
         return ctx.smap(body, out_varying=True)
 
     fn = ctx.compiled(X._key(sendbuf, "neighbor_allgather"), build)
-    out = ctx.my_shard(fn(ctx.to_global(sendbuf)))
+    # the full sendbuf goes to every out-neighbor
+    out = X._run("neighbor_allgather", comm, sendbuf,
+                 ctx.bind(fn, sendbuf),
+                 per_peer=lambda: _per_peer(topo, comm.rank,
+                                            sendbuf.nbytes))
     return out[:my_rows]
 
 
+@X._slot("neighbor_alltoall")
 def neighbor_alltoall_dev(comm, sendbuf):
     """Device MPI_Neighbor_alltoall: ``sendbuf`` rows are per-out-
     neighbor blocks (row j to out-neighbor j); returns (n_in, *blk)
@@ -222,9 +221,6 @@ def neighbor_alltoall_dev(comm, sendbuf):
     import jax.numpy as jnp
     from jax import lax
 
-    from ompi_tpu.coll import xla as X
-
-    pvar.record("coll_xla_device")
     topo = _global_topo(comm)
     ctx = X._ctx(comm)
     n = ctx.n
@@ -235,18 +231,7 @@ def neighbor_alltoall_dev(comm, sendbuf):
             errors.ERR_COUNT,
             f"neighbor_alltoall: sendbuf dim0 {sendbuf.shape[0]} != "
             f"out-degree {my_out}")
-    tm = _mon.TRAFFIC
-    if tm is not None:
-        # one sendbuf row per out-neighbor (PROC_NULL rows go nowhere)
-        rowb = (sendbuf.nbytes / sendbuf.shape[0]
-                if sendbuf.shape[0] else 0.0)
-        per = {}
-        for p in topo.out_neighbors(comm.rank):
-            if p != PROC_NULL:
-                per[p] = per.get(p, 0.0) + rowb
-        tm.coll("neighbor_alltoall", comm,
-                getattr(sendbuf, "nbytes", 0), per_peer=per,
-                dtype=str(getattr(sendbuf, "dtype", "")))
+    payload = sendbuf  # as the caller gave it, before the padding
     edges, max_in, max_out = _edges_alltoall(topo, n)
     # SPMD needs uniform operand shapes: pad ragged out-degrees
     if sendbuf.shape[0] < max_out:
@@ -286,7 +271,12 @@ def neighbor_alltoall_dev(comm, sendbuf):
         return ctx.smap(body, out_varying=True)
 
     fn = ctx.compiled(X._key(sendbuf, "neighbor_alltoall"), build)
-    out = ctx.my_shard(fn(ctx.to_global(sendbuf)))
+    # one sendbuf row per out-neighbor (PROC_NULL rows go nowhere)
+    out = X._run("neighbor_alltoall", comm, payload,
+                 ctx.bind(fn, sendbuf),
+                 per_peer=lambda: _per_peer(
+                     topo, comm.rank,
+                     payload.nbytes / my_out if my_out else 0.0))
     return out[:my_in]
 
 

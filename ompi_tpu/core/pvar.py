@@ -136,8 +136,7 @@ WELL_KNOWN = (
     # coll/pallas (hand-rolled ring collectives): kernel launches,
     # fused compute+comm kernel launches (ZeRO update / allgather-
     # matmul), staged fallthroughs to coll/xla, and bytes moved per
-    # algorithm family (the switchpoint-tuning signal bench.py
-    # --pallas reads back)
+    # algorithm family (the switchpoint-tuning signal)
     "pallas_launches", "pallas_fused_launches", "pallas_fallthrough",
     "pallas_ring_bytes", "pallas_bidir_bytes", "pallas_linear_bytes",
     # coll/hier (two-level ICI x DCN collectives): hierarchical

@@ -29,8 +29,6 @@ late ones. Three layers live under this name:
   plane (:mod:`ompi_tpu.ingest`) implements it for host->device
   upload units, so "start on the first ready shards" reads the same
   both places.
-
-``ompi_tpu.pml.part`` remains as a compat shim over ``part.host``.
 """
 
 from ompi_tpu.part import host  # noqa: F401  (attaches at import)

@@ -3,8 +3,8 @@
     python -m ompi_tpu.prof report r0_trace.json r1_trace.json
     python -m ompi_tpu.prof report -o attribution.json --top 15 *.json
 
-Inputs are ordinary per-rank trace files (``trace.export.write`` /
-``bench.py --trace`` output) — the prof plane's phase and xfer spans
+Inputs are ordinary per-rank trace files (``trace.export.write``
+output, as ``scripts/trace_smoke.sh`` makes them) — the prof plane's phase and xfer spans
 ride the same recorder, so clock sync and cross-rank merge are
 exactly ``python -m ompi_tpu.trace merge`` (store-synced clocks,
 pid-per-rank). The report answers "where did the wall go":

@@ -3,16 +3,17 @@
 The measurement half of ROADMAP item 3 (the coll/tuned measured
 dynamic-rules story, PAPER.md: coll/tuned): the three decision
 tables (``coll_pallas_switchpoints``, ``coll_hier_switchpoints``,
-``coll_xla_bucket_bytes``) were fed by a human running ``bench.py``
-offline; this plane measures real collectives **in-band** instead.
+``coll_xla_bucket_bytes``) were fed by hand from offline sweeps;
+this plane measures real collectives **in-band** instead.
 
 Four cooperating pieces, all opt-in via ``tune_observe`` (or the
 short ``OMPI_TPU_TUNE`` env knob):
 
 - :mod:`observe` — the ``OBSERVER`` guard (one attribute load + one
-  ``is None`` branch per dispatch site when off — the ``FLIGHT``/
-  ``TRAFFIC`` discipline) timing every served device-collective
-  launch in coll/xla, coll/pallas, and coll/hier, keyed ``(op,
+  ``is None`` branch when off — the ``FLIGHT``/``TRAFFIC``
+  discipline), read by the one dispatch seam (``coll/dispatch.py``)
+  that times every served device-collective launch of coll/xla,
+  coll/pallas and coll/hier, keyed ``(op,
   dtype, log2-size, mesh-shape, provider, algorithm)`` — the
   provider being whichever backend actually served after staged
   fallthrough.

@@ -2,16 +2,17 @@
 
 The measurement half of the coll/tuned story (reference:
 ompi/mca/coll/tuned's measured dynamic-rules files): every device
-collective dispatch site in coll/xla, coll/pallas and coll/hier wraps
-its zero-arg launcher behind the process-wide :data:`OBSERVER` guard —
-the ``FLIGHT``/``TRAFFIC`` one-branch discipline, enforced by the lint
-engine's ``GUARD_GLOBALS`` — and, when the plane is up, times the
-dispatch and folds the sample into an associative per-key table.
+collective of coll/xla, coll/pallas and coll/hier hands its zero-arg
+launcher to the one dispatch seam (``coll/dispatch.py``), which reads
+the process-wide :data:`OBSERVER` guard — the ``FLIGHT``/``TRAFFIC``
+one-branch discipline, enforced by the lint engine's
+``GUARD_GLOBALS`` — and, when the plane is up, times the dispatch
+and folds the sample into an associative per-key table.
 
 Keys are exactly what every switchpoint table already selects on —
 ``(op, dtype, log2-size-bucket, mesh-shape, provider, algorithm)`` —
 and the provider is the backend that ACTUALLY served the call after
-staged fallthrough (only the serving backend's launch funnel fires),
+staged fallthrough (only the serving backend reaches the seam),
 so the table answers "which algorithm ran, on what, how fast" without
 replaying traces. Per-key stats are count/sum/min/max plus a log2
 latency histogram (the serve-plane ``lat_ns`` shape): every component
@@ -20,8 +21,8 @@ accumulate across ranks and across runs.
 
 Sampling cost when enabled: two ``perf_counter_ns`` reads + one dict
 update under the lock + two pvar bumps. Disabled: one module-attribute
-load and one ``is None`` branch per dispatch site — the level-0
-contract ``bench.py --tune`` bounds against the 256 KiB payload floor.
+load and one ``is None`` branch per dispatch (tests/
+test_coll_dispatch.py pins that nothing is constructed).
 """
 
 from __future__ import annotations

@@ -132,8 +132,8 @@ def test_forced_algorithm_cvar():
 
 
 def test_switchpoint_table():
-    """A measured switchpoint table (the bench.py --pallas JSON)
-    selects per (op, log2-size, dtype, mesh): the largest log2 <= the
+    """A measured switchpoint table (the tune report's candidate
+    JSON) selects per (op, log2-size, dtype, mesh): the largest log2 <= the
     payload bucket wins, and 'xla' entries fall through."""
     run_ranks("""
     import json, jax.numpy as jnp

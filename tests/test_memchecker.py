@@ -1,4 +1,4 @@
-"""memchecker — buffer-definedness shadow tracking (core/memchecker).
+"""memchecker — buffer-definedness shadow tracking (check/memchecker).
 
 Reference parity: the MEMCHECKER() annotations in the API layer
 (ompi/mpi/c/allreduce.c:52-66) that flag use of undefined receive
