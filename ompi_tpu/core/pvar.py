@@ -44,6 +44,9 @@ WELL_KNOWN = (
     # ops/attention.attention, once per TRACED attention (the choice
     # is static inside jit): the blockwise kernel, or att.mha
     "attn_blockwise_layers", "attn_reference_layers",
+    # ops/moe.sorted_moe_ffn, once per TRACED MoE layer: its grouped
+    # matmuls are the Pallas kernels, or lax.ragged_dot
+    "moe_grouped_kernel_layers", "moe_ragged_dot_layers",
     # the phases of mpi.Init(), once per job (runtime/state.py,
     # runtime/device_plane.py; "import" also holds the import of
     # ompi_tpu.mpi itself): they end before any profiler session can

@@ -6,9 +6,12 @@ weights as they are unless the config renormalises them, and the
 ingredients of the two router losses) and :func:`sorted_moe_ffn`: the
 ``T * k`` token-expert assignments are sorted by expert (stable), the
 rows gathered into expert order, the experts run as ONE grouped matmul
-over the ragged groups (``lax.ragged_dot``: a native kernel on TPU,
-plain XLA on CPU), and the weighted rows gathered back and summed per
-token. Static shapes, no capacity, no token ever dropped, any ``k``,
+per expert matrix over the ragged groups (:func:`grouped_matmul`: on
+the TPU, where the static rule :func:`grouped_tiles` gives tiles, the
+Pallas kernels of ops/grouped_matmul.py, forward and both transposes;
+``lax.ragged_dot`` everywhere else), and the weighted rows gathered
+back and summed per token. Static shapes, no capacity, no token ever
+dropped, any ``k``,
 gated (``w3``) or plain ReLU experts; differentiable with respect to
 the rows, the expert weights and, through the weights, the router.
 Both permutations are gathers in the forward AND the backward pass
@@ -231,23 +234,144 @@ def activation(name: str):
     return _ACT[name]
 
 
+# -- the grouped matmul of the sorted path ------------------------------------
+
+class GroupedTiles(NamedTuple):
+    """The tiles of the three products a grouped matmul is made of, as
+    the kernels in ops/grouped_matmul.py take them."""
+    fwd: tuple     # (tm, sub, tn)       [m, k] x [E, k, n]
+    drows: tuple   # (tm, sub, tn)       [m, n] x [E, k, n]^T
+    dw: tuple      # (tm, sub, tk, tn)   [m, k]^T x [m, n]
+
+
+#: Rows of a tile, and of the blocks a tile with a group edge in it is
+#: worked in (v5e, one layer's experts alone at olmoe-train-t4096's
+#: shapes and group sizes, PERF.md section 6, PR 29: 512 / 128 is the
+#: fastest of 11 row products and of 9 weight gradients, on the cell's
+#: groups and on uniform ones).
+_TM, _SUB = 512, 128
+#: What a kernel's blocks may take of VMEM, both buffers of each
+#: counted (the kernels ask the compiler for 96 MiB of v5e's 128).
+_VMEM_BLOCKS = 64 * 1024 * 1024
+
+
+def _blocks(x: int):
+    """The 128-multiples that divide x, largest first."""
+    return [d for d in range(x, 0, -128) if x % d == 0]
+
+
+def _row_product_tiles(k: int, n: int, size: int) -> Optional[tuple]:
+    """(tm, sub, tn) of ``[m, k] x [k, n]`` with the whole of K in one
+    block: the widest block of columns whose weights, rows and result
+    fit, or None."""
+    for tn in _blocks(n):
+        if (2 * size * (k * tn + _TM * k + _TM * tn) + 4 * _TM * tn
+                <= _VMEM_BLOCKS):
+            return _TM, _SUB, tn
+    return None
+
+
+def _dw_tiles(k: int, n: int, size: int) -> Optional[tuple]:
+    """(tm, sub, tk, tn) of the weights' gradient: the largest
+    [tk, tn] block whose float32 sum, result and operand tiles fit, or
+    None."""
+    fit = [(tk * tn, tn, tk) for tk in _blocks(k) for tn in _blocks(n)
+           if (4 + 2 * size) * tk * tn + 2 * size * _TM * (tk + tn)
+           <= _VMEM_BLOCKS]
+    if not fit:
+        return None
+    _, tn, tk = max(fit)
+    return _TM, _SUB, tk, tn
+
+
+def grouped_tiles(backend: str, m: int, k: int, n: int,
+                  dtype) -> Optional[GroupedTiles]:
+    """The rule that sends a grouped matmul ``[m, k] x [E, k, n]`` to
+    the Pallas kernels, made of what the caller can observe: each
+    product's tiles, or None where it stays ``lax.ragged_dot`` — off
+    the TPU, K or N that are not multiples of the 128 lanes, rows the
+    row tile does not divide, anything but bfloat16 or float32 operands
+    of one type (`dtype` None: two types), matrices so wide that a
+    whole-K block does not fit in VMEM."""
+    if (backend != "tpu" or k % 128 or n % 128 or m % _TM or dtype is None
+            or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32)):
+        return None
+    size = jnp.dtype(dtype).itemsize
+    tiles = GroupedTiles(fwd=_row_product_tiles(k, n, size),
+                         drows=_row_product_tiles(n, k, size),
+                         dw=_dw_tiles(k, n, size))
+    return tiles if all(tiles) else None
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_kernels(tiles: GroupedTiles, interpret: bool):
+    """`grouped_matmul`'s kernel path for one tile set: the product and
+    its two transposes, each its own Pallas kernel with its own tiles.
+    Built per tile set (the tiles are static), imported late: Pallas is
+    1.3 s of Python that only a TPU run needs."""
+    from ompi_tpu.ops import grouped_matmul as gk
+
+    @jax.custom_vjp
+    def product(rows, w, counts):
+        return gk.gmm(rows, w, counts, tiles.fwd, interpret=interpret)
+
+    def fwd(rows, w, counts):
+        return product(rows, w, counts), (rows, w, counts)
+
+    def bwd(res, g):
+        rows, w, counts = res
+        return (gk.gmm(g, w, counts, tiles.drows, transpose_rhs=True,
+                       out_dtype=rows.dtype, interpret=interpret),
+                gk.tgmm(rows, g, counts, tiles.dw, out_dtype=w.dtype,
+                        interpret=interpret), None)
+
+    product.defvjp(fwd, bwd)
+    return product
+
+
+def _tiles_of(rows, w) -> Optional[GroupedTiles]:
+    """The rule, asked about these operands on this backend (operands
+    of two types promote in ``lax.ragged_dot``, not in the kernels)."""
+    return grouped_tiles(jax.default_backend(), *rows.shape, w.shape[2],
+                         rows.dtype if w.dtype == rows.dtype else None)
+
+
+def grouped_matmul(rows, w, counts, interpret: bool = False):
+    """``lax.ragged_dot(rows, w, counts)``: rows [M, K] sorted by
+    group, w [E, K, N], counts [E] int32 rows a group -> [M, N] in the
+    rows' type, float32 accumulation. Where :func:`grouped_tiles`
+    gives tiles (the TPU) the product and both its transposes are
+    Pallas kernels (ops/grouped_matmul.py); everywhere else it IS
+    ``lax.ragged_dot``, jax's own transposes included."""
+    tiles = _tiles_of(rows, w)
+    if tiles is None:
+        return lax.ragged_dot(rows, w, counts)
+    return _grouped_kernels(tiles, interpret)(rows, w, counts)
+
+
 def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
                    w2, act: str = "relu"):
     """Drop-free MoE FFN on one device. x: [T, D] tokens; w1 (and the
     gate's w3, or None for an ungated expert): [E, D, F]; w2:
     [E, F, D]. Returns ``sum_k weight_k * expert_k(x)``, [T, D] in
     x's type: ``act(x W1_e) * (x W3_e)`` through ``W2_e`` when gated,
-    ``act(x W1_e) W2_e`` when not."""
+    ``act(x W1_e) W2_e`` when not. The products are
+    :func:`grouped_matmul`'s; which path they took is counted once per
+    traced call (pvars ``moe_grouped_kernel_layers`` /
+    ``moe_ragged_dot_layers``)."""
     t, k = route.experts.shape
     with jax.named_scope("moe_dispatch"):
         order = jnp.argsort(route.experts.reshape(t * k), stable=True)
         inv = jnp.argsort(order)
         rows = _to_expert_order(x, order, inv, k)
+    # the rule reads K and N alike: what it says of w1 holds for w2
+    pvar.record("moe_grouped_kernel_layers" if _tiles_of(rows, w1)
+                else "moe_ragged_dot_layers")
     with jax.named_scope("moe_experts"):
-        hidden = activation(act)(lax.ragged_dot(rows, w1, route.counts))
+        hidden = activation(act)(grouped_matmul(rows, w1, route.counts))
         if w3 is not None:
-            hidden = hidden * lax.ragged_dot(rows, w3, route.counts)
-        out = lax.ragged_dot(hidden, w2, route.counts)
+            hidden = hidden * grouped_matmul(rows, w3, route.counts)
+        out = grouped_matmul(hidden, w2, route.counts)
     with jax.named_scope("moe_combine"):
         out = _permute(out, inv, order).reshape(t, k, x.shape[-1])
         return jnp.einsum("tkd,tk->td", out.astype(jnp.float32),

@@ -28,3 +28,21 @@ def pvar_clean():
     pvar.reset()
     yield
     pvar.reset()
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described (not attached) v5e host, as a sharding:
+    the TPU compiler is installed here and compiles for it. Asked for by
+    name, never autouse, and the topology is described only once a test
+    that wants it runs (not at import): the process that does holds the
+    TPU library from then on."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])

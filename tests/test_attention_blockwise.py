@@ -22,7 +22,6 @@ jax = pytest.importorskip("jax")
 
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from ompi_tpu.core import pvar  # noqa: E402
 from ompi_tpu.models import transformer as tfm  # noqa: E402
@@ -240,17 +239,6 @@ def test_the_cpu_step_is_the_step_that_calls_the_reference(model,
 
 
 # -- the kernels at the cells' widths, compiled for a described chip ---------
-
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
 
 @pytest.mark.parametrize("cell,b,t,h", [("opt30b-train-t2048", 2, 2048, 56),
                                         ("opt30b-train-t1024", 4, 1024, 56),
