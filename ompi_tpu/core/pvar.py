@@ -47,6 +47,14 @@ WELL_KNOWN = (
     # ops/moe.sorted_moe_ffn, once per TRACED MoE layer: its grouped
     # matmuls are the Pallas kernels, or lax.ragged_dot
     "moe_grouped_kernel_layers", "moe_ragged_dot_layers",
+    # models/transformer.py, once per TRACED layer: latent attention
+    # (MLA); of those, the layers whose sparse-attention indexer
+    # selects (the sequence is longer than index_topk)
+    "attn_mla_layers", "attn_dsa_layers",
+    # the set-up probes transformer.dsa_selection / route_counts: the
+    # (query, key) pairs attention keeps of the causal ones, and the
+    # token-expert assignments that fell to the experts this chip holds
+    "dsa_selected_pairs", "dsa_causal_pairs", "moe_held_assignments",
     # the phases of mpi.Init(), once per job (runtime/state.py,
     # runtime/device_plane.py; "import" also holds the import of
     # ompi_tpu.mpi itself): they end before any profiler session can

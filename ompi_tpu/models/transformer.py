@@ -33,6 +33,15 @@ without renormalisation, untied head, load-balancing and router-z
 losses) is the second published model it computes (reference
 ``benchmark/reference/olmoe_decoder.py``). Without an ``ep`` axis a
 MoE layer takes the drop-free sorted path of :mod:`ompi_tpu.ops.moe`.
+GLM-5 is the third (reference ``benchmark/reference/glm5_decoder.py``):
+attention of another KIND (``attn="mla"``: latent attention, with the
+learned sparse-attention indexer of :mod:`ompi_tpu.ops.attention`
+where ``index_topk`` is set), layers of three kinds by INDEX
+(``first_dense`` leading dense layers of width ``d_ff``, then expert
+layers of width ``moe_d_ff``, then ``mtp_layers`` multi-token-prediction
+modules with a second loss), a sigmoid ``noaux_tc`` router over all
+``n_experts`` of which this chip holds ``held_experts``, a shared
+expert, and ``remat``: each layer recomputed in the backward pass.
 
 Names on the device (``jax.named_scope``: metadata, the HLO is the
 same): the jitted step is module ``jit_ompi_train_step``; its ops carry
@@ -44,7 +53,14 @@ reader finds a model part by name, not by XLA's fusion numbering.
 Inside ``attn_proj``: ``qk_rope`` (QK-norm and RoPE); inside ``mlp`` of
 a MoE layer: ``moe_route`` (router matmul, softmax, top-k, the two
 losses), ``moe_dispatch`` (sort, gather), ``moe_experts`` (grouped
-matmuls, activation), ``moe_combine`` (un-sort, weighted sum).
+matmuls, activation), ``moe_combine`` (un-sort, weighted sum),
+``moe_shared`` (the shared expert). A latent-attention layer has
+``attn_proj/{mla_q, mla_kv, mla_o, qk_rope, dsa_index_proj}`` and
+``attn_core/{dsa_index, dsa_attend, dsa_kl}`` (the indexer's scores
+and top-k, attention over the selection, the indexer's loss); the
+multi-token-prediction module is ``layer_<n_layers>`` with
+``attn_proj/mtp_merge``, its head ``head_loss/mtp``. All of these sit
+INSIDE the scopes named first.
 """
 
 from __future__ import annotations
@@ -52,7 +68,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -116,10 +132,67 @@ class Config:
     #: O(T/P) memory) or "ulysses" (head-resharding all_to_alls,
     #: exact single-pass softmax; needs local heads % sp size == 0)
     sp_schedule: str = "ring"
+    #: layers 0 .. first_dense - 1 have a dense FFN and every later
+    #: one a mixture of experts (the source's first_k_dense_replace);
+    #: None: `moe_every` says which layers are which
+    first_dense: Optional[int] = None
+    #: an expert's width where it is not the dense layers' d_ff (0)
+    moe_d_ff: int = 0
+    #: "softmax" (the k largest probabilities of a softmax over all
+    #: experts) or "sigmoid" (ops.moe.sigmoid_routing: the k largest
+    #: sigmoid scores, chosen with the bias params[..]["wg_bias"] where
+    #: `router_bias`, renormalised per `norm_topk_prob`, times
+    #: `routed_scale`)
+    router_score: str = "softmax"
+    router_bias: bool = False
+    routed_scale: float = 1.0
+    #: experts every token passes through beside the routed ones: ONE
+    #: gated FFN of width n_shared_experts * expert width
+    n_shared_experts: int = 0
+    #: (first, count): the experts of every MoE layer THIS chip holds
+    #: (its w1 / w3 / w2 carry `count` experts); the router still
+    #: scores all `n_experts` and what the absent ones would add is
+    #: left out. None: all of them
+    held_experts: Optional[Tuple[int, int]] = None
+    #: "mha" (q, k, v of n_heads equal heads) or "mla" (latent
+    #: attention: low-rank q and kv paths with their own RMSNorms, per
+    #: head a no-position part of qk_nope_dim and a RoPE part of
+    #: qk_rope_dim whose key is ONE for all heads, values of v_head_dim)
+    attn: str = "mha"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    #: RoPE pairs dimensions 2i and 2i + 1 (the MLA and indexer parts;
+    #: pos="rope" on whole heads stays rotate-half)
+    rope_interleave: bool = False
+    #: the learned sparse-attention indexer (DSA) of an "mla" layer:
+    #: index_heads heads of index_dim score every causal key, each
+    #: query attends to its index_topk best (0: no indexer). A sequence
+    #: no longer than index_topk selects nothing: the indexer does not
+    #: run and attention is the causal one. Its loss (ops.attention.
+    #: dsa_kl, averaged over the layers that select) is added to the
+    #: mean next-token loss at index_loss_weight
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    index_loss_weight: float = 1.0
+    #: multi-token-prediction modules after the last layer
+    #: (params["mtp"]; DeepSeek-V3's, depth 1 supported) and the weight
+    #: of their loss
+    mtp_layers: int = 0
+    mtp_weight: float = 0.0
+    #: recompute each layer in the backward pass from its input
+    remat: bool = False
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,7 +214,13 @@ class Axes:
 
 
 def _is_moe(cfg: Config, layer: int) -> bool:
+    if cfg.first_dense is not None:
+        return layer >= cfg.first_dense
     return cfg.moe_every > 0 and (layer + 1) % cfg.moe_every == 0
+
+
+def _held_count(cfg: Config) -> int:
+    return cfg.held_experts[1] if cfg.held_experts else cfg.n_experts
 
 
 def init_params(rng: np.random.Generator, cfg: Config) -> Dict:
@@ -167,27 +246,71 @@ def init_params(rng: np.random.Generator, cfg: Config) -> Dict:
     if not cfg.tie_head:
         params["head"] = normal(v, d, scale=s_emb)
     params["ln_f"] = norm()
-    params["layers"] = []
-    for i in range(cfg.n_layers):
+
+    def gain(n):
+        return {"g": np.ones(n, pdt)}
+
+    def mla():
+        h, rq, rkv = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+        qk = cfg.qk_nope_dim + cfg.qk_rope_dim
         lp = {
-            "ln1": norm(), "ln2": norm(),
-            "wq": normal(d, d, scale=s_emb),
-            "wk": normal(d, d, scale=s_emb),
-            "wv": normal(d, d, scale=s_emb),
-            "wo": normal(d, d, scale=s_emb / math.sqrt(2 * cfg.n_layers)),
+            "wq_a": normal(d, rq, scale=s_emb), "q_a_norm": gain(rq),
+            "wq_b": normal(rq, h * qk, scale=1.0 / math.sqrt(rq)),
+            "wkv_a": normal(d, rkv + cfg.qk_rope_dim, scale=s_emb),
+            "kv_a_norm": gain(rkv),
+            "wkv_b": normal(rkv, h * (cfg.qk_nope_dim + cfg.v_head_dim),
+                            scale=1.0 / math.sqrt(rkv)),
+            "wo": normal(h * cfg.v_head_dim, d,
+                         scale=1.0 / math.sqrt(h * cfg.v_head_dim)
+                         / math.sqrt(2 * cfg.n_layers)),
         }
+        if cfg.index_topk:
+            lp.update(
+                wi_q=normal(rq, cfg.index_heads * cfg.index_dim,
+                            scale=1.0 / math.sqrt(rq)),
+                wi_k=normal(d, cfg.index_dim, scale=s_emb),
+                wi_k_norm={"g": np.ones(cfg.index_dim, pdt),
+                           "b": np.zeros(cfg.index_dim, pdt)},
+                wi_w=normal(d, cfg.index_heads, scale=s_emb))
+        return lp
+
+    def layer(moe: bool):
+        lp = {"ln1": norm(), "ln2": norm()}
+        if cfg.attn == "mla":
+            lp.update(mla())
+        else:
+            lp.update(
+                wq=normal(d, d, scale=s_emb), wk=normal(d, d, scale=s_emb),
+                wv=normal(d, d, scale=s_emb),
+                wo=normal(d, d, scale=s_emb / math.sqrt(2 * cfg.n_layers)))
         if cfg.qk_norm:
             lp["q_norm"] = {"g": np.ones(d, pdt)}
             lp["k_norm"] = {"g": np.ones(d, pdt)}
-        # experts carry a leading [n_experts] dimension
-        ex = (cfg.n_experts,) if _is_moe(cfg, i) else ()
+        # experts carry a leading [held experts] dimension
+        ex = (_held_count(cfg),) if moe else ()
+        fl = cfg.expert_d_ff if moe else f
         if ex:
             lp["wg"] = normal(d, cfg.n_experts, scale=s_emb)
-        lp["w1"] = normal(*ex, d, f, scale=s_emb)
+            if cfg.router_bias:
+                lp["wg_bias"] = normal(cfg.n_experts, scale=0.01)
+        lp["w1"] = normal(*ex, d, fl, scale=s_emb)
         if cfg.mlp_gated:
-            lp["w3"] = normal(*ex, d, f, scale=s_emb)
-        lp["w2"] = normal(*ex, f, d, scale=1.0 / math.sqrt(f))
-        params["layers"].append(lp)
+            lp["w3"] = normal(*ex, d, fl, scale=s_emb)
+        lp["w2"] = normal(*ex, fl, d, scale=1.0 / math.sqrt(fl))
+        if moe and cfg.n_shared_experts:
+            fs = cfg.n_shared_experts * fl
+            lp["ws1"] = normal(d, fs, scale=s_emb)
+            if cfg.mlp_gated:
+                lp["ws3"] = normal(d, fs, scale=s_emb)
+            lp["ws2"] = normal(fs, d, scale=1.0 / math.sqrt(fs))
+        return lp
+
+    params["layers"] = [layer(_is_moe(cfg, i)) for i in range(cfg.n_layers)]
+    if cfg.mtp_layers:
+        params["mtp"] = [dict(
+            layer(_is_moe(cfg, cfg.n_layers)), enorm=norm(), hnorm=norm(),
+            eh_proj=normal(2 * d, d, scale=1.0 / math.sqrt(2 * d)))
+            for _ in range(cfg.mtp_layers)]
     return params
 
 
@@ -205,19 +328,37 @@ def _like_params(cfg: Config, leaf, wide, expert):
     if not cfg.tie_head:
         tree["head"] = leaf
     tree["ln_f"] = norm()
-    tree["layers"] = []
     ffn = ("w1", "w3", "w2") if cfg.mlp_gated else ("w1", "w2")
-    for i in range(cfg.n_layers):
+
+    def layer(moe: bool):
         lt = {"ln1": norm(), "ln2": norm()}
-        lt.update({n: wide(n) for n in ("wq", "wk", "wv", "wo")})
+        if cfg.attn == "mla":  # replicated: no tp path yet
+            lt.update({n: leaf for n in ("wq_a", "wq_b", "wkv_a", "wkv_b",
+                                         "wo")})
+            lt.update(q_a_norm={"g": leaf}, kv_a_norm={"g": leaf})
+            if cfg.index_topk:
+                lt.update(wi_q=leaf, wi_k=leaf, wi_w=leaf,
+                          wi_k_norm={"g": leaf, "b": leaf})
+        else:
+            lt.update({n: wide(n) for n in ("wq", "wk", "wv", "wo")})
         if cfg.qk_norm:
             lt["q_norm"] = {"g": leaf}
             lt["k_norm"] = {"g": leaf}
-        if _is_moe(cfg, i):
+        if moe:
             lt.update({n: expert(n) for n in ("wg",) + ffn})
+            if cfg.router_bias:
+                lt["wg_bias"] = leaf
+            if cfg.n_shared_experts:  # a dense FFN inside the tp region
+                lt.update({"ws" + n[1:]: wide(n) for n in ffn})
         else:
             lt.update({n: wide(n) for n in ffn})
-        tree["layers"].append(lt)
+        return lt
+
+    tree["layers"] = [layer(_is_moe(cfg, i)) for i in range(cfg.n_layers)]
+    if cfg.mtp_layers:
+        tree["mtp"] = [dict(layer(_is_moe(cfg, cfg.n_layers)), enorm=norm(),
+                            hnorm=norm(), eh_proj=leaf)
+                       for _ in range(cfg.mtp_layers)]
     return tree
 
 
@@ -291,9 +432,45 @@ def rope(x, positions, theta: float):
                            axis=-1).astype(x.dtype)
 
 
+def rope_interleaved(x, positions, theta: float):
+    """Rotary positions on x [B, T, H, Dr] at integer `positions` [T]
+    with the INTERLEAVED pairing (dimension 2i with 2i + 1, frequency
+    theta^(-2i / Dr)), computed in float32, returned in x's type and
+    x's layout."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
 def _check_supported(cfg: Config, ax: Axes, is_moe: bool, pos_offset):
     """What the config may ask for that an axis cannot give yet is an
     error, never another function computed in silence."""
+    if cfg.attn == "mla" and (ax.tp or ax.sp):
+        raise NotImplementedError(
+            "latent attention (attn='mla') and its sparse-attention "
+            "mask under tensor or sequence parallelism (ax.tp, ax.sp): "
+            "the low-rank projections' column split, the shared RoPE "
+            "key and a [T, T] selection over a sharded sequence are "
+            "not written yet")
+    if cfg.attn not in ("mha", "mla"):
+        raise ValueError(f"attn={cfg.attn!r}: expected 'mha' or 'mla'")
+    if is_moe and cfg.held_experts and ax.ep:
+        raise NotImplementedError(
+            "held experts (held_experts) describe ONE chip's share of "
+            "a layer; over an expert-parallel axis (ax.ep) the share "
+            "comes from the exchange in the middle of the sort, which "
+            "is ROADMAP R1b")
+    if cfg.mtp_layers and ax.pp:
+        raise NotImplementedError(
+            "multi-token prediction under pipeline parallelism "
+            "(ax.pp): the last stage would need the embedding and a "
+            "second head; unequal pipeline stages are ROADMAP R3")
     if is_moe and ax.ep and (cfg.top_k != 1 or cfg.mlp_gated
                              or cfg.mlp_act != "relu"):
         raise NotImplementedError(
@@ -322,29 +499,157 @@ def _moe_sorted(flat, lp, cfg: Config, aux):
         logits = jnp.dot(flat.astype(jnp.float32),
                          lp["wg"].astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        route = moe_mod.topk_routing(logits, cfg.top_k,
-                                     cfg.norm_topk_prob)
+        if cfg.router_score == "sigmoid":
+            route = moe_mod.sigmoid_routing(
+                logits, lp["wg_bias"] if cfg.router_bias else None,
+                cfg.top_k, cfg.norm_topk_prob, cfg.routed_scale)
+        elif cfg.router_score == "softmax":
+            route = moe_mod.topk_routing(logits, cfg.top_k,
+                                         cfg.norm_topk_prob)
+        else:
+            raise ValueError(f"router_score={cfg.router_score!r}: "
+                             "expected 'softmax' or 'sigmoid'")
         if aux is not None:
             aux.append((moe_mod.load_balance_loss(route),
                         moe_mod.router_z_loss(route), route))
     w3 = lp["w3"].astype(dt) if cfg.mlp_gated else None
-    return moe_mod.sorted_moe_ffn(flat, route, lp["w1"].astype(dt), w3,
-                                  lp["w2"].astype(dt), cfg.mlp_act)
+    if cfg.held_experts is not None:
+        with jax.named_scope("moe_dispatch"):
+            route = moe_mod.held_share(route, *cfg.held_experts)
+    return moe_mod.sorted_moe_ffn(
+        flat, route, lp["w1"].astype(dt), w3, lp["w2"].astype(dt),
+        cfg.mlp_act)
+
+
+def _ffn(x, w1, w3, w2, cfg: Config):
+    """act(x W1) [* (x W3)] W2 in x's type."""
+    u = moe_mod.activation(cfg.mlp_act)(x @ w1.astype(x.dtype))
+    if w3 is not None:
+        u = u * (x @ w3.astype(x.dtype))
+    return u @ w2.astype(x.dtype)
+
+
+def _mla_project(lp, x, cfg: Config, positions):
+    """Latent attention's projections of the normed x [B, T, d]: (q, k
+    [B, T, H, nope + rope], v [B, T, H, v_head_dim], c_q [B, T,
+    q_lora_rank]: the normed query latent, which the indexer reads
+    too). RoPE (interleaved or rotate-half per the config) turns the
+    rope part of q and the ONE rope key all heads share."""
+    dt = cfg.dtype
+    b, t, _ = x.shape
+    h, nope, rkv = cfg.n_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
+    turn = rope_interleaved if cfg.rope_interleave else rope
+    with jax.named_scope("mla_q"):
+        c_q = _rms((x @ lp["wq_a"].astype(dt)).astype(jnp.float32),
+                   lp["q_a_norm"]["g"], cfg.norm_eps).astype(dt)
+        q = (c_q @ lp["wq_b"].astype(dt)).reshape(
+            b, t, h, nope + cfg.qk_rope_dim)
+    with jax.named_scope("mla_kv"):
+        kv_a = x @ lp["wkv_a"].astype(dt)
+        c_kv = _rms(kv_a[..., :rkv].astype(jnp.float32),
+                    lp["kv_a_norm"]["g"], cfg.norm_eps).astype(dt)
+        kv = (c_kv @ lp["wkv_b"].astype(dt)).reshape(
+            b, t, h, nope + cfg.v_head_dim)
+    with jax.named_scope("qk_rope"):
+        q_r = turn(q[..., nope:], positions, cfg.rope_theta)
+        k_r = turn(kv_a[:, :, None, rkv:], positions, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_r, q_r.shape)], axis=-1)
+    return q, k, kv[..., nope:], c_q
+
+
+def _index_project(lp, x, c_q, cfg: Config, positions):
+    """The indexer's (queries [B, T, Hi, Di], keys [B, T, Di] — one
+    for all its heads —, head weights [B, T, Hi] float32) from the
+    normed x and the query latent, both with their gradient STOPPED:
+    the indexer is trained by its own loss and nothing else learns
+    from it. RoPE turns the first qk_rope_dim dimensions of each."""
+    dt = cfg.dtype
+    b, t, _ = x.shape
+    hi, di, r = cfg.index_heads, cfg.index_dim, cfg.qk_rope_dim
+    x, c_q = lax.stop_gradient(x), lax.stop_gradient(c_q)
+    turn = rope_interleaved if cfg.rope_interleave else rope
+    qi = (c_q @ lp["wi_q"].astype(dt)).reshape(b, t, hi, di)
+    ki = _ln((x @ lp["wi_k"].astype(dt)).astype(jnp.float32),
+             lp["wi_k_norm"]["g"], lp["wi_k_norm"]["b"]).astype(dt)
+    qi = jnp.concatenate(
+        [turn(qi[..., :r], positions, cfg.rope_theta), qi[..., r:]], -1)
+    ki = jnp.concatenate(
+        [turn(ki[:, :, None, :r], positions, cfg.rope_theta)[:, :, 0],
+         ki[..., r:]], -1)
+    w = jnp.dot(x.astype(jnp.float32), lp["wi_w"].astype(jnp.float32),
+                precision=lax.Precision.HIGHEST) * (hi * di) ** -0.5
+    return qi, ki, w
+
+
+def _dsa_core(q, k, v, index, cfg: Config, index_aux):
+    """Attention over each query's index_topk keys, sequence by
+    sequence. `index_aux` receives (the indexer's loss, the selection
+    [B, T, T] bool)."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+
+    def one(q, k, v, qi, ki, w):
+        with jax.named_scope("dsa_index"):
+            scores = att.dsa_index_scores(qi, ki, w)
+            keep = att.dsa_select(lax.stop_gradient(scores),
+                                  cfg.index_topk)
+        with jax.named_scope("dsa_attend"):
+            o, p = att.dsa_attend(q, k, v, keep, scale)
+        with jax.named_scope("dsa_kl"):
+            return o, att.dsa_kl(scores, keep, p), keep
+
+    o, kl, keep = jax.vmap(one)(q, k, v, *index)
+    if index_aux is not None:
+        index_aux.append((kl.mean(), keep))
+    return o
+
+
+def _mla_attention(lp, h, x, cfg: Config, pos_offset, index_aux):
+    """h + the latent-attention half of a block on one device (x: the
+    normed h). Where the sequence is longer than index_topk each query
+    attends to the keys its indexer selects; else to all causal ones,
+    through the model's one causal entry."""
+    b, t = h.shape[0], h.shape[1]
+    positions = jnp.arange(t) if pos_offset is None \
+        else pos_offset + jnp.arange(t)
+    selects = bool(cfg.index_topk) and t > cfg.index_topk
+    pvar.record("attn_mla_layers")
+    with jax.named_scope("attn_proj"):
+        q, k, v, c_q = _mla_project(lp, x, cfg, positions)
+        if selects:
+            with jax.named_scope("dsa_index_proj"):
+                index = _index_project(lp, x, c_q, cfg, positions)
+    with jax.named_scope("attn_core"):
+        if selects:
+            pvar.record("attn_dsa_layers")
+            o = _dsa_core(q, k, v, index, cfg, index_aux)
+        else:  # the blockwise kernel wants one width for q, k and v
+            attend = att.attention if v.shape[-1] == q.shape[-1] \
+                else att.mha
+            o = attend(q, k, v, causal=True)
+    with jax.named_scope("attn_proj"), jax.named_scope("mla_o"):
+        return h + o.reshape(b, t, -1) @ lp["wo"].astype(cfg.dtype)
 
 
 def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
-                  pos_offset=None, aux=None):
+                  pos_offset=None, aux=None, index_aux=None):
     """One transformer block on local shards: pre-norm attention (+tp
-    Megatron f/g pair, +sp ring attention) then FFN or MoE. Shared by
+    Megatron f/g pair, +sp ring attention; or latent attention with
+    its sparse-attention indexer) then FFN or MoE. Shared by
     the layer loop below and the pipeline-parallel stage scan
     (models/pipeline.py). `pos_offset` is the global position of the
     shard's first token (RoPE under sp needs it; None = not given);
     `aux`, a list, receives a MoE layer's (load-balancing loss, z-loss,
-    routing: an ops.moe.TopKRoute)."""
+    routing: an ops.moe.TopKRoute); `index_aux`, a list, a selecting
+    layer's (indexer loss, selection [B, T, T])."""
     _check_supported(cfg, ax, is_moe, pos_offset)
     dt = cfg.dtype
     b, t = h.shape[0], h.shape[1]
     x = _norm(h.astype(jnp.float32), lp["ln1"], cfg).astype(dt)
+    if cfg.attn == "mla":
+        h = _mla_attention(lp, h, x, cfg, pos_offset, index_aux)
+        return _ffn_half(lp, h, cfg, ax, is_moe, aux)
     # The blockwise kernel takes q already scaled. Where it will run
     # (the rule att.attention applies below), 1/sqrt(Dh) goes in where q
     # is still float32 — the projection's accumulator or the QK-norm —
@@ -400,7 +705,14 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
         if ax.tp:
             o = region_exit(o, ax.tp)
         h = h + o
+    return _ffn_half(lp, h, cfg, ax, is_moe, aux)
 
+
+def _ffn_half(lp, h, cfg: Config, ax: Axes, is_moe: bool, aux):
+    """h + the FFN half of a block: dense, or the mixture of experts
+    (with its shared expert where the config has one)."""
+    dt = cfg.dtype
+    b, t = h.shape[0], h.shape[1]
     x = _norm(h.astype(jnp.float32), lp["ln2"], cfg).astype(dt)
     with jax.named_scope("mlp"):
         if ax.tp:
@@ -414,24 +726,48 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
                     capacity_factor=cfg.capacity_factor)
             else:
                 y = _moe_sorted(flat, lp, cfg, aux)
+            if cfg.n_shared_experts:
+                with jax.named_scope("moe_shared"):
+                    y = y + _ffn(flat, lp["ws1"], lp.get("ws3"), lp["ws2"],
+                                 cfg)
             if ax.tp:
                 y = region_exit(y, ax.tp)
             y = y.reshape(b, t, cfg.d_model)
         else:
-            u = moe_mod.activation(cfg.mlp_act)(x @ lp["w1"].astype(dt))
-            if cfg.mlp_gated:
-                u = u * (x @ lp["w3"].astype(dt))
-            y = u @ lp["w2"].astype(dt)
+            y = _ffn(x, lp["w1"], lp["w3"] if cfg.mlp_gated else None,
+                     lp["w2"], cfg)
             if ax.tp:
                 y = region_exit(y, ax.tp)
         return h + y
 
 
-def forward_local(params, tokens, cfg: Config, ax: Axes, aux=None):
-    """Forward pass on local shards (inside shard_map when any axis is
-    set). tokens: [B_local, T_local] int32 -> logits [B_local, T_local,
-    vocab] float32. `aux`, a list, receives each MoE layer's
-    (load-balancing loss, z-loss, routing)."""
+def _run_layer(lp, h, cfg: Config, ax: Axes, is_moe: bool, pos_offset,
+               aux, index_aux):
+    """layer_forward, recomputed in the backward pass from the layer's
+    input where the config says so (what a layer collects for `aux`
+    and `index_aux` then leaves the recomputed region as results)."""
+    if not cfg.remat:
+        return layer_forward(lp, h, cfg, ax, is_moe, pos_offset=pos_offset,
+                             aux=aux, index_aux=index_aux)
+
+    def layer(lp, h):
+        mine, index_mine = [], []
+        out = layer_forward(lp, h, cfg, ax, is_moe, pos_offset=pos_offset,
+                            aux=mine, index_aux=index_mine)
+        return out, mine, index_mine
+
+    out, mine, index_mine = jax.checkpoint(layer)(lp, h)
+    if aux is not None:
+        aux.extend(mine)
+    if index_aux is not None:
+        index_aux.extend(index_mine)
+    return out
+
+
+def _trunk(params, tokens, cfg: Config, ax: Axes, aux=None, index_aux=None):
+    """Embedding and layers on local shards: tokens [B_local, T_local]
+    -> the residual stream after the last layer, and the global
+    position of the shard's first token."""
     dt = cfg.dtype
     b, t = tokens.shape
     # global sequence offset of this sp shard
@@ -449,18 +785,61 @@ def forward_local(params, tokens, cfg: Config, ax: Axes, aux=None):
 
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope(f"layer_{i}"):
-            h = layer_forward(lp, h, cfg, ax, _is_moe(cfg, i),
-                              pos_offset=t_off, aux=aux)
+            h = _run_layer(lp, h, cfg, ax, _is_moe(cfg, i), t_off, aux,
+                           index_aux)
+    return h, t_off
 
+
+def _head(params, h, cfg: Config):
+    """Final norm and head: float32 logits [B, T, vocab]."""
+    dt = cfg.dtype
+    h = _norm(h.astype(jnp.float32), params["ln_f"], cfg)
+    # the head (tied: the embedding's transpose): bf16 operands at
+    # full MXU rate, f32 accumulation (the vocab matmul is the
+    # single largest matmul in the model; an f32xf32 product here
+    # runs at half the systolic-array throughput)
+    head = params["embed"] if cfg.tie_head else params["head"]
+    return jnp.einsum("btd,vd->btv", h.astype(dt), head.astype(dt),
+                      preferred_element_type=jnp.float32)
+
+
+def forward_local(params, tokens, cfg: Config, ax: Axes, aux=None,
+                  index_aux=None):
+    """Forward pass on local shards (inside shard_map when any axis is
+    set). tokens: [B_local, T_local] int32 -> logits [B_local, T_local,
+    vocab] float32. `aux`, a list, receives each MoE layer's
+    (load-balancing loss, z-loss, routing); `index_aux` each selecting
+    layer's (indexer loss, selection)."""
+    h, _ = _trunk(params, tokens, cfg, ax, aux, index_aux)
     with jax.named_scope("head_loss"):
-        h = _norm(h.astype(jnp.float32), params["ln_f"], cfg)
-        # the head (tied: the embedding's transpose): bf16 operands at
-        # full MXU rate, f32 accumulation (the vocab matmul is the
-        # single largest matmul in the model; an f32xf32 product here
-        # runs at half the systolic-array throughput)
-        head = params["embed"] if cfg.tie_head else params["head"]
-        return jnp.einsum("btd,vd->btv", h.astype(dt), head.astype(dt),
-                          preferred_element_type=jnp.float32)
+        return _head(params, h, cfg)
+
+
+def _mtp_forward(mp, h, params, labels, cfg: Config, ax: Axes, pos_offset,
+                 aux, index_aux):
+    """One multi-token-prediction module (DeepSeek-V3's): position i
+    merges the trunk's h[i] with the embedding of token i + 1 (the
+    label of i) — ``W_eh [norm(h) ; norm(emb)]`` — and runs one more
+    layer of the last layers' kind; the caller puts the SHARED final
+    norm and head on the result to predict token i + 2."""
+    dt = cfg.dtype
+    with jax.named_scope("attn_proj"), jax.named_scope("mtp_merge"):
+        e = params["embed"].astype(dt)[jnp.maximum(labels, 0)]
+        both = jnp.concatenate(
+            [_norm(h.astype(jnp.float32), mp["hnorm"], cfg),
+             _norm(e.astype(jnp.float32), mp["enorm"], cfg)], axis=-1)
+        h = both.astype(dt) @ mp["eh_proj"].astype(dt)
+    return _run_layer(mp, h, cfg, ax, _is_moe(cfg, cfg.n_layers),
+                      pos_offset, aux, index_aux)
+
+
+def _token_nll(logits, labels, mask):
+    """Summed cross-entropy of float32 logits over the masked
+    positions."""
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(
+        logits, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
+    return ((logz - gold) * mask).sum()
 
 
 def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
@@ -468,23 +847,63 @@ def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
     normalizes after cross-shard psum). Where the config weighs the
     router's losses, their mean over the MoE layers times the local
     count is in the sum, so the caller's nll / count is the mean CE
-    plus the weighted router losses."""
+    plus the weighted router losses; the indexers' loss (mean over the
+    selecting layers) and the multi-token-prediction loss (mean over
+    ITS positions) enter the same way at their weights."""
     aux = [] if (cfg.router_aux_weight or cfg.router_z_weight) else None
-    logits = forward_local(params, tokens, cfg, ax, aux)
+    if not (cfg.index_topk or cfg.mtp_layers):
+        logits = forward_local(params, tokens, cfg, ax, aux)
+        with jax.named_scope("head_loss"):
+            logits = logits.astype(jnp.float32)
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(
+                logits, labels[..., None], axis=-1)[..., 0]
+            mask = (labels >= 0).astype(jnp.float32)
+            nll = ((logz - gold) * mask).sum()
+            if aux:
+                with jax.named_scope("moe_route"):
+                    balance = sum(a[0] for a in aux) / len(aux)
+                    z = sum(a[1] for a in aux) / len(aux)
+                    nll = nll + mask.sum() * (
+                        cfg.router_aux_weight * balance
+                        + cfg.router_z_weight * z)
+            return nll, mask.sum()
+
+    index_aux = []
+    h, t_off = _trunk(params, tokens, cfg, ax, aux, index_aux)
     with jax.named_scope("head_loss"):
-        logits = logits.astype(jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(
-            logits, labels[..., None], axis=-1)[..., 0]
         mask = (labels >= 0).astype(jnp.float32)
-        nll = ((logz - gold) * mask).sum()
-        if aux:
-            with jax.named_scope("moe_route"):
-                balance = sum(a[0] for a in aux) / len(aux)
-                z = sum(a[1] for a in aux) / len(aux)
-                nll = nll + mask.sum() * (cfg.router_aux_weight * balance
-                                          + cfg.router_z_weight * z)
-        return nll, mask.sum()
+        count = mask.sum()
+        nll = _token_nll(_head(params, h, cfg), labels, mask)
+    extra = 0.0
+    if cfg.mtp_layers:
+        if cfg.mtp_layers != 1:
+            raise NotImplementedError(
+                "mtp_layers > 1: only DeepSeek-V3's depth-1 module is "
+                "written")
+        with jax.named_scope(f"layer_{cfg.n_layers}"):
+            h2 = _mtp_forward(params["mtp"][0], h, params, labels, cfg, ax,
+                              t_off, aux, index_aux)
+        with jax.named_scope("head_loss"), jax.named_scope("mtp"):
+            # position i predicts token i + 2, the label of i + 1; the
+            # last position has none
+            t = labels.shape[1]
+            labels2 = jnp.roll(labels, -1, axis=1)
+            mask2 = ((labels >= 0) & (labels2 >= 0)
+                     & (jnp.arange(t) < t - 1)[None]).astype(jnp.float32)
+            extra = extra + cfg.mtp_weight * _token_nll(
+                _head(params, h2, cfg), labels2, mask2) \
+                / jnp.maximum(mask2.sum(), 1.0)
+    if index_aux:
+        with jax.named_scope("head_loss"):
+            extra = extra + cfg.index_loss_weight * sum(
+                a[0] for a in index_aux) / len(index_aux)
+    if aux:
+        with jax.named_scope("moe_route"):
+            extra = extra + cfg.router_aux_weight * sum(
+                a[0] for a in aux) / len(aux) + cfg.router_z_weight * sum(
+                a[1] for a in aux) / len(aux)
+    return nll + count * extra, count
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -508,6 +927,10 @@ def route_counts(params, tokens, cfg: Config):
     pvar.record("moe_assignments", routed)
     pvar.record("moe_dropped_assignments",
                 counts.shape[0] * tokens.size * cfg.top_k - routed)
+    if cfg.held_experts:  # what this chip's share of each layer got
+        first, n = cfg.held_experts
+        pvar.record("moe_held_assignments",
+                    int(counts[:, first:first + n].sum()))
     return counts
 
 
@@ -516,6 +939,29 @@ def route_experts(params, tokens, cfg: Config):
     (the same probe as :func:`route_counts`; top-k of many is discrete,
     so this is what tells a re-routed token from a wrong one)."""
     return _route_probe(params, tokens, cfg)[1]
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _selection_probe(params, tokens, cfg: Config):
+    index_aux = []
+    forward_local(params, tokens, cfg, Axes(), None, index_aux)
+    return jnp.stack([keep for _, keep in index_aux])
+
+
+def dsa_selection(params, tokens, cfg: Config):
+    """bool [selecting layers, B, T, T]: the keys each query attends to
+    in each layer whose indexer selects, in a one-device forward pass
+    (empty where the sequence is no longer than index_topk). A probe
+    the host calls outside any timed window: what it counted goes to
+    the always-on counters `dsa_selected_pairs` and `dsa_causal_pairs`
+    (their ratio is the share of the causal pairs attention keeps)."""
+    b, t = tokens.shape
+    if not cfg.index_topk or t <= cfg.index_topk:
+        return jnp.zeros((0, b, t, t), bool)
+    keep = _selection_probe(params, tokens, cfg)
+    pvar.record("dsa_selected_pairs", int(keep.sum()))
+    pvar.record("dsa_causal_pairs", keep.shape[0] * b * t * (t + 1) // 2)
+    return keep
 
 
 def grad_sync(grads, specs, ax: Axes, extra=None):

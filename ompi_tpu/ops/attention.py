@@ -10,6 +10,20 @@ reference that is also the oracle for the blockwise kernel and for the
 distributed ring attention (:mod:`ompi_tpu.ops.ring_attention`, which
 builds on :func:`online_softmax_block`). Shapes follow
 [batch, seq, heads, head_dim] throughout.
+
+Learned sparse attention (DeepSeek-V3.2's DSA, as GLM-5 — the third
+published model of ``models/transformer.py``, reference
+``benchmark/reference/glm5_decoder.py`` — has it) is four functions of
+ONE sequence ([seq, heads, head_dim]; the model maps them over the
+batch): :func:`dsa_index_scores` (the indexer's [T, T] scores, its
+heads summed block by block), :func:`dsa_select` (each query's top-k
+keys, a mask shared by all heads), :func:`dsa_attend` (the softmax
+over the selected keys alone, and the head-summed probabilities) and
+:func:`dsa_kl` (the indexer's own loss). First support: every causal
+block of scores is computed and masked, in plain ``jax.numpy`` a block
+of rows at a time — exact, and nothing of [heads, T, T] exists; a
+kernel that skips the unselected keys is not written yet. A sequence no
+longer than the top-k selects nothing and takes :func:`attention`.
 """
 
 from __future__ import annotations
@@ -138,6 +152,143 @@ def attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
                    k_offset=k_offset)
     pvar.record("attn_blockwise_layers")
     return blockwise_mha(q, k, v, tile, scale=scale)
+
+
+# -- learned sparse attention: indexer scores, selection, attention over it ----
+
+#: Query rows, and heads, worked at a time by the functions below (the
+#: largest number of rows that divides T and is shorter than it): what
+#: bounds their temporaries — [heads at a time, rows, keys] float32 —
+#: since nothing of [heads, T, T] may exist. Chosen on the chip (v5e,
+#: one layer alone at T 4096, 64 heads of 256, forward + backward,
+#: PERF.md section 6, PR 30): attention 35.5 ms at 512 rows x 16 heads
+#: (34.6 at 256 x 16 with twice the blocks to compile, 43.1 at 1024 x
+#: 16, 45.1-51.8 with 32 or 64 heads at a time); the indexer's scores
+#: 6.1 ms at 256 rows (7.8 at 512, 9.9 at 1024). Plain Python blocks,
+#: no loop instruction: on the chip a `while` event carries no op path
+#: (its HLO does), so a trace reader counts the whole loop as unnamed
+#: time — one layer with `lax.scan` over the head groups: 35.3 of its
+#: 43.8 ms unnamed, and 5.7% slower than these blocks (44.1 against
+#: 41.8 ms) for a third less code.
+_DSA_ROWS = (512, 256, 128)
+_DSA_INDEX_ROWS = (256, 128)
+_DSA_HEADS = 16
+
+
+def dsa_row_blocks(t: int, sizes=None):
+    """[(first row, the row past the last)]: the blocks of query rows
+    (`sizes`: the candidates, `_DSA_ROWS` by default). A block sees
+    the keys up to its last row and no further."""
+    rows = next((b for b in sizes or _DSA_ROWS if t % b == 0 and t > b), t)
+    return [(a, a + rows) for a in range(0, t, rows)]
+
+
+def _causal(t_q: int, t_k: int, q_first: int = 0):
+    return (q_first + jnp.arange(t_q))[:, None] >= jnp.arange(t_k)[None, :]
+
+
+def dsa_index_scores(qi, ki, w):
+    """The indexer's score of every causal (query, key) pair of one
+    sequence: ``I[t, s] = sum_j w[t, j] relu(qi[t, j] . ki[s])``.
+    qi: [T, Hi, Di]; ki: [T, Di] (one key for all indexer heads); w:
+    [T, Hi] float32. Returns [T, T] float32, -inf where s > t. The
+    per-head scores exist for one block of rows at a time and are
+    summed over the heads there; the backward pass makes them again."""
+    t = qi.shape[0]
+
+    @jax.checkpoint
+    def block(qb, kb, wb):
+        s = jnp.einsum("qhd,kd->hqk", qb, kb,
+                       preferred_element_type=jnp.float32)
+        return (jnp.maximum(s, 0.0) * wb.T[:, :, None]).sum(0)
+
+    out = []
+    for a, b in dsa_row_blocks(t, _DSA_INDEX_ROWS):
+        got = jnp.where(_causal(b - a, b, a), block(qi[a:b], ki[:b], w[a:b]),
+                        -jnp.inf)
+        out.append(jnp.pad(got, ((0, 0), (0, t - b)),
+                           constant_values=-jnp.inf))
+    return jnp.concatenate(out) if len(out) > 1 else out[0]
+
+
+def _kth_largest(scores, k: int):
+    """Per row of float32 `scores`, the k-th largest value, exactly,
+    without a sort: the floats' bits as keys in the floats' order, and
+    the largest key that k of the row reach, found bit by bit (32
+    passes of compare-and-count over the row: 1.4 ms for [4096, 4096]
+    on a v5e where `lax.top_k`'s sort takes 8.6, PERF.md section 6, PR
+    30)."""
+    # one zero: -0.0 equals 0.0 as a float and must as a key
+    bits = lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores).astype(jnp.float32), jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    keys = jnp.where(bits >= top, ~bits, bits | top)
+    kth = jnp.zeros(scores.shape[:-1] + (1,), jnp.uint32)
+    for bit in range(31, -1, -1):
+        higher = kth | jnp.uint32(1 << bit)
+        enough = (keys >= higher).sum(-1, keepdims=True) >= k
+        kth = jnp.where(enough, higher, kth)
+    return keys, kth
+
+
+def dsa_select(scores, topk: int):
+    """bool [T, T]: per query the `topk` causal keys of largest score —
+    every causal key while there are no more than `topk` (a key whose
+    score ties with the last chosen one is chosen too). Discrete: no
+    gradient passes."""
+    t = scores.shape[-1]
+    keys, kth = _kth_largest(scores, min(topk, t))
+    return (keys >= kth) & _causal(t, t)
+
+
+def dsa_attend(q, k, v, keep, scale: float):
+    """Softmax attention of one sequence in which query t sees the keys
+    `keep[t]` and no others, the same for every head. q, k: [T, H, D];
+    v: [T, H, Dv]; keep: [T, T] bool. Returns (o [T, H, Dv] in q's
+    type, p [T, T] float32: the heads' probabilities summed over the
+    heads — a constant, for the indexer's loss). Exactly the softmax
+    over the kept keys: every causal block of scores is computed and
+    masked, `_DSA_HEADS` heads and one block of rows at a time (float32
+    scores and statistics), and made again in the backward pass."""
+    t, h, _ = q.shape
+    step = _DSA_HEADS if h % _DSA_HEADS == 0 else h
+    qh, kh, vh = (a.transpose(1, 0, 2) for a in (q, k, v))
+
+    @jax.checkpoint
+    def block(qb, kb, vb, keep_b):
+        s = jnp.einsum("hqd,hkd->hqk", qb, kb,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(keep_b[None], s, -jnp.inf)
+        e = jnp.exp(s - lax.stop_gradient(s.max(-1, keepdims=True)))
+        p = e / e.sum(-1, keepdims=True)
+        o = jnp.einsum("hqk,hkd->hqd", p.astype(vb.dtype), vb,
+                       preferred_element_type=jnp.float32).astype(qb.dtype)
+        return o, lax.stop_gradient(p.sum(0))
+
+    cat = lambda xs, ax: jnp.concatenate(xs, ax) \
+        if len(xs) > 1 else xs[0]  # noqa: E731
+    outs, probs = [], []
+    for a, b in dsa_row_blocks(t):
+        got = [block(qh[g:g + step, a:b], kh[g:g + step, :b],
+                     vh[g:g + step, :b], keep[a:b, :b])
+               for g in range(0, h, step)]
+        outs.append(cat([o for o, _ in got], 0))
+        probs.append(jnp.pad(sum(p for _, p in got), ((0, 0), (0, t - b))))
+    return cat(outs, 1).transpose(1, 0, 2), cat(probs, 0)
+
+
+def dsa_kl(scores, keep, p_heads):
+    """The indexer's loss for one sequence: ``mean_t KL(p_t ||
+    softmax(scores[t] over keep[t]))``, `p_t` the main attention's
+    probabilities summed over its heads (`dsa_attend`'s second result)
+    and L1-normalised — a constant; the gradient reaches `scores`
+    alone."""
+    p = lax.stop_gradient(p_heads / p_heads.sum(-1, keepdims=True))
+    logq = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
+    seen = keep & (p > 0)
+    logp = jnp.log(jnp.where(seen, p, 1.0))
+    return jnp.where(seen, p * (logp - jnp.where(seen, logq, 0.0)),
+                     0.0).sum(-1).mean()
 
 
 def online_softmax_block(q, k, v, o, l, m, mask=None,

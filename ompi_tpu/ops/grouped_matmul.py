@@ -146,15 +146,25 @@ def _gmm_kernel(offsets, group, tile, lhs, rhs, out, *, tm: int, sub: int,
 
     # a tile with a group edge in it: only the blocks of `sub` rows the
     # group reaches into, and of those only the group's rows — every
-    # row is written by the one visit of its own group (the tail's
-    # writes zeros), so what the other rows hold meanwhile is nobody's
-    @pl.when(jnp.logical_not(whole))
+    # row is written by the one visit of its own group, so what the
+    # other rows hold meanwhile is nobody's
+    @pl.when(jnp.logical_not(whole) & real)
     def _():
         def block(rows, first):
-            val = jnp.where(real, product(rows, slice(None)), 0.0)
+            val = product(rows, slice(None))
             out[rows, :] = jnp.where(
                 _mine(start, end, first, val.shape), val,
                 out[rows, :].astype(jnp.float32)).astype(out.dtype)
+        _edge_blocks(tm, sub, start, end, row0, block)
+
+    # the tail (the rows past the last group; a layer that holds a
+    # share of the experts has mostly such): zeros, and no product
+    @pl.when(jnp.logical_not(real))
+    def _():
+        def block(rows, first):
+            old = out[rows, :]
+            out[rows, :] = jnp.where(_mine(start, end, first, old.shape),
+                                     jnp.zeros_like(old), old)
         _edge_blocks(tm, sub, start, end, row0, block)
 
 
@@ -172,7 +182,8 @@ def gmm(lhs, rhs, sizes, tiles: Tuple[int, int, int],
         interpret: bool = False, run: int = RUN):
     """``lax.ragged_dot(lhs, rhs, sizes)``. lhs: [M, K]; rhs:
     [E, K, N] ([E, N, K] with `transpose_rhs`); sizes: [E] int32;
-    tiles: (tm, sub, tn). Returns [M, N]."""
+    tiles: (tm, sub, tn). Returns [M, N]; the rows past the last
+    group come out zero, and no product is made for them."""
     m, k = lhs.shape
     e = rhs.shape[0]
     n = rhs.shape[1 if transpose_rhs else 2]

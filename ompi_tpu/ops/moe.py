@@ -3,7 +3,16 @@
 **One device (``ax.ep`` is None): the drop-free sorted path.**
 :func:`topk_routing` (float32 softmax over all experts, top-k, the
 weights as they are unless the config renormalises them, and the
-ingredients of the two router losses) and :func:`sorted_moe_ffn`: the
+ingredients of the two router losses; OLMoE's) or
+:func:`sigmoid_routing` (float32 sigmoid scores, top-k of score +
+a selection bias without gradient, renormalised and scaled weights:
+DeepSeek-V3's ``noaux_tc``, as GLM-5 — the third published model of
+``models/transformer.py``, reference
+``benchmark/reference/glm5_decoder.py`` — has it), optionally
+:func:`held_share` (the routing as ONE chip of an expert-parallel
+deployment sees it: it holds some of the experts, the router scored
+them all, and the other chips' assignments sort past the last group
+where nobody computes them), and :func:`sorted_moe_ffn`: the
 ``T * k`` token-expert assignments are sorted by expert (stable), the
 rows gathered into expert order, the experts run as ONE grouped matmul
 per expert matrix over the ragged groups (:func:`grouped_matmul`: on
@@ -176,6 +185,30 @@ def topk_routing(logits, k: int, renormalize: bool = False) -> TopKRoute:
                      mean_prob=probs.mean(0), lse=lse)
 
 
+def sigmoid_routing(logits, bias, k: int, renormalize: bool = True,
+                    scale: float = 1.0) -> TopKRoute:
+    """The auxiliary-loss-free router (DeepSeek-V3's `noaux_tc`; GLM-5):
+    float32 sigmoid scores of ALL experts; the k experts are the k
+    largest of score + `bias` ([E], a correction buffer that carries no
+    gradient, or None), their weights the scores themselves (the bias
+    only chooses), renormalised to sum to 1 and multiplied by `scale`.
+    logits: [T, E]."""
+    logits = logits.astype(jnp.float32)
+    t, e = logits.shape
+    probs = jax.nn.sigmoid(logits)
+    choose = probs if bias is None else probs + lax.stop_gradient(
+        bias.astype(jnp.float32))
+    experts = lax.top_k(choose, k)[1]
+    weights = jnp.take_along_axis(probs, experts, axis=-1)
+    if renormalize:
+        weights = weights / weights.sum(-1, keepdims=True)
+    counts = (experts.reshape(t * k, 1)
+              == jnp.arange(e, dtype=experts.dtype)).sum(0, dtype=jnp.int32)
+    return TopKRoute(experts=experts, weights=weights * scale,
+                     counts=counts, mean_prob=probs.mean(0),
+                     lse=jax.nn.logsumexp(logits, axis=-1))
+
+
 def load_balance_loss(route: TopKRoute):
     """``E * sum_e f_e P_e`` (Switch / OLMoE): 1 when routing is
     uniform; the gradient reaches the router through P_e alone."""
@@ -339,7 +372,8 @@ def _tiles_of(rows, w) -> Optional[GroupedTiles]:
 def grouped_matmul(rows, w, counts, interpret: bool = False):
     """``lax.ragged_dot(rows, w, counts)``: rows [M, K] sorted by
     group, w [E, K, N], counts [E] int32 rows a group -> [M, N] in the
-    rows' type, float32 accumulation. Where :func:`grouped_tiles`
+    rows' type, float32 accumulation; rows past the last group
+    (``sum(counts) < M``) come out zero. Where :func:`grouped_tiles`
     gives tiles (the TPU) the product and both its transposes are
     Pallas kernels (ops/grouped_matmul.py); everywhere else it IS
     ``lax.ragged_dot``, jax's own transposes included."""
@@ -347,6 +381,22 @@ def grouped_matmul(rows, w, counts, interpret: bool = False):
     if tiles is None:
         return lax.ragged_dot(rows, w, counts)
     return _grouped_kernels(tiles, interpret)(rows, w, counts)
+
+
+def held_share(route: TopKRoute, first: int, count: int) -> TopKRoute:
+    """The routing as the chip that holds experts `first` ..
+    `first + count - 1` of a layer sees it: the router chose among ALL
+    experts; an assignment to a held expert keeps its weight and takes
+    the expert's local number, every other one takes number `count` —
+    it sorts after the held ones and weighs nothing — and `counts` are
+    the held experts' alone. Static shapes; nothing stands in for the
+    chips that hold the rest."""
+    local = route.experts - first
+    here = (local >= 0) & (local < count)
+    return route._replace(
+        experts=jnp.where(here, local, count),
+        weights=jnp.where(here, route.weights, 0.0),
+        counts=route.counts[first:first + count])
 
 
 def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
@@ -358,7 +408,10 @@ def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
     ``act(x W1_e) W2_e`` when not. The products are
     :func:`grouped_matmul`'s; which path they took is counted once per
     traced call (pvars ``moe_grouped_kernel_layers`` /
-    ``moe_ragged_dot_layers``)."""
+    ``moe_ragged_dot_layers``). An assignment whose expert number is
+    ``E`` or more (:func:`held_share`: another chip's expert) sorts
+    past the last group: its row comes out zero and is computed by
+    nobody."""
     t, k = route.experts.shape
     with jax.named_scope("moe_dispatch"):
         order = jnp.argsort(route.experts.reshape(t * k), stable=True)
