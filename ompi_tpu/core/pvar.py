@@ -51,6 +51,9 @@ WELL_KNOWN = (
     # (MLA); of those, the layers whose sparse-attention indexer
     # selects (the sequence is longer than index_topk)
     "attn_mla_layers", "attn_dsa_layers",
+    # ops/attention.dsa_attend, once per TRACED call: the Pallas
+    # kernels of ops/sparse_attention.py, or the masked blocks
+    "attn_dsa_kernel_layers", "attn_dsa_masked_layers",
     # the set-up probes transformer.dsa_selection / route_counts: the
     # (query, key) pairs attention keeps of the causal ones, and the
     # token-expert assignments that fell to the experts this chip holds

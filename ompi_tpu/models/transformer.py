@@ -57,7 +57,10 @@ matmuls, activation), ``moe_combine`` (un-sort, weighted sum),
 ``moe_shared`` (the shared expert). A latent-attention layer has
 ``attn_proj/{mla_q, mla_kv, mla_o, qk_rope, dsa_index_proj}`` and
 ``attn_core/{dsa_index, dsa_attend, dsa_kl}`` (the indexer's scores
-and top-k, attention over the selection, the indexer's loss); the
+and top-k; attention over the selection — on the TPU the Pallas
+kernels ``dsa_fwd``, ``dsa_head_sum`` and ``dsa_bwd`` of
+ops/sparse_attention.py, forward and backward both under this scope;
+the indexer's loss); the
 multi-token-prediction module is ``layer_<n_layers>`` with
 ``attn_proj/mtp_merge``, its head ``head_loss/mtp``. All of these sit
 INSIDE the scopes named first.

@@ -19,11 +19,20 @@ batch): :func:`dsa_index_scores` (the indexer's [T, T] scores, its
 heads summed block by block), :func:`dsa_select` (each query's top-k
 keys, a mask shared by all heads), :func:`dsa_attend` (the softmax
 over the selected keys alone, and the head-summed probabilities) and
-:func:`dsa_kl` (the indexer's own loss). First support: every causal
-block of scores is computed and masked, in plain ``jax.numpy`` a block
-of rows at a time — exact, and nothing of [heads, T, T] exists; a
-kernel that skips the unselected keys is not written yet. A sequence no
-longer than the top-k selects nothing and takes :func:`attention`.
+:func:`dsa_kl` (the indexer's own loss). Exact, and nothing of [heads,
+T, T] exists. On the TPU, where the rule :func:`dsa_tile` gives tiles,
+:func:`dsa_attend` is three Pallas kernels of the repo's own
+(ops/sparse_attention.py: an online softmax over (row block, key block)
+pairs whose mask tile is data, the heads' summed probabilities as a
+second pass, one fused backward; the key blocks above the diagonal are
+never visited, no score leaves VMEM); everywhere else, and for the
+indexer's scores everywhere, every causal block of scores is computed
+and masked in plain ``jax.numpy`` a block of rows at a time. Neither
+skips a block under the diagonal that no query selected: at a top-2048
+of T 4096 with seeded weights there is none (PERF.md section 6, PR 31).
+The library's splash kernels take such a mask too (a ``jax.Array``
+mask, jax 0.9.0) and lost the probe on the chip. A sequence no longer
+than the top-k selects nothing and takes :func:`attention`.
 """
 
 from __future__ import annotations
@@ -156,9 +165,11 @@ def attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
 
 # -- learned sparse attention: indexer scores, selection, attention over it ----
 
-#: Query rows, and heads, worked at a time by the functions below (the
-#: largest number of rows that divides T and is shorter than it): what
-#: bounds their temporaries — [heads at a time, rows, keys] float32 —
+#: Query rows, and heads, worked at a time by the plain-``jax.numpy``
+#: functions below — the indexer's scores everywhere, and the attention
+#: where `dsa_tile` refuses (`_dsa_attend_blocks`: the CPU, odd shapes)
+#: — the largest number of rows that divides T and is shorter than it:
+#: what bounds their temporaries, [heads at a time, rows, keys] float32,
 #: since nothing of [heads, T, T] may exist. Chosen on the chip (v5e,
 #: one layer alone at T 4096, 64 heads of 256, forward + backward,
 #: PERF.md section 6, PR 30): attention 35.5 ms at 512 rows x 16 heads
@@ -241,15 +252,121 @@ def dsa_select(scores, topk: int):
     return (keys >= kth) & _causal(t, t)
 
 
-def dsa_attend(q, k, v, keep, scale: float):
+#: The kernels' tiles. Rows and keys of a (row block, key block) pair:
+#: the largest that divides T. The heads a grid step works (a pair's
+#: mask tile is read once for all of them): the forward and the head
+#: sum take `_DSA_GROUP_BYTES` / itemsize heads (16 bfloat16, 8 float32:
+#: their blocks and accumulators are double that in float32);
+#: the backward keeps a group's dq whole in VMEM, [heads, T, D] float32
+#: beside the block it is written from, twice: `_DSA_DQ_BYTES` bounds
+#: that group, and a sequence so long that one head does not fit takes
+#: the blocks. Chosen on the chip (v5e, one layer alone at T 4096, 64
+#: heads of 256, forward + backward with the layout changes, PERF.md
+#: section 6, PR 31): 20.4 ms at 512 x 512 with 16 heads a step and 4
+#: in the backward (20.7 with 8 and 4, 21.6 with 4 and 2; 21.8-22.1 at
+#: 1024 rows or keys, 23.1 at 256 x 256) where the blocks below take
+#: 45.1.
+_DSA_TILES = (512, 256, 128)
+_DSA_KERNEL_HEADS = (16, 8, 4, 2, 1)
+_DSA_GROUP_BYTES = 32
+_DSA_DQ_BYTES = 40 * 1024 * 1024
+
+
+def dsa_tile(backend: str, t: int, heads: int, d_qk: int, d_v: int,
+             itemsize: int = 2):
+    """The rule that sends a :func:`dsa_attend` to the Pallas kernels
+    (ops/sparse_attention.py), made of what the call can observe: their
+    tiles (a ``sparse_attention.Tiles``), or None where it takes the
+    masked blocks — off the TPU, widths that are not multiples of the
+    128 lanes, a length no tile divides or whose dq does not fit in
+    VMEM for a single head."""
+    if backend != "tpu" or d_qk % 128 or d_v % 128:
+        return None
+    tile = next((b for b in _DSA_TILES if t % b == 0), None)
+    dq = t * d_qk * (4 + 2 * itemsize)  # scratch + the block, twice
+
+    def heads_a_step(fits):
+        return next((g for g in _DSA_KERNEL_HEADS
+                     if heads % g == 0 and fits(g)), None)
+
+    group = heads_a_step(lambda g: g * itemsize <= _DSA_GROUP_BYTES)
+    bwd = heads_a_step(lambda g: g * dq <= _DSA_DQ_BYTES)
+    if tile is None or bwd is None:
+        return None
+    from ompi_tpu.ops import sparse_attention as sa
+
+    return sa.Tiles(tile, tile, group, bwd)
+
+
+@functools.lru_cache(maxsize=None)
+def _dsa_kernels(tiles, interpret: bool):
+    """`dsa_attend`'s kernel path for one tile set: (attend: q, k, v
+    [H, T, .], the mask as int8 -> (o, lse), with the fused backward
+    kernel behind a ``custom_vjp``; head_sum). Imported late: Pallas is
+    1.3 s of Python that only a TPU run needs."""
+    from ompi_tpu.ops import sparse_attention as sa
+
+    on = dict(tiles=tiles, interpret=interpret)
+
+    @jax.custom_vjp
+    def attend(q, k, v, keep):
+        return sa.forward(q, k, v, keep, **on)
+
+    def fwd(q, k, v, keep):
+        o, lse = attend(q, k, v, keep)
+        return (o, lse), (q, k, v, keep.T, o, lse)  # dsa_bwd works S^T
+
+    def bwd(res, cts):
+        q, k, v, keep_t, o, lse = res
+        do = cts[0]  # lse feeds the constant probabilities alone
+        di = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+        return sa.backward(q, k, v, do, lse, di[:, None, :], keep_t,
+                           **on) + (None,)
+
+    attend.defvjp(fwd, bwd)
+    return attend, functools.partial(sa.head_sum, **on)
+
+
+def dsa_attend(q, k, v, keep, scale: float, interpret: bool = False):
     """Softmax attention of one sequence in which query t sees the keys
     `keep[t]` and no others, the same for every head. q, k: [T, H, D];
-    v: [T, H, Dv]; keep: [T, T] bool. Returns (o [T, H, Dv] in q's
-    type, p [T, T] float32: the heads' probabilities summed over the
-    heads — a constant, for the indexer's loss). Exactly the softmax
-    over the kept keys: every causal block of scores is computed and
-    masked, `_DSA_HEADS` heads and one block of rows at a time (float32
-    scores and statistics), and made again in the backward pass."""
+    v: [T, H, Dv]; keep: [T, T] bool, the causal mask included. Returns
+    (o [T, H, Dv] in q's type, p [T, T] float32: the heads'
+    probabilities summed over the heads — a constant, for the indexer's
+    loss). Exactly the softmax over the kept keys, float32 scores and
+    statistics. Where :func:`dsa_tile` gives tiles (the TPU) it runs as
+    the blockwise Pallas kernels of ops/sparse_attention.py — an online
+    softmax, no score outside VMEM, the key blocks above the diagonal
+    never visited, the backward one fused kernel — and everywhere else
+    as :func:`_dsa_attend_blocks`. Inside ``jit`` the choice is static;
+    it is counted once per traced call (pvars ``attn_dsa_kernel_layers``
+    / ``attn_dsa_masked_layers``)."""
+    t, h, d = q.shape
+    same = q.dtype == k.dtype == v.dtype and q.dtype in (jnp.bfloat16,
+                                                         jnp.float32)
+    tiles = dsa_tile(jax.default_backend(), t, h, d, v.shape[-1],
+                     q.dtype.itemsize) if same else None
+    if tiles is None:
+        pvar.record("attn_dsa_masked_layers")
+        return _dsa_attend_blocks(q, k, v, keep, scale)
+    pvar.record("attn_dsa_kernel_layers")
+    attend, head_sum = _dsa_kernels(tiles, interpret)
+    # the kernels have no scale of their own: q carries it (GLM-5's is
+    # 1/16: exact in any float type)
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    qh, kh, vh = (a.transpose(1, 0, 2) for a in (q, k, v))
+    o, lse = attend(qh, kh, vh, keep.astype(jnp.int8))
+    p = head_sum(*map(lax.stop_gradient, (qh, kh, lse)))
+    # the head sum reads no mask and writes no pair above the diagonal
+    return o.transpose(1, 0, 2), jnp.where(keep, p, 0.0)
+
+
+def _dsa_attend_blocks(q, k, v, keep, scale: float):
+    """:func:`dsa_attend` in plain ``jax.numpy``, what the rule falls
+    back to and the kernels' oracle: every causal block of scores is
+    computed and masked, `_DSA_HEADS` heads and one block of rows at a
+    time (float32 `[heads, rows, keys]` blocks in HBM), and made again
+    in the backward pass."""
     t, h, _ = q.shape
     step = _DSA_HEADS if h % _DSA_HEADS == 0 else h
     qh, kh, vh = (a.transpose(1, 0, 2) for a in (q, k, v))
