@@ -58,6 +58,12 @@ WELL_KNOWN = (
     # (query, key) pairs attention keeps of the causal ones, and the
     # token-expert assignments that fell to the experts this chip holds
     "dsa_selected_pairs", "dsa_causal_pairs", "moe_held_assignments",
+    # models/transformer.py: once per TRACED pass over the layer list,
+    # and per TRACED application of a layer (layers x passes); the
+    # set-up probe transformer.exit_stats: the labelled positions it
+    # read (the summed probability of exit s rides the dynamic name
+    # exit_mass_micro_p<s>, in millionths of a token)
+    "loop_passes", "loop_layer_applications", "exit_probe_tokens",
     # the phases of mpi.Init(), once per job (runtime/state.py,
     # runtime/device_plane.py; "import" also holds the import of
     # ompi_tpu.mpi itself): they end before any profiler session can

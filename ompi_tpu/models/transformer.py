@@ -42,6 +42,13 @@ layers of width ``moe_d_ff``, then ``mtp_layers`` multi-token-prediction
 modules with a second loss), a sigmoid ``noaux_tc`` router over all
 ``n_experts`` of which this chip holds ``held_experts``, a shared
 expert, and ``remat``: each layer recomputed in the backward pass.
+The fourth (reference ``benchmark/reference/ouro_decoder.py``) runs
+its layers more than once: ``loops`` passes over the ONE layer list
+with the same weights, the final norm between passes, a norm on every
+sub-layer's output too (``post_norm``), and after each pass an exit —
+the shared head's loss and a learned gate (``exit_gate``); the loss is
+the expected loss under the exit distribution the gates give, less
+``exit_entropy_weight`` times that distribution's entropy.
 
 Names on the device (``jax.named_scope``: metadata, the HLO is the
 same): the jitted step is module ``jit_ompi_train_step``; its ops carry
@@ -63,11 +70,16 @@ ops/sparse_attention.py, forward and backward both under this scope;
 the indexer's loss); the
 multi-token-prediction module is ``layer_<n_layers>`` with
 ``attn_proj/mtp_merge``, its head ``head_loss/mtp``. All of these sit
-INSIDE the scopes named first.
+INSIDE the scopes named first. Where the layers run more than once,
+pass s is ``loop_<s>`` AROUND its ``layer_<i>`` scopes, with the norm
+between passes as ``loop_<s>/ln``; exit s is ``head_loss/exit_<s>``
+and the gates, the exit distribution and its entropy
+``head_loss/exit_gate``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -188,6 +200,20 @@ class Config:
     mtp_weight: float = 0.0
     #: recompute each layer in the backward pass from its input
     remat: bool = False
+    #: passes over the layer list, every pass with the same weights;
+    #: the final norm (params["ln_f"]) is applied after EVERY pass, so
+    #: the next one starts from the normed state
+    loops: int = 1
+    #: a second norm on each sub-layer's OUTPUT, before it joins the
+    #: residual stream (leaves ln1_post, ln2_post)
+    post_norm: bool = False
+    #: an exit after every pass: the shared head's loss there, and a
+    #: gate sigmoid(h . w + b) (params["exit_gate"]) that says how much
+    #: of what has not left yet leaves; the loss is the expected loss
+    #: over the exits less exit_entropy_weight x the entropy of that
+    #: exit distribution, per token
+    exit_gate: bool = False
+    exit_entropy_weight: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -249,6 +275,9 @@ def init_params(rng: np.random.Generator, cfg: Config) -> Dict:
     if not cfg.tie_head:
         params["head"] = normal(v, d, scale=s_emb)
     params["ln_f"] = norm()
+    if cfg.exit_gate:
+        params["exit_gate"] = {"w": normal(d, scale=s_emb),
+                               "b": np.zeros(1, pdt)}
 
     def gain(n):
         return {"g": np.ones(n, pdt)}
@@ -279,6 +308,8 @@ def init_params(rng: np.random.Generator, cfg: Config) -> Dict:
 
     def layer(moe: bool):
         lp = {"ln1": norm(), "ln2": norm()}
+        if cfg.post_norm:
+            lp.update(ln1_post=norm(), ln2_post=norm())
         if cfg.attn == "mla":
             lp.update(mla())
         else:
@@ -331,10 +362,14 @@ def _like_params(cfg: Config, leaf, wide, expert):
     if not cfg.tie_head:
         tree["head"] = leaf
     tree["ln_f"] = norm()
+    if cfg.exit_gate:
+        tree["exit_gate"] = {"w": leaf, "b": leaf}
     ffn = ("w1", "w3", "w2") if cfg.mlp_gated else ("w1", "w2")
 
     def layer(moe: bool):
         lt = {"ln1": norm(), "ln2": norm()}
+        if cfg.post_norm:
+            lt.update(ln1_post=norm(), ln2_post=norm())
         if cfg.attn == "mla":  # replicated: no tp path yet
             lt.update({n: leaf for n in ("wq_a", "wq_b", "wkv_a", "wkv_b",
                                          "wo")})
@@ -469,6 +504,13 @@ def _check_supported(cfg: Config, ax: Axes, is_moe: bool, pos_offset):
             "a layer; over an expert-parallel axis (ax.ep) the share "
             "comes from the exchange in the middle of the sort, which "
             "is ROADMAP R1b")
+    if (cfg.loops > 1 or cfg.exit_gate) and ax.pp:
+        raise NotImplementedError(
+            "a layer stack run more than once (loops > 1) and exits "
+            "after each pass (exit_gate) under pipeline parallelism "
+            "(ax.pp): the last stage would feed the first and every "
+            "pass would need the head; a repeated stack under pp is "
+            "ROADMAP Queue 2a (models/pipeline.py)")
     if cfg.mtp_layers and ax.pp:
         raise NotImplementedError(
             "multi-token prediction under pipeline parallelism "
@@ -530,6 +572,14 @@ def _ffn(x, w1, w3, w2, cfg: Config):
     if w3 is not None:
         u = u * (x @ w3.astype(x.dtype))
     return u @ w2.astype(x.dtype)
+
+
+def _residual(h, y, post, cfg: Config):
+    """h + y; where the config puts a norm on a sub-layer's output
+    (`post`: that norm's leaves), h + norm(y)."""
+    if post is None:
+        return h + y
+    return h + _norm(y.astype(jnp.float32), post, cfg).astype(y.dtype)
 
 
 def _mla_project(lp, x, cfg: Config, positions):
@@ -632,7 +682,8 @@ def _mla_attention(lp, h, x, cfg: Config, pos_offset, index_aux):
                 else att.mha
             o = attend(q, k, v, causal=True)
     with jax.named_scope("attn_proj"), jax.named_scope("mla_o"):
-        return h + o.reshape(b, t, -1) @ lp["wo"].astype(cfg.dtype)
+        return _residual(h, o.reshape(b, t, -1) @ lp["wo"].astype(cfg.dtype),
+                         lp["ln1_post"] if cfg.post_norm else None, cfg)
 
 
 def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
@@ -707,7 +758,7 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
         o = o @ lp["wo"].astype(dt)   # row parallel: partial sums
         if ax.tp:
             o = region_exit(o, ax.tp)
-        h = h + o
+        h = _residual(h, o, lp["ln1_post"] if cfg.post_norm else None, cfg)
     return _ffn_half(lp, h, cfg, ax, is_moe, aux)
 
 
@@ -741,7 +792,8 @@ def _ffn_half(lp, h, cfg: Config, ax: Axes, is_moe: bool, aux):
                      lp["w2"], cfg)
             if ax.tp:
                 y = region_exit(y, ax.tp)
-        return h + y
+        return _residual(h, y, lp["ln2_post"] if cfg.post_norm else None,
+                         cfg)
 
 
 def _run_layer(lp, h, cfg: Config, ax: Axes, is_moe: bool, pos_offset,
@@ -767,10 +819,14 @@ def _run_layer(lp, h, cfg: Config, ax: Axes, is_moe: bool, pos_offset,
     return out
 
 
-def _trunk(params, tokens, cfg: Config, ax: Axes, aux=None, index_aux=None):
+def _trunk(params, tokens, cfg: Config, ax: Axes, aux=None, index_aux=None,
+           exits=None):
     """Embedding and layers on local shards: tokens [B_local, T_local]
     -> the residual stream after the last layer, and the global
-    position of the shard's first token."""
+    position of the shard's first token. Where the config runs the
+    layers more than once, every pass but the last ends in the final
+    norm and the next starts from that normed state, which `exits`, a
+    list, receives."""
     dt = cfg.dtype
     b, t = tokens.shape
     # global sequence offset of this sp shard
@@ -786,24 +842,47 @@ def _trunk(params, tokens, cfg: Config, ax: Axes, aux=None, index_aux=None):
                 if ax.sp else params["pos"][:t]
             h = h + pos.astype(dt)[None]
 
-    for i, lp in enumerate(params["layers"]):
-        with jax.named_scope(f"layer_{i}"):
-            h = _run_layer(lp, h, cfg, ax, _is_moe(cfg, i), t_off, aux,
-                           index_aux)
+    for s in range(cfg.loops):
+        with jax.named_scope(f"loop_{s}") if cfg.loops > 1 \
+                else contextlib.nullcontext():
+            pvar.record("loop_passes")
+            for i, lp in enumerate(params["layers"]):
+                pvar.record("loop_layer_applications")
+                with jax.named_scope(f"layer_{i}"):
+                    h = _run_layer(lp, h, cfg, ax, _is_moe(cfg, i), t_off,
+                                   aux, index_aux)
+            if s < cfg.loops - 1:
+                h = _final_norm(params, h, cfg)
+                if exits is not None:
+                    exits.append(h)
     return h, t_off
+
+
+def _final_norm(params, h, cfg: Config):
+    """The final norm of the residual stream, in the activations'
+    type."""
+    return _norm(h.astype(jnp.float32), params["ln_f"], cfg).astype(
+        cfg.dtype)
+
+
+def _head_matrix(params, cfg: Config):
+    """The head's [vocab, d] matrix (tied: the embedding)."""
+    return params["embed"] if cfg.tie_head else params["head"]
+
+
+def _head_logits(head, x, cfg: Config):
+    """The head on a final-normed x: float32 logits [B, T, vocab]."""
+    # bf16 operands at full MXU rate, f32 accumulation (the vocab
+    # matmul is the single largest matmul in the model; an f32xf32
+    # product here runs at half the systolic-array throughput)
+    return jnp.einsum("btd,vd->btv", x, head.astype(cfg.dtype),
+                      preferred_element_type=jnp.float32)
 
 
 def _head(params, h, cfg: Config):
     """Final norm and head: float32 logits [B, T, vocab]."""
-    dt = cfg.dtype
-    h = _norm(h.astype(jnp.float32), params["ln_f"], cfg)
-    # the head (tied: the embedding's transpose): bf16 operands at
-    # full MXU rate, f32 accumulation (the vocab matmul is the
-    # single largest matmul in the model; an f32xf32 product here
-    # runs at half the systolic-array throughput)
-    head = params["embed"] if cfg.tie_head else params["head"]
-    return jnp.einsum("btd,vd->btv", h.astype(dt), head.astype(dt),
-                      preferred_element_type=jnp.float32)
+    return _head_logits(_head_matrix(params, cfg),
+                        _final_norm(params, h, cfg), cfg)
 
 
 def forward_local(params, tokens, cfg: Config, ax: Axes, aux=None,
@@ -845,6 +924,54 @@ def _token_nll(logits, labels, mask):
     return ((logz - gold) * mask).sum()
 
 
+def _exit_terms(params, exits, h, labels, mask, cfg: Config):
+    """Per exit, at each position [B, T] in float32: (the shared
+    head's cross-entropy there, the log of the probability of leaving
+    there). `exits`: the final-normed state after each pass but the
+    last, `h` the last pass's state before its norm. Exit s is taken with probability lambda_s x prod_{j<s} (1 - lambda_j),
+    lambda_s = sigmoid(state_s . w + b); the last takes what is left.
+    An exit's logits are made again in the backward pass, so that one
+    exit's [B, T, vocab] is alive at a time."""
+    def nll(x, head):
+        logits = _head_logits(head, x, cfg)
+        # the label's logit by a mask, not a gather: a gather's
+        # transpose scatters into the FLATTENED logits, and the chip
+        # pays a relayout copy of [B, T, vocab] float32 for it (6.6 ms
+        # an exit, under no scope's name: PERF.md 6, PR 32)
+        hit = lax.broadcasted_iota(jnp.int32, logits.shape, 2) \
+            == jnp.maximum(labels, 0)[..., None]
+        gold = jnp.where(hit, logits, 0.0).sum(-1)
+        return (jax.nn.logsumexp(logits, axis=-1) - gold) * mask
+
+    states = exits + [_final_norm(params, h, cfg)]
+    nlls = []
+    for s, x in enumerate(states):
+        with jax.named_scope(f"exit_{s}"):
+            nlls.append(jax.checkpoint(nll)(x, _head_matrix(params, cfg)))
+    with jax.named_scope("exit_gate"):
+        gate = params["exit_gate"]
+        logps, left = [], 0.0
+        for x in states[:-1]:
+            z = jnp.einsum("btd,d->bt", x.astype(jnp.float32),
+                           gate["w"].astype(jnp.float32),
+                           precision=lax.Precision.HIGHEST) \
+                + gate["b"].astype(jnp.float32)
+            logps.append(left + jax.nn.log_sigmoid(z))
+            left = left + jax.nn.log_sigmoid(-z)
+        logps.append(left + jnp.zeros_like(mask))
+    return nlls, logps
+
+
+def _exit_loss(nlls, logps, mask, cfg: Config):
+    """The summed loss over the exits: per position the expected
+    cross-entropy under the exit distribution, less
+    exit_entropy_weight x that distribution's entropy."""
+    with jax.named_scope("exit_gate"):
+        each = sum(jnp.exp(lp) * (n + cfg.exit_entropy_weight * lp)
+                   for n, lp in zip(nlls, logps))
+        return (each * mask).sum()
+
+
 def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
     """Summed next-token CE over local tokens + local count (caller
     normalizes after cross-shard psum). Where the config weighs the
@@ -854,7 +981,7 @@ def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
     selecting layers) and the multi-token-prediction loss (mean over
     ITS positions) enter the same way at their weights."""
     aux = [] if (cfg.router_aux_weight or cfg.router_z_weight) else None
-    if not (cfg.index_topk or cfg.mtp_layers):
+    if not (cfg.index_topk or cfg.mtp_layers or cfg.exit_gate):
         logits = forward_local(params, tokens, cfg, ax, aux)
         with jax.named_scope("head_loss"):
             logits = logits.astype(jnp.float32)
@@ -873,11 +1000,16 @@ def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
             return nll, mask.sum()
 
     index_aux = []
-    h, t_off = _trunk(params, tokens, cfg, ax, aux, index_aux)
+    exits = [] if cfg.exit_gate else None
+    h, t_off = _trunk(params, tokens, cfg, ax, aux, index_aux, exits)
     with jax.named_scope("head_loss"):
         mask = (labels >= 0).astype(jnp.float32)
         count = mask.sum()
-        nll = _token_nll(_head(params, h, cfg), labels, mask)
+        if cfg.exit_gate:
+            nll = _exit_loss(*_exit_terms(params, exits, h, labels, mask,
+                                          cfg), mask, cfg)
+        else:
+            nll = _token_nll(_head(params, h, cfg), labels, mask)
     extra = 0.0
     if cfg.mtp_layers:
         if cfg.mtp_layers != 1:
@@ -965,6 +1097,33 @@ def dsa_selection(params, tokens, cfg: Config):
     pvar.record("dsa_selected_pairs", int(keep.sum()))
     pvar.record("dsa_causal_pairs", keep.shape[0] * b * t * (t + 1) // 2)
     return keep
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _exit_probe(params, tokens, labels, cfg: Config):
+    exits = []
+    h, _ = _trunk(params, tokens, cfg, Axes(), exits=exits)
+    with jax.named_scope("head_loss"):
+        mask = (labels >= 0).astype(jnp.float32)
+        nlls, logps = _exit_terms(params, exits, h, labels, mask, cfg)
+        return (jnp.stack([n.sum() for n in nlls]),
+                jnp.stack([(jnp.exp(lp) * mask).sum() for lp in logps]),
+                mask.sum())
+
+
+def exit_stats(params, tokens, labels, cfg: Config):
+    """(mean cross-entropy at each exit [loops], mean probability of
+    leaving at each exit [loops]) over the batch's labelled positions,
+    in a one-device forward pass of a config with exits. A probe the
+    host calls outside any timed window: what it counted goes to the
+    always-on counters `exit_probe_tokens` and `exit_mass_micro_p<s>`
+    (the summed probability of exit s, in millionths of a token)."""
+    nll, mass, count = (np.asarray(a, np.float64) for a in _exit_probe(
+        params, tokens, labels, cfg))
+    pvar.record("exit_probe_tokens", int(count))
+    for s, m in enumerate(mass):
+        pvar.record(f"exit_mass_micro_p{s}", int(round(m * 1e6)))
+    return nll / count, mass / count
 
 
 def grad_sync(grads, specs, ax: Axes, extra=None):
