@@ -47,6 +47,13 @@ WELL_KNOWN = (
     # ops/moe.sorted_moe_ffn, once per TRACED MoE layer: its grouped
     # matmuls are the Pallas kernels, or lax.ragged_dot
     "moe_grouped_kernel_layers", "moe_ragged_dot_layers",
+    # the same, once per TRACED MoE layer: the layer carries a bounded
+    # number of rows (ops/moe.held_rows_bound: the chip holds a share
+    # of the experts) with the full path as its fallback, or all T * k
+    # rows and no second path; and, from the set-up probe
+    # transformer.route_counts, the layer-batches whose held
+    # assignments exceed the bound: how often the fallback would run
+    "moe_bounded_layers", "moe_full_layers", "moe_over_bound_layers",
     # models/transformer.py, once per TRACED layer: latent attention
     # (MLA); of those, the layers whose sparse-attention indexer
     # selects (the sequence is longer than index_topk)

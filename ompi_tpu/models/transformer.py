@@ -558,12 +558,15 @@ def _moe_sorted(flat, lp, cfg: Config, aux):
             aux.append((moe_mod.load_balance_loss(route),
                         moe_mod.router_z_loss(route), route))
     w3 = lp["w3"].astype(dt) if cfg.mlp_gated else None
+    bound = None  # every expert is here: all the rows
     if cfg.held_experts is not None:
         with jax.named_scope("moe_dispatch"):
             route = moe_mod.held_share(route, *cfg.held_experts)
+        bound = moe_mod.held_rows_bound(
+            flat.shape[0], cfg.top_k, cfg.held_experts[1], cfg.n_experts)
     return moe_mod.sorted_moe_ffn(
         flat, route, lp["w1"].astype(dt), w3, lp["w2"].astype(dt),
-        cfg.mlp_act)
+        cfg.mlp_act, bound)
 
 
 def _ffn(x, w1, w3, w2, cfg: Config):
@@ -1056,7 +1059,11 @@ def route_counts(params, tokens, cfg: Config):
     timed window (it waits for the result): what it counted goes to
     the always-on counters `moe_assignments` and
     `moe_dropped_assignments` — the second is what a capacity or an
-    exchange lost, and reads 0 on the sorted path by construction."""
+    exchange lost, and reads 0 on the sorted path by construction —
+    and, for a chip that holds a share of the experts,
+    `moe_held_assignments` and `moe_over_bound_layers`: the layers of
+    this batch whose held assignments exceed the rows the layer is
+    bounded at (`ops/moe.held_rows_bound`), which take its full path."""
     counts = _route_probe(params, tokens, cfg)[0]
     routed = int(counts.sum())
     pvar.record("moe_assignments", routed)
@@ -1064,8 +1071,12 @@ def route_counts(params, tokens, cfg: Config):
                 counts.shape[0] * tokens.size * cfg.top_k - routed)
     if cfg.held_experts:  # what this chip's share of each layer got
         first, n = cfg.held_experts
-        pvar.record("moe_held_assignments",
-                    int(counts[:, first:first + n].sum()))
+        held = np.asarray(counts[:, first:first + n].sum(1))
+        pvar.record("moe_held_assignments", int(held.sum()))
+        # the layers of this batch that would take the full path
+        pvar.record("moe_over_bound_layers", int((
+            held > moe_mod.held_rows_bound(tokens.size, cfg.top_k, n,
+                                           cfg.n_experts)).sum()))
     return counts
 
 

@@ -157,8 +157,10 @@ def _gmm_kernel(offsets, group, tile, lhs, rhs, out, *, tm: int, sub: int,
                 out[rows, :].astype(jnp.float32)).astype(out.dtype)
         _edge_blocks(tm, sub, start, end, row0, block)
 
-    # the tail (the rows past the last group; a layer that holds a
-    # share of the experts has mostly such): zeros, and no product
+    # the tail (the rows past the last group: a layer that holds a
+    # share of the experts bounds its rows at a few times the share,
+    # ops/moe.held_rows_bound, so a few tiles of it; most of the rows
+    # only on that layer's full path): zeros, and no product
     @pl.when(jnp.logical_not(real))
     def _():
         def block(rows, first):

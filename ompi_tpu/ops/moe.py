@@ -25,7 +25,14 @@ gated (``w3``) or plain ReLU experts; differentiable with respect to
 the rows, the expert weights and, through the weights, the router.
 Both permutations are gathers in the forward AND the backward pass
 (``custom_vjp``): the transpose of a permutation is its inverse, and a
-scatter-add is the slow way to say so on a TPU.
+scatter-add is the slow way to say so on a TPU. **A chip that holds a
+share of the experts** carries a static BOUND of rows instead of all
+``T * k`` (the rule :func:`held_rows_bound`: a few times its share):
+the first `bound` rows of the same sort are gathered, multiplied by
+the same kernels and summed back per token by a 0/1 product on the
+MXU (:func:`_sum_rows`), and a batch that sends the chip more rows
+than that takes the layer over all rows instead, inside one
+``lax.cond`` a direction — exact, counted, never a drop.
 
 **Expert parallel (``ax.ep``): capacity-based top-1 over all_to_all.**
 BASELINE.md config #5 is the MPI_Alltoall(v) MoE expert-dispatch
@@ -376,11 +383,24 @@ def grouped_matmul(rows, w, counts, interpret: bool = False):
     (``sum(counts) < M``) come out zero. Where :func:`grouped_tiles`
     gives tiles (the TPU) the product and both its transposes are
     Pallas kernels (ops/grouped_matmul.py); everywhere else it IS
-    ``lax.ragged_dot``, jax's own transposes included."""
+    ``lax.ragged_dot``, jax's own transposes included (on the TPU with
+    the tail zeroed around it: :func:`_ragged_dot_zero_tail`)."""
     tiles = _tiles_of(rows, w)
     if tiles is None:
-        return lax.ragged_dot(rows, w, counts)
+        return (_ragged_dot_zero_tail if jax.default_backend() == "tpu"
+                else lax.ragged_dot)(rows, w, counts)
     return _grouped_kernels(tiles, interpret)(rows, w, counts)
+
+
+def _ragged_dot_zero_tail(rows, w, counts):
+    """``lax.ragged_dot`` whose rows past the last group are ZERO, in
+    the product and in the rows' gradient: libtpu's kernels leave there
+    what memory held (read on the chip, PR 33: a 31/32 tail through
+    them gave the rows' gradient a relative error of 24; OLMoE, their
+    only user before, has no tail)."""
+    inside = (jnp.arange(rows.shape[0]) < counts.sum())[:, None]
+    return jnp.where(inside, lax.ragged_dot(
+        jnp.where(inside, rows, 0), w, counts), 0)
 
 
 def held_share(route: TopKRoute, first: int, count: int) -> TopKRoute:
@@ -389,8 +409,10 @@ def held_share(route: TopKRoute, first: int, count: int) -> TopKRoute:
     experts; an assignment to a held expert keeps its weight and takes
     the expert's local number, every other one takes number `count` —
     it sorts after the held ones and weighs nothing — and `counts` are
-    the held experts' alone. Static shapes; nothing stands in for the
-    chips that hold the rest."""
+    the held experts' alone. The shapes stay the router's (``T * k``
+    assignments); how many rows of them the layer then carries is
+    :func:`held_rows_bound`'s to say. Nothing stands in for the chips
+    that hold the rest."""
     local = route.experts - first
     here = (local >= 0) & (local < count)
     return route._replace(
@@ -399,8 +421,212 @@ def held_share(route: TopKRoute, first: int, count: int) -> TopKRoute:
         counts=route.counts[first:first + count])
 
 
+#: How many times its mean share of a layer's assignments a chip's
+#: rows are bounded at: the smallest power of two that clears twice
+#: over the most the probe has read. On the chip (PR 33,
+#: glm5-train-t4096, 8 of 256 experts held: `transformer.route_counts`
+#: over 24 seeds — the cell's calibration seeds among them — x 8
+#: batches x 4 layers): 685 ... 1,579 held rows a layer and batch
+#: around the share's 1,024, so twice the most is 3,158 of 4 x 1,024.
+SLACK = 4
+
+
+def held_rows_bound(t: int, k: int, count: int, n_experts: int) -> int:
+    """The static number of rows :func:`sorted_moe_ffn` works on for a
+    chip that holds `count` of a layer's `n_experts`: `SLACK` times
+    the share ``count / n_experts`` of the ``t * k`` assignments,
+    rounded up to whole row tiles, and never more than all of them
+    (the whole of them at share 1 and wherever `SLACK` shares cover the
+    layer: no second path exists there). A batch that sends the chip
+    more takes the layer's full path, counted
+    (`moe_over_bound_layers`)."""
+    rows = t * k
+    want = -(-SLACK * count * rows // n_experts)
+    return min(rows, -(-want // _TM) * _TM)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _take_rows(x, token, t: int):
+    """x[token]: the rows of x [t, D] that `token` [B] names. Its
+    transpose is :func:`_sum_rows`, and that one's is this."""
+    return x[token]
+
+
+def _take_rows_fwd(x, token, t):
+    return x[token], token
+
+
+def _take_rows_bwd(t, token, g):
+    return _sum_rows(g, token, t), None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _sum_rows(v, token, t: int):
+    """[t, D] in v's type: row i is the sum of the rows of v [B, D]
+    whose `token` is i (at most k of them), summed in float32 whatever
+    v's type. On the MXU, which the expert layer of a chip with a
+    small share leaves idle: the 0/1 matrix ``[t, B]`` is exact in
+    bfloat16 and so is each of the three bfloat16 pieces a float32 v is
+    cut into, so every product is exact and only the float32 sum's
+    order is the hardware's."""
+    hot = (jnp.arange(t, dtype=token.dtype)[:, None]
+           == token[None, :]).astype(jnp.bfloat16)
+    total, rest = 0.0, v.astype(jnp.float32)
+    for _ in range(1 if v.dtype == jnp.bfloat16 else 3):
+        # reduce_precision, not a cast there and back: the compiler
+        # may keep excess precision through a pair of converts
+        piece = lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
+        total = total + jnp.dot(hot, piece.astype(jnp.bfloat16),
+                                preferred_element_type=jnp.float32)
+        rest = rest - piece
+    return total.astype(v.dtype)
+
+
+def _sum_rows_fwd(v, token, t):
+    return _sum_rows(v, token, t), token
+
+
+def _sum_rows_bwd(t, token, g):
+    return _take_rows(g, token, t), None
+
+
+_sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_head(flat, order, inv, bound: int):
+    """flat[order[:bound]] for a permutation and its inverse — a gather
+    both ways."""
+    return flat[order[:bound]]
+
+
+def _take_head_fwd(flat, order, inv, bound):
+    return flat[order[:bound]], inv
+
+
+def _take_head_bwd(bound, inv, g):
+    return jnp.where(inv < bound, g[jnp.minimum(inv, bound - 1)], 0), \
+        None, None
+
+
+_take_head.defvjp(_take_head_fwd, _take_head_bwd)
+
+
+def _experts(rows, counts, w1, w3, w2, act: str, product=None):
+    """The experts on rows in expert order, [M, D] -> [M, D]; the
+    products are `product`'s (None: :func:`grouped_matmul`'s)."""
+    product = product or grouped_matmul
+    with jax.named_scope("moe_experts"):
+        hidden = activation(act)(product(rows, w1, counts))
+        if w3 is not None:
+            hidden = hidden * product(rows, w3, counts)
+        return product(hidden, w2, counts)
+
+
+def _expert_order(experts):
+    """The stable sort of the ``T * k`` assignments by expert, as a
+    permutation and its inverse."""
+    order = jnp.argsort(experts.reshape(-1), stable=True)
+    return order, jnp.argsort(order)
+
+
+def _all_rows(x, experts, weights, counts, w1, w3, w2, act: str,
+              product=None):
+    """The layer over all ``T * k`` assignments."""
+    t, k = experts.shape
+    with jax.named_scope("moe_dispatch"):
+        order, inv = _expert_order(experts)
+        rows = _to_expert_order(x, order, inv, k)
+    out = _experts(rows, counts, w1, w3, w2, act, product)
+    with jax.named_scope("moe_combine"):
+        out = _permute(out, inv, order).reshape(t, k, x.shape[-1])
+        return jnp.einsum("tkd,tk->td", out.astype(jnp.float32),
+                          weights).astype(x.dtype)
+
+
+def _held_rows(x, experts, weights, counts, w1, w3, w2, act: str,
+               bound: int):
+    """The layer over the first `bound` assignments of the sort — all
+    the held ones where ``counts.sum() <= bound`` — and nothing of the
+    size of ``T * k`` rows."""
+    t, k = experts.shape
+    with jax.named_scope("moe_dispatch"):
+        order, inv = _expert_order(experts)
+        token = order[:bound] // k
+        rows = _take_rows(x, token, t)
+    out = _experts(rows, counts, w1, w3, w2, act)
+    with jax.named_scope("moe_combine"):
+        weight = _take_head(weights.reshape(t * k), order, inv, bound)
+        return _sum_rows(out.astype(jnp.float32) * weight[:, None], token,
+                         t).astype(x.dtype)
+
+
+def _fallback_rows(x, experts, weights, counts, w1, w3, w2, act: str):
+    """:func:`_all_rows` as a bounded layer's second branch: the same
+    layer with its products through ``lax.ragged_dot`` on every
+    backend. A kernel's code is not shared between its call sites, and
+    this branch's twelve a layer would be paid for by every run that
+    compiles or loads the step — in set-up time, for a branch the
+    bound is chosen never to take (PERF.md 6, PR 33: compiled for a
+    v5e, glm5-train-t4096's step is 0.94 GB of code and 193 s with the
+    kernels here, 0.85 GB and 147 s so, 0.78 GB and 145 s without the
+    branch)."""
+    return _all_rows(x, experts, weights, counts, w1, w3, w2, act,
+                     product=_ragged_dot_zero_tail)
+
+
+def _branches(act: str, bound: int):
+    return (functools.partial(_held_rows, act=act, bound=bound),
+            functools.partial(_fallback_rows, act=act))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_or_all_rows(x, experts, weights, counts, w1, w3, w2, act: str,
+                      bound: int):
+    """:func:`_held_rows` where the batch's held assignments fit the
+    bound, else :func:`_fallback_rows`: ONE conditional a direction.
+    The backward pass makes the taken branch's forward again inside its
+    own conditional, so that neither branch's residuals are outputs of
+    a conditional (jax fills the other branch's with zeros: ``T * k``
+    rows of them)."""
+    return lax.cond(counts.sum() <= bound, *_branches(act, bound),
+                    x, experts, weights, counts, w1, w3, w2)
+
+
+def _held_or_all_rows_fwd(x, experts, weights, counts, w1, w3, w2, act,
+                          bound):
+    return (_held_or_all_rows(x, experts, weights, counts, w1, w3, w2, act,
+                              bound),
+            (x, experts, weights, counts, w1, w3, w2))
+
+
+def _held_or_all_rows_bwd(act, bound, res, g):
+    x, experts, weights, counts, w1, w3, w2 = res
+
+    def transposed(body):
+        return lambda *floats: jax.vjp(
+            lambda x, weights, *w: body(x, experts, weights, counts, *w),
+            *floats)[1](g)
+
+    # the barrier keeps what follows out of the branches: XLA moved the
+    # update's float32 copy of each weight gradient INTO them, and the
+    # conditional then held three float32 gradients where the update
+    # reads three bfloat16 ones (the chip, PR 33: 14.6 ms a step of
+    # converts and 0.6 GB)
+    dx, dweights, dw1, dw3, dw2 = lax.optimization_barrier(lax.cond(
+        counts.sum() <= bound, *map(transposed, _branches(act, bound)),
+        x, weights, w1, w3, w2))
+    return dx, None, dweights, None, dw1, dw3, dw2
+
+
+_held_or_all_rows.defvjp(_held_or_all_rows_fwd, _held_or_all_rows_bwd)
+
+
 def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
-                   w2, act: str = "relu"):
+                   w2, act: str = "relu", bound: Optional[int] = None):
     """Drop-free MoE FFN on one device. x: [T, D] tokens; w1 (and the
     gate's w3, or None for an ungated expert): [E, D, F]; w2:
     [E, F, D]. Returns ``sum_k weight_k * expert_k(x)``, [T, D] in
@@ -410,22 +636,25 @@ def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
     traced call (pvars ``moe_grouped_kernel_layers`` /
     ``moe_ragged_dot_layers``). An assignment whose expert number is
     ``E`` or more (:func:`held_share`: another chip's expert) sorts
-    past the last group: its row comes out zero and is computed by
-    nobody."""
+    past the last group and is computed by nobody.
+
+    `bound` (:func:`held_rows_bound`; None: ``T * k``) is the static
+    number of rows the layer carries. Under ``T * k`` only the first
+    `bound` rows of the sort exist — gathered, multiplied and summed
+    back per token — and a batch whose held assignments exceed it
+    takes the layer over all ``T * k`` rows instead, exactly, inside
+    one ``lax.cond``: no assignment is ever dropped. Counted once per
+    traced call: ``moe_bounded_layers`` (a bound and its fallback) /
+    ``moe_full_layers`` (all the rows, no second path)."""
     t, k = route.experts.shape
-    with jax.named_scope("moe_dispatch"):
-        order = jnp.argsort(route.experts.reshape(t * k), stable=True)
-        inv = jnp.argsort(order)
-        rows = _to_expert_order(x, order, inv, k)
+    rows = t * k if bound is None else min(bound, t * k)
     # the rule reads K and N alike: what it says of w1 holds for w2
-    pvar.record("moe_grouped_kernel_layers" if _tiles_of(rows, w1)
-                else "moe_ragged_dot_layers")
-    with jax.named_scope("moe_experts"):
-        hidden = activation(act)(grouped_matmul(rows, w1, route.counts))
-        if w3 is not None:
-            hidden = hidden * grouped_matmul(rows, w3, route.counts)
-        out = grouped_matmul(hidden, w2, route.counts)
-    with jax.named_scope("moe_combine"):
-        out = _permute(out, inv, order).reshape(t, k, x.shape[-1])
-        return jnp.einsum("tkd,tk->td", out.astype(jnp.float32),
-                          route.weights).astype(x.dtype)
+    pvar.record("moe_grouped_kernel_layers" if _tiles_of(
+        jax.ShapeDtypeStruct((rows, x.shape[-1]), x.dtype), w1)
+        else "moe_ragged_dot_layers")
+    pvar.record("moe_bounded_layers" if rows < t * k else "moe_full_layers")
+    if rows < t * k:
+        return _held_or_all_rows(x, route.experts, route.weights,
+                                 route.counts, w1, w3, w2, act, rows)
+    return _all_rows(x, route.experts, route.weights, route.counts, w1, w3,
+                     w2, act)

@@ -221,6 +221,42 @@ def test_probe_counters(pvar_clean):
     assert pvar.read("moe_assignments") == 128 * 4
     assert pvar.read("moe_dropped_assignments") == 0
     assert pvar.read("moe_held_assignments") == int(counts[:, :4].sum())
+    # 4 of 16 held and SLACK shares of 4: the bound is all the rows, no
+    # layer of a traced step has a second path and none can be over it
+    traced = pvar.read("moe_full_layers")        # the probes' own traces
+    jax.jit(_mean_loss(cfg, tok, tok)).lower(params)
+    assert pvar.read("moe_full_layers") - traced == 2  # trunk and MTP
+    assert pvar.read("moe_bounded_layers") == 0
+    assert pvar.read("moe_over_bound_layers") == 0
+
+
+def test_probe_counters_of_a_bounded_share(pvar_clean, monkeypatch):
+    """ONE of 16 experts held and 256 tokens x 4: the layers are
+    bounded at 512 of their 1,024 rows, each counted once per trace;
+    the probe counts the layer-batches whose held rows exceed the
+    bound (here with the rule cut down to the held expert's mean: the
+    fullest expert is over it)."""
+    sizes, cfg = _toy(held_experts=(0, 1))
+    sizes = dict(sizes, held_count=1)
+    params = weights_glm5.device_init(sizes, 1)
+    tok, lab = _batch(sizes, 1, batch=4)
+    counts = np.asarray(tfm.route_counts(params, tok, cfg))
+    fullest = int(counts[0].argmax())
+    cfg = tfm.Config(**{**cfg.__dict__, "held_experts": (fullest, 1)})
+    assert moe.held_rows_bound(tok.size, cfg.top_k, 1, 16) == 512
+    pvar.reset()
+    jax.jit(_mean_loss(cfg, tok, lab)).lower(params)
+    assert pvar.read("moe_bounded_layers") == 2
+    assert pvar.read("moe_full_layers") == 0
+    tfm.route_counts(params, tok, cfg)
+    assert pvar.read("moe_held_assignments") == counts[0, fullest] < 512
+    assert pvar.read("moe_over_bound_layers") == 0
+    monkeypatch.setattr(moe, "SLACK", 1)
+    monkeypatch.setattr(moe, "_TM", 8)
+    assert moe.held_rows_bound(tok.size, cfg.top_k, 1, 16) == 64
+    tfm.route_counts(params, tok, cfg)
+    assert counts[0, fullest] > 64
+    assert pvar.read("moe_over_bound_layers") == 1
 
 
 def test_a_sequence_no_longer_than_topk_takes_todays_causal_path(
@@ -274,16 +310,20 @@ def test_no_gradient_through_the_selection_and_none_to_the_bias():
 # -- the share of the experts a chip holds ---------------------------------------
 
 @pytest.mark.parametrize("seed", [0, 1])
-@pytest.mark.parametrize("shares", [4, 2, 1])
-def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(seed, shares):
+@pytest.mark.parametrize("shares, seq", [(4, 32), (2, 32), (1, 32),
+                                         (16, 128)])
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(seed, shares, seq,
+                                                         pvar_clean):
     """16 experts held by `shares` chips: what each chip's layer adds
     for its own experts, summed, with the shared expert counted once,
-    is the uncut reference's layer."""
+    is the uncut reference's layer. With 16 chips and 256 tokens each
+    chip's layer carries 512 of its 1,024 rows (the bound is under
+    T x k); the others carry all of theirs."""
     sizes, whole = _toy(held_experts=(0, 16))
     lp = jax.tree.map(jnp.asarray, tfm.init_params(
         np.random.default_rng(seed), whole)["layers"][1])
     h = jnp.asarray(np.random.default_rng(seed + 10).standard_normal(
-        (2, 32, whole.d_model)), jnp.float32)
+        (2, seq, whole.d_model)), jnp.float32)
     per = 16 // shares
     with jax.default_matmul_precision("highest"):
         flat = tfm._norm(h, lp["ln2"], whole).reshape(-1, whole.d_model)
@@ -301,6 +341,11 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(seed, shares):
     _close(routed + shared, want, rel=1e-5)
     assert float(jnp.linalg.norm(routed)) > 0.1 * float(
         jnp.linalg.norm(shared))
+    bounded = shares if moe.held_rows_bound(
+        2 * seq, whole.top_k, per, 16) < 2 * seq * whole.top_k else 0
+    assert bounded == (16 if shares == 16 else 0)
+    assert (pvar.read("moe_bounded_layers"),
+            pvar.read("moe_full_layers")) == (bounded, shares - bounded)
 
 
 def test_held_share_sorts_the_absent_past_the_last_group():

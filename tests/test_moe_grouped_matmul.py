@@ -287,6 +287,162 @@ def test_the_cpu_step_is_the_step_that_calls_ragged_dot(model, monkeypatch,
 CELL_M, CELL_E, CELL_D, CELL_F = 32768, 64, 2048, 1024
 
 
+# -- the rows of a chip that holds a share of the experts -------------------
+
+#: tokens, assignments a token, experts the router scores, experts held
+#: (numbers 0 and 1), the rows the layer is bounded at
+BT, BK, BE, BHELD, BOUND = 128, 2, 16, 2, 128
+#: held assignments of the BT * BK = 256 a batch makes
+HELD = {"under": 40, "at": BOUND, "zero": 0, "just_over": BOUND + 1,
+        "far_over": 230}
+
+
+def _forced_route(x, wg, held: int):
+    """A route whose first `held` assignments (in token order) go to
+    the held experts 0 / 1 and every other one to experts 2 .. 15 — a
+    token's two experts always differ — with weights that are the
+    router's own: the softmax of the chosen experts' logits."""
+    flat = np.arange(BT * BK)
+    experts = np.where(flat < held, flat % BK,
+                       2 + (flat // BK + 5 * (flat % BK)) % (BE - 2))
+    experts = jnp.asarray(experts.reshape(BT, BK), jnp.int32)
+    weights = jax.nn.softmax(jnp.take_along_axis(
+        (x @ wg).astype(jnp.float32), experts, axis=-1), axis=-1)
+    counts = (experts.reshape(-1, 1) == jnp.arange(BE)).sum(
+        0, dtype=jnp.int32)
+    return moe.held_share(moe.TopKRoute(experts, weights, counts, None,
+                                        None), 0, BHELD)
+
+
+def _held_layer(bound, held, act, gated):
+    """((a loss, the output), the loss's gradient with respect to (x,
+    wg, w1, w3, w2)) of one expert layer whose rows are bounded at
+    `bound`, as the model runs it: jitted, differentiated, the layer
+    recomputed."""
+    def loss(x, wg, w1, w3, w2):
+        y = moe.sorted_moe_ffn(x, _forced_route(x, wg, held), w1,
+                               w3 if gated else None, w2, act, bound)
+        return jnp.sum(y.astype(jnp.float32) * jnp.cos(
+            jnp.arange(y.size).reshape(y.shape))), y
+
+    return jax.jit(jax.value_and_grad(jax.checkpoint(loss), has_aux=True,
+                                      argnums=(0, 1, 2, 3, 4)))
+
+
+def _held_operands(dtype, d=128, f=128):
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    shapes = ((BT, d), (d, BE), (BHELD, d, f), (BHELD, d, f), (BHELD, f, d))
+    return [(jax.random.normal(k, s, jnp.float32)
+             / np.sqrt(s[-2])).astype(dtype) for k, s in zip(ks, shapes)]
+
+
+@pytest.mark.parametrize("held", sorted(HELD))
+@pytest.mark.parametrize("path", ["ragged_dot", "kernels"])
+@pytest.mark.parametrize("experts", ["gated_silu", "ungated_relu"])
+def test_the_bounded_layer_is_the_full_layer(experts, path, held, request,
+                                             pvar_clean):
+    """The layer on `BOUND` rows with its fallback against the layer on
+    all T * k, output and every gradient (rows, router, w1, w3, w2):
+    held assignments under the bound, exactly at it, none, and over it
+    (the fallback taken: the layer on all rows through
+    `lax.ragged_dot` — where the full layer's products are that too,
+    bit for bit)."""
+    if path == "kernels":
+        request.getfixturevalue("kernels_on_cpu")
+    act, gated = ("silu", True) if experts == "gated_silu" else ("relu",
+                                                                 False)
+    args = _held_operands(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want, want_g = _held_layer(None, HELD[held], act, gated)(*args)
+        assert (pvar.read("moe_full_layers"),
+                pvar.read("moe_bounded_layers")) == (1, 0)
+        got, got_g = _held_layer(BOUND, HELD[held], act, gated)(*args)
+    assert (pvar.read("moe_full_layers"),
+            pvar.read("moe_bounded_layers")) == (1, 1)
+    # the products of both layers took the path asked for
+    assert pvar.read("moe_grouped_kernel_layers" if path == "kernels"
+                     else "moe_ragged_dot_layers") == 2
+    over = HELD[held] > BOUND
+    for name, a, b in zip(("y", "x", "wg", "w1", "w3", "w2"),
+                          (got[1],) + got_g, (want[1],) + want_g):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        if over and path == "ragged_dot":
+            assert (a == b).all(), name
+        else:  # the float32 sums of a token's rows, in another order
+            assert np.linalg.norm(a - b) <= 1e-6 * np.linalg.norm(b) + 1e-9, (
+                name, np.linalg.norm(a - b), np.linalg.norm(b))
+    if HELD[held] and gated:
+        assert all(float(jnp.linalg.norm(g)) > 0 for g in want_g)
+
+
+def test_the_bounded_layer_in_bfloat16_sums_in_float32(pvar_clean):
+    """bfloat16 rows: the experts' outputs and weight gradients are the
+    full layer's bit for bit (the same rows in the same order), the
+    output differs by the order of at most k float32 terms before ONE
+    rounding."""
+    args = _held_operands(jnp.bfloat16)
+    want, want_g = _held_layer(None, HELD["under"], "silu", True)(*args)
+    got, got_g = _held_layer(BOUND, HELD["under"], "silu", True)(*args)
+    for name, a, b in zip(("y", "x", "wg", "w1", "w3", "w2"),
+                          (got[1],) + got_g, (want[1],) + want_g):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert np.linalg.norm(a - b) <= 1e-2 * np.linalg.norm(b), name
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_sum_rows_is_the_float32_sum_and_take_rows_transpose(dtype):
+    """`_sum_rows` against numpy's float64 sum of the values, rounded
+    once: the pieces a float32 row is cut into lose nothing, and each
+    of the two functions is the other's transpose."""
+    rng = np.random.default_rng(2)
+    t, b, d = 40, 96, 24
+    token = jnp.asarray(np.sort(rng.integers(0, t, b)), jnp.int32)
+    v = jnp.asarray(rng.standard_normal((b, d)) * 10.0 ** rng.integers(
+        -3, 4, (b, 1)), dtype)
+    want = np.zeros((t, d))
+    np.add.at(want, np.asarray(token), np.asarray(v, np.float64))
+    got = moe._sum_rows(v, token, t)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(got, np.float64),
+        np.asarray(jnp.asarray(want, jnp.float32).astype(dtype), np.float64),
+        rtol=3e-7, atol=1e-30)
+    g = jnp.asarray(rng.standard_normal((t, d)), dtype)
+    back = jax.grad(lambda v: jnp.sum(
+        (moe._sum_rows(v, token, t) * g).astype(jnp.float32)))(v)
+    assert back.dtype == dtype and (np.asarray(back, np.float32)
+                                    == np.asarray(g[token], np.float32)).all()
+    gx = jax.grad(lambda x: jnp.sum(
+        (moe._take_rows(x, token, t) * v).astype(jnp.float32)))(g)
+    assert gx.dtype == dtype and (np.asarray(gx, np.float32)
+                                  == np.asarray(got, np.float32)).all()
+
+
+@pytest.mark.parametrize("t,k", [(4096, 8), (128, 4), (96, 2), (1000, 3)])
+def test_the_bound_rule(t, k):
+    """A multiple of the row tile under T * k, T * k itself at share 1
+    and wherever SLACK shares cover the layer, never less than SLACK
+    times the share, monotone in the share."""
+    n = 256
+    bounds = [moe.held_rows_bound(t, k, c, n) for c in range(1, n + 1)]
+    assert bounds == sorted(bounds) and bounds[-1] == t * k
+    for c, b in zip(range(1, n + 1), bounds):
+        assert b == t * k or b % moe._TM == 0
+        assert b >= min(t * k, moe.SLACK * c * t * k / n)
+        assert b == t * k or b < moe.SLACK * c * t * k / n + moe._TM
+        if moe.SLACK * c >= n:
+            assert b == t * k
+
+
+def test_the_bound_of_the_cells():
+    """glm5-train-t4096 (8 of 256 held, 4,096 tokens x 8): 8 row tiles
+    of the 64; its rehearsal (4 of 16, 128 x 4) and OLMoE: all rows."""
+    assert moe.held_rows_bound(4096, 8, 8, 256) == moe.SLACK * 1024
+    assert moe.held_rows_bound(128, 4, 4, 16) == 512
+    assert moe.held_rows_bound(4096, 8, 64, 64) == 4096 * 8
+
+
 @pytest.mark.parametrize("leaf,k,n", [("w1_w3", CELL_D, CELL_F),
                                       ("w2", CELL_F, CELL_D)])
 def test_kernels_compile_for_the_chip_at_the_cells_shapes(one_chip, leaf, k,
@@ -352,3 +508,48 @@ def test_the_cells_step_compiles_for_the_chip_on_the_kernels(one_chip,
     kernels = sorted(c.lstrip("%").rsplit(".", 1)[0] for c in calls)
     assert kernels == (["moe_gmm"] * 3 + ["moe_gmm_nt"] * 3
                        + ["moe_tgmm"] * 3), calls
+
+
+def test_the_bounded_layer_compiles_for_the_chip(one_chip, monkeypatch,
+                                                 pvar_clean):
+    """glm5-train-t4096's expert layer (8 of 256 experts held, 4,096
+    tokens x 8, the published widths) for a described v5e, the rule
+    asked as on the TPU: one conditional a direction; the bounded
+    branch's twelve products are the kernels on 4,096 rows, the
+    fallback's are ragged-dot instructions and no kernel; nothing of
+    the bounded branch has 32,768 rows."""
+    t, k, d, f, held, n = 4096, 8, 6144, 2048, 8, 256
+    rule = moe.grouped_tiles
+    monkeypatch.setattr(moe, "grouped_tiles",
+                        lambda backend, *a: rule("tpu", *a))
+    bound = moe.held_rows_bound(t, k, held, n)
+    assert bound == 4096
+
+    def loss(x, logits, w1, w3, w2, g):
+        route = moe.held_share(moe.sigmoid_routing(logits, None, k), 0, held)
+        y = moe.sorted_moe_ffn(x, route, w1, w3, w2, "silu", bound)
+        return jnp.sum(y.astype(jnp.float32) * g)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg((t, d)), arg((t, n), jnp.float32), arg((held, d, f)),
+        arg((held, d, f)), arg((held, f, d)),
+        arg((t, d), jnp.float32)).compile()
+    assert (pvar.read("moe_bounded_layers"),
+            pvar.read("moe_grouped_kernel_layers")) == (1, 1)
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 2
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = [c.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for c in calls]
+    assert sorted(n for n in names if n.startswith("moe_")) == (
+        ["moe_gmm"] * 6 + ["moe_gmm_nt"] * 3 + ["moe_tgmm"] * 3), names
+    # libtpu's own kernels (PR 26's) are the fallback's, all of them
+    assert all(n.startswith(("moe_", "ragged-dot")) for n in names), names
+    for name, call in zip(names, calls):
+        if name.startswith("moe_"):
+            assert f"[{t * k}," not in call.split("custom_call_target")[0]

@@ -330,7 +330,11 @@ def test_what_an_axis_cannot_give_yet_raises(over):
 #: 4f8c89c (jax 0.9.0, CPU, batch 2 x 64): the `glm-5` rehearsal step
 #: (`glm5_train.build_step(...).lower(...).as_text()`), and this file's
 #: toy run ONCE without gate or output norms — a plain RMSNorm / RoPE /
-#: gated-FFN decoder with an untied head, recomputed layers
+#: gated-FFN decoder with an untied head, recomputed layers. PR 33
+#: (rows of a held share bounded, `ops/moe.held_rows_bound`) left both
+#: `glm-5` texts as they were: the rehearsal holds 4 of 16 experts, so
+#: SLACK (4) shares cover the layer, its bound is all 512 rows and no
+#: second path exists — nothing to re-record
 PARENT = {
     ("glm-5", "bfloat16"):
         "ba6f5504497ac50bcecb66dc436e404cd27ee26be9cc7d80332cf22f21e04b3c",
