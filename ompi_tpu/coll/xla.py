@@ -361,8 +361,6 @@ class _Ctx:
         fn = self.fns.get(key)
         if fn is None:
             pvar.record("coll_xla_cache_misses")
-            if _prof.PROFILER is not None:
-                pvar.record("prof_compile_misses")
             name = _naming.program = program_name(key)
             try:
                 fn = self.fns[key] = build()
@@ -374,8 +372,6 @@ class _Ctx:
             self._evict(self.fns)
         else:
             pvar.record("coll_xla_cache_hits")
-            if _prof.PROFILER is not None:
-                pvar.record("prof_compile_hits")
             self.fns[key] = self.fns.pop(key)  # LRU touch
         return fn
 
@@ -385,19 +381,13 @@ class _Ctx:
         p = self.plans.get(key)
         if p is None:
             pvar.record("coll_xla_plan_cache_misses")
-            t0 = _trace.now()
             with _trace.span("plan_build", "coll_xla",
                              plan=program_name(key)):
                 p = self.plans[key] = build()
-            if _prof.PROFILER is not None:
-                pvar.record("prof_compile_misses")
-                pvar.record("prof_compile_ns", _trace.now() - t0)
             pvar.record_hwm("coll_xla_plans_size", len(self.plans))
             self._evict(self.plans)
         else:
             pvar.record("coll_xla_plan_cache_hits")
-            if _prof.PROFILER is not None:
-                pvar.record("prof_compile_hits")
             self.plans[key] = self.plans.pop(key)  # LRU touch
         return p
 
@@ -421,8 +411,11 @@ class _Ctx:
         A program's FIRST launch is where jax compiles it or loads it
         from the persistent cache: it is always timed (two clock reads
         against a compile) into ``coll_xla_cold_launch_ns`` /
-        ``coll_xla_cold_launches`` and ``prof_compile_ns``, and is
-        span ``compile`` around a ``launch`` with ``cold=1``."""
+        ``coll_xla_cold_launches``, and is span ``compile`` around a
+        ``launch`` with ``cold=1``; what jax did inside it — trace,
+        lowering, XLA compile or cache load — is the compile ledger's
+        (``prof/compile.py``: spans ``compile.<phase>`` with this
+        call's number)."""
         pvar.record("coll_xla_launches")
         if fn in self._cold:
             return self._launch_cold(fn, args)
@@ -444,8 +437,6 @@ class _Ctx:
         dt = _trace.now() - t0
         pvar.record("coll_xla_cold_launches")
         pvar.record("coll_xla_cold_launch_ns", dt)
-        if _prof.PROFILER is not None:
-            pvar.record("prof_compile_ns", dt)
         return out
 
     def _nbytes(self, args) -> int:

@@ -122,8 +122,8 @@ WELL_KNOWN = (
     # prof/ plane (wall-clock attribution): phase-ledger wall per
     # canonical phase, host<->device transfer bytes + time per
     # direction (bandwidth hwm gauges ride prof_xfer_*_bw_mbps_hwm),
-    # _Ctx compile cache traffic + build time, and jax's persistent
-    # compilation cache hit/miss accounting (compile_cache_dir cvar)
+    # and jax's persistent compilation cache hits/misses over every
+    # program (compile_cache_dir cvar)
     "prof_phase_staging_ns", "prof_phase_compile_ns",
     "prof_phase_train_ns", "prof_phase_teardown_ns",
     # the async checkpoint plane's d2h thread runs under "snapshot";
@@ -139,8 +139,16 @@ WELL_KNOWN = (
     "prof_phase_overlap_ns",
     "prof_xfer_h2d_bytes", "prof_xfer_h2d_ns",
     "prof_xfer_d2h_bytes", "prof_xfer_d2h_ns",
-    "prof_compile_hits", "prof_compile_misses", "prof_compile_ns",
     "prof_compile_cache_hits", "prof_compile_cache_misses",
+    # the compile ledger (prof/compile.py; always on, fed by jax's own
+    # events): what the job's OWN programs (named ompi_*) took to
+    # trace, to lower, in XLA's compile and to load from the
+    # persistent cache; how many reached the backend, asked the cache
+    # and were answered by it; every other program's time and count
+    "compile_trace_ns", "compile_lower_ns", "compile_backend_ns",
+    "compile_cache_load_ns", "compile_programs",
+    "compile_cache_requests", "compile_cache_hits",
+    "compile_foreign_ns", "compile_foreign_programs",
     # monitoring plane per-context traffic (combined monitoring_msgs/
     # monitoring_bytes stay alongside; per-cell/per-link/per-expert
     # families are dynamically named — monitoring_tx_*_s<i>_d<j>_<ctx>,

@@ -81,7 +81,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -1044,7 +1043,17 @@ def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
     return nll + count * extra, count
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
+def _probe(name: str):
+    """jit a set-up probe under the job's own prefix: the compile
+    ledger (prof/compile.py) counts programs named ``ompi_*`` as the
+    job's, and a probe's compile is part of its set-up."""
+    def wrap(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn, static_argnames=("cfg",))
+    return wrap
+
+
+@_probe("ompi_route_counts")
 def _route_probe(params, tokens, cfg: Config):
     aux = []
     forward_local(params, tokens, cfg, Axes(), aux)
@@ -1087,7 +1096,7 @@ def route_experts(params, tokens, cfg: Config):
     return _route_probe(params, tokens, cfg)[1]
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
+@_probe("ompi_dsa_selection")
 def _selection_probe(params, tokens, cfg: Config):
     index_aux = []
     forward_local(params, tokens, cfg, Axes(), None, index_aux)
@@ -1110,7 +1119,7 @@ def dsa_selection(params, tokens, cfg: Config):
     return keep
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
+@_probe("ompi_exit_stats")
 def _exit_probe(params, tokens, labels, cfg: Config):
     exits = []
     h, _ = _trunk(params, tokens, cfg, Axes(), exits=exits)

@@ -10,13 +10,24 @@ plane. Three sub-planes, all riding the existing substrate:
 - **transfer instrumentation**: h2d/d2h copy spans with bytes,
   bandwidth gauges and log2 size/latency histograms, emitted by the
   accelerator and ``_Ctx.to_global`` staging sites;
-- **compile observability**: `_Ctx` compile spans + hit/miss pvars,
-  jax's persistent compilation cache placed by
-  :func:`wire_compile_cache` with ``prof_compile_cache_{hits,misses}``
-  accounting, and the ``python -m ompi_tpu.prof`` attribution CLI.
+- the **compile ledger** (:mod:`ompi_tpu.prof.compile`), ALWAYS ON:
+  every program the job compiles — the train step, coll/xla's
+  collectives, the set-up probes, and apart from them whatever else
+  jax compiles — split into trace, lowering, XLA compile and load
+  from the persistent cache by jax's own events: pvars
+  ``compile_{trace,lower,backend,cache_load}_ns``,
+  ``compile_programs``, ``compile_cache_{requests,hits}``,
+  ``compile_foreign_{ns,programs}``; :func:`compile_table` per
+  program; spans ``compile.<phase>`` for ``python -m ompi_tpu.prof
+  report``'s ``compile`` section. :func:`wire_compile_cache` places
+  jax's persistent compilation cache and hooks the ledger
+  (``prof_compile_cache_{hits,misses}`` count every program's
+  requests).
 
-Enable with ``--mca prof_enable 1`` (or ``OMPI_TPU_PROF=1``); off by
-default at the usual one-branch cost per instrumented site.
+The phase ledger and the transfer spans wait for ``--mca prof_enable
+1`` (or ``OMPI_TPU_PROF=1``); off by default at the usual one-branch
+cost per instrumented site. The compile ledger's listeners run only
+when jax traces, lowers or compiles.
 """
 
 from __future__ import annotations
@@ -25,7 +36,9 @@ import os
 import sys
 
 from ompi_tpu import errors
-from ompi_tpu.core import cvar, pvar
+from ompi_tpu.core import cvar
+from ompi_tpu.prof import compile as _compile
+from ompi_tpu.prof.compile import table as compile_table  # noqa: F401
 from ompi_tpu.prof.ledger import (  # noqa: F401  (public re-exports)
     PROFILER, Profiler, current_phase, disable, enable,
     overlap_seconds, phase, phase_seconds, requested,
@@ -48,17 +61,6 @@ _cache_min_var = cvar.register(
 
 _CACHE_WIRED = False
 
-
-def _on_cache_event(event: str, **kw) -> None:
-    # jax fires compile_requests_use_cache before (on a hit)
-    # cache_hits — count every request as a miss, then reclassify.
-    if event == "/jax/compilation_cache/cache_hits":
-        pvar.record("prof_compile_cache_hits")
-        pvar.record("prof_compile_cache_misses", -1)
-    elif event == "/jax/compilation_cache/compile_requests_use_cache":
-        pvar.record("prof_compile_cache_misses")
-
-
 #: where the cache goes when neither the environment nor the cvar
 #: places it: a FIXED path beside the package (the path is part of
 #: jax's cache key — a directory that moves never hits)
@@ -68,9 +70,10 @@ DEFAULT_CACHE_DIR = os.path.join(
 
 
 def wire_compile_cache() -> str:
-    """Place jax's persistent compilation cache and hook hit/miss
-    accounting; returns the directory. Called from runtime init, before
-    anything compiles, so every rank of every job shares it.
+    """Place jax's persistent compilation cache and hook the compile
+    ledger (prof/compile.py); returns the directory. Called from
+    runtime init, before anything compiles, so every rank of every
+    job shares it.
 
     Placement: ``JAX_COMPILATION_CACHE_DIR`` when the environment sets
     it — jax reads that itself, so nothing is configured in code and
@@ -105,6 +108,6 @@ def wire_compile_cache() -> str:
         if min_secs >= 0:
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", min_secs)
-        jax.monitoring.register_event_listener(_on_cache_event)
+        _compile.register(jax.monitoring)
         _CACHE_WIRED = True
     return d

@@ -13,6 +13,10 @@ pid-per-rank). The report answers "where did the wall go":
   a staging-bound run prints ``staging`` on top;
 - **transfer summary** per direction (bytes, spans, average and peak
   achieved bandwidth) from the xfer spans;
+- **compile ledger**: program x phase (trace, lowering, XLA compile,
+  load from the persistent cache), worst-rank seconds, hit or miss —
+  from the always-on ``compile.<phase>`` spans of ``prof/compile.py``:
+  what an operator asks of a slow start;
 - **top-N span consumers** by total time across the remaining
   subsystems.
 
@@ -27,6 +31,7 @@ import json
 import sys
 from typing import Any, Dict, List, Optional
 
+from ompi_tpu.prof.compile import PHASES as _COMPILE_PHASES
 from ompi_tpu.trace import merge as _merge
 
 SCHEMA = "ompi_tpu.prof.attribution/1"
@@ -114,9 +119,32 @@ def attribution(doc: Dict[str, Any], top: int = 10) -> Dict[str, Any]:
             if cell["seconds"] > 0 else None
         cell["peak_gbps"] = round(cell["peak_gbps"], 3)
 
+    # compile ledger: per program and phase the worst rank's seconds
+    # (the wall waits for the slowest), and what the persistent cache
+    # answered its backend requests, over all ranks
+    progs: Dict[str, Dict[str, Any]] = {}
+    for ev in spans:
+        if ev.get("cat") != "compile":
+            continue
+        args = ev.get("args", {})
+        cell = progs.setdefault(args.get("program", "?"), {
+            "per_rank": {}, "hits": 0, "misses": 0})
+        per = cell["per_rank"].setdefault(ev["name"], {})
+        pid = ev.get("pid", 0)
+        per[pid] = per.get(pid, 0.0) + ev.get("dur", 0.0) / 1e6
+        if ev["name"] == "backend" and "cache" in args:
+            cell["hits" if args["cache"] == "hit" else "misses"] += 1
+    compiles = [{
+        "program": name,
+        "phases_s": {ph: round(max(cell["per_rank"][ph].values()), 6)
+                     for ph in _COMPILE_PHASES if ph in cell["per_rank"]},
+        "hits": cell["hits"], "misses": cell["misses"],
+    } for name, cell in progs.items()]
+    compiles.sort(key=lambda c: -sum(c["phases_s"].values()))
+
     by_op: Dict[Any, List[float]] = {}
     for ev in spans:
-        if ev.get("cat") == "prof":
+        if ev.get("cat") in ("prof", "compile"):  # have their sections
             continue
         cell = by_op.setdefault((ev.get("cat", "?"), ev["name"]),
                                 [0, 0.0])
@@ -134,6 +162,7 @@ def attribution(doc: Dict[str, Any], top: int = 10) -> Dict[str, Any]:
         "phases": phases,
         "phase_overlap": phase_overlap,
         "transfers": transfers,
+        "compile": compiles,
         "top": consumers[:top],
     }
 
@@ -160,6 +189,16 @@ def _render(rep: Dict[str, Any]) -> str:
               if c["avg_gbps"] is not None else "async (0ns spans)")
         lines.append(f"transfers {d}: {c['bytes']} bytes in "
                      f"{c['spans']} span(s), {c['seconds']:.3f}s, {bw}")
+    if rep["compile"]:
+        lines.append("compile ledger (worst-rank seconds: "
+                     + " / ".join(_COMPILE_PHASES)
+                     + "; persistent cache over all ranks):")
+        for c in rep["compile"]:
+            secs = " ".join(f"{c['phases_s'].get(ph, 0.0):9.3f}"
+                            for ph in _COMPILE_PHASES)
+            cache = ", ".join(f"{c[k]} {k}" for k in ("hits", "misses")
+                              if c[k]) or "no request"
+            lines.append(f"  {c['program']:28s} {secs}  {cache}")
     if rep["top"]:
         lines.append(f"top {len(rep['top'])} span consumers:")
         for c in rep["top"]:

@@ -59,6 +59,15 @@ def init_instance() -> None:
     with _lock:
         if _instance_up:
             return
+        # the span recorder first (cvar trace_enable / OMPI_TPU_TRACE):
+        # the ring then holds every phase of Init and every compile
+        # after it; its clock is synced across ranks once the store
+        # is up (_init_pml_and_planes)
+        if _trace.requested():
+            try:
+                _trace.enable()
+            except Exception as exc:  # tracing must never sink init
+                _out.verbose(0, "trace enable failed: %r", exc)
         with init_phase("rte"):
             rte.init()
         _out.verbose(2, "rte up: rank %d/%d job %s",
@@ -158,16 +167,14 @@ def _init_pml_and_planes() -> None:
     from ompi_tpu.tools import msgq as _msgq
 
     _msgq.install_signal_dump()
-    # tracing plane (cvar trace_enable / OMPI_TPU_TRACE): bring
-    # the span recorder up before any traffic flows and exchange
-    # wall-vs-monotonic clock offsets through the store so merged
-    # per-rank timelines share rank 0's timebase
-    from ompi_tpu.trace import recorder as _trace_rec
-
-    if _trace_rec.requested():
+    # tracing plane (the recorder came up at the top of
+    # init_instance): exchange wall-vs-monotonic clock offsets
+    # through the store before any traffic flows, so merged per-rank
+    # timelines share rank 0's timebase
+    if _trace.requested():
         try:
-            _trace_rec.enable(rank=rte.rank)
-            _trace_rec.sync_clock()
+            _trace.enable(rank=rte.rank)
+            _trace.sync_clock()
         except Exception as exc:  # tracing must never sink init
             _out.verbose(0, "trace enable failed: %r", exc)
     # telemetry plane (cvar telemetry_enable / OMPI_TPU_TELEMETRY):

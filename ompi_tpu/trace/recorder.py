@@ -24,6 +24,8 @@ instrumented site on the device path and in ``mpi.Init()`` makes:
 
 Spans of one API call carry the same ``call`` sequence number
 (:func:`api_span` opens the call; every span inside it inherits it).
+:func:`closed` feeds both sinks a span that someone else timed and
+that has already ended (jax's compile events).
 
 Hot-path contract (regression-tested): while both sinks are down — the
 default — an instrumented site pays :func:`active` (one attribute
@@ -295,6 +297,32 @@ def api_span(name: str):
     """The span of one MPI API call: opens a new ``call`` sequence
     number that every span inside it carries."""
     return _open(name, "api", {}, True)
+
+
+def closed(name: str, subsys: str, t0_wall_ns: int, t1_wall_ns: int,
+           **args) -> None:
+    """A span that has ALREADY ended, timed by someone else on the
+    wall clock (jax's compile events, ``prof/compile.py``). The ring
+    gets it moved onto its monotonic clock by the offset sampled at
+    enable; a live profiler session gets ``ompi:<subsys>.<name>``
+    opened and closed NOW, at the span's end (a ``TraceAnnotation``
+    cannot be backdated), carrying ``dur_ns``. Like every span it
+    carries the ``call`` of the API call it happened in, if any."""
+    rec = RECORDER
+    live = _profiler_live()
+    if rec is None and not live:
+        return
+    call = _call_of.get(_thread_id())
+    if call is not None:
+        args["call"] = call
+    if rec is not None:
+        off = rec.clock_offset_ns
+        rec.record(name, subsys, t0_wall_ns - off, t1_wall_ns - off,
+                   args or None)
+    if live:
+        with _annotation(PREFIX + subsys + "." + name,
+                         dur_ns=t1_wall_ns - t0_wall_ns, **args):
+            pass
 
 
 # -- log2 latency histogram (pvar-plane export) --------------------------

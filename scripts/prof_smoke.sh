@@ -71,6 +71,10 @@ assert top == "staging", (
 assert phases["staging"]["max_s"] >= 0.4, phases["staging"]
 x = rep["transfers"]["h2d"]
 assert x["bytes"] >= 2 * (9 << 20) and x["spans"] >= 2, x
+# the always-on compile ledger: whatever the job compiled (here the
+# chunked upload's concatenate), program by phase
+assert rep["compile"] and all(
+    c["program"] and c["phases_s"] for c in rep["compile"]), rep["compile"]
 print(f"prof smoke OK: staging {phases['staging']['max_s']:.3f}s "
       f"worst-rank (train {phases['train']['max_s']:.3f}s), "
       f"{x['bytes']} h2d bytes in {x['spans']} spans")

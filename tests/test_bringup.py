@@ -235,8 +235,10 @@ def test_cache_dir_that_cannot_be_made_is_an_error(
 
 
 def test_cache_hit_miss_accounting(cache_updates, pvar_clean):
-    """jax fires compile_requests_use_cache first, then (only on a
-    hit) cache_hits — the listener reclassifies."""
+    """jax fires compile_requests_use_cache and (only on a hit)
+    cache_hits INSIDE the backend event of the program that asked;
+    the compile ledger counts the request once, when that event
+    ends, as a hit or as a miss."""
     from jax import monitoring as jmon
 
     from ompi_tpu import prof
@@ -245,11 +247,14 @@ def test_cache_hit_miss_accounting(cache_updates, pvar_clean):
     prof.wire_compile_cache()
     s = pvar.session()
     req = "/jax/compilation_cache/compile_requests_use_cache"
+    backend = "/jax/core/compile/backend_compile_duration"
     jmon.record_event(req)
+    jmon.record_event_time_span(backend, 1.0, 2.0, fun_name="jit(f)")
     assert (s.read("prof_compile_cache_misses"),
             s.read("prof_compile_cache_hits")) == (1, 0)
     jmon.record_event(req)
     jmon.record_event("/jax/compilation_cache/cache_hits")
+    jmon.record_event_time_span(backend, 3.0, 4.0, fun_name="jit(f)")
     assert (s.read("prof_compile_cache_misses"),
             s.read("prof_compile_cache_hits")) == (1, 1)
 

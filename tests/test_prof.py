@@ -181,23 +181,32 @@ def test_sampler_publishes_rolling_bandwidth_gauge(no_prof):
 # -- compile observability -----------------------------------------------
 
 def test_ctx_compile_pvars_miss_then_hit(no_prof):
+    """A coll/xla program's first launch is in the compile ledger
+    (always on: no `prof_enable`) as the job's own; the same slot
+    again compiles nothing."""
     import jax.numpy as jnp
 
+    from ompi_tpu import prof
     from ompi_tpu.coll import xla as cx
 
-    ledger.enable()
+    prof.wire_compile_cache()
     ctx = cx._Ctx.local()
     comm = types.SimpleNamespace(_coll_xla_ctx=ctx)
     s = pvar.session()
     launch = cx._allreduce_prep(comm, jnp.ones(16, jnp.float32))
     launch()
-    assert s.read("prof_compile_misses") >= 1
-    assert s.read("prof_compile_ns") > 0
+    assert s.read("compile_programs") >= 1
+    assert s.read("compile_trace_ns") > 0
+    assert s.read("compile_lower_ns") > 0
+    assert s.read("compile_backend_ns") > 0
+    assert "ompi_allreduce" in [r["program"]
+                                for r in prof.compile_table() if r["own"]]
     s2 = pvar.session()
     relaunch = cx._allreduce_prep(comm, jnp.ones(16, jnp.float32))
     relaunch()
-    assert s2.read("prof_compile_hits") >= 1
-    assert s2.read("prof_compile_misses") == 0
+    assert s2.read("coll_xla_cache_hits") >= 1
+    assert not {k: v for k, v in s2.snapshot().items()
+                if k.startswith("compile_") and v}
 
 
 # compile-cache placement and hit/miss accounting: tests/test_bringup.py
@@ -232,8 +241,11 @@ def test_prof_pvars_are_well_known():
                  "prof_phase_train_ns", "prof_phase_teardown_ns",
                  "prof_xfer_h2d_bytes", "prof_xfer_h2d_ns",
                  "prof_xfer_d2h_bytes", "prof_xfer_d2h_ns",
-                 "prof_compile_hits", "prof_compile_misses",
-                 "prof_compile_ns", "prof_compile_cache_hits",
+                 "compile_trace_ns", "compile_lower_ns",
+                 "compile_backend_ns", "compile_cache_load_ns",
+                 "compile_programs", "compile_cache_requests",
+                 "compile_cache_hits", "compile_foreign_ns",
+                 "compile_foreign_programs", "prof_compile_cache_hits",
                  "prof_compile_cache_misses"):
         assert name in pvar.WELL_KNOWN, name
 

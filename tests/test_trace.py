@@ -192,42 +192,48 @@ def test_span_records_the_error_that_escaped(no_recorder):
 
 def test_cold_launch_not_build_is_the_compile(no_recorder):
     """`_Ctx.compiled` only wraps (`build()` returns a lazy jax.jit):
-    `prof_compile_ns` and the cold-launch pvars grow on the key's
-    FIRST launch, not on `build()`, and never again."""
+    the compile ledger's counters and the cold-launch pvars grow on
+    the key's FIRST launch, not on `build()`, and never again; the
+    ledger's four phases lie inside the cold launch's wall time."""
     import jax.numpy as jnp
 
     from ompi_tpu import prof
     from ompi_tpu.coll import xla as cx
 
+    def ledger_ns():
+        return sum(s.read("compile_%s_ns" % ph) for ph in
+                   ("trace", "lower", "backend", "cache_load"))
+
+    prof.wire_compile_cache()
     ctx = cx._Ctx.local()
     x = jnp.ones(24, jnp.float32)
     key = cx._key(x, "allreduce", "MPI_SUM", None)
-    prof.enable(rank=0)
     s = pvar.session()
-    try:
-        fn = ctx.compiled(key, lambda: ctx.smap(
-            lambda a: a[0] * 2, out_varying=True))
-        assert ctx.programs[fn] == "ompi_allreduce"
-        assert s.read("prof_compile_misses") == 1
-        assert s.read("prof_compile_ns") == 0  # nothing compiled yet
-        assert s.read("coll_xla_cold_launches") == 0
-        g = ctx.to_global(x)
-        # the view is the operand under the global shape (n = 1
-        # here), and the body above still saw its (1, 24) block
-        assert g.shape == (24,)
-        assert (g.addressable_data(0).unsafe_buffer_pointer()
-                == x.unsafe_buffer_pointer())
-        assert ctx.launch(fn, g).shape == (24,)
-        cold_ns = s.read("coll_xla_cold_launch_ns")
-        assert s.read("coll_xla_cold_launches") == 1 and cold_ns > 0
-        assert s.read("prof_compile_ns") == cold_ns
-        assert ctx.compiled(key, None) is fn  # warm: build not called
-        ctx.launch(fn, g)
-        assert s.read("coll_xla_cold_launches") == 1
-        assert s.read("coll_xla_cold_launch_ns") == cold_ns
-        assert s.read("prof_compile_ns") == cold_ns
-    finally:
-        prof.disable()
+    fn = ctx.compiled(key, lambda: ctx.smap(
+        lambda a: a[0] * 2, out_varying=True))
+    assert ctx.programs[fn] == "ompi_allreduce"
+    assert s.read("coll_xla_cache_misses") == 1
+    assert s.read("compile_programs") == 0  # nothing compiled yet
+    assert ledger_ns() == 0
+    assert s.read("coll_xla_cold_launches") == 0
+    g = ctx.to_global(x)
+    # the view is the operand under the global shape (n = 1
+    # here), and the body above still saw its (1, 24) block
+    assert g.shape == (24,)
+    assert (g.addressable_data(0).unsafe_buffer_pointer()
+            == x.unsafe_buffer_pointer())
+    assert ctx.launch(fn, g).shape == (24,)
+    cold_ns = s.read("coll_xla_cold_launch_ns")
+    assert s.read("coll_xla_cold_launches") == 1 and cold_ns > 0
+    assert s.read("compile_programs") == 1
+    compiled_ns = ledger_ns()
+    assert 0 < compiled_ns <= cold_ns
+    assert ctx.compiled(key, None) is fn  # warm: build not called
+    ctx.launch(fn, g)
+    assert s.read("coll_xla_cold_launches") == 1
+    assert s.read("coll_xla_cold_launch_ns") == cold_ns
+    assert s.read("compile_programs") == 1
+    assert ledger_ns() == compiled_ns
 
 
 @pytest.mark.parametrize("key, name", [
