@@ -17,6 +17,10 @@ _counters: Dict[str, int] = {}
 _watermarks: Dict[str, int] = {}
 _timers: Dict[str, float] = {}
 _lock = threading.Lock()
+#: pvar.captured: the captures open in the process (record looks for
+#: its thread's only while there is one) and each thread's table
+_capturing = 0
+_capture = threading.local()
 
 # Counter names mirror the reference SPC set where it applies
 # (ompi/runtime/ompi_spc.h): send/recv counts, bytes, collective op counts,
@@ -71,6 +75,14 @@ WELL_KNOWN = (
     # read (the summed probability of exit s rides the dynamic name
     # exit_mass_micro_p<s>, in millionths of a token)
     "loop_passes", "loop_layer_applications", "exit_probe_tokens",
+    # models/transformer._run_layer, once per TRACED application of a
+    # recomputed layer (Config.remat): its backward pass is given what
+    # the application made under the names the rule remat_keep chose,
+    # or recomputes it whole from its input (no name fits, or the
+    # device states no memory limit); and the bytes the rule reckons
+    # the kept names hold, summed over the traced applications
+    "remat_kept_applications", "remat_whole_applications",
+    "remat_kept_bytes",
     # the phases of mpi.Init(), once per job (runtime/state.py,
     # runtime/device_plane.py; "import" also holds the import of
     # ompi_tpu.mpi itself): they end before any profiler session can
@@ -300,9 +312,38 @@ WELL_KNOWN = (
 
 
 def record(name: str, value: int = 1) -> None:
-    """SPC_RECORD equivalent — add to a counter."""
+    """SPC_RECORD equivalent — add to a counter (inside `captured`, on
+    that thread: to the capture's table instead)."""
+    if _capturing:
+        sink = getattr(_capture, "sink", None)
+        if sink is not None:
+            sink[name] = sink.get(name, 0) + value
+            return
     with _lock:
         _counters[name] = _counters.get(name, 0) + value
+
+
+class captured:
+    """Context manager: what THIS thread records inside goes to the
+    dict it yields and not to the counters. For code that runs once
+    where the work it counts happens many times (a function jax traces
+    once and calls per layer): its owner records the captured counts
+    itself, once per call (models/transformer._run_layer)."""
+
+    def __enter__(self) -> Dict[str, int]:
+        global _capturing
+        self._outer = getattr(_capture, "sink", None)
+        _capture.sink = sink = {}
+        with _lock:
+            _capturing += 1
+        return sink
+
+    def __exit__(self, *exc):
+        global _capturing
+        _capture.sink = self._outer
+        with _lock:
+            _capturing -= 1
+        return False
 
 
 def record_hwm(name: str, value: int) -> None:

@@ -41,7 +41,14 @@ where ``index_topk`` is set), layers of three kinds by INDEX
 layers of width ``moe_d_ff``, then ``mtp_layers`` multi-token-prediction
 modules with a second loss), a sigmoid ``noaux_tc`` router over all
 ``n_experts`` of which this chip holds ``held_experts``, a shared
-expert, and ``remat``: each layer recomputed in the backward pass.
+expert, and ``remat``: each layer application recomputed in the
+backward pass — from its input and from what the static rule
+:func:`remat_keep` lets it keep of its own forward pass (attention's
+output and log-sum-exp, the sub-layers' outputs, q / k / v, ...: named
+values, the one that spares most operations per byte first, while the
+reckoned peak stays under a share of the device's memory limit;
+nothing where no limit is stated, which is the whole recomputation of
+before).
 The fourth (reference ``benchmark/reference/ouro_decoder.py``) runs
 its layers more than once: ``loops`` passes over the ONE layer list
 with the same weights, the final norm between passes, a norm on every
@@ -88,6 +95,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ompi_tpu.core import pvar
 from ompi_tpu.ops import attention as att
@@ -197,7 +205,11 @@ class Config:
     #: of their loss
     mtp_layers: int = 0
     mtp_weight: float = 0.0
-    #: recompute each layer in the backward pass from its input
+    #: recompute each layer application in the backward pass: from its
+    #: input and the named values `remat_keep` chooses for this trace's
+    #: shapes and the device's memory limit (`remat_order`,
+    #: REMAT_SHARE); from its input alone where the device states no
+    #: limit (the CPU) or has no room
     remat: bool = False
     #: passes over the layer list, every pass with the same weights;
     #: the final norm (params["ln_f"]) is applied after EVERY pass, so
@@ -570,15 +582,19 @@ def _moe_sorted(flat, lp, cfg: Config, aux):
 
 def _ffn(x, w1, w3, w2, cfg: Config):
     """act(x W1) [* (x W3)] W2 in x's type."""
-    u = moe_mod.activation(cfg.mlp_act)(x @ w1.astype(x.dtype))
+    u = moe_mod.activation(cfg.mlp_act)(
+        checkpoint_name(x @ w1.astype(x.dtype), MLP_UP))
     if w3 is not None:
-        u = u * (x @ w3.astype(x.dtype))
+        u = u * checkpoint_name(x @ w3.astype(x.dtype), MLP_UP)
     return u @ w2.astype(x.dtype)
 
 
-def _residual(h, y, post, cfg: Config):
+def _residual(h, y, post, cfg: Config, name: str):
     """h + y; where the config puts a norm on a sub-layer's output
-    (`post`: that norm's leaves), h + norm(y)."""
+    (`post`: that norm's leaves), h + norm(y). y, the sub-layer's
+    output before that norm (whose backward pass reads it), carries
+    `name` for `_run_layer`'s policy."""
+    y = checkpoint_name(y, name)
     if post is None:
         return h + y
     return h + _norm(y.astype(jnp.float32), post, cfg).astype(y.dtype)
@@ -595,12 +611,13 @@ def _mla_project(lp, x, cfg: Config, positions):
     h, nope, rkv = cfg.n_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
     turn = rope_interleaved if cfg.rope_interleave else rope
     with jax.named_scope("mla_q"):
-        c_q = _rms((x @ lp["wq_a"].astype(dt)).astype(jnp.float32),
+        q_a = checkpoint_name(x @ lp["wq_a"].astype(dt), MLA_LATENTS)
+        c_q = _rms(q_a.astype(jnp.float32),
                    lp["q_a_norm"]["g"], cfg.norm_eps).astype(dt)
         q = (c_q @ lp["wq_b"].astype(dt)).reshape(
             b, t, h, nope + cfg.qk_rope_dim)
     with jax.named_scope("mla_kv"):
-        kv_a = x @ lp["wkv_a"].astype(dt)
+        kv_a = checkpoint_name(x @ lp["wkv_a"].astype(dt), MLA_LATENTS)
         c_kv = _rms(kv_a[..., :rkv].astype(jnp.float32),
                     lp["kv_a_norm"]["g"], cfg.norm_eps).astype(dt)
         kv = (c_kv @ lp["wkv_b"].astype(dt)).reshape(
@@ -646,9 +663,11 @@ def _dsa_core(q, k, v, index, cfg: Config, index_aux):
 
     def one(q, k, v, qi, ki, w):
         with jax.named_scope("dsa_index"):
-            scores = att.dsa_index_scores(qi, ki, w)
-            keep = att.dsa_select(lax.stop_gradient(scores),
-                                  cfg.index_topk)
+            scores = checkpoint_name(att.dsa_index_scores(qi, ki, w),
+                                     DSA_SELECT)
+            keep = checkpoint_name(
+                att.dsa_select(lax.stop_gradient(scores), cfg.index_topk),
+                DSA_SELECT)
         with jax.named_scope("dsa_attend"):
             o, p = att.dsa_attend(q, k, v, keep, scale)
         with jax.named_scope("dsa_kl"):
@@ -685,7 +704,8 @@ def _mla_attention(lp, h, x, cfg: Config, pos_offset, index_aux):
             o = attend(q, k, v, causal=True)
     with jax.named_scope("attn_proj"), jax.named_scope("mla_o"):
         return _residual(h, o.reshape(b, t, -1) @ lp["wo"].astype(cfg.dtype),
-                         lp["ln1_post"] if cfg.post_norm else None, cfg)
+                         lp["ln1_post"] if cfg.post_norm else None, cfg,
+                         ATTN_PROJ_OUT)
 
 
 def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
@@ -760,7 +780,8 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
         o = o @ lp["wo"].astype(dt)   # row parallel: partial sums
         if ax.tp:
             o = region_exit(o, ax.tp)
-        h = _residual(h, o, lp["ln1_post"] if cfg.post_norm else None, cfg)
+        h = _residual(h, o, lp["ln1_post"] if cfg.post_norm else None, cfg,
+                      ATTN_PROJ_OUT)
     return _ffn_half(lp, h, cfg, ax, is_moe, aux)
 
 
@@ -795,25 +816,267 @@ def _ffn_half(lp, h, cfg: Config, ax: Axes, is_moe: bool, aux):
             if ax.tp:
                 y = region_exit(y, ax.tp)
         return _residual(h, y, lp["ln2_post"] if cfg.post_norm else None,
-                         cfg)
+                         cfg, MLP_OUT)
+
+
+#: Names (``jax.ad_checkpoint.checkpoint_name``) of what a layer
+#: application makes that its backward pass reads, beside those
+#: ops/attention.py gives (QKV, ATTN_OUT, DSA_PROBS): a sub-layer's
+#: output before its residual add and output norm; the FFN's (and a
+#: shared expert's) up-projections; latent attention's down-projections
+#: before their norms; the indexer's scores and the selection.
+ATTN_PROJ_OUT = "attn_proj_out"
+MLP_OUT = "mlp_out"
+MLP_UP = "mlp_up"
+MLA_LATENTS = "mla_latents"
+DSA_SELECT = "dsa_select"
+
+#: The share of the device's memory limit the reckoned peak may reach.
+#: The rest is the room for what the reckoning misses: on a v5e
+#: (16.9 GB) 2.5 GB, where the four full-size compiles of PR 35 put
+#: the compiled peak between 0.9 GB under and 1.0 GB over the
+#: reckoned one (PERF.md section 6; tests/test_remat_policy.py holds
+#: the rule to twice that).
+REMAT_SHARE = 0.85
+
+
+def remat_sizes(cfg: Config, b: int, t: int, is_moe: bool) -> Dict[str, int]:
+    """Bytes ONE application of a layer holds under each name, of
+    [b, t] tokens: the names that layer kind makes and its backward
+    pass reads (the FFN's output only where a norm follows it: a bare
+    residual add's backward reads nothing), from the config's widths
+    alone."""
+    it = jnp.dtype(cfg.dtype).itemsize
+    n, d, heads = b * t, cfg.d_model, cfg.n_heads
+    gated = 2 if cfg.mlp_gated else 1
+    ff = cfg.n_shared_experts * cfg.expert_d_ff if is_moe else cfg.d_ff
+    sizes = {ATTN_PROJ_OUT: n * d * it,
+             MLP_OUT: n * d * it if cfg.post_norm else 0,
+             MLP_UP: n * ff * gated * it}
+    if cfg.attn == "mla":
+        qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+        sizes[att.ATTN_OUT] = n * heads * (cfg.v_head_dim * it + 4)
+        sizes[att.QKV] = n * heads * (2 * qk + cfg.v_head_dim) * it
+        sizes[MLA_LATENTS] = n * (cfg.q_lora_rank + cfg.kv_lora_rank
+                                  + cfg.qk_rope_dim) * it
+        if cfg.index_topk and t > cfg.index_topk:
+            sizes[att.DSA_PROBS] = b * t * t * 4
+            sizes[DSA_SELECT] = b * t * t * (4 + 1)
+    else:
+        sizes[att.ATTN_OUT] = n * heads * (cfg.head_dim * it + 4)
+        sizes[att.QKV] = 3 * n * d * it
+    return {name: size for name, size in sizes.items() if size}
+
+
+def remat_spared(cfg: Config, b: int, t: int, is_moe: bool) -> Dict[str, int]:
+    """The operations of the PRODUCTS one application's backward pass
+    need not make again where a name is kept (each name as if kept
+    alone; the norms, RoPE, layout changes and the indexer's search it
+    spares beside are not counted): from the config's widths alone,
+    attention's over the causal half."""
+    n, d, heads = b * t, cfg.d_model, cfg.n_heads
+    gated = 2 if cfg.mlp_gated else 1
+    ff = cfg.n_shared_experts * cfg.expert_d_ff if is_moe else cfg.d_ff
+    ops = {MLP_OUT: 2 * n * ff * d, MLP_UP: 2 * n * d * ff * gated}
+    if cfg.attn == "mla":
+        qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+        ops[ATTN_PROJ_OUT] = 2 * n * heads * cfg.v_head_dim * d
+        ops[att.ATTN_OUT] = n * t * heads * (qk + cfg.v_head_dim)
+        ops[att.QKV] = 2 * n * heads * (
+            cfg.q_lora_rank * qk
+            + cfg.kv_lora_rank * (cfg.qk_nope_dim + cfg.v_head_dim))
+        ops[MLA_LATENTS] = 2 * n * d * (cfg.q_lora_rank + cfg.kv_lora_rank
+                                        + cfg.qk_rope_dim)
+        ops[att.DSA_PROBS] = n * t * heads * qk
+        ops[DSA_SELECT] = n * t * cfg.index_heads * cfg.index_dim
+    else:
+        ops[ATTN_PROJ_OUT] = 2 * n * d * d
+        ops[att.ATTN_OUT] = 2 * n * t * d
+        ops[att.QKV] = 3 * 2 * n * d * d
+    return ops
+
+
+def _application_kinds(cfg: Config):
+    """Per layer application of a step, in order: is it a MoE layer's
+    (the trunk's layers, pass after pass, then the multi-token-prediction
+    modules)."""
+    return [_is_moe(cfg, i) for i in range(cfg.n_layers)] * cfg.loops \
+        + [_is_moe(cfg, cfg.n_layers)] * cfg.mtp_layers
+
+
+def remat_order(cfg: Config, b: int, t: int):
+    """[(name, bytes all the step's applications hold under it)], the
+    dearest first: by the operations a name spares per byte it holds
+    (over a product's result that is 2 x the contracted width / the
+    item size: 16,384 wide, GLM-5's attention output projection stands
+    first; 2,048 wide, Ouro's stands behind its attention and its FFN's
+    output), of equals the smaller first."""
+    kinds = _application_kinds(cfg)
+    per = {moe: (remat_sizes(cfg, b, t, moe), remat_spared(cfg, b, t, moe))
+           for moe in set(kinds)}
+    held, spared = {}, {}
+    for moe in kinds:
+        sizes, ops = per[moe]
+        for name, size in sizes.items():
+            held[name] = held.get(name, 0) + size
+            spared[name] = spared.get(name, 0) + ops[name]
+    return sorted(held.items(),
+                  key=lambda kv: (-spared[kv[0]] / kv[1], kv[1], kv[0]))
+
+
+def whole_step_peak(cfg: Config, b: int, t: int, param_bytes: int) -> int:
+    """The bytes a train step (`make_train_step`) is reckoned to hold
+    at its peak with every layer application recomputed from its input
+    alone, term by term from the program: the parameters; their
+    gradients (all of them where the layers run more than once and a
+    leaf's gradient is a sum over the passes, else one application's
+    share: the update takes each as it appears); an input per
+    application; ONE exit's float32 logits, their exponentials and
+    their cotangent (`_exit_terms`, `_token_nll`); the values one
+    application's backward pass makes again and a cotangent for
+    each."""
+    it = jnp.dtype(cfg.dtype).itemsize
+    kinds = _application_kinds(cfg)
+    grads = param_bytes if cfg.loops > 1 \
+        else param_bytes // max(len(kinds), 1)
+    again = max(sum(remat_sizes(cfg, b, t, moe).values())
+                for moe in set(kinds))
+    return (param_bytes + grads + len(kinds) * b * t * cfg.d_model * it
+            + 3 * b * t * cfg.vocab * 4 + 2 * again)
+
+
+def remat_keep(cfg: Config, b: int, t: int, param_bytes: int,
+               limit: Optional[int]) -> Tuple[str, ...]:
+    """The rule that says what a recomputed layer application keeps
+    for its backward pass, made of what the trace can observe: the
+    tokens' shape, the config's widths and depth, the bytes of the
+    parameters and the device's memory limit. It starts from
+    `whole_step_peak`, walks the names in `remat_order`, adding what
+    all the applications hold under a name, and stops before the first
+    name that would take the reckoned peak past `REMAT_SHARE` of the
+    limit. No limit (the CPU) or no room: the empty tuple, every
+    application recomputed whole — the parent's program."""
+    if not limit:
+        return ()
+    peak = whole_step_peak(cfg, b, t, param_bytes)
+    keep = []
+    for name, held in remat_order(cfg, b, t):
+        if peak + held > REMAT_SHARE * limit:
+            break
+        keep.append(name)
+        peak += held
+    return tuple(keep)
+
+
+def _memory_limit() -> Optional[int]:
+    """The bytes a process may hold on its first device (None where the
+    backend does not say: the CPU)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
+def _remat_names(params, tokens, cfg: Config) -> Tuple[str, ...]:
+    """`remat_keep` on what this trace has."""
+    if not cfg.remat:
+        return ()
+    return remat_keep(
+        cfg, *tokens.shape,
+        sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params)),
+        _memory_limit())
+
+
+def _recomputed_layer(cfg: Config, ax: Axes, is_moe: bool,
+                      keep: Tuple[str, ...], fixed_offset: bool,
+                      counted: dict):
+    """layer_forward as a function of arrays — (lp, h, pos_offset) ->
+    (the block's output, what it collected for `aux`, for `index_aux`)
+    — recomputed in the backward pass but for the names in `keep`.
+    Where `fixed_offset`, pos_offset is None or a Python int and part
+    of the program, as a value the function closed over would be. jax
+    traces the function when it likes (once behind `_kept_layer`'s
+    `jit`), so what layer_forward counts (pvars) while traced on an
+    input of a shape and type is set aside in `counted`, for the caller
+    to count once per application."""
+    def layer(lp, h, pos_offset):
+        mine, index_mine = [], []
+        with pvar.captured() as counts:
+            out = layer_forward(lp, h, cfg, ax, is_moe,
+                                pos_offset=pos_offset, aux=mine,
+                                index_aux=index_mine)
+        counted[h.shape, h.dtype] = counts
+        return out, mine, index_mine
+
+    # no policy where nothing is kept: under one, even an empty one, jax
+    # splits every inner jitted function afresh at each call site
+    return jax.checkpoint(
+        layer, policy=jax.checkpoint_policies.save_only_these_names(*keep)
+        if keep else None, static_argnums=(2,) if fixed_offset else ())
+
+
+def _kept_layer(cfg: Config, ax: Axes, is_moe: bool, keep: Tuple[str, ...],
+                fixed_offset: bool, counted: dict):
+    """`_recomputed_layer` as ONE jitted function: with a policy jax
+    splits every inner jitted function (the attention kernels', the
+    activation's) into what is kept and what is made again, afresh at
+    each call site — 48 applications of the same layer traced, split
+    and lowered 48 times, kernels and all (ouro-train-t4096's step:
+    17 s of tracing and 6 of lowering where the recomputation with no
+    policy, which splits nothing, takes 6 and 2; PERF.md section 6,
+    PR 35). Behind a `jit` of its own the layer is traced, linearized,
+    split, transposed and lowered once per kind and shape, and called;
+    XLA inlines the calls."""
+    return jax.jit(
+        _recomputed_layer(cfg, ax, is_moe, keep, fixed_offset, counted),
+        static_argnums=(2,) if fixed_offset else ())
+
+
+class _Recomputed:
+    """The recomputed layers of ONE trace of a step: the names they
+    keep (`remat_keep`'s answer for the trace) and each layer kind as
+    one jitted function (`_kept_layer`) shared by that kind's
+    applications in this trace — and in no other, so a later trace
+    sees the rules and the device as they are then."""
+
+    def __init__(self, cfg: Config, ax: Axes, keep: Tuple[str, ...]):
+        self.cfg, self.ax, self.keep = cfg, ax, keep
+        self._kinds = {}  # (is_moe, fixed_offset) -> (layer, counted)
+
+    def __call__(self, lp, h, is_moe: bool, pos_offset):
+        fixed = pos_offset is None or isinstance(pos_offset, int)
+        if (is_moe, fixed) not in self._kinds:
+            counted = {}
+            self._kinds[is_moe, fixed] = _kept_layer(
+                self.cfg, self.ax, is_moe, self.keep, fixed,
+                counted), counted
+        layer, counted = self._kinds[is_moe, fixed]
+        results = layer(lp, h, pos_offset)
+        for name, count in counted[h.shape, h.dtype].items():
+            pvar.record(name, count)
+        return results
 
 
 def _run_layer(lp, h, cfg: Config, ax: Axes, is_moe: bool, pos_offset,
-               aux, index_aux):
-    """layer_forward, recomputed in the backward pass from the layer's
-    input where the config says so (what a layer collects for `aux`
-    and `index_aux` then leaves the recomputed region as results)."""
+               aux, index_aux, recomputed: _Recomputed):
+    """layer_forward, recomputed in the backward pass where the config
+    says so (what a layer collects for `aux` and `index_aux` then
+    leaves the recomputed region as results). The backward pass is
+    given the layer's input and what the application made under the
+    names `recomputed` keeps, and makes the rest again; with no name
+    kept — the fallback: a device that states no limit or has no room —
+    that is the whole layer, from its input, as before there were
+    names. ONE path either way. Counted once per traced application:
+    pvars ``remat_kept_applications`` or ``remat_whole_applications``,
+    ``remat_kept_bytes`` (the rule's reckoning of what it holds), and
+    whatever layer_forward counts of itself."""
     if not cfg.remat:
         return layer_forward(lp, h, cfg, ax, is_moe, pos_offset=pos_offset,
                              aux=aux, index_aux=index_aux)
-
-    def layer(lp, h):
-        mine, index_mine = [], []
-        out = layer_forward(lp, h, cfg, ax, is_moe, pos_offset=pos_offset,
-                            aux=mine, index_aux=index_mine)
-        return out, mine, index_mine
-
-    out, mine, index_mine = jax.checkpoint(layer)(lp, h)
+    sizes = remat_sizes(cfg, h.shape[0], h.shape[1], is_moe)
+    pvar.record("remat_kept_applications" if recomputed.keep
+                else "remat_whole_applications")
+    pvar.record("remat_kept_bytes",
+                sum(sizes.get(name, 0) for name in recomputed.keep))
+    out, mine, index_mine = recomputed(lp, h, is_moe, pos_offset)
     if aux is not None:
         aux.extend(mine)
     if index_aux is not None:
@@ -844,6 +1107,7 @@ def _trunk(params, tokens, cfg: Config, ax: Axes, aux=None, index_aux=None,
                 if ax.sp else params["pos"][:t]
             h = h + pos.astype(dt)[None]
 
+    recomputed = _Recomputed(cfg, ax, _remat_names(params, tokens, cfg))
     for s in range(cfg.loops):
         with jax.named_scope(f"loop_{s}") if cfg.loops > 1 \
                 else contextlib.nullcontext():
@@ -852,7 +1116,7 @@ def _trunk(params, tokens, cfg: Config, ax: Axes, aux=None, index_aux=None,
                 pvar.record("loop_layer_applications")
                 with jax.named_scope(f"layer_{i}"):
                     h = _run_layer(lp, h, cfg, ax, _is_moe(cfg, i), t_off,
-                                   aux, index_aux)
+                                   aux, index_aux, recomputed)
             if s < cfg.loops - 1:
                 h = _final_norm(params, h, cfg)
                 if exits is not None:
@@ -914,7 +1178,8 @@ def _mtp_forward(mp, h, params, labels, cfg: Config, ax: Axes, pos_offset,
              _norm(e.astype(jnp.float32), mp["enorm"], cfg)], axis=-1)
         h = both.astype(dt) @ mp["eh_proj"].astype(dt)
     return _run_layer(mp, h, cfg, ax, _is_moe(cfg, cfg.n_layers),
-                      pos_offset, aux, index_aux)
+                      pos_offset, aux, index_aux, _Recomputed(
+                          cfg, ax, _remat_names(params, labels, cfg)))
 
 
 def _token_nll(logits, labels, mask):

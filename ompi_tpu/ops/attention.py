@@ -33,6 +33,17 @@ of T 4096 with seeded weights there is none (PERF.md section 6, PR 31).
 The library's splash kernels take such a mask too (a ``jax.Array``
 mask, jax 0.9.0) and lost the probe on the chip. A sequence no longer
 than the top-k selects nothing and takes :func:`attention`.
+
+What a backward pass reads of an attention carries a NAME
+(``jax.ad_checkpoint.checkpoint_name``: an identity wherever no
+``jax.checkpoint`` policy asks for it, it lowers to its operand):
+:data:`QKV` — q, k and v as the path taken reads them (the kernels'
+head-major layout on the TPU) — and :data:`ATTN_OUT` — the output and,
+from a kernel, the per-row log-sum-exp, named inside the kernels' own
+forward rules, so that a policy that keeps them spares the backward
+pass the forward kernel; :func:`dsa_attend` names the heads' summed
+probabilities :data:`DSA_PROBS` too. ``models/transformer.py`` decides
+which of them a recomputed layer keeps.
 """
 
 from __future__ import annotations
@@ -43,8 +54,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ompi_tpu.core import pvar
+
+#: Names of what an attention's backward pass reads (the module
+#: docstring's last paragraph).
+QKV = "qkv"
+ATTN_OUT = "attn_out"
+DSA_PROBS = "dsa_probs"
 
 
 def mha(q, k, v, causal: bool = True, scale: Optional[float] = None,
@@ -121,7 +139,8 @@ def _splash_kernel(t: int, heads: int, tile: int, interpret: bool):
     # program that uses it, not values of the trace that asked first
     with jax.ensure_compile_time_eval():
         return sk.make_splash_mha_single_device(
-            mask, block_sizes=sizes, interpret=interpret)
+            mask, block_sizes=sizes, interpret=interpret,
+            residual_checkpoint_name=ATTN_OUT)
 
 
 def blockwise_mha(q, k, v, tile: int, scale: Optional[float] = None,
@@ -141,8 +160,8 @@ def blockwise_mha(q, k, v, tile: int, scale: Optional[float] = None,
     if scale != 1.0:
         q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     kernel = _splash_kernel(t, h, tile, interpret)
-    o = jax.vmap(kernel)(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                         v.transpose(0, 2, 1, 3))
+    o = jax.vmap(kernel)(*checkpoint_name(
+        tuple(a.transpose(0, 2, 1, 3) for a in (q, k, v)), QKV))
     return o.transpose(0, 2, 1, 3)
 
 
@@ -157,8 +176,9 @@ def attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
                           q.shape[-1], causal, q_offset, k_offset)
     if tile is None:
         pvar.record("attn_reference_layers")
-        return mha(q, k, v, causal=causal, scale=scale, q_offset=q_offset,
-                   k_offset=k_offset)
+        return checkpoint_name(
+            mha(*checkpoint_name((q, k, v), QKV), causal=causal, scale=scale,
+                q_offset=q_offset, k_offset=k_offset), ATTN_OUT)
     pvar.record("attn_blockwise_layers")
     return blockwise_mha(q, k, v, tile, scale=scale)
 
@@ -313,7 +333,7 @@ def _dsa_kernels(tiles, interpret: bool):
         return sa.forward(q, k, v, keep, **on)
 
     def fwd(q, k, v, keep):
-        o, lse = attend(q, k, v, keep)
+        o, lse = checkpoint_name(attend(q, k, v, keep), ATTN_OUT)
         return (o, lse), (q, k, v, keep.T, o, lse)  # dsa_bwd works S^T
 
     def bwd(res, cts):
@@ -348,17 +368,21 @@ def dsa_attend(q, k, v, keep, scale: float, interpret: bool = False):
                      q.dtype.itemsize) if same else None
     if tiles is None:
         pvar.record("attn_dsa_masked_layers")
-        return _dsa_attend_blocks(q, k, v, keep, scale)
+        o, p = _dsa_attend_blocks(*checkpoint_name((q, k, v), QKV), keep,
+                                  scale)
+        return checkpoint_name(o, ATTN_OUT), checkpoint_name(p, DSA_PROBS)
     pvar.record("attn_dsa_kernel_layers")
     attend, head_sum = _dsa_kernels(tiles, interpret)
     # the kernels have no scale of their own: q carries it (GLM-5's is
     # 1/16: exact in any float type)
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    qh, kh, vh = (a.transpose(1, 0, 2) for a in (q, k, v))
+    qh, kh, vh = checkpoint_name(
+        tuple(a.transpose(1, 0, 2) for a in (q, k, v)), QKV)
     o, lse = attend(qh, kh, vh, keep.astype(jnp.int8))
     p = head_sum(*map(lax.stop_gradient, (qh, kh, lse)))
     # the head sum reads no mask and writes no pair above the diagonal
-    return o.transpose(1, 0, 2), jnp.where(keep, p, 0.0)
+    return o.transpose(1, 0, 2), checkpoint_name(jnp.where(keep, p, 0.0),
+                                                 DSA_PROBS)
 
 
 def _dsa_attend_blocks(q, k, v, keep, scale: float):

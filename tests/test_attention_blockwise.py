@@ -28,6 +28,7 @@ from ompi_tpu.models import transformer as tfm  # noqa: E402
 from ompi_tpu.ops import attention as att  # noqa: E402
 from ompi_tpu.parallel import make_mesh  # noqa: E402
 from ompi_tpu.util import jaxcompat  # noqa: E402
+from tests import lowered_text  # noqa: E402
 
 TILE = 128
 
@@ -226,7 +227,11 @@ def test_the_cpu_step_is_the_step_that_calls_the_reference(model,
                                                            monkeypatch):
     """On the CPU the entry adds nothing to the program: the lowered
     step is, as text, the one whose layers call att.mha themselves, as
-    every layer did before there was an entry."""
+    every layer did before there was an entry (the names the entry
+    gives q, k, v and the output for a recomputed layer's policy are
+    taken out: they lower to their operands, and move jax's numbering
+    of its private functions — tests/lowered_text.py)."""
+    lowered_text.without_names(monkeypatch)
     cfg, params, tok, lab = _toy(model, jnp.bfloat16)
     through_entry = _step(cfg).lower(params, tok, lab).as_text()
     monkeypatch.setattr(

@@ -7,7 +7,6 @@ prediction) and per-layer recomputation — and that what was there
 before (OPT, OLMoE) lowers to the parent's text."""
 
 import functools
-import hashlib
 import json
 import os
 
@@ -25,6 +24,7 @@ from ompi_tpu.core import pvar
 from ompi_tpu.models import transformer as tfm
 from ompi_tpu.ops import attention as att
 from ompi_tpu.ops import moe
+from tests import lowered_text
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AX = tfm.Axes()
@@ -509,7 +509,11 @@ PARENT = {
 
 
 @pytest.mark.parametrize("name, dtype", sorted(PARENT))
-def test_the_lowered_toy_steps_are_the_parents_text(name, dtype):
+def test_the_lowered_toy_steps_are_the_parents_text(name, dtype,
+                                                    monkeypatch):
+    """Without the residuals' names (PR 35) the text is the parent's,
+    raw; with them it is that text but for jax's numbering of its
+    private functions (tests/lowered_text.py)."""
     if jax.__version__ != "0.9.0":
         pytest.skip("the recorded text is jax 0.9.0's")
     runner, made = {"opt-30b": (train_step, weights),
@@ -520,9 +524,16 @@ def test_the_lowered_toy_steps_are_the_parents_text(name, dtype):
     config["param_dtype"] = dtype
     sizes = runner.model_sizes(config)
     toks, labs = weights.batches(sizes["vocab"], 1, 2, 64, 1)
-    text = runner.build_step(sizes, 0.01).lower(
-        made.device_init(sizes, 1), toks[0], labs[0]).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT[name, dtype]
+
+    def text():
+        return runner.build_step(sizes, 0.01).lower(
+            made.device_init(sizes, 1), toks[0], labs[0]).as_text()
+
+    named = text()
+    lowered_text.without_names(monkeypatch)
+    bare = text()
+    assert lowered_text.sha256(bare) == PARENT[name, dtype]
+    assert lowered_text.canonical(named) == lowered_text.canonical(bare)
 
 
 def test_the_seeded_tree_is_init_params_tree():
