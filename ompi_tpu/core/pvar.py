@@ -48,6 +48,9 @@ WELL_KNOWN = (
     # ops/attention.attention, once per TRACED attention (the choice
     # is static inside jit): the blockwise kernel, or att.mha
     "attn_blockwise_layers", "attn_reference_layers",
+    # the same, once per TRACED attention that took a segment mask
+    # (whichever of the two ways it went)
+    "attn_segment_layers",
     # ops/moe.sorted_moe_ffn, once per TRACED MoE layer: its grouped
     # matmuls are the Pallas kernels, or lax.ragged_dot
     "moe_grouped_kernel_layers", "moe_ragged_dot_layers",
@@ -62,6 +65,16 @@ WELL_KNOWN = (
     # (MLA); of those, the layers whose sparse-attention indexer
     # selects (the sequence is longer than index_topk)
     "attn_mla_layers", "attn_dsa_layers",
+    # of the latent-attention layers, those whose query is one product
+    # of x (no query latent: q_lora_rank 0)
+    "attn_mla_plain_q_layers",
+    # models/vision.py, per TRACED step of a config with a tower: the
+    # patches of the packed row, and the positions of the sequence
+    # their merged rows replace; the set-up probe vision.vision_stats:
+    # the images of the batch it read, and the (query, key) pairs of
+    # the block diagonal against all pairs of the packed row
+    "vision_patches", "vision_image_positions", "vision_images",
+    "vision_diag_pairs", "vision_row_pairs",
     # ops/attention.dsa_attend, once per TRACED call: the Pallas
     # kernels of ops/sparse_attention.py, or the masked blocks
     "attn_dsa_kernel_layers", "attn_dsa_masked_layers",

@@ -56,6 +56,18 @@ sub-layer's output too (``post_norm``), and after each pass an exit —
 the shared head's loss and a learned gate (``exit_gate``); the loss is
 the expected loss under the exit distribution the gates give, less
 ``exit_entropy_weight`` times that distribution's entropy.
+The fifth (reference ``benchmark/reference/kimivl_decoder.py``) reads
+images: a second TOWER (``vision``: a :mod:`ompi_tpu.models.vision`
+``VisionConfig``; a native-resolution ViT over images packed back to
+back in one row of patches, attention both ways inside an image and
+not across images, and a projector) whose merged rows replace the
+embedding's rows at the image positions, in front of a latent-attention
+decoder WITHOUT a query latent (``q_lora_rank`` 0: q is one product of
+x) whose heads are 192 wide against values of 128. A batch is then a
+dict — today's ``tokens`` array and the packed images' leaves beside
+it — and the loss runs over the positions whose label is not -1 (the
+text). The tower's blocks are a third layer kind (`VIT`) of the
+recomputation rule.
 
 Names on the device (``jax.named_scope``: metadata, the HLO is the
 same): the jitted step is module ``jit_ompi_train_step``; its ops carry
@@ -81,7 +93,11 @@ INSIDE the scopes named first. Where the layers run more than once,
 pass s is ``loop_<s>`` AROUND its ``layer_<i>`` scopes, with the norm
 between passes as ``loop_<s>/ln``; exit s is ``head_loss/exit_<s>``
 and the gates, the exit distribution and its entropy
-``head_loss/exit_gate``.
+``head_loss/exit_gate``. A vision tower is ``embed/vision`` >
+``vit_embed``, ``vit_<i>`` > {``ln``, ``attn_proj`` > ``rope2d``,
+``attn_core``, ``mlp``}, ``vit_merge`` (models/vision.py): AROUND the
+names above, so a reader of ``attn_core`` sums the tower's too and one
+of ``vision`` tells them apart.
 """
 
 from __future__ import annotations
@@ -181,6 +197,8 @@ class Config:
     #: head a no-position part of qk_nope_dim and a RoPE part of
     #: qk_rope_dim whose key is ONE for all heads, values of v_head_dim)
     attn: str = "mha"
+    #: the query latent's width; 0: no query latent, q is ONE product
+    #: of x (leaf wq [d_model, n_heads x (nope + rope)])
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
@@ -225,6 +243,14 @@ class Config:
     #: exit distribution, per token
     exit_gate: bool = False
     exit_entropy_weight: float = 0.0
+    #: a vision tower in front of the decoder (a models/vision.py
+    #: VisionConfig; None: none): a batch is then a dict — the ids
+    #: under "tokens" and the packed images' leaves beside them — and
+    #: the tower's merged rows replace the embedding's at the image
+    #: positions (params["vision"]). Its norms are LayerNorms with gain
+    #: and bias and every product of it has a bias, whatever the
+    #: decoder's `norm` says; the decoder's products have none
+    vision: Any = None
 
     @property
     def head_dim(self) -> int:
@@ -294,11 +320,13 @@ def init_params(rng: np.random.Generator, cfg: Config) -> Dict:
         return {"g": np.ones(n, pdt)}
 
     def mla():
+        _check_indexer(cfg)
         h, rq, rkv = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
         qk = cfg.qk_nope_dim + cfg.qk_rope_dim
-        lp = {
+        lp = {"wq": normal(d, h * qk, scale=s_emb)} if not rq else {
             "wq_a": normal(d, rq, scale=s_emb), "q_a_norm": gain(rq),
-            "wq_b": normal(rq, h * qk, scale=1.0 / math.sqrt(rq)),
+            "wq_b": normal(rq, h * qk, scale=1.0 / math.sqrt(rq))}
+        lp.update({
             "wkv_a": normal(d, rkv + cfg.qk_rope_dim, scale=s_emb),
             "kv_a_norm": gain(rkv),
             "wkv_b": normal(rkv, h * (cfg.qk_nope_dim + cfg.v_head_dim),
@@ -306,7 +334,7 @@ def init_params(rng: np.random.Generator, cfg: Config) -> Dict:
             "wo": normal(h * cfg.v_head_dim, d,
                          scale=1.0 / math.sqrt(h * cfg.v_head_dim)
                          / math.sqrt(2 * cfg.n_layers)),
-        }
+        })
         if cfg.index_topk:
             lp.update(
                 wi_q=normal(rq, cfg.index_heads * cfg.index_dim,
@@ -356,6 +384,10 @@ def init_params(rng: np.random.Generator, cfg: Config) -> Dict:
             layer(_is_moe(cfg, cfg.n_layers)), enorm=norm(), hnorm=norm(),
             eh_proj=normal(2 * d, d, scale=1.0 / math.sqrt(2 * d)))
             for _ in range(cfg.mtp_layers)]
+    if cfg.vision is not None:
+        from ompi_tpu.models import vision
+
+        params["vision"] = vision.init_params(rng, cfg.vision, d, pdt)
     return params
 
 
@@ -382,9 +414,12 @@ def _like_params(cfg: Config, leaf, wide, expert):
         if cfg.post_norm:
             lt.update(ln1_post=norm(), ln2_post=norm())
         if cfg.attn == "mla":  # replicated: no tp path yet
-            lt.update({n: leaf for n in ("wq_a", "wq_b", "wkv_a", "wkv_b",
-                                         "wo")})
-            lt.update(q_a_norm={"g": leaf}, kv_a_norm={"g": leaf})
+            lt.update({n: leaf for n in ("wkv_a", "wkv_b", "wo")})
+            lt.update(kv_a_norm={"g": leaf})
+            if cfg.q_lora_rank:
+                lt.update(wq_a=leaf, wq_b=leaf, q_a_norm={"g": leaf})
+            else:
+                lt["wq"] = leaf
             if cfg.index_topk:
                 lt.update(wi_q=leaf, wi_k=leaf, wi_w=leaf,
                           wi_k_norm={"g": leaf, "b": leaf})
@@ -408,6 +443,10 @@ def _like_params(cfg: Config, leaf, wide, expert):
         tree["mtp"] = [dict(layer(_is_moe(cfg, cfg.n_layers)), enorm=norm(),
                             hnorm=norm(), eh_proj=leaf)
                        for _ in range(cfg.mtp_layers)]
+    if cfg.vision is not None:
+        from ompi_tpu.models import vision
+
+        tree["vision"] = vision.like_params(cfg.vision, leaf)
     return tree
 
 
@@ -497,9 +536,23 @@ def rope_interleaved(x, positions, theta: float):
                      axis=-1).reshape(x.shape).astype(x.dtype)
 
 
+def _check_indexer(cfg: Config):
+    if cfg.attn == "mla" and cfg.index_topk and not cfg.q_lora_rank:
+        raise NotImplementedError(
+            "the sparse-attention indexer (index_topk) reads the query "
+            "latent, which a config with q_lora_rank 0 does not have")
+
+
 def _check_supported(cfg: Config, ax: Axes, is_moe: bool, pos_offset):
     """What the config may ask for that an axis cannot give yet is an
     error, never another function computed in silence."""
+    if cfg.vision is not None and (ax.tp or ax.sp or ax.pp):
+        raise NotImplementedError(
+            "a vision tower (Config.vision) under tensor, sequence or "
+            "pipeline parallelism (ax.tp, ax.sp, ax.pp): the tower is "
+            "replicated and runs whole before the first layer; which "
+            "stage owns it, its column split and a packed row of "
+            "patches over a sharded sequence are ROADMAP Queue 2a")
     if cfg.attn == "mla" and (ax.tp or ax.sp):
         raise NotImplementedError(
             "latent attention (attn='mla') and its sparse-attention "
@@ -509,6 +562,7 @@ def _check_supported(cfg: Config, ax: Axes, is_moe: bool, pos_offset):
             "not written yet")
     if cfg.attn not in ("mha", "mla"):
         raise ValueError(f"attn={cfg.attn!r}: expected 'mha' or 'mla'")
+    _check_indexer(cfg)
     if is_moe and cfg.held_experts and ax.ep:
         raise NotImplementedError(
             "held experts (held_experts) describe ONE chip's share of "
@@ -611,11 +665,14 @@ def _mla_project(lp, x, cfg: Config, positions):
     h, nope, rkv = cfg.n_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
     turn = rope_interleaved if cfg.rope_interleave else rope
     with jax.named_scope("mla_q"):
-        q_a = checkpoint_name(x @ lp["wq_a"].astype(dt), MLA_LATENTS)
-        c_q = _rms(q_a.astype(jnp.float32),
-                   lp["q_a_norm"]["g"], cfg.norm_eps).astype(dt)
-        q = (c_q @ lp["wq_b"].astype(dt)).reshape(
-            b, t, h, nope + cfg.qk_rope_dim)
+        if cfg.q_lora_rank:
+            q_a = checkpoint_name(x @ lp["wq_a"].astype(dt), MLA_LATENTS)
+            c_q = _rms(q_a.astype(jnp.float32),
+                       lp["q_a_norm"]["g"], cfg.norm_eps).astype(dt)
+            q = c_q @ lp["wq_b"].astype(dt)
+        else:
+            c_q, q = None, x @ lp["wq"].astype(dt)
+        q = q.reshape(b, t, h, nope + cfg.qk_rope_dim)
     with jax.named_scope("mla_kv"):
         kv_a = checkpoint_name(x @ lp["wkv_a"].astype(dt), MLA_LATENTS)
         c_kv = _rms(kv_a[..., :rkv].astype(jnp.float32),
@@ -683,12 +740,20 @@ def _mla_attention(lp, h, x, cfg: Config, pos_offset, index_aux):
     """h + the latent-attention half of a block on one device (x: the
     normed h). Where the sequence is longer than index_topk each query
     attends to the keys its indexer selects; else to all causal ones,
-    through the model's one causal entry."""
+    through the model's one entry (``ops.attention.attention``: the
+    blockwise kernel on the TPU also where q and v differ in width,
+    192 against 128 in the fifth model — the kernel pads both to its
+    lanes; ``att.mha`` off the TPU or at a length no tile divides,
+    pvar ``attn_reference_layers``). Counted once per traced layer:
+    ``attn_mla_layers``, and ``attn_mla_plain_q_layers`` for those
+    without a query latent."""
     b, t = h.shape[0], h.shape[1]
     positions = jnp.arange(t) if pos_offset is None \
         else pos_offset + jnp.arange(t)
     selects = bool(cfg.index_topk) and t > cfg.index_topk
     pvar.record("attn_mla_layers")
+    if not cfg.q_lora_rank:
+        pvar.record("attn_mla_plain_q_layers")
     with jax.named_scope("attn_proj"):
         q, k, v, c_q = _mla_project(lp, x, cfg, positions)
         if selects:
@@ -698,10 +763,8 @@ def _mla_attention(lp, h, x, cfg: Config, pos_offset, index_aux):
         if selects:
             pvar.record("attn_dsa_layers")
             o = _dsa_core(q, k, v, index, cfg, index_aux)
-        else:  # the blockwise kernel wants one width for q, k and v
-            attend = att.attention if v.shape[-1] == q.shape[-1] \
-                else att.mha
-            o = attend(q, k, v, causal=True)
+        else:
+            o = att.attention(q, k, v, causal=True)
     with jax.named_scope("attn_proj"), jax.named_scope("mla_o"):
         return _residual(h, o.reshape(b, t, -1) @ lp["wo"].astype(cfg.dtype),
                          lp["ln1_post"] if cfg.post_norm else None, cfg,
@@ -896,27 +959,50 @@ def remat_spared(cfg: Config, b: int, t: int, is_moe: bool) -> Dict[str, int]:
     return ops
 
 
+#: the tower's layer kind beside the decoder's two (False: a dense
+#: layer, True: a MoE layer), models/vision.py's VIT
+VIT = "vit"
+
+
 def _application_kinds(cfg: Config):
     """Per layer application of a step, in order: is it a MoE layer's
     (the trunk's layers, pass after pass, then the multi-token-prediction
-    modules)."""
-    return [_is_moe(cfg, i) for i in range(cfg.n_layers)] * cfg.loops \
+    modules), or `VIT`: a block of the vision tower, which run first."""
+    return [VIT] * (cfg.vision.n_layers if cfg.vision is not None else 0) \
+        + [_is_moe(cfg, i) for i in range(cfg.n_layers)] * cfg.loops \
         + [_is_moe(cfg, cfg.n_layers)] * cfg.mtp_layers
 
 
-def remat_order(cfg: Config, b: int, t: int):
+def _kind_costs(cfg: Config, b: int, t: int, patches: int, kind):
+    """(`remat_sizes`, `remat_spared`, the bytes of its input) of one
+    application of a layer of `kind`: a decoder layer's over [b, t]
+    tokens, a tower block's over `patches` rows."""
+    it = jnp.dtype(cfg.dtype).itemsize
+    if kind == VIT:
+        from ompi_tpu.models import vision
+
+        return (vision.remat_sizes(cfg.vision, patches, it),
+                vision.remat_spared(cfg.vision, patches),
+                patches * cfg.vision.d_model * it)
+    return (remat_sizes(cfg, b, t, kind), remat_spared(cfg, b, t, kind),
+            b * t * cfg.d_model * it)
+
+
+def remat_order(cfg: Config, b: int, t: int, patches: int = 0):
     """[(name, bytes all the step's applications hold under it)], the
     dearest first: by the operations a name spares per byte it holds
     (over a product's result that is 2 x the contracted width / the
     item size: 16,384 wide, GLM-5's attention output projection stands
     first; 2,048 wide, Ouro's stands behind its attention and its FFN's
-    output), of equals the smaller first."""
+    output), of equals the smaller first. A name is one entry whatever
+    kinds of layer make it: a tower's blocks (over `patches` rows) and
+    the decoder's layers keep or drop it together."""
     kinds = _application_kinds(cfg)
-    per = {moe: (remat_sizes(cfg, b, t, moe), remat_spared(cfg, b, t, moe))
-           for moe in set(kinds)}
+    per = {kind: _kind_costs(cfg, b, t, patches, kind)[:2]
+           for kind in set(kinds)}
     held, spared = {}, {}
-    for moe in kinds:
-        sizes, ops = per[moe]
+    for kind in kinds:
+        sizes, ops = per[kind]
         for name, size in sizes.items():
             held[name] = held.get(name, 0) + size
             spared[name] = spared.get(name, 0) + ops[name]
@@ -924,32 +1010,34 @@ def remat_order(cfg: Config, b: int, t: int):
                   key=lambda kv: (-spared[kv[0]] / kv[1], kv[1], kv[0]))
 
 
-def whole_step_peak(cfg: Config, b: int, t: int, param_bytes: int) -> int:
+def whole_step_peak(cfg: Config, b: int, t: int, param_bytes: int,
+                    patches: int = 0) -> int:
     """The bytes a train step (`make_train_step`) is reckoned to hold
     at its peak with every layer application recomputed from its input
     alone, term by term from the program: the parameters; their
     gradients (all of them where the layers run more than once and a
     leaf's gradient is a sum over the passes, else one application's
     share: the update takes each as it appears); an input per
-    application; ONE exit's float32 logits, their exponentials and
-    their cotangent (`_exit_terms`, `_token_nll`); the values one
-    application's backward pass makes again and a cotangent for
-    each."""
-    it = jnp.dtype(cfg.dtype).itemsize
+    application (a tower block's: the packed row of `patches`); ONE
+    exit's float32 logits, their exponentials and their cotangent
+    (`_exit_terms`, `_token_nll`); the values one application's
+    backward pass makes again and a cotangent for each."""
     kinds = _application_kinds(cfg)
     grads = param_bytes if cfg.loops > 1 \
         else param_bytes // max(len(kinds), 1)
-    again = max(sum(remat_sizes(cfg, b, t, moe).values())
-                for moe in set(kinds))
-    return (param_bytes + grads + len(kinds) * b * t * cfg.d_model * it
+    per = {kind: _kind_costs(cfg, b, t, patches, kind)
+           for kind in set(kinds)}
+    again = max(sum(sizes.values()) for sizes, _, _ in per.values())
+    return (param_bytes + grads + sum(per[kind][2] for kind in kinds)
             + 3 * b * t * cfg.vocab * 4 + 2 * again)
 
 
 def remat_keep(cfg: Config, b: int, t: int, param_bytes: int,
-               limit: Optional[int]) -> Tuple[str, ...]:
+               limit: Optional[int], patches: int = 0) -> Tuple[str, ...]:
     """The rule that says what a recomputed layer application keeps
     for its backward pass, made of what the trace can observe: the
-    tokens' shape, the config's widths and depth, the bytes of the
+    tokens' shape (and the packed row's patches where the config has a
+    tower), the config's widths and depth, the bytes of the
     parameters and the device's memory limit. It starts from
     `whole_step_peak`, walks the names in `remat_order`, adding what
     all the applications hold under a name, and stops before the first
@@ -958,9 +1046,9 @@ def remat_keep(cfg: Config, b: int, t: int, param_bytes: int,
     application recomputed whole — the parent's program."""
     if not limit:
         return ()
-    peak = whole_step_peak(cfg, b, t, param_bytes)
+    peak = whole_step_peak(cfg, b, t, param_bytes, patches)
     keep = []
-    for name, held in remat_order(cfg, b, t):
+    for name, held in remat_order(cfg, b, t, patches):
         if peak + held > REMAT_SHARE * limit:
             break
         keep.append(name)
@@ -975,14 +1063,22 @@ def _memory_limit() -> Optional[int]:
     return stats.get("bytes_limit")
 
 
-def _remat_names(params, tokens, cfg: Config) -> Tuple[str, ...]:
+def _ids(batch):
+    """A batch's token ids [B, T]: the batch itself, or its "tokens"
+    leaf where it is a dict (a config with a tower: models/vision.py
+    says what lies beside them)."""
+    return batch["tokens"] if isinstance(batch, dict) else batch
+
+
+def _remat_names(params, batch, cfg: Config) -> Tuple[str, ...]:
     """`remat_keep` on what this trace has."""
     if not cfg.remat:
         return ()
+    patches = batch["patches"].shape[0] if isinstance(batch, dict) else 0
     return remat_keep(
-        cfg, *tokens.shape,
+        cfg, *_ids(batch).shape,
         sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params)),
-        _memory_limit())
+        _memory_limit(), patches)
 
 
 def _recomputed_layer(cfg: Config, ax: Axes, is_moe: bool,
@@ -1084,21 +1180,49 @@ def _run_layer(lp, h, cfg: Config, ax: Axes, is_moe: bool, pos_offset,
     return out
 
 
-def _trunk(params, tokens, cfg: Config, ax: Axes, aux=None, index_aux=None,
+def _vision_rows(params, batch, cfg: Config, keep: Tuple[str, ...]):
+    """The tower's merged rows for the batch's packed images
+    (models/vision.py), its blocks recomputed as the decoder's layers
+    are and counted as `_run_layer` counts those."""
+    from ompi_tpu.models import vision
+
+    vc = cfg.vision
+    if cfg.remat:
+        sizes = vision.remat_sizes(vc, batch["patches"].shape[0],
+                                   jnp.dtype(cfg.dtype).itemsize)
+        pvar.record("remat_kept_applications" if keep
+                    else "remat_whole_applications", vc.n_layers)
+        pvar.record("remat_kept_bytes", vc.n_layers * sum(
+            sizes.get(name, 0) for name in keep))
+    return vision.tower(params["vision"], batch, vc, cfg.dtype, cfg.remat,
+                        keep)
+
+
+def _trunk(params, batch, cfg: Config, ax: Axes, aux=None, index_aux=None,
            exits=None):
     """Embedding and layers on local shards: tokens [B_local, T_local]
-    -> the residual stream after the last layer, and the global
-    position of the shard's first token. Where the config runs the
+    (or, for a config with a vision tower, the dict that holds them
+    and the packed images: the tower's merged rows replace the
+    embedding's at the image positions) -> the residual stream after
+    the last layer, and the global position of the shard's first
+    token. Where the config runs the
     layers more than once, every pass but the last ends in the final
     norm and the next starts from that normed state, which `exits`, a
     list, receives."""
     dt = cfg.dtype
+    tokens = _ids(batch)
     b, t = tokens.shape
+    if (cfg.vision is not None) != isinstance(batch, dict):
+        raise ValueError(
+            "a config with a vision tower (Config.vision) takes a batch "
+            "that is a dict (models/vision.py: tokens and the packed "
+            "images), every other config the token ids alone")
     # global sequence offset of this sp shard
     if ax.sp:
         t_off = lax.axis_index(ax.sp) * t
     else:
         t_off = 0
+    keep = _remat_names(params, batch, cfg)
     with jax.named_scope("embed"):
         h = params["embed"].astype(dt)[tokens]
         if cfg.pos == "learned":
@@ -1106,8 +1230,14 @@ def _trunk(params, tokens, cfg: Config, ax: Axes, aux=None, index_aux=None,
                 params["pos"], t_off, t, axis=0) \
                 if ax.sp else params["pos"][:t]
             h = h + pos.astype(dt)[None]
+        if cfg.vision is not None:
+            from ompi_tpu.models import vision
 
-    recomputed = _Recomputed(cfg, ax, _remat_names(params, tokens, cfg))
+            _check_supported(cfg, ax, False, t_off)
+            h = vision.place(h, _vision_rows(params, batch, cfg, keep),
+                             batch["image_positions"])
+
+    recomputed = _Recomputed(cfg, ax, keep)
     for s in range(cfg.loops):
         with jax.named_scope(f"loop_{s}") if cfg.loops > 1 \
                 else contextlib.nullcontext():
@@ -1154,7 +1284,8 @@ def _head(params, h, cfg: Config):
 def forward_local(params, tokens, cfg: Config, ax: Axes, aux=None,
                   index_aux=None):
     """Forward pass on local shards (inside shard_map when any axis is
-    set). tokens: [B_local, T_local] int32 -> logits [B_local, T_local,
+    set). tokens: [B_local, T_local] int32 (or the dict `_trunk`
+    takes) -> logits [B_local, T_local,
     vocab] float32. `aux`, a list, receives each MoE layer's
     (load-balancing loss, z-loss, routing); `index_aux` each selecting
     layer's (indexer loss, selection)."""
@@ -1339,6 +1470,7 @@ def route_counts(params, tokens, cfg: Config):
     this batch whose held assignments exceed the rows the layer is
     bounded at (`ops/moe.held_rows_bound`), which take its full path."""
     counts = _route_probe(params, tokens, cfg)[0]
+    tokens = _ids(tokens)
     routed = int(counts.sum())
     pvar.record("moe_assignments", routed)
     pvar.record("moe_dropped_assignments",
@@ -1382,6 +1514,19 @@ def dsa_selection(params, tokens, cfg: Config):
     pvar.record("dsa_selected_pairs", int(keep.sum()))
     pvar.record("dsa_causal_pairs", keep.shape[0] * b * t * (t + 1) // 2)
     return keep
+
+
+@_probe("ompi_vision_rows")
+def _vision_probe(params, batch, cfg: Config):
+    return _vision_rows(params, batch, cfg, ())
+
+
+def vision_rows(params, batch, cfg: Config):
+    """[image positions, d_model]: the rows the vision tower and its
+    projector make of a batch's packed images, as the step's forward
+    pass places them in the sequence. A probe the host calls outside
+    any timed window."""
+    return _vision_probe(params, batch, cfg=cfg)
 
 
 @_probe("ompi_exit_stats")

@@ -1,14 +1,25 @@
 """Attention on one device.
 
-:func:`attention` is the model's one way in. On the TPU, causal
-self-attention over a whole sequence whose shapes the rule
-:func:`blockwise_tile` accepts runs blockwise (:func:`blockwise_mha`:
-the library's splash-attention kernels, tiles in VMEM, an online
-softmax, masked tiles skipped); everything else — the CPU, odd shapes,
-blocks of a longer sequence — runs :func:`mha`, the full-softmax
-reference that is also the oracle for the blockwise kernel and for the
-distributed ring attention (:mod:`ompi_tpu.ops.ring_attention`, which
-builds on :func:`online_softmax_block`). Shapes follow
+:func:`attention` is the model's one way in. It takes the causal mask,
+a SEGMENT mask (``segments``: an id per position, a pair attends only
+inside one id — images packed back to back in one row of patches, each
+attending both ways inside itself), or both. On the TPU, self-attention
+over a whole sequence whose shapes the rule :func:`blockwise_tile`
+accepts runs blockwise (:func:`blockwise_mha`: the library's
+splash-attention kernels, tiles in VMEM, an online softmax; tiles above
+the diagonal are never visited, and a tile that lies wholly between two
+segments is skipped — the segment ids are DATA, so the tiles to visit
+are a small table the kernels read, not a constant of the trace). The
+kernels work lanes of 128: heads of any other width (a vision tower's
+72, latent attention's 192 against values of 128) are padded with
+zeros up to the next 128, which changes no score and no output.
+Everything else — the CPU, a length no tile divides, blocks of a
+longer sequence, attention that is neither causal nor segmented — runs
+:func:`mha`, the full-softmax reference (pvar
+``attn_reference_layers``) that takes the same masks and is the oracle
+for every kernel path and for the distributed ring attention
+(:mod:`ompi_tpu.ops.ring_attention`, which builds on
+:func:`online_softmax_block`). Shapes follow
 [batch, seq, heads, head_dim] throughout.
 
 Learned sparse attention (DeepSeek-V3.2's DSA, as GLM-5 — the third
@@ -66,12 +77,14 @@ DSA_PROBS = "dsa_probs"
 
 
 def mha(q, k, v, causal: bool = True, scale: Optional[float] = None,
-        q_offset: int = 0, k_offset: int = 0):
+        q_offset: int = 0, k_offset: int = 0, segments=None):
     """Multi-head attention, full-softmax reference.
 
-    q: [B, Tq, H, D], k/v: [B, Tk, H, D] -> [B, Tq, H, D].
-    q_offset/k_offset give the global positions of the local blocks
-    (used when blocks are slices of a longer sequence).
+    q: [B, Tq, H, D], k: [B, Tk, H, D], v: [B, Tk, H, Dv] ->
+    [B, Tq, H, Dv]. q_offset/k_offset give the global positions of the
+    local blocks (used when blocks are slices of a longer sequence).
+    `segments`: [B, T] integers for self-attention (Tq == Tk): a query
+    sees the keys of its own id alone.
     """
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / jnp.sqrt(d)
@@ -82,6 +95,9 @@ def mha(q, k, v, causal: bool = True, scale: Optional[float] = None,
         kpos = k_offset + jnp.arange(k.shape[1])
         mask = qpos[:, None] >= kpos[None, :]
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
+    if segments is not None:
+        same = segments[:, :, None] == segments[:, None, :]
+        scores = jnp.where(same[:, None], scores, -jnp.inf)
     p = jnp.exp(scores - lax.stop_gradient(
         jnp.max(scores, axis=-1, keepdims=True)))
     p = jnp.where(jnp.isfinite(scores), p, 0.0)
@@ -102,20 +118,42 @@ _TILES = (1024, 512, 256)
 _KV_COMPUTE = 512
 
 
+#: The kernels' lanes: a head is padded with zeros to a multiple.
+LANES = 128
+
+
+def lanes(width: int) -> int:
+    """`width` rounded up to the kernels' lanes."""
+    return -(-width // LANES) * LANES
+
+
 def blockwise_tile(backend: str, t_q: int, t_k: int, head_dim: int,
-                   causal: bool = True, q_offset=0,
-                   k_offset=0) -> Optional[int]:
+                   causal: bool = True, q_offset=0, k_offset=0,
+                   segmented: bool = False) -> Optional[int]:
     """The rule that sends an attention to the blockwise kernel, made
     of what the caller can observe: the tile it runs with, or None
-    where it takes :func:`mha` — off the TPU, heads that are not
-    multiples of the 128 lanes, a length no tile divides, anything but
-    causal self-attention over one whole sequence (blocks at an offset
-    of a longer one are the ring's)."""
+    where it takes :func:`mha` — off the TPU, a length no tile divides,
+    anything but self-attention over one whole sequence (blocks at an
+    offset of a longer one are the ring's), or a mask that is not
+    EITHER causal OR `segmented` (neither: plain two-way attention;
+    both: packed causal documents, ROADMAP Queue 2a). Any `head_dim`
+    passes: the kernel pads it to its lanes."""
     whole = all(isinstance(o, int) and o == 0 for o in (q_offset, k_offset))
-    if (backend != "tpu" or not causal or not whole or t_q != t_k
-            or head_dim % 128):
+    if (backend != "tpu" or causal == segmented or not whole
+            or t_q != t_k or head_dim < 1):
         return None
     return next((b for b in _TILES if t_q % b == 0), None)
+
+
+def _block_sizes(tile: int):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+
+    compute = min(tile, _KV_COMPUTE)
+    return sk.BlockSizes(
+        block_q=tile, block_kv=tile, block_kv_compute=compute,
+        block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=compute,
+        use_fused_bwd_kernel=True)
 
 
 @functools.lru_cache(maxsize=None)
@@ -129,58 +167,121 @@ def _splash_kernel(t: int, heads: int, tile: int, interpret: bool):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
 
-    compute = min(tile, _KV_COMPUTE)
-    sizes = sk.BlockSizes(
-        block_q=tile, block_kv=tile, block_kv_compute=compute,
-        block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=compute,
-        use_fused_bwd_kernel=True)
     mask = sm.MultiHeadMask([sm.CausalMask((t, t))] * heads)
     # the kernel keeps its block tables as arrays: constants of every
     # program that uses it, not values of the trace that asked first
     with jax.ensure_compile_time_eval():
         return sk.make_splash_mha_single_device(
-            mask, block_sizes=sizes, interpret=interpret,
+            mask, block_sizes=_block_sizes(tile), interpret=interpret,
             residual_checkpoint_name=ATTN_OUT)
 
 
+def segment_tiles(ids, tile: int):
+    """bool [T / tile, T / tile]: the (query tile, key tile) pairs that
+    may hold a pair of one segment: their ranges of ids overlap. `ids`
+    [T] is data: so is the table. Exact for ids that never decrease
+    along the row (images back to back); for any other ids a superset,
+    which costs time and changes nothing: inside a visited tile the
+    kernels compare the ids pair by pair."""
+    blocks = ids.reshape(-1, tile)
+    lo, hi = blocks.min(1), blocks.max(1)
+    return (lo[:, None] <= hi[None, :]) & (lo[None, :] <= hi[:, None])
+
+
+def _segment_kernel(ids, tile: int, interpret: bool):
+    """The splash-attention kernels for ONE sequence whose mask is its
+    segment ids [T], attended both ways: the same forward and fused
+    backward kernels as `_splash_kernel`'s, given their table
+    of tiles as data (`segment_tiles`: a tile is visited whole or not
+    at all, and a tile that is not visited is neither fetched nor
+    computed) and the ids, which they compare inside each visited tile
+    (`SegmentIds`). No [T, T] mask exists anywhere."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask_info as mi)
+
+    visit = segment_tiles(ids, tile)[None]  # one table for all heads
+    n = visit.shape[-1]
+    block = jnp.where(visit, 2, 0).astype(jnp.int8)
+
+    def info(next_block):
+        return mi.MaskInfo(
+            data_next=jnp.where(visit, next_block, 0).astype(jnp.int32),
+            mask_next=None, block_mask=block, partial_mask_blocks=None,
+            q_sequence=None, is_dynamic_mask=True)
+
+    kernel = sk.SplashAttentionKernel(
+        info(jnp.arange(n, dtype=jnp.int32)[None, None, :]), None,
+        info(jnp.arange(n, dtype=jnp.int32)[None, :, None]),
+        block_sizes=_block_sizes(tile), is_mqa=False, save_residuals=False,
+        mask_value=sk.DEFAULT_MASK_VALUE, attn_logits_soft_cap=None,
+        residual_checkpoint_name=ATTN_OUT, mask_function=None,
+        interpret=interpret)
+    return functools.partial(
+        kernel, segment_ids=sk.SegmentIds(q=ids, kv=ids))
+
+
+def _pad_heads(a, width: int):
+    """[..., D] -> [..., width] with zeros."""
+    short = width - a.shape[-1]
+    return a if not short else jnp.pad(
+        a, [(0, 0)] * (a.ndim - 1) + [(0, short)])
+
+
 def blockwise_mha(q, k, v, tile: int, scale: Optional[float] = None,
-                  interpret: bool = False):
-    """Causal self-attention, :func:`mha`'s mathematics (exact softmax
-    over the whole causal row, float32 scores, statistics and
+                  interpret: bool = False, segments=None):
+    """Self-attention under the causal mask, or — where `segments`
+    [B, T] is given — both ways under that segment mask and no causal
+    one: :func:`mha`'s mathematics (exact softmax over the whole
+    unmasked row, float32 scores, statistics and
     accumulation) computed tile by tile with an online softmax: the
     [B, H, T, T] scores and probabilities never reach HBM, the forward
     saves the per-row log-sum-exp and the backward recomputes each
-    tile's scores from it. q, k, v: [B, T, H, D] -> [B, T, H, D].
+    tile's scores from it. q, k: [B, T, H, D], v: [B, T, H, Dv] ->
+    [B, T, H, Dv]; D and Dv are padded with zeros to the kernels' lanes
+    here and the padding cut from the result.
 
     The kernel has no scale of its own, so q carries it: a caller that
     can fold 1/sqrt(D) in where q is still float32 passes scale=1.0 and
     nothing is rounded twice."""
     _, t, h, d = q.shape
+    dv = v.shape[-1]
     scale = scale if scale is not None else 1.0 / float(d) ** 0.5
     if scale != 1.0:
         q = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    kernel = _splash_kernel(t, h, tile, interpret)
-    o = jax.vmap(kernel)(*checkpoint_name(
-        tuple(a.transpose(0, 2, 1, 3) for a in (q, k, v)), QKV))
-    return o.transpose(0, 2, 1, 3)
+    q, k, v = (_pad_heads(a, lanes(a.shape[-1])) for a in (q, k, v))
+    qkv = checkpoint_name(
+        tuple(a.transpose(0, 2, 1, 3) for a in (q, k, v)), QKV)
+    if segments is None:
+        o = jax.vmap(_splash_kernel(t, h, tile, interpret))(*qkv)
+    else:  # the tables are a sequence's own: one sequence at a time
+        o = jnp.stack([
+            _segment_kernel(segments[i], tile, interpret)(
+                *(a[i] for a in qkv)) for i in range(q.shape[0])])
+    return o.transpose(0, 2, 1, 3)[..., :dv]
 
 
 def attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
-              q_offset=0, k_offset=0):
+              q_offset=0, k_offset=0, segments=None):
     """The model's one way to attention: the blockwise kernel where
     :func:`blockwise_tile` gives a tile, :func:`mha` everywhere else.
-    Inside ``jit`` the choice is static; it is counted once per traced
-    attention (pvars ``attn_blockwise_layers`` /
-    ``attn_reference_layers``)."""
+    `segments` ([B, T] integers, data) restricts every query to the
+    keys of its own id. Inside ``jit`` the choice is static; it is
+    counted once per traced attention (pvars ``attn_blockwise_layers``
+    / ``attn_reference_layers``, and ``attn_segment_layers`` for those
+    that took a segment mask, whichever way they went)."""
     tile = blockwise_tile(jax.default_backend(), q.shape[1], k.shape[1],
-                          q.shape[-1], causal, q_offset, k_offset)
+                          q.shape[-1], causal, q_offset, k_offset,
+                          segments is not None)
+    if segments is not None:
+        pvar.record("attn_segment_layers")
     if tile is None:
         pvar.record("attn_reference_layers")
         return checkpoint_name(
             mha(*checkpoint_name((q, k, v), QKV), causal=causal, scale=scale,
-                q_offset=q_offset, k_offset=k_offset), ATTN_OUT)
+                q_offset=q_offset, k_offset=k_offset, segments=segments),
+            ATTN_OUT)
     pvar.record("attn_blockwise_layers")
-    return blockwise_mha(q, k, v, tile, scale=scale)
+    return blockwise_mha(q, k, v, tile, scale=scale, segments=segments)
 
 
 # -- learned sparse attention: indexer scores, selection, attention over it ----

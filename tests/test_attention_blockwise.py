@@ -95,8 +95,8 @@ def test_rule_gives_the_largest_tile_that_divides_t(t, tile):
 @pytest.mark.parametrize("why,args,kwargs", [
     ("the CPU", ("cpu", 1024, 1024, 128), {}),
     ("a GPU", ("gpu", 1024, 1024, 128), {}),
-    ("heads of 64", ("tpu", 1024, 1024, 64), {}),
-    ("heads of 192", ("tpu", 1024, 1024, 192), {}),
+    ("causal AND segmented", ("tpu", 1024, 1024, 128), {"segmented": True}),
+    ("no head", ("tpu", 1024, 1024, 0), {}),
     ("no tile divides 384", ("tpu", 384, 384, 128), {}),
     ("no tile divides 128", ("tpu", 128, 128, 128), {}),
     ("no tile divides 1000", ("tpu", 1000, 1000, 128), {}),
@@ -111,6 +111,17 @@ def test_rule_gives_the_largest_tile_that_divides_t(t, tile):
 ])
 def test_rule_refuses(why, args, kwargs):
     assert att.blockwise_tile(*args, **kwargs) is None, why
+
+
+@pytest.mark.parametrize("width,padded", [(64, 128), (72, 128), (128, 128),
+                                          (192, 256), (256, 256)])
+def test_rule_takes_any_head_width_and_the_kernel_pads_it(width, padded):
+    """Since the fifth model (heads of 72, of 192 against 128): the
+    kernels' lanes are the kernel's affair, not the rule's."""
+    assert att.blockwise_tile("tpu", 1024, 1024, width) == 1024
+    assert att.blockwise_tile("tpu", 1024, 1024, width, causal=False,
+                              segmented=True) == 1024
+    assert att.lanes(width) == padded
 
 
 def test_the_cpu_takes_the_reference_and_counts_it(pvar_clean):
@@ -269,4 +280,37 @@ def test_kernels_compile_for_the_chip_without_a_score_buffer(one_chip, cell,
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text, cell
     assert f"[{b},{h},{t},{t}]" not in text, cell
     scores_bytes = b * h * t * t * 4  # float32, as att.mha holds them
+    assert compiled.memory_analysis().temp_size_in_bytes < scores_bytes / 2
+
+
+@pytest.mark.parametrize("cell,t,h,d,dv,segmented", [
+    ("kimivl-train-t4096 tower", 12288, 16, 72, 72, True),
+    ("kimivl-train-t4096 decoder", 4096, 16, 192, 128, False)])
+def test_padded_and_segmented_kernels_compile_for_the_chip(
+        one_chip, cell, t, h, d, dv, segmented):
+    """The fifth model's two attentions at the cell's shapes: heads of
+    72 (the tower's, under the segment mask whose tile table is data)
+    and of 192 against values of 128 (the decoder's, causal), padded
+    to the kernels' lanes — the chip's compiler takes both and nothing
+    of the size of the [H, T, T] scores is left."""
+    tile = att.blockwise_tile("tpu", t, t, d, not segmented,
+                              segmented=segmented)
+    assert tile == 1024
+
+    def loss(q, k, v, w, ids):
+        o = att.blockwise_mha(q, k, v, tile,
+                              segments=ids if segmented else None)
+        return jnp.sum(o.astype(jnp.float32) * w)
+
+    def arg(width, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((1, t, h, width), dtype,
+                                    sharding=one_chip)
+
+    ids = jax.ShapeDtypeStruct((1, t), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(d), arg(d), arg(dv), arg(dv, jnp.float32), ids).compile()
+    text = compiled.as_text()
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text, cell
+    assert f"[{h},{t},{t}]" not in text and f"[1,{h},{t},{t}]" not in text
+    scores_bytes = h * t * t * 4  # float32, as att.mha holds them
     assert compiled.memory_analysis().temp_size_in_bytes < scores_bytes / 2
