@@ -51,6 +51,10 @@ WELL_KNOWN = (
     # the same, once per TRACED attention that took a segment mask
     # (whichever of the two ways it went)
     "attn_segment_layers",
+    # the same, once per TRACED attention under a segment mask that
+    # took the repo's own kernels (ops/segment_attention.py); it counts
+    # among attn_blockwise_layers too
+    "attn_segment_kernel_layers",
     # ops/moe.sorted_moe_ffn, once per TRACED MoE layer: its grouped
     # matmuls are the Pallas kernels, or lax.ragged_dot
     "moe_grouped_kernel_layers", "moe_ragged_dot_layers",

@@ -36,8 +36,8 @@ of `head_dim`, 2-D RoPE on q and k (a head's complex pairs turned
 alternately by the patch's column and its row, `head_dim` / 4
 frequencies each, theta ** (-4 j / head_dim)), attention both ways over
 the image's own patches and no others (``ops.attention.attention`` with
-the image ids as its segment mask: blockwise on the TPU, tiles between
-two images skipped), ``h += o Wo + b``, ``h += gelu_tanh(LN(h) W1 + b)
+the image ids as its segment mask: the repo's segment kernels on the
+TPU, tiles between two images skipped), ``h += o Wo + b``, ``h += gelu_tanh(LN(h) W1 + b)
 W2 + b`` —; a final LayerNorm; the merge; the projector
 ``gelu(LN(x) per patch, merged, W1 + b) W2 + b`` into the decoder's
 width. Biases everywhere, as the source has them.
@@ -364,8 +364,11 @@ def place(h, rows, image_positions):
 
 def remat_sizes(vc: VisionConfig, patches: int, itemsize: int) -> Dict[str, int]:
     """Bytes ONE application of a tower block holds under each name,
-    of `patches` rows: q, k, v and the output as the blockwise kernel
-    holds them, heads padded to its lanes."""
+    of `patches` rows: q, k, v and the output as the segment kernels
+    hold them — ``[heads, patches, head_dim]``, where the device's
+    tiled layout gives every row of a head whole lanes (72 lie in 128)
+    though no copy pads them — and the per-row log-sum-exp as
+    ``[heads, patches]`` float32."""
     from ompi_tpu.models import transformer as tfm
 
     n, wide = patches, att.lanes(vc.head_dim)

@@ -4,20 +4,28 @@
 a SEGMENT mask (``segments``: an id per position, a pair attends only
 inside one id — images packed back to back in one row of patches, each
 attending both ways inside itself), or both. On the TPU, self-attention
-over a whole sequence whose shapes the rule :func:`blockwise_tile`
-accepts runs blockwise (:func:`blockwise_mha`: the library's
-splash-attention kernels, tiles in VMEM, an online softmax; tiles above
-the diagonal are never visited, and a tile that lies wholly between two
-segments is skipped — the segment ids are DATA, so the tiles to visit
-are a small table the kernels read, not a constant of the trace). The
-kernels work lanes of 128: heads of any other width (a vision tower's
-72, latent attention's 192 against values of 128) are padded with
-zeros up to the next 128, which changes no score and no output.
-Everything else — the CPU, a length no tile divides, blocks of a
-longer sequence, attention that is neither causal nor segmented — runs
-:func:`mha`, the full-softmax reference (pvar
-``attn_reference_layers``) that takes the same masks and is the oracle
-for every kernel path and for the distributed ring attention
+over a whole sequence runs blockwise (:func:`blockwise_mha`: tiles in
+VMEM, an online softmax, no score in HBM) wherever one of two rules
+accepts its shapes. Under the causal mask, :func:`blockwise_tile`: the
+library's splash-attention kernels; tiles above the diagonal are never
+visited; the kernels work lanes of 128, so heads of any other width
+(latent attention's 192 against values of 128) are padded with zeros
+up to the next 128, which changes no score and no output. Under a
+segment mask, :func:`segment_tile`: two kernels of the repo's own
+(ops/segment_attention.py). The segment ids are DATA, so the (query
+tile, key tile) pairs to visit are a small table made in the trace
+(:func:`segment_tiles`) that the kernels walk — visited pairs first,
+several heads a grid step, a pair that lies wholly between two
+segments neither fetched nor computed, the ids compared only in a pair
+that straddles a segment's edge (:func:`segment_interior` says which
+do not) — and another packing of the same length runs the same
+executable. Those kernels take heads as wide as they are (a tower's
+72): the layout of VMEM pads them to its lanes, no copy in HBM does,
+and the output is written at its own width. Everything else — the CPU, a length no tile divides,
+blocks of a longer sequence, attention that is neither causal nor
+segmented, or both — runs :func:`mha`, the full-softmax reference
+(pvar ``attn_reference_layers``) that takes the same masks and is the
+oracle for every kernel path and for the distributed ring attention
 (:mod:`ompi_tpu.ops.ring_attention`, which builds on
 :func:`online_softmax_block`). Shapes follow
 [batch, seq, heads, head_dim] throughout.
@@ -49,12 +57,13 @@ What a backward pass reads of an attention carries a NAME
 (``jax.ad_checkpoint.checkpoint_name``: an identity wherever no
 ``jax.checkpoint`` policy asks for it, it lowers to its operand):
 :data:`QKV` — q, k and v as the path taken reads them (the kernels'
-head-major layout on the TPU) — and :data:`ATTN_OUT` — the output and,
+head-major layouts on the TPU) — and :data:`ATTN_OUT` — the output and,
 from a kernel, the per-row log-sum-exp, named inside the kernels' own
 forward rules, so that a policy that keeps them spares the backward
 pass the forward kernel; :func:`dsa_attend` names the heads' summed
 probabilities :data:`DSA_PROBS` too. ``models/transformer.py`` decides
-which of them a recomputed layer keeps.
+which of them a recomputed layer keeps. The segment kernels hold
+both as ``[heads, T, width]``, the log-sum-exp as ``[heads, T]``.
 """
 
 from __future__ import annotations
@@ -127,19 +136,22 @@ def lanes(width: int) -> int:
     return -(-width // LANES) * LANES
 
 
+def _whole(q_offset, k_offset) -> bool:
+    return all(isinstance(o, int) and o == 0 for o in (q_offset, k_offset))
+
+
 def blockwise_tile(backend: str, t_q: int, t_k: int, head_dim: int,
-                   causal: bool = True, q_offset=0, k_offset=0,
-                   segmented: bool = False) -> Optional[int]:
-    """The rule that sends an attention to the blockwise kernel, made
-    of what the caller can observe: the tile it runs with, or None
-    where it takes :func:`mha` — off the TPU, a length no tile divides,
-    anything but self-attention over one whole sequence (blocks at an
-    offset of a longer one are the ring's), or a mask that is not
-    EITHER causal OR `segmented` (neither: plain two-way attention;
-    both: packed causal documents, ROADMAP Queue 2a). Any `head_dim`
+                   causal: bool = True, q_offset=0,
+                   k_offset=0) -> Optional[int]:
+    """The rule that sends a CAUSAL attention to the library's blockwise
+    kernels, made of what the caller can observe: the tile it runs
+    with, or None where it takes :func:`mha` — off the TPU, a length no
+    tile divides, anything but self-attention over one whole sequence
+    (blocks at an offset of a longer one are the ring's), or no causal
+    mask (a segment mask alone is :func:`segment_tile`'s; both at once,
+    packed causal documents, is ROADMAP Queue 2a). Any `head_dim`
     passes: the kernel pads it to its lanes."""
-    whole = all(isinstance(o, int) and o == 0 for o in (q_offset, k_offset))
-    if (backend != "tpu" or causal == segmented or not whole
+    if (backend != "tpu" or not causal or not _whole(q_offset, k_offset)
             or t_q != t_k or head_dim < 1):
         return None
     return next((b for b in _TILES if t_q % b == 0), None)
@@ -176,48 +188,121 @@ def _splash_kernel(t: int, heads: int, tile: int, interpret: bool):
             residual_checkpoint_name=ATTN_OUT)
 
 
-def segment_tiles(ids, tile: int):
-    """bool [T / tile, T / tile]: the (query tile, key tile) pairs that
-    may hold a pair of one segment: their ranges of ids overlap. `ids`
-    [T] is data: so is the table. Exact for ids that never decrease
-    along the row (images back to back); for any other ids a superset,
-    which costs time and changes nothing: inside a visited tile the
-    kernels compare the ids pair by pair."""
-    blocks = ids.reshape(-1, tile)
-    lo, hi = blocks.min(1), blocks.max(1)
-    return (lo[:, None] <= hi[None, :]) & (lo[None, :] <= hi[:, None])
+def _tile_ids(ids, tile: int, keys: Optional[int]):
+    """(smallest, largest) id of each query tile of `ids` [T] as
+    columns, of each key tile (`keys`, or `tile`) as rows."""
+    def ends(size):
+        blocks = ids.reshape(-1, size)
+        return blocks.min(1), blocks.max(1)
+
+    (lo_q, hi_q), (lo_k, hi_k) = ends(tile), ends(keys or tile)
+    return lo_q[:, None], hi_q[:, None], lo_k[None, :], hi_k[None, :]
 
 
-def _segment_kernel(ids, tile: int, interpret: bool):
-    """The splash-attention kernels for ONE sequence whose mask is its
-    segment ids [T], attended both ways: the same forward and fused
-    backward kernels as `_splash_kernel`'s, given their table
-    of tiles as data (`segment_tiles`: a tile is visited whole or not
-    at all, and a tile that is not visited is neither fetched nor
-    computed) and the ids, which they compare inside each visited tile
-    (`SegmentIds`). No [T, T] mask exists anywhere."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk, splash_attention_mask_info as mi)
+def segment_tiles(ids, tile: int, keys: Optional[int] = None):
+    """bool [T / tile, T / keys]: the (query tile, key tile) pairs that
+    may hold a pair of one segment: their ranges of ids overlap (`keys`:
+    the key tile, `tile` where not given). `ids` [T] is data: so is the
+    table. Exact for ids that never decrease along the row (images back
+    to back); for any other ids a superset, which costs time and
+    changes nothing: inside a visited pair that is not
+    :func:`segment_interior` the kernels compare the ids pair by
+    pair."""
+    lo_q, hi_q, lo_k, hi_k = _tile_ids(ids, tile, keys)
+    return (lo_q <= hi_k) & (lo_k <= hi_q)
 
-    visit = segment_tiles(ids, tile)[None]  # one table for all heads
-    n = visit.shape[-1]
-    block = jnp.where(visit, 2, 0).astype(jnp.int8)
 
-    def info(next_block):
-        return mi.MaskInfo(
-            data_next=jnp.where(visit, next_block, 0).astype(jnp.int32),
-            mask_next=None, block_mask=block, partial_mask_blocks=None,
-            q_sequence=None, is_dynamic_mask=True)
+def segment_interior(ids, tile: int, keys: Optional[int] = None):
+    """bool, as :func:`segment_tiles`: the pairs whose two tiles hold
+    ONE id each, the same one — no pair inside is masked, whatever the
+    ids elsewhere."""
+    lo_q, hi_q, lo_k, hi_k = _tile_ids(ids, tile, keys)
+    return (lo_q == hi_q) & (lo_k == hi_k) & (lo_q == lo_k)
 
-    kernel = sk.SplashAttentionKernel(
-        info(jnp.arange(n, dtype=jnp.int32)[None, None, :]), None,
-        info(jnp.arange(n, dtype=jnp.int32)[None, :, None]),
-        block_sizes=_block_sizes(tile), is_mqa=False, save_residuals=False,
-        mask_value=sk.DEFAULT_MASK_VALUE, attn_logits_soft_cap=None,
-        residual_checkpoint_name=ATTN_OUT, mask_function=None,
-        interpret=interpret)
-    return functools.partial(
-        kernel, segment_ids=sk.SegmentIds(q=ids, kv=ids))
+
+#: The segment kernels' tiles (square: the largest that divides T) and
+#: the heads a grid step works: the largest group that divides the
+#: heads; the backward's is bounded besides by its dq, kept whole in
+#: VMEM as [heads, T, lanes] float32 beside the block it is written
+#: to, twice. Chosen on the chip (v5e, one tower block alone at T
+#: 12,288, 16 heads of 72, four images, forward + backward, the ops
+#: under `attn_core`, PERF.md section 6, PR 38): 10.15 ms at 1024 x
+#: 1024 with 8 heads a step and 4 in the backward (10.39 with 4 and 2,
+#: 10.52 with 2 and 2; 11.19-12.19 at 512 x 512, 10.94-11.01 at 1024 x
+#: 512 either way) where the library's kernels take 13.27.
+_SEG_TILES = (1024, 512, 256)
+_SEG_HEADS = (8, 4, 2, 1)
+_SEG_DQ_BYTES = 56 * 1024 * 1024
+
+
+def segment_tile(backend: str, t_q: int, t_k: int, heads: int, d_qk: int,
+                 d_v: int, itemsize: int = 2, causal: bool = False,
+                 q_offset=0, k_offset=0):
+    """The rule that sends an attention under a SEGMENT mask to the
+    repo's own kernels (ops/segment_attention.py), made of what the
+    call can observe: their tiles (a ``segment_attention.Tiles``), or
+    None — off the TPU, with the causal mask besides, anything but
+    self-attention over one whole sequence, a length no tile divides or
+    whose dq does not fit in VMEM for a single head. Any width passes:
+    a head lies in whole lanes of VMEM, not of HBM."""
+    if (backend != "tpu" or causal or not _whole(q_offset, k_offset)
+            or t_q != t_k or min(d_qk, d_v) < 1):
+        return None
+    tile = next((b for b in _SEG_TILES if t_q % b == 0), None)
+    return None if tile is None else _segment_groups(tile, t_q, heads, d_qk,
+                                                     itemsize)
+
+
+def _segment_groups(tile: int, t: int, heads: int, d_qk: int, itemsize: int):
+    """Square tiles of `tile` with the heads a step that fit, or None
+    where one head's dq does not."""
+    from ompi_tpu.ops import segment_attention as sg
+
+    dq = t * lanes(d_qk) * (4 + 2 * itemsize)  # scratch + the block, twice
+
+    def heads_a_step(fits):
+        return next((g for g in _SEG_HEADS if heads % g == 0 and fits(g)),
+                    None)
+
+    bwd = heads_a_step(lambda g: g * dq <= _SEG_DQ_BYTES)
+    return None if bwd is None else sg.Tiles(
+        tile, tile, heads_a_step(lambda g: True), bwd)
+
+
+@functools.lru_cache(maxsize=None)
+def _segment_kernels(tiles, interpret: bool):
+    """The segment kernels for one tile set as a function (q, k, v
+    [H, T, .], ids [T]) -> o [H, T, Dv], the fused backward kernel behind a
+    ``custom_vjp``. The tables of pairs are made from the ids in the
+    trace, once for each kernel (`segment_tiles` says which pairs are
+    visited, `segment_interior` which of them compare no ids). Imported
+    late, as `_dsa_kernels`."""
+    from ompi_tpu.ops import segment_attention as sg
+
+    on = dict(tiles=tiles, interpret=interpret)
+
+    def table(ids, by_key: bool):
+        return sg.pair_table(segment_tiles(ids, tiles.rows, tiles.keys),
+                             segment_interior(ids, tiles.rows, tiles.keys),
+                             by_key)
+
+    @jax.custom_vjp
+    def attend(q, k, v, ids):
+        return sg.forward(q, k, v, ids, table(ids, False), **on)[0]
+
+    def fwd(q, k, v, ids):
+        o, lse = sg.forward(q, k, v, ids, table(ids, False), **on)
+        o, lse = checkpoint_name((o, lse[:, 0]), ATTN_OUT)
+        return o, (q, k, v, ids, o, lse)
+
+    def bwd(res, do):
+        q, k, v, ids, o, lse = res
+        di = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+        return sg.backward(q, k, v, do, lse[:, None], di[:, None], ids,
+                           table(ids, True), **on) + (None,)
+
+    attend.defvjp(fwd, bwd)
+    return attend
 
 
 def _pad_heads(a, width: int):
@@ -227,7 +312,7 @@ def _pad_heads(a, width: int):
         a, [(0, 0)] * (a.ndim - 1) + [(0, short)])
 
 
-def blockwise_mha(q, k, v, tile: int, scale: Optional[float] = None,
+def blockwise_mha(q, k, v, tile, scale: Optional[float] = None,
                   interpret: bool = False, segments=None):
     """Self-attention under the causal mask, or — where `segments`
     [B, T] is given — both ways under that segment mask and no causal
@@ -237,43 +322,59 @@ def blockwise_mha(q, k, v, tile: int, scale: Optional[float] = None,
     [B, H, T, T] scores and probabilities never reach HBM, the forward
     saves the per-row log-sum-exp and the backward recomputes each
     tile's scores from it. q, k: [B, T, H, D], v: [B, T, H, Dv] ->
-    [B, T, H, Dv]; D and Dv are padded with zeros to the kernels' lanes
-    here and the padding cut from the result.
+    [B, T, H, Dv]. `tile`: what the rule gave — :func:`blockwise_tile`'s
+    int (the library's kernels: D and Dv are padded with zeros to their
+    lanes here and the padding cut from the result) or
+    :func:`segment_tile`'s tiles (the repo's kernels: heads as wide as
+    they are, nothing padded here; an int stands for square tiles of it
+    with the heads a step that fit).
 
-    The kernel has no scale of its own, so q carries it: a caller that
-    can fold 1/sqrt(D) in where q is still float32 passes scale=1.0 and
-    nothing is rounded twice."""
+    The kernels have no scale of their own, so q carries it: a caller
+    that can fold 1/sqrt(D) in where q is still float32 passes
+    scale=1.0 and nothing is rounded twice."""
     _, t, h, d = q.shape
     dv = v.shape[-1]
     scale = scale if scale is not None else 1.0 / float(d) ** 0.5
     if scale != 1.0:
         q = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    q, k, v = (_pad_heads(a, lanes(a.shape[-1])) for a in (q, k, v))
+    if segments is None:
+        q, k, v = (_pad_heads(a, lanes(a.shape[-1])) for a in (q, k, v))
+        qkv = checkpoint_name(
+            tuple(a.transpose(0, 2, 1, 3) for a in (q, k, v)), QKV)
+        o = jax.vmap(_splash_kernel(t, h, tile, interpret))(*qkv)
+        return o.transpose(0, 2, 1, 3)[..., :dv]
+    if isinstance(tile, int):
+        tile = _segment_groups(tile, t, h, d, q.dtype.itemsize)
     qkv = checkpoint_name(
         tuple(a.transpose(0, 2, 1, 3) for a in (q, k, v)), QKV)
-    if segments is None:
-        o = jax.vmap(_splash_kernel(t, h, tile, interpret))(*qkv)
-    else:  # the tables are a sequence's own: one sequence at a time
-        o = jnp.stack([
-            _segment_kernel(segments[i], tile, interpret)(
-                *(a[i] for a in qkv)) for i in range(q.shape[0])])
-    return o.transpose(0, 2, 1, 3)[..., :dv]
+    attend = _segment_kernels(tile, interpret)
+    # the tables are a sequence's own: one sequence at a time
+    o = jnp.stack([attend(*(a[i] for a in qkv), segments[i])
+                   for i in range(q.shape[0])])
+    return o.transpose(0, 2, 1, 3)
 
 
 def attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
               q_offset=0, k_offset=0, segments=None):
-    """The model's one way to attention: the blockwise kernel where
-    :func:`blockwise_tile` gives a tile, :func:`mha` everywhere else.
+    """The model's one way to attention: the blockwise kernels where
+    a rule gives tiles — :func:`segment_tile` under a segment mask,
+    :func:`blockwise_tile` without one —, :func:`mha` everywhere else.
     `segments` ([B, T] integers, data) restricts every query to the
     keys of its own id. Inside ``jit`` the choice is static; it is
     counted once per traced attention (pvars ``attn_blockwise_layers``
-    / ``attn_reference_layers``, and ``attn_segment_layers`` for those
-    that took a segment mask, whichever way they went)."""
-    tile = blockwise_tile(jax.default_backend(), q.shape[1], k.shape[1],
-                          q.shape[-1], causal, q_offset, k_offset,
-                          segments is not None)
-    if segments is not None:
+    / ``attn_reference_layers``; ``attn_segment_layers`` for those that
+    took a segment mask, whichever way they went, and
+    ``attn_segment_kernel_layers`` for those of them that took the
+    repo's kernels)."""
+    shape = jax.default_backend(), q.shape[1], k.shape[1]
+    if segments is None:
+        tile = blockwise_tile(*shape, q.shape[-1], causal, q_offset, k_offset)
+    else:
         pvar.record("attn_segment_layers")
+        tile = segment_tile(*shape, q.shape[2], q.shape[-1], v.shape[-1],
+                            q.dtype.itemsize, causal, q_offset, k_offset)
+        if tile is not None:
+            pvar.record("attn_segment_kernel_layers")
     if tile is None:
         pvar.record("attn_reference_layers")
         return checkpoint_name(

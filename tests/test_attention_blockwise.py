@@ -14,6 +14,7 @@ orders of magnitude.
 """
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -95,7 +96,7 @@ def test_rule_gives_the_largest_tile_that_divides_t(t, tile):
 @pytest.mark.parametrize("why,args,kwargs", [
     ("the CPU", ("cpu", 1024, 1024, 128), {}),
     ("a GPU", ("gpu", 1024, 1024, 128), {}),
-    ("causal AND segmented", ("tpu", 1024, 1024, 128), {"segmented": True}),
+    ("more queries than keys", ("tpu", 1024, 512, 128), {}),
     ("no head", ("tpu", 1024, 1024, 0), {}),
     ("no tile divides 384", ("tpu", 384, 384, 128), {}),
     ("no tile divides 128", ("tpu", 128, 128, 128), {}),
@@ -117,10 +118,10 @@ def test_rule_refuses(why, args, kwargs):
                                           (192, 256), (256, 256)])
 def test_rule_takes_any_head_width_and_the_kernel_pads_it(width, padded):
     """Since the fifth model (heads of 72, of 192 against 128): the
-    kernels' lanes are the kernel's affair, not the rule's."""
+    kernels' lanes are the kernel's affair, not either rule's."""
     assert att.blockwise_tile("tpu", 1024, 1024, width) == 1024
-    assert att.blockwise_tile("tpu", 1024, 1024, width, causal=False,
-                              segmented=True) == 1024
+    assert att.segment_tile("tpu", 1024, 1024, 16, width,
+                            width)[:2] == (1024, 1024)
     assert att.lanes(width) == padded
 
 
@@ -289,13 +290,18 @@ def test_kernels_compile_for_the_chip_without_a_score_buffer(one_chip, cell,
 def test_padded_and_segmented_kernels_compile_for_the_chip(
         one_chip, cell, t, h, d, dv, segmented):
     """The fifth model's two attentions at the cell's shapes: heads of
-    72 (the tower's, under the segment mask whose tile table is data)
-    and of 192 against values of 128 (the decoder's, causal), padded
-    to the kernels' lanes — the chip's compiler takes both and nothing
-    of the size of the [H, T, T] scores is left."""
-    tile = att.blockwise_tile("tpu", t, t, d, not segmented,
-                              segmented=segmented)
-    assert tile == 1024
+    72 (the tower's, under the segment mask whose table of pairs is
+    data: the repo's own kernels, `seg_fwd` and `seg_bwd`, heads as
+    wide as they are) and of 192 against values of 128 (the decoder's,
+    causal: the library's kernels, padded to their lanes) — the chip's
+    compiler takes both and nothing of the size of the [H, T, T] scores
+    is left."""
+    if segmented:
+        tile = att.segment_tile("tpu", t, t, h, d, dv)
+        assert tile[:2] == (1024, 1024)
+    else:
+        tile = att.blockwise_tile("tpu", t, t, d)
+        assert tile == 1024
 
     def loss(q, k, v, w, ids):
         o = att.blockwise_mha(q, k, v, tile,
@@ -310,7 +316,10 @@ def test_padded_and_segmented_kernels_compile_for_the_chip(
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         arg(d), arg(d), arg(dv), arg(dv, jnp.float32), ids).compile()
     text = compiled.as_text()
-    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text, cell
+    mine = "seg_fwd" in text and "seg_bwd" in text
+    library = "splash_mha_fwd" in text and "splash_mha_dkv" in text
+    assert (mine, library) == (segmented, not segmented), cell
+    assert not re.search(r"splash_mha_\w*segmented", text), cell
     assert f"[{h},{t},{t}]" not in text and f"[1,{h},{t},{t}]" not in text
     scores_bytes = h * t * t * 4  # float32, as att.mha holds them
     assert compiled.memory_analysis().temp_size_in_bytes < scores_bytes / 2

@@ -7,6 +7,7 @@ hand-written cases and against the float32 reference
 (benchmark/reference/kimivl_decoder.py)."""
 
 import os
+import re
 import sys
 
 import jax
@@ -246,13 +247,23 @@ def test_blockwise_kernels_pad_any_width_and_equal_mha(d, dv, masks):
 
 
 @pytest.mark.parametrize("causal,segmented,want", [
-    (True, False, 1024), (False, True, 1024), (False, False, None),
+    (True, False, "library"), (False, True, "segment"), (False, False, None),
     (True, True, None)])
 def test_rule_takes_one_mask_or_the_other(causal, segmented, want):
-    assert att.blockwise_tile("tpu", 4096, 4096, 72, causal,
-                              segmented=segmented) == want
-    assert att.blockwise_tile("cpu", 4096, 4096, 72, causal,
-                              segmented=segmented) is None
+    """One rule per mask: the library's kernels under the causal mask,
+    the repo's own under a segment mask, the reference under neither
+    or both."""
+    def taken(backend):  # as `att.attention` asks them
+        if segmented:
+            return att.segment_tile(backend, 4096, 4096, 16, 72, 72,
+                                    causal=causal) and "segment"
+        return att.blockwise_tile(backend, 4096, 4096, 72, causal) and \
+            "library"
+
+    assert taken("tpu") == want
+    assert taken("cpu") is None
+    assert att.blockwise_tile("tpu", 4096, 4096, 72, True) == 1024
+    assert att.segment_tile("tpu", 4096, 4096, 16, 72, 72)[:2] == (1024, 1024)
 
 
 # -- the decoder ---------------------------------------------------------------------
@@ -384,6 +395,39 @@ def test_another_mix_of_grids_compiles_nothing(params):
          jnp.delete(other["tokens"], np.concatenate(where), axis=1)], 1)),
         jnp.full((1, T), -1).at[0, -1].set(3), spec)
     assert np.isfinite(float(want))  # the reference lays images first
+
+
+def test_step_lowered_for_the_tpu_holds_both_kinds_of_kernel(monkeypatch,
+                                                             pvar_clean):
+    """The rules as on a TPU (128-row tiles) and the step lowered for
+    it, here without one: the tower's attention is the repo's own
+    kernels and no `splash_mha_*segmented*`, the decoder's causal
+    attention the library's, as before there was a second rule."""
+    rules = att.blockwise_tile, att.segment_tile
+    monkeypatch.setattr(att, "_TILES", (128,))
+    monkeypatch.setattr(att, "_SEG_TILES", (128,))
+    monkeypatch.setattr(att, "blockwise_tile",
+                        lambda backend, *a, **k: rules[0]("tpu", *a, **k))
+    monkeypatch.setattr(att, "segment_tile",
+                        lambda backend, *a, **k: rules[1]("tpu", *a, **k))
+    grids = ((16, 12), (8, 16), (4, 16))  # 384 patches: edges at 192, 320
+    where = [np.arange(0, 48), np.arange(98, 130), np.arange(180, 196)]
+    cfg = config(max_seq=256, remat=True, dtype=jnp.bfloat16)
+    batch, labels = make_batch(0, grids, where, seq=256)
+    shapes = jax.eval_shape(lambda: tfm.init_params(
+        np.random.default_rng(0), cfg))
+    text = jax.jit(tfm.make_train_step(
+        cfg, AX, tfm.param_specs(cfg, AX))).trace(
+            shapes, batch, labels).lower(
+                lowering_platforms=("tpu",)).as_text()
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
+    assert "seg_fwd" in text and "seg_bwd" in text
+    assert not re.search(r"splash_mha_\w*segmented", text)
+    layers = VC.n_layers + cfg.n_layers
+    assert pvar.read("attn_blockwise_layers") == layers
+    assert pvar.read("attn_segment_layers") == VC.n_layers
+    assert pvar.read("attn_segment_kernel_layers") == VC.n_layers
+    assert pvar.read("attn_reference_layers") == 0
 
 
 # -- recomputation: the tower's blocks are a layer kind of the rule -------------------
