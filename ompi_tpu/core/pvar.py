@@ -100,6 +100,13 @@ WELL_KNOWN = (
     # the kept names hold, summed over the traced applications
     "remat_kept_applications", "remat_whole_applications",
     "remat_kept_bytes",
+    # models/transformer.py, once per TRACED layer of a pattern: a
+    # Mamba-2 state-space layer and the chunks its scan works a
+    # sequence in (ops/ssm.py); an attention layer whose query heads
+    # share fewer key heads; the set-up probe transformer.ssm_probe:
+    # the norm of the first state-space layer's state after the last
+    # token, in millionths
+    "ssm_layers", "ssm_chunks", "attn_gqa_layers", "ssm_state_norm_micro",
     # the phases of mpi.Init(), once per job (runtime/state.py,
     # runtime/device_plane.py; "import" also holds the import of
     # ompi_tpu.mpi itself): they end before any profiler session can

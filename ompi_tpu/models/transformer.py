@@ -68,6 +68,18 @@ dict — today's ``tokens`` array and the packed images' leaves beside
 it — and the loss runs over the positions whose label is not -1 (the
 text). The tower's blocks are a third layer kind (`VIT`) of the
 recomputation rule.
+The sixth (reference ``benchmark/reference/nemotron_decoder.py``) has
+layers that are ONE pre-norm and ONE mixer, by a published pattern
+string (``layer_pattern``, one letter a layer): `SSM` ``M``, a
+Mamba-2 state-space mixer (:mod:`ompi_tpu.ops.ssm`: a chunked scan
+with no loop in the step; imported only where the pattern has an
+``M``); `EXPERTS` ``E``, the mixture of experts of the third model
+without a gate matrix (``relu2``: ``relu(x W1)^2 W2``) and with a
+shared expert of a width of its own (``shared_d_ff``); `ATTENTION`
+``*``, grouped-query attention (``n_kv_heads`` key heads shared by
+``n_heads`` query heads of ``head_width``) with no positions at all
+(``pos="none"``: the state-space layers carry the order). Each is
+``h + mixer(norm(h))`` and a layer kind of the recomputation rule.
 
 Names on the device (``jax.named_scope``: metadata, the HLO is the
 same): the jitted step is module ``jit_ompi_train_step``; its ops carry
@@ -97,7 +109,13 @@ and the gates, the exit distribution and its entropy
 ``vit_embed``, ``vit_<i>`` > {``ln``, ``attn_proj`` > ``rope2d``,
 ``attn_core``, ``mlp``}, ``vit_merge`` (models/vision.py): AROUND the
 names above, so a reader of ``attn_core`` sums the tower's too and one
-of ``vision`` tells them apart.
+of ``vision`` tells them apart. A layer of a pattern is
+``layer_<i>/{ln, ssm}`` with ``ssm/{ssm_proj, ssm_conv, ssm_scan,
+ssm_gate_norm}`` (``ssm_proj``: both products), ``layer_<i>/{ln,
+attn_proj, attn_core}`` or ``layer_<i>/{ln, mlp}`` with the ``moe_*``
+names above. Counted once per traced layer: ``ssm_layers``,
+``ssm_chunks`` (chunks a layer), ``attn_gqa_layers``; the probe
+:func:`ssm_probe` counts ``ssm_state_norm_micro``.
 """
 
 from __future__ import annotations
@@ -146,8 +164,9 @@ class Config:
     #: "layernorm" (gain and bias) or "rmsnorm" (gain only)
     norm: str = "layernorm"
     norm_eps: float = 1e-5
-    #: "learned" (a table of max_seq rows, params["pos"]) or "rope"
-    #: (rotate-half pairing of dimensions i and i + head_dim / 2)
+    #: "learned" (a table of max_seq rows, params["pos"]), "rope"
+    #: (rotate-half pairing of dimensions i and i + head_dim / 2) or
+    #: "none" (no table, no rotation: layers of a pattern only)
     pos: str = "learned"
     rope_theta: float = 10000.0
     #: RMSNorm of q and k over the WHOLE projection (width d_model),
@@ -251,10 +270,47 @@ class Config:
     #: and bias and every product of it has a bias, whatever the
     #: decoder's `norm` says; the decoder's products have none
     vision: Any = None
+    #: one letter a layer (the source's hybrid_override_pattern): a
+    #: layer is ONE pre-norm and ONE mixer, h + mixer(norm(h)) — `SSM`
+    #: "M" a Mamba-2 state-space mixer, `EXPERTS` "E" the mixture of
+    #: experts (with its shared expert), `ATTENTION` "*" attention.
+    #: None: every layer is attention THEN a feed-forward part
+    layer_pattern: Optional[str] = None
+    #: a head's width where it is not d_model / n_heads (0), and the
+    #: key / value heads where n_heads query heads share fewer (0: as
+    #: many): query head i attends with key head i // (n_heads /
+    #: n_kv_heads). Layers of a pattern only
+    head_width: int = 0
+    n_kv_heads: int = 0
+    #: the shared expert's width where it is not n_shared_experts x
+    #: the experts' (0). Layers of a pattern only
+    shared_d_ff: int = 0
+    #: a Mamba-2 mixer's sizes (ops/ssm.py): heads of ssm_head_dim,
+    #: groups of B and C of ssm_state numbers each, the convolution's
+    #: taps, the tokens of a chunk of the scan
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_width or self.d_model // self.n_heads
+
+    @property
+    def shared_width(self) -> int:
+        return self.shared_d_ff or self.n_shared_experts * self.expert_d_ff
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """The channels the convolution runs over: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def expert_d_ff(self) -> int:
@@ -283,6 +339,33 @@ def _is_moe(cfg: Config, layer: int) -> bool:
     if cfg.first_dense is not None:
         return layer >= cfg.first_dense
     return cfg.moe_every > 0 and (layer + 1) % cfg.moe_every == 0
+
+
+#: the letters of `Config.layer_pattern`: a layer's kind beside the
+#: block's two (False: attention then a dense FFN, True: attention
+#: then a mixture of experts)
+SSM, EXPERTS, ATTENTION = "M", "E", "*"
+
+
+def _layer_kind(cfg: Config, layer: int):
+    """What layer `layer` is: its letter where the config has a
+    pattern, else whether the block's feed-forward part is a mixture
+    of experts."""
+    if cfg.layer_pattern is None:
+        return _is_moe(cfg, layer)
+    _check_pattern(cfg)
+    return cfg.layer_pattern[layer]
+
+
+def _check_pattern(cfg: Config):
+    pattern = cfg.layer_pattern
+    if len(pattern) != cfg.n_layers or set(pattern) - {SSM, EXPERTS,
+                                                       ATTENTION}:
+        raise ValueError(
+            f"layer_pattern={pattern!r}: expected n_layers = "
+            f"{cfg.n_layers} letters of {SSM!r} (a Mamba-2 mixer), "
+            f"{EXPERTS!r} (experts) and {ATTENTION!r} (attention); a "
+            "dense FFN alone ('-') is not written")
 
 
 def _held_count(cfg: Config) -> int:
@@ -378,7 +461,53 @@ def init_params(rng: np.random.Generator, cfg: Config) -> Dict:
             lp["ws2"] = normal(fs, d, scale=1.0 / math.sqrt(fs))
         return lp
 
-    params["layers"] = [layer(_is_moe(cfg, i)) for i in range(cfg.n_layers)]
+    def mixer_layer(kind: str):
+        """A layer of a pattern: one pre-norm and one mixer."""
+        lp = {"ln": norm()}
+        if kind == SSM:
+            heads, inner, k = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv
+            dt = np.exp(rng.uniform(math.log(1e-3), math.log(0.1), heads))
+            lp.update(
+                in_proj=normal(d, inner + cfg.ssm_conv_width + heads,
+                               scale=s_emb),
+                conv_w=normal(cfg.ssm_conv_width, k,
+                              scale=1.0 / math.sqrt(k)),
+                conv_b=normal(cfg.ssm_conv_width, scale=1.0 / math.sqrt(k)),
+                # the family's initialisation: decays of a trained
+                # model, not all ~1 or ~0
+                A_log=np.log(rng.uniform(1.0, 16.0, heads)).astype(pdt),
+                dt_bias=(dt + np.log(-np.expm1(-dt))).astype(pdt),
+                D=np.ones(heads, pdt), ssm_norm=gain(inner),
+                out_proj=normal(inner, d, scale=1.0 / math.sqrt(inner)))
+        elif kind == ATTENTION:
+            wide = cfg.n_heads * cfg.head_dim
+            narrow = (cfg.n_kv_heads or cfg.n_heads) * cfg.head_dim
+            lp.update(
+                wq=normal(d, wide, scale=s_emb),
+                wk=normal(d, narrow, scale=s_emb),
+                wv=normal(d, narrow, scale=s_emb),
+                wo=normal(wide, d, scale=1.0 / math.sqrt(wide)
+                          / math.sqrt(2 * cfg.n_layers)))
+        else:
+            fl, fs = cfg.expert_d_ff, cfg.shared_width
+            held = _held_count(cfg)
+            lp["wg"] = normal(d, cfg.n_experts, scale=s_emb)
+            if cfg.router_bias:
+                lp["wg_bias"] = normal(cfg.n_experts, scale=0.01)
+            lp["w1"] = normal(held, d, fl, scale=s_emb)
+            if cfg.mlp_gated:
+                lp["w3"] = normal(held, d, fl, scale=s_emb)
+            lp["w2"] = normal(held, fl, d, scale=1.0 / math.sqrt(fl))
+            if fs:
+                lp["ws1"] = normal(d, fs, scale=s_emb)
+                if cfg.mlp_gated:
+                    lp["ws3"] = normal(d, fs, scale=s_emb)
+                lp["ws2"] = normal(fs, d, scale=1.0 / math.sqrt(fs))
+        return lp
+
+    params["layers"] = [
+        layer(_is_moe(cfg, i)) if cfg.layer_pattern is None
+        else mixer_layer(_layer_kind(cfg, i)) for i in range(cfg.n_layers)]
     if cfg.mtp_layers:
         params["mtp"] = [dict(
             layer(_is_moe(cfg, cfg.n_layers)), enorm=norm(), hnorm=norm(),
@@ -438,7 +567,22 @@ def _like_params(cfg: Config, leaf, wide, expert):
             lt.update({n: wide(n) for n in ffn})
         return lt
 
-    tree["layers"] = [layer(_is_moe(cfg, i)) for i in range(cfg.n_layers)]
+    def mixer_layer(kind: str):  # replicated: no tp, sp, ep or pp path
+        names = {SSM: ("in_proj", "conv_w", "conv_b", "A_log", "dt_bias",
+                       "D", "out_proj"),
+                 ATTENTION: ("wq", "wk", "wv", "wo"),
+                 EXPERTS: ("wg",) + ffn + (("wg_bias",) if cfg.router_bias
+                                           else ())
+                 + (tuple("ws" + n[1:] for n in ffn) if cfg.shared_width
+                    else ())}[kind]
+        lt = dict({n: leaf for n in names}, ln=norm())
+        if kind == SSM:
+            lt["ssm_norm"] = {"g": leaf}
+        return lt
+
+    tree["layers"] = [
+        layer(_is_moe(cfg, i)) if cfg.layer_pattern is None
+        else mixer_layer(_layer_kind(cfg, i)) for i in range(cfg.n_layers)]
     if cfg.mtp_layers:
         tree["mtp"] = [dict(layer(_is_moe(cfg, cfg.n_layers)), enorm=norm(),
                             hnorm=norm(), eh_proj=leaf)
@@ -543,9 +687,59 @@ def _check_indexer(cfg: Config):
             "latent, which a config with q_lora_rank 0 does not have")
 
 
-def _check_supported(cfg: Config, ax: Axes, is_moe: bool, pos_offset):
+def _check_supported(cfg: Config, ax: Axes, is_moe, pos_offset, t=None):
     """What the config may ask for that an axis cannot give yet is an
-    error, never another function computed in silence."""
+    error, never another function computed in silence. `t`: the tokens
+    of a sequence on this shard, where the caller has them."""
+    if cfg.pos not in ("learned", "rope", "none"):
+        raise ValueError(f"pos={cfg.pos!r}: expected 'learned', 'rope' or "
+                         "'none'")
+    if cfg.layer_pattern is not None:
+        _check_pattern(cfg)
+        for axis, missing in (
+                (ax.tp, "tensor parallelism (ax.tp): a Mamba-2 mixer's "
+                 "heads and groups, the shared key heads and the widths "
+                 "of a pattern's layers are not split by columns yet"),
+                (ax.sp, "sequence parallelism (ax.sp): the scan's carried "
+                 "state and the convolution's last taps would cross "
+                 "chips, and attention without positions has no ring "
+                 "path for shared key heads yet"),
+                (ax.ep, "expert parallelism (ax.ep): a pattern's expert "
+                 "layers take the sorted path with a chip's held share; "
+                 "the exchange in the middle of the sort is ROADMAP R1b"),
+                (ax.pp, "pipeline parallelism (ax.pp): the stages of a "
+                 "pattern are unlike (models/pipeline.py scans equal "
+                 "ones; ROADMAP R3)")):
+            if axis:
+                raise NotImplementedError(
+                    "a layer pattern (Config.layer_pattern) under "
+                    + missing)
+        if cfg.pos == "rope" or cfg.qk_norm or cfg.post_norm \
+                or cfg.attn != "mha":
+            raise NotImplementedError(
+                "a layer pattern (Config.layer_pattern) with RoPE, "
+                "QK-norm, a norm on a mixer's output or latent attention: "
+                "its attention layer is plain grouped-query attention")
+        if cfg.n_heads % (cfg.n_kv_heads or cfg.n_heads):
+            raise ValueError(f"n_heads={cfg.n_heads} is no multiple of "
+                             f"n_kv_heads={cfg.n_kv_heads}")
+        if SSM in cfg.layer_pattern:
+            if cfg.ssm_heads % cfg.ssm_groups:
+                raise ValueError(
+                    f"ssm_heads={cfg.ssm_heads} is no multiple of "
+                    f"ssm_groups={cfg.ssm_groups}")
+            if t is not None and t % cfg.ssm_chunk:
+                raise NotImplementedError(
+                    f"a sequence of {t} tokens is no whole number of the "
+                    f"scan's chunks (ssm_chunk={cfg.ssm_chunk}): a last "
+                    "chunk padded with tokens that change no state is "
+                    "not written")
+    elif cfg.pos == "none" or cfg.head_width or cfg.n_kv_heads \
+            or cfg.shared_d_ff:
+        raise NotImplementedError(
+            "pos='none', head_width, n_kv_heads and shared_d_ff describe "
+            "the layers of a pattern (Config.layer_pattern); the block "
+            "of attention then a feed-forward part reads none of them")
     if cfg.vision is not None and (ax.tp or ax.sp or ax.pp):
         raise NotImplementedError(
             "a vision tower (Config.vision) under tensor, sequence or "
@@ -781,8 +975,12 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
     shard's first token (RoPE under sp needs it; None = not given);
     `aux`, a list, receives a MoE layer's (load-balancing loss, z-loss,
     routing: an ops.moe.TopKRoute); `index_aux`, a list, a selecting
-    layer's (indexer loss, selection [B, T, T])."""
-    _check_supported(cfg, ax, is_moe, pos_offset)
+    layer's (indexer loss, selection [B, T, T]). Where the config has
+    a layer pattern `is_moe` is the layer's letter, and the layer one
+    pre-norm and one mixer (:func:`_mixer_layer`)."""
+    _check_supported(cfg, ax, is_moe, pos_offset, h.shape[1])
+    if cfg.layer_pattern is not None:
+        return _mixer_layer(lp, h, cfg, is_moe, aux)
     dt = cfg.dtype
     b, t = h.shape[0], h.shape[1]
     x = _norm(h.astype(jnp.float32), lp["ln1"], cfg).astype(dt)
@@ -848,6 +1046,79 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
     return _ffn_half(lp, h, cfg, ax, is_moe, aux)
 
 
+def _ssm_mixer(lp, x, cfg: Config):
+    """(the Mamba-2 mixer's output, its scan's final state) of the
+    normed x at the config's sizes (ops/ssm.py, imported here and
+    nowhere else)."""
+    from ompi_tpu.ops import ssm
+
+    with jax.named_scope("ssm"):
+        return ssm.mixer(
+            lp, x, heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
+            groups=cfg.ssm_groups, state=cfg.ssm_state, chunk=cfg.ssm_chunk,
+            eps=cfg.norm_eps)
+
+
+def _gqa_attention(lp, x, cfg: Config):
+    """Causal attention of `n_heads` query heads over `n_kv_heads`
+    shared key / value heads, no positions: query head i attends with
+    key head ``i // (n_heads / n_kv_heads)``. The key heads are
+    repeated in front of the model's one entry
+    (``ops.attention.attention``: the blockwise kernel on the TPU,
+    ``att.mha`` elsewhere), so autodiff sums a key head's gradient
+    over its queries."""
+    dt = cfg.dtype
+    b, t, _ = x.shape
+    heads, kv, dh = cfg.n_heads, cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
+    # as in the block: where the kernel will run, 1/sqrt(Dh) goes in
+    # while q is still the projection's float32 accumulator
+    q_scale = dh ** -0.5 if att.blockwise_tile(
+        jax.default_backend(), t, t, dh) else None
+    with jax.named_scope("attn_proj"):
+        if q_scale:
+            q = (jnp.dot(x, lp["wq"].astype(dt),
+                         preferred_element_type=jnp.float32)
+                 * q_scale).astype(dt)
+        else:
+            q = x @ lp["wq"].astype(dt)
+        q = q.reshape(b, t, heads, dh)
+        k = (x @ lp["wk"].astype(dt)).reshape(b, t, kv, dh)
+        v = (x @ lp["wv"].astype(dt)).reshape(b, t, kv, dh)
+        if kv != heads:
+            pvar.record("attn_gqa_layers")
+            k, v = (jnp.repeat(a, heads // kv, axis=2) for a in (k, v))
+    with jax.named_scope("attn_core"):
+        o = att.attention(q, k, v, causal=True,
+                          scale=1.0 if q_scale else None)
+    with jax.named_scope("attn_proj"):
+        return o.reshape(b, t, heads * dh) @ lp["wo"].astype(dt)
+
+
+def _mixer_layer(lp, h, cfg: Config, kind: str, aux):
+    """A layer of a pattern: ``h + mixer(norm(h))``, the mixer a
+    Mamba-2 state-space model (`SSM`), grouped-query attention
+    (`ATTENTION`) or the mixture of experts with its shared expert
+    (`EXPERTS`). Counted once per traced layer: ``ssm_layers`` and
+    ``ssm_chunks``, ``attn_gqa_layers``, and what the expert path
+    counts of itself."""
+    dt = cfg.dtype
+    b, t = h.shape[0], h.shape[1]
+    x = _norm(h.astype(jnp.float32), lp["ln"], cfg).astype(dt)
+    if kind == SSM:
+        pvar.record("ssm_layers")
+        pvar.record("ssm_chunks", t // cfg.ssm_chunk)
+        return h + _ssm_mixer(lp, x, cfg)[0]
+    if kind == ATTENTION:
+        return h + _gqa_attention(lp, x, cfg)
+    with jax.named_scope("mlp"):
+        flat = x.reshape(b * t, cfg.d_model)
+        y = _moe_sorted(flat, lp, cfg, aux)
+        if cfg.shared_width:
+            with jax.named_scope("moe_shared"):
+                y = y + _ffn(flat, lp["ws1"], lp.get("ws3"), lp["ws2"], cfg)
+        return h + y.reshape(b, t, cfg.d_model)
+
+
 def _ffn_half(lp, h, cfg: Config, ax: Axes, is_moe: bool, aux):
     """h + the FFN half of a block: dense, or the mixture of experts
     (with its shared expert where the config has one)."""
@@ -908,9 +1179,12 @@ def remat_sizes(cfg: Config, b: int, t: int, is_moe: bool) -> Dict[str, int]:
     [b, t] tokens: the names that layer kind makes and its backward
     pass reads (the FFN's output only where a norm follows it: a bare
     residual add's backward reads nothing), from the config's widths
-    alone."""
+    alone. `is_moe`: the layer's kind — its letter where the config
+    has a layer pattern."""
     it = jnp.dtype(cfg.dtype).itemsize
     n, d, heads = b * t, cfg.d_model, cfg.n_heads
+    if cfg.layer_pattern is not None:
+        return _mixer_costs(cfg, n, t, is_moe, it)[0]
     gated = 2 if cfg.mlp_gated else 1
     ff = cfg.n_shared_experts * cfg.expert_d_ff if is_moe else cfg.d_ff
     sizes = {ATTN_PROJ_OUT: n * d * it,
@@ -938,6 +1212,9 @@ def remat_spared(cfg: Config, b: int, t: int, is_moe: bool) -> Dict[str, int]:
     spares beside are not counted): from the config's widths alone,
     attention's over the causal half."""
     n, d, heads = b * t, cfg.d_model, cfg.n_heads
+    if cfg.layer_pattern is not None:
+        return _mixer_costs(cfg, n, t, is_moe,
+                            jnp.dtype(cfg.dtype).itemsize)[1]
     gated = 2 if cfg.mlp_gated else 1
     ff = cfg.n_shared_experts * cfg.expert_d_ff if is_moe else cfg.d_ff
     ops = {MLP_OUT: 2 * n * ff * d, MLP_UP: 2 * n * d * ff * gated}
@@ -959,8 +1236,39 @@ def remat_spared(cfg: Config, b: int, t: int, is_moe: bool) -> Dict[str, int]:
     return ops
 
 
-#: the tower's layer kind beside the decoder's two (False: a dense
-#: layer, True: a MoE layer), models/vision.py's VIT
+def _mixer_costs(cfg: Config, n: int, t: int, kind: str, it: int):
+    """(`remat_sizes`, `remat_spared`) of one application of a layer
+    of a pattern over `n` tokens in sequences of `t`. A state-space
+    layer makes ops/ssm.py's three names (kept, the scan's output
+    spares the two products that make it, not those its own backward
+    pass reads); the attention layer attention's two, with the key
+    heads as the kernel reads them: repeated; an expert layer the
+    shared expert's up-projection."""
+    d = cfg.d_model
+    if kind == SSM:
+        from ompi_tpu.ops import ssm
+
+        inner, conv = cfg.ssm_inner, cfg.ssm_conv_width
+        first = inner + conv + cfg.ssm_heads
+        return ({ssm.SSM_IN: n * first * it, ssm.SSM_CONV: n * conv * it,
+                 ssm.SSM_Y: n * inner * it},
+                {ssm.SSM_IN: 2 * n * d * first,
+                 ssm.SSM_CONV: 2 * n * conv * cfg.ssm_conv,
+                 ssm.SSM_Y: n * inner * (cfg.ssm_chunk + 2 * cfg.ssm_state)})
+    if kind == ATTENTION:
+        heads, dh = cfg.n_heads, cfg.head_dim
+        kv = cfg.n_kv_heads or heads
+        return ({att.ATTN_OUT: n * heads * (dh * it + 4),
+                 att.QKV: 3 * n * heads * dh * it},
+                {att.ATTN_OUT: 2 * n * t * heads * dh,
+                 att.QKV: 2 * n * d * (heads + 2 * kv) * dh})
+    gated = 2 if cfg.mlp_gated else 1
+    up = n * cfg.shared_width * gated
+    return ({MLP_UP: up * it} if up else {}), {MLP_UP: 2 * d * up}
+
+
+#: the tower's layer kind beside the decoder's (False: a dense layer,
+#: True: a MoE layer, or a pattern's letter), models/vision.py's VIT
 VIT = "vit"
 
 
@@ -969,7 +1277,7 @@ def _application_kinds(cfg: Config):
     (the trunk's layers, pass after pass, then the multi-token-prediction
     modules), or `VIT`: a block of the vision tower, which run first."""
     return [VIT] * (cfg.vision.n_layers if cfg.vision is not None else 0) \
-        + [_is_moe(cfg, i) for i in range(cfg.n_layers)] * cfg.loops \
+        + [_layer_kind(cfg, i) for i in range(cfg.n_layers)] * cfg.loops \
         + [_is_moe(cfg, cfg.n_layers)] * cfg.mtp_layers
 
 
@@ -1011,20 +1319,23 @@ def remat_order(cfg: Config, b: int, t: int, patches: int = 0):
 
 
 def whole_step_peak(cfg: Config, b: int, t: int, param_bytes: int,
-                    patches: int = 0) -> int:
+                    patches: int = 0, largest: Optional[int] = None) -> int:
     """The bytes a train step (`make_train_step`) is reckoned to hold
     at its peak with every layer application recomputed from its input
     alone, term by term from the program: the parameters; their
     gradients (all of them where the layers run more than once and a
-    leaf's gradient is a sum over the passes, else one application's
-    share: the update takes each as it appears); an input per
+    leaf's gradient is a sum over the passes, else ONE application's:
+    the update takes each as it appears — `largest`, the bytes of the
+    parameters of the application that has most, where the caller has
+    the tree, else the mean over the applications, which is less
+    where the layers are unlike); an input per
     application (a tower block's: the packed row of `patches`); ONE
     exit's float32 logits, their exponentials and their cotangent
     (`_exit_terms`, `_token_nll`); the values one application's
     backward pass makes again and a cotangent for each."""
     kinds = _application_kinds(cfg)
     grads = param_bytes if cfg.loops > 1 \
-        else param_bytes // max(len(kinds), 1)
+        else largest or param_bytes // max(len(kinds), 1)
     per = {kind: _kind_costs(cfg, b, t, patches, kind)
            for kind in set(kinds)}
     again = max(sum(sizes.values()) for sizes, _, _ in per.values())
@@ -1033,12 +1344,14 @@ def whole_step_peak(cfg: Config, b: int, t: int, param_bytes: int,
 
 
 def remat_keep(cfg: Config, b: int, t: int, param_bytes: int,
-               limit: Optional[int], patches: int = 0) -> Tuple[str, ...]:
+               limit: Optional[int], patches: int = 0,
+               largest: Optional[int] = None) -> Tuple[str, ...]:
     """The rule that says what a recomputed layer application keeps
     for its backward pass, made of what the trace can observe: the
     tokens' shape (and the packed row's patches where the config has a
     tower), the config's widths and depth, the bytes of the
-    parameters and the device's memory limit. It starts from
+    parameters (all of them, and `largest`: of the application that
+    has most) and the device's memory limit. It starts from
     `whole_step_peak`, walks the names in `remat_order`, adding what
     all the applications hold under a name, and stops before the first
     name that would take the reckoned peak past `REMAT_SHARE` of the
@@ -1046,7 +1359,7 @@ def remat_keep(cfg: Config, b: int, t: int, param_bytes: int,
     application recomputed whole — the parent's program."""
     if not limit:
         return ()
-    peak = whole_step_peak(cfg, b, t, param_bytes, patches)
+    peak = whole_step_peak(cfg, b, t, param_bytes, patches, largest)
     keep = []
     for name, held in remat_order(cfg, b, t, patches):
         if peak + held > REMAT_SHARE * limit:
@@ -1075,10 +1388,18 @@ def _remat_names(params, batch, cfg: Config) -> Tuple[str, ...]:
     if not cfg.remat:
         return ()
     patches = batch["patches"].shape[0] if isinstance(batch, dict) else 0
-    return remat_keep(
-        cfg, *_ids(batch).shape,
-        sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params)),
-        _memory_limit(), patches)
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    # the layers of a pattern are unlike (an expert layer's parameters
+    # are 4.6x a state-space layer's in the sixth model): the largest.
+    # The blocks' keep-sets were fitted on the chip with the mean
+    # (PERF.md 6, PR 35 and PR 37) and stay on it
+    largest = max(map(nbytes, params["layers"])) \
+        if cfg.layer_pattern is not None else None
+    return remat_keep(cfg, *_ids(batch).shape, nbytes(params),
+                      _memory_limit(), patches, largest)
 
 
 def _recomputed_layer(cfg: Config, ax: Axes, is_moe: bool,
@@ -1245,8 +1566,8 @@ def _trunk(params, batch, cfg: Config, ax: Axes, aux=None, index_aux=None,
             for i, lp in enumerate(params["layers"]):
                 pvar.record("loop_layer_applications")
                 with jax.named_scope(f"layer_{i}"):
-                    h = _run_layer(lp, h, cfg, ax, _is_moe(cfg, i), t_off,
-                                   aux, index_aux, recomputed)
+                    h = _run_layer(lp, h, cfg, ax, _layer_kind(cfg, i),
+                                   t_off, aux, index_aux, recomputed)
             if s < cfg.loops - 1:
                 h = _final_norm(params, h, cfg)
                 if exits is not None:
@@ -1554,6 +1875,90 @@ def exit_stats(params, tokens, labels, cfg: Config):
     for s, m in enumerate(mass):
         pvar.record(f"exit_mass_micro_p{s}", int(round(m * 1e6)))
     return nll / count, mass / count
+
+
+#: the leaves of a state-space layer whose gradients exist only
+#: through the scan, the convolution and the gated norm
+SSM_SMALL = ("A_log", "dt_bias", "D", "conv_w", "conv_b", "ssm_norm")
+
+
+def _first_of(kind: str, params, tokens, cfg: Config):
+    """(the leaves of the first layer of `kind`, its normed input) of a
+    one-device forward pass of a config with a layer pattern."""
+    dt = cfg.dtype
+    h = params["embed"].astype(dt)[tokens]
+    if cfg.pos == "learned":
+        h = h + params["pos"][:tokens.shape[1]].astype(dt)[None]
+    for i, lp in enumerate(params["layers"]):
+        if _layer_kind(cfg, i) == kind:
+            return lp, _norm(h.astype(jnp.float32), lp["ln"], cfg).astype(dt)
+        h = layer_forward(lp, h, cfg, Axes(), _layer_kind(cfg, i))
+    raise ValueError(f"layer_pattern={cfg.layer_pattern!r} has no layer "
+                     f"{kind!r}")
+
+
+@_probe("ompi_ssm_probe")
+def _ssm_probe(params, tokens, cfg: Config):
+    return _ssm_mixer(*_first_of(SSM, params, tokens, cfg), cfg)
+
+
+@_probe("ompi_gqa_probe")
+def _gqa_probe(params, tokens, cfg: Config):
+    return _gqa_attention(*_first_of(ATTENTION, params, tokens, cfg), cfg)
+
+
+def gqa_probe(params, tokens, cfg: Config):
+    """The first attention layer's mixer output [B, T, d_model] (before
+    the residual add) in a one-device forward pass of a config with a
+    layer pattern: which key head a query head attends with shows in
+    it and in little else. A probe the host calls outside any timed
+    window."""
+    return _gqa_probe(params, tokens, cfg=cfg)
+
+
+def ssm_probe(params, tokens, cfg: Config):
+    """(the first state-space layer's mixer output [B, T, d_model],
+    its scan's state after the last token [B, H, P, N] float32) in a
+    one-device forward pass. A probe the host calls outside any timed
+    window: the norm of that state (a scan that forgets everything or
+    nothing shows in it) goes to the always-on counter
+    `ssm_state_norm_micro`, in millionths."""
+    out, last = _ssm_probe(params, tokens, cfg=cfg)
+    pvar.record("ssm_state_norm_micro", int(round(1e6 * float(
+        jnp.sqrt(jnp.sum(last * last))))))
+    return out, last
+
+
+@_probe("ompi_ssm_leaf_grads")
+def _ssm_grads_probe(params, tokens, labels, cfg: Config):
+    def small(lp):
+        return jax.tree.map(lambda a: a.astype(jnp.float32),
+                            {n: lp[n] for n in SSM_SMALL})
+
+    mine = [i for i in range(cfg.n_layers) if _layer_kind(cfg, i) == SSM]
+
+    def mean_loss(leaves):
+        layers = list(params["layers"])
+        for i, sm in zip(mine, leaves):
+            layers[i] = dict(layers[i], **sm)
+        nll, count = loss_local(dict(params, layers=layers), tokens, labels,
+                                cfg, Axes())
+        return nll / count
+
+    grads = jax.grad(mean_loss)([small(params["layers"][i]) for i in mine])
+    return jnp.stack([jnp.stack([
+        jnp.sqrt(sum(jnp.sum(a * a) for a in jax.tree.leaves(g[n])))
+        for n in SSM_SMALL]) for g in grads])
+
+
+def ssm_leaf_grads(params, tokens, labels, cfg: Config):
+    """float32 [state-space layers, len(SSM_SMALL)]: the norm of the
+    training loss's gradient in each `SSM_SMALL` leaf of each
+    state-space layer, taken in float32 (the step hands the optimizer
+    the parameters' type, and a step of lr x such a gradient is less
+    than bfloat16 resolves in a leaf near 1). A probe the host calls
+    outside any timed window."""
+    return _ssm_grads_probe(params, tokens, labels, cfg=cfg)
 
 
 def grad_sync(grads, specs, ax: Axes, extra=None):

@@ -18,7 +18,9 @@ rows gathered into expert order, the experts run as ONE grouped matmul
 per expert matrix over the ragged groups (:func:`grouped_matmul`: on
 the TPU, where the static rule :func:`grouped_tiles` gives tiles, the
 Pallas kernels of ops/grouped_matmul.py, forward and both transposes;
-``lax.ragged_dot`` everywhere else), and the weighted rows gathered
+``lax.ragged_dot`` everywhere else; an expert width the kernels'
+lanes do not divide enters them padded with zero columns,
+:func:`expert_width_pad`), and the weighted rows gathered
 back and summed per token. Static shapes, no capacity, no token ever
 dropped, any ``k``,
 gated (``w3``) or plain ReLU experts; differentiable with respect to
@@ -263,7 +265,8 @@ def _permute_bwd(inv, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 _ACT = {"relu": lambda x: jnp.maximum(x, 0), "silu": jax.nn.silu,
-        "gelu": jax.nn.gelu}
+        "gelu": jax.nn.gelu,
+        "relu2": lambda x: jnp.square(jnp.maximum(x, 0))}
 
 
 def activation(name: str):
@@ -625,6 +628,34 @@ def _held_or_all_rows_bwd(act, bound, res, g):
 _held_or_all_rows.defvjp(_held_or_all_rows_fwd, _held_or_all_rows_bwd)
 
 
+def expert_width_pad(backend: str, width: int) -> int:
+    """The rule that says how many ZERO columns the experts' width
+    takes on its way into the grouped matmuls, made of what the caller
+    can observe: on the TPU, up to the next multiple of the kernels'
+    128 lanes (1856 -> 1920: without them :func:`grouped_tiles`
+    refuses the width and the products are libtpu's ``ragged-dot``
+    kernels, whose time follows the rows that are real — seed by seed
+    — and which ran at 6% of the experts' roofline: PERF.md section 6,
+    PR 39); none elsewhere, and none for a width the lanes divide."""
+    return -width % 128 if backend == "tpu" else 0
+
+
+def _lane_padded(w1, w3, w2):
+    """The experts' matrices with their width padded by
+    :func:`expert_width_pad`'s zeros: every activation here maps 0 to
+    0 and the padded rows of `w2` are zero, so the layer computes the
+    same function and the gradient of the padding is dropped."""
+    pad = expert_width_pad(jax.default_backend(), w1.shape[2])
+    if not pad:
+        return w1, w3, w2
+
+    def wider(w):
+        return jnp.pad(w, ((0, 0), (0, 0), (0, pad)))
+
+    return (wider(w1), None if w3 is None else wider(w3),
+            jnp.pad(w2, ((0, 0), (0, pad), (0, 0))))
+
+
 def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
                    w2, act: str = "relu", bound: Optional[int] = None):
     """Drop-free MoE FFN on one device. x: [T, D] tokens; w1 (and the
@@ -645,9 +676,12 @@ def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
     takes the layer over all ``T * k`` rows instead, exactly, inside
     one ``lax.cond``: no assignment is ever dropped. Counted once per
     traced call: ``moe_bounded_layers`` (a bound and its fallback) /
-    ``moe_full_layers`` (all the rows, no second path)."""
+    ``moe_full_layers`` (all the rows, no second path). On the TPU a
+    width the kernels' lanes do not divide is padded with zeros on the
+    way in (:func:`expert_width_pad`)."""
     t, k = route.experts.shape
     rows = t * k if bound is None else min(bound, t * k)
+    w1, w3, w2 = _lane_padded(w1, w3, w2)
     # the rule reads K and N alike: what it says of w1 holds for w2
     pvar.record("moe_grouped_kernel_layers" if _tiles_of(
         jax.ShapeDtypeStruct((rows, x.shape[-1]), x.dtype), w1)
