@@ -65,6 +65,10 @@ WELL_KNOWN = (
     # transformer.route_counts, the layer-batches whose held
     # assignments exceed the bound: how often the fallback would run
     "moe_bounded_layers", "moe_full_layers", "moe_over_bound_layers",
+    # the same, once per TRACED bounded layer: a token's rows are
+    # fetched by the sort's inverse and added, or summed by a 0/1
+    # product on the MXU (the rule ops/moe.row_sum_gathers)
+    "moe_row_sum_gather_layers", "moe_row_sum_product_layers",
     # models/transformer.py, once per TRACED layer: latent attention
     # (MLA); of those, the layers whose sparse-attention indexer
     # selects (the sequence is longer than index_topk)

@@ -31,10 +31,13 @@ scatter-add is the slow way to say so on a TPU. **A chip that holds a
 share of the experts** carries a static BOUND of rows instead of all
 ``T * k`` (the rule :func:`held_rows_bound`: a few times its share):
 the first `bound` rows of the same sort are gathered, multiplied by
-the same kernels and summed back per token by a 0/1 product on the
-MXU (:func:`_sum_rows`), and a batch that sends the chip more rows
-than that takes the layer over all rows instead, inside one
-``lax.cond`` a direction — exact, counted, never a drop.
+the same kernels and summed back per token — fetched by the sort's
+inverse and added (:func:`_weigh_held`, :func:`_sum_held`), or by a
+0/1 product on the MXU where the bound is a small part of ``T * k``
+(:func:`_sum_rows`; the rule :func:`row_sum_gathers`) —, and a batch
+that sends the chip more rows than that takes the layer over all rows
+instead, inside one ``lax.cond`` a direction — exact, counted, never a
+drop.
 
 **Expert parallel (``ax.ep``): capacity-based top-1 over all_to_all.**
 BASELINE.md config #5 is the MPI_Alltoall(v) MoE expert-dispatch
@@ -448,10 +451,39 @@ def held_rows_bound(t: int, k: int, count: int, n_experts: int) -> int:
     return min(rows, -(-want // _TM) * _TM)
 
 
+#: Operations of a 0/1 product on the MXU that take the time of one
+#: gathered byte: the v5e's 197 TFLOP/s over the ~100 GB/s at which
+#: XLA's row gather and the sum behind it run. On the chip (PR 40, one
+#: bounded layer, forward + backward, bfloat16): at nemotron-train-
+#: t8192's shapes (24,576 of 49,152 rows of 2,688) the product form
+#: 38.9 ms, the gather 18.8 — its two gathers of 264 MB 2.07 ms each
+#: and the sums 0.45; at glm5-train-t4096's (4,096 of 32,768 rows of
+#: 6,144) the product 12.6, the gather 15.6 — 403 MB in 3.09 ms, twice,
+#: against four passes of 1.1-1.2. The rule's ratio (below) reads 8,192
+#: and 1,024 operations a byte there.
+ROW_SUM_OPS_PER_BYTE = 2000
+
+
+def row_sum_gathers(t: int, k: int, bound: int, d: int, dtype) -> bool:
+    """The rule that says how a bounded layer adds a token's rows,
+    made of what the caller can observe: the 0/1 product
+    (:func:`_sum_rows`) costs ``2 * t * bound * d`` operations a pass
+    — three passes for the combine's float32 rows, and for the
+    dispatch's transpose one (bfloat16 rows) or three —, the gather by
+    the sort's inverse (:func:`_weigh_held`, :func:`_sum_held`) moves
+    ``t * k`` rows of `d` a direction. True where the product's
+    operations are more than `ROW_SUM_OPS_PER_BYTE` a byte of those."""
+    dtype = jnp.dtype(dtype)
+    passes = 3 + (1 if dtype == jnp.bfloat16 else 3)
+    return (passes * 2 * t * bound * d
+            > ROW_SUM_OPS_PER_BYTE * 2 * t * k * d * dtype.itemsize)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _take_rows(x, token, t: int):
-    """x[token]: the rows of x [t, D] that `token` [B] names. Its
-    transpose is :func:`_sum_rows`, and that one's is this."""
+    """x[token]: the rows of x [t, D] that `token` [B] names, where
+    the per-token sums are products. Its transpose is
+    :func:`_sum_rows`, and that one's is this."""
     return x[token]
 
 
@@ -474,7 +506,9 @@ def _sum_rows(v, token, t: int):
     small share leaves idle: the 0/1 matrix ``[t, B]`` is exact in
     bfloat16 and so is each of the three bfloat16 pieces a float32 v is
     cut into, so every product is exact and only the float32 sum's
-    order is the hardware's."""
+    order is the hardware's. Its work grows with ``t * B``: the form
+    of a B that is a small part of ``t * k``
+    (:func:`row_sum_gathers`; :func:`_sum_held` is the other)."""
     hot = (jnp.arange(t, dtype=token.dtype)[:, None]
            == token[None, :]).astype(jnp.bfloat16)
     total, rest = 0.0, v.astype(jnp.float32)
@@ -550,18 +584,122 @@ def _all_rows(x, experts, weights, counts, w1, w3, w2, act: str,
                           weights).astype(x.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _take_held(x, order, inv, k: int, bound: int):
+    """x[order[:bound] // k]: the tokens of the first `bound`
+    assignments of the sort. Its transpose is :func:`_sum_held`, and
+    that one's is this: a gather both ways."""
+    return x[order[:bound] // k]
+
+
+def _take_held_fwd(x, order, inv, k, bound):
+    return x[order[:bound] // k], (order, inv)
+
+
+def _take_held_bwd(k, bound, res, g):
+    return _sum_held(g, *res, k, bound), None, None
+
+
+_take_held.defvjp(_take_held_fwd, _take_held_bwd)
+
+
+def _places(v, inv, k: int, bound: int, held):
+    """([k, t, ...] in v's type, [k, t] bool): the rows of v [bound,
+    ...] at each token's k places in the sort, place by place, and
+    which places are under `held` (<= bound); a place past the bound
+    reads some row — the rows one after another, not one row for all of
+    them: most places of a small share are such — and counts for
+    nothing. The places lead: ``[k * t, D]`` splits into ``[k, t, D]``
+    for nothing on the TPU, where ``[t, k, D]`` is a copy into tiles of
+    16 rows that k does not fill (the chip, PR 40, a layer and
+    direction at nemotron-train-t8192: that copy 0.97 ms, the sum over
+    its 2.7 times the rows 1.07 for 0.45, the gather 2.31 for 2.07
+    with every such place on the last row)."""
+    at = inv.reshape(-1, k).T
+    walk = (jnp.arange(at.size, dtype=at.dtype) % bound).reshape(at.shape)
+    return v[jnp.where(at < bound, at, walk)], at < held
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _sum_held(v, order, inv, k: int, bound: int):
+    """[t, D] in v's type: row i is the sum of the rows of v [bound, D]
+    that stand at token i's places in the sort (``inv[i * k + j] <
+    bound``: at most k of them), fetched by the sort's inverse in the
+    type they have and summed in float32."""
+    rows, under = _places(v, inv, k, bound, bound)
+    return jnp.where(under[..., None], rows, 0).astype(jnp.float32).sum(
+        0).astype(v.dtype)
+
+
+def _sum_held_fwd(v, order, inv, k, bound):
+    return _sum_held(v, order, inv, k, bound), (order, inv)
+
+
+def _sum_held_bwd(k, bound, res, g):
+    return _take_held(g, *res, k, bound), None, None
+
+
+_sum_held.defvjp(_sum_held_fwd, _sum_held_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _weigh_held(out, weights, order, inv, held, bound: int):
+    """[t, D] in out's type: ``sum_j weights[i, j] * out[inv[i * k +
+    j]]`` over token i's places under `held` (an int32 scalar, <=
+    bound: the held assignments), the products and the sum in float32
+    and ONE rounding, as the full layer's. A place past `held` weighs
+    nothing whatever its row holds. The transpose reads `bound` rows
+    of the cotangent (:func:`_take_held`) and nothing of the size of
+    ``T * k`` rows."""
+    rows, under = _places(out, inv, weights.shape[1], bound, held)
+    return (jnp.where(under[..., None], rows, 0).astype(jnp.float32)
+            * weights.T[..., None]).sum(0).astype(out.dtype)
+
+
+def _weigh_held_fwd(out, weights, order, inv, held, bound):
+    return (_weigh_held(out, weights, order, inv, held, bound),
+            (out, weights, order, inv, held))
+
+
+def _weigh_held_bwd(bound, res, g):
+    out, weights, order, inv, held = res
+    k = weights.shape[1]
+    g = _take_held(g, order, inv, k, bound).astype(jnp.float32)
+    dout = g * weights.reshape(-1)[order[:bound]][:, None]
+    dweight, under = _places((out.astype(jnp.float32) * g).sum(-1), inv, k,
+                             bound, held)
+    return (dout.astype(out.dtype), jnp.where(under, dweight, 0).T, None,
+            None, None)
+
+
+_weigh_held.defvjp(_weigh_held_fwd, _weigh_held_bwd)
+
+
 def _held_rows(x, experts, weights, counts, w1, w3, w2, act: str,
-               bound: int):
+               bound: int, gather: bool):
     """The layer over the first `bound` assignments of the sort — all
     the held ones where ``counts.sum() <= bound`` — and nothing of the
-    size of ``T * k`` rows."""
+    size of ``T * k`` rows but, where `gather`
+    (:func:`row_sum_gathers`), the rows a token's sum fetches."""
     t, k = experts.shape
     with jax.named_scope("moe_dispatch"):
         order, inv = _expert_order(experts)
-        token = order[:bound] // k
-        rows = _take_rows(x, token, t)
+        if gather:
+            rows = _take_held(x, order, inv, k, bound)
+        else:
+            token = order[:bound] // k
+            rows = _take_rows(x, token, t)
     out = _experts(rows, counts, w1, w3, w2, act)
     with jax.named_scope("moe_combine"):
+        if gather:
+            # the barrier keeps the sum INSIDE this branch: the full
+            # layer ends in the same float32 products and sum, and XLA
+            # moved both branches' out of the conditional, whose result
+            # was then the float32 ``[T, k, D]`` rows (compiled for a
+            # v5e, PR 40: 0.53 GB a layer at nemotron-train-t8192)
+            return lax.optimization_barrier(_weigh_held(
+                out, weights, order, inv, jnp.minimum(counts.sum(), bound),
+                bound))
         weight = _take_head(weights.reshape(t * k), order, inv, bound)
         return _sum_rows(out.astype(jnp.float32) * weight[:, None], token,
                          t).astype(x.dtype)
@@ -581,32 +719,33 @@ def _fallback_rows(x, experts, weights, counts, w1, w3, w2, act: str):
                      product=_ragged_dot_zero_tail)
 
 
-def _branches(act: str, bound: int):
-    return (functools.partial(_held_rows, act=act, bound=bound),
+def _branches(act: str, bound: int, gather: bool):
+    return (functools.partial(_held_rows, act=act, bound=bound,
+                              gather=gather),
             functools.partial(_fallback_rows, act=act))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
 def _held_or_all_rows(x, experts, weights, counts, w1, w3, w2, act: str,
-                      bound: int):
+                      bound: int, gather: bool):
     """:func:`_held_rows` where the batch's held assignments fit the
     bound, else :func:`_fallback_rows`: ONE conditional a direction.
     The backward pass makes the taken branch's forward again inside its
     own conditional, so that neither branch's residuals are outputs of
     a conditional (jax fills the other branch's with zeros: ``T * k``
     rows of them)."""
-    return lax.cond(counts.sum() <= bound, *_branches(act, bound),
+    return lax.cond(counts.sum() <= bound, *_branches(act, bound, gather),
                     x, experts, weights, counts, w1, w3, w2)
 
 
 def _held_or_all_rows_fwd(x, experts, weights, counts, w1, w3, w2, act,
-                          bound):
+                          bound, gather):
     return (_held_or_all_rows(x, experts, weights, counts, w1, w3, w2, act,
-                              bound),
+                              bound, gather),
             (x, experts, weights, counts, w1, w3, w2))
 
 
-def _held_or_all_rows_bwd(act, bound, res, g):
+def _held_or_all_rows_bwd(act, bound, gather, res, g):
     x, experts, weights, counts, w1, w3, w2 = res
 
     def transposed(body):
@@ -620,7 +759,8 @@ def _held_or_all_rows_bwd(act, bound, res, g):
     # reads three bfloat16 ones (the chip, PR 33: 14.6 ms a step of
     # converts and 0.6 GB)
     dx, dweights, dw1, dw3, dw2 = lax.optimization_barrier(lax.cond(
-        counts.sum() <= bound, *map(transposed, _branches(act, bound)),
+        counts.sum() <= bound,
+        *map(transposed, _branches(act, bound, gather)),
         x, weights, w1, w3, w2))
     return dx, None, dweights, None, dw1, dw3, dw2
 
@@ -676,7 +816,10 @@ def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
     takes the layer over all ``T * k`` rows instead, exactly, inside
     one ``lax.cond``: no assignment is ever dropped. Counted once per
     traced call: ``moe_bounded_layers`` (a bound and its fallback) /
-    ``moe_full_layers`` (all the rows, no second path). On the TPU a
+    ``moe_full_layers`` (all the rows, no second path); and of the
+    bounded ones ``moe_row_sum_gather_layers`` (a token's rows fetched
+    by the sort's inverse and added) / ``moe_row_sum_product_layers``
+    (summed by a 0/1 product: :func:`row_sum_gathers`). On the TPU a
     width the kernels' lanes do not divide is padded with zeros on the
     way in (:func:`expert_width_pad`)."""
     t, k = route.experts.shape
@@ -688,7 +831,10 @@ def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
         else "moe_ragged_dot_layers")
     pvar.record("moe_bounded_layers" if rows < t * k else "moe_full_layers")
     if rows < t * k:
+        gather = row_sum_gathers(t, k, rows, x.shape[-1], x.dtype)
+        pvar.record("moe_row_sum_gather_layers" if gather
+                    else "moe_row_sum_product_layers")
         return _held_or_all_rows(x, route.experts, route.weights,
-                                 route.counts, w1, w3, w2, act, rows)
+                                 route.counts, w1, w3, w2, act, rows, gather)
     return _all_rows(x, route.experts, route.weights, route.counts, w1, w3,
                      w2, act)

@@ -248,6 +248,9 @@ def test_probe_counters_of_a_bounded_share(pvar_clean, monkeypatch):
     jax.jit(_mean_loss(cfg, tok, lab)).lower(params)
     assert pvar.read("moe_bounded_layers") == 2
     assert pvar.read("moe_full_layers") == 0
+    # 512 of 4,096 rows: a bound this small takes the 0/1 product
+    assert (pvar.read("moe_row_sum_gather_layers"),
+            pvar.read("moe_row_sum_product_layers")) == (0, 2)
     tfm.route_counts(params, tok, cfg)
     assert pvar.read("moe_held_assignments") == counts[0, fullest] < 512
     assert pvar.read("moe_over_bound_layers") == 0
@@ -346,6 +349,8 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(seed, shares, seq,
     assert bounded == (16 if shares == 16 else 0)
     assert (pvar.read("moe_bounded_layers"),
             pvar.read("moe_full_layers")) == (bounded, shares - bounded)
+    assert (pvar.read("moe_row_sum_gather_layers")
+            + pvar.read("moe_row_sum_product_layers")) == bounded
 
 
 def test_held_share_sorts_the_absent_past_the_last_group():

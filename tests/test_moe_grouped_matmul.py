@@ -15,6 +15,7 @@ missing row misses these by orders of magnitude.
 import functools
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -336,17 +337,30 @@ def _held_operands(dtype, d=128, f=128):
              / np.sqrt(s[-2])).astype(dtype) for k, s in zip(ks, shapes)]
 
 
+FORMS = ("gather", "product")
+
+
+@pytest.fixture
+def row_sum(request, monkeypatch):
+    """The rule `moe.row_sum_gathers` answering for the form the case
+    names, whatever the shapes."""
+    monkeypatch.setattr(moe, "row_sum_gathers",
+                        lambda *a: request.param == "gather")
+    return request.param
+
+
+@pytest.mark.parametrize("row_sum", FORMS, indirect=True)
 @pytest.mark.parametrize("held", sorted(HELD))
 @pytest.mark.parametrize("path", ["ragged_dot", "kernels"])
 @pytest.mark.parametrize("experts", ["gated_silu", "ungated_relu"])
-def test_the_bounded_layer_is_the_full_layer(experts, path, held, request,
-                                             pvar_clean):
+def test_the_bounded_layer_is_the_full_layer(experts, path, held, row_sum,
+                                             request, pvar_clean):
     """The layer on `BOUND` rows with its fallback against the layer on
-    all T * k, output and every gradient (rows, router, w1, w3, w2):
-    held assignments under the bound, exactly at it, none, and over it
-    (the fallback taken: the layer on all rows through
-    `lax.ragged_dot` — where the full layer's products are that too,
-    bit for bit)."""
+    all T * k, output and every gradient (rows, router, w1, w3, w2), a
+    token's rows added in both forms: held assignments under the bound,
+    exactly at it, none, and over it (the fallback taken: the layer on
+    all rows through `lax.ragged_dot` — where the full layer's products
+    are that too, bit for bit)."""
     if path == "kernels":
         request.getfixturevalue("kernels_on_cpu")
     act, gated = ("silu", True) if experts == "gated_silu" else ("relu",
@@ -359,6 +373,9 @@ def test_the_bounded_layer_is_the_full_layer(experts, path, held, request,
         got, got_g = _held_layer(BOUND, HELD[held], act, gated)(*args)
     assert (pvar.read("moe_full_layers"),
             pvar.read("moe_bounded_layers")) == (1, 1)
+    assert (pvar.read("moe_row_sum_gather_layers"),
+            pvar.read("moe_row_sum_product_layers")) == (
+                (1, 0) if row_sum == "gather" else (0, 1))
     # the products of both layers took the path asked for
     assert pvar.read("moe_grouped_kernel_layers" if path == "kernels"
                      else "moe_ragged_dot_layers") == 2
@@ -366,7 +383,11 @@ def test_the_bounded_layer_is_the_full_layer(experts, path, held, request,
     for name, a, b in zip(("y", "x", "wg", "w1", "w3", "w2"),
                           (got[1],) + got_g, (want[1],) + want_g):
         a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-        if over and path == "ragged_dot":
+        if (over and path == "ragged_dot") or (
+                name == "y" and row_sum == "gather"
+                and path == "ragged_dot"):
+            # the fallback IS the full layer; the gathered sum adds the
+            # full layer's terms, and two a token have one order
             assert (a == b).all(), name
         else:  # the float32 sums of a token's rows, in another order
             assert np.linalg.norm(a - b) <= 1e-6 * np.linalg.norm(b) + 1e-9, (
@@ -375,48 +396,129 @@ def test_the_bounded_layer_is_the_full_layer(experts, path, held, request,
         assert all(float(jnp.linalg.norm(g)) > 0 for g in want_g)
 
 
-def test_the_bounded_layer_in_bfloat16_sums_in_float32(pvar_clean):
+@pytest.mark.parametrize("row_sum", FORMS, indirect=True)
+def test_the_bounded_layer_in_bfloat16_sums_in_float32(row_sum, pvar_clean):
     """bfloat16 rows: the experts' outputs and weight gradients are the
     full layer's bit for bit (the same rows in the same order), the
     output differs by the order of at most k float32 terms before ONE
-    rounding."""
+    rounding — and by nothing where the rows are gathered: those are
+    the full layer's terms, and two a token have one order."""
     args = _held_operands(jnp.bfloat16)
     want, want_g = _held_layer(None, HELD["under"], "silu", True)(*args)
     got, got_g = _held_layer(BOUND, HELD["under"], "silu", True)(*args)
     for name, a, b in zip(("y", "x", "wg", "w1", "w3", "w2"),
                           (got[1],) + got_g, (want[1],) + want_g):
         a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-        assert np.linalg.norm(a - b) <= 1e-2 * np.linalg.norm(b), name
+        if row_sum == "gather" and name in ("y", "w1", "w3", "w2"):
+            assert (a == b).all(), name
+        else:
+            assert np.linalg.norm(a - b) <= 1e-2 * np.linalg.norm(b), name
+
+
+def _sorted_assignments(rng, t, k, bound):
+    """A stable sort of t * k assignments of which `bound` or fewer
+    stand in front, as (order, inv, token of each of the first `bound`
+    rows)."""
+    order = jnp.asarray(rng.permutation(t * k), jnp.int32)
+    return order, jnp.argsort(order), order[:bound] // k
+
+
+def _sums(form, v, order, inv, token, t, k):
+    """`v`'s rows summed per token and the tokens' rows taken, in the
+    form named: the functions and what they take differ, the
+    mathematics does not."""
+    if form == "product":
+        return (lambda v: moe._sum_rows(v, token, t),
+                lambda x: moe._take_rows(x, token, t))
+    bound = v.shape[0]
+    return (lambda v: moe._sum_held(v, order, inv, k, bound),
+            lambda x: moe._take_held(x, order, inv, k, bound))
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bfloat16", "float32"])
-def test_sum_rows_is_the_float32_sum_and_take_rows_transpose(dtype):
-    """`_sum_rows` against numpy's float64 sum of the values, rounded
-    once: the pieces a float32 row is cut into lose nothing, and each
-    of the two functions is the other's transpose."""
+@pytest.mark.parametrize("form", FORMS)
+def test_sum_rows_is_the_float32_sum_and_take_rows_transpose(form, dtype):
+    """A token's rows summed (the 0/1 product `_sum_rows`, the gather
+    by the sort's inverse `_sum_held`) against numpy's float64 sum of
+    the values, rounded once: the pieces a float32 row is cut into lose
+    nothing, a row fetched is a row as it was, and each sum and its
+    take are each other's transposes."""
     rng = np.random.default_rng(2)
-    t, b, d = 40, 96, 24
-    token = jnp.asarray(np.sort(rng.integers(0, t, b)), jnp.int32)
+    t, k, b, d = 40, 3, 96, 24
+    order, inv, token = _sorted_assignments(rng, t, k, b)
     v = jnp.asarray(rng.standard_normal((b, d)) * 10.0 ** rng.integers(
         -3, 4, (b, 1)), dtype)
-    want = np.zeros((t, d))
+    want, size = np.zeros((t, d)), np.zeros((t, d))
     np.add.at(want, np.asarray(token), np.asarray(v, np.float64))
-    got = moe._sum_rows(v, token, t)
+    np.add.at(size, np.asarray(token), np.abs(np.asarray(v, np.float64)))
+    sum_rows, take_rows = _sums(form, v, order, inv, token, t, k)
+    got = sum_rows(v)
     assert got.dtype == dtype
-    np.testing.assert_allclose(
-        np.asarray(got, np.float64),
-        np.asarray(jnp.asarray(want, jnp.float32).astype(dtype), np.float64),
-        rtol=3e-7, atol=1e-30)
+    # one rounding of the sum to `dtype`, and float32's of the terms
+    # on the way (a bfloat16 result differs from numpy's by no more)
+    want = np.asarray(jnp.asarray(want, jnp.float32).astype(dtype),
+                      np.float64)
+    assert (np.abs(np.asarray(got, np.float64) - want)
+            <= 3e-7 * np.maximum(size, np.abs(want))).all()
     g = jnp.asarray(rng.standard_normal((t, d)), dtype)
     back = jax.grad(lambda v: jnp.sum(
-        (moe._sum_rows(v, token, t) * g).astype(jnp.float32)))(v)
+        (sum_rows(v) * g).astype(jnp.float32)))(v)
     assert back.dtype == dtype and (np.asarray(back, np.float32)
                                     == np.asarray(g[token], np.float32)).all()
     gx = jax.grad(lambda x: jnp.sum(
-        (moe._take_rows(x, token, t) * v).astype(jnp.float32)))(g)
+        (take_rows(x) * v).astype(jnp.float32)))(g)
     assert gx.dtype == dtype and (np.asarray(gx, np.float32)
                                   == np.asarray(got, np.float32)).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_the_weighted_sum_is_the_float32_sum_and_clears_what_is_not_held(
+        dtype):
+    """`_weigh_held` against numpy's float64 weighted sum rounded once,
+    with the rows past the held ones NOT finite under their weights of
+    0 (`held_share`'s) — the mask clears them where a product with 0
+    would not — and its two gradients against autodiff's of the plain
+    formula over the held rows."""
+    rng = np.random.default_rng(3)
+    t, k, b, d, held = 40, 3, 96, 24, 70
+    order, inv, token = _sorted_assignments(rng, t, k, b)
+    place = np.asarray(inv).reshape(t, k)
+    weights = jnp.asarray(np.where(place < held, rng.random((t, k)), 0.0),
+                          jnp.float32)
+    clean = rng.standard_normal((b, d)) * (np.arange(b) < held)[:, None]
+    out = jnp.asarray(np.where((np.arange(b) < held)[:, None], clean,
+                               np.tile([np.nan, np.inf], d // 2)), dtype)
+    clean = jnp.asarray(clean, dtype)
+
+    def plain(out, weights):
+        rows = out[jnp.minimum(inv, b - 1)].reshape(t, k, d)
+        return jnp.einsum("tkd,tk->td", rows.astype(jnp.float32), weights,
+                          precision="highest")
+
+    def ours(out, weights):
+        return moe._weigh_held(out, weights, order, inv, jnp.int32(held), b)
+
+    got = ours(out, weights)
+    assert got.dtype == dtype and bool(jnp.isfinite(
+        got.astype(jnp.float32)).all())
+    one_rounding = 4e-3 if dtype == jnp.bfloat16 else 1e-6
+    assert _gap(got, plain(clean, weights)) <= one_rounding
+    g = jnp.asarray(rng.standard_normal((t, d)), dtype)
+    for arg in (0, 1):
+        mine = jax.grad(lambda *a: jnp.sum(
+            ours(*a).astype(jnp.float32) * g), argnums=arg)(clean, weights)
+        auto = jax.grad(lambda *a: jnp.sum(
+            plain(*a) * g.astype(jnp.float32)), argnums=arg)(clean, weights)
+        if arg == 1:  # a place that is not held has no gradient
+            auto = jnp.where(place < held, auto, 0)
+        assert mine.dtype == auto.dtype
+        assert _gap(mine, auto) <= (one_rounding if arg == 0 else 1e-6), arg
+    # the rows that are not finite reach neither gradient of the held
+    dw = jax.grad(lambda w: jnp.sum(ours(out, w).astype(jnp.float32) * g))(
+        weights)
+    assert bool(jnp.isfinite(dw).all())
 
 
 @pytest.mark.parametrize("t,k", [(4096, 8), (128, 4), (96, 2), (1000, 3)])
@@ -441,6 +543,70 @@ def test_the_bound_of_the_cells():
     assert moe.held_rows_bound(4096, 8, 8, 256) == moe.SLACK * 1024
     assert moe.held_rows_bound(128, 4, 4, 16) == 512
     assert moe.held_rows_bound(4096, 8, 64, 64) == 4096 * 8
+
+
+#: the two accepted cells that bound a layer's rows: (t, k, experts
+#: held, experts routed, D), (the experts' width, activation, gated),
+#: and whether their per-token sums gather
+BOUNDED_CELLS = {
+    "nemotron-train-t8192": ((8192, 6, 16, 128, 2688),
+                             (1856, "relu2", False), True),
+    "glm5-train-t4096": ((4096, 8, 8, 256, 6144), (2048, "silu", True),
+                         False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(BOUNDED_CELLS))
+def test_the_row_sum_of_the_cells(cell):
+    """Which form each accepted cell's shapes take — nemotron's 24,576
+    rows of 49,152 the gather, GLM-5's 4,096 of 32,768 the product —
+    and that the choice reads shapes and the rows' type alone: it moves
+    with the bound, with k and with the type, not with t or D (both
+    forms grow with them alike)."""
+    (t, k, held, n, d), _, gathers = BOUNDED_CELLS[cell]
+    bound = moe.held_rows_bound(t, k, held, n)
+    assert moe.row_sum_gathers(t, k, bound, d, jnp.bfloat16) is gathers
+    for t2, d2 in ((t // 4, d), (t * 4, d), (t, 128), (t, 8 * d)):
+        assert moe.row_sum_gathers(t2, k, bound, d2, jnp.bfloat16) is gathers
+    # float32 rows: six passes over twice the bytes
+    assert moe.row_sum_gathers(t, k, bound, d, jnp.float32) is gathers
+    # the product's operations grow with the bound, the gather's bytes
+    # with k: far enough either way the other form takes over
+    assert moe.row_sum_gathers(t, k, 64 * bound, d, jnp.bfloat16)
+    assert not moe.row_sum_gathers(t, 64 * k, bound, d, jnp.bfloat16)
+    assert not moe.row_sum_gathers(t, k, moe._TM, d, jnp.bfloat16)
+
+
+def test_the_gathered_layer_lowers_without_a_scatter_or_a_t_by_bound_product(
+        pvar_clean):
+    """The bounded layer at nemotron-train-t8192's shapes, lowered on
+    abstract operands (no compile): the rule takes the gather, and no
+    `dot_general` has a ``[T, bound]`` operand and nothing scatters —
+    in the bounded branch and in the fallback, both directions."""
+    (t, k, held, n, d), (f, act, _), gathers = BOUNDED_CELLS[
+        "nemotron-train-t8192"]
+    bound = moe.held_rows_bound(t, k, held, n)
+    assert gathers and bound == 24576
+
+    def loss(x, weights, w1, w2, experts, counts, g):
+        route = moe.TopKRoute(experts, weights, counts, None, None)
+        y = moe.sorted_moe_ffn(x, route, w1, None, w2, act, bound)
+        return jnp.sum(y.astype(jnp.float32) * g)
+
+    arg = jax.ShapeDtypeStruct
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        arg((t, d), jnp.bfloat16), arg((t, k), jnp.float32),
+        arg((held, d, f), jnp.bfloat16), arg((held, f, d), jnp.bfloat16),
+        arg((t, k), jnp.int32), arg((held,), jnp.int32),
+        arg((t, d), jnp.float32)).as_text()
+    assert (pvar.read("moe_bounded_layers"),
+            pvar.read("moe_row_sum_gather_layers"),
+            pvar.read("moe_row_sum_product_layers")) == (1, 1, 0)
+    assert "stablehlo.gather" in text and "stablehlo.case" in text
+    assert "scatter" not in text
+    products = [line for line in text.splitlines() if "dot_general" in line]
+    assert products  # the fallback's dense ragged_dot on this backend
+    assert not [p for p in products if f"tensor<{t}x{bound}x" in p]
 
 
 @pytest.mark.parametrize("leaf,k,n", [("w1_w3", CELL_D, CELL_F),
@@ -510,24 +676,32 @@ def test_the_cells_step_compiles_for_the_chip_on_the_kernels(one_chip,
                        + ["moe_tgmm"] * 3), calls
 
 
-def test_the_bounded_layer_compiles_for_the_chip(one_chip, monkeypatch,
+@pytest.mark.parametrize("cell", sorted(BOUNDED_CELLS))
+def test_the_bounded_layer_compiles_for_the_chip(cell, one_chip, monkeypatch,
                                                  pvar_clean):
-    """glm5-train-t4096's expert layer (8 of 256 experts held, 4,096
-    tokens x 8, the published widths) for a described v5e, the rule
+    """The expert layer of the two cells that bound their rows
+    (glm5-train-t4096: 8 of 256 experts held, 4,096 tokens x 8;
+    nemotron-train-t8192: 16 of 128, 8,192 x 6, a width the lanes do
+    not divide; the published widths) for a described v5e, the rules
     asked as on the TPU: one conditional a direction; the bounded
-    branch's twelve products are the kernels on 4,096 rows, the
-    fallback's are ragged-dot instructions and no kernel; nothing of
-    the bounded branch has 32,768 rows."""
-    t, k, d, f, held, n = 4096, 8, 6144, 2048, 8, 256
-    rule = moe.grouped_tiles
+    branch's products are the kernels on `bound` rows, the fallback's
+    are ragged-dot instructions and no kernel; no kernel has T * k
+    rows. Where the rule gathers, nothing is ``[T, bound]`` and no
+    conditional hands on float32 rows of the size of T * k (XLA moved
+    the final sum out of both branches until a barrier kept it in)."""
+    (t, k, held, n, d), (f, act, gated), gathers = BOUNDED_CELLS[cell]
+    rule, pad = moe.grouped_tiles, moe.expert_width_pad
     monkeypatch.setattr(moe, "grouped_tiles",
                         lambda backend, *a: rule("tpu", *a))
+    monkeypatch.setattr(moe, "expert_width_pad",
+                        lambda backend, width: pad("tpu", width))
     bound = moe.held_rows_bound(t, k, held, n)
-    assert bound == 4096
+    assert bound == (t * k // 2 if gathers else 4096)
 
     def loss(x, logits, w1, w3, w2, g):
         route = moe.held_share(moe.sigmoid_routing(logits, None, k), 0, held)
-        y = moe.sorted_moe_ffn(x, route, w1, w3, w2, "silu", bound)
+        y = moe.sorted_moe_ffn(x, route, w1, w3 if gated else None, w2, act,
+                               bound)
         return jnp.sum(y.astype(jnp.float32) * g)
 
     def arg(shape, dtype=jnp.bfloat16):
@@ -540,16 +714,29 @@ def test_the_bounded_layer_compiles_for_the_chip(one_chip, monkeypatch,
         arg((t, d), jnp.float32)).compile()
     assert (pvar.read("moe_bounded_layers"),
             pvar.read("moe_grouped_kernel_layers")) == (1, 1)
+    assert (pvar.read("moe_row_sum_gather_layers"),
+            pvar.read("moe_row_sum_product_layers")) == (
+                (1, 0) if gathers else (0, 1))
     text = compiled.as_text()
-    assert text.count(" conditional(") == 2
+    conditionals = [line for line in text.splitlines()
+                    if " conditional(" in line]
+    assert len(conditionals) == 2
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     names = [c.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
              for c in calls]
+    per = 3 if gated else 2  # products a direction: w1, (w3,) w2
     assert sorted(n for n in names if n.startswith("moe_")) == (
-        ["moe_gmm"] * 6 + ["moe_gmm_nt"] * 3 + ["moe_tgmm"] * 3), names
+        ["moe_gmm"] * 2 * per + ["moe_gmm_nt"] * per + ["moe_tgmm"] * per
+    ), names
     # libtpu's own kernels (PR 26's) are the fallback's, all of them
     assert all(n.startswith(("moe_", "ragged-dot")) for n in names), names
     for name, call in zip(names, calls):
         if name.startswith("moe_"):
             assert f"[{t * k}," not in call.split("custom_call_target")[0]
+    assert (f"[{t},{bound}]" in text) is not gathers
+    if gathers:
+        for line in conditionals:
+            result = line.split(" conditional(")[0]
+            assert not re.search(
+                rf"f32\[({t * k}|{t},{k}|{k},{t}),{d}\]", result), result
