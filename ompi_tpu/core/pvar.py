@@ -111,6 +111,10 @@ WELL_KNOWN = (
     # the norm of the first state-space layer's state after the last
     # token, in millionths
     "ssm_layers", "ssm_chunks", "attn_gqa_layers", "ssm_state_norm_micro",
+    # ops/ssm.mixer, once per TRACED call: its scan runs on the Pallas
+    # kernels of ops/ssm_scan.py, or as jax.numpy's batched products
+    # (the rule ops/ssm.scan_tile)
+    "ssm_scan_kernel_layers", "ssm_scan_product_layers",
     # the phases of mpi.Init(), once per job (runtime/state.py,
     # runtime/device_plane.py; "import" also holds the import of
     # ompi_tpu.mpi itself): they end before any profiler session can

@@ -114,8 +114,10 @@ of ``vision`` tells them apart. A layer of a pattern is
 ssm_gate_norm}`` (``ssm_proj``: both products), ``layer_<i>/{ln,
 attn_proj, attn_core}`` or ``layer_<i>/{ln, mlp}`` with the ``moe_*``
 names above. Counted once per traced layer: ``ssm_layers``,
-``ssm_chunks`` (chunks a layer), ``attn_gqa_layers``; the probe
-:func:`ssm_probe` counts ``ssm_state_norm_micro``.
+``ssm_chunks`` (chunks a layer), ``attn_gqa_layers`` and, by
+``ops/ssm.mixer`` for the form its scan took, ``ssm_scan_kernel_layers``
+/ ``ssm_scan_product_layers``; the probe :func:`ssm_probe` counts
+``ssm_state_norm_micro``.
 """
 
 from __future__ import annotations
@@ -1241,9 +1243,12 @@ def _mixer_costs(cfg: Config, n: int, t: int, kind: str, it: int):
     of a pattern over `n` tokens in sequences of `t`. A state-space
     layer makes ops/ssm.py's three names (kept, the scan's output
     spares the two products that make it, not those its own backward
-    pass reads); the attention layer attention's two, with the key
-    heads as the kernel reads them: repeated; an expert layer the
-    shared expert's up-projection."""
+    pass reads: on the kernels of ops/ssm_scan.py that is the whole
+    forward kernel, whose backward keeps its operands alone and makes
+    the states entering the chunks again in a sweep of its own — the
+    numbers stand for both forms); the attention layer attention's two,
+    with the key heads as the kernel reads them: repeated; an expert
+    layer the shared expert's up-projection."""
     d = cfg.d_model
     if kind == SSM:
         from ompi_tpu.ops import ssm

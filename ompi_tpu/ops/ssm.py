@@ -19,7 +19,24 @@ width.
 
 :func:`chunked_scan` computes the recurrence in chunks of L tokens
 (the "state-space duality" form of the Mamba-2 paper, arXiv:2405.21060
-section 6) as FOUR batched products and no ``while``:
+section 6) and no ``while``, in one of two forms chosen by the static
+rule :func:`scan_tile` from the backend and the shapes alone:
+
+**On the TPU, the kernels of** ``ops/ssm_scan.py`` (:func:`kernel_scan`):
+a chunk's decays, scores and mixing matrix stay in VMEM, the state is
+carried along the grid's chunk axis, x, B and C are read as they lie in
+the convolved ``xBC`` — with the SEQUENCE in the lanes, which is how XLA
+lays the mixer's arrays out by itself, so nothing around the kernels is
+laid out anew — and the ``D x`` term is the kernel's epilogue. Their
+gradient is a ``custom_vjp`` of ``(xBC, dt, cum, D)`` that keeps
+its inputs and nothing else: a states-only sweep and one reverse kernel
+(``A_log``, ``dt_bias`` and the softplus keep autodiff's gradient
+through ``dt`` and ``cum``).
+
+**Everywhere else** (the CPU, a rehearsal, a shape the kernels do not
+take) **and as the kernels' oracle, the** ``jax.numpy`` **form**: FOUR
+batched products and ONE between chunk ends, the backward pass
+autodiff's of them —
 
 1. inside a chunk, ``y[l] += sum_{s <= l} exp(a[s+1..l]) (C_l . B_s)
    dt_s x_s`` — the ``[L, L]`` causal matrix of decays times ``C B^T``,
@@ -29,21 +46,24 @@ section 6) as FOUR batched products and no ``while``:
 3. the states carried from chunk to chunk: ONE product with the
    ``[chunks + 1, chunks]`` matrix of decays between chunk ends — what
    a ``lax.scan`` over the chunks would do one step at a time (and what
-   a device trace could not see: a ``while`` event carries no op path);
-   its last row is the state after the last token;
+   a device trace could not see: a ``while`` event carries no op path;
+   the kernels' grid is one custom call with the scope's path); its
+   last row is the state after the last token;
 4. the carried state read out through ``C`` with the decay from the
    chunk's start.
 
-``a = dt A`` and its cumulative sums, and every exponential, are
-float32; a decay's exponent is a DIFFERENCE of cumulative sums, masked
-to the causal half BEFORE the exponential (never a quotient of two
-exponentials, never ``exp`` of a positive number). The products take
-operands in the activations' type with float32 accumulation, but the
-chunk-to-chunk product, which is float32 at the highest precision (64
-x 64 a head at 8,192 tokens: nothing). Nothing of ``[T, T]`` or of a
-state per TOKEN exists: the largest arrays are the decays ``[B, chunks,
-H, L, L]`` and the states ``[B, chunks, H, P, N]`` in float32. The
-backward pass is autodiff's of this form.
+In BOTH forms ``a = dt A`` and its cumulative sums, and every
+exponential, are float32; a decay's exponent is a DIFFERENCE of
+cumulative sums, masked to the causal half BEFORE the exponential
+(never a quotient of two exponentials, never ``exp`` of a positive
+number). The products take operands in the activations' type with
+float32 accumulation (the ``jax.numpy`` form's chunk-to-chunk product,
+which the kernels' carry replaces, is float32 at the highest
+precision). Nothing of ``[T, T]`` or of a state per TOKEN exists. In
+the ``jax.numpy`` form the largest arrays are the decays ``[B, chunks,
+H, L, L]`` and the states ``[B, chunks, H, P, N]`` in float32, in HBM;
+the kernels' largest is the backward's entering states, the same ``[B,
+chunks, H, P, N]``, written once and read once.
 
 What a recomputed layer may keep (``jax.ad_checkpoint.checkpoint_name``,
 chosen by ``models/transformer.py``'s rule): :data:`SSM_IN` — the first
@@ -54,10 +74,14 @@ the gate.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+
+from ompi_tpu.core import pvar
 
 SSM_IN = "ssm_in"
 SSM_CONV = "ssm_conv"
@@ -147,6 +171,126 @@ def chunked_scan(x, dt, a, bm, cm, chunk: int):
     return y.astype(dtype), entering[:, -1].reshape(b, h, p, n)
 
 
+#: the lanes of a VMEM tile, and what a grid step's working set may
+#: take of VMEM (of the kernels' limit of 96 MiB: the blocks twice, the
+#: carried states and a head's [L, L] float32 temporaries; the cell's
+#: shapes take 4 MiB)
+LANES = 128
+_SCAN_VMEM_BYTES = 48 * 1024 * 1024
+
+
+def scan_tile(backend: str, t: int, heads: int, head_dim: int, groups: int,
+              state: int, chunk: int, dtype):
+    """The rule that sends the scan to the repo's own kernels
+    (ops/ssm_scan.py), made of what the call can observe: their sizes
+    (an ``ssm_scan.Dims``), or None — off the TPU, a sequence the chunk
+    does not divide, a chunk or a state the lanes do not divide, a head
+    that is no whole number of the type's sublane tiles (or B's first
+    row not a whole number of states), a chunk's working set over the
+    VMEM budget."""
+    size = jnp.dtype(dtype).itemsize
+    if (backend != "tpu" or t % chunk or heads % groups or chunk % LANES
+            or state % LANES or head_dim % (32 // size)
+            or heads * head_dim % state):
+        return None
+    width = heads // groups * head_dim
+    blocks = 2 * chunk * (3 * width + 4 * state) * size
+    carried = 5 * width * state * 4 + 2 * width * chunk * size
+    decays = 10 * chunk * chunk * 4
+    if blocks + carried + decays > _SCAN_VMEM_BYTES:
+        return None
+    from ompi_tpu.ops import ssm_scan  # Pallas: only where it will run
+
+    return ssm_scan.Dims(heads, head_dim, groups, state, chunk)
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_kernels(dims, interpret: bool):
+    """The scan kernels for one set of sizes as a function (xbc [B, H P
+    + 2 G N, T], dt, cum [B, T, H] float32, d [H] float32) -> (y [B, H
+    P, T], last [B, H, P, N] float32): `ssm_scan.forward`, and behind a
+    ``custom_vjp`` that keeps the four operands `ssm_scan.states` and
+    `ssm_scan.backward`. The per-head vectors' two layouts are made and
+    added up here."""
+    from ompi_tpu.ops import ssm_scan as sk
+
+    on = dict(dims=dims, interpret=interpret)
+    g, per = dims.groups, dims.per
+
+    def rows(v):     # [B, T, H] -> [B, G, heads a group, T]
+        return jnp.swapaxes(v, 1, 2).reshape(v.shape[0], g, per, v.shape[1])
+
+    def cols(v):     # [B, T, H] -> [B, G, T, heads a group]
+        return v.reshape(*v.shape[:2], g, per).transpose(0, 2, 1, 3)
+
+    def run(xbc, dt, cum, d):
+        y, last = sk.forward(xbc, rows(dt), rows(cum), cols(dt), cols(cum),
+                             d, **on)
+        return y, last.reshape(xbc.shape[0], dims.heads, dims.head_dim,
+                               dims.state)
+
+    scan = jax.custom_vjp(run)
+
+    def bwd(res, cts):
+        xbc, dt, cum, d = res
+        dy, dlast = cts
+        b, t = dt.shape[:2]
+        dt_r, cum_r = rows(dt), rows(cum)
+        dx, dbm, dcm, ddt_r, dcum_r, ddt_c, dcum_c, dd = sk.backward(
+            xbc, dy, dlast.reshape(b, dims.inner, dims.state),
+            sk.states(xbc, dt_r, cum_r, **on), dt_r, cum_r, cols(dt),
+            cols(cum), d, **on)
+
+        def back(r, c):  # the two layouts' parts -> [B, T, H]
+            return (jnp.swapaxes(r.reshape(b, dims.heads, t), 1, 2)
+                    + c.transpose(0, 2, 1, 3).reshape(b, t, dims.heads))
+
+        return (jnp.concatenate([dx, dbm, dcm], axis=1), back(ddt_r, ddt_c),
+                back(dcum_r, dcum_c),
+                dd.reshape(b, dims.heads, -1).sum((0, 2)))
+
+    scan.defvjp(lambda *a: (run(*a), a), bwd)
+    return scan
+
+
+def kernel_scan(xbc, dt, a, d, dims, interpret: bool = False):
+    """:func:`chunked_scan` WITH its ``D x`` term on the kernels, the
+    wide operands with the sequence LAST: xbc [B, H P + 2 G N, T], the
+    convolved ``[x | B | C]`` whole; dt [B, T, H] float32, positive; a,
+    d [H] float32 -> (y [B, H P, T] in xbc's type, the state after the
+    last token [B, H, P, N] float32). `dims`: :func:`scan_tile`'s. The
+    cumulative log-decay inside each chunk is made here, by XLA."""
+    b, t, h = dt.shape
+    cum = jnp.cumsum((dt * a).reshape(b, t // dims.chunk, dims.chunk, h),
+                     axis=2).reshape(b, t, h)
+    return _scan_kernels(dims, interpret)(xbc, dt, cum, d)
+
+
+def _scan_by_products(lp, xbc, dt, *, heads, head_dim, groups, state, chunk):
+    """The mixer's scan in the ``jax.numpy`` form (PR 39's lines in PR
+    39's order: a step that takes it lowers to the text it had)."""
+    b, t, _ = xbc.shape
+    inner, bc = heads * head_dim, groups * state
+    with jax.named_scope("ssm_scan"):
+        xs = xbc[..., :inner].reshape(b, t, heads, head_dim)
+        bm = xbc[..., inner:inner + bc].reshape(b, t, groups, state)
+        cm = xbc[..., inner + bc:].reshape(b, t, groups, state)
+        dt = jax.nn.softplus(dt.astype(F32) + lp["dt_bias"].astype(F32))
+        y, last = chunked_scan(xs, dt, -jnp.exp(lp["A_log"].astype(F32)),
+                               bm, cm, chunk)
+        y = y.astype(F32) + lp["D"].astype(F32)[:, None] * xs.astype(F32)
+        return y.astype(xbc.dtype).reshape(b, t, inner), last
+
+
+def _scan_by_kernels(lp, xbc, dt, dims):
+    """The mixer's scan on the kernels; xbc [B, H P + 2 G N, T] and the
+    result [B, H P, T]: the sequence last."""
+    with jax.named_scope("ssm_scan"):
+        dt = jax.nn.softplus(dt.astype(F32) + lp["dt_bias"].astype(F32))
+        return kernel_scan(xbc, dt, -jnp.exp(lp["A_log"].astype(F32)),
+                           lp["D"].astype(F32), dims)
+
+
 def mixer(lp, x, *, heads: int, head_dim: int, groups: int, state: int,
           chunk: int, eps: float):
     """The Mamba-2 mixer of the normed x [B, T, d] -> ([B, T, d] in
@@ -156,27 +300,37 @@ def mixer(lp, x, *, heads: int, head_dim: int, groups: int, state: int,
     [d, 2 * H * P + 2 * G * N + H] (columns ``[z | x B C | dt]``),
     ``conv_w`` [H * P + 2 * G * N, K], ``conv_b``, ``A_log``, ``D``,
     ``dt_bias`` [H], ``ssm_norm`` {"g": [H * P]}, ``out_proj`` [H * P,
-    d]."""
+    d]. Counted once per traced call: ``ssm_scan_kernel_layers`` /
+    ``ssm_scan_product_layers``, the scan's form by :func:`scan_tile`."""
     dt_ = x.dtype
-    b, t, _ = x.shape
+    sizes = dict(heads=heads, head_dim=head_dim, groups=groups, state=state,
+                 chunk=chunk)
     inner, bc = heads * head_dim, groups * state
     with jax.named_scope("ssm_proj"):
         zxd = checkpoint_name(x @ lp["in_proj"].astype(dt_), SSM_IN)
         z, xbc, dt = (zxd[..., :inner], zxd[..., inner:2 * inner + 2 * bc],
                       zxd[..., 2 * inner + 2 * bc:])
+    dims = scan_tile(jax.default_backend(), x.shape[1], dtype=dt_, **sizes)
+    pvar.record("ssm_scan_product_layers" if dims is None
+                else "ssm_scan_kernel_layers")
+    # The kernels take the sequence LAST, as XLA lays these arrays out by
+    # itself: the two ``swapaxes`` are bitcasts. Each stands in the scope
+    # of the operation it is fused with (XLA names a fusion for its last
+    # operation: in the scan's scope they would book the convolution and
+    # the gate's backward pass to the scan), and the convolution's in
+    # front of its name: XLA makes a value that is kept under one shape
+    # and read under another TWICE (1.24 ms a layer on the chip).
     with jax.named_scope("ssm_conv"):
-        xbc = checkpoint_name(
-            causal_conv(xbc, lp["conv_w"], lp["conv_b"]), SSM_CONV)
-    with jax.named_scope("ssm_scan"):
-        xs = xbc[..., :inner].reshape(b, t, heads, head_dim)
-        bm = xbc[..., inner:inner + bc].reshape(b, t, groups, state)
-        cm = xbc[..., inner + bc:].reshape(b, t, groups, state)
-        dt = jax.nn.softplus(dt.astype(F32) + lp["dt_bias"].astype(F32))
-        y, last = chunked_scan(xs, dt, -jnp.exp(lp["A_log"].astype(F32)),
-                               bm, cm, chunk)
-        y = y.astype(F32) + lp["D"].astype(F32)[:, None] * xs.astype(F32)
-        y = checkpoint_name(y.astype(dt_).reshape(b, t, inner), SSM_Y)
+        xbc = causal_conv(xbc, lp["conv_w"], lp["conv_b"])
+        if dims is not None:
+            xbc = jnp.swapaxes(xbc, 1, 2)
+        xbc = checkpoint_name(xbc, SSM_CONV)
+    y, last = (_scan_by_products(lp, xbc, dt, **sizes) if dims is None
+               else _scan_by_kernels(lp, xbc, dt, dims))
     with jax.named_scope("ssm_gate_norm"):
-        y = gated_group_norm(y, z, lp["ssm_norm"]["g"], groups, eps)
+        if dims is not None:
+            y = jnp.swapaxes(y, 1, 2)
+        y = gated_group_norm(checkpoint_name(y, SSM_Y), z,
+                             lp["ssm_norm"]["g"], groups, eps)
     with jax.named_scope("ssm_proj"):
         return y @ lp["out_proj"].astype(dt_), last
