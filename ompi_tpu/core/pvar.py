@@ -96,7 +96,7 @@ WELL_KNOWN = (
     # read (the summed probability of exit s rides the dynamic name
     # exit_mass_micro_p<s>, in millionths of a token)
     "loop_passes", "loop_layer_applications", "exit_probe_tokens",
-    # models/transformer._run_layer, once per TRACED application of a
+    # models/remat.Recomputed, once per TRACED application of a
     # recomputed layer (Config.remat): its backward pass is given what
     # the application made under the names the rule remat_keep chose,
     # or recomputes it whole from its input (no name fits, or the
@@ -360,7 +360,7 @@ class captured:
     dict it yields and not to the counters. For code that runs once
     where the work it counts happens many times (a function jax traces
     once and calls per layer): its owner records the captured counts
-    itself, once per call (models/transformer._run_layer)."""
+    itself, once per call (models/remat.Recomputed)."""
 
     def __enter__(self) -> Dict[str, int]:
         global _capturing

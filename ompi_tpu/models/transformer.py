@@ -20,66 +20,40 @@ All axes are optional (None = that strategy off), so the same code runs
 single-device (``entry()``) and on any mesh factorization. bfloat16
 activations by default — MXU-native.
 
-The block is a DESCRIPTION, read from a model's published config, not a
-fork per model: :class:`Config` says which norm (LayerNorm / RMSNorm),
-which positions (learned / RoPE), QK-norm or not, dense or
-mixture-of-experts FFN (top-k, gated or not, which activation), tied
-or untied head, and the router's auxiliary-loss weights; there is ONE
-:func:`layer_forward`. The defaults are OPT's (pre-LN, ReLU MLP,
-learned positions, tied head: reference
-``benchmark/reference/opt_decoder.py``); OLMoE-1B-7B (RMSNorm, QK-norm
-over the whole projection, RoPE, top-8 of 64 SiLU-gated experts
-without renormalisation, untied head, load-balancing and router-z
-losses) is the second published model it computes (reference
-``benchmark/reference/olmoe_decoder.py``). Without an ``ep`` axis a
-MoE layer takes the drop-free sorted path of :mod:`ompi_tpu.ops.moe`.
-GLM-5 is the third (reference ``benchmark/reference/glm5_decoder.py``):
-attention of another KIND (``attn="mla"``: latent attention, with the
-learned sparse-attention indexer of :mod:`ompi_tpu.ops.attention`
-where ``index_topk`` is set), layers of three kinds by INDEX
-(``first_dense`` leading dense layers of width ``d_ff``, then expert
-layers of width ``moe_d_ff``, then ``mtp_layers`` multi-token-prediction
-modules with a second loss), a sigmoid ``noaux_tc`` router over all
-``n_experts`` of which this chip holds ``held_experts``, a shared
-expert, and ``remat``: each layer application recomputed in the
-backward pass — from its input and from what the static rule
-:func:`remat_keep` lets it keep of its own forward pass (attention's
-output and log-sum-exp, the sub-layers' outputs, q / k / v, ...: named
-values, the one that spares most operations per byte first, while the
-reckoned peak stays under a share of the device's memory limit;
-nothing where no limit is stated, which is the whole recomputation of
-before).
-The fourth (reference ``benchmark/reference/ouro_decoder.py``) runs
-its layers more than once: ``loops`` passes over the ONE layer list
-with the same weights, the final norm between passes, a norm on every
-sub-layer's output too (``post_norm``), and after each pass an exit —
-the shared head's loss and a learned gate (``exit_gate``); the loss is
-the expected loss under the exit distribution the gates give, less
-``exit_entropy_weight`` times that distribution's entropy.
-The fifth (reference ``benchmark/reference/kimivl_decoder.py``) reads
-images: a second TOWER (``vision``: a :mod:`ompi_tpu.models.vision`
-``VisionConfig``; a native-resolution ViT over images packed back to
-back in one row of patches, attention both ways inside an image and
-not across images, and a projector) whose merged rows replace the
-embedding's rows at the image positions, in front of a latent-attention
-decoder WITHOUT a query latent (``q_lora_rank`` 0: q is one product of
-x) whose heads are 192 wide against values of 128. A batch is then a
-dict — today's ``tokens`` array and the packed images' leaves beside
-it — and the loss runs over the positions whose label is not -1 (the
-text). The tower's blocks are a third layer kind (`VIT`) of the
-recomputation rule.
-The sixth (reference ``benchmark/reference/nemotron_decoder.py``) has
-layers that are ONE pre-norm and ONE mixer, by a published pattern
-string (``layer_pattern``, one letter a layer): `SSM` ``M``, a
-Mamba-2 state-space mixer (:mod:`ompi_tpu.ops.ssm`: a chunked scan
-with no loop in the step; imported only where the pattern has an
-``M``); `EXPERTS` ``E``, the mixture of experts of the third model
-without a gate matrix (``relu2``: ``relu(x W1)^2 W2``) and with a
-shared expert of a width of its own (``shared_d_ff``); `ATTENTION`
-``*``, grouped-query attention (``n_kv_heads`` key heads shared by
-``n_heads`` query heads of ``head_width``) with no positions at all
-(``pos="none"``: the state-space layers carry the order). Each is
-``h + mixer(norm(h))`` and a layer kind of the recomputation rule.
+The model is a DESCRIPTION, read from a published config, not a fork
+per model: :class:`Config` says which norm, positions, attention and
+feed-forward part, how many passes and exits, whether a tower stands in
+front; the defaults are OPT's (pre-LN, ReLU MLP, learned positions,
+tied head). Six published configurations run through it, each against
+a float32 reference of its own under ``benchmark/reference/``: OPT,
+OLMoE, GLM-5, Ouro, Kimi-VL, Nemotron-3-Nano. What the module holds:
+
+- :class:`Config`, :class:`Axes`, `_check_supported`: what a config
+  asks for that an axis cannot give yet is an error, never another
+  function computed in silence.
+- A LAYER is a short list of sub-layers, each ``h + [norm](mixer(
+  norm(h)))`` applied by ONE function (`_sublayer`); `layer_forward`
+  walks the list models/params.py's ``layout`` gives for the layer's
+  kind (that module also says which layer is of which kind and
+  describes the parameter tree). The block is attention then a dense
+  FFN or the experts, by the layer's INDEX (``moe_every``,
+  ``first_dense``); a layer of a ``layer_pattern`` is ONE mixer, by its
+  letter (`SSM` ``M``, `EXPERTS` ``E``, `ATTENTION` ``*``).
+- The MIXERS, five, each with what it costs the recomputation rule
+  (`_COSTS`): multi-head attention (`_attention`: shared key heads, a
+  head width of its own, positions learned / RoPE / none, QK-norm, tp,
+  sp); latent attention (`_mla_attention`, with the sparse-attention
+  indexer where ``index_topk`` is set); the dense FFN; the experts
+  (`_experts`: sorted path, held share, shared expert, ep); the
+  Mamba-2 state-space mixer (ops/ssm.py, imported only where a pattern
+  has an ``M``).
+- ``remat``: each layer application runs as models/remat.py's
+  ``Recomputed`` and keeps what that module's rule chooses from the
+  costs summed over the layout (`layer_costs`, `step_costs`);
+  nothing where no memory limit is stated (the CPU).
+- The trunk (`_trunk`: the embedding, a vision tower's rows at the
+  image positions, ``loops`` passes over the ONE layer list), the head,
+  the losses, the set-up probes and the step (`make_train_step`).
 
 Names on the device (``jax.named_scope``: metadata, the HLO is the
 same): the jitted step is module ``jit_ompi_train_step``; its ops carry
@@ -113,7 +87,10 @@ of ``vision`` tells them apart. A layer of a pattern is
 ``layer_<i>/{ln, ssm}`` with ``ssm/{ssm_proj, ssm_conv, ssm_scan,
 ssm_gate_norm}`` (``ssm_proj``: both products), ``layer_<i>/{ln,
 attn_proj, attn_core}`` or ``layer_<i>/{ln, mlp}`` with the ``moe_*``
-names above. Counted once per traced layer: ``ssm_layers``,
+names above. A sub-layer's residual add lies under the scopes its
+layout row names (the block's under its mixer's; a pattern's experts'
+under ``mlp``, its other two mixers' at ``layer_<i>``'s own level).
+Counted once per traced layer: ``ssm_layers``,
 ``ssm_chunks`` (chunks a layer), ``attn_gqa_layers`` and, by
 ``ops/ssm.mixer`` for the form its scan took, ``ssm_scan_kernel_layers``
 / ``ssm_scan_product_layers``; the probe :func:`ssm_probe` counts
@@ -124,8 +101,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -134,6 +110,12 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ompi_tpu.core import pvar
+from ompi_tpu.models import remat, vision
+from ompi_tpu.models.params import (  # noqa: F401 (the model's own names)
+    ATTENTION, EXPERTS, SSM, _check_indexer, _check_pattern, _is_moe,
+    _layer_kind, grad_extra_axes, init_params, layout, param_specs)
+from ompi_tpu.models.remat import (  # noqa: F401
+    ATTN_PROJ_OUT, DSA_SELECT, MLA_LATENTS, MLP_OUT, MLP_UP, REMAT_SHARE)
 from ompi_tpu.ops import attention as att
 from ompi_tpu.ops import moe as moe_mod
 from ompi_tpu.ops.ring_attention import ring_attention
@@ -168,7 +150,7 @@ class Config:
     norm_eps: float = 1e-5
     #: "learned" (a table of max_seq rows, params["pos"]), "rope"
     #: (rotate-half pairing of dimensions i and i + head_dim / 2) or
-    #: "none" (no table, no rotation: layers of a pattern only)
+    #: "none" (no table, no rotation)
     pos: str = "learned"
     rope_theta: float = 10000.0
     #: RMSNorm of q and k over the WHOLE projection (width d_model),
@@ -281,11 +263,11 @@ class Config:
     #: a head's width where it is not d_model / n_heads (0), and the
     #: key / value heads where n_heads query heads share fewer (0: as
     #: many): query head i attends with key head i // (n_heads /
-    #: n_kv_heads). Layers of a pattern only
+    #: n_kv_heads)
     head_width: int = 0
     n_kv_heads: int = 0
     #: the shared expert's width where it is not n_shared_experts x
-    #: the experts' (0). Layers of a pattern only
+    #: the experts' (0)
     shared_d_ff: int = 0
     #: a Mamba-2 mixer's sizes (ops/ssm.py): heads of ssm_head_dim,
     #: groups of B and C of ssm_state numbers each, the convolution's
@@ -335,297 +317,6 @@ class Axes:
         them; the tp axis is handled by the region_enter/exit AD
         boundary instead (Megatron f/g), never by grad psum."""
         return tuple(a for a in (self.dp, self.sp, self.ep) if a)
-
-
-def _is_moe(cfg: Config, layer: int) -> bool:
-    if cfg.first_dense is not None:
-        return layer >= cfg.first_dense
-    return cfg.moe_every > 0 and (layer + 1) % cfg.moe_every == 0
-
-
-#: the letters of `Config.layer_pattern`: a layer's kind beside the
-#: block's two (False: attention then a dense FFN, True: attention
-#: then a mixture of experts)
-SSM, EXPERTS, ATTENTION = "M", "E", "*"
-
-
-def _layer_kind(cfg: Config, layer: int):
-    """What layer `layer` is: its letter where the config has a
-    pattern, else whether the block's feed-forward part is a mixture
-    of experts."""
-    if cfg.layer_pattern is None:
-        return _is_moe(cfg, layer)
-    _check_pattern(cfg)
-    return cfg.layer_pattern[layer]
-
-
-def _check_pattern(cfg: Config):
-    pattern = cfg.layer_pattern
-    if len(pattern) != cfg.n_layers or set(pattern) - {SSM, EXPERTS,
-                                                       ATTENTION}:
-        raise ValueError(
-            f"layer_pattern={pattern!r}: expected n_layers = "
-            f"{cfg.n_layers} letters of {SSM!r} (a Mamba-2 mixer), "
-            f"{EXPERTS!r} (experts) and {ATTENTION!r} (attention); a "
-            "dense FFN alone ('-') is not written")
-
-
-def _held_count(cfg: Config) -> int:
-    return cfg.held_experts[1] if cfg.held_experts else cfg.n_experts
-
-
-def init_params(rng: np.random.Generator, cfg: Config) -> Dict:
-    """Full (unsharded) parameters, host-side numpy. Sharding happens at
-    the jit boundary via param_specs (the driver of HtoD layout)."""
-    pdt = np.dtype(cfg.param_dtype)
-
-    def normal(*shape, scale):
-        return np.asarray(rng.standard_normal(shape) * scale,
-                          dtype=pdt)
-
-    d, f, v = cfg.d_model, cfg.d_ff, cfg.vocab
-    s_emb = 1.0 / math.sqrt(d)
-
-    def norm():
-        gain = {"g": np.ones(d, pdt)}
-        return gain if cfg.norm == "rmsnorm" else dict(
-            gain, b=np.zeros(d, pdt))
-
-    params: Dict = {"embed": normal(v, d, scale=s_emb)}
-    if cfg.pos == "learned":
-        params["pos"] = normal(cfg.max_seq, d, scale=0.02)
-    if not cfg.tie_head:
-        params["head"] = normal(v, d, scale=s_emb)
-    params["ln_f"] = norm()
-    if cfg.exit_gate:
-        params["exit_gate"] = {"w": normal(d, scale=s_emb),
-                               "b": np.zeros(1, pdt)}
-
-    def gain(n):
-        return {"g": np.ones(n, pdt)}
-
-    def mla():
-        _check_indexer(cfg)
-        h, rq, rkv = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
-        qk = cfg.qk_nope_dim + cfg.qk_rope_dim
-        lp = {"wq": normal(d, h * qk, scale=s_emb)} if not rq else {
-            "wq_a": normal(d, rq, scale=s_emb), "q_a_norm": gain(rq),
-            "wq_b": normal(rq, h * qk, scale=1.0 / math.sqrt(rq))}
-        lp.update({
-            "wkv_a": normal(d, rkv + cfg.qk_rope_dim, scale=s_emb),
-            "kv_a_norm": gain(rkv),
-            "wkv_b": normal(rkv, h * (cfg.qk_nope_dim + cfg.v_head_dim),
-                            scale=1.0 / math.sqrt(rkv)),
-            "wo": normal(h * cfg.v_head_dim, d,
-                         scale=1.0 / math.sqrt(h * cfg.v_head_dim)
-                         / math.sqrt(2 * cfg.n_layers)),
-        })
-        if cfg.index_topk:
-            lp.update(
-                wi_q=normal(rq, cfg.index_heads * cfg.index_dim,
-                            scale=1.0 / math.sqrt(rq)),
-                wi_k=normal(d, cfg.index_dim, scale=s_emb),
-                wi_k_norm={"g": np.ones(cfg.index_dim, pdt),
-                           "b": np.zeros(cfg.index_dim, pdt)},
-                wi_w=normal(d, cfg.index_heads, scale=s_emb))
-        return lp
-
-    def layer(moe: bool):
-        lp = {"ln1": norm(), "ln2": norm()}
-        if cfg.post_norm:
-            lp.update(ln1_post=norm(), ln2_post=norm())
-        if cfg.attn == "mla":
-            lp.update(mla())
-        else:
-            lp.update(
-                wq=normal(d, d, scale=s_emb), wk=normal(d, d, scale=s_emb),
-                wv=normal(d, d, scale=s_emb),
-                wo=normal(d, d, scale=s_emb / math.sqrt(2 * cfg.n_layers)))
-        if cfg.qk_norm:
-            lp["q_norm"] = {"g": np.ones(d, pdt)}
-            lp["k_norm"] = {"g": np.ones(d, pdt)}
-        # experts carry a leading [held experts] dimension
-        ex = (_held_count(cfg),) if moe else ()
-        fl = cfg.expert_d_ff if moe else f
-        if ex:
-            lp["wg"] = normal(d, cfg.n_experts, scale=s_emb)
-            if cfg.router_bias:
-                lp["wg_bias"] = normal(cfg.n_experts, scale=0.01)
-        lp["w1"] = normal(*ex, d, fl, scale=s_emb)
-        if cfg.mlp_gated:
-            lp["w3"] = normal(*ex, d, fl, scale=s_emb)
-        lp["w2"] = normal(*ex, fl, d, scale=1.0 / math.sqrt(fl))
-        if moe and cfg.n_shared_experts:
-            fs = cfg.n_shared_experts * fl
-            lp["ws1"] = normal(d, fs, scale=s_emb)
-            if cfg.mlp_gated:
-                lp["ws3"] = normal(d, fs, scale=s_emb)
-            lp["ws2"] = normal(fs, d, scale=1.0 / math.sqrt(fs))
-        return lp
-
-    def mixer_layer(kind: str):
-        """A layer of a pattern: one pre-norm and one mixer."""
-        lp = {"ln": norm()}
-        if kind == SSM:
-            heads, inner, k = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv
-            dt = np.exp(rng.uniform(math.log(1e-3), math.log(0.1), heads))
-            lp.update(
-                in_proj=normal(d, inner + cfg.ssm_conv_width + heads,
-                               scale=s_emb),
-                conv_w=normal(cfg.ssm_conv_width, k,
-                              scale=1.0 / math.sqrt(k)),
-                conv_b=normal(cfg.ssm_conv_width, scale=1.0 / math.sqrt(k)),
-                # the family's initialisation: decays of a trained
-                # model, not all ~1 or ~0
-                A_log=np.log(rng.uniform(1.0, 16.0, heads)).astype(pdt),
-                dt_bias=(dt + np.log(-np.expm1(-dt))).astype(pdt),
-                D=np.ones(heads, pdt), ssm_norm=gain(inner),
-                out_proj=normal(inner, d, scale=1.0 / math.sqrt(inner)))
-        elif kind == ATTENTION:
-            wide = cfg.n_heads * cfg.head_dim
-            narrow = (cfg.n_kv_heads or cfg.n_heads) * cfg.head_dim
-            lp.update(
-                wq=normal(d, wide, scale=s_emb),
-                wk=normal(d, narrow, scale=s_emb),
-                wv=normal(d, narrow, scale=s_emb),
-                wo=normal(wide, d, scale=1.0 / math.sqrt(wide)
-                          / math.sqrt(2 * cfg.n_layers)))
-        else:
-            fl, fs = cfg.expert_d_ff, cfg.shared_width
-            held = _held_count(cfg)
-            lp["wg"] = normal(d, cfg.n_experts, scale=s_emb)
-            if cfg.router_bias:
-                lp["wg_bias"] = normal(cfg.n_experts, scale=0.01)
-            lp["w1"] = normal(held, d, fl, scale=s_emb)
-            if cfg.mlp_gated:
-                lp["w3"] = normal(held, d, fl, scale=s_emb)
-            lp["w2"] = normal(held, fl, d, scale=1.0 / math.sqrt(fl))
-            if fs:
-                lp["ws1"] = normal(d, fs, scale=s_emb)
-                if cfg.mlp_gated:
-                    lp["ws3"] = normal(d, fs, scale=s_emb)
-                lp["ws2"] = normal(fs, d, scale=1.0 / math.sqrt(fs))
-        return lp
-
-    params["layers"] = [
-        layer(_is_moe(cfg, i)) if cfg.layer_pattern is None
-        else mixer_layer(_layer_kind(cfg, i)) for i in range(cfg.n_layers)]
-    if cfg.mtp_layers:
-        params["mtp"] = [dict(
-            layer(_is_moe(cfg, cfg.n_layers)), enorm=norm(), hnorm=norm(),
-            eh_proj=normal(2 * d, d, scale=1.0 / math.sqrt(2 * d)))
-            for _ in range(cfg.mtp_layers)]
-    if cfg.vision is not None:
-        from ompi_tpu.models import vision
-
-        params["vision"] = vision.init_params(rng, cfg.vision, d, pdt)
-    return params
-
-
-def _like_params(cfg: Config, leaf, wide, expert):
-    """A tree of init_params' structure: `leaf` for every replicated
-    leaf, `wide(name)` for the tp-sharded attention and dense-FFN
-    matrices, `expert(name)` for a MoE layer's wg / w1 / w3 / w2."""
-    def norm():
-        return {"g": leaf} if cfg.norm == "rmsnorm" else {"g": leaf,
-                                                          "b": leaf}
-
-    tree: Dict = {"embed": leaf}
-    if cfg.pos == "learned":
-        tree["pos"] = leaf
-    if not cfg.tie_head:
-        tree["head"] = leaf
-    tree["ln_f"] = norm()
-    if cfg.exit_gate:
-        tree["exit_gate"] = {"w": leaf, "b": leaf}
-    ffn = ("w1", "w3", "w2") if cfg.mlp_gated else ("w1", "w2")
-
-    def layer(moe: bool):
-        lt = {"ln1": norm(), "ln2": norm()}
-        if cfg.post_norm:
-            lt.update(ln1_post=norm(), ln2_post=norm())
-        if cfg.attn == "mla":  # replicated: no tp path yet
-            lt.update({n: leaf for n in ("wkv_a", "wkv_b", "wo")})
-            lt.update(kv_a_norm={"g": leaf})
-            if cfg.q_lora_rank:
-                lt.update(wq_a=leaf, wq_b=leaf, q_a_norm={"g": leaf})
-            else:
-                lt["wq"] = leaf
-            if cfg.index_topk:
-                lt.update(wi_q=leaf, wi_k=leaf, wi_w=leaf,
-                          wi_k_norm={"g": leaf, "b": leaf})
-        else:
-            lt.update({n: wide(n) for n in ("wq", "wk", "wv", "wo")})
-        if cfg.qk_norm:
-            lt["q_norm"] = {"g": leaf}
-            lt["k_norm"] = {"g": leaf}
-        if moe:
-            lt.update({n: expert(n) for n in ("wg",) + ffn})
-            if cfg.router_bias:
-                lt["wg_bias"] = leaf
-            if cfg.n_shared_experts:  # a dense FFN inside the tp region
-                lt.update({"ws" + n[1:]: wide(n) for n in ffn})
-        else:
-            lt.update({n: wide(n) for n in ffn})
-        return lt
-
-    def mixer_layer(kind: str):  # replicated: no tp, sp, ep or pp path
-        names = {SSM: ("in_proj", "conv_w", "conv_b", "A_log", "dt_bias",
-                       "D", "out_proj"),
-                 ATTENTION: ("wq", "wk", "wv", "wo"),
-                 EXPERTS: ("wg",) + ffn + (("wg_bias",) if cfg.router_bias
-                                           else ())
-                 + (tuple("ws" + n[1:] for n in ffn) if cfg.shared_width
-                    else ())}[kind]
-        lt = dict({n: leaf for n in names}, ln=norm())
-        if kind == SSM:
-            lt["ssm_norm"] = {"g": leaf}
-        return lt
-
-    tree["layers"] = [
-        layer(_is_moe(cfg, i)) if cfg.layer_pattern is None
-        else mixer_layer(_layer_kind(cfg, i)) for i in range(cfg.n_layers)]
-    if cfg.mtp_layers:
-        tree["mtp"] = [dict(layer(_is_moe(cfg, cfg.n_layers)), enorm=norm(),
-                            hnorm=norm(), eh_proj=leaf)
-                       for _ in range(cfg.mtp_layers)]
-    if cfg.vision is not None:
-        from ompi_tpu.models import vision
-
-        tree["vision"] = vision.like_params(cfg.vision, leaf)
-    return tree
-
-
-def param_specs(cfg: Config, ax: Axes):
-    """PartitionSpec pytree matching init_params' structure.
-
-    tp shards: wq/wk/wv on output dim (column parallel), wo on input dim
-    (row parallel), dense w1/w2 likewise. ep shards MoE experts on dim 0.
-    Everything else replicated.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    row = ("wo", "w2")  # row parallel: the input dim is sharded
-    return _like_params(
-        cfg, P(),
-        wide=lambda n: P(ax.tp, None) if n in row else P(None, ax.tp),
-        expert=lambda n: P() if n == "wg" else P(
-            ax.ep, ax.tp, None) if n in row else P(ax.ep, None, ax.tp))
-
-
-def grad_extra_axes(cfg: Config, ax: Axes):
-    """Extra grad-psum axes per param, same structure as init_params.
-
-    The MoE router wg is replicated yet lives *inside* the tp region
-    (its cotangent arrives partial, via the combine-weights path through
-    the tp-sharded expert outputs), so unlike other replicated params it
-    needs an explicit psum over tp."""
-    # leaves are axis-name strings ("" = none): strings are pytree
-    # leaves, so the tree composes with tree.flatten_up_to cleanly
-    none = ""
-    return _like_params(
-        cfg, none, wide=lambda n: none,
-        expert=lambda n: (ax.tp or none) if n == "wg" else none)
 
 
 def _ln(x, g, b):
@@ -682,13 +373,6 @@ def rope_interleaved(x, positions, theta: float):
                      axis=-1).reshape(x.shape).astype(x.dtype)
 
 
-def _check_indexer(cfg: Config):
-    if cfg.attn == "mla" and cfg.index_topk and not cfg.q_lora_rank:
-        raise NotImplementedError(
-            "the sparse-attention indexer (index_topk) reads the query "
-            "latent, which a config with q_lora_rank 0 does not have")
-
-
 def _check_supported(cfg: Config, ax: Axes, is_moe, pos_offset, t=None):
     """What the config may ask for that an axis cannot give yet is an
     error, never another function computed in silence. `t`: the tokens
@@ -716,15 +400,11 @@ def _check_supported(cfg: Config, ax: Axes, is_moe, pos_offset, t=None):
                 raise NotImplementedError(
                     "a layer pattern (Config.layer_pattern) under "
                     + missing)
-        if cfg.pos == "rope" or cfg.qk_norm or cfg.post_norm \
-                or cfg.attn != "mha":
+        if cfg.post_norm or cfg.attn != "mha":
             raise NotImplementedError(
-                "a layer pattern (Config.layer_pattern) with RoPE, "
-                "QK-norm, a norm on a mixer's output or latent attention: "
-                "its attention layer is plain grouped-query attention")
-        if cfg.n_heads % (cfg.n_kv_heads or cfg.n_heads):
-            raise ValueError(f"n_heads={cfg.n_heads} is no multiple of "
-                             f"n_kv_heads={cfg.n_kv_heads}")
+                "a layer pattern (Config.layer_pattern) with a norm on a "
+                "mixer's output or latent attention: a pattern's layer is "
+                "one pre-norm and one mixer, its attention multi-head")
         if SSM in cfg.layer_pattern:
             if cfg.ssm_heads % cfg.ssm_groups:
                 raise ValueError(
@@ -736,12 +416,9 @@ def _check_supported(cfg: Config, ax: Axes, is_moe, pos_offset, t=None):
                     f"scan's chunks (ssm_chunk={cfg.ssm_chunk}): a last "
                     "chunk padded with tokens that change no state is "
                     "not written")
-    elif cfg.pos == "none" or cfg.head_width or cfg.n_kv_heads \
-            or cfg.shared_d_ff:
-        raise NotImplementedError(
-            "pos='none', head_width, n_kv_heads and shared_d_ff describe "
-            "the layers of a pattern (Config.layer_pattern); the block "
-            "of attention then a feed-forward part reads none of them")
+    if cfg.n_heads % (cfg.n_kv_heads or cfg.n_heads):
+        raise ValueError(f"n_heads={cfg.n_heads} is no multiple of "
+                         f"n_kv_heads={cfg.n_kv_heads}")
     if cfg.vision is not None and (ax.tp or ax.sp or ax.pp):
         raise NotImplementedError(
             "a vision tower (Config.vision) under tensor, sequence or "
@@ -839,17 +516,6 @@ def _ffn(x, w1, w3, w2, cfg: Config):
     return u @ w2.astype(x.dtype)
 
 
-def _residual(h, y, post, cfg: Config, name: str):
-    """h + y; where the config puts a norm on a sub-layer's output
-    (`post`: that norm's leaves), h + norm(y). y, the sub-layer's
-    output before that norm (whose backward pass reads it), carries
-    `name` for `_run_layer`'s policy."""
-    y = checkpoint_name(y, name)
-    if post is None:
-        return h + y
-    return h + _norm(y.astype(jnp.float32), post, cfg).astype(y.dtype)
-
-
 def _mla_project(lp, x, cfg: Config, positions):
     """Latent attention's projections of the normed x [B, T, d]: (q, k
     [B, T, H, nope + rope], v [B, T, H, v_head_dim], c_q [B, T,
@@ -932,18 +598,19 @@ def _dsa_core(q, k, v, index, cfg: Config, index_aux):
     return o
 
 
-def _mla_attention(lp, h, x, cfg: Config, pos_offset, index_aux):
-    """h + the latent-attention half of a block on one device (x: the
-    normed h). Where the sequence is longer than index_topk each query
-    attends to the keys its indexer selects; else to all causal ones,
-    through the model's one entry (``ops.attention.attention``: the
-    blockwise kernel on the TPU also where q and v differ in width,
-    192 against 128 in the fifth model — the kernel pads both to its
-    lanes; ``att.mha`` off the TPU or at a length no tile divides,
-    pvar ``attn_reference_layers``). Counted once per traced layer:
+def _mla_attention(lp, x, cfg: Config, pos_offset, index_aux):
+    """Latent attention's mixer on one device (x: the normed h): the
+    output projection's result [B, T, d]. Where the sequence is longer
+    than index_topk each query attends to the keys its indexer
+    selects; else to all causal ones, through the model's one entry
+    (``ops.attention.attention``: the blockwise kernel on the TPU also
+    where q and v differ in width, 192 against 128 in Kimi-VL's
+    decoder — the kernel pads both to its lanes; ``att.mha`` off the
+    TPU or at a length no tile divides, pvar
+    ``attn_reference_layers``). Counted once per traced layer:
     ``attn_mla_layers``, and ``attn_mla_plain_q_layers`` for those
     without a query latent."""
-    b, t = h.shape[0], h.shape[1]
+    b, t = x.shape[0], x.shape[1]
     positions = jnp.arange(t) if pos_offset is None \
         else pos_offset + jnp.arange(t)
     selects = bool(cfg.index_topk) and t > cfg.index_topk
@@ -962,39 +629,43 @@ def _mla_attention(lp, h, x, cfg: Config, pos_offset, index_aux):
         else:
             o = att.attention(q, k, v, causal=True)
     with jax.named_scope("attn_proj"), jax.named_scope("mla_o"):
-        return _residual(h, o.reshape(b, t, -1) @ lp["wo"].astype(cfg.dtype),
-                         lp["ln1_post"] if cfg.post_norm else None, cfg,
-                         ATTN_PROJ_OUT)
+        return o.reshape(b, t, -1) @ lp["wo"].astype(cfg.dtype)
 
 
-def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
-                  pos_offset=None, aux=None, index_aux=None):
-    """One transformer block on local shards: pre-norm attention (+tp
-    Megatron f/g pair, +sp ring attention; or latent attention with
-    its sparse-attention indexer) then FFN or MoE. Shared by
-    the layer loop below and the pipeline-parallel stage scan
-    (models/pipeline.py). `pos_offset` is the global position of the
-    shard's first token (RoPE under sp needs it; None = not given);
-    `aux`, a list, receives a MoE layer's (load-balancing loss, z-loss,
-    routing: an ops.moe.TopKRoute); `index_aux`, a list, a selecting
-    layer's (indexer loss, selection [B, T, T]). Where the config has
-    a layer pattern `is_moe` is the layer's letter, and the layer one
-    pre-norm and one mixer (:func:`_mixer_layer`)."""
-    _check_supported(cfg, ax, is_moe, pos_offset, h.shape[1])
-    if cfg.layer_pattern is not None:
-        return _mixer_layer(lp, h, cfg, is_moe, aux)
+def _attention(lp, x, cfg: Config, ax: Axes, pos_offset):
+    """Multi-head attention's mixer (x: the normed h): causal attention
+    of `n_heads` query heads over `n_kv_heads` shared key / value heads
+    (0: as many), then the output projection [B, T, d]; inside the tp
+    region where there is one. Query head i attends with key head
+    ``i // (n_heads / n_kv_heads)``: the key heads are repeated in
+    front of the model's one entry (``ops.attention.attention``: the
+    blockwise kernel on the TPU, ``att.mha`` elsewhere), so autodiff
+    sums a key head's gradient over its queries. RoPE turns q and k
+    where ``pos == "rope"`` (a learned table is the embedding's
+    business). Under sp the ring or the Ulysses schedule runs in the
+    kernel's place. Counted once per traced layer with shared key
+    heads: ``attn_gqa_layers``."""
     dt = cfg.dtype
-    b, t = h.shape[0], h.shape[1]
-    x = _norm(h.astype(jnp.float32), lp["ln1"], cfg).astype(dt)
-    if cfg.attn == "mla":
-        h = _mla_attention(lp, h, x, cfg, pos_offset, index_aux)
-        return _ffn_half(lp, h, cfg, ax, is_moe, aux)
+    b, t, _ = x.shape
+    dh = cfg.head_dim
     # The blockwise kernel takes q already scaled. Where it will run
     # (the rule att.attention applies below), 1/sqrt(Dh) goes in where q
     # is still float32 — the projection's accumulator or the QK-norm —
     # so q is rounded to dt once, as it is for att.mha.
-    q_scale = cfg.head_dim ** -0.5 if not ax.sp and att.blockwise_tile(
-        jax.default_backend(), t, t, cfg.head_dim) else None
+    q_scale = dh ** -0.5 if not ax.sp and att.blockwise_tile(
+        jax.default_backend(), t, t, dh) else None
+
+    def split(a):  # [B, T, Hl, Dh]: the local heads under tp
+        return a.reshape(b, t, a.shape[-1] // dh, dh)
+
+    # The ORDER of these equations is part of a step's lowered text:
+    # with shared key heads and no QK-norm (which reads the WHOLE
+    # projection) each projection is split into heads as it is made —
+    # nemotron-train-t8192's step, as PR 39 wrote it — else after all
+    # three, every other cell's. One order for both changes a cell's
+    # program text: measured work (ROADMAP D23), not PR 42's fold.
+    at_once = not cfg.qk_norm and cfg.n_kv_heads not in (0, cfg.n_heads)
+    first, later = (split, lambda a: a) if at_once else (lambda a: a, split)
     with jax.named_scope("attn_proj"):
         if ax.tp:
             x = region_enter(x, ax.tp)
@@ -1004,8 +675,9 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
                  * q_scale).astype(dt)
         else:
             q = x @ lp["wq"].astype(dt)  # [B,T,Hl*Dh] (tp-sharded cols)
-        k = x @ lp["wk"].astype(dt)
-        v = x @ lp["wv"].astype(dt)
+        q = first(q)
+        k = first(x @ lp["wk"].astype(dt))
+        v = first(x @ lp["wv"].astype(dt))
         if cfg.qk_norm:
             with jax.named_scope("qk_rope"):
                 q = _rms(q.astype(jnp.float32), lp["q_norm"]["g"],
@@ -1013,16 +685,17 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
                 q = (q * q_scale if q_scale else q).astype(dt)
                 k = _rms(k.astype(jnp.float32), lp["k_norm"]["g"],
                          cfg.norm_eps).astype(dt)
-        hl = q.shape[-1] // cfg.head_dim  # local heads under tp
-        q = q.reshape(b, t, hl, cfg.head_dim)
-        k = k.reshape(b, t, hl, cfg.head_dim)
-        v = v.reshape(b, t, hl, cfg.head_dim)
+        q, k, v = later(q), later(k), later(v)
         if cfg.pos == "rope":
             with jax.named_scope("qk_rope"):
                 positions = jnp.arange(t) if pos_offset is None \
                     else pos_offset + jnp.arange(t)
                 q = rope(q, positions, cfg.rope_theta)
                 k = rope(k, positions, cfg.rope_theta)
+        if k.shape[2] != q.shape[2]:
+            pvar.record("attn_gqa_layers")
+            k, v = (jnp.repeat(a, q.shape[2] // k.shape[2], axis=2)
+                    for a in (k, v))
     with jax.named_scope("attn_core"):  # scores, softmax, AV
         if ax.sp:
             if cfg.sp_schedule == "ulysses":
@@ -1039,19 +712,56 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
             o = att.attention(q, k, v, causal=True,
                               scale=1.0 if q_scale else None)
     with jax.named_scope("attn_proj"):
-        o = o.reshape(b, t, hl * cfg.head_dim)
+        o = o.reshape(b, t, -1)
         o = o @ lp["wo"].astype(dt)   # row parallel: partial sums
         if ax.tp:
             o = region_exit(o, ax.tp)
-        h = _residual(h, o, lp["ln1_post"] if cfg.post_norm else None, cfg,
-                      ATTN_PROJ_OUT)
-    return _ffn_half(lp, h, cfg, ax, is_moe, aux)
+        return o
+
+
+def _dense_ffn(lp, x, cfg: Config, ax: Axes):
+    """The dense FFN's mixer, inside the tp region where there is one."""
+    with jax.named_scope("mlp"):
+        if ax.tp:
+            x = region_enter(x, ax.tp)
+        y = _ffn(x, lp["w1"], lp["w3"] if cfg.mlp_gated else None, lp["w2"],
+                 cfg)
+        if ax.tp:
+            y = region_exit(y, ax.tp)
+        return y
+
+
+def _experts(lp, x, cfg: Config, ax: Axes, aux):
+    """The mixture of experts' mixer: the routed experts (the sorted
+    path on one device, ``ops.moe.moe_ffn``'s capacity path over an ep
+    axis) and, where the config has one (``Config.shared_width``), the
+    shared expert every token passes through, inside the tp region
+    where there is one."""
+    dt = cfg.dtype
+    b, t, d = x.shape
+    with jax.named_scope("mlp"):
+        if ax.tp:
+            x = region_enter(x, ax.tp)
+        flat = x.reshape(b * t, d)
+        if ax.ep:
+            y = moe_mod.moe_ffn(
+                flat, lp["wg"].astype(dt), lp["w1"].astype(dt),
+                lp["w2"].astype(dt), ax.ep,
+                capacity_factor=cfg.capacity_factor)
+        else:
+            y = _moe_sorted(flat, lp, cfg, aux)
+        if cfg.shared_width:
+            with jax.named_scope("moe_shared"):
+                y = y + _ffn(flat, lp["ws1"], lp.get("ws3"), lp["ws2"], cfg)
+        if ax.tp:
+            y = region_exit(y, ax.tp)
+        return y.reshape(b, t, d)
 
 
 def _ssm_mixer(lp, x, cfg: Config):
     """(the Mamba-2 mixer's output, its scan's final state) of the
-    normed x at the config's sizes (ops/ssm.py, imported here and
-    nowhere else)."""
+    normed x at the config's sizes (ops/ssm.py, imported where a
+    pattern has a state-space layer and nowhere else)."""
     from ompi_tpu.ops import ssm
 
     with jax.named_scope("ssm"):
@@ -1061,219 +771,158 @@ def _ssm_mixer(lp, x, cfg: Config):
             eps=cfg.norm_eps)
 
 
-def _gqa_attention(lp, x, cfg: Config):
-    """Causal attention of `n_heads` query heads over `n_kv_heads`
-    shared key / value heads, no positions: query head i attends with
-    key head ``i // (n_heads / n_kv_heads)``. The key heads are
-    repeated in front of the model's one entry
-    (``ops.attention.attention``: the blockwise kernel on the TPU,
-    ``att.mha`` elsewhere), so autodiff sums a key head's gradient
-    over its queries."""
-    dt = cfg.dtype
-    b, t, _ = x.shape
-    heads, kv, dh = cfg.n_heads, cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
-    # as in the block: where the kernel will run, 1/sqrt(Dh) goes in
-    # while q is still the projection's float32 accumulator
-    q_scale = dh ** -0.5 if att.blockwise_tile(
-        jax.default_backend(), t, t, dh) else None
-    with jax.named_scope("attn_proj"):
-        if q_scale:
-            q = (jnp.dot(x, lp["wq"].astype(dt),
-                         preferred_element_type=jnp.float32)
-                 * q_scale).astype(dt)
-        else:
-            q = x @ lp["wq"].astype(dt)
-        q = q.reshape(b, t, heads, dh)
-        k = (x @ lp["wk"].astype(dt)).reshape(b, t, kv, dh)
-        v = (x @ lp["wv"].astype(dt)).reshape(b, t, kv, dh)
-        if kv != heads:
-            pvar.record("attn_gqa_layers")
-            k, v = (jnp.repeat(a, heads // kv, axis=2) for a in (k, v))
-    with jax.named_scope("attn_core"):
-        o = att.attention(q, k, v, causal=True,
-                          scale=1.0 if q_scale else None)
-    with jax.named_scope("attn_proj"):
-        return o.reshape(b, t, heads * dh) @ lp["wo"].astype(dt)
-
-
-def _mixer_layer(lp, h, cfg: Config, kind: str, aux):
-    """A layer of a pattern: ``h + mixer(norm(h))``, the mixer a
-    Mamba-2 state-space model (`SSM`), grouped-query attention
-    (`ATTENTION`) or the mixture of experts with its shared expert
-    (`EXPERTS`). Counted once per traced layer: ``ssm_layers`` and
-    ``ssm_chunks``, ``attn_gqa_layers``, and what the expert path
-    counts of itself."""
-    dt = cfg.dtype
-    b, t = h.shape[0], h.shape[1]
-    x = _norm(h.astype(jnp.float32), lp["ln"], cfg).astype(dt)
-    if kind == SSM:
+def _sublayer(lp, h, cfg: Config, ax: Axes, sub, pos_offset=None, aux=None,
+              index_aux=None):
+    """One sub-layer (`sub`: a row of models/params.py's ``layout``):
+    ``h + mixer(norm(h))`` — through a norm on the mixer's output where
+    the row names one, the output named for the recomputation rule
+    where the row names it, the add under the row's scopes. Counted
+    once per traced state-space layer: ``ssm_layers`` and
+    ``ssm_chunks``; the other mixers count of themselves."""
+    x = _norm(h.astype(jnp.float32), lp[sub.pre], cfg).astype(cfg.dtype)
+    if sub.mixer == "attention":
+        y = _attention(lp, x, cfg, ax, pos_offset)
+    elif sub.mixer == "mla":
+        y = _mla_attention(lp, x, cfg, pos_offset, index_aux)
+    elif sub.mixer == "ffn":
+        y = _dense_ffn(lp, x, cfg, ax)
+    elif sub.mixer == "experts":
+        y = _experts(lp, x, cfg, ax, aux)
+    else:
         pvar.record("ssm_layers")
-        pvar.record("ssm_chunks", t // cfg.ssm_chunk)
-        return h + _ssm_mixer(lp, x, cfg)[0]
-    if kind == ATTENTION:
-        return h + _gqa_attention(lp, x, cfg)
-    with jax.named_scope("mlp"):
-        flat = x.reshape(b * t, cfg.d_model)
-        y = _moe_sorted(flat, lp, cfg, aux)
-        if cfg.shared_width:
-            with jax.named_scope("moe_shared"):
-                y = y + _ffn(flat, lp["ws1"], lp.get("ws3"), lp["ws2"], cfg)
-        return h + y.reshape(b, t, cfg.d_model)
+        pvar.record("ssm_chunks", x.shape[1] // cfg.ssm_chunk)
+        y = _ssm_mixer(lp, x, cfg)[0]
+    with contextlib.ExitStack() as scopes:
+        for scope in sub.scopes:
+            scopes.enter_context(jax.named_scope(scope))
+        if sub.name:  # what the backward pass of a norm on y reads
+            y = checkpoint_name(y, sub.name)
+        if not sub.post:
+            return h + y
+        return h + _norm(y.astype(jnp.float32), lp[sub.post],
+                         cfg).astype(y.dtype)
 
 
-def _ffn_half(lp, h, cfg: Config, ax: Axes, is_moe: bool, aux):
-    """h + the FFN half of a block: dense, or the mixture of experts
-    (with its shared expert where the config has one)."""
-    dt = cfg.dtype
-    b, t = h.shape[0], h.shape[1]
-    x = _norm(h.astype(jnp.float32), lp["ln2"], cfg).astype(dt)
-    with jax.named_scope("mlp"):
-        if ax.tp:
-            x = region_enter(x, ax.tp)
-        if is_moe:
-            flat = x.reshape(b * t, cfg.d_model)
-            if ax.ep:
-                y = moe_mod.moe_ffn(
-                    flat, lp["wg"].astype(dt), lp["w1"].astype(dt),
-                    lp["w2"].astype(dt), ax.ep,
-                    capacity_factor=cfg.capacity_factor)
-            else:
-                y = _moe_sorted(flat, lp, cfg, aux)
-            if cfg.n_shared_experts:
-                with jax.named_scope("moe_shared"):
-                    y = y + _ffn(flat, lp["ws1"], lp.get("ws3"), lp["ws2"],
-                                 cfg)
-            if ax.tp:
-                y = region_exit(y, ax.tp)
-            y = y.reshape(b, t, cfg.d_model)
-        else:
-            y = _ffn(x, lp["w1"], lp["w3"] if cfg.mlp_gated else None,
-                     lp["w2"], cfg)
-            if ax.tp:
-                y = region_exit(y, ax.tp)
-        return _residual(h, y, lp["ln2_post"] if cfg.post_norm else None,
-                         cfg, MLP_OUT)
+def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
+                  pos_offset=None, aux=None, index_aux=None):
+    """One layer on local shards: its sub-layers in turn
+    (models/params.py's ``layout``) — the block's pre-norm attention
+    (+tp Megatron f/g pair, +sp ring attention; or latent attention
+    with its sparse-attention indexer) then FFN or MoE, or the ONE
+    mixer of a pattern's letter. Shared by the layer loop below and the
+    pipeline-parallel stage scan (models/pipeline.py). `pos_offset` is
+    the global position of the shard's first token (RoPE under sp needs
+    it; None = not given); `aux`, a list, receives a MoE layer's
+    (load-balancing loss, z-loss, routing: an ops.moe.TopKRoute);
+    `index_aux`, a list, a selecting layer's (indexer loss, selection
+    [B, T, T]). `is_moe` is the layer's kind (`_layer_kind`): whether
+    the block's feed-forward part is a mixture of experts, or the
+    layer's letter where the config has a pattern."""
+    _check_supported(cfg, ax, is_moe, pos_offset, h.shape[1])
+    for sub in layout(cfg, is_moe):
+        h = _sublayer(lp, h, cfg, ax, sub, pos_offset, aux, index_aux)
+    return h
 
 
-#: Names (``jax.ad_checkpoint.checkpoint_name``) of what a layer
-#: application makes that its backward pass reads, beside those
-#: ops/attention.py gives (QKV, ATTN_OUT, DSA_PROBS): a sub-layer's
-#: output before its residual add and output norm; the FFN's (and a
-#: shared expert's) up-projections; latent attention's down-projections
-#: before their norms; the indexer's scores and the selection.
-ATTN_PROJ_OUT = "attn_proj_out"
-MLP_OUT = "mlp_out"
-MLP_UP = "mlp_up"
-MLA_LATENTS = "mla_latents"
-DSA_SELECT = "dsa_select"
+# What a mixer costs models/remat.py's rule, from the config's widths
+# alone: (the bytes ONE application over `n` tokens in sequences of `t`
+# holds under each name the mixer makes and its backward pass reads, at
+# `it` bytes an item; the operations of the PRODUCTS that pass need not
+# make again where a name is kept — each name as if kept alone; the
+# norms, RoPE, layout changes and the indexer's search it spares beside
+# are not counted; attention's over the causal half —; the operations of
+# the mixer's LAST product, which its output's name spares).
 
-#: The share of the device's memory limit the reckoned peak may reach.
-#: The rest is the room for what the reckoning misses: on a v5e
-#: (16.9 GB) 2.5 GB, where the four full-size compiles of PR 35 put
-#: the compiled peak between 0.9 GB under and 1.0 GB over the
-#: reckoned one (PERF.md section 6; tests/test_remat_policy.py holds
-#: the rule to twice that).
-REMAT_SHARE = 0.85
+def _attention_costs(cfg: Config, n: int, t: int, it: int):
+    """q, k and v as the kernel reads them: the key heads repeated."""
+    d, heads, dh = cfg.d_model, cfg.n_heads, cfg.head_dim
+    kv = cfg.n_kv_heads or heads
+    return ({att.ATTN_OUT: n * heads * (dh * it + 4),
+             att.QKV: 3 * n * heads * dh * it},
+            {att.ATTN_OUT: 2 * n * t * heads * dh,
+             att.QKV: 2 * n * d * (heads + 2 * kv) * dh},
+            2 * n * heads * dh * d)
 
 
-def remat_sizes(cfg: Config, b: int, t: int, is_moe: bool) -> Dict[str, int]:
-    """Bytes ONE application of a layer holds under each name, of
-    [b, t] tokens: the names that layer kind makes and its backward
-    pass reads (the FFN's output only where a norm follows it: a bare
-    residual add's backward reads nothing), from the config's widths
-    alone. `is_moe`: the layer's kind — its letter where the config
-    has a layer pattern."""
+def _mla_costs(cfg: Config, n: int, t: int, it: int):
+    d, heads = cfg.d_model, cfg.n_heads
+    qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+    latents = cfg.q_lora_rank + cfg.kv_lora_rank + cfg.qk_rope_dim
+    sizes = {att.ATTN_OUT: n * heads * (cfg.v_head_dim * it + 4),
+             att.QKV: n * heads * (2 * qk + cfg.v_head_dim) * it,
+             MLA_LATENTS: n * latents * it}
+    if cfg.index_topk and t > cfg.index_topk:
+        sizes[att.DSA_PROBS] = n * t * 4
+        sizes[DSA_SELECT] = n * t * (4 + 1)
+    return (sizes,
+            {att.ATTN_OUT: n * t * heads * (qk + cfg.v_head_dim),
+             att.QKV: 2 * n * heads * (
+                 cfg.q_lora_rank * qk
+                 + cfg.kv_lora_rank * (cfg.qk_nope_dim + cfg.v_head_dim)),
+             MLA_LATENTS: 2 * n * d * latents,
+             att.DSA_PROBS: n * t * heads * qk,
+             DSA_SELECT: n * t * cfg.index_heads * cfg.index_dim},
+            2 * n * heads * cfg.v_head_dim * d)
+
+
+def _ffn_costs(width: int):
+    """A feed-forward part of `width`: its up-projections (the dense
+    FFN's; of the experts, the shared expert's — the routed rows are
+    ops/moe.py's own business)."""
+    def costs(cfg: Config, n: int, t: int, it: int):
+        up = n * width(cfg) * (2 if cfg.mlp_gated else 1)
+        return ({MLP_UP: up * it}, {MLP_UP: 2 * cfg.d_model * up},
+                2 * n * width(cfg) * cfg.d_model)
+    return costs
+
+
+def _ssm_costs(cfg: Config, n: int, t: int, it: int):
+    """ops/ssm.py's three names. Kept, the scan's output spares the
+    two products that make it, not those its own backward pass reads:
+    on the kernels of ops/ssm_scan.py that is the whole forward kernel,
+    whose backward keeps its operands alone and makes the states
+    entering the chunks again in a sweep of its own — the numbers stand
+    for both forms."""
+    from ompi_tpu.ops import ssm
+
+    inner, conv = cfg.ssm_inner, cfg.ssm_conv_width
+    first = inner + conv + cfg.ssm_heads
+    return ({ssm.SSM_IN: n * first * it, ssm.SSM_CONV: n * conv * it,
+             ssm.SSM_Y: n * inner * it},
+            {ssm.SSM_IN: 2 * n * cfg.d_model * first,
+             ssm.SSM_CONV: 2 * n * conv * cfg.ssm_conv,
+             ssm.SSM_Y: n * inner * (cfg.ssm_chunk + 2 * cfg.ssm_state)},
+            0)
+
+
+#: a mixer's costs, by the name its layout row carries
+_COSTS = {"attention": _attention_costs, "mla": _mla_costs,
+          "ffn": _ffn_costs(lambda cfg: cfg.d_ff),
+          "experts": _ffn_costs(lambda cfg: cfg.shared_width),
+          "ssm": _ssm_costs}
+
+
+def layer_costs(cfg: Config, b: int, t: int, kind) -> remat.Application:
+    """What ONE application of a layer of `kind` over [b, t] tokens
+    costs the rule: its mixers' costs summed over its layout, and a
+    sub-layer's output where something reads it again — a norm on it,
+    or the sub-layer after it (a bare residual add's backward reads
+    nothing, and the layer's own output is the next layer's input).
+    Names that hold nothing are left out."""
     it = jnp.dtype(cfg.dtype).itemsize
-    n, d, heads = b * t, cfg.d_model, cfg.n_heads
-    if cfg.layer_pattern is not None:
-        return _mixer_costs(cfg, n, t, is_moe, it)[0]
-    gated = 2 if cfg.mlp_gated else 1
-    ff = cfg.n_shared_experts * cfg.expert_d_ff if is_moe else cfg.d_ff
-    sizes = {ATTN_PROJ_OUT: n * d * it,
-             MLP_OUT: n * d * it if cfg.post_norm else 0,
-             MLP_UP: n * ff * gated * it}
-    if cfg.attn == "mla":
-        qk = cfg.qk_nope_dim + cfg.qk_rope_dim
-        sizes[att.ATTN_OUT] = n * heads * (cfg.v_head_dim * it + 4)
-        sizes[att.QKV] = n * heads * (2 * qk + cfg.v_head_dim) * it
-        sizes[MLA_LATENTS] = n * (cfg.q_lora_rank + cfg.kv_lora_rank
-                                  + cfg.qk_rope_dim) * it
-        if cfg.index_topk and t > cfg.index_topk:
-            sizes[att.DSA_PROBS] = b * t * t * 4
-            sizes[DSA_SELECT] = b * t * t * (4 + 1)
-    else:
-        sizes[att.ATTN_OUT] = n * heads * (cfg.head_dim * it + 4)
-        sizes[att.QKV] = 3 * n * d * it
-    return {name: size for name, size in sizes.items() if size}
-
-
-def remat_spared(cfg: Config, b: int, t: int, is_moe: bool) -> Dict[str, int]:
-    """The operations of the PRODUCTS one application's backward pass
-    need not make again where a name is kept (each name as if kept
-    alone; the norms, RoPE, layout changes and the indexer's search it
-    spares beside are not counted): from the config's widths alone,
-    attention's over the causal half."""
-    n, d, heads = b * t, cfg.d_model, cfg.n_heads
-    if cfg.layer_pattern is not None:
-        return _mixer_costs(cfg, n, t, is_moe,
-                            jnp.dtype(cfg.dtype).itemsize)[1]
-    gated = 2 if cfg.mlp_gated else 1
-    ff = cfg.n_shared_experts * cfg.expert_d_ff if is_moe else cfg.d_ff
-    ops = {MLP_OUT: 2 * n * ff * d, MLP_UP: 2 * n * d * ff * gated}
-    if cfg.attn == "mla":
-        qk = cfg.qk_nope_dim + cfg.qk_rope_dim
-        ops[ATTN_PROJ_OUT] = 2 * n * heads * cfg.v_head_dim * d
-        ops[att.ATTN_OUT] = n * t * heads * (qk + cfg.v_head_dim)
-        ops[att.QKV] = 2 * n * heads * (
-            cfg.q_lora_rank * qk
-            + cfg.kv_lora_rank * (cfg.qk_nope_dim + cfg.v_head_dim))
-        ops[MLA_LATENTS] = 2 * n * d * (cfg.q_lora_rank + cfg.kv_lora_rank
-                                        + cfg.qk_rope_dim)
-        ops[att.DSA_PROBS] = n * t * heads * qk
-        ops[DSA_SELECT] = n * t * cfg.index_heads * cfg.index_dim
-    else:
-        ops[ATTN_PROJ_OUT] = 2 * n * d * d
-        ops[att.ATTN_OUT] = 2 * n * t * d
-        ops[att.QKV] = 3 * 2 * n * d * d
-    return ops
-
-
-def _mixer_costs(cfg: Config, n: int, t: int, kind: str, it: int):
-    """(`remat_sizes`, `remat_spared`) of one application of a layer
-    of a pattern over `n` tokens in sequences of `t`. A state-space
-    layer makes ops/ssm.py's three names (kept, the scan's output
-    spares the two products that make it, not those its own backward
-    pass reads: on the kernels of ops/ssm_scan.py that is the whole
-    forward kernel, whose backward keeps its operands alone and makes
-    the states entering the chunks again in a sweep of its own — the
-    numbers stand for both forms); the attention layer attention's two,
-    with the key heads as the kernel reads them: repeated; an expert
-    layer the shared expert's up-projection."""
-    d = cfg.d_model
-    if kind == SSM:
-        from ompi_tpu.ops import ssm
-
-        inner, conv = cfg.ssm_inner, cfg.ssm_conv_width
-        first = inner + conv + cfg.ssm_heads
-        return ({ssm.SSM_IN: n * first * it, ssm.SSM_CONV: n * conv * it,
-                 ssm.SSM_Y: n * inner * it},
-                {ssm.SSM_IN: 2 * n * d * first,
-                 ssm.SSM_CONV: 2 * n * conv * cfg.ssm_conv,
-                 ssm.SSM_Y: n * inner * (cfg.ssm_chunk + 2 * cfg.ssm_state)})
-    if kind == ATTENTION:
-        heads, dh = cfg.n_heads, cfg.head_dim
-        kv = cfg.n_kv_heads or heads
-        return ({att.ATTN_OUT: n * heads * (dh * it + 4),
-                 att.QKV: 3 * n * heads * dh * it},
-                {att.ATTN_OUT: 2 * n * t * heads * dh,
-                 att.QKV: 2 * n * d * (heads + 2 * kv) * dh})
-    gated = 2 if cfg.mlp_gated else 1
-    up = n * cfg.shared_width * gated
-    return ({MLP_UP: up * it} if up else {}), {MLP_UP: 2 * d * up}
+    n, rows = b * t, layout(cfg, kind)
+    sizes, spared = {}, {}
+    for sub in rows:
+        made, ops, last_product = _COSTS[sub.mixer](cfg, n, t, it)
+        if sub.name and (sub.post or sub is not rows[-1]):
+            made[sub.name], ops[sub.name] = n * cfg.d_model * it, last_product
+        sizes.update({name: size for name, size in made.items() if size})
+        spared.update({name: ops[name] for name in made if made[name]})
+    return remat.Application(sizes, spared, n * cfg.d_model * it)
 
 
 #: the tower's layer kind beside the decoder's (False: a dense layer,
-#: True: a MoE layer, or a pattern's letter), models/vision.py's VIT
+#: True: a MoE layer, or a pattern's letter), models/vision.py's blocks
 VIT = "vit"
 
 
@@ -1286,99 +935,30 @@ def _application_kinds(cfg: Config):
         + [_is_moe(cfg, cfg.n_layers)] * cfg.mtp_layers
 
 
-def _kind_costs(cfg: Config, b: int, t: int, patches: int, kind):
-    """(`remat_sizes`, `remat_spared`, the bytes of its input) of one
-    application of a layer of `kind`: a decoder layer's over [b, t]
-    tokens, a tower block's over `patches` rows."""
+def step_costs(cfg: Config, b: int, t: int, param_bytes: int = 0,
+               patches: int = 0, largest: Optional[int] = None):
+    """(the step's applications, the bytes it holds whatever they keep)
+    as models/remat.py's rule takes them, from what a trace can
+    observe: the tokens' shape [b, t] (and the packed row's `patches`
+    where the config has a tower: a block's costs are
+    models/vision.py's), the config's widths and depth, the bytes of
+    the parameters. The fixed bytes, term by term from the program
+    (`make_train_step`): the parameters; their gradients (all of them
+    where the layers run more than once and a leaf's gradient is a sum
+    over the passes, else ONE application's: the update takes each as
+    it appears — `largest`, the bytes of the parameters of the
+    application that has most, where the caller has the tree, else the
+    mean over the applications, which is less where the layers are
+    unlike); ONE exit's float32 logits, their exponentials and their
+    cotangent (`_exit_terms`, `_token_nll`)."""
     it = jnp.dtype(cfg.dtype).itemsize
-    if kind == VIT:
-        from ompi_tpu.models import vision
-
-        return (vision.remat_sizes(cfg.vision, patches, it),
-                vision.remat_spared(cfg.vision, patches),
-                patches * cfg.vision.d_model * it)
-    return (remat_sizes(cfg, b, t, kind), remat_spared(cfg, b, t, kind),
-            b * t * cfg.d_model * it)
-
-
-def remat_order(cfg: Config, b: int, t: int, patches: int = 0):
-    """[(name, bytes all the step's applications hold under it)], the
-    dearest first: by the operations a name spares per byte it holds
-    (over a product's result that is 2 x the contracted width / the
-    item size: 16,384 wide, GLM-5's attention output projection stands
-    first; 2,048 wide, Ouro's stands behind its attention and its FFN's
-    output), of equals the smaller first. A name is one entry whatever
-    kinds of layer make it: a tower's blocks (over `patches` rows) and
-    the decoder's layers keep or drop it together."""
     kinds = _application_kinds(cfg)
-    per = {kind: _kind_costs(cfg, b, t, patches, kind)[:2]
-           for kind in set(kinds)}
-    held, spared = {}, {}
-    for kind in kinds:
-        sizes, ops = per[kind]
-        for name, size in sizes.items():
-            held[name] = held.get(name, 0) + size
-            spared[name] = spared.get(name, 0) + ops[name]
-    return sorted(held.items(),
-                  key=lambda kv: (-spared[kv[0]] / kv[1], kv[1], kv[0]))
-
-
-def whole_step_peak(cfg: Config, b: int, t: int, param_bytes: int,
-                    patches: int = 0, largest: Optional[int] = None) -> int:
-    """The bytes a train step (`make_train_step`) is reckoned to hold
-    at its peak with every layer application recomputed from its input
-    alone, term by term from the program: the parameters; their
-    gradients (all of them where the layers run more than once and a
-    leaf's gradient is a sum over the passes, else ONE application's:
-    the update takes each as it appears — `largest`, the bytes of the
-    parameters of the application that has most, where the caller has
-    the tree, else the mean over the applications, which is less
-    where the layers are unlike); an input per
-    application (a tower block's: the packed row of `patches`); ONE
-    exit's float32 logits, their exponentials and their cotangent
-    (`_exit_terms`, `_token_nll`); the values one application's
-    backward pass makes again and a cotangent for each."""
-    kinds = _application_kinds(cfg)
+    per = {kind: vision.application(cfg.vision, patches, it) if kind == VIT
+           else layer_costs(cfg, b, t, kind) for kind in set(kinds)}
     grads = param_bytes if cfg.loops > 1 \
         else largest or param_bytes // max(len(kinds), 1)
-    per = {kind: _kind_costs(cfg, b, t, patches, kind)
-           for kind in set(kinds)}
-    again = max(sum(sizes.values()) for sizes, _, _ in per.values())
-    return (param_bytes + grads + sum(per[kind][2] for kind in kinds)
-            + 3 * b * t * cfg.vocab * 4 + 2 * again)
-
-
-def remat_keep(cfg: Config, b: int, t: int, param_bytes: int,
-               limit: Optional[int], patches: int = 0,
-               largest: Optional[int] = None) -> Tuple[str, ...]:
-    """The rule that says what a recomputed layer application keeps
-    for its backward pass, made of what the trace can observe: the
-    tokens' shape (and the packed row's patches where the config has a
-    tower), the config's widths and depth, the bytes of the
-    parameters (all of them, and `largest`: of the application that
-    has most) and the device's memory limit. It starts from
-    `whole_step_peak`, walks the names in `remat_order`, adding what
-    all the applications hold under a name, and stops before the first
-    name that would take the reckoned peak past `REMAT_SHARE` of the
-    limit. No limit (the CPU) or no room: the empty tuple, every
-    application recomputed whole — the parent's program."""
-    if not limit:
-        return ()
-    peak = whole_step_peak(cfg, b, t, param_bytes, patches, largest)
-    keep = []
-    for name, held in remat_order(cfg, b, t, patches):
-        if peak + held > REMAT_SHARE * limit:
-            break
-        keep.append(name)
-        peak += held
-    return tuple(keep)
-
-
-def _memory_limit() -> Optional[int]:
-    """The bytes a process may hold on its first device (None where the
-    backend does not say: the CPU)."""
-    stats = jax.local_devices()[0].memory_stats() or {}
-    return stats.get("bytes_limit")
+    return ([per[kind] for kind in kinds],
+            param_bytes + grads + 3 * b * t * cfg.vocab * 4)
 
 
 def _ids(batch):
@@ -1389,7 +969,7 @@ def _ids(batch):
 
 
 def _remat_names(params, batch, cfg: Config) -> Tuple[str, ...]:
-    """`remat_keep` on what this trace has."""
+    """models/remat.py's ``remat_keep`` on what this trace has."""
     if not cfg.remat:
         return ()
     patches = batch["patches"].shape[0] if isinstance(batch, dict) else 0
@@ -1398,130 +978,63 @@ def _remat_names(params, batch, cfg: Config) -> Tuple[str, ...]:
         return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
     # the layers of a pattern are unlike (an expert layer's parameters
-    # are 4.6x a state-space layer's in the sixth model): the largest.
+    # are 4.6x a state-space layer's in Nemotron-3-Nano): the largest.
     # The blocks' keep-sets were fitted on the chip with the mean
     # (PERF.md 6, PR 35 and PR 37) and stay on it
     largest = max(map(nbytes, params["layers"])) \
         if cfg.layer_pattern is not None else None
-    return remat_keep(cfg, *_ids(batch).shape, nbytes(params),
-                      _memory_limit(), patches, largest)
-
-
-def _recomputed_layer(cfg: Config, ax: Axes, is_moe: bool,
-                      keep: Tuple[str, ...], fixed_offset: bool,
-                      counted: dict):
-    """layer_forward as a function of arrays — (lp, h, pos_offset) ->
-    (the block's output, what it collected for `aux`, for `index_aux`)
-    — recomputed in the backward pass but for the names in `keep`.
-    Where `fixed_offset`, pos_offset is None or a Python int and part
-    of the program, as a value the function closed over would be. jax
-    traces the function when it likes (once behind `_kept_layer`'s
-    `jit`), so what layer_forward counts (pvars) while traced on an
-    input of a shape and type is set aside in `counted`, for the caller
-    to count once per application."""
-    def layer(lp, h, pos_offset):
-        mine, index_mine = [], []
-        with pvar.captured() as counts:
-            out = layer_forward(lp, h, cfg, ax, is_moe,
-                                pos_offset=pos_offset, aux=mine,
-                                index_aux=index_mine)
-        counted[h.shape, h.dtype] = counts
-        return out, mine, index_mine
-
-    # no policy where nothing is kept: under one, even an empty one, jax
-    # splits every inner jitted function afresh at each call site
-    return jax.checkpoint(
-        layer, policy=jax.checkpoint_policies.save_only_these_names(*keep)
-        if keep else None, static_argnums=(2,) if fixed_offset else ())
-
-
-def _kept_layer(cfg: Config, ax: Axes, is_moe: bool, keep: Tuple[str, ...],
-                fixed_offset: bool, counted: dict):
-    """`_recomputed_layer` as ONE jitted function: with a policy jax
-    splits every inner jitted function (the attention kernels', the
-    activation's) into what is kept and what is made again, afresh at
-    each call site — 48 applications of the same layer traced, split
-    and lowered 48 times, kernels and all (ouro-train-t4096's step:
-    17 s of tracing and 6 of lowering where the recomputation with no
-    policy, which splits nothing, takes 6 and 2; PERF.md section 6,
-    PR 35). Behind a `jit` of its own the layer is traced, linearized,
-    split, transposed and lowered once per kind and shape, and called;
-    XLA inlines the calls."""
-    return jax.jit(
-        _recomputed_layer(cfg, ax, is_moe, keep, fixed_offset, counted),
-        static_argnums=(2,) if fixed_offset else ())
+    return remat.remat_keep(
+        *step_costs(cfg, *_ids(batch).shape, nbytes(params), patches,
+                    largest), remat._memory_limit())
 
 
 class _Recomputed:
-    """The recomputed layers of ONE trace of a step: the names they
-    keep (`remat_keep`'s answer for the trace) and each layer kind as
-    one jitted function (`_kept_layer`) shared by that kind's
-    applications in this trace — and in no other, so a later trace
-    sees the rules and the device as they are then."""
+    """layer_forward for the applications of ONE trace of a step,
+    recomputed in the backward pass where the config says so: the
+    names the applications keep (the rule's answer for the trace) and
+    each layer kind as one models/remat.py ``Recomputed``, shared by
+    that kind's applications in this trace and in no other. The
+    backward pass is given the layer's input and what the application
+    made under those names, and makes the rest again; with no name
+    kept — the fallback: a device that states no limit or has no room —
+    that is the whole layer, from its input. ONE path either way.
+    Counted once per traced application: models/remat.py's three
+    ``remat_*`` pvars, and whatever layer_forward counts of itself."""
 
     def __init__(self, cfg: Config, ax: Axes, keep: Tuple[str, ...]):
         self.cfg, self.ax, self.keep = cfg, ax, keep
-        self._kinds = {}  # (is_moe, fixed_offset) -> (layer, counted)
+        self._kinds = {}  # (is_moe, fixed_offset) -> the kind's function
 
-    def __call__(self, lp, h, is_moe: bool, pos_offset):
+    def __call__(self, lp, h, is_moe: bool, pos_offset, aux, index_aux):
+        """The layer's output; what it collects for `aux` and
+        `index_aux` leaves the recomputed region as results. A
+        pos_offset that is None or a Python int is a static argument
+        (part of the program, as a value the function closed over would
+        be), an `axis_index` a traced one."""
+        cfg, ax = self.cfg, self.ax
+        if not cfg.remat:
+            return layer_forward(lp, h, cfg, ax, is_moe,
+                                 pos_offset=pos_offset, aux=aux,
+                                 index_aux=index_aux)
         fixed = pos_offset is None or isinstance(pos_offset, int)
         if (is_moe, fixed) not in self._kinds:
-            counted = {}
-            self._kinds[is_moe, fixed] = _kept_layer(
-                self.cfg, self.ax, is_moe, self.keep, fixed,
-                counted), counted
-        layer, counted = self._kinds[is_moe, fixed]
-        results = layer(lp, h, pos_offset)
-        for name, count in counted[h.shape, h.dtype].items():
-            pvar.record(name, count)
-        return results
+            def layer(lp, h, pos_offset):
+                mine, index_mine = [], []
+                out = layer_forward(lp, h, cfg, ax, is_moe,
+                                    pos_offset=pos_offset, aux=mine,
+                                    index_aux=index_mine)
+                return out, mine, index_mine
 
-
-def _run_layer(lp, h, cfg: Config, ax: Axes, is_moe: bool, pos_offset,
-               aux, index_aux, recomputed: _Recomputed):
-    """layer_forward, recomputed in the backward pass where the config
-    says so (what a layer collects for `aux` and `index_aux` then
-    leaves the recomputed region as results). The backward pass is
-    given the layer's input and what the application made under the
-    names `recomputed` keeps, and makes the rest again; with no name
-    kept — the fallback: a device that states no limit or has no room —
-    that is the whole layer, from its input, as before there were
-    names. ONE path either way. Counted once per traced application:
-    pvars ``remat_kept_applications`` or ``remat_whole_applications``,
-    ``remat_kept_bytes`` (the rule's reckoning of what it holds), and
-    whatever layer_forward counts of itself."""
-    if not cfg.remat:
-        return layer_forward(lp, h, cfg, ax, is_moe, pos_offset=pos_offset,
-                             aux=aux, index_aux=index_aux)
-    sizes = remat_sizes(cfg, h.shape[0], h.shape[1], is_moe)
-    pvar.record("remat_kept_applications" if recomputed.keep
-                else "remat_whole_applications")
-    pvar.record("remat_kept_bytes",
-                sum(sizes.get(name, 0) for name in recomputed.keep))
-    out, mine, index_mine = recomputed(lp, h, is_moe, pos_offset)
-    if aux is not None:
-        aux.extend(mine)
-    if index_aux is not None:
-        index_aux.extend(index_mine)
-    return out
-
-
-def _vision_rows(params, batch, cfg: Config, keep: Tuple[str, ...]):
-    """The tower's merged rows for the batch's packed images
-    (models/vision.py), its blocks recomputed as the decoder's layers
-    are and counted as `_run_layer` counts those."""
-    from ompi_tpu.models import vision
-
-    vc = cfg.vision
-    if cfg.remat:
-        sizes = vision.remat_sizes(vc, batch["patches"].shape[0],
-                                   jnp.dtype(cfg.dtype).itemsize)
-        pvar.record("remat_kept_applications" if keep
-                    else "remat_whole_applications", vc.n_layers)
-        pvar.record("remat_kept_bytes", vc.n_layers * sum(
-            sizes.get(name, 0) for name in keep))
-    return vision.tower(params["vision"], batch, vc, cfg.dtype, cfg.remat,
-                        keep)
+            self._kinds[is_moe, fixed] = remat.Recomputed(
+                layer, self.keep,
+                layer_costs(cfg, h.shape[0], h.shape[1], is_moe).sizes,
+                static_argnums=(2,) if fixed else ())
+        out, mine, index_mine = self._kinds[is_moe, fixed](lp, h, pos_offset)
+        if aux is not None:
+            aux.extend(mine)
+        if index_aux is not None:
+            index_aux.extend(index_mine)
+        return out
 
 
 def _trunk(params, batch, cfg: Config, ax: Axes, aux=None, index_aux=None,
@@ -1557,11 +1070,10 @@ def _trunk(params, batch, cfg: Config, ax: Axes, aux=None, index_aux=None,
                 if ax.sp else params["pos"][:t]
             h = h + pos.astype(dt)[None]
         if cfg.vision is not None:
-            from ompi_tpu.models import vision
-
             _check_supported(cfg, ax, False, t_off)
-            h = vision.place(h, _vision_rows(params, batch, cfg, keep),
-                             batch["image_positions"])
+            rows = vision.tower(params["vision"], batch, cfg.vision, dt,
+                                cfg.remat, keep)
+            h = vision.place(h, rows, batch["image_positions"])
 
     recomputed = _Recomputed(cfg, ax, keep)
     for s in range(cfg.loops):
@@ -1571,8 +1083,8 @@ def _trunk(params, batch, cfg: Config, ax: Axes, aux=None, index_aux=None,
             for i, lp in enumerate(params["layers"]):
                 pvar.record("loop_layer_applications")
                 with jax.named_scope(f"layer_{i}"):
-                    h = _run_layer(lp, h, cfg, ax, _layer_kind(cfg, i),
-                                   t_off, aux, index_aux, recomputed)
+                    h = recomputed(lp, h, _layer_kind(cfg, i), t_off, aux,
+                                   index_aux)
             if s < cfg.loops - 1:
                 h = _final_norm(params, h, cfg)
                 if exits is not None:
@@ -1634,9 +1146,8 @@ def _mtp_forward(mp, h, params, labels, cfg: Config, ax: Axes, pos_offset,
             [_norm(h.astype(jnp.float32), mp["hnorm"], cfg),
              _norm(e.astype(jnp.float32), mp["enorm"], cfg)], axis=-1)
         h = both.astype(dt) @ mp["eh_proj"].astype(dt)
-    return _run_layer(mp, h, cfg, ax, _is_moe(cfg, cfg.n_layers),
-                      pos_offset, aux, index_aux, _Recomputed(
-                          cfg, ax, _remat_names(params, labels, cfg)))
+    return _Recomputed(cfg, ax, _remat_names(params, labels, cfg))(
+        mp, h, _is_moe(cfg, cfg.n_layers), pos_offset, aux, index_aux)
 
 
 def _token_nll(logits, labels, mask):
@@ -1844,7 +1355,8 @@ def dsa_selection(params, tokens, cfg: Config):
 
 @_probe("ompi_vision_rows")
 def _vision_probe(params, batch, cfg: Config):
-    return _vision_rows(params, batch, cfg, ())
+    return vision.tower(params["vision"], batch, cfg.vision, cfg.dtype,
+                        cfg.remat)
 
 
 def vision_rows(params, batch, cfg: Config):
@@ -1909,7 +1421,8 @@ def _ssm_probe(params, tokens, cfg: Config):
 
 @_probe("ompi_gqa_probe")
 def _gqa_probe(params, tokens, cfg: Config):
-    return _gqa_attention(*_first_of(ATTENTION, params, tokens, cfg), cfg)
+    return _attention(*_first_of(ATTENTION, params, tokens, cfg), cfg,
+                      Axes(), None)
 
 
 def gqa_probe(params, tokens, cfg: Config):

@@ -1,7 +1,7 @@
 """A native-resolution vision tower in front of the decoder.
 
-The fifth published model ``models/transformer.py`` computes (Kimi-VL's
-decoder; reference ``benchmark/reference/kimivl_decoder.py``) reads
+A decoder of ``models/transformer.py`` whose ``Config.vision`` is set
+(Kimi-VL's; reference ``benchmark/reference/kimivl_decoder.py``) reads
 images: a MoonViT-style encoder turns each image's 14 x 14 patches into
 rows, a projector merges every 2 x 2 neighbourhood of them into ONE row
 of the decoder's width, and those rows take the place of the
@@ -48,10 +48,10 @@ tower makes IS the embedding of the image positions): ``vision`` >
 ``attn_proj`` > ``rope2d``, ``attn_core``, ``mlp``}, ``vit_merge``
 (final norm, merge, projector; the scatter into the sequence is the
 caller's, under ``embed/vision/vit_merge`` too). With ``Config.remat``
-every block is recomputed in the backward pass but for the named values
-the decoder's rule ``transformer.remat_keep`` chose for the trace (the
-tower's 27 applications are a layer kind of that rule,
-``transformer.VIT``).
+every block is recomputed in the backward pass (models/remat.py's
+`Recomputed`) but for the named values the rule chose for the trace:
+the tower's 27 applications enter it with `application`'s costs beside
+the decoder's layers.
 """
 
 from __future__ import annotations
@@ -67,6 +67,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ompi_tpu.core import pvar
+from ompi_tpu.models.remat import (ATTN_PROJ_OUT, MLP_UP, Application,
+                                   Recomputed)
 from ompi_tpu.ops import attention as att
 
 
@@ -267,8 +269,6 @@ def _embed(vp, batch, dt):
 
 def layer_forward(lp, h, angles, image_ids, vc: VisionConfig, dt):
     """One block of the tower on the packed row h [P, d]."""
-    from ompi_tpu.models import transformer as tfm
-
     p, d = h.shape
     heads, dh = vc.n_heads, vc.head_dim
     x = _ln(h, lp["ln1"], vc.norm_eps).astype(dt)
@@ -286,11 +286,11 @@ def layer_forward(lp, h, angles, image_ids, vc: VisionConfig, dt):
     with jax.named_scope("attn_proj"):
         h = h + checkpoint_name(
             o.reshape(p, d) @ lp["wo"].astype(dt) + lp["bo"].astype(dt),
-            tfm.ATTN_PROJ_OUT)
+            ATTN_PROJ_OUT)
     x = _ln(h, lp["ln2"], vc.norm_eps).astype(dt)
     with jax.named_scope("mlp"):
         u = checkpoint_name(x @ lp["w1"].astype(dt) + lp["b1"].astype(dt),
-                            tfm.MLP_UP)
+                            MLP_UP)
         return h + (jax.nn.gelu(u, approximate=True) @ lp["w2"].astype(dt)
                     + lp["b2"].astype(dt))
 
@@ -306,47 +306,35 @@ def _merge(vp, h, merge_index, vc: VisionConfig, dt):
         return u @ pp["w2"].astype(dt) + pp["b2"].astype(dt)
 
 
-def _recomputed(fn, keep: Tuple[str, ...], remat: bool):
-    """`fn` as ONE jitted function recomputed in the backward pass but
-    for the names in `keep` (transformer._kept_layer's reasons), or as
-    it is."""
-    if not remat:
-        return fn
-    return jax.jit(jax.checkpoint(
-        fn, policy=jax.checkpoint_policies.save_only_these_names(*keep)
-        if keep else None))
-
-
 def tower(vp, batch, vc: VisionConfig, dt, remat: bool = False,
           keep: Tuple[str, ...] = ()):
     """The merged, projected rows [P / m^2, d_out] of a batch's packed
-    images, in `dt`. Counted once per traced step: pvars
+    images, in `dt`. Where `remat`, every block is one application of
+    the recomputation rule, keeping `keep`, and the embedding and the
+    merge are recomputed whole. Counted once per traced step: pvars
     ``vision_patches``, ``vision_image_positions``, and per application
-    what the attention counts of itself."""
+    what the attention counts of itself and the rule of its own."""
     pvar.record("vision_patches", batch["patches"].shape[0])
     pvar.record("vision_image_positions", batch["image_positions"].shape[0])
-    counted = {}
 
     def block(lp, h, angles, image_ids):
-        with pvar.captured() as counts:
-            out = layer_forward(lp, h, angles, image_ids, vc, dt)
-        counted.update(counts)
-        return out
+        return layer_forward(lp, h, angles, image_ids, vc, dt)
 
-    block = _recomputed(block, keep, remat)
+    def whole(fn):  # recomputed from its input; no application of the rule
+        return Recomputed(fn, ()) if remat else fn
+
+    if remat:
+        block = Recomputed(block, keep, application(
+            vc, batch["patches"].shape[0], jnp.dtype(dt).itemsize).sizes)
     with jax.named_scope("vision"):
-        h = _recomputed(lambda vp, b: _embed(vp, b, dt), (), remat)(
+        h = whole(lambda vp, b: _embed(vp, b, dt))(
             {"patch": vp["patch"], "pos": vp["pos"]},
             {n: batch[n] for n in ("patches", "pos_index", "pos_weight")})
         angles = rope2d_angles(batch["patch_pos"], vc)
         for i, lp in enumerate(vp["layers"]):
             with jax.named_scope(f"vit_{i}"):
                 h = block(lp, h, angles, batch["image_ids"])
-            # traced once where jitted, counted once per application
-            for name, count in counted.items():
-                pvar.record(name, count)
-        return _recomputed(
-            lambda vp, h, index: _merge(vp, h, index, vc, dt), (), remat)(
+        return whole(lambda vp, h, index: _merge(vp, h, index, vc, dt))(
             {"ln_f": vp["ln_f"], "proj": vp["proj"]}, h,
             batch["merge_index"])
 
@@ -362,33 +350,24 @@ def place(h, rows, image_positions):
 
 # -- the recomputation rule's layer kind -----------------------------------------
 
-def remat_sizes(vc: VisionConfig, patches: int, itemsize: int) -> Dict[str, int]:
-    """Bytes ONE application of a tower block holds under each name,
-    of `patches` rows: q, k, v and the output as the segment kernels
-    hold them — ``[heads, patches, head_dim]``, where the device's
-    tiled layout gives every row of a head whole lanes (72 lie in 128)
-    though no copy pads them — and the per-row log-sum-exp as
-    ``[heads, patches]`` float32."""
-    from ompi_tpu.models import transformer as tfm
-
-    n, wide = patches, att.lanes(vc.head_dim)
-    return {tfm.ATTN_PROJ_OUT: n * vc.d_model * itemsize,
-            tfm.MLP_UP: n * vc.d_ff * itemsize,
-            att.ATTN_OUT: n * vc.n_heads * (wide * itemsize + 4),
-            att.QKV: 3 * n * vc.n_heads * wide * itemsize}
-
-
-def remat_spared(vc: VisionConfig, patches: int) -> Dict[str, int]:
-    """The operations of the products a block's backward pass need not
-    make again where a name is kept. Which patches share an image is
-    data, so attention's is reckoned over HALF the packed row's square,
-    as a causal layer's is (the cell's four images fill 0.345 of
-    it)."""
-    from ompi_tpu.models import transformer as tfm
-
-    n, d = patches, vc.d_model
-    return {tfm.ATTN_PROJ_OUT: 2 * n * d * d, tfm.MLP_UP: 2 * n * d * vc.d_ff,
-            att.ATTN_OUT: 2 * n * n * d, att.QKV: 3 * 2 * n * d * d}
+def application(vc: VisionConfig, patches: int, itemsize: int) -> Application:
+    """What ONE application of a tower block over `patches` rows costs
+    the recomputation rule. Bytes: q, k, v and the output as the
+    segment kernels hold them — ``[heads, patches, head_dim]``, where
+    the device's tiled layout gives every row of a head whole lanes (72
+    lie in 128) though no copy pads them — and the per-row log-sum-exp
+    as ``[heads, patches]`` float32. Spared operations: which patches
+    share an image is data, so attention's is reckoned over HALF the
+    packed row's square, as a causal layer's is (the cell's four images
+    fill 0.345 of it)."""
+    n, d, wide = patches, vc.d_model, att.lanes(vc.head_dim)
+    return Application(
+        {ATTN_PROJ_OUT: n * d * itemsize, MLP_UP: n * vc.d_ff * itemsize,
+         att.ATTN_OUT: n * vc.n_heads * (wide * itemsize + 4),
+         att.QKV: 3 * n * vc.n_heads * wide * itemsize},
+        {ATTN_PROJ_OUT: 2 * n * d * d, MLP_UP: 2 * n * d * vc.d_ff,
+         att.ATTN_OUT: 2 * n * n * d, att.QKV: 3 * 2 * n * d * d},
+        n * d * itemsize)
 
 
 # -- a set-up probe ------------------------------------------------------------

@@ -1,8 +1,10 @@
 """What a lowered step's text says, apart from the residuals' names
 (PR 35).
 
-The names (`jax.ad_checkpoint.checkpoint_name` in models/transformer.py
-and ops/attention.py) lower to their operands and leave no operation.
+The names (`jax.ad_checkpoint.checkpoint_name` in models/transformer.py,
+models/vision.py, ops/attention.py and ops/ssm.py; models/remat.py owns
+the models' names and calls it nowhere) lower to their operands and
+leave no operation.
 But jax lowers EVERY equation through a private function named after
 its primitive (inlined again at once), and MLIR's symbol table tells
 two of a name apart by a counter the whole module shares: one more
@@ -17,15 +19,17 @@ import hashlib
 import re
 
 from ompi_tpu.models import transformer as tfm
+from ompi_tpu.models import vision
 from ompi_tpu.ops import attention as att
+from ompi_tpu.ops import ssm
 
 _NUMBERED = re.compile(r"@([A-Za-z_][A-Za-z_0-9]*?)_(\d+)\b")
 
 
 def without_names(monkeypatch):
     """From here on in this test `checkpoint_name` is the identity in
-    both modules that name residuals."""
-    for module in (tfm, att):
+    every module that names residuals."""
+    for module in (tfm, vision, att, ssm):
         monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
 
 

@@ -134,7 +134,7 @@ def test_latent_attention_is_plain_mha_of_the_expanded_matrices(interleave):
         (b, t, d)), jnp.float32)
     x = tfm._norm(hidden, lp["ln1"], cfg)
     with jax.default_matmul_precision("highest"):
-        got = tfm._mla_attention(lp, hidden, x, cfg, None, None) - hidden
+        got = tfm._mla_attention(lp, x, cfg, None, None)
         turn = tfm.rope_interleaved if interleave else tfm.rope
         pos = jnp.arange(t)
         c_q = tfm._rms(x @ lp["wq_a"], lp["q_a_norm"]["g"], cfg.norm_eps)
@@ -338,8 +338,8 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(seed, shares, seq,
                                 "held_experts": (s * per, per)})
             mine = dict(lp, **{n: lp[n][s * per:(s + 1) * per]
                                for n in ("w1", "w3", "w2")})
-            routed = routed + tfm._ffn_half(mine, h, cfg, AX, True,
-                                            None) - h - shared
+            routed = routed + tfm._sublayer(
+                mine, h, cfg, AX, tfm.layout(cfg, True)[1]) - h - shared
         want = ref.ffn_block(lp, h, gt.reference_spec(sizes)) - h
     _close(routed + shared, want, rel=1e-5)
     assert float(jnp.linalg.norm(routed)) > 0.1 * float(

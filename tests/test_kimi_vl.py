@@ -20,6 +20,7 @@ sys.path.insert(0, HERE)
 
 from benchmark.reference import kimivl_decoder as ref  # noqa: E402
 from ompi_tpu.core import pvar  # noqa: E402
+from ompi_tpu.models import remat  # noqa: E402
 from ompi_tpu.models import transformer as tfm  # noqa: E402
 from ompi_tpu.models import vision  # noqa: E402
 from ompi_tpu.ops import attention as att  # noqa: E402
@@ -283,7 +284,7 @@ def test_plain_q_latent_attention_is_mha_at_192_over_128_shape():
     want = h + att.mha(q, k, v, causal=True).reshape(1, 16, -1) @ lp["wo"]
     before = {n: pvar.read(n) for n in ("attn_mla_plain_q_layers",
                                         "attn_reference_layers")}
-    got = tfm._mla_attention(lp, h, x, cfg, None, None)
+    got = h + tfm._mla_attention(lp, x, cfg, None, None)
     np.testing.assert_allclose(got, want, atol=1e-5)
     assert all(pvar.read(n) == v + 1 for n, v in before.items())
 
@@ -456,18 +457,21 @@ def test_rule_reckons_the_tower_s_applications():
     kinds = tfm._application_kinds(cfg)
     assert kinds == [tfm.VIT] * 2 + [False, True]
     assert tfm._application_kinds(config(vision=None)) == [False, True]
-    with_tower = tfm.whole_step_peak(cfg, 1, T, 0, 40)
-    without = tfm.whole_step_peak(config(vision=None, remat=True), 1, T, 0)
+    with_tower = remat.whole_step_peak(*tfm.step_costs(cfg, 1, T, 0, 40))
+    without = remat.whole_step_peak(*tfm.step_costs(
+        config(vision=None, remat=True), 1, T, 0))
     assert with_tower >= without + VC.n_layers * 40 * VC.d_model * 4
-    sizes = vision.remat_sizes(VC, 40, 4)  # float32 activations here
+    sizes = vision.application(VC, 40, 4).sizes  # float32 activations
     assert sizes[att.QKV] == 3 * 40 * VC.n_heads * 128 * 4  # lanes of 128
-    held = dict(tfm.remat_order(cfg, 1, T, 40))
-    alone = dict(tfm.remat_order(config(vision=None, remat=True), 1, T))
+    order = remat.remat_order(tfm.step_costs(cfg, 1, T, patches=40)[0])
+    held = dict(order)
+    alone = dict(remat.remat_order(tfm.step_costs(
+        config(vision=None, remat=True), 1, T)[0]))
     for name, size in sizes.items():
         assert held[name] == alone.get(name, 0) + VC.n_layers * size
-    assert tfm.remat_keep(cfg, 1, T, 10 ** 6, None, 40) == ()
-    assert tfm.remat_keep(cfg, 1, T, 10 ** 6, 10 ** 12, 40) == tuple(
-        n for n, _ in tfm.remat_order(cfg, 1, T, 40))
+    costs = tfm.step_costs(cfg, 1, T, 10 ** 6, 40)
+    assert remat.remat_keep(*costs, None) == ()
+    assert remat.remat_keep(*costs, 10 ** 12) == tuple(n for n, _ in order)
 
 
 def test_kept_names_change_nothing_but_what_is_recomputed(params,
@@ -476,7 +480,7 @@ def test_kept_names_change_nothing_but_what_is_recomputed(params,
     cfg = config(remat=True)
     fn = lambda p: tfm.loss_local(p, batch, labels, cfg, AX)[0]  # noqa: E731
     whole = jax.value_and_grad(fn)(params)
-    monkeypatch.setattr(tfm, "_memory_limit", lambda: 10 ** 12)
+    monkeypatch.setattr(remat, "_memory_limit", lambda: 10 ** 12)
     before = pvar.read("remat_kept_applications")
     kept = jax.value_and_grad(fn)(params)
     assert pvar.read("remat_kept_applications") == before + VC.n_layers + 2

@@ -21,6 +21,7 @@ sys.path.insert(0, HERE)
 from benchmark import weights, weights_nemotron  # noqa: E402
 from benchmark.reference import nemotron_decoder as ref  # noqa: E402
 from ompi_tpu.core import pvar  # noqa: E402
+from ompi_tpu.models import remat  # noqa: E402
 from ompi_tpu.models import transformer as tfm  # noqa: E402
 from ompi_tpu.ops import attention as att  # noqa: E402
 from ompi_tpu.ops import moe, ssm  # noqa: E402
@@ -260,13 +261,14 @@ def test_kept_names_change_no_gradient(params, batch, monkeypatch):
     toks, labs = batch
     cfg = config(remat=True)
     whole = jax.jit(jax.grad(_mean_loss(cfg, toks[0], labs[0])))(params)
-    names = tuple(n for n, _ in tfm.remat_order(cfg, B, T))
+    order = remat.remat_order(tfm.step_costs(cfg, B, T)[0])
+    names = tuple(n for n, _ in order)
     monkeypatch.setattr(tfm, "_remat_names", lambda p, t, c: names)
     s = pvar.session()
     kept = jax.jit(jax.grad(_mean_loss(cfg, toks[0], labs[0])))(params)
     assert s.read("remat_kept_applications") == 5
     assert s.read("remat_kept_bytes") == sum(
-        held for _, held in tfm.remat_order(cfg, B, T))
+        held for _, held in order)
     for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(kept)):
         assert (np.asarray(a) == np.asarray(b)).all()
 
@@ -288,15 +290,15 @@ def test_shared_key_heads_are_mha_over_repeated_heads(params):
             k = k + separate
         return att.mha(q, k, v, causal=True).reshape(B, T, 64) @ lp["wo"]
 
-    out = highest(tfm._gqa_attention, lp, x, cfg)
+    out = highest(tfm._attention, lp, x, cfg, AX, None)
     close(out, highest(by_hand, lp, x))
     # the gradient of each REPEATED key head, summed over a key head's
     # queries, is the shared key head's
     zero = jnp.zeros((B, T, 4, 16))
     per_query_head = highest(jax.grad(
         lambda s: (by_hand(lp, x, s) ** 2).sum()), zero)
-    shared = highest(jax.grad(lambda k_: (tfm._gqa_attention(
-        dict(lp, wk=k_), x, cfg) ** 2).sum()), lp["wk"])
+    shared = highest(jax.grad(lambda k_: (tfm._attention(
+        dict(lp, wk=k_), x, cfg, AX, None) ** 2).sum()), lp["wk"])
     summed = per_query_head.reshape(B, T, 2, 2, 16).sum(3).reshape(B, T, 32)
     close(shared, jnp.einsum("btd,bte->de", x, summed), 1e-4)
 
@@ -311,11 +313,86 @@ def test_no_positions_means_no_table_and_no_rotation(params, batch):
     lp, x = params["layers"][2], jax.random.normal(jax.random.key(1),
                                                    (1, T, 32))
     perm = jnp.concatenate([jnp.arange(T - 1)[::-1], jnp.array([T - 1])])
-    out = highest(tfm._gqa_attention, lp, x, cfg)
-    mixed = highest(tfm._gqa_attention, lp, x[:, perm], cfg)
+    out = highest(tfm._attention, lp, x, cfg, AX, None)
+    mixed = highest(tfm._attention, lp, x[:, perm], cfg, AX, None)
     close(out[:, -1], mixed[:, -1])
     with pytest.raises(ValueError, match="pos='alibi'"):
         tfm.layer_forward(lp, x, config(pos="alibi"), AX, tfm.ATTENTION)
+
+
+@pytest.mark.parametrize("pattern", [True, False], ids=["pattern", "block"])
+@pytest.mark.parametrize("pos, qk_norm", [
+    ("rope", False), ("none", True), ("rope", True), ("none", False)])
+def test_one_attention_computes_what_two_refused(pattern, pos, qk_norm):
+    """Until PR 42 a pattern's attention refused RoPE and QK-norm and
+    the block refused shared key heads, a head width of its own and no
+    positions: the ONE mixer computes every combination — here against
+    `att.mha` over the key heads repeated by hand, the norms over the
+    whole projection and the rotation on the split heads."""
+    cfg = config(pos=pos, qk_norm=qk_norm) if pattern else config(
+        pos=pos, qk_norm=qk_norm, layer_pattern=None, n_layers=2,
+        moe_every=1, max_seq=T)
+    lp = tfm.init_params(np.random.default_rng(3), cfg)["layers"][
+        2 if pattern else 0]
+    rng = np.random.default_rng(4)
+    if qk_norm:  # gains that are not 1, of the projections' own widths
+        assert lp["q_norm"]["g"].shape == (64,)
+        assert lp["k_norm"]["g"].shape == (32,)
+        lp = dict(lp, q_norm={"g": rng.uniform(0.5, 2.0, 64)},
+                  k_norm={"g": rng.uniform(0.5, 2.0, 32)})
+    lp = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), lp)
+    x = jax.random.normal(jax.random.key(5), (B, T, 32))
+
+    def by_hand(lp, x):
+        q, k, v = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
+        if qk_norm:
+            q = tfm._rms(q, lp["q_norm"]["g"], cfg.norm_eps)
+            k = tfm._rms(k, lp["k_norm"]["g"], cfg.norm_eps)
+        q, k, v = (a.reshape(B, T, -1, 16) for a in (q, k, v))
+        if pos == "rope":
+            q = tfm.rope(q, jnp.arange(T), cfg.rope_theta)
+            k = tfm.rope(k, jnp.arange(T), cfg.rope_theta)
+        k, v = k[:, :, [0, 0, 1, 1]], v[:, :, [0, 0, 1, 1]]
+        return att.mha(q, k, v, causal=True).reshape(B, T, 64) @ lp["wo"]
+
+    close(highest(tfm._attention, lp, x, cfg, AX, None),
+          highest(by_hand, lp, x))
+    # and the layer around it: the pre-norm's leaf by the layout's name
+    name = "ln" if pattern else "ln1"
+    kind = tfm.ATTENTION if pattern else True
+    want = x + highest(by_hand, lp, tfm._norm(x, lp[name], cfg))
+    if pattern:
+        close(highest(tfm.layer_forward, lp, x, cfg, AX, kind), want)
+    else:
+        close(highest(tfm._sublayer, lp, x, cfg, AX,
+                      tfm.layout(cfg, kind)[0]), want)
+
+
+def test_the_block_reads_the_shared_experts_own_width(params):
+    """`shared_d_ff` in the block (refused until PR 42): its expert
+    half is the pattern's expert layer on the same leaves, and a whole
+    step of such a block — shared key heads, a head width of its own,
+    RoPE — has a gradient in every leaf that weighs anything."""
+    lp = params["layers"][1]
+    h = jax.random.normal(jax.random.key(9), (B, T, 32))
+    cfg = config(layer_pattern=None, n_layers=2, moe_every=1, pos="rope",
+                 max_seq=T)
+    assert cfg.shared_width == 40 != cfg.n_shared_experts * cfg.expert_d_ff
+    row = tfm.layout(cfg, True)[1]
+    assert (row.mixer, row.pre) == ("experts", "ln2")
+    close(highest(tfm._sublayer, dict(lp, ln2=lp["ln"]), h, cfg, AX, row),
+          highest(tfm.layer_forward, lp, h, config(), AX, tfm.EXPERTS))
+    tree = jax.tree.map(jnp.asarray, tfm.init_params(
+        np.random.default_rng(0), cfg))
+    assert tree["layers"][0]["ws1"].shape == (32, 40)
+    assert tree["layers"][0]["wk"].shape == (32, 32)  # 2 heads of 16
+    toks, labs = weights.batches(64, 1, B, T, 7)
+    grads = jax.grad(_mean_loss(cfg, toks[0], labs[0]))(tree)
+    for path, g in jax.tree_util.tree_leaves_with_path(grads):
+        assert np.isfinite(np.asarray(g)).all()
+        # the router's bias picks experts and weighs nothing: no gradient
+        assert float(jnp.abs(g).max()) > 0 or path[-1].key == "wg_bias", \
+            jax.tree_util.keystr(path)
 
 
 # -- experts -----------------------------------------------------------------------
@@ -344,15 +421,15 @@ def test_the_shares_add_up_to_the_uncut_layer(params, share):
         mine = dict(lp, w1=lp["w1"][first:first + share],
                     w2=lp["w2"][first:first + share])
         cfg = config(held_experts=(first, share))
-        total = total + highest(tfm._mixer_layer, mine, h, cfg, tfm.EXPERTS,
-                                None) - h - shared
+        total = total + highest(tfm.layer_forward, mine, h, cfg, AX,
+                                tfm.EXPERTS) - h - shared
     close(total + shared, whole, 1e-4)
     # and the reference cut the same way
     part = ref.experts(dict(lp, w1=lp["w1"][4:8], w2=lp["w2"][4:8]), x,
                        SPEC._replace(held_first=4))
-    close(highest(tfm._mixer_layer, dict(lp, w1=lp["w1"][4:8],
-                                         w2=lp["w2"][4:8]), h,
-                  config(held_experts=(4, 4)), tfm.EXPERTS, None) - h, part,
+    close(highest(tfm.layer_forward, dict(lp, w1=lp["w1"][4:8],
+                                          w2=lp["w2"][4:8]), h,
+                  config(held_experts=(4, 4)), AX, tfm.EXPERTS) - h, part,
           1e-4)
 
 
@@ -374,7 +451,7 @@ def test_a_width_padded_to_the_lanes_is_the_same_layer(params, monkeypatch,
     assert moe.expert_width_pad("tpu", 2048) == 0
 
     def value(lp):
-        out = tfm._mixer_layer(lp, h, cfg, tfm.EXPERTS, None)
+        out = tfm.layer_forward(lp, h, cfg, AX, tfm.EXPERTS)
         return (out * out).sum(), out
 
     (_, plain), grads = highest(jax.value_and_grad(value, has_aux=True), lp)
@@ -421,9 +498,10 @@ def test_a_pattern_under_an_axis_raises(params, axis, says):
     (dict(layer_pattern="ME-EM"), ValueError),       # a dense FFN alone
     (dict(n_kv_heads=3), ValueError),                # 4 heads over 3
     (dict(ssm_groups=3), ValueError),                # 4 heads in 3 groups
-    (dict(pos="rope"), NotImplementedError),
-    (dict(qk_norm=True), NotImplementedError),
-    (dict(layer_pattern=None, n_layers=2), NotImplementedError)])
+    (dict(post_norm=True), NotImplementedError),     # no leaf for it
+    (dict(attn="mla"), NotImplementedError),         # a block's mixer
+    # the block reads n_kv_heads too since PR 42: 4 heads over 3
+    (dict(layer_pattern=None, n_layers=2, n_kv_heads=3), ValueError)])
 def test_a_config_the_layers_cannot_compute_raises(params, kw, error):
     cfg = config(**kw)
     with pytest.raises(error):
@@ -437,14 +515,14 @@ def test_the_three_kinds_are_kinds_of_the_rule():
     cfg = config(dtype=jnp.bfloat16)
     assert tfm._application_kinds(cfg) == list(PATTERN)
     n = B * T
-    assert tfm.remat_sizes(cfg, B, T, tfm.SSM) == {
+    assert tfm.layer_costs(cfg, B, T, tfm.SSM).sizes == {
         ssm.SSM_IN: n * (32 + 96 + 4) * 2, ssm.SSM_CONV: n * 96 * 2,
         ssm.SSM_Y: n * 32 * 2}
-    assert tfm.remat_sizes(cfg, B, T, tfm.ATTENTION) == {
+    assert tfm.layer_costs(cfg, B, T, tfm.ATTENTION).sizes == {
         att.ATTN_OUT: n * 4 * (16 * 2 + 4), att.QKV: 3 * n * 64 * 2}
-    assert tfm.remat_sizes(cfg, B, T, tfm.EXPERTS) == {
+    assert tfm.layer_costs(cfg, B, T, tfm.EXPERTS).sizes == {
         tfm.MLP_UP: n * 40 * 2}
-    order = tfm.remat_order(cfg, B, T)
+    order = remat.remat_order(tfm.step_costs(cfg, B, T)[0])
     assert {name for name, _ in order} == {
         ssm.SSM_IN, ssm.SSM_CONV, ssm.SSM_Y, att.ATTN_OUT, att.QKV,
         tfm.MLP_UP}
@@ -452,26 +530,29 @@ def test_the_three_kinds_are_kinds_of_the_rule():
     assert held[ssm.SSM_IN] == 2 * n * 132 * 2  # two M layers
     assert order[-1][0] == ssm.SSM_CONV  # spares least per byte
     for kind in PATTERN:
-        assert set(tfm.remat_spared(cfg, B, T, kind)) == set(
-            tfm.remat_sizes(cfg, B, T, kind))
+        assert set(tfm.layer_costs(cfg, B, T, kind).spared) == set(
+            tfm.layer_costs(cfg, B, T, kind).sizes)
 
 
 def test_the_rule_keeps_a_prefix_and_reckons_the_largest_layer():
     cfg = config(dtype=jnp.bfloat16, remat=True)
-    order = tfm.remat_order(cfg, B, T)
+    order = remat.remat_order(tfm.step_costs(cfg, B, T)[0])
     params = 10 ** 6
-    assert tfm.remat_keep(cfg, B, T, params, None) == ()
+    assert remat.remat_keep(*tfm.step_costs(cfg, B, T, params), None) \
+        == ()
     last = ()
     for limit in range(10 ** 6, 4 * 10 ** 6, 10 ** 4):
-        keep = tfm.remat_keep(cfg, B, T, params, limit, largest=4 * 10 ** 5)
+        keep = remat.remat_keep(*tfm.step_costs(
+            cfg, B, T, params, largest=4 * 10 ** 5), limit)
         assert keep == tuple(n for n, _ in order[:len(keep)])
         assert len(keep) >= len(last)
         last = keep
     assert last == tuple(n for n, _ in order)
     # the gradients of ONE application: the largest where it is given,
     # else the mean over the five
-    assert tfm.whole_step_peak(cfg, B, T, params, largest=4 * 10 ** 5) \
-        - tfm.whole_step_peak(cfg, B, T, params) == 4 * 10 ** 5 - params // 5
+    assert remat.whole_step_peak(*tfm.step_costs(
+        cfg, B, T, params, largest=4 * 10 ** 5)) - remat.whole_step_peak(
+        *tfm.step_costs(cfg, B, T, params)) == 4 * 10 ** 5 - params // 5
 
 
 def test_the_trace_hands_the_rule_the_largest_layer(monkeypatch):
@@ -480,11 +561,13 @@ def test_the_trace_hands_the_rule_the_largest_layer(monkeypatch):
         jnp.asarray, tfm.init_params(np.random.default_rng(0), cfg)))
     seen = {}
 
-    def keep(cfg, b, t, param_bytes, limit, patches=0, largest=None):
-        seen.update(param_bytes=param_bytes, largest=largest)
-        return ()
+    real = tfm.step_costs
 
-    monkeypatch.setattr(tfm, "remat_keep", keep)
+    def costs(cfg, b, t, param_bytes=0, patches=0, largest=None):
+        seen.update(param_bytes=param_bytes, largest=largest)
+        return real(cfg, b, t, param_bytes, patches, largest)
+
+    monkeypatch.setattr(tfm, "step_costs", costs)
     tfm._remat_names(tree, jax.ShapeDtypeStruct((B, T), jnp.int32), cfg)
 
     def nbytes(t):

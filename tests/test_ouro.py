@@ -19,6 +19,7 @@ from benchmark.reference import ouro_decoder as ref
 from benchmark.runners import glm5_train
 from benchmark.runners import ouro_train as ot
 from ompi_tpu.core import pvar
+from ompi_tpu.models import remat
 from ompi_tpu.models import transformer as tfm
 from tests import lowered_text
 
@@ -254,7 +255,7 @@ def test_scopes_of_the_compiled_step():
     passes under `ln`, the exits and the gate under `head_loss` — in
     the compiled step's operation names, which are what a device trace
     carries: a recomputed layer stands behind a `jit` of its own since
-    PR 35 (`tfm._kept_layer`), one private function of the lowered
+    PR 35 (`remat.kept`), one private function of the lowered
     module for all its applications, and XLA, inlining the calls, puts
     each call's pass and layer before the names inside."""
     sizes, cfg = _toy()
@@ -344,7 +345,7 @@ def test_what_an_axis_cannot_give_yet_raises(over):
 #: second path exists — nothing to re-record. PR 35 left all four as
 #: they were: they are the texts without the residuals' names and
 #: without the `jit` of its own that a recomputed layer stands behind
-#: since (`tfm._kept_layer`; XLA inlines it)
+#: since (models/remat.py's `kept`; XLA inlines it)
 PARENT = {
     ("glm-5", "bfloat16"):
         "ba6f5504497ac50bcecb66dc436e404cd27ee26be9cc7d80332cf22f21e04b3c",
@@ -362,8 +363,8 @@ def test_with_the_fields_at_their_defaults_the_step_is_the_parents(
         name, dtype, monkeypatch):
     """Both steps recompute their layers (`remat=True`) and the CPU
     states no memory limit, so nothing is kept: with the recomputed
-    layer called as it is (`tfm._recomputed_layer`, not behind
-    `_kept_layer`'s `jit`) and the residuals' names taken out, the
+    layer called as it is (`remat.recomputed`, not behind
+    `remat.kept`'s `jit`) and the residuals' names taken out, the
     text is the parent's, raw; the names move jax's numbering of its
     private functions and nothing else (tests/lowered_text.py); behind
     the `jit` each layer kind is a private function of the module,
@@ -397,7 +398,7 @@ def test_with_the_fields_at_their_defaults_the_step_is_the_parents(
 
     shipped = text()
     assert re.search(r"call @layer\w*\(", shipped)
-    monkeypatch.setattr(tfm, "_kept_layer", tfm._recomputed_layer)
+    monkeypatch.setattr(remat, "kept", remat.recomputed)
     named = text()
     lowered_text.without_names(monkeypatch)
     bare = text()

@@ -1,6 +1,7 @@
 """What a recomputed layer application keeps for its backward pass
-(`Config.remat`: models/transformer.py's `_run_layer`, the rule
-`remat_keep`, the names of ops/attention.py and transformer.py): any
+(`Config.remat`: models/transformer.py's `_Recomputed` over
+models/remat.py's `Recomputed`, the rule `remat_keep`, the names of
+ops/attention.py and remat.py): any
 set of kept names gives the empty set's loss and gradients bit for
 bit, the policy saves exactly what the rule names and reckons, the
 rule itself, the programs that do not recompute are the parent's, and
@@ -15,9 +16,12 @@ import numpy as np
 import pytest
 from jax._src.ad_checkpoint import saved_residuals
 
-from benchmark import weights, weights_glm5, weights_ouro
-from benchmark.runners import glm5_train, ouro_train, train_step
+from benchmark import (weights, weights_glm5, weights_kimivl,
+                       weights_nemotron, weights_ouro)
+from benchmark.runners import (glm5_train, kimivl_train, nemotron_train,
+                               ouro_train, train_step)
 from ompi_tpu.core import pvar
+from ompi_tpu.models import remat
 from ompi_tpu.models import transformer as tfm
 from ompi_tpu.ops import attention as att
 from tests import lowered_text
@@ -25,7 +29,10 @@ from tests import lowered_text
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AX = tfm.Axes()
 CELLS = {"ouro": (ouro_train, weights_ouro, "ouro-2.6b"),
-         "glm5": (glm5_train, weights_glm5, "glm-5")}
+         "glm5": (glm5_train, weights_glm5, "glm-5"),
+         "kimivl": (kimivl_train, weights_kimivl, "kimi-vl-a3b"),
+         "nemotron": (nemotron_train, weights_nemotron,
+                      "nemotron-3-nano-30b-a3b")}
 #: every name there is
 ALL = (tfm.ATTN_PROJ_OUT, att.DSA_PROBS, att.ATTN_OUT, tfm.MLA_LATENTS,
        tfm.MLP_OUT, tfm.DSA_SELECT, att.QKV, tfm.MLP_UP)
@@ -58,8 +65,8 @@ def _names(model, rehearsal=True):
     _, cfg = _config(model, rehearsal,
                      "float32" if rehearsal else "bfloat16")
     return tuple(name for name, _ in (
-        tfm.remat_order(cfg, 2, 64) if rehearsal
-        else tfm.remat_order(cfg, 1, 4096)))
+        remat.remat_order(tfm.step_costs(cfg, 2, 64)[0]) if rehearsal
+        else remat.remat_order(tfm.step_costs(cfg, 1, 4096)[0])))
 
 
 def _force(monkeypatch, keep):
@@ -95,12 +102,10 @@ def test_the_order_is_read_from_the_widths(model):
     _, cfg = _config(model, rehearsal=False, dtype="bfloat16")
     kinds = tfm._application_kinds(cfg)
     worth = []
-    for name, held in tfm.remat_order(cfg, 1, 4096):
-        assert held == sum(tfm.remat_sizes(cfg, 1, 4096, moe).get(name, 0)
-                           for moe in kinds)
-        spared = sum(tfm.remat_spared(cfg, 1, 4096, moe)[name]
-                     for moe in kinds
-                     if name in tfm.remat_sizes(cfg, 1, 4096, moe))
+    for name, held in remat.remat_order(tfm.step_costs(cfg, 1, 4096)[0]):
+        costs = [tfm.layer_costs(cfg, 1, 4096, moe) for moe in kinds]
+        assert held == sum(c.sizes.get(name, 0) for c in costs)
+        spared = sum(c.spared[name] for c in costs if name in c.sizes)
         worth.append((-spared / held, held))
     assert worth == sorted(worth)
     # a product's result is worth 2 x the contracted width / item size
@@ -110,7 +115,8 @@ def test_the_order_is_read_from_the_widths(model):
     # wider, the same name is dearer: nothing reads a model's name
     wide = tfm.Config(**{**cfg.__dict__, "d_ff": 8 * cfg.d_ff,
                          "post_norm": True})
-    assert tfm.remat_order(wide, 1, 4096)[0][0] == tfm.MLP_OUT
+    assert remat.remat_order(tfm.step_costs(wide, 1, 4096)[0])[0][0] \
+        == tfm.MLP_OUT
 
 
 @pytest.mark.parametrize("model, kept", [
@@ -159,14 +165,13 @@ def _residuals(model, layer, keep):
 
     def apply(lp, h):
         index_aux = []  # the indexer's loss reads the probabilities
-        out = tfm._run_layer(lp, h, cfg, AX, moe, 0, None, index_aux,
-                             tfm._Recomputed(cfg, AX, keep))
+        out = tfm._Recomputed(cfg, AX, keep)(lp, h, moe, 0, None, index_aux)
         return out.sum() + sum(kl for kl, _ in index_aux)
 
     saved = saved_residuals(apply, lp, h)
     made = [aval for aval, why in saved if "from the argument" not in why]
     return (sum(a.size * a.dtype.itemsize for a in made),
-            tfm.remat_sizes(cfg, 2, 64, moe), cfg)
+            tfm.layer_costs(cfg, 2, 64, moe).sizes, cfg)
 
 
 @pytest.mark.parametrize("model, layer", [("ouro", 0), ("glm5", 0),
@@ -251,7 +256,7 @@ def _cell(model):
 
 
 def _reckoned(cfg, b, t, keep):
-    return sum(tfm.remat_sizes(cfg, b, t, moe).get(name, 0)
+    return sum(tfm.layer_costs(cfg, b, t, moe).sizes.get(name, 0)
                for moe in tfm._application_kinds(cfg) for name in keep)
 
 
@@ -259,13 +264,14 @@ def _reckoned(cfg, b, t, keep):
 def test_the_rule(model):
     cfg, params = _cell(model)
     # no limit stated (the CPU), none that allows it: today's program
-    assert tfm.remat_keep(cfg, 1, 4096, params, None) == ()
-    assert tfm.remat_keep(cfg, 1, 4096, params, 0) == ()
-    assert tfm.remat_keep(cfg, 1, 4096, params, params) == ()
+    costs = tfm.step_costs(cfg, 1, 4096, params)
+    assert remat.remat_keep(*costs, None) == ()
+    assert remat.remat_keep(*costs, 0) == ()
+    assert remat.remat_keep(*costs, params) == ()
     # monotone in the limit, a prefix of the order, never past its share
     last = ()
     for limit in range(4 * GB, 200 * GB, GB):
-        keep = tfm.remat_keep(cfg, 1, 4096, params, limit)
+        keep = remat.remat_keep(*costs, limit)
         assert keep == ORDER[model][:len(keep)]
         assert len(keep) >= len(last)
         assert params + _reckoned(cfg, 1, 4096, keep) \
@@ -276,24 +282,45 @@ def test_the_rule(model):
     # tokens never keep more
     again = tfm.Config(**cfg.__dict__)
     for limit in (12 * GB, 16 * GB, 32 * GB):
-        keep = tfm.remat_keep(cfg, 1, 4096, params, limit)
-        assert tfm.remat_keep(again, 1, 4096, params, limit) == keep
-        assert len(tfm.remat_keep(cfg, 2, 4096, params, limit)) <= len(keep)
+        keep = remat.remat_keep(*costs, limit)
+        assert remat.remat_keep(
+            *tfm.step_costs(again, 1, 4096, params), limit) == keep
+        assert len(remat.remat_keep(
+            *tfm.step_costs(cfg, 2, 4096, params), limit)) <= len(keep)
 
 
 #: a v5e's `memory_stats()["bytes_limit"]` (my chip runs PR 35)
 V5E_LIMIT = 16_909_336_064
 
 
-@pytest.mark.parametrize("model, ships", [
-    ("ouro", ORDER["ouro"][:4]),  # the up-projections do not fit
-    ("glm5", ORDER["glm5"][:5]),  # nor do q, k and v, 2.4 GB
+@pytest.mark.parametrize("model, seq, patches, ships", [
+    ("ouro", 4096, 0, ORDER["ouro"][:4]),  # the up-projections do not fit
+    ("glm5", 4096, 0, ORDER["glm5"][:5]),  # nor do q, k and v, 2.4 GB
+    # the tower's 27 blocks over 12,288 patches beside the decoder's 4
+    # layers: q, k and v do not fit (read from PR 41's rule, PR 42)
+    ("kimivl", 4096, 12288, (att.ATTN_OUT, tfm.MLA_LATENTS, tfm.MLP_UP,
+                             tfm.ATTN_PROJ_OUT)),
+    # everything a pattern's layers name, the gradients reckoned at the
+    # largest layer's (an expert layer's) parameters
+    ("nemotron", 8192, 0, (att.ATTN_OUT, tfm.MLP_UP, "ssm_in", att.QKV,
+                           "ssm_y", "ssm_conv")),
 ])
-def test_the_sets_the_cells_ship_with(model, ships):
-    """ouro-train-t4096's and glm5-train-t4096's shapes against a v5e's
-    limit: the keep-sets PR 35 measured."""
-    cfg, params = _cell(model)
-    assert tfm.remat_keep(cfg, 1, 4096, params, V5E_LIMIT) == ships
+def test_the_sets_the_cells_ship_with(model, seq, patches, ships):
+    """The four recomputing cells' shapes against a v5e's limit: the
+    keep-sets PR 35 measured for ouro-train-t4096 and glm5-train-t4096,
+    and those kimivl-train-t4096 and nemotron-train-t8192 ran with at
+    PR 41, read from that commit's rule before PR 42 folded the cost
+    tables."""
+    sizes, cfg = _config(model, rehearsal=False, dtype="bfloat16")
+    shapes = jax.eval_shape(lambda: CELLS[model][1].device_init(sizes, 1))
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    largest = max(map(nbytes, shapes["layers"])) \
+        if cfg.layer_pattern is not None else None
+    assert remat.remat_keep(*tfm.step_costs(
+        cfg, 1, seq, nbytes(shapes), patches, largest), V5E_LIMIT) == ships
 
 
 #: parameters + temporaries of the two cells' steps with the shipped
@@ -310,8 +337,9 @@ def test_the_room_covers_what_the_reckoning_misses(model):
     rule does not count), and both cells' compiled peaks stand under
     the 14.5 GB their ISSUE allowed."""
     cfg, params = _cell(model)
-    keep = tfm.remat_keep(cfg, 1, 4096, params, V5E_LIMIT)
-    reckoned = tfm.whole_step_peak(cfg, 1, 4096, params) \
+    costs = tfm.step_costs(cfg, 1, 4096, params)
+    keep = remat.remat_keep(*costs, V5E_LIMIT)
+    reckoned = remat.whole_step_peak(*costs) \
         + _reckoned(cfg, 1, 4096, keep)
     room = (1 - tfm.REMAT_SHARE) * V5E_LIMIT
     assert abs(COMPILED[model] - reckoned) <= room / 2
@@ -327,9 +355,9 @@ def test_the_limit_is_the_devices_own(monkeypatch):
     params = jax.eval_shape(
         lambda: tfm.init_params(np.random.default_rng(0), cfg))
     tok = jax.ShapeDtypeStruct((2, 64), jnp.int32)
-    assert tfm._memory_limit() is None
+    assert remat._memory_limit() is None
     assert tfm._remat_names(params, tok, cfg) == ()
-    monkeypatch.setattr(tfm, "_memory_limit", lambda: GB)
+    monkeypatch.setattr(remat, "_memory_limit", lambda: GB)
     assert tfm._remat_names(params, tok, cfg) == _names("ouro")
     plain = tfm.Config(**{**cfg.__dict__, "remat": False})
     assert tfm._remat_names(params, tok, plain) == ()
@@ -355,8 +383,7 @@ def test_without_remat_the_names_leave_no_operation(monkeypatch):
 
     named = text()
     assert named.count("stablehlo.") > 100
-    for module in (tfm, att):
-        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+    lowered_text.without_names(monkeypatch)
     bare = text()
     assert lowered_text.canonical(named) == lowered_text.canonical(bare)
 
@@ -367,7 +394,7 @@ def test_without_remat_the_names_leave_no_operation(monkeypatch):
 def test_one_record_per_traced_application(keep, monkeypatch, pvar_clean):
     """Every application counts once — the rule's three counters and
     what layer_forward counts of itself, though jax traces the one
-    jitted layer (`_kept_layer`) once for all of a kind's applications;
+    jitted layer (`remat.kept`) once for all of a kind's applications;
     and a second trace of the step counts what the first did."""
     sizes, cfg = _config("ouro")
     _force(monkeypatch, keep)
@@ -375,7 +402,7 @@ def test_one_record_per_traced_application(keep, monkeypatch, pvar_clean):
         lambda: tfm.init_params(np.random.default_rng(0), cfg))
     tok = jax.ShapeDtypeStruct((2, 64), jnp.int32)
     applications = cfg.n_layers * cfg.loops
-    per = tfm.remat_sizes(cfg, 2, 64, False)
+    per = tfm.layer_costs(cfg, 2, 64, False).sizes
     for traces in (1, 2):
         jax.jit(tfm.make_train_step(
             cfg, AX, tfm.param_specs(cfg, AX))).lower(shapes, tok, tok)
