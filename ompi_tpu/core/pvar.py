@@ -111,6 +111,14 @@ WELL_KNOWN = (
     # the norm of the first state-space layer's state after the last
     # token, in millionths
     "ssm_layers", "ssm_chunks", "attn_gqa_layers", "ssm_state_norm_micro",
+    # models/transformer.py, once per TRACED layer of a config that
+    # mixes kinds of attention (Config.attn_layers): a layer under the
+    # sliding window, a full one; ops/attention.attention, once per
+    # TRACED attention under a window that took the blockwise kernels:
+    # the (query tile, key tile) pairs they walk, and what the causal
+    # triangle would be at that tile (ops/attention.window_tiles)
+    "attn_window_layers", "attn_full_layers", "attn_window_tiles",
+    "attn_causal_tiles",
     # ops/ssm.mixer, once per TRACED call: its scan runs on the Pallas
     # kernels of ops/ssm_scan.py, or as jax.numpy's batched products
     # (the rule ops/ssm.scan_tile)

@@ -1,8 +1,9 @@
 """What a Config's layers are made of, described once.
 
 Three questions of models/transformer.py have ONE answer here: which
-kind each layer is (`_is_moe`, `_layer_kind`: the block's two, a
-pattern's three letters); which sub-layers a layer of a kind has
+kind each layer is (`_is_moe`, `_layer_kind`: the block's two — each
+under a sliding window or not where a config mixes kinds of attention,
+`Block` —, a pattern's three letters); which sub-layers a layer of a kind has
 (`layout`: the rows `layer_forward` walks, the recomputation rule sums
 over and the parameter tree is built from); and every leaf of the
 parameter tree — its path, its shape, how it is initialised and how an
@@ -35,14 +36,44 @@ def _is_moe(cfg, layer: int) -> bool:
 SSM, EXPERTS, ATTENTION = "M", "E", "*"
 
 
+#: the letters of `Config.attn_layers`: a block's attention attends
+#: inside the sliding window, or over the whole causal triangle
+WINDOWED, FULL = "w", "f"
+
+
+class Block(NamedTuple):
+    """A block's kind where a config mixes kinds of attention
+    (`Config.attn_layers`): whether its feed-forward part is a mixture
+    of experts, and whether its attention is under the window."""
+    moe: bool
+    windowed: bool
+
+
 def _layer_kind(cfg, layer: int):
     """What layer `layer` is: its letter where the config has a
     pattern, else whether the block's feed-forward part is a mixture
-    of experts."""
-    if cfg.layer_pattern is None:
+    of experts — and, where the config mixes kinds of attention, a
+    `Block` that says which kind this layer's is besides."""
+    if cfg.layer_pattern is not None:
+        _check_pattern(cfg)
+        return cfg.layer_pattern[layer]
+    if cfg.attn_layers is None:
         return _is_moe(cfg, layer)
-    _check_pattern(cfg)
-    return cfg.layer_pattern[layer]
+    _check_attn_layers(cfg)
+    return Block(_is_moe(cfg, layer), cfg.attn_layers[layer] == WINDOWED)
+
+
+def _check_attn_layers(cfg):
+    kinds = cfg.attn_layers
+    if len(kinds) != cfg.n_layers or set(kinds) - {WINDOWED, FULL}:
+        raise ValueError(
+            f"attn_layers={kinds!r}: expected n_layers = {cfg.n_layers} "
+            f"letters of {WINDOWED!r} (attention inside the sliding "
+            f"window) and {FULL!r} (over the whole causal triangle)")
+    if WINDOWED in kinds and cfg.attn_window < 1:
+        raise ValueError(
+            f"attn_layers={kinds!r} has layers under a sliding window "
+            f"and attn_window={cfg.attn_window} keys is none")
 
 
 def _check_pattern(cfg):
@@ -86,7 +117,8 @@ class Sub(NamedTuple):
 
 def layout(cfg, kind) -> Tuple[Sub, ...]:
     """The sub-layers of a layer of `kind` (`_layer_kind`). The block
-    is attention (of the config's `attn`) then a dense FFN or the
+    is attention (of the config's `attn`; under the sliding window
+    where a `Block` says so) then a dense FFN or the
     experts, each output named and added under its mixer's scope. A
     pattern's letter is ONE sub-layer; its output is the layer's, which
     the next layer's input holds anyway, so it has no name, and its add
@@ -96,11 +128,13 @@ def layout(cfg, kind) -> Tuple[Sub, ...]:
         mixer = {SSM: "ssm", ATTENTION: "attention", EXPERTS: "experts"}[kind]
         return (Sub(mixer, "ln", None, None,
                     ("mlp",) if kind == EXPERTS else ()),)
+    moe, windowed = kind if isinstance(kind, Block) else (kind, False)
     post = ("ln1_post", "ln2_post") if cfg.post_norm else (None, None)
     return (Sub("mla", "ln1", post[0], ATTN_PROJ_OUT, ("attn_proj", "mla_o"))
             if cfg.attn == "mla" else
-            Sub("attention", "ln1", post[0], ATTN_PROJ_OUT, ("attn_proj",)),
-            Sub("experts" if kind else "ffn", "ln2", post[1], MLP_OUT,
+            Sub("window_attention" if windowed else "attention", "ln1",
+                post[0], ATTN_PROJ_OUT, ("attn_proj",)),
+            Sub("experts" if moe else "ffn", "ln2", post[1], MLP_OUT,
                 ("mlp",)))
 
 
@@ -218,7 +252,8 @@ def _ssm_leaves(cfg):  # replicated: no tp, sp, ep or pp path
 
 
 #: a mixer's leaves, by `Sub.mixer`
-MIXERS = {"attention": _attention_leaves, "mla": _mla_leaves,
+MIXERS = {"attention": _attention_leaves,
+          "window_attention": _attention_leaves, "mla": _mla_leaves,
           "ffn": lambda cfg: _ffn_leaves(cfg, cfg.d_ff),
           "experts": _experts_leaves, "ssm": _ssm_leaves}
 

@@ -24,9 +24,10 @@ The model is a DESCRIPTION, read from a published config, not a fork
 per model: :class:`Config` says which norm, positions, attention and
 feed-forward part, how many passes and exits, whether a tower stands in
 front; the defaults are OPT's (pre-LN, ReLU MLP, learned positions,
-tied head). Six published configurations run through it, each against
+tied head). Seven published configurations run through it, each against
 a float32 reference of its own under ``benchmark/reference/``: OPT,
-OLMoE, GLM-5, Ouro, Kimi-VL, Nemotron-3-Nano. What the module holds:
+OLMoE, GLM-5, Ouro, Kimi-VL, Nemotron-3-Nano, Mellum2. What the module
+holds:
 
 - :class:`Config`, :class:`Axes`, `_check_supported`: what a config
   asks for that an axis cannot give yet is an error, never another
@@ -37,12 +38,16 @@ OLMoE, GLM-5, Ouro, Kimi-VL, Nemotron-3-Nano. What the module holds:
   kind (that module also says which layer is of which kind and
   describes the parameter tree). The block is attention then a dense
   FFN or the experts, by the layer's INDEX (``moe_every``,
-  ``first_dense``); a layer of a ``layer_pattern`` is ONE mixer, by its
-  letter (`SSM` ``M``, `EXPERTS` ``E``, `ATTENTION` ``*``).
+  ``first_dense``) — its attention inside a sliding window or over the
+  whole causal triangle, each kind with RoPE parameters of its own,
+  where ``attn_layers`` mixes the two —; a layer of a ``layer_pattern``
+  is ONE mixer, by its letter (`SSM` ``M``, `EXPERTS` ``E``,
+  `ATTENTION` ``*``).
 - The MIXERS, five, each with what it costs the recomputation rule
   (`_COSTS`): multi-head attention (`_attention`: shared key heads, a
-  head width of its own, positions learned / RoPE / none, QK-norm, tp,
-  sp); latent attention (`_mla_attention`, with the sparse-attention
+  head width of its own, positions learned / RoPE — YaRN's blended
+  frequencies and attention factor, `Rope` — / none, QK-norm, a sliding
+  window, tp, sp); latent attention (`_mla_attention`, with the sparse-attention
   indexer where ``index_topk`` is set); the dense FFN; the experts
   (`_experts`: sorted path, held share, shared expert, ep); the
   Mamba-2 state-space mixer (ops/ssm.py, imported only where a pattern
@@ -94,13 +99,22 @@ Counted once per traced layer: ``ssm_layers``,
 ``ssm_chunks`` (chunks a layer), ``attn_gqa_layers`` and, by
 ``ops/ssm.mixer`` for the form its scan took, ``ssm_scan_kernel_layers``
 / ``ssm_scan_product_layers``; the probe :func:`ssm_probe` counts
-``ssm_state_norm_micro``.
+``ssm_state_norm_micro``. Where a config mixes kinds of attention
+(``attn_layers``) — and nowhere else, so every other op path stands —
+the scores, softmax and values lie one scope further in:
+``attn_core/attn_window`` on a layer under the sliding window,
+``attn_core/attn_full`` on a full one (YaRN's table and the rotation
+stay under ``attn_proj/qk_rope``); counted once per traced layer there:
+``attn_window_layers`` / ``attn_full_layers``, and by
+``ops/attention.attention`` for a windowed attention that took the
+kernels ``attn_window_tiles`` / ``attn_causal_tiles``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import math
 from typing import Any, Optional, Tuple
 
 import jax
@@ -112,14 +126,34 @@ from jax.ad_checkpoint import checkpoint_name
 from ompi_tpu.core import pvar
 from ompi_tpu.models import remat, vision
 from ompi_tpu.models.params import (  # noqa: F401 (the model's own names)
-    ATTENTION, EXPERTS, SSM, _check_indexer, _check_pattern, _is_moe,
-    _layer_kind, grad_extra_axes, init_params, layout, param_specs)
+    ATTENTION, EXPERTS, FULL, SSM, WINDOWED, Block, _check_attn_layers,
+    _check_indexer, _check_pattern, _is_moe, _layer_kind, grad_extra_axes,
+    init_params, layout, param_specs)
 from ompi_tpu.models.remat import (  # noqa: F401
     ATTN_PROJ_OUT, DSA_SELECT, MLA_LATENTS, MLP_OUT, MLP_UP, REMAT_SHARE)
 from ompi_tpu.ops import attention as att
 from ompi_tpu.ops import moe as moe_mod
 from ompi_tpu.ops.ring_attention import ring_attention
 from ompi_tpu.parallel.collectives import region_enter, region_exit
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """RoPE's parameters for one kind of attention layer (an entry of
+    the source's ``rope_parameters``): the base, and YaRN's (Peng et
+    al., arXiv:2309.00071) where `factor` is not 1 — the pairs that
+    turn more than `beta_fast` times over the `original_max` positions
+    the model was first trained on keep their frequency, those that
+    turn less than `beta_slow` times are slowed `factor` times, a
+    straight line over the pair's index between; cos and sin are both
+    multiplied by `attention_factor`, so a layer's scores carry its
+    square."""
+    theta: float = 10000.0
+    factor: float = 1.0
+    original_max: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -278,6 +312,18 @@ class Config:
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    #: kinds of attention mixed by layer (the source's layer_types):
+    #: one letter a layer — `WINDOWED` "w": query t attends the keys
+    #: t - attn_window < s <= t, itself and the attn_window - 1 before
+    #: it; `FULL` "f": every s <= t. None: every layer is full
+    attn_layers: Optional[str] = None
+    attn_window: int = 0
+    #: RoPE's parameters where they are more than `rope_theta` says, by
+    #: the layer's kind of attention (a `Rope`: a base of its own,
+    #: YaRN): the full layers' — every layer's where `attn_layers` is
+    #: None — and the windowed ones'. None: `rope_theta`, unscaled
+    rope_full: Optional[Rope] = None
+    rope_window: Optional[Rope] = None
 
     @property
     def head_dim(self) -> int:
@@ -342,15 +388,47 @@ def _norm(x, p, cfg: Config):
                      "'rmsnorm'")
 
 
-def rope(x, positions, theta: float):
+def yarn_range(half: int, rp: Rope) -> Tuple[int, int]:
+    """(low, high): the pairs 0 .. low keep their frequency under
+    YaRN, the pairs from high on are slowed `rp.factor` times — the
+    pair's index at which a head of 2 x `half` dimensions turns
+    `beta_fast` / `beta_slow` times over `original_max` positions,
+    rounded outwards and held inside the head."""
+    def pair(turns: float) -> float:
+        return 2 * half * math.log(rp.original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(rp.theta))
+
+    return (max(math.floor(pair(rp.beta_fast)), 0),
+            min(math.ceil(pair(rp.beta_slow)), 2 * half - 1))
+
+
+def rope_frequencies(half: int, rp: Rope):
+    """float32 [half]: the angle a position adds to pair i,
+    ``theta^(-i / half)`` — under YaRN blended with that divided by
+    `rp.factor` along `yarn_range`'s line."""
+    pairs = jnp.arange(half, dtype=jnp.float32)
+    inv_freq = rp.theta ** (-pairs / half)
+    if rp.factor == 1.0:
+        return inv_freq
+    low, high = yarn_range(half, rp)
+    ramp = jnp.clip((pairs - low) / (max(high, low + 0.001) - low), 0.0, 1.0)
+    return inv_freq * (1.0 - ramp) + inv_freq / rp.factor * ramp
+
+
+def rope(x, positions, theta):
     """Rotary positions on x [B, T, H, Dh] at integer `positions` [T]:
     the rotate-half pairing (dimension i with i + Dh/2), computed in
-    float32, returned in x's type."""
+    float32, returned in x's type. `theta`: the base, or a `Rope`
+    (its frequencies, cos and sin times its attention factor)."""
     half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    scaled = isinstance(theta, Rope)
+    inv_freq = rope_frequencies(half, theta) if scaled \
+        else theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
+    if scaled and theta.attention_factor != 1.0:
+        cos, sin = (a * theta.attention_factor for a in (cos, sin))
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
@@ -377,6 +455,7 @@ def _check_supported(cfg: Config, ax: Axes, is_moe, pos_offset, t=None):
     """What the config may ask for that an axis cannot give yet is an
     error, never another function computed in silence. `t`: the tokens
     of a sequence on this shard, where the caller has them."""
+    is_moe = getattr(is_moe, "moe", is_moe)  # a `Block`'s
     if cfg.pos not in ("learned", "rope", "none"):
         raise ValueError(f"pos={cfg.pos!r}: expected 'learned', 'rope' or "
                          "'none'")
@@ -416,6 +495,33 @@ def _check_supported(cfg: Config, ax: Axes, is_moe, pos_offset, t=None):
                     f"scan's chunks (ssm_chunk={cfg.ssm_chunk}): a last "
                     "chunk padded with tokens that change no state is "
                     "not written")
+    if cfg.attn_layers is not None:
+        _check_attn_layers(cfg)
+        for on, missing in (
+                (ax.sp, "sequence parallelism (ax.sp): the ring and the "
+                 "Ulysses schedules take causal=True and nothing else; a "
+                 "window that skips the blocks no query of a shard "
+                 "reaches is ROADMAP Queue 2a"),
+                (ax.pp, "pipeline parallelism (ax.pp): layers of two "
+                 "kinds do not stack into equal stages "
+                 "(models/pipeline.py scans equal ones; ROADMAP R3)"),
+                (cfg.attn == "mla", "latent attention (attn='mla'): its "
+                 "core and its sparse-attention indexer take no window"),
+                (cfg.layer_pattern is not None, "a layer pattern "
+                 "(Config.layer_pattern): a pattern's attention layers "
+                 "are told apart by no letter yet"),
+                (cfg.mtp_layers, "multi-token prediction (mtp_layers): "
+                 "which kind of attention the module after the last "
+                 "layer has is not written")):
+            if on:
+                raise NotImplementedError(
+                    "kinds of attention mixed by layer (Config.attn_layers) "
+                    "under " + missing)
+    elif cfg.attn_window or cfg.rope_window is not None:
+        raise ValueError(
+            f"attn_window={cfg.attn_window}, rope_window="
+            f"{cfg.rope_window!r}: which layers are under the window is "
+            "for Config.attn_layers to say, and it is None")
     if cfg.n_heads % (cfg.n_kv_heads or cfg.n_heads):
         raise ValueError(f"n_heads={cfg.n_heads} is no multiple of "
                          f"n_kv_heads={cfg.n_kv_heads}")
@@ -632,7 +738,8 @@ def _mla_attention(lp, x, cfg: Config, pos_offset, index_aux):
         return o.reshape(b, t, -1) @ lp["wo"].astype(cfg.dtype)
 
 
-def _attention(lp, x, cfg: Config, ax: Axes, pos_offset):
+def _attention(lp, x, cfg: Config, ax: Axes, pos_offset,
+               windowed: bool = False):
     """Multi-head attention's mixer (x: the normed h): causal attention
     of `n_heads` query heads over `n_kv_heads` shared key / value heads
     (0: as many), then the output projection [B, T, d]; inside the tp
@@ -644,16 +751,24 @@ def _attention(lp, x, cfg: Config, ax: Axes, pos_offset):
     where ``pos == "rope"`` (a learned table is the embedding's
     business). Under sp the ring or the Ulysses schedule runs in the
     kernel's place. Counted once per traced layer with shared key
-    heads: ``attn_gqa_layers``."""
+    heads: ``attn_gqa_layers``. `windowed`: this layer's attention is
+    under the config's sliding window (``attn_window`` keys, passed
+    down to the entry) and turns by ``rope_window``; a full layer by
+    ``rope_full``. Where the config mixes the two kinds the core lies
+    under a scope of the kind's name and the layer is counted:
+    ``attn_window_layers`` / ``attn_full_layers``."""
     dt = cfg.dtype
     b, t, _ = x.shape
     dh = cfg.head_dim
+    window = cfg.attn_window if windowed else None
+    theta = (cfg.rope_window if windowed else cfg.rope_full) \
+        or cfg.rope_theta
     # The blockwise kernel takes q already scaled. Where it will run
     # (the rule att.attention applies below), 1/sqrt(Dh) goes in where q
     # is still float32 — the projection's accumulator or the QK-norm —
     # so q is rounded to dt once, as it is for att.mha.
     q_scale = dh ** -0.5 if not ax.sp and att.blockwise_tile(
-        jax.default_backend(), t, t, dh) else None
+        jax.default_backend(), t, t, dh, window=window) else None
 
     def split(a):  # [B, T, Hl, Dh]: the local heads under tp
         return a.reshape(b, t, a.shape[-1] // dh, dh)
@@ -690,13 +805,19 @@ def _attention(lp, x, cfg: Config, ax: Axes, pos_offset):
             with jax.named_scope("qk_rope"):
                 positions = jnp.arange(t) if pos_offset is None \
                     else pos_offset + jnp.arange(t)
-                q = rope(q, positions, cfg.rope_theta)
-                k = rope(k, positions, cfg.rope_theta)
+                q = rope(q, positions, theta)
+                k = rope(k, positions, theta)
         if k.shape[2] != q.shape[2]:
             pvar.record("attn_gqa_layers")
             k, v = (jnp.repeat(a, q.shape[2] // k.shape[2], axis=2)
                     for a in (k, v))
-    with jax.named_scope("attn_core"):  # scores, softmax, AV
+    with contextlib.ExitStack() as scopes:  # scores, softmax, AV
+        scopes.enter_context(jax.named_scope("attn_core"))
+        if cfg.attn_layers is not None:
+            pvar.record("attn_window_layers" if windowed
+                        else "attn_full_layers")
+            scopes.enter_context(jax.named_scope(
+                "attn_window" if windowed else "attn_full"))
         if ax.sp:
             if cfg.sp_schedule == "ulysses":
                 from ompi_tpu.ops.ulysses import ulysses_attention
@@ -708,7 +829,10 @@ def _attention(lp, x, cfg: Config, ax: Axes, pos_offset):
                 raise ValueError(
                     f"sp_schedule={cfg.sp_schedule!r}: expected 'ring' "
                     "or 'ulysses'")
-        else:
+        elif window:
+            o = att.attention(q, k, v, causal=True,
+                              scale=1.0 if q_scale else None, window=window)
+        else:  # the call as every configuration without a window makes it
             o = att.attention(q, k, v, causal=True,
                               scale=1.0 if q_scale else None)
     with jax.named_scope("attn_proj"):
@@ -782,6 +906,8 @@ def _sublayer(lp, h, cfg: Config, ax: Axes, sub, pos_offset=None, aux=None,
     x = _norm(h.astype(jnp.float32), lp[sub.pre], cfg).astype(cfg.dtype)
     if sub.mixer == "attention":
         y = _attention(lp, x, cfg, ax, pos_offset)
+    elif sub.mixer == "window_attention":
+        y = _attention(lp, x, cfg, ax, pos_offset, windowed=True)
     elif sub.mixer == "mla":
         y = _mla_attention(lp, x, cfg, pos_offset, index_aux)
     elif sub.mixer == "ffn":
@@ -833,15 +959,24 @@ def layer_forward(lp, h, cfg: Config, ax: Axes, is_moe: bool,
 # are not counted; attention's over the causal half —; the operations of
 # the mixer's LAST product, which its output's name spares).
 
-def _attention_costs(cfg: Config, n: int, t: int, it: int):
-    """q, k and v as the kernel reads them: the key heads repeated."""
+def _attention_costs(cfg: Config, n: int, t: int, it: int,
+                     window: Optional[int] = None):
+    """q, k and v as the kernel reads them: the key heads repeated.
+    The core's two products over the pairs the mask keeps: a query's
+    share of the causal half, t / 2 keys — under a `window` of w =
+    min(t, window) keys, w (1 - w / 2t) of them."""
     d, heads, dh = cfg.d_model, cfg.n_heads, cfg.head_dim
     kv = cfg.n_kv_heads or heads
+    w = min(t, window or t)
     return ({att.ATTN_OUT: n * heads * (dh * it + 4),
              att.QKV: 3 * n * heads * dh * it},
-            {att.ATTN_OUT: 2 * n * t * heads * dh,
+            {att.ATTN_OUT: 2 * n * (w * (2 * t - w) // t) * heads * dh,
              att.QKV: 2 * n * d * (heads + 2 * kv) * dh},
             2 * n * heads * dh * d)
+
+
+def _window_attention_costs(cfg: Config, n: int, t: int, it: int):
+    return _attention_costs(cfg, n, t, it, cfg.attn_window)
 
 
 def _mla_costs(cfg: Config, n: int, t: int, it: int):
@@ -896,7 +1031,8 @@ def _ssm_costs(cfg: Config, n: int, t: int, it: int):
 
 
 #: a mixer's costs, by the name its layout row carries
-_COSTS = {"attention": _attention_costs, "mla": _mla_costs,
+_COSTS = {"attention": _attention_costs,
+          "window_attention": _window_attention_costs, "mla": _mla_costs,
           "ffn": _ffn_costs(lambda cfg: cfg.d_ff),
           "experts": _ffn_costs(lambda cfg: cfg.shared_width),
           "ssm": _ssm_costs}
@@ -1276,13 +1412,14 @@ def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
     return nll + count * extra, count
 
 
-def _probe(name: str):
+def _probe(name: str, *static: str):
     """jit a set-up probe under the job's own prefix: the compile
     ledger (prof/compile.py) counts programs named ``ompi_*`` as the
-    job's, and a probe's compile is part of its set-up."""
+    job's, and a probe's compile is part of its set-up. `static`: its
+    static arguments beside the config."""
     def wrap(fn):
         fn.__name__ = fn.__qualname__ = name
-        return jax.jit(fn, static_argnames=("cfg",))
+        return jax.jit(fn, static_argnames=("cfg",) + static)
     return wrap
 
 
@@ -1432,6 +1569,38 @@ def gqa_probe(params, tokens, cfg: Config):
     it and in little else. A probe the host calls outside any timed
     window."""
     return _gqa_probe(params, tokens, cfg=cfg)
+
+
+@_probe("ompi_attn_probe", "layer")
+def _attn_probe(params, tokens, cfg: Config, layer: int):
+    dt = cfg.dtype
+    h = params["embed"].astype(dt)[tokens]
+    if cfg.pos == "learned":
+        h = h + params["pos"][:tokens.shape[1]].astype(dt)[None]
+    lp, kind = params["layers"][layer], _layer_kind(cfg, layer)
+    _check_supported(cfg, Axes(), kind, None, tokens.shape[1])
+    sub = layout(cfg, kind)[0]
+    x = _norm(h.astype(jnp.float32), lp[sub.pre], cfg).astype(dt)
+    return _attention(lp, x, cfg, Axes(), None,
+                      windowed=sub.mixer == "window_attention")
+
+
+
+def attn_probe(params, tokens, cfg: Config, layer: int):
+    """Block `layer`'s attention mixer — its norm, its weights, its
+    kind's mask and RoPE parameters — on the EMBEDDED batch: [B, T,
+    d_model], before any residual add. Every layer is read on the same
+    rows, the stream entering layer 0, so that a reading is the mixer's
+    own and carries nothing of the layers in front (a bfloat16 run
+    re-routes a few tokens in every expert layer it has passed). A
+    window ignored or one key off, one kind's RoPE parameters on the
+    other kind of layer, or query heads on the wrong key heads show in
+    it and in little else. A probe the host calls outside any timed
+    window."""
+    if cfg.layer_pattern is not None or cfg.attn != "mha":
+        raise ValueError("attn_probe reads a block's multi-head attention "
+                         "(no layer_pattern, attn='mha')")
+    return _attn_probe(params, tokens, cfg=cfg, layer=layer)
 
 
 def ssm_probe(params, tokens, cfg: Config):

@@ -8,7 +8,11 @@ over a whole sequence runs blockwise (:func:`blockwise_mha`: tiles in
 VMEM, an online softmax, no score in HBM) wherever one of two rules
 accepts its shapes. Under the causal mask, :func:`blockwise_tile`: the
 library's splash-attention kernels; tiles above the diagonal are never
-visited; the kernels work lanes of 128, so heads of any other width
+visited — nor, under a sliding WINDOW (``window=W``: query t attends
+the keys ``t - W < s <= t``, itself and the W - 1 before it; the
+library's ``LocalMask`` in ``CausalMask``'s place, tiles from a list of
+its own), those wholly below the window, forward and backward —; the
+kernels work lanes of 128, so heads of any other width
 (latent attention's 192 against values of 128) are padded with zeros
 up to the next 128, which changes no score and no output. Under a
 segment mask, :func:`segment_tile`: two kernels of the repo's own
@@ -28,7 +32,11 @@ segmented, or both — runs :func:`mha`, the full-softmax reference
 oracle for every kernel path and for the distributed ring attention
 (:mod:`ompi_tpu.ops.ring_attention`, which builds on
 :func:`online_softmax_block`). Shapes follow
-[batch, seq, heads, head_dim] throughout.
+[batch, seq, heads, head_dim] throughout. Counted once per traced
+attention under a window that took the kernels, by the rule that picked
+its tile: ``attn_window_tiles`` (the (query tile, key tile) pairs the
+kernels walk, :func:`window_tiles`) and ``attn_causal_tiles`` (what the
+whole triangle would be at that tile).
 
 Learned sparse attention (DeepSeek-V3.2's DSA, as GLM-5 — the third
 published model of ``models/transformer.py``, reference
@@ -86,15 +94,18 @@ DSA_PROBS = "dsa_probs"
 
 
 def mha(q, k, v, causal: bool = True, scale: Optional[float] = None,
-        q_offset: int = 0, k_offset: int = 0, segments=None):
+        q_offset: int = 0, k_offset: int = 0, segments=None,
+        window: Optional[int] = None):
     """Multi-head attention, full-softmax reference.
 
     q: [B, Tq, H, D], k: [B, Tk, H, D], v: [B, Tk, H, Dv] ->
     [B, Tq, H, Dv]. q_offset/k_offset give the global positions of the
     local blocks (used when blocks are slices of a longer sequence).
     `segments`: [B, T] integers for self-attention (Tq == Tk): a query
-    sees the keys of its own id alone.
+    sees the keys of its own id alone. `window`: under the causal mask,
+    a query at position t sees the keys at t - window < s <= t alone.
     """
+    _check_window(causal, segments, window)
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / jnp.sqrt(d)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
@@ -103,6 +114,8 @@ def mha(q, k, v, causal: bool = True, scale: Optional[float] = None,
         qpos = q_offset + jnp.arange(q.shape[1])
         kpos = k_offset + jnp.arange(k.shape[1])
         mask = qpos[:, None] >= kpos[None, :]
+        if window:
+            mask = mask & (qpos[:, None] - kpos[None, :] < window)
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
     if segments is not None:
         same = segments[:, :, None] == segments[:, None, :]
@@ -115,6 +128,15 @@ def mha(q, k, v, causal: bool = True, scale: Optional[float] = None,
     # bf16 operands + f32 accumulation: full MXU rate, f32 precision
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _check_window(causal: bool, segments, window) -> None:
+    if window is not None and (window < 1 or not causal
+                               or segments is not None):
+        raise ValueError(
+            f"window={window!r}: a sliding window is a positive number of "
+            "keys under the causal mask; with a segment mask, or without "
+            "the causal one, it is not written")
 
 
 #: Square query / key-value tiles of the blockwise kernel: the largest
@@ -140,9 +162,30 @@ def _whole(q_offset, k_offset) -> bool:
     return all(isinstance(o, int) and o == 0 for o in (q_offset, k_offset))
 
 
+#: The tiles of a WINDOWED attention, in the order they are tried, and
+#: the form of its backward pass. A tile the window's edge crosses is
+#: walked whole, so a smaller tile wastes less (at T 16,384 under a
+#: window of 1,024 the kernels walk 31 of the triangle's 136 tiles of
+#: 1024 and 2.00 x the pairs the window keeps, 93 of 528 at 512 and
+#: 1.50 x, 310 of 2,080 at 256 and 1.25 x) and runs slower a pair (PR
+#: 27, above). The library's FUSED backward writes a partial dq for
+#: every key tile, [T / tile, heads, T, D], and sums them: under the
+#: causal mask that is the cheaper form, under a window it is T / tile
+#: times the bytes for W / tile key tiles of work a row, so a windowed
+#: attention takes the two-kernel backward (`splash_mha_dq`,
+#: `splash_mha_dkv`; the scores are made twice and no partial exists).
+#: Chosen on the chip (v5e, one core alone at B1 T16384 H32 D128 under
+#: a window of 1,024, forward + backward with the layout changes,
+#: PERF.md section 6, PR 43): two kernels 18.6 ms at 512 (19.6 at 1024,
+#: 28.3 at 256); fused 23.7 ms at 1024, 30.7 at 512, 66.6 at 256,
+#: holding 2.1 / 4.3 / 8.6 GB of partials; the whole triangle 58.6 ms
+#: fused at 1024 (69.0 with two kernels).
+_WINDOW_TILES = (512, 1024, 256)
+
+
 def blockwise_tile(backend: str, t_q: int, t_k: int, head_dim: int,
-                   causal: bool = True, q_offset=0,
-                   k_offset=0) -> Optional[int]:
+                   causal: bool = True, q_offset=0, k_offset=0,
+                   window: Optional[int] = None) -> Optional[int]:
     """The rule that sends a CAUSAL attention to the library's blockwise
     kernels, made of what the caller can observe: the tile it runs
     with, or None where it takes :func:`mha` — off the TPU, a length no
@@ -150,18 +193,35 @@ def blockwise_tile(backend: str, t_q: int, t_k: int, head_dim: int,
     (blocks at an offset of a longer one are the ring's), or no causal
     mask (a segment mask alone is :func:`segment_tile`'s; both at once,
     packed causal documents, is ROADMAP Queue 2a). Any `head_dim`
-    passes: the kernel pads it to its lanes."""
+    passes: the kernel pads it to its lanes. Under a `window` the tiles
+    are `_WINDOW_TILES`'."""
     if (backend != "tpu" or not causal or not _whole(q_offset, k_offset)
             or t_q != t_k or head_dim < 1):
         return None
-    return next((b for b in _TILES if t_q % b == 0), None)
+    return next((b for b in (_WINDOW_TILES if window else _TILES)
+                 if t_q % b == 0), None)
 
 
-def _block_sizes(tile: int):
+def window_tiles(t: int, tile: int, window: Optional[int] = None) -> int:
+    """The (query tile, key tile) pairs of a [t, t] attention in square
+    tiles of `tile` that hold a pair the mask keeps — what the
+    blockwise kernels walk: the triangle's, or under a `window` those
+    of them whose nearest pair is fewer than `window` keys apart."""
+    n = t // tile
+    reach = n if not window else min(n, (window - 2) // tile + 2)
+    return sum(min(i + 1, reach) for i in range(n))
+
+
+def _block_sizes(tile: int, windowed: bool = False):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk)
 
     compute = min(tile, _KV_COMPUTE)
+    if windowed:  # the two-kernel backward: `_WINDOW_TILES`' comment
+        return sk.BlockSizes(
+            block_q=tile, block_kv=tile, block_kv_compute=compute,
+            block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=compute,
+            block_q_dq=tile, block_kv_dq=tile, use_fused_bwd_kernel=False)
     return sk.BlockSizes(
         block_q=tile, block_kv=tile, block_kv_compute=compute,
         block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=compute,
@@ -169,22 +229,29 @@ def _block_sizes(tile: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _splash_kernel(t: int, heads: int, tile: int, interpret: bool):
+def _splash_kernel(t: int, heads: int, tile: int, interpret: bool,
+                   window: Optional[int] = None):
     """The library's splash-attention kernels for a causal [t, t] mask
     over `heads` heads of one sequence, built once per shape (not once
     per layer): forward `splash_mha_fwd_residuals`, one fused backward
     `splash_mha_dkv_no_residuals`. The mask is computed in the kernel
     from the tile's position; tiles above the diagonal are never
-    visited, in any of them."""
+    visited, in any of them — nor, under a `window`, those wholly
+    below it (the library's ``LocalMask``: itself and window - 1 keys
+    to the left, none to the right), and the backward is two kernels
+    (`_block_sizes`)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
 
-    mask = sm.MultiHeadMask([sm.CausalMask((t, t))] * heads)
+    one = sm.LocalMask((t, t), (window - 1, 0), 0) if window \
+        else sm.CausalMask((t, t))
+    mask = sm.MultiHeadMask([one] * heads)
     # the kernel keeps its block tables as arrays: constants of every
     # program that uses it, not values of the trace that asked first
     with jax.ensure_compile_time_eval():
         return sk.make_splash_mha_single_device(
-            mask, block_sizes=_block_sizes(tile), interpret=interpret,
+            mask, block_sizes=_block_sizes(tile, bool(window)),
+            interpret=interpret,
             residual_checkpoint_name=ATTN_OUT)
 
 
@@ -313,8 +380,10 @@ def _pad_heads(a, width: int):
 
 
 def blockwise_mha(q, k, v, tile, scale: Optional[float] = None,
-                  interpret: bool = False, segments=None):
-    """Self-attention under the causal mask, or — where `segments`
+                  interpret: bool = False, segments=None,
+                  window: Optional[int] = None):
+    """Self-attention under the causal mask (inside a `window` of keys
+    where one is given), or — where `segments`
     [B, T] is given — both ways under that segment mask and no causal
     one: :func:`mha`'s mathematics (exact softmax over the whole
     unmasked row, float32 scores, statistics and
@@ -341,7 +410,7 @@ def blockwise_mha(q, k, v, tile, scale: Optional[float] = None,
         q, k, v = (_pad_heads(a, lanes(a.shape[-1])) for a in (q, k, v))
         qkv = checkpoint_name(
             tuple(a.transpose(0, 2, 1, 3) for a in (q, k, v)), QKV)
-        o = jax.vmap(_splash_kernel(t, h, tile, interpret))(*qkv)
+        o = jax.vmap(_splash_kernel(t, h, tile, interpret, window))(*qkv)
         return o.transpose(0, 2, 1, 3)[..., :dv]
     if isinstance(tile, int):
         tile = _segment_groups(tile, t, h, d, q.dtype.itemsize)
@@ -355,20 +424,30 @@ def blockwise_mha(q, k, v, tile, scale: Optional[float] = None,
 
 
 def attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
-              q_offset=0, k_offset=0, segments=None):
+              q_offset=0, k_offset=0, segments=None,
+              window: Optional[int] = None):
     """The model's one way to attention: the blockwise kernels where
     a rule gives tiles — :func:`segment_tile` under a segment mask,
     :func:`blockwise_tile` without one —, :func:`mha` everywhere else.
     `segments` ([B, T] integers, data) restricts every query to the
-    keys of its own id. Inside ``jit`` the choice is static; it is
+    keys of its own id; `window` (under the causal mask) every query to
+    itself and the window - 1 keys before it (``attn_window_tiles`` /
+    ``attn_causal_tiles`` where the kernels take it: the tiles they
+    walk of the triangle's). Inside ``jit`` the choice is static; it is
     counted once per traced attention (pvars ``attn_blockwise_layers``
     / ``attn_reference_layers``; ``attn_segment_layers`` for those that
     took a segment mask, whichever way they went, and
     ``attn_segment_kernel_layers`` for those of them that took the
     repo's kernels)."""
+    _check_window(causal, segments, window)
     shape = jax.default_backend(), q.shape[1], k.shape[1]
     if segments is None:
-        tile = blockwise_tile(*shape, q.shape[-1], causal, q_offset, k_offset)
+        tile = blockwise_tile(*shape, q.shape[-1], causal, q_offset, k_offset,
+                              window)
+        if window and tile is not None:
+            pvar.record("attn_window_tiles",
+                        window_tiles(q.shape[1], tile, window))
+            pvar.record("attn_causal_tiles", window_tiles(q.shape[1], tile))
     else:
         pvar.record("attn_segment_layers")
         tile = segment_tile(*shape, q.shape[2], q.shape[-1], v.shape[-1],
@@ -379,10 +458,12 @@ def attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
         pvar.record("attn_reference_layers")
         return checkpoint_name(
             mha(*checkpoint_name((q, k, v), QKV), causal=causal, scale=scale,
-                q_offset=q_offset, k_offset=k_offset, segments=segments),
+                q_offset=q_offset, k_offset=k_offset, segments=segments,
+                window=window),
             ATTN_OUT)
     pvar.record("attn_blockwise_layers")
-    return blockwise_mha(q, k, v, tile, scale=scale, segments=segments)
+    return blockwise_mha(q, k, v, tile, scale=scale, segments=segments,
+                         window=window)
 
 
 # -- learned sparse attention: indexer scores, selection, attention over it ----

@@ -46,7 +46,8 @@ COUNTED = ("moe_bounded_layers", "moe_full_layers",
            "moe_row_sum_gather_layers", "moe_row_sum_product_layers",
            "moe_grouped_kernel_layers", "moe_ragged_dot_layers",
            "attn_blockwise_layers", "attn_reference_layers",
-           "attn_dsa_kernel_layers", "ssm_scan_kernel_layers",
+           "attn_window_layers", "attn_full_layers", "attn_window_tiles",
+           "attn_causal_tiles", "attn_dsa_kernel_layers", "ssm_scan_kernel_layers",
            "ssm_scan_product_layers", "remat_kept_applications",
            "remat_whole_applications", "remat_kept_bytes")
 
