@@ -20,24 +20,32 @@ the TPU, where the static rule :func:`grouped_tiles` gives tiles, the
 Pallas kernels of ops/grouped_matmul.py, forward and both transposes;
 ``lax.ragged_dot`` everywhere else; an expert width the kernels'
 lanes do not divide enters them padded with zero columns,
-:func:`expert_width_pad`), and the weighted rows gathered
-back and summed per token. Static shapes, no capacity, no token ever
-dropped, any ``k``,
+:func:`expert_width_pad`), and each token's k rows fetched back by
+the sort's inverse, weighted and summed in float32 with one rounding.
+Static shapes, no capacity, no token ever dropped, any ``k``,
 gated (``w3``) or plain ReLU experts; differentiable with respect to
 the rows, the expert weights and, through the weights, the router.
-Both permutations are gathers in the forward AND the backward pass
-(``custom_vjp``): the transpose of a permutation is its inverse, and a
-scatter-add is the slow way to say so on a TPU. **A chip that holds a
+Rows move in three ways and no other, each a gather in the forward
+AND the backward pass (``custom_vjp``; a scatter-add is the slow way
+to transpose a gather on a TPU): :func:`_take_held` (a token to each
+of its places in the sort), its transpose :func:`_sum_held` (a token's
+k places fetched, places leading, and added) and :func:`_weigh_held`
+(the same with the router's weights; its transpose keeps the products
+in the sort's order and reads the cotangent from the TOKENS — on the
+chip a gather from the ``[T, D]`` tokens ran at 650 GB/s of rows
+written and one from the ``[T k, D]`` rows at ~122, PERF.md 5 —, and
+the weights and their gradient change order as scalars, by a sort:
+:func:`_by_key`). **A chip that holds a
 share of the experts** carries a static BOUND of rows instead of all
 ``T * k`` (the rule :func:`held_rows_bound`: a few times its share):
-the first `bound` rows of the same sort are gathered, multiplied by
-the same kernels and summed back per token — fetched by the sort's
-inverse and added (:func:`_weigh_held`, :func:`_sum_held`), or by a
-0/1 product on the MXU where the bound is a small part of ``T * k``
-(:func:`_sum_rows`; the rule :func:`row_sum_gathers`) —, and a batch
-that sends the chip more rows than that takes the layer over all rows
-instead, inside one ``lax.cond`` a direction — exact, counted, never a
-drop.
+the first `bound` rows of the same sort are taken, multiplied by the
+same kernels and summed back per token by the same three functions —
+the full layer is the bounded one at ``bound = T * k``, one body
+(:func:`_held_rows`) — or, where the bound is a small part of
+``T * k``, by a 0/1 product on the MXU (:func:`_sum_rows`; the rule
+:func:`row_sum_gathers`), and a batch that sends the chip more rows
+than that takes the layer over all rows instead, inside one
+``lax.cond`` a direction — exact, counted, never a drop.
 
 **Expert parallel (``ax.ep``): capacity-based top-1 over all_to_all.**
 BASELINE.md config #5 is the MPI_Alltoall(v) MoE expert-dispatch
@@ -231,41 +239,6 @@ def router_z_loss(route: TopKRoute):
     """``mean(logsumexp(logits) ** 2)`` (ST-MoE)."""
     return jnp.mean(route.lse ** 2)
 
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _to_expert_order(x, order, inv, k: int):
-    """Row i of the result is the token of the i-th assignment in
-    expert order: x[order // k]. Its transpose un-sorts and sums each
-    token's k rows — gathers both ways."""
-    return x[order // k]
-
-
-def _to_expert_order_fwd(x, order, inv, k):
-    return x[order // k], inv
-
-
-def _to_expert_order_bwd(k, inv, g):
-    return g[inv].reshape(-1, k, g.shape[-1]).sum(1), None, None
-
-
-_to_expert_order.defvjp(_to_expert_order_fwd, _to_expert_order_bwd)
-
-
-@jax.custom_vjp
-def _permute(x, perm, inv):
-    """x[perm] for a permutation and its inverse."""
-    return x[perm]
-
-
-def _permute_fwd(x, perm, inv):
-    return x[perm], inv
-
-
-def _permute_bwd(inv, g):
-    return g[inv], None, None
-
-
-_permute.defvjp(_permute_fwd, _permute_bwd)
 
 _ACT = {"relu": lambda x: jnp.maximum(x, 0), "silu": jax.nn.silu,
         "gelu": jax.nn.gelu,
@@ -570,20 +543,6 @@ def _expert_order(experts):
     return order, jnp.argsort(order)
 
 
-def _all_rows(x, experts, weights, counts, w1, w3, w2, act: str,
-              product=None):
-    """The layer over all ``T * k`` assignments."""
-    t, k = experts.shape
-    with jax.named_scope("moe_dispatch"):
-        order, inv = _expert_order(experts)
-        rows = _to_expert_order(x, order, inv, k)
-    out = _experts(rows, counts, w1, w3, w2, act, product)
-    with jax.named_scope("moe_combine"):
-        out = _permute(out, inv, order).reshape(t, k, x.shape[-1])
-        return jnp.einsum("tkd,tk->td", out.astype(jnp.float32),
-                          weights).astype(x.dtype)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _take_held(x, order, inv, k: int, bound: int):
     """x[order[:bound] // k]: the tokens of the first `bound`
@@ -604,20 +563,32 @@ _take_held.defvjp(_take_held_fwd, _take_held_bwd)
 
 
 def _places(v, inv, k: int, bound: int, held):
-    """([k, t, ...] in v's type, [k, t] bool): the rows of v [bound,
-    ...] at each token's k places in the sort, place by place, and
-    which places are under `held` (<= bound); a place past the bound
-    reads some row — the rows one after another, not one row for all of
-    them: most places of a small share are such — and counts for
-    nothing. The places lead: ``[k * t, D]`` splits into ``[k, t, D]``
-    for nothing on the TPU, where ``[t, k, D]`` is a copy into tiles of
-    16 rows that k does not fill (the chip, PR 40, a layer and
-    direction at nemotron-train-t8192: that copy 0.97 ms, the sum over
-    its 2.7 times the rows 1.07 for 0.45, the gather 2.31 for 2.07
-    with every such place on the last row)."""
+    """[k, t, ...] in v's type: the rows of v [bound, ...] at each
+    token's k places in the sort, place by place, and zero at a place
+    that is not under `held` (<= bound). Under a bound a place past it
+    reads some row — the rows one after another, not one row for all
+    of them: most places of a small share are such — and counts for
+    nothing; with all ``t * k`` rows (the full layer) every place has
+    its row, and by the static shape no such index is built. The mask
+    stands either way: without it XLA left the rows' conversion to
+    float32 out of the weighted sum's fusion (the chip, PR 44, the full
+    layer at mellum2-train-t16384: a float32 copy of the ``[8, 16384,
+    2304]`` rows written and read, 2.85 + 1.66 ms a layer beside the
+    gather's 4.93, where the einsum this replaced took 0.87). The
+    places lead: ``[k * t, D]`` splits into ``[k, t, D]`` for nothing
+    on the TPU, where ``[t, k, D]`` is a copy into tiles of 16 rows
+    that k does not fill (the chip, PR 40, a layer and direction at
+    nemotron-train-t8192: that copy 0.97 ms, the sum over its 2.7
+    times the rows 1.07 for 0.45, the gather 2.31 for 2.07 with every
+    such place on the last row)."""
     at = inv.reshape(-1, k).T
-    walk = (jnp.arange(at.size, dtype=at.dtype) % bound).reshape(at.shape)
-    return v[jnp.where(at < bound, at, walk)], at < held
+    read = at
+    if bound < at.size:
+        walk = (jnp.arange(at.size, dtype=at.dtype) % bound).reshape(at.shape)
+        read = jnp.where(at < bound, at, walk)
+    rows = v[read]
+    return jnp.where(jnp.expand_dims(at < held, tuple(range(2, rows.ndim))),
+                     rows, 0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -626,8 +597,7 @@ def _sum_held(v, order, inv, k: int, bound: int):
     that stand at token i's places in the sort (``inv[i * k + j] <
     bound``: at most k of them), fetched by the sort's inverse in the
     type they have and summed in float32."""
-    rows, under = _places(v, inv, k, bound, bound)
-    return jnp.where(under[..., None], rows, 0).astype(jnp.float32).sum(
+    return _places(v, inv, k, bound, bound).astype(jnp.float32).sum(
         0).astype(v.dtype)
 
 
@@ -647,13 +617,14 @@ def _weigh_held(out, weights, order, inv, held, bound: int):
     """[t, D] in out's type: ``sum_j weights[i, j] * out[inv[i * k +
     j]]`` over token i's places under `held` (an int32 scalar, <=
     bound: the held assignments), the products and the sum in float32
-    and ONE rounding, as the full layer's. A place past `held` weighs
-    nothing whatever its row holds. The transpose reads `bound` rows
-    of the cotangent (:func:`_take_held`) and nothing of the size of
-    ``T * k`` rows."""
-    rows, under = _places(out, inv, weights.shape[1], bound, held)
-    return (jnp.where(under[..., None], rows, 0).astype(jnp.float32)
-            * weights.T[..., None]).sum(0).astype(out.dtype)
+    and ONE rounding. A place past `held` weighs nothing whatever its
+    row holds. The transpose keeps `out` as it stands, in the sort's
+    order, and reads `bound` rows of the cotangent FROM THE TOKENS
+    (:func:`_take_held`): nothing of the size of `out` is permuted in
+    it; the weights go into the sort's order and their gradient comes
+    back as scalars (:func:`_by_key` where every row exists)."""
+    return (_places(out, inv, weights.shape[1], bound, held).astype(
+        jnp.float32) * weights.T[..., None]).sum(0).astype(out.dtype)
 
 
 def _weigh_held_fwd(out, weights, order, inv, held, bound):
@@ -661,26 +632,43 @@ def _weigh_held_fwd(out, weights, order, inv, held, bound):
             (out, weights, order, inv, held))
 
 
+def _by_key(keys, values):
+    """`values` in the order of their `keys`, a permutation: with the
+    sort's inverse as keys that is ``values[order]``, with the sort
+    itself ``values[inv]``. ONE two-operand sort where a gather of
+    scalars walks them one by one (the chip, PR 44, 131,072 float32:
+    0.93-1.13 ms a gather, 0.13 a sort)."""
+    return lax.sort((keys, values), num_keys=1)[1]
+
+
 def _weigh_held_bwd(bound, res, g):
     out, weights, order, inv, held = res
     k = weights.shape[1]
     g = _take_held(g, order, inv, k, bound).astype(jnp.float32)
-    dout = g * weights.reshape(-1)[order[:bound]][:, None]
-    dweight, under = _places((out.astype(jnp.float32) * g).sum(-1), inv, k,
-                             bound, held)
-    return (dout.astype(out.dtype), jnp.where(under, dweight, 0).T, None,
-            None, None)
+    flat = weights.reshape(-1)
+    # all ``T * k`` scalars change order by a sort; under a bound the
+    # first `bound` of them are fetched, as a bounded layer's always
+    # were (there a sort stood between two fusions: compiled for a
+    # v5e, PR 44, nemotron-train-t8192's `dout` left its fusion)
+    full = bound == flat.size
+    dout = g * (_by_key(inv, flat) if full else flat[order[:bound]])[:, None]
+    rowsum = (out.astype(jnp.float32) * g).sum(-1)
+    dweight = (_by_key(order, rowsum).reshape(weights.shape) if full
+               else _places(rowsum, inv, k, bound, held).T)
+    return dout.astype(out.dtype), dweight, None, None, None
 
 
 _weigh_held.defvjp(_weigh_held_fwd, _weigh_held_bwd)
 
 
 def _held_rows(x, experts, weights, counts, w1, w3, w2, act: str,
-               bound: int, gather: bool):
-    """The layer over the first `bound` assignments of the sort — all
-    the held ones where ``counts.sum() <= bound`` — and nothing of the
-    size of ``T * k`` rows but, where `gather`
-    (:func:`row_sum_gathers`), the rows a token's sum fetches."""
+               bound: int, gather: bool, product=None):
+    """The layer over the first `bound` assignments of the sort: all
+    ``T * k`` of them in the full layer, all the held ones of a bounded
+    layer where ``counts.sum() <= bound``. Under a bound nothing is of
+    the size of ``T * k`` rows but, where `gather`
+    (:func:`row_sum_gathers`; the full layer always), the rows a
+    token's sum fetches."""
     t, k = experts.shape
     with jax.named_scope("moe_dispatch"):
         order, inv = _expert_order(experts)
@@ -689,24 +677,25 @@ def _held_rows(x, experts, weights, counts, w1, w3, w2, act: str,
         else:
             token = order[:bound] // k
             rows = _take_rows(x, token, t)
-    out = _experts(rows, counts, w1, w3, w2, act)
+    out = _experts(rows, counts, w1, w3, w2, act, product)
     with jax.named_scope("moe_combine"):
         if gather:
-            # the barrier keeps the sum INSIDE this branch: the full
-            # layer ends in the same float32 products and sum, and XLA
-            # moved both branches' out of the conditional, whose result
-            # was then the float32 ``[T, k, D]`` rows (compiled for a
-            # v5e, PR 40: 0.53 GB a layer at nemotron-train-t8192)
-            return lax.optimization_barrier(_weigh_held(
-                out, weights, order, inv, jnp.minimum(counts.sum(), bound),
-                bound))
+            y = _weigh_held(out, weights, order, inv,
+                            jnp.minimum(counts.sum(), bound), bound)
+            # under a bound the barrier keeps the sum INSIDE its branch
+            # of the conditional: the full layer ends in the same
+            # float32 products and sum, and XLA moved both branches'
+            # out of it, whose result was then the float32 ``[T, k,
+            # D]`` rows (compiled for a v5e, PR 40: 0.53 GB a layer at
+            # nemotron-train-t8192)
+            return lax.optimization_barrier(y) if bound < t * k else y
         weight = _take_head(weights.reshape(t * k), order, inv, bound)
         return _sum_rows(out.astype(jnp.float32) * weight[:, None], token,
                          t).astype(x.dtype)
 
 
 def _fallback_rows(x, experts, weights, counts, w1, w3, w2, act: str):
-    """:func:`_all_rows` as a bounded layer's second branch: the same
+    """The full layer as a bounded layer's second branch: the same
     layer with its products through ``lax.ragged_dot`` on every
     backend. A kernel's code is not shared between its call sites, and
     this branch's twelve a layer would be paid for by every run that
@@ -715,8 +704,8 @@ def _fallback_rows(x, experts, weights, counts, w1, w3, w2, act: str):
     v5e, glm5-train-t4096's step is 0.94 GB of code and 193 s with the
     kernels here, 0.85 GB and 147 s so, 0.78 GB and 145 s without the
     branch)."""
-    return _all_rows(x, experts, weights, counts, w1, w3, w2, act,
-                     product=_ragged_dot_zero_tail)
+    return _held_rows(x, experts, weights, counts, w1, w3, w2, act,
+                      experts.size, True, product=_ragged_dot_zero_tail)
 
 
 def _branches(act: str, bound: int, gather: bool):
@@ -816,9 +805,10 @@ def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
     takes the layer over all ``T * k`` rows instead, exactly, inside
     one ``lax.cond``: no assignment is ever dropped. Counted once per
     traced call: ``moe_bounded_layers`` (a bound and its fallback) /
-    ``moe_full_layers`` (all the rows, no second path); and of the
-    bounded ones ``moe_row_sum_gather_layers`` (a token's rows fetched
-    by the sort's inverse and added) / ``moe_row_sum_product_layers``
+    ``moe_full_layers`` (all the rows, no second path: the same body
+    at ``bound = T * k``, a token's rows fetched by the sort's inverse
+    and added); and of the bounded ones ``moe_row_sum_gather_layers``
+    (fetched and added likewise) / ``moe_row_sum_product_layers``
     (summed by a 0/1 product: :func:`row_sum_gathers`). On the TPU a
     width the kernels' lanes do not divide is padded with zeros on the
     way in (:func:`expert_width_pad`)."""
@@ -836,5 +826,5 @@ def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
                     else "moe_row_sum_product_layers")
         return _held_or_all_rows(x, route.experts, route.weights,
                                  route.counts, w1, w3, w2, act, rows, gather)
-    return _all_rows(x, route.experts, route.weights, route.counts, w1, w3,
-                     w2, act)
+    return _held_rows(x, route.experts, route.weights, route.counts, w1, w3,
+                      w2, act, rows, True)

@@ -108,13 +108,12 @@ def as_on_a_v5e():
             mod._memory_limit = lambda: V5E_LIMIT
 
 
-def lowered(name: str, manifest, mf):
-    """(text with debug information, what the trace counted) of cell
-    `name`'s step, lowered for the TPU on abstract arrays."""
+def step_and_shapes(name: str, manifest, mf):
+    """(cell `name`'s jitted step, its abstract parameters, one
+    abstract batch's tokens and labels)."""
     import jax
 
     from benchmark import weights
-    from ompi_tpu.core import pvar
 
     _, workload, traffic, config, _ = mf.cell_inputs(manifest, name)
     runner = importlib.import_module("benchmark.runners." + workload["runner"])
@@ -129,11 +128,20 @@ def lowered(name: str, manifest, mf):
         toks, labs = jax.eval_shape(lambda: weights.batches(
             sizes["vocab"], traffic["n_batches"], traffic["batch"],
             traffic["seq"], 0))
+    return (runner.build_step(sizes, traffic["lr"]), params, toks[0],
+            labs[0])
+
+
+def lowered(name: str, manifest, mf):
+    """(text with debug information, what the trace counted) of cell
+    `name`'s step, lowered for the TPU on abstract arrays."""
+    from ompi_tpu.core import pvar
+
+    step, *shapes = step_and_shapes(name, manifest, mf)
     names = tuple(n for n in COUNTED if n in pvar.WELL_KNOWN)
     before = {n: pvar.read(n) for n in names}
-    text = runner.build_step(sizes, traffic["lr"]).trace(
-        params, toks[0], labs[0]).lower(lowering_platforms=("tpu",)).as_text(
-            debug_info=True)
+    text = step.trace(*shapes).lower(lowering_platforms=("tpu",)).as_text(
+        debug_info=True)
     return text, {n: pvar.read(n) - was for n, was in before.items()
                   if pvar.read(n) != was}
 

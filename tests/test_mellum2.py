@@ -489,14 +489,17 @@ def test_the_step_lowered_for_the_tpu_holds_the_windows_kernels(
 
 #: sha256 of `lowered_text.canonical` of the CPU-lowered rehearsal steps
 #: at the parent commit 0fd3f76 (jax 0.9.0, bfloat16, batch 2 x 64):
-#: `build_step(...).lower(...).as_text()` of three accepted runners
+#: `build_step(...).lower(...).as_text()` of three accepted runners —
+#: the two with experts as PR 44 left them, whose full expert layer
+#: (OLMoE's layer, Nemotron's never-taken branch) moves its rows as
+#: the bounded one does: bc56a7fe... and 81d66122... before it
 PARENT = {
     "opt-30b":
         "64ef42df46a7b8a345c4b87952058c0b20c9914626bc543b767ff8a5a0b7d4e3",
     "olmoe-1b-7b":
-        "bc56a7fe9a3c5396294c4679d8b8a8e0cd272499e5b14f45168b3a871f3a9e7c",
+        "a9fb09441b5271d5fcb83be9c7cd45484ad23600b459ae56b5c19e8ea63c8cb9",
     "nemotron-3-nano-30b-a3b":
-        "81d66122a7a99ac52c2c1afec59b8e1e4177a921132a299b0ed790ee649f67b5",
+        "5ece6908d0d3acefcfe2cdd7f034cb559c4d1f2fe89d992c1759e66c79769648",
 }
 RUNNERS = {"opt-30b": (train_step, weights),
            "olmoe-1b-7b": (olmoe_train, weights_olmoe),

@@ -521,6 +521,97 @@ def test_the_weighted_sum_is_the_float32_sum_and_clears_what_is_not_held(
     assert bool(jnp.isfinite(dw).all())
 
 
+#: the activations of the full layer's cases, with their derivatives
+_NUMPY_ACT = {
+    "silu": (lambda h: h / (1 + np.exp(-h)),
+             lambda h: (1 + np.exp(-h) + h * np.exp(-h))
+             / (1 + np.exp(-h)) ** 2),
+    "relu": (lambda h: np.maximum(h, 0), lambda h: (h > 0) * 1.0),
+}
+
+
+def _numpy_layer(x, experts, weights, w1, w3, w2, act, g):
+    """The expert layer and its transpose in float64, assignment by
+    assignment and with no sort: ``y[t] = sum_j weights[t, j] *
+    expert_{experts[t, j]}(x[t])`` and, for the cotangent `g` of y, the
+    gradients of x, weights, w1, w3 (None: ungated) and w2."""
+    x, weights, w1, w2, g = (np.asarray(a, np.float64)
+                             for a in (x, weights, w1, w2, g))
+    w3 = None if w3 is None else np.asarray(w3, np.float64)
+    f, df = _NUMPY_ACT[act]
+    y, dx, dweights = np.zeros_like(x), np.zeros_like(x), np.zeros_like(
+        weights)
+    dw1, dw2 = np.zeros_like(w1), np.zeros_like(w2)
+    dw3 = None if w3 is None else np.zeros_like(w3)
+    for tok, j in np.ndindex(*experts.shape):
+        e = int(experts[tok, j])
+        pre = x[tok] @ w1[e]
+        gate = 1.0 if w3 is None else x[tok] @ w3[e]
+        hidden = f(pre) * gate
+        out = hidden @ w2[e]
+        y[tok] += weights[tok, j] * out
+        dweights[tok, j] = out @ g[tok]
+        dout = weights[tok, j] * g[tok]
+        dw2[e] += np.outer(hidden, dout)
+        dhidden = w2[e] @ dout
+        dpre = dhidden * gate * df(pre)
+        dw1[e] += np.outer(x[tok], dpre)
+        dx[tok] += w1[e] @ dpre
+        if w3 is not None:
+            dw3[e] += np.outer(x[tok], dhidden * f(pre))
+            dx[tok] += w3[e] @ (dhidden * f(pre))
+    return y, (dx, dweights, dw1, dw3, dw2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("experts", ["gated_silu", "ungated_relu"])
+@pytest.mark.parametrize("k", [1, 6, 8])
+def test_the_full_layer_is_the_float64_layer(k, experts, dtype, pvar_clean):
+    """The layer over all T * k rows — a token taken to its places by
+    `_take_held`, its rows weighed and added by `_weigh_held`, their
+    transposes `_sum_held` and `_take_held` — against numpy's float64
+    layer written assignment by assignment, with no sort in it: output,
+    and the gradients of the rows, the router's weights and every
+    expert matrix, for one, six and all eight of 8 experts a token. In
+    float32 the two differ by the order of sums; in bfloat16 by the
+    rounding of each product's result (three a row) and of the sum."""
+    act, gated = ("silu", True) if experts == "gated_silu" else ("relu",
+                                                                 False)
+    t, d, f, e = 48, 32, 40, 8
+    rng = np.random.default_rng(7 + k)
+    x, g = (jnp.asarray(rng.standard_normal((t, d)), dtype) for _ in "xg")
+    w1, w3, w2 = (jnp.asarray(rng.standard_normal(s) / np.sqrt(s[1]), dtype)
+                  for s in ((e, d, f), (e, d, f), (e, f, d)))
+    route = moe.topk_routing(jnp.asarray(rng.standard_normal((t, e)),
+                                         jnp.float32), k)
+
+    def layer(x, weights, w1, w3, w2):
+        y = moe.sorted_moe_ffn(x, route._replace(weights=weights), w1,
+                               w3 if gated else None, w2, act)
+        return jnp.sum(y.astype(jnp.float32) * g.astype(jnp.float32)), y
+
+    with jax.default_matmul_precision("highest"):
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            layer, argnums=(0, 1, 2, 3, 4), has_aux=True))(
+                x, route.weights, w1, w3, w2)
+    assert (pvar.read("moe_full_layers"),
+            pvar.read("moe_bounded_layers")) == (1, 0)
+    want_y, want = _numpy_layer(x, np.asarray(route.experts), route.weights,
+                                w1, w3 if gated else None, w2, act, g)
+    assert y.dtype == dtype
+    tol = 1e-2 if dtype == jnp.bfloat16 else 2e-6  # measured: 6e-3, 2e-7
+    for name, got, ref in zip(("y", "x", "weights", "w1", "w3", "w2"),
+                              (y,) + grads, (want_y,) + want):
+        if ref is None:  # an ungated layer's w3: used by nothing
+            assert not np.asarray(got, np.float32).any()
+            continue
+        assert got.dtype == (jnp.float32 if name == "weights" else dtype)
+        gap = np.linalg.norm(np.asarray(got, np.float64) - ref)
+        assert np.linalg.norm(ref) > 0 and gap <= tol * np.linalg.norm(ref), (
+            name, gap, np.linalg.norm(ref))
+
+
 @pytest.mark.parametrize("t,k", [(4096, 8), (128, 4), (96, 2), (1000, 3)])
 def test_the_bound_rule(t, k):
     """A multiple of the row tile under T * k, T * k itself at share 1
@@ -740,3 +831,60 @@ def test_the_bounded_layer_compiles_for_the_chip(cell, one_chip, monkeypatch,
             result = line.split(" conditional(")[0]
             assert not re.search(
                 rf"f32\[({t * k}|{t},{k}|{k},{t}),{d}\]", result), result
+
+
+#: the full layer's shapes (t, k, experts, D, F): a toy for this
+#: backend, mellum2-train-t16384's for the described chip
+FULL_LAYERS = {"cpu": (64, 4, 8, 96, 128),
+               "v5e": (16384, 8, 64, 2304, 896)}
+
+
+@pytest.mark.parametrize("target", sorted(FULL_LAYERS))
+def test_the_recomputed_full_layer_holds_five_row_gathers(target, request,
+                                                          monkeypatch):
+    """One full expert layer under `jax.checkpoint`, value and
+    gradient, compiled (here, and for a described v5e at
+    mellum2-train-t16384's shapes on the kernels): five gathers make
+    rows of D — the forward's dispatch and combine, the recomputed
+    dispatch, the combine's transpose and the dispatch's — where the
+    einsum's transposes made six; three of them read the ``[T, D]``
+    tokens (on the chip the fast kind, PERF.md 5) and two the ``[T k,
+    D]`` rows; and the recomputed forward fetches nothing for the
+    combine: the transpose keeps the products in the sort's order."""
+    t, k, e, d, f = FULL_LAYERS[target]
+    sharding = {}
+    if target == "v5e":
+        sharding["sharding"] = request.getfixturevalue("one_chip")
+        rule = moe.grouped_tiles
+        monkeypatch.setattr(moe, "grouped_tiles",
+                            lambda backend, *a: rule("tpu", *a))
+
+    def loss(x, logits, w1, w3, w2, g):
+        y = moe.sorted_moe_ffn(x, moe.topk_routing(logits, k, True), w1, w3,
+                               w2, "silu")
+        return jnp.sum(y.astype(jnp.float32) * g)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, **sharding)
+
+    text = jax.jit(jax.value_and_grad(
+        jax.checkpoint(loss), argnums=(0, 1, 2, 3, 4))).lower(
+        arg((t, d)), arg((t, e), jnp.float32), arg((e, d, f)),
+        arg((e, d, f)), arg((e, f, d)),
+        arg((t, d), jnp.float32)).compile().as_text()
+    assert ("moe_gmm" in text) is (target == "v5e")
+    shape_of = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
+    gathers = []  # (rows of the source, the gather's scope path)
+    for line in text.splitlines():
+        made = re.search(r"= \w+\[([\d,]*)\]\S* gather\("
+                         r"(?:\w+\[[\d,]*\]\S* )?%([\w.\-]+)", line)
+        if made and made.group(1).split(",")[-1] == str(d):
+            source = shape_of[made.group(2)].split(",")
+            assert source[-1] == str(d), line
+            gathers.append((int(source[0]), re.search(
+                r'op_name="([^"]*)"', line).group(1)))
+    assert len(gathers) == 5, gathers
+    assert sorted(rows for rows, _ in gathers) == [t] * 3 + [t * k] * 2
+    assert not [path for _, path in gathers
+                if "rematted_computation" in path and "moe_combine" in path]
+    assert sum("rematted_computation" in path for _, path in gathers) == 1

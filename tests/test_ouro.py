@@ -345,12 +345,15 @@ def test_what_an_axis_cannot_give_yet_raises(over):
 #: second path exists — nothing to re-record. PR 35 left all four as
 #: they were: they are the texts without the residuals' names and
 #: without the `jit` of its own that a recomputed layer stands behind
-#: since (models/remat.py's `kept`; XLA inlines it)
+#: since (models/remat.py's `kept`; XLA inlines it). PR 44 re-recorded
+#: the two `glm-5` texts (ba6f5504... and 39d35e4e... before it): the
+#: rehearsal's layer is the FULL one, and that moves its rows as the
+#: bounded one does since (`ops/moe._held_rows` at ``bound = T * k``)
 PARENT = {
     ("glm-5", "bfloat16"):
-        "ba6f5504497ac50bcecb66dc436e404cd27ee26be9cc7d80332cf22f21e04b3c",
+        "1dac97dca4f9b7f99abbbf7bb0a10499aee103616aa45e7377720b2617715ffb",
     ("glm-5", "float32"):
-        "39d35e4e557dcea874e12762e2d1f9d586e0b990aa79267291f3c2c94b5aa738",
+        "39047cb26212ed5f7dbaafa73bee2fb03930a83af5346332ab1c67d65c5057d2",
     ("once", "bfloat16"):
         "7b780c71a862533a759df2d768a264c642c0303c3b339ecd7736d5051c8e6a52",
     ("once", "float32"):
