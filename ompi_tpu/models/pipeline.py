@@ -181,13 +181,9 @@ def make_pp_train_step(cfg: tfm.Config, ax: tfm.Axes, specs,
             logits = pipeline_forward(p, tokens, cfg, ax, n_micro)
             pp = jaxcompat.axis_size(ax.pp)
             last = (lax.axis_index(ax.pp) == pp - 1).astype(jnp.float32)
-            logz = jax.nn.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(
-                logits, jnp.maximum(labels, 0)[..., None],
-                axis=-1)[..., 0]
             mask = (labels >= 0).astype(jnp.float32) * last
-            nll = ((logz - gold) * mask).sum()
-            return nll, mask.sum()
+            return tfm._token_nll(logits, labels, mask,
+                                  cfg.dtype), mask.sum()
 
         (nll, cnt), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)

@@ -71,7 +71,9 @@ Inside ``attn_proj``: ``qk_rope`` (QK-norm and RoPE); inside ``mlp`` of
 a MoE layer: ``moe_route`` (router matmul, softmax, top-k, the two
 losses), ``moe_dispatch`` (sort, gather), ``moe_experts`` (grouped
 matmuls, activation), ``moe_combine`` (un-sort, weighted sum),
-``moe_shared`` (the shared expert). A latent-attention layer has
+``moe_shared`` (the shared expert); the loss's weighted mean of the
+layers' router losses is ``moe_route`` alone, after ``head_loss``. A
+latent-attention layer has
 ``attn_proj/{mla_q, mla_kv, mla_o, qk_rope, dsa_index_proj}`` and
 ``attn_core/{dsa_index, dsa_attend, dsa_kl}`` (the indexer's scores
 and top-k; attention over the selection — on the TPU the Pallas
@@ -114,6 +116,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import math
 from typing import Any, Optional, Tuple
 
@@ -1086,7 +1089,7 @@ def step_costs(cfg: Config, b: int, t: int, param_bytes: int = 0,
     application that has most, where the caller has the tree, else the
     mean over the applications, which is less where the layers are
     unlike); ONE exit's float32 logits, their exponentials and their
-    cotangent (`_exit_terms`, `_token_nll`)."""
+    cotangent (`_nll`'s operand)."""
     it = jnp.dtype(cfg.dtype).itemsize
     kinds = _application_kinds(cfg)
     per = {kind: vision.application(cfg.vision, patches, it) if kind == VIT
@@ -1286,13 +1289,54 @@ def _mtp_forward(mp, h, params, labels, cfg: Config, ax: Axes, pos_offset,
         mp, h, _is_moe(cfg, cfg.n_layers), pos_offset, aux, index_aux)
 
 
-def _token_nll(logits, labels, mask):
-    """Summed cross-entropy of float32 logits over the masked
-    positions."""
+def _label_hit(logits, labels):
+    """bool [B, T, vocab]: where each position's label sits (a label
+    below 0 is compared as 0 and left to the caller's mask)."""
+    return lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1) \
+        == jnp.maximum(labels, 0)[..., None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _nll(logits, labels, dtype):
+    """Per-position cross-entropy [B, T] of float32 logits [B, T,
+    vocab]: the one reading of the label's logit in the model, and the
+    one making of the logits' cotangent, written once in `dtype` (the
+    activations' type: what the head's two backward products take)."""
+    return _nll_fwd(logits, labels, dtype)[0]
+
+
+def _nll_fwd(logits, labels, dtype):
+    # the label's logit by a mask, not a gather: a gather's transpose
+    # scatters into the FLATTENED logits, and the chip pays two
+    # relayout copies of [B, T, vocab] float32 and a float32 cotangent
+    # around it, under no scope's name (6.6 ms an exit of [4096,
+    # 49152]: PERF.md 6, PR 32; 6.8 ms of olmoe-train-t4096's 122:
+    # PR 45). A sum of one float32 value and zeros is exact.
     logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(
-        logits, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
-    return ((logz - gold) * mask).sum()
+    gold = jnp.where(_label_hit(logits, labels), logits, 0.0).sum(-1)
+    return logz - gold, (logits, logz, labels)
+
+
+def _nll_bwd(dtype, res, g):
+    logits, logz, labels = res
+    d = (jnp.exp(logits - logz[..., None])
+         - _label_hit(logits, labels).astype(jnp.float32)) * g[..., None]
+    # softmax - onehot is written ONCE, rounded as the products round
+    # it anyway, and the barrier keeps it so: left to itself XLA makes
+    # it again inside both products' input fusions, for every output
+    # tile (+3.2 ms on the two products of olmoe-train-t4096 where this
+    # pass costs 1.8: PERF.md 6, PR 45)
+    return lax.optimization_barrier(d.astype(dtype)).astype(
+        jnp.float32), None
+
+
+_nll.defvjp(_nll_fwd, _nll_bwd)
+
+
+def _token_nll(logits, labels, mask, dtype):
+    """Summed cross-entropy of float32 logits over the masked
+    positions (`dtype`: `_nll`'s)."""
+    return (_nll(logits, labels, dtype) * mask).sum()
 
 
 def _exit_terms(params, exits, h, labels, mask, cfg: Config):
@@ -1304,15 +1348,7 @@ def _exit_terms(params, exits, h, labels, mask, cfg: Config):
     An exit's logits are made again in the backward pass, so that one
     exit's [B, T, vocab] is alive at a time."""
     def nll(x, head):
-        logits = _head_logits(head, x, cfg)
-        # the label's logit by a mask, not a gather: a gather's
-        # transpose scatters into the FLATTENED logits, and the chip
-        # pays a relayout copy of [B, T, vocab] float32 for it (6.6 ms
-        # an exit, under no scope's name: PERF.md 6, PR 32)
-        hit = lax.broadcasted_iota(jnp.int32, logits.shape, 2) \
-            == jnp.maximum(labels, 0)[..., None]
-        gold = jnp.where(hit, logits, 0.0).sum(-1)
-        return (jax.nn.logsumexp(logits, axis=-1) - gold) * mask
+        return _nll(_head_logits(head, x, cfg), labels, cfg.dtype) * mask
 
     states = exits + [_final_norm(params, h, cfg)]
     nlls = []
@@ -1352,24 +1388,6 @@ def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
     selecting layers) and the multi-token-prediction loss (mean over
     ITS positions) enter the same way at their weights."""
     aux = [] if (cfg.router_aux_weight or cfg.router_z_weight) else None
-    if not (cfg.index_topk or cfg.mtp_layers or cfg.exit_gate):
-        logits = forward_local(params, tokens, cfg, ax, aux)
-        with jax.named_scope("head_loss"):
-            logits = logits.astype(jnp.float32)
-            logz = jax.nn.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(
-                logits, labels[..., None], axis=-1)[..., 0]
-            mask = (labels >= 0).astype(jnp.float32)
-            nll = ((logz - gold) * mask).sum()
-            if aux:
-                with jax.named_scope("moe_route"):
-                    balance = sum(a[0] for a in aux) / len(aux)
-                    z = sum(a[1] for a in aux) / len(aux)
-                    nll = nll + mask.sum() * (
-                        cfg.router_aux_weight * balance
-                        + cfg.router_z_weight * z)
-            return nll, mask.sum()
-
     index_aux = []
     exits = [] if cfg.exit_gate else None
     h, t_off = _trunk(params, tokens, cfg, ax, aux, index_aux, exits)
@@ -1380,7 +1398,7 @@ def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
             nll = _exit_loss(*_exit_terms(params, exits, h, labels, mask,
                                           cfg), mask, cfg)
         else:
-            nll = _token_nll(_head(params, h, cfg), labels, mask)
+            nll = _token_nll(_head(params, h, cfg), labels, mask, cfg.dtype)
     extra = 0.0
     if cfg.mtp_layers:
         if cfg.mtp_layers != 1:
@@ -1398,7 +1416,7 @@ def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
             mask2 = ((labels >= 0) & (labels2 >= 0)
                      & (jnp.arange(t) < t - 1)[None]).astype(jnp.float32)
             extra = extra + cfg.mtp_weight * _token_nll(
-                _head(params, h2, cfg), labels2, mask2) \
+                _head(params, h2, cfg), labels2, mask2, cfg.dtype) \
                 / jnp.maximum(mask2.sum(), 1.0)
     if index_aux:
         with jax.named_scope("head_loss"):
@@ -1409,7 +1427,8 @@ def loss_local(params, tokens, labels, cfg: Config, ax: Axes):
             extra = extra + cfg.router_aux_weight * sum(
                 a[0] for a in aux) / len(aux) + cfg.router_z_weight * sum(
                 a[1] for a in aux) / len(aux)
-    return nll + count * extra, count
+    with jax.named_scope("head_loss"):
+        return nll + count * extra, count
 
 
 def _probe(name: str, *static: str):

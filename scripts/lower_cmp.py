@@ -108,14 +108,16 @@ def as_on_a_v5e():
             mod._memory_limit = lambda: V5E_LIMIT
 
 
-def step_and_shapes(name: str, manifest, mf):
+def step_and_shapes(name: str, manifest, mf, rehearsal: bool = False):
     """(cell `name`'s jitted step, its abstract parameters, one
-    abstract batch's tokens and labels)."""
+    abstract batch's tokens and labels); `rehearsal`: of its toy twin
+    (`benchmark/configs/<config>.rehearsal.json`)."""
     import jax
 
     from benchmark import weights
 
-    _, workload, traffic, config, _ = mf.cell_inputs(manifest, name)
+    _, workload, traffic, config, _ = mf.cell_inputs(manifest, name,
+                                                     rehearsal)
     runner = importlib.import_module("benchmark.runners." + workload["runner"])
     own = [m for m in vars(runner).values() if inspect.ismodule(m)
            and m.__name__.startswith("benchmark.weights_")]

@@ -502,16 +502,18 @@ def test_what_an_axis_cannot_give_yet_raises(axes, what):
 #: commit 8f3d401 (jax 0.9.0, CPU): `build_step(...).lower(...).as_text()`
 #: of the two runners at their rehearsal configurations, batch 2 x 64
 #: — OLMoE's as PR 44 left it, whose full expert layer moves its rows
-#: as the bounded one does (14d80870... and 0ceab40f... before it)
+#: as the bounded one does (14d80870... and 0ceab40f... before it).
+#: PR 45 re-recorded all four: one loss body, the label's logit by a mask
+#: (2cf9a876..., 44ae5c47..., e1c69145... and 5ed4a8c0... before it)
 PARENT = {
     ("opt-30b", "bfloat16"):
-        "2cf9a8762760ea4ae985201e96ebedb84f8469025e5bb13ca68b60bc55e73177",
+        "1c1ff97b8b8ebdd163542ec1ee00c89f5b642a0970b770064350024859c14822",
     ("opt-30b", "float32"):
-        "44ae5c4777a4c265612533d9f6ace4db30d1203ee8ef248c6ae62838e72f6f77",
+        "240463fe65810ebf586e75ce337587596c01d1026b86a12667e8c791f3d06161",
     ("olmoe-1b-7b", "bfloat16"):
-        "e1c691458db483c69a75037fc67f7d27d0928bd211f66645ebeb3e811076abd8",
+        "5ea4396b33250254f3e16794f825a0d9b1312bda790aa9ac29ae72f03b00a3a5",
     ("olmoe-1b-7b", "float32"):
-        "5ed4a8c07f44faa61c5672bdd9f685012abe398cf10a000a428dcf414ea1a88b",
+        "8d34f41d586e1bcd76f325e247577299a22bba132eb4b310f1c928dea83e4266",
 }
 
 

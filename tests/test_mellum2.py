@@ -492,14 +492,16 @@ def test_the_step_lowered_for_the_tpu_holds_the_windows_kernels(
 #: `build_step(...).lower(...).as_text()` of three accepted runners —
 #: the two with experts as PR 44 left them, whose full expert layer
 #: (OLMoE's layer, Nemotron's never-taken branch) moves its rows as
-#: the bounded one does: bc56a7fe... and 81d66122... before it
+#: the bounded one does: bc56a7fe... and 81d66122... before it. PR 45
+#: re-recorded all three: one loss body, the label's logit by a mask
+#: (64ef42df..., a9fb0944... and 5ece6908... before it)
 PARENT = {
     "opt-30b":
-        "64ef42df46a7b8a345c4b87952058c0b20c9914626bc543b767ff8a5a0b7d4e3",
+        "c90271158275193b3843c20b3f0af0c5a528ab2fa0345c3d1e2ed33a29abe869",
     "olmoe-1b-7b":
-        "a9fb09441b5271d5fcb83be9c7cd45484ad23600b459ae56b5c19e8ea63c8cb9",
+        "d64dc9ac8d2d4b8da2911ff1e10ee94c834a4d3e4290aa34db0a3ebb5d999b08",
     "nemotron-3-nano-30b-a3b":
-        "5ece6908d0d3acefcfe2cdd7f034cb559c4d1f2fe89d992c1759e66c79769648",
+        "1224dbf654afbbb6e92872008c9fd886dbf0fc73203b79bd6482c755acc5ee74",
 }
 RUNNERS = {"opt-30b": (train_step, weights),
            "olmoe-1b-7b": (olmoe_train, weights_olmoe),

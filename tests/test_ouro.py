@@ -348,16 +348,20 @@ def test_what_an_axis_cannot_give_yet_raises(over):
 #: since (models/remat.py's `kept`; XLA inlines it). PR 44 re-recorded
 #: the two `glm-5` texts (ba6f5504... and 39d35e4e... before it): the
 #: rehearsal's layer is the FULL one, and that moves its rows as the
-#: bounded one does since (`ops/moe._held_rows` at ``bound = T * k``)
+#: bounded one does since (`ops/moe._held_rows` at ``bound = T * k``).
+#: PR 45 re-recorded all four: `_token_nll` (both of `glm-5`'s heads, and
+#: `once`, which took the first loss body until then) reads the label's
+#: logit by a mask (1dac97dc..., 39047cb2..., 7b780c71... and a0b63b53...
+#: before it)
 PARENT = {
     ("glm-5", "bfloat16"):
-        "1dac97dca4f9b7f99abbbf7bb0a10499aee103616aa45e7377720b2617715ffb",
+        "ab7ced94b29c0989d30f919c4f9870d57e4b11aa611ff11dabebbc1dcc5a2d7c",
     ("glm-5", "float32"):
-        "39047cb26212ed5f7dbaafa73bee2fb03930a83af5346332ab1c67d65c5057d2",
+        "a2c06cea55873db87cdf40ad14ff077e325e0594ec4932909e4dc33166ba5161",
     ("once", "bfloat16"):
-        "7b780c71a862533a759df2d768a264c642c0303c3b339ecd7736d5051c8e6a52",
+        "5b6a94459e35024a4a1ec8cc4e2397768f807ed8c9731cb90bb4707321b8841c",
     ("once", "float32"):
-        "a0b63b53fe55bdbbe52269e6257323703b70bef4f1b8e5fa90a672414c4df5d2",
+        "5333a47d2900259c96da1d7f18dea8ddc314326cae7b90f582755d10ac32baa2",
 }
 
 
