@@ -2,8 +2,9 @@
 
 Three questions of models/transformer.py have ONE answer here: which
 kind each layer is (`_is_moe`, `_layer_kind`: the block's two — each
-under a sliding window or not where a config mixes kinds of attention,
-`Block` —, a pattern's three letters); which sub-layers a layer of a kind has
+under a sliding window or not, or with a delta-rule mixer in
+attention's place, where a config mixes kinds of attention, `Block` —,
+a pattern's three letters); which sub-layers a layer of a kind has
 (`layout`: the rows `layer_forward` walks, the recomputation rule sums
 over and the parameter tree is built from); and every leaf of the
 parameter tree — its path, its shape, how it is initialised and how an
@@ -37,16 +38,20 @@ SSM, EXPERTS, ATTENTION = "M", "E", "*"
 
 
 #: the letters of `Config.attn_layers`: a block's attention attends
-#: inside the sliding window, or over the whole causal triangle
-WINDOWED, FULL = "w", "f"
+#: inside the sliding window, or over the whole causal triangle — or
+#: the block's first sub-layer is no softmax attention but the
+#: delta-rule linear mixer (ops/kda.py)
+WINDOWED, FULL, DELTA = "w", "f", "d"
 
 
 class Block(NamedTuple):
     """A block's kind where a config mixes kinds of attention
     (`Config.attn_layers`): whether its feed-forward part is a mixture
-    of experts, and whether its attention is under the window."""
+    of experts, whether its attention is under the window, and whether
+    the delta-rule mixer stands in attention's place."""
     moe: bool
     windowed: bool
+    delta: bool = False
 
 
 def _layer_kind(cfg, layer: int):
@@ -60,16 +65,23 @@ def _layer_kind(cfg, layer: int):
     if cfg.attn_layers is None:
         return _is_moe(cfg, layer)
     _check_attn_layers(cfg)
-    return Block(_is_moe(cfg, layer), cfg.attn_layers[layer] == WINDOWED)
+    letter = cfg.attn_layers[layer]
+    return Block(_is_moe(cfg, layer), letter == WINDOWED, letter == DELTA)
 
 
 def _check_attn_layers(cfg):
     kinds = cfg.attn_layers
-    if len(kinds) != cfg.n_layers or set(kinds) - {WINDOWED, FULL}:
+    if len(kinds) != cfg.n_layers or set(kinds) - {WINDOWED, FULL, DELTA}:
         raise ValueError(
             f"attn_layers={kinds!r}: expected n_layers = {cfg.n_layers} "
             f"letters of {WINDOWED!r} (attention inside the sliding "
-            f"window) and {FULL!r} (over the whole causal triangle)")
+            f"window), {FULL!r} (over the whole causal triangle) and "
+            f"{DELTA!r} (the delta-rule linear mixer)")
+    if DELTA in kinds and not (cfg.kda_heads and cfg.kda_head_dim):
+        raise ValueError(
+            f"attn_layers={kinds!r} has delta-rule layers and kda_heads="
+            f"{cfg.kda_heads} heads of kda_head_dim={cfg.kda_head_dim} "
+            "are none")
     if WINDOWED in kinds and cfg.attn_window < 1:
         raise ValueError(
             f"attn_layers={kinds!r} has layers under a sliding window "
@@ -117,9 +129,10 @@ class Sub(NamedTuple):
 
 def layout(cfg, kind) -> Tuple[Sub, ...]:
     """The sub-layers of a layer of `kind` (`_layer_kind`). The block
-    is attention (of the config's `attn`; under the sliding window
-    where a `Block` says so) then a dense FFN or the
-    experts, each output named and added under its mixer's scope. A
+    is attention (of the config's `attn`; under the sliding window, or
+    the delta-rule mixer in its place, where a `Block` says so) then a
+    dense FFN or the experts, each output named and added under its
+    mixer's scope. A
     pattern's letter is ONE sub-layer; its output is the layer's, which
     the next layer's input holds anyway, so it has no name, and its add
     lies where PR 39 measured it: the experts' under ``mlp``, the other
@@ -128,10 +141,13 @@ def layout(cfg, kind) -> Tuple[Sub, ...]:
         mixer = {SSM: "ssm", ATTENTION: "attention", EXPERTS: "experts"}[kind]
         return (Sub(mixer, "ln", None, None,
                     ("mlp",) if kind == EXPERTS else ()),)
-    moe, windowed = kind if isinstance(kind, Block) else (kind, False)
+    moe, windowed, delta = kind if isinstance(kind, Block) \
+        else (kind, False, False)
     post = ("ln1_post", "ln2_post") if cfg.post_norm else (None, None)
     return (Sub("mla", "ln1", post[0], ATTN_PROJ_OUT, ("attn_proj", "mla_o"))
             if cfg.attn == "mla" else
+            Sub("kda", "ln1", post[0], ATTN_PROJ_OUT, ("kda", "kda_proj"))
+            if delta else
             Sub("window_attention" if windowed else "attention", "ln1",
                 post[0], ATTN_PROJ_OUT, ("attn_proj",)),
             Sub("experts" if moe else "ffn", "ln2", post[1], MLP_OUT,
@@ -182,6 +198,8 @@ def _attention_leaves(cfg):
     if cfg.qk_norm:  # over the WHOLE projection, gain only
         yield Leaf(("q_norm", "g"), (wide,), ONES)
         yield Leaf(("k_norm", "g"), (narrow,), ONES)
+    if cfg.attn_gate:  # the output gate, one number a head and channel
+        yield Leaf(("wa",), (d, wide), s_emb, COLUMN)
 
 
 def _mla_leaves(cfg):  # replicated: no tp path yet
@@ -251,9 +269,31 @@ def _ssm_leaves(cfg):  # replicated: no tp, sp, ep or pp path
     yield Leaf(("out_proj",), (inner, d), 1.0 / math.sqrt(inner))
 
 
+def _kda_leaves(cfg):  # replicated: no tp, sp, ep or pp path
+    d, s_emb = cfg.d_model, 1.0 / math.sqrt(cfg.d_model)
+    heads, wide = cfg.kda_heads, cfg.kda_heads * cfg.kda_head_dim
+    rank, k = cfg.kda_rank or cfg.kda_head_dim, cfg.kda_conv
+    for name in ("q", "k", "v"):
+        yield Leaf(("w" + name,), (d, wide), s_emb)
+        yield Leaf(("conv_" + name,), (wide, k), 1.0 / math.sqrt(k))
+    # the decay through its bottleneck, initialised as the scan's of a
+    # state-space layer: decays of a trained model, not all ~1 or ~0
+    yield Leaf(("w_fa",), (d, rank), s_emb)
+    yield Leaf(("w_fb",), (rank, wide), 1.0 / math.sqrt(rank))
+    yield Leaf(("dt_bias",), (wide,), DT_BIAS)
+    yield Leaf(("A_log",), (heads,), A_LOG)
+    yield Leaf(("w_b",), (d, heads), s_emb)
+    yield Leaf(("o_norm", "g"), (cfg.kda_head_dim,), ONES)
+    yield Leaf(("w_ga",), (d, rank), s_emb)
+    yield Leaf(("w_gb",), (rank, wide), 1.0 / math.sqrt(rank))
+    yield Leaf(("wo",), (wide, d), 1.0 / math.sqrt(wide)
+               / math.sqrt(2 * cfg.n_layers))
+
+
 #: a mixer's leaves, by `Sub.mixer`
 MIXERS = {"attention": _attention_leaves,
           "window_attention": _attention_leaves, "mla": _mla_leaves,
+          "kda": _kda_leaves,
           "ffn": lambda cfg: _ffn_leaves(cfg, cfg.d_ff),
           "experts": _experts_leaves, "ssm": _ssm_leaves}
 

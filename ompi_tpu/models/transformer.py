@@ -24,10 +24,10 @@ The model is a DESCRIPTION, read from a published config, not a fork
 per model: :class:`Config` says which norm, positions, attention and
 feed-forward part, how many passes and exits, whether a tower stands in
 front; the defaults are OPT's (pre-LN, ReLU MLP, learned positions,
-tied head). Seven published configurations run through it, each against
+tied head). Eight published configurations run through it, each against
 a float32 reference of its own under ``benchmark/reference/``: OPT,
-OLMoE, GLM-5, Ouro, Kimi-VL, Nemotron-3-Nano, Mellum2. What the module
-holds:
+OLMoE, GLM-5, Ouro, Kimi-VL, Nemotron-3-Nano, Mellum2, Solar-Open2.
+What the module holds:
 
 - :class:`Config`, :class:`Axes`, `_check_supported`: what a config
   asks for that an axis cannot give yet is an error, never another
@@ -39,15 +39,17 @@ holds:
   describes the parameter tree). The block is attention then a dense
   FFN or the experts, by the layer's INDEX (``moe_every``,
   ``first_dense``) — its attention inside a sliding window or over the
-  whole causal triangle, each kind with RoPE parameters of its own,
-  where ``attn_layers`` mixes the two —; a layer of a ``layer_pattern``
+  whole causal triangle, each kind with RoPE parameters of its own, or
+  the delta-rule linear mixer in its place, where ``attn_layers`` mixes
+  them —; a layer of a ``layer_pattern``
   is ONE mixer, by its letter (`SSM` ``M``, `EXPERTS` ``E``,
   `ATTENTION` ``*``).
-- The MIXERS, five, each with what it costs the recomputation rule
+- The MIXERS, six, each with what it costs the recomputation rule
   (`_COSTS`): multi-head attention (`_attention`: shared key heads, a
   head width of its own, positions learned / RoPE — YaRN's blended
   frequencies and attention factor, `Rope` — / none, QK-norm, a sliding
-  window, tp, sp); latent attention (`_mla_attention`, with the sparse-attention
+  window, an output gate, tp, sp); Kimi Delta Attention (ops/kda.py,
+  imported only where ``attn_layers`` has a ``d``); latent attention (`_mla_attention`, with the sparse-attention
   indexer where ``index_topk`` is set); the dense FFN; the experts
   (`_experts`: sorted path, held share, shared expert, ep); the
   Mamba-2 state-space mixer (ops/ssm.py, imported only where a pattern
@@ -109,7 +111,18 @@ the scores, softmax and values lie one scope further in:
 stay under ``attn_proj/qk_rope``); counted once per traced layer there:
 ``attn_window_layers`` / ``attn_full_layers``, and by
 ``ops/attention.attention`` for a windowed attention that took the
-kernels ``attn_window_tiles`` / ``attn_causal_tiles``.
+kernels ``attn_window_tiles`` / ``attn_causal_tiles``. A delta-rule
+layer of such a config (``d``) is ``layer_<i>/{ln, kda, mlp}`` with
+``kda/{kda_proj, kda_conv, kda_core, kda_gate_norm}`` (``kda_proj``:
+the q, k, v, decay, beta and gate products, the output product and the
+residual add; ``kda_core``: the norms of q and k, the decays, the
+intra-chunk system, the carry — on the TPU the Pallas kernels
+``kda_carry_fwd`` / ``kda_carry_bwd`` of ops/kda.py — and the
+outputs); a gated attention's gate is ``attn_proj/attn_gate``. Counted
+once per traced layer: ``kda_layers``, ``kda_chunks``,
+``attn_gated_layers`` and, by ``ops/kda.mixer`` for the form its carry
+took, ``kda_carry_kernel_layers`` / ``kda_carry_scan_layers``; the probe
+:func:`kda_probe` counts ``kda_state_norm_micro``.
 """
 
 from __future__ import annotations
@@ -129,7 +142,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ompi_tpu.core import pvar
 from ompi_tpu.models import remat, vision
 from ompi_tpu.models.params import (  # noqa: F401 (the model's own names)
-    ATTENTION, EXPERTS, FULL, SSM, WINDOWED, Block, _check_attn_layers,
+    ATTENTION, DELTA, EXPERTS, FULL, SSM, WINDOWED, Block, _check_attn_layers,
     _check_indexer, _check_pattern, _is_moe, _layer_kind, grad_extra_axes,
     init_params, layout, param_specs)
 from ompi_tpu.models.remat import (  # noqa: F401
@@ -327,6 +340,21 @@ class Config:
     #: None — and the windowed ones'. None: `rope_theta`, unscaled
     rope_full: Optional[Rope] = None
     rope_window: Optional[Rope] = None
+    #: the delta-rule linear mixer of the layers `attn_layers` marks
+    #: `DELTA` "d" (Kimi Delta Attention, ops/kda.py): heads of
+    #: kda_head_dim channels for keys and values alike, the taps of the
+    #: three short convolutions, the tokens of a chunk, and the rank of
+    #: the decay's and the output gate's two-step projections (0: a
+    #: head's width)
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_chunk: int = 64
+    kda_rank: int = 0
+    #: multi-head attention's output is gated before its last product:
+    #: o * sigmoid(x W_a), one number a head and channel from the same
+    #: normed x (leaf wa [d_model, n_heads x head_dim])
+    attn_gate: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -500,11 +528,30 @@ def _check_supported(cfg: Config, ax: Axes, is_moe, pos_offset, t=None):
                     "not written")
     if cfg.attn_layers is not None:
         _check_attn_layers(cfg)
+        if DELTA in cfg.attn_layers:
+            for axis, missing in (
+                    (ax.tp, "tensor parallelism (ax.tp): the mixer's heads, "
+                     "its convolutions' channels and the decay's bottleneck "
+                     "are not split by columns yet"),
+                    (ax.ep, "expert parallelism (ax.ep): the tokens an ep "
+                     "axis shards are a sequence's, whose carried state "
+                     "would cross chips")):
+                if axis:
+                    raise NotImplementedError(
+                        "a delta-rule mixer (Config.attn_layers 'd') under "
+                        + missing)
+            if t is not None and t % cfg.kda_chunk:
+                raise NotImplementedError(
+                    f"a sequence of {t} tokens is no whole number of the "
+                    f"delta rule's chunks (kda_chunk={cfg.kda_chunk}): a "
+                    "last chunk padded with tokens that change no state "
+                    "is not written")
         for on, missing in (
                 (ax.sp, "sequence parallelism (ax.sp): the ring and the "
                  "Ulysses schedules take causal=True and nothing else; a "
                  "window that skips the blocks no query of a shard "
-                 "reaches is ROADMAP Queue 2a"),
+                 "reaches — and a delta-rule mixer's carried state and "
+                 "last taps across chips — are ROADMAP Queue 2a"),
                 (ax.pp, "pipeline parallelism (ax.pp): layers of two "
                  "kinds do not stack into equal stages "
                  "(models/pipeline.py scans equal ones; ROADMAP R3)"),
@@ -759,7 +806,11 @@ def _attention(lp, x, cfg: Config, ax: Axes, pos_offset,
     down to the entry) and turns by ``rope_window``; a full layer by
     ``rope_full``. Where the config mixes the two kinds the core lies
     under a scope of the kind's name and the layer is counted:
-    ``attn_window_layers`` / ``attn_full_layers``."""
+    ``attn_window_layers`` / ``attn_full_layers``. Where the config
+    gates attention's output (``attn_gate``) the core's result is
+    multiplied by ``sigmoid(x W_a)`` in float32 in front of the output
+    projection, under ``attn_proj/attn_gate``; counted once per traced
+    layer: ``attn_gated_layers``."""
     dt = cfg.dtype
     b, t, _ = x.shape
     dh = cfg.head_dim
@@ -840,6 +891,13 @@ def _attention(lp, x, cfg: Config, ax: Axes, pos_offset,
                               scale=1.0 if q_scale else None)
     with jax.named_scope("attn_proj"):
         o = o.reshape(b, t, -1)
+        if cfg.attn_gate:
+            with jax.named_scope("attn_gate"):
+                pvar.record("attn_gated_layers")
+                gate = jax.nn.sigmoid(jnp.dot(
+                    x, lp["wa"].astype(dt),
+                    preferred_element_type=jnp.float32))
+                o = (o.astype(jnp.float32) * gate).astype(dt)
         o = o @ lp["wo"].astype(dt)   # row parallel: partial sums
         if ax.tp:
             o = region_exit(o, ax.tp)
@@ -898,6 +956,18 @@ def _ssm_mixer(lp, x, cfg: Config):
             eps=cfg.norm_eps)
 
 
+def _kda_mixer(lp, x, cfg: Config):
+    """(the delta-rule mixer's output, its state after the last token)
+    of the normed x at the config's sizes (ops/kda.py, imported where
+    `attn_layers` has a delta-rule layer and nowhere else)."""
+    from ompi_tpu.ops import kda
+
+    with jax.named_scope("kda"):
+        return kda.mixer(lp, x, heads=cfg.kda_heads,
+                         head_dim=cfg.kda_head_dim, chunk=cfg.kda_chunk,
+                         eps=cfg.norm_eps)
+
+
 def _sublayer(lp, h, cfg: Config, ax: Axes, sub, pos_offset=None, aux=None,
               index_aux=None):
     """One sub-layer (`sub`: a row of models/params.py's ``layout``):
@@ -905,7 +975,8 @@ def _sublayer(lp, h, cfg: Config, ax: Axes, sub, pos_offset=None, aux=None,
     the row names one, the output named for the recomputation rule
     where the row names it, the add under the row's scopes. Counted
     once per traced state-space layer: ``ssm_layers`` and
-    ``ssm_chunks``; the other mixers count of themselves."""
+    ``ssm_chunks``, per traced delta-rule layer ``kda_layers`` and
+    ``kda_chunks``; the other mixers count of themselves."""
     x = _norm(h.astype(jnp.float32), lp[sub.pre], cfg).astype(cfg.dtype)
     if sub.mixer == "attention":
         y = _attention(lp, x, cfg, ax, pos_offset)
@@ -917,6 +988,10 @@ def _sublayer(lp, h, cfg: Config, ax: Axes, sub, pos_offset=None, aux=None,
         y = _dense_ffn(lp, x, cfg, ax)
     elif sub.mixer == "experts":
         y = _experts(lp, x, cfg, ax, aux)
+    elif sub.mixer == "kda":
+        pvar.record("kda_layers")
+        pvar.record("kda_chunks", x.shape[1] // cfg.kda_chunk)
+        y = _kda_mixer(lp, x, cfg)[0]
     else:
         pvar.record("ssm_layers")
         pvar.record("ssm_chunks", x.shape[1] // cfg.ssm_chunk)
@@ -1033,9 +1108,27 @@ def _ssm_costs(cfg: Config, n: int, t: int, it: int):
             0)
 
 
+def _kda_costs(cfg: Config, n: int, t: int, it: int):
+    """ops/kda.py's two names: the three wide products' results, and
+    the gated output in front of the last product — kept, it spares the
+    convolutions, the chunked recurrence, the norm and the gate one of
+    their runs (each run of heads is recomputed in its own backward
+    pass whatever is kept); the operations counted are the
+    recurrence's products."""
+    from ompi_tpu.ops import kda
+
+    wide = cfg.kda_heads * cfg.kda_head_dim
+    return ({kda.KDA_PROJ: 3 * n * wide * it, kda.KDA_OUT: n * wide * it},
+            {kda.KDA_PROJ: 2 * n * cfg.d_model * 3 * wide,
+             kda.KDA_OUT: n * cfg.kda_heads * kda.core_flops_per_token(
+                 cfg.kda_head_dim, cfg.kda_chunk)},
+            2 * n * wide * cfg.d_model)
+
+
 #: a mixer's costs, by the name its layout row carries
 _COSTS = {"attention": _attention_costs,
           "window_attention": _window_attention_costs, "mla": _mla_costs,
+          "kda": _kda_costs,
           "ffn": _ffn_costs(lambda cfg: cfg.d_ff),
           "experts": _ffn_costs(lambda cfg: cfg.shared_width),
           "ssm": _ssm_costs}
@@ -1590,8 +1683,11 @@ def gqa_probe(params, tokens, cfg: Config):
     return _gqa_probe(params, tokens, cfg=cfg)
 
 
-@_probe("ompi_attn_probe", "layer")
-def _attn_probe(params, tokens, cfg: Config, layer: int):
+def _embedded_for(params, tokens, cfg: Config, layer: int, delta: bool):
+    """(block `layer`'s leaves, its first sub-layer's layout row, that
+    sub-layer's normed input) on the EMBEDDED batch — the stream
+    entering layer 0 — for a probe of a delta-rule mixer (`delta`) or
+    of attention: asked for the other kind of layer it raises."""
     dt = cfg.dtype
     h = params["embed"].astype(dt)[tokens]
     if cfg.pos == "learned":
@@ -1599,10 +1695,19 @@ def _attn_probe(params, tokens, cfg: Config, layer: int):
     lp, kind = params["layers"][layer], _layer_kind(cfg, layer)
     _check_supported(cfg, Axes(), kind, None, tokens.shape[1])
     sub = layout(cfg, kind)[0]
-    x = _norm(h.astype(jnp.float32), lp[sub.pre], cfg).astype(dt)
+    if (sub.mixer == "kda") != delta:
+        raise ValueError(
+            f"layer {layer} " + ("has no delta-rule mixer" if delta else
+                                 "is a delta-rule layer: kda_probe reads it")
+            + f" (attn_layers={cfg.attn_layers!r})")
+    return lp, sub, _norm(h.astype(jnp.float32), lp[sub.pre], cfg).astype(dt)
+
+
+@_probe("ompi_attn_probe", "layer")
+def _attn_probe(params, tokens, cfg: Config, layer: int):
+    lp, sub, x = _embedded_for(params, tokens, cfg, layer, delta=False)
     return _attention(lp, x, cfg, Axes(), None,
                       windowed=sub.mixer == "window_attention")
-
 
 
 def attn_probe(params, tokens, cfg: Config, layer: int):
@@ -1620,6 +1725,27 @@ def attn_probe(params, tokens, cfg: Config, layer: int):
         raise ValueError("attn_probe reads a block's multi-head attention "
                          "(no layer_pattern, attn='mha')")
     return _attn_probe(params, tokens, cfg=cfg, layer=layer)
+
+
+@_probe("ompi_kda_probe", "layer")
+def _kda_probe(params, tokens, cfg: Config, layer: int):
+    lp, _, x = _embedded_for(params, tokens, cfg, layer, delta=True)
+    return _kda_mixer(lp, x, cfg)
+
+
+def kda_probe(params, tokens, cfg: Config, layer: int):
+    """(block `layer`'s delta-rule mixer output [B, T, d_model], its
+    state after the last token [B, H, K, K] float32) on the EMBEDDED
+    batch, as :func:`attn_probe` reads a block's attention: a decay
+    ignored, the correction term left out, a chunk boundary handled
+    wrongly or the output gate missing show in it and in little else. A
+    probe the host calls outside any timed window: the norm of that
+    state goes to the always-on counter `kda_state_norm_micro`, in
+    millionths."""
+    out, last = _kda_probe(params, tokens, cfg=cfg, layer=layer)
+    pvar.record("kda_state_norm_micro", int(round(1e6 * float(
+        jnp.sqrt(jnp.sum(last * last))))))
+    return out, last
 
 
 def ssm_probe(params, tokens, cfg: Config):

@@ -90,16 +90,16 @@ SSM_Y = "ssm_y"
 F32 = jnp.float32
 
 
-def causal_conv(xbc, w, b):
+def causal_conv(xbc, w, b=None):
     """``silu(b + sum_j w[:, j] * xbc[t - (K - 1) + j])``: a depthwise
     convolution over time with zeros before the sequence. xbc [B, T,
-    C], w [C, K], b [C] -> [B, T, C] in xbc's type, computed in
-    float32 as K shifted sums."""
+    C], w [C, K], b [C] (None: no bias) -> [B, T, C] in xbc's type,
+    computed in float32 as K shifted sums."""
     t, k = xbc.shape[1], w.shape[1]
     padded = jnp.pad(xbc.astype(F32), ((0, 0), (k - 1, 0), (0, 0)))
     w = w.astype(F32)
-    out = b.astype(F32) + sum(
-        padded[:, j:j + t] * w[:, j] for j in range(k))
+    taps = (padded[:, j:j + t] * w[:, j] for j in range(k))
+    out = sum(taps) if b is None else b.astype(F32) + sum(taps)
     return jax.nn.silu(out).astype(xbc.dtype)
 
 

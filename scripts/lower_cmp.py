@@ -5,7 +5,8 @@ For each train cell of ``BENCHMARK.json`` (or the cells named), lower the
 runner's ``build_step`` for ``("tpu",)`` on abstract parameters and one
 abstract batch at the cell's full shape — the static rules that choose
 a kernel (``att.blockwise_tile``, ``segment_tile``, ``dsa_tile``,
-``moe.grouped_tiles``, ``expert_width_pad``, ``ssm.scan_tile``) and the
+``moe.grouped_tiles``, ``expert_width_pad``, ``ssm.scan_tile``,
+``kda.carry_tile``) and the
 device's memory limit answering as on a v5e — and print one line a cell:
 
     <cell> text <sha256> scopes <sha256> <characters> {counters}
@@ -40,7 +41,8 @@ V5E_LIMIT = 16_909_336_064
 RULES = (("ops.attention", "blockwise_tile"),
          ("ops.attention", "segment_tile"),
          ("ops.attention", "dsa_tile"), ("ops.moe", "grouped_tiles"),
-         ("ops.moe", "expert_width_pad"), ("ops.ssm", "scan_tile"))
+         ("ops.moe", "expert_width_pad"), ("ops.ssm", "scan_tile"),
+         ("ops.kda", "carry_tile"))
 #: the pvars that say which path a traced layer took
 COUNTED = ("moe_bounded_layers", "moe_full_layers",
            "moe_row_sum_gather_layers", "moe_row_sum_product_layers",
@@ -48,7 +50,9 @@ COUNTED = ("moe_bounded_layers", "moe_full_layers",
            "attn_blockwise_layers", "attn_reference_layers",
            "attn_window_layers", "attn_full_layers", "attn_window_tiles",
            "attn_causal_tiles", "attn_dsa_kernel_layers", "ssm_scan_kernel_layers",
-           "ssm_scan_product_layers", "remat_kept_applications",
+           "ssm_scan_product_layers", "kda_carry_kernel_layers",
+           "kda_carry_scan_layers", "attn_gated_layers",
+           "remat_kept_applications",
            "remat_whole_applications", "remat_kept_bytes")
 
 _NUMBERED = re.compile(r"@([A-Za-z_][A-Za-z_0-9]*?)_(\d+)\b")

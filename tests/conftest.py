@@ -46,3 +46,19 @@ def one_chip():
     except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def kernels_on_cpu(monkeypatch):
+    """ops/kda.py's carry as on a TPU — the rule `carry_tile` answering
+    with heads a grid step, the Pallas kernels in interpret mode —:
+    everything else is the program's own path."""
+    import functools
+
+    from ompi_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "carry_tile",
+                        lambda backend, t, heads, *a: next(
+                            n for n in (2, 1) if heads % n == 0))
+    monkeypatch.setattr(kda, "kernel_carry", functools.partial(
+        kda.kernel_carry, interpret=True))
