@@ -127,12 +127,14 @@ WELL_KNOWN = (
     # (Config.attn_layers 'd') and the chunks its recurrence works a
     # sequence in; ops/kda.mixer, once per TRACED call: the
     # chunk-to-chunk carry runs on the Pallas kernels, or as a lax.scan
-    # (the rule ops/kda.carry_tile); an attention layer whose output is
+    # (the rule ops/kda.carry_tile), and the chunk-local work ran in
+    # those kernels too; an attention layer whose output is
     # gated (Config.attn_gate); the set-up probe transformer.kda_probe:
     # the norm of the probed layer's state after the last token, in
     # millionths
     "kda_layers", "kda_chunks", "kda_carry_kernel_layers",
-    "kda_carry_scan_layers", "attn_gated_layers", "kda_state_norm_micro",
+    "kda_carry_scan_layers", "kda_core_kernel_layers", "attn_gated_layers",
+    "kda_state_norm_micro",
     # the phases of mpi.Init(), once per job (runtime/state.py,
     # runtime/device_plane.py; "import" also holds the import of
     # ompi_tpu.mpi itself): they end before any profiler session can

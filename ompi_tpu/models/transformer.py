@@ -116,12 +116,13 @@ layer of such a config (``d``) is ``layer_<i>/{ln, kda, mlp}`` with
 ``kda/{kda_proj, kda_conv, kda_core, kda_gate_norm}`` (``kda_proj``:
 the q, k, v, decay, beta and gate products, the output product and the
 residual add; ``kda_core``: the norms of q and k, the decays, the
-intra-chunk system, the carry — on the TPU the Pallas kernels
-``kda_carry_fwd`` / ``kda_carry_bwd`` of ops/kda.py — and the
-outputs); a gated attention's gate is ``attn_proj/attn_gate``. Counted
-once per traced layer: ``kda_layers``, ``kda_chunks``,
-``attn_gated_layers`` and, by ``ops/kda.mixer`` for the form its carry
-took, ``kda_carry_kernel_layers`` / ``kda_carry_scan_layers``; the probe
+intra-chunk system, the carry and the outputs — on the TPU all but
+the decays in the Pallas kernels ``kda_delta_fwd`` / ``kda_delta_bwd``
+of ops/kda.py); a gated attention's gate is ``attn_proj/attn_gate``.
+Counted once per traced layer: ``kda_layers``, ``kda_chunks``,
+``attn_gated_layers`` and, by ``ops/kda.mixer`` for the form its core
+took, ``kda_carry_scan_layers``, or ``kda_carry_kernel_layers`` and
+``kda_core_kernel_layers``; the probe
 :func:`kda_probe` counts ``kda_state_norm_micro``.
 """
 
@@ -1110,18 +1111,25 @@ def _ssm_costs(cfg: Config, n: int, t: int, it: int):
 
 def _kda_costs(cfg: Config, n: int, t: int, it: int):
     """ops/kda.py's two names: the three wide products' results, and
-    the gated output in front of the last product — kept, it spares the
-    convolutions, the chunked recurrence, the norm and the gate one of
-    their runs (each run of heads is recomputed in its own backward
-    pass whatever is kept); the operations counted are the
-    recurrence's products."""
+    the gated output in front of the last product. Each run of heads
+    is recomputed in its own backward pass whatever is kept; what the
+    gated output, kept, spares of the recurrence's products depends on
+    the core's form (``kda.carry_tile``): as ``jax.numpy`` the run's
+    recomputation is all the backward pass needs, and the layer's is
+    spared one run of the core; on the kernels the run keeps the core's
+    output and entering states from the LAYER's recomputation, which
+    therefore runs the core whatever is kept — and the run's own does
+    not: nothing is spared."""
     from ompi_tpu.ops import kda
 
     wide = cfg.kda_heads * cfg.kda_head_dim
+    on_kernels = kda.carry_tile(
+        jax.default_backend(), t, min(kda.HEADS_A_RUN, cfg.kda_heads),
+        cfg.kda_head_dim, cfg.kda_chunk, cfg.dtype) is not None
     return ({kda.KDA_PROJ: 3 * n * wide * it, kda.KDA_OUT: n * wide * it},
             {kda.KDA_PROJ: 2 * n * cfg.d_model * 3 * wide,
-             kda.KDA_OUT: n * cfg.kda_heads * kda.core_flops_per_token(
-                 cfg.kda_head_dim, cfg.kda_chunk)},
+             kda.KDA_OUT: 0 if on_kernels else n * cfg.kda_heads
+             * kda.core_flops_per_token(cfg.kda_head_dim, cfg.kda_chunk)},
             2 * n * wide * cfg.d_model)
 
 

@@ -50,22 +50,31 @@ accumulation. Nothing of ``[T, T]`` and no state per token exists.
 **The chunk-to-chunk carry is a true recurrence** — the state entering
 a chunk is multiplied by a ``[K, K]`` matrix, ``Diag(exp(G_C)) - Kd^T
 W`` — so unlike Mamba-2's (``ops/ssm.py``) it is no single product. The
-carry alone (``V' = U - W S``, the entering states, the update) runs in
-one of two forms chosen by the static rule :func:`carry_tile` from the
-backend and the shapes: **on the TPU** a Pallas kernel whose grid walks
-the chunks with S in VMEM (:func:`kernel_carry`: ``kda_carry_fwd``,
-and behind a ``custom_vjp`` that keeps its operands and results the
-reverse-grid ``kda_carry_bwd``), so that the step has no ``while`` (a
-``while`` event of a device trace carries no op path); **everywhere
-else** (the CPU, a rehearsal) and as the kernels' oracle a ``lax.scan``
-over the chunks (:func:`scan_carry`). Everything else is batched
-``jax.numpy`` around it, its gradient autodiff's.
+core runs in one of two forms chosen by the static rule
+:func:`carry_tile` from the backend and the shapes. **On the TPU** two
+Pallas kernels behind one ``custom_vjp`` (:func:`kernel_delta`):
+``kda_delta_fwd``, whose grid walks the chunks of a few heads with S in
+VMEM and makes in each step a chunk's cumulative sums, pair sums,
+system, W, U, the carry and the output, and the reverse-grid
+``kda_delta_bwd``, which makes them again from the kernel's own
+operands and the kept entering states and writes all five cotangents.
+They read q, k, v, g as ``[B, T, H K]``, where the products and the
+convolutions wrote them, norm q and k themselves, and keep every
+intermediate in VMEM: the step has no ``while`` (a ``while`` event of a
+device trace carries no op path) and no float32 copy of q, k or v in
+HBM. **Everywhere else** (the CPU, a rehearsal) and as the kernels'
+oracle, :func:`chunked_delta`: batched ``jax.numpy`` around a
+``lax.scan`` over the chunks (:func:`scan_carry`), its gradient
+autodiff's.
 
 What a recomputed layer may keep (``checkpoint_name``, chosen by
 ``models/transformer.py``'s rule): :data:`KDA_PROJ` — the three wide
 products' results — and :data:`KDA_OUT` — the normed, gated output in
 front of the last product; what lies between them is recomputed a run
-of heads at a time whatever is kept (:data:`HEADS_A_RUN`).
+of heads at a time whatever is kept (:data:`HEADS_A_RUN`), except the
+kernels' output and entering states (:data:`KDA_CORE`), which a run
+keeps from the layer's recomputation: its own runs the convolutions
+and the decays again, not the core.
 """
 
 from __future__ import annotations
@@ -85,6 +94,9 @@ from ompi_tpu.ops.ssm import LANES, causal_conv
 
 KDA_PROJ = "kda_proj"
 KDA_OUT = "kda_out"
+#: what a run of heads keeps of its own recomputation: the core's
+#: kernel's output and the states entering the chunks
+KDA_CORE = "kda_core"
 
 F32 = jnp.float32
 _HIGHEST = lax.Precision.HIGHEST
@@ -94,10 +106,11 @@ _TN = (((0,), (0,)), ((), ()))  # A^T B
 #: rows of a sub-block of a chunk: pairs inside one are summed
 #: elementwise over the channels, pairs of two are products
 SUB = 16
-#: the heads a grid step of the carry's kernels takes (what divides the
-#: heads: a step's three products of [C, K] x [K, K] are short, and its
-#: fixed cost is shared)
-_HEADS_A_STEP = (8, 4, 2, 1)
+#: the heads a grid step of the core's kernels takes (what divides the
+#: heads: a step's fixed cost is shared, and the heads' chains of small
+#: products interleave; scripts/kda_core_probe.py reads 1 / 2 / 4 / 8
+#: on the chip)
+_HEADS_A_STEP = (4, 2, 1)
 
 
 def l2norm(x, eps: float):
@@ -259,149 +272,440 @@ def scan_carry(w, u, kd, grown):
 
 def carry_tile(backend: str, t: int, heads: int, head_dim: int, chunk: int,
                dtype):
-    """The rule that sends the carry to the Pallas kernels, made of
-    what the call can observe: the heads a grid step takes, or None —
-    off the TPU, a sequence the chunk does not divide, a head the lanes
-    do not divide, a chunk that is no whole number of the type's
-    sublane tiles."""
+    """The rule that sends the CORE to the Pallas kernels, made of what
+    the call can observe: the heads a grid step takes, or None — off
+    the TPU, a sequence the chunk does not divide, a head the lanes do
+    not divide, a chunk that is no whole number of the type's sublane
+    tiles or of sub-blocks that pair up (the kernels' system merges
+    them two by two)."""
     size = jnp.dtype(dtype).itemsize
+    blocks = max(chunk // SUB, 1)
     if (backend != "tpu" or t % chunk or head_dim % LANES
-            or chunk % (32 // size)):
+            or chunk % (32 // size) or chunk % min(SUB, chunk)
+            or blocks & (blocks - 1)):
         return None
     return next(n for n in _HEADS_A_STEP if heads % n == 0)
 
 
-def _fwd_kernel(w, u, kd, grown, vp, entering, last, s_s, *, per: int):
+# -- a chunk in VMEM -----------------------------------------------------------
+#
+# What follows works on VALUES inside a kernel: one head's chunk, [C, K]
+# with the channels along the lanes, [C, C] matrices with a row's pairs
+# along the lanes. It is `chunked_delta`'s arithmetic term for term; only
+# the order of the steps is the kernel's own (the system's substitution
+# rides the loop that makes the pairs' columns).
+
+def _of_each(x, sub: int, of):
+    """`of` (a sub-block [sub, N] -> [1, N]) of every sub-block of x
+    [C, N], over that sub-block's rows."""
+    return jnp.concatenate(
+        [jnp.broadcast_to(of(x[a:a + sub]), (sub,) + x.shape[1:])
+         for a in range(0, x.shape[0], sub)], axis=0)
+
+
+def _row_of_each(x, i: int, sub: int):
+    """Row `i` of every sub-block of x, over that sub-block's rows."""
+    return _of_each(x, sub, lambda block: block[i:i + 1])
+
+
+def _sum_of_each(x, sub: int):
+    """Every sub-block's rows summed, over that sub-block's rows."""
+    return _of_each(x, sub, lambda block: block.sum(0, keepdims=True))
+
+
+def _high(x, y, dims=(((1,), (0,)), ((), ()))):
+    """A float32 product at the highest precision (the system's)."""
+    return lax.dot_general(x, y, dims, precision=_HIGHEST,
+                           preferred_element_type=F32)
+
+
+def _mxu(x, y, dims=(((1,), (0,)), ((), ()))):
+    """A product of operands in the activations' type, float32 sums."""
+    return lax.dot_general(x, y, dims, preferred_element_type=F32)
+
+
+class _Grid:
+    """The index arrays of a chunk of `c` rows in sub-blocks of `sub`."""
+
+    def __init__(self, c: int):
+        self.c, self.sub = c, min(SUB, c)
+        self.row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+        self.col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+        #: a column's place in the ROW's own sub-block (outside 0 ..
+        #: sub - 1: another sub-block's column)
+        self.place = self.col - (self.row & -self.sub)
+        #: a row's place in its sub-block, [C, 1]
+        self.inner = lax.broadcasted_iota(jnp.int32, (c, 1), 0) \
+            & (self.sub - 1)
+
+    def earlier(self, a: int, cum, kf, dtype):
+        """Sub-block `a`'s operands against the sub-blocks before it,
+        referred to its first row: (rise [sub, K], fall [a sub, K], the
+        earlier rows' decayed k over the whole chunk's rows [C, K] in
+        `dtype`, zero from this sub-block on)."""
+        lo = a * self.sub
+        first = cum[lo:lo + 1]
+        rise = jnp.exp(cum[lo:lo + self.sub] - first)
+        fall = jnp.exp(first - cum[:lo])
+        early = jnp.concatenate(
+            [(kf[:lo] * fall).astype(dtype),
+             jnp.zeros((self.c - lo, kf.shape[1]), dtype)], axis=0)
+        return rise, fall, early
+
+
+def _same_block_column(grid: _Grid, i: int, cum, kf):
+    """(e, k_i e) [C, K] of column `i` of every diagonal sub-block: e =
+    exp(cum_r - cum_i) on the rows r >= i of i's sub-block, 0 above."""
+    e = jnp.exp(jnp.where(grid.inner >= i,
+                          cum - _row_of_each(cum, i, grid.sub), -jnp.inf))
+    return e, _row_of_each(kf, i, grid.sub) * e
+
+
+def _within(q, k, v, g, beta):
+    """One head's chunk: q, k [C, K], v [C, V] in the activations'
+    type, g [C, K] and beta [C, 1] float32 -> a dict of `chunked_delta`'s
+    values of that chunk: cum, decay = exp(cum), fade = exp(cum_C -
+    cum), grown = exp(cum_C) [1, K], pairs, kk, solve = (I + beta
+    KK)^-1 [C, C], w, u float32 (before their rounding)."""
+    c, dtype = k.shape[0], v.dtype
+    grid = _Grid(c)
+    sub, row, col = grid.sub, grid.row, grid.col
+    cum = _high((col <= row).astype(F32), g)
+    qf, kf = q.astype(F32), k.astype(F32)
+    pairs = kk = jnp.zeros((c, c), F32)
+    solve = (row == col).astype(F32)
+    for i in range(sub):  # a column of every diagonal sub-block at once
+        _, ke = _same_block_column(grid, i, cum, kf)
+        p_i = (qf * ke).sum(-1, keepdims=True)
+        k_i = (kf * ke).sum(-1, keepdims=True)
+        pairs = jnp.where(grid.place == i, p_i, pairs)
+        kk = jnp.where(grid.place == i, k_i, kk)
+        if i < sub - 1:  # forward substitution: the rows below row i
+            solve = solve - jnp.where(grid.inner > i, beta * k_i, 0.0) \
+                * _row_of_each(solve, i, sub)
+    rows_p, rows_k = [jnp.zeros((sub, c), F32)], [jnp.zeros((sub, c), F32)]
+    for a in range(1, c // sub):
+        lo = a * sub
+        rise, _, early = grid.earlier(a, cum, kf, dtype)
+        both = jnp.concatenate([qf[lo:lo + sub] * rise,
+                                kf[lo:lo + sub] * rise], axis=0)
+        off = _mxu(both.astype(dtype), early, _NT)           # [2 sub, C]
+        rows_p.append(off[:sub])
+        rows_k.append(off[sub:])
+    if len(rows_p) > 1:
+        pairs = pairs + jnp.concatenate(rows_p, axis=0)
+        kk = kk + jnp.concatenate(rows_k, axis=0)
+    system = jnp.where(col < row, beta * kk, 0.0)
+    size = sub
+    while size < c:  # [[X, 0], [-Y a21 X, Y]] of every pair of blocks
+        below = ((row & size) != 0) & ((col & -size) == (row & -size) - size)
+        solve = solve - _high(_high(solve, jnp.where(below, system, 0.0)),
+                              solve)
+        size *= 2
+    decay = jnp.exp(cum)
+    total = cum[c - 1:c]
+    return dict(cum=cum, decay=decay, fade=jnp.exp(total - cum),
+                grown=jnp.exp(total), pairs=pairs, kk=kk, solve=solve,
+                w=_high(solve, beta * kf * decay),
+                u=_high(solve, beta * v.astype(F32)))
+
+
+def _read(m, q, k, s_b):
+    """What the carry's and the read-out's products read, in the
+    activations' type, of `_within`'s values m, the chunk's q, k [C, K]
+    and the state ENTERING it s_b [V, K] (TRANSPOSED: the decay scales
+    its columns): (W, V' = U - W S, Qd = q e^G, Kd, P)."""
+    dtype = q.dtype
+    w = m["w"].astype(dtype)
+    vp = (m["u"].astype(dtype).astype(F32) - _mxu(w, s_b, _NT)).astype(dtype)
+    return (w, vp, (q.astype(F32) * m["decay"]).astype(dtype),
+            (k.astype(F32) * m["fade"]).astype(dtype),
+            m["pairs"].astype(dtype))
+
+
+def _carry(m, q, k, s):
+    """A chunk's carry and read-out, s [V, K] the entering state in
+    float32 -> (s as the products read it, o [C, V] float32, the state
+    after the chunk)."""
+    s_b = s.astype(q.dtype)
+    _, vp, qd, kd, pairs = _read(m, q, k, s_b)
+    return (s_b, _mxu(qd, s_b, _NT) + _mxu(pairs, vp),
+            m["grown"] * s + _mxu(vp, kd, _TN))
+
+
+def _unit(x, l2, scaled: bool):
+    """(x [C, K] as the core reads it, 1 / its rows' norms or None):
+    under `l2` = (eps, q's scale) x is divided by its norm over the
+    head as :func:`l2norm` does, q then scaled, and rounded to x's
+    type; under None x is the core's operand as it is."""
+    if l2 is None:
+        return x, None
+    eps, scale = l2
+    xf = x.astype(F32)
+    r = lax.rsqrt((xf * xf).sum(-1, keepdims=True) + eps)
+    return (xf * r * (scale if scaled else 1.0)).astype(x.dtype), r
+
+
+def _unit_back(x, r, d, l2, scaled: bool):
+    """`_unit` transposed: the cotangent of x of the cotangent d of
+    the normed rows (float32)."""
+    if l2 is None:
+        return d
+    n = x.astype(F32) * r
+    return (l2[1] if scaled else 1.0) * r * (
+        d - n * (n * d).sum(-1, keepdims=True))
+
+
+def _delta_fwd_kernel(q, k, v, g, beta, o, *rest, per: int, keep: bool, l2):
+    """One chunk of `per` heads: the chunk-local values, then the carry
+    and the output, the state in `s_s`. `keep`: the entering states are
+    written (the backward kernel's residual). `l2`: q and k come as the
+    convolutions left them and are normed here (`_unit`)."""
+    entering, last, s_s = rest if keep else (None,) + rest
     c = pl.program_id(2)
 
     @pl.when(c == 0)
     def _():
         s_s[...] = jnp.zeros_like(s_s)
 
-    for h in range(per):  # the state lies transposed, [V, K]
-        s = s_s[h]
-        s_b = s.astype(vp.dtype)
-        entering[h] = s_b
-        v = (u[h].astype(F32) - lax.dot_general(
-            w[h], s_b, _NT, preferred_element_type=F32)).astype(vp.dtype)
-        vp[h] = v
-        s_s[h] = grown[h] * s + lax.dot_general(
-            v, kd[h], _TN, preferred_element_type=F32)
+    width, wide = k.shape[1] // per, v.shape[1] // per
+    for h in range(per):
+        ks, vs = slice(h * width, (h + 1) * width), \
+            slice(h * wide, (h + 1) * wide)
+        q_h, k_h = _unit(q[:, ks], l2, True)[0], _unit(k[:, ks], l2, False)[0]
+        m = _within(q_h, k_h, v[:, vs], g[:, ks], beta[:, h:h + 1])
+        s_b, o_h, s_s[h] = _carry(m, q_h, k_h, s_s[h])
+        if keep:
+            entering[h] = s_b
+        o[:, vs] = o_h.astype(o.dtype)
 
     @pl.when(c == pl.num_programs(2) - 1)
     def _():
         last[...] = s_s[...]
 
 
-def _bwd_kernel(w, kd, grown, entering, vp, dvp, dentering, dlast,
-                dw, du, dkd, dgrown, ds_s, *, per: int):
+def _carry_back(m, q, k, s_b, ds, do):
+    """`_carry` transposed: of the entering state as the products read
+    it, the cotangent of the state AFTER the chunk ds [V, K] float32
+    and of the output do [C, V] -> the cotangents of V' (= of U), W,
+    Kd, Qd [C, .] and the pairs [C, C], float32, of exp(G_C) [1, K] and
+    of the entering state."""
+    c = q.shape[0]
+    low = lax.broadcasted_iota(jnp.int32, (c, c), 1) \
+        <= lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    w, vp, qd, kd, pairs = _read(m, q, k, s_b)
+    ds_b = ds.astype(q.dtype)
+    dvp = _mxu(pairs, do, _TN) + _mxu(kd, ds_b, _NT)
+    dvp_b = dvp.astype(q.dtype)
+    return dict(
+        vp=dvp, w=-_mxu(dvp_b, s_b), kd=_mxu(vp, ds_b), qd=_mxu(do, s_b),
+        pairs=jnp.where(low, _mxu(do, vp, _NT), 0.0),
+        grown=(ds * s_b.astype(F32)).sum(0, keepdims=True),
+        s=m["grown"] * ds - _mxu(dvp_b, w, _TN) + _mxu(do, qd, _TN))
+
+
+def _delta_bwd_kernel(q, k, v, g, beta, entering, do, dlast,
+                      dq, dk, dv, dg, dbeta, ds_s, *, per: int, l2):
     """The grid walked from the last chunk to the first, the cotangent
-    of the state AFTER the chunk carried in `ds_s`."""
+    of the state AFTER the chunk carried in `ds_s`; a chunk's values
+    are made again from the kernel's own inputs."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         ds_s[...] = dlast[...]
 
-    dtype = vp.dtype
+    dtype = v.dtype
+    c = k.shape[0]
+    width, wide = k.shape[1] // per, v.shape[1] // per
+    grid = _Grid(c)
+    sub, row, col = grid.sub, grid.row, grid.col
     for h in range(per):
-        ds = ds_s[h]                                         # [V, K]
-        ds_b, s_b = ds.astype(dtype), entering[h]
-        dv = dvp[h].astype(F32) + lax.dot_general(
-            kd[h], ds_b, _NT, preferred_element_type=F32)
-        dv_b = dv.astype(dtype)
-        du[h] = dv_b
-        dkd[h] = jnp.dot(vp[h], ds_b,
-                         preferred_element_type=F32).astype(dtype)
-        dw[h] = (-jnp.dot(dv_b, s_b,
-                          preferred_element_type=F32)).astype(dtype)
-        dgrown[h] = (ds * s_b.astype(F32)).sum(axis=0, keepdims=True)
-        ds_s[h] = dentering[h].astype(F32) + grown[h] * ds \
-            - lax.dot_general(dv_b, w[h], _TN, preferred_element_type=F32)
+        ks, vs = slice(h * width, (h + 1) * width), \
+            slice(h * wide, (h + 1) * wide)
+        v_h, b_h = v[:, vs], beta[:, h:h + 1]
+        (q_h, r_q), (k_h, r_k) = _unit(q[:, ks], l2, True), \
+            _unit(k[:, ks], l2, False)
+        m = _within(q_h, k_h, v_h, g[:, ks], b_h)
+        qf, kf, vf = q_h.astype(F32), k_h.astype(F32), v_h.astype(F32)
+        cum, decay, fade, solve = m["cum"], m["decay"], m["fade"], m["solve"]
+        kdf = kf * fade
+        d = _carry_back(m, q_h, k_h, entering[h], ds_s[h], do[:, vs])
+        ds_s[h] = d["s"]
+        dvp, dw, dkd, dqd, dpairs = d["vp"], d["w"], d["kd"], d["qd"], \
+            d["pairs"]
+        # -- [W | U] = solve rhs, solve = (I + beta KK)^-1
+        drk, drv = _high(solve, dw, _TN), _high(solve, dvp, _TN)
+        dsystem = jnp.where(col < row, -(_high(drk, m["w"], _NT)
+                                         + _high(drv, m["u"], _NT)), 0.0)
+        dkk = b_h * dsystem
+        d_beta = (dsystem * m["kk"]).sum(-1, keepdims=True) \
+            + (drk * kf * decay).sum(-1, keepdims=True) \
+            + (drv * vf).sum(-1, keepdims=True)
+        dv[:, vs] = (b_h * drv).astype(dtype)
+        d_k = b_h * decay * drk + dkd * fade
+        lost = dkd * kdf
+        d_cum = (b_h * kf * drk + dqd * qf) * decay - lost
+        d_q = dqd * decay
+        dtotal = lost.sum(0, keepdims=True) + d["grown"] * m["grown"]
+        # -- the pairs of one sub-block, column by column
+        at_i = jnp.zeros_like(kf)
+        for i in range(sub):
+            e, ke = _same_block_column(grid, i, cum, kf)
+            dp_i = jnp.where(grid.place == i, dpairs, 0.0).sum(
+                -1, keepdims=True)
+            dk_i = jnp.where(grid.place == i, dkk, 0.0).sum(
+                -1, keepdims=True)
+            d_q = d_q + dp_i * ke
+            d_k = d_k + dk_i * ke
+            te = (dp_i * qf + dk_i * kf) * e
+            d_cum = d_cum + te * _row_of_each(kf, i, sub)
+            at_i = at_i + jnp.where(grid.inner == i, _sum_of_each(te, sub),
+                                    0.0)
+        d_k = d_k + at_i
+        d_cum = d_cum - at_i * kf
+        # -- the pairs of two sub-blocks
+        n = c // sub
+        zero = jnp.zeros((sub, width), F32)
+        q_rows, k_rows, cum_rows = [zero] * n, [zero] * n, [zero] * n
+        head = lax.broadcasted_iota(jnp.int32, (sub, 1), 0) == 0
+        for a in range(1, n):
+            lo = a * sub
+            rise, fall, early = grid.earlier(a, cum, kf, dtype)
+            q_a, k_a = qf[lo:lo + sub], kf[lo:lo + sub]
+            both = jnp.concatenate([q_a * rise, k_a * rise],
+                                   axis=0).astype(dtype)
+            doff = jnp.concatenate([dpairs[lo:lo + sub], dkk[lo:lo + sub]],
+                                   axis=0).astype(dtype)    # [2 sub, C]
+            dboth = _mxu(doff, early)                        # [2 sub, K]
+            dearly = _mxu(doff, both, _TN)[:lo]              # [lo, K]
+            risen = (dboth[:sub] * q_a + dboth[sub:] * k_a) * rise
+            fallen = dearly * kf[:lo] * fall
+            dfirst = fallen.sum(0, keepdims=True) \
+                - risen.sum(0, keepdims=True)
+            q_rows[a] = dboth[:sub] * rise
+            k_rows[a] = k_rows[a] + dboth[sub:] * rise
+            cum_rows[a] = cum_rows[a] + risen + jnp.where(head, dfirst, 0.0)
+            for j in range(a):
+                rows = slice(j * sub, (j + 1) * sub)
+                k_rows[j] = k_rows[j] + dearly[rows] * fall[rows]
+                cum_rows[j] = cum_rows[j] - fallen[rows]
+        if n > 1:
+            d_q = d_q + jnp.concatenate(q_rows, axis=0)
+            d_k = d_k + jnp.concatenate(k_rows, axis=0)
+            d_cum = d_cum + jnp.concatenate(cum_rows, axis=0)
+        d_cum = d_cum + jnp.where(
+            lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1, dtotal, 0.0)
+        dq[:, ks] = _unit_back(q[:, ks], r_q, d_q, l2, True).astype(dtype)
+        dk[:, ks] = _unit_back(k[:, ks], r_k, d_k, l2, False).astype(dtype)
+        dg[:, ks] = _high((col >= row).astype(F32), d_cum)
+        dbeta[:, h:h + 1] = d_beta
 
 
-def _carry_calls(w, u, per: int, interpret: bool):
-    """(the forward call, the backward call) of the carry's kernels for
-    operands of these shapes, `per` heads a grid step."""
-    b, h, nc, c, width = w.shape
-    wide, dtype = u.shape[-1], u.dtype
-    grid = (b, h // per, nc)
+def _delta_call(which: str, q, v, heads: int, chunk: int, per: int, l2,
+                interpret: bool):
+    """The ``pallas_call`` of one of the core's passes — "fwd", "kept"
+    (the forward pass that also writes the entering states) or "bwd" —
+    for q [B, T, H K] and v [B, T, H V], `per` heads a grid step. beta
+    and its cotangent lie [B, H / per, T, per]: a block's last
+    dimension is then the array's."""
+    b, t, hk = q.shape
+    nc, width, wide = t // chunk, hk // heads, v.shape[-1] // heads
+    dtype = v.dtype
+    back, keep = which == "bwd", which == "kept"
 
-    def specs(chunk_of):
-        def at(*tail):  # a [.., chunks, ...] operand's block
-            return pl.BlockSpec(
-                (None, per, None) + tail,
-                lambda b, h, c: (b, h, chunk_of(c)) + (0,) * len(tail))
-        return dict(k=at(c, width), v=at(c, wide), row=at(1, width),
-                    state=at(wide, width),
-                    last=pl.BlockSpec((None, per, wide, width),
-                                      lambda b, h, c: (b, h, 0, 0)))
+    def chunk_of(c):  # the backward pass walks the chunks from the last
+        return nc - 1 - c if back else c
 
-    def shape(*tail, dt=dtype):
-        return jax.ShapeDtypeStruct((b, h, nc) + tail, dt)
+    def rows(n):  # a [B, T, H n] operand's block
+        return pl.BlockSpec((None, chunk, per * n),
+                            lambda b, h, c: (b, chunk_of(c), h))
 
-    params = dict(
+    on = dict(
+        k=rows(width), g=rows(width), v=rows(wide),
+        beta=pl.BlockSpec((None, None, chunk, per),
+                          lambda b, h, c: (b, h, chunk_of(c), 0)),
+        state=pl.BlockSpec((None, per, None, wide, width),
+                           lambda b, h, c: (b, h, chunk_of(c), 0, 0)),
+        last=pl.BlockSpec((None, per, wide, width),
+                          lambda b, h, c: (b, h, 0, 0)))
+    like = dict(k=jax.ShapeDtypeStruct((b, t, hk), dtype),
+                v=jax.ShapeDtypeStruct(v.shape, dtype),
+                g=jax.ShapeDtypeStruct((b, t, hk), F32),
+                beta=jax.ShapeDtypeStruct((b, heads // per, t, per), F32),
+                state=jax.ShapeDtypeStruct((b, heads, nc, wide, width),
+                                           dtype),
+                last=jax.ShapeDtypeStruct((b, heads, wide, width), F32))
+    tokens = b * t * heads
+    products = tokens * core_flops_per_token(width, chunk)
+    moved = tokens * ((2 * width + 2 * wide) * dtype.itemsize
+                      + (width + 1) * 4)
+    kept = b * heads * nc * wide * width * dtype.itemsize
+    if back:
+        kernel = functools.partial(_delta_bwd_kernel, per=per, l2=l2)
+        ins = ("k", "k", "v", "g", "beta", "state", "v", "last")
+        outs = ("k", "k", "v", "g", "beta")
+        cost = pl.CostEstimate(
+            flops=3 * products, transcendentals=tokens * (2 * SUB + 9) * width,
+            bytes_accessed=2 * moved + kept)
+    else:
+        kernel = functools.partial(_delta_fwd_kernel, per=per, keep=keep,
+                                   l2=l2)
+        ins = ("k", "k", "v", "g", "beta")
+        outs = ("v", "state", "last") if keep else ("v", "last")
+        cost = pl.CostEstimate(
+            flops=products, transcendentals=tokens * (SUB + 6) * width,
+            bytes_accessed=moved + keep * kept)
+    return pl.pallas_call(
+        kernel, name="kda_delta_bwd" if back else "kda_delta_fwd",
+        grid=(b, heads // per, nc),
+        in_specs=[on[n] for n in ins], out_specs=tuple(on[n] for n in outs),
+        out_shape=tuple(like[n] for n in outs),
+        scratch_shapes=[pltpu.VMEM((per, wide, width), F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        interpret=interpret)
-    products = 2 * b * h * nc * c * width * wide
-    moved = b * h * nc * (2 * c * width + 2 * c * wide + width * wide) \
-        * dtype.itemsize
-    on = specs(lambda c: c)
-    forward = pl.pallas_call(
-        functools.partial(_fwd_kernel, per=per), name="kda_carry_fwd",
-        out_shape=(shape(c, wide), shape(wide, width),
-                   jax.ShapeDtypeStruct((b, h, wide, width), F32)),
-        grid=grid, in_specs=[on["k"], on["v"], on["k"], on["row"]],
-        out_specs=(on["v"], on["state"], on["last"]),
-        scratch_shapes=[pltpu.VMEM((per, wide, width), F32)],
-        cost_estimate=pl.CostEstimate(flops=2 * products, transcendentals=0,
-                                      bytes_accessed=moved),
-        **params)
-    back = specs(lambda c: nc - 1 - c)
-    backward = pl.pallas_call(
-        functools.partial(_bwd_kernel, per=per), name="kda_carry_bwd",
-        out_shape=(shape(c, width), shape(c, wide), shape(c, width),
-                   shape(1, width, dt=F32)),
-        grid=grid,
-        in_specs=[back["k"], back["k"], back["row"], back["state"],
-                  back["v"], back["v"], back["state"], back["last"]],
-        out_specs=(back["k"], back["v"], back["k"], back["row"]),
-        scratch_shapes=[pltpu.VMEM((per, wide, width), F32)],
-        cost_estimate=pl.CostEstimate(flops=4 * products, transcendentals=0,
-                                      bytes_accessed=2 * moved),
-        **params)
-    return forward, backward
+        cost_estimate=cost, interpret=interpret)
 
 
 @functools.lru_cache(maxsize=None)
-def _kernel_carry(per: int, interpret: bool):
-    """The carry on the kernels as a function of `scan_carry`'s
-    operands, behind a ``custom_vjp`` that keeps w, kd, grown and the
-    forward's own results."""
-    def run(w, u, kd, grown):
-        return _carry_calls(w, u, per, interpret)[0](
-            w, u, kd, grown[..., None, :])
+def _kernel_delta(heads: int, chunk: int, per: int, l2, interpret: bool):
+    """The core on the kernels, behind a ``custom_vjp`` whose residuals
+    are the operands themselves and the states entering the chunks;
+    those and the output carry the name :data:`KDA_CORE`."""
+    def grouped(beta):  # [B, T, H] -> [B, H / per, T, per]
+        b, t, _ = beta.shape
+        return jnp.moveaxis(beta.reshape(b, t, heads // per, per), 2, 1)
 
-    carry = jax.custom_vjp(run)
+    def call(which, q, k, v, g, beta, *more):
+        return _delta_call(which, q, v, heads, chunk, per, l2, interpret)(
+            q, k, v, g, grouped(beta), *more)
 
-    def fwd(w, u, kd, grown):
-        vp, entering, last = run(w, u, kd, grown)
-        return (vp, entering, last), (w, kd, grown, entering, vp)
+    core = jax.custom_vjp(functools.partial(call, "fwd"))
+
+    def fwd(q, k, v, g, beta):
+        o, entering, last = call("kept", q, k, v, g, beta)
+        o, entering = checkpoint_name((o, entering), KDA_CORE)
+        return (o, last), (q, k, v, g, beta, entering)
 
     def bwd(res, cts):
-        w, kd, grown, entering, vp = res
-        dw, du, dkd, dgrown = _carry_calls(w, vp, per, interpret)[1](
-            w, kd, grown[..., None, :], entering, vp, *cts)
-        return dw, du, dkd, dgrown[..., 0, :]
+        dq, dk, dv, dg, dbeta = call("bwd", *res, *cts)
+        b, _, t, _ = dbeta.shape
+        return dq, dk, dv, dg, jnp.moveaxis(dbeta, 1, 2).reshape(b, t, heads)
 
-    carry.defvjp(fwd, bwd)
-    return carry
+    core.defvjp(fwd, bwd)
+    return core
 
 
-def kernel_carry(w, u, kd, grown, per: int, interpret: bool = False):
-    """:func:`scan_carry` on the Pallas kernels, `per` heads a grid
-    step (:func:`carry_tile`'s)."""
-    return _kernel_carry(per, interpret)(w, u, kd, grown)
+def kernel_delta(q, k, v, g, beta, heads: int, chunk: int, per: int,
+                 l2=None, interpret: bool = False):
+    """:func:`chunked_delta` on the Pallas kernels, `per` heads a grid
+    step (:func:`carry_tile`'s), of operands as the mixer's products
+    and convolutions leave them: q, k, g [B, T, H K], v [B, T, H V],
+    beta [B, T, H] -> (o [B, T, H V], the state after the last token
+    TRANSPOSED, [B, H, V, K] float32). `l2` = (eps, q's scale): q and k
+    are normed over each head inside the kernels (:func:`l2norm`, q
+    then scaled), forward and backward."""
+    return _kernel_delta(heads, chunk, per, l2, interpret)(
+        q, k, v, g.astype(F32), beta.astype(F32))
 
 
 # -- the core ------------------------------------------------------------------
@@ -412,12 +716,17 @@ def chunked_delta(q, k, v, g, beta, chunk: int, per=None):
     activations' type; g [B, T, H, K] float32, <= 0; beta [B, T, H]
     float32 -> (o [B, T, H, V] in v's type, the state after the last
     token [B, H, K, V] float32). T is a multiple of `chunk`. `per`:
-    :func:`carry_tile`'s answer — the carry's form."""
+    :func:`carry_tile`'s answer — None: ``jax.numpy`` around a
+    ``lax.scan``, the kernels' oracle; else :func:`kernel_delta`."""
     b, t, h, width = k.shape
     if t % chunk:
         raise ValueError(f"a sequence of {t} tokens is no whole number of "
                          f"chunks of {chunk}")
     dtype = v.dtype
+    if per is not None:
+        o, last = kernel_delta(*(a.reshape(b, t, -1) for a in (q, k, v, g)),
+                               beta, h, chunk, per)
+        return o.reshape(b, t, h, -1), jnp.swapaxes(last, -1, -2)
 
     def chunks(a):  # [B, T, H, ..] -> [B, H, chunks, C, ..]
         a = jnp.moveaxis(a, 2, 1)
@@ -437,10 +746,7 @@ def chunked_delta(q, k, v, g, beta, chunk: int, per=None):
     w, u = wu[..., :width], wu[..., width:]
     kd = (kf * jnp.exp(total - cum)).astype(dtype)
     grown = jnp.exp(total[..., 0, :])
-    if per is None:
-        vp, entering, last = scan_carry(w, u, kd, grown)
-    else:
-        vp, entering, last = kernel_carry(w, u, kd, grown, per)
+    vp, entering, last = scan_carry(w, u, kd, grown)
     o = jnp.einsum("bhnck,bhnvk->bhncv",
                    (q.astype(F32) * jnp.exp(cum)).astype(dtype), entering,
                    preferred_element_type=F32) \
@@ -481,11 +787,19 @@ def _heads(small, q, k, v, f, gate, beta, head_dim: int, chunk: int,
                    for a, name in ((q, "conv_q"), (k, "conv_k"),
                                    (v, "conv_v")))
     with jax.named_scope("kda_core"):
-        q = (l2norm(split(q), l2_eps) * head_dim ** -0.5).astype(dt_)
-        k = l2norm(split(k), l2_eps).astype(dt_)
         step = jax.nn.softplus(f + small["dt_bias"].astype(F32))
-        g = -jnp.exp(small["A_log"].astype(F32))[:, None] * split(step)
-        o, last = chunked_delta(q, k, split(v), g, beta, chunk, per)
+        rate = -jnp.exp(small["A_log"].astype(F32))
+        if per is None:
+            q = (l2norm(split(q), l2_eps) * head_dim ** -0.5).astype(dt_)
+            k = l2norm(split(k), l2_eps).astype(dt_)
+            o, last = chunked_delta(q, k, split(v), rate[:, None] * split(
+                step), beta, chunk)
+        else:  # the kernels read [B, T, h K] and norm q and k themselves:
+            # a [.., h, K] view of a [.., h K] array is a relayout there
+            o, last = kernel_delta(
+                q, k, v, jnp.repeat(rate, head_dim) * step, beta,
+                rate.shape[0], chunk, per, (l2_eps, head_dim ** -0.5))
+            o, last = split(o), jnp.swapaxes(last, -1, -2)
     with jax.named_scope("kda_gate_norm"):
         o = o.astype(F32)
         o = o * lax.rsqrt((o * o).mean(-1, keepdims=True) + eps) \
@@ -498,8 +812,9 @@ def _heads(small, q, k, v, f, gate, beta, head_dim: int, chunk: int,
 #: holds (decays, their sums and exponentials, the convolutions' and the
 #: norms' inputs: [T, heads, K] each, a dozen of them alive at once in
 #: the backward pass) are what a layer's memory peak is made of: a run
-#: of heads is one recomputed function (``jax.checkpoint``), so that
-#: the backward pass holds ONE run's at a time
+#: of heads is one recomputed function (``jax.checkpoint``, keeping
+#: :data:`KDA_CORE` alone), so that the backward pass holds ONE run's
+#: at a time
 HEADS_A_RUN = 16
 
 
@@ -512,8 +827,10 @@ def mixer(lp, x, *, heads: int, head_dim: int, chunk: int, eps: float,
     ``conv_v`` [H K, taps]; ``w_fa`` [d, R], ``w_fb`` [R, H K],
     ``dt_bias`` [H K], ``A_log`` [H]; ``w_b`` [d, H]; ``o_norm`` {"g":
     [K]}; ``w_ga`` [d, R], ``w_gb`` [R, H K]; ``wo`` [H K, d]. Counted
-    once per traced call: ``kda_carry_kernel_layers`` /
-    ``kda_carry_scan_layers``, the carry's form by :func:`carry_tile`."""
+    once per traced call: ``kda_carry_scan_layers``, or
+    ``kda_carry_kernel_layers`` and ``kda_core_kernel_layers`` — the
+    core's form by :func:`carry_tile` (the kernels hold the carry AND
+    the chunk-local work)."""
     dt_ = x.dtype
     t = x.shape[1]
 
@@ -530,11 +847,13 @@ def mixer(lp, x, *, heads: int, head_dim: int, chunk: int, eps: float,
     run = next(n for n in range(min(HEADS_A_RUN, heads), 0, -1)
                if heads % n == 0)
     per = carry_tile(jax.default_backend(), t, run, head_dim, chunk, dt_)
-    pvar.record("kda_carry_scan_layers" if per is None
-                else "kda_carry_kernel_layers")
-    heads_of = jax.checkpoint(functools.partial(
-        _heads, head_dim=head_dim, chunk=chunk, eps=eps, l2_eps=l2_eps,
-        per=per))
+    for name in (("kda_carry_scan_layers",) if per is None else
+                 ("kda_carry_kernel_layers", "kda_core_kernel_layers")):
+        pvar.record(name)
+    heads_of = jax.checkpoint(
+        functools.partial(_heads, head_dim=head_dim, chunk=chunk, eps=eps,
+                          l2_eps=l2_eps, per=per),
+        policy=jax.checkpoint_policies.save_only_these_names(KDA_CORE))
     ys, lasts = [], []
     for first in range(0, heads, run):
         hs = slice(first, first + run)

@@ -51,7 +51,8 @@ COUNTED = ("moe_bounded_layers", "moe_full_layers",
            "attn_window_layers", "attn_full_layers", "attn_window_tiles",
            "attn_causal_tiles", "attn_dsa_kernel_layers", "ssm_scan_kernel_layers",
            "ssm_scan_product_layers", "kda_carry_kernel_layers",
-           "kda_carry_scan_layers", "attn_gated_layers",
+           "kda_carry_scan_layers", "kda_core_kernel_layers",
+           "attn_gated_layers",
            "remat_kept_applications",
            "remat_whole_applications", "remat_kept_bytes")
 

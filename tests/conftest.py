@@ -50,7 +50,7 @@ def one_chip():
 
 @pytest.fixture
 def kernels_on_cpu(monkeypatch):
-    """ops/kda.py's carry as on a TPU — the rule `carry_tile` answering
+    """ops/kda.py's core as on a TPU — the rule `carry_tile` answering
     with heads a grid step, the Pallas kernels in interpret mode —:
     everything else is the program's own path."""
     import functools
@@ -60,5 +60,5 @@ def kernels_on_cpu(monkeypatch):
     monkeypatch.setattr(kda, "carry_tile",
                         lambda backend, t, heads, *a: next(
                             n for n in (2, 1) if heads % n == 0))
-    monkeypatch.setattr(kda, "kernel_carry", functools.partial(
-        kda.kernel_carry, interpret=True))
+    monkeypatch.setattr(kda, "kernel_delta", functools.partial(
+        kda.kernel_delta, interpret=True))

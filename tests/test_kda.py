@@ -3,10 +3,11 @@ against the recurrence written a second time here, token by token —
 values, the state after the last token and every leaf's gradient, over
 chunks of 16 and of 64, with decays near 0 and near 1 and beta near 2 —,
 the pair sums and the intra-chunk inverse against their definitions,
-the carry's Pallas kernels in interpret mode against their `lax.scan`
-oracle, runs of heads against the whole mixer, the rule that picks the
-carry's form, and the kernels compiled for a described v5e
-(tests/test_solar2.py holds the model around it)."""
+the mixer with its core on the Pallas kernels (interpret mode), runs of
+heads against the whole mixer, the rule that picks the core's form, and
+the kernels compiled for a described v5e (tests/test_kda_kernels.py
+holds the kernels against their oracle, tests/test_solar2.py the model
+around it)."""
 
 import os
 import sys
@@ -120,13 +121,7 @@ REGIMES = {
 }
 
 
-@pytest.mark.parametrize("regime", sorted(REGIMES))
-@pytest.mark.parametrize("chunk", [16, 64])
-def test_the_chunked_mixer_is_the_token_by_token_recurrence(params, chunk,
-                                                            regime):
-    """Values, the state after the last token and the gradient of EVERY
-    leaf of the mixer and of its input, over several chunks (of 16: 8;
-    of 64: 2, four sub-blocks each)."""
+def _mixer_against_tokens(params, chunk, regime):
     lp = dict(_kda_leaves(params))
     x = jax.random.normal(jax.random.key(3), (B, 128, 32))
     how = REGIMES[regime]
@@ -158,6 +153,27 @@ def test_the_chunked_mixer_is_the_token_by_token_recurrence(params, chunk,
         close(g, r, 3e-3 if regime == "fast_decay" else 5e-4, atol=1e-7)
 
 
+@pytest.mark.parametrize("regime", sorted(REGIMES))
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_the_chunked_mixer_is_the_token_by_token_recurrence(params, chunk,
+                                                            regime):
+    """Values, the state after the last token and the gradient of EVERY
+    leaf of the mixer and of its input, over several chunks (of 16: 8;
+    of 64: 2, four sub-blocks each)."""
+    _mixer_against_tokens(params, chunk, regime)
+
+
+@pytest.mark.parametrize("chunk, regime", [(16, "beta_near_2"),
+                                           (64, "as_seeded")])
+def test_the_mixer_on_the_kernels_is_the_recurrence(params, kernels_on_cpu,
+                                                    chunk, regime):
+    """The same with the core on its kernels (interpret mode): q and k
+    reach them as the convolutions left them and are normed inside,
+    forward and backward, and a run of heads keeps the kernel's output
+    and entering states for its own backward pass."""
+    _mixer_against_tokens(params, chunk, regime)
+
+
 def test_runs_of_heads_are_the_whole_mixer(params, monkeypatch):
     """The mixer works `HEADS_A_RUN` heads at a time, one run after the
     other behind an optimization barrier, each recomputed in its own
@@ -181,49 +197,29 @@ def test_runs_of_heads_are_the_whole_mixer(params, monkeypatch):
         close(a, b, 1e-5, atol=1e-8)
 
 
-def _core_operands(seed, b, t, h, width, dtype=jnp.float32):
+#: the core's operands pushed to their ends: log-decays of ~ -1e-4 and
+#: of ~ -6 a token (a chunk's cumulative sum reaches hundreds), beta
+#: near 2 (the factor I - beta k k^T turns k's direction over)
+CORE_REGIMES = {
+    "as_drawn": dict(low=-7.0, high=1.0),
+    "slow_decay": dict(low=-9.5, high=-9.0),
+    "fast_decay": dict(low=1.7, high=1.8),
+    "beta_near_2": dict(low=-7.0, high=1.0, beta_shift=4.0),
+}
+
+
+def _core_operands(seed, b, t, h, width, dtype=jnp.float32,
+                   low=-7.0, high=1.0, beta_shift=0.0):
     ks = jax.random.split(jax.random.key(seed), 5)
     q = kda.l2norm(jax.random.normal(ks[0], (b, t, h, width)), 1e-6) \
         * width ** -0.5
     k = kda.l2norm(jax.random.normal(ks[1], (b, t, h, width)), 1e-6)
     v = jax.random.normal(ks[2], (b, t, h, width))
-    g = -jnp.exp(jax.random.uniform(ks[3], (b, t, h, width), minval=-7.0,
-                                    maxval=1.0))
-    beta = 2 * jax.nn.sigmoid(3 * jax.random.normal(ks[4], (b, t, h)))
+    g = -jnp.exp(jax.random.uniform(ks[3], (b, t, h, width), minval=low,
+                                    maxval=high))
+    beta = 2 * jax.nn.sigmoid(3 * jax.random.normal(ks[4], (b, t, h))
+                              + beta_shift)
     return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta)
-
-
-def _weighed(fn):
-    def loss(*args):
-        o, last = fn(*args)
-        return (o.astype(jnp.float32) * jnp.cos(jnp.arange(o.size).reshape(
-            o.shape))).sum() + (last * last).sum()
-    return loss
-
-
-@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5),
-                                        (jnp.bfloat16, 2e-2)],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("per", [1, 2])
-def test_the_carrys_kernels_are_the_scan(kernels_on_cpu, per, dtype, tol):
-    """The kernel form (interpret mode) against its `lax.scan` oracle:
-    the outputs, the last state and the gradient of all five operands,
-    one head a grid step and two; both forms round the same operands of
-    the same products."""
-    args = _core_operands(1, 2, 64, 4, 8, dtype)
-
-    def form(per):
-        return lambda *a: kda.chunked_delta(*a, 16, per)
-
-    o, last = highest(form(per), *args)
-    r_o, r_last = highest(form(None), *args)
-    close(o, r_o, tol)
-    close(last, r_last, tol)
-    grads = highest(jax.grad(_weighed(form(per)), (0, 1, 2, 3, 4)), *args)
-    r_grads = highest(jax.grad(_weighed(form(None)), (0, 1, 2, 3, 4)), *args)
-    for g, r in zip(grads, r_grads):
-        assert float(jnp.abs(r.astype(jnp.float32)).max()) > 0
-        close(g, r, tol)
 
 
 def test_a_carry_dropped_shows(monkeypatch):
@@ -266,35 +262,46 @@ def test_a_sequence_no_chunk_divides_raises():
         kda.chunked_delta(*_core_operands(0, 1, 24, 2, 8), 16)
 
 
-def test_the_rule_sends_the_cells_carry_to_the_kernels():
-    assert kda.carry_tile("tpu", 8192, 64, 128, 64, jnp.bfloat16) == 8
+def test_the_rule_sends_the_cells_core_to_the_kernels():
+    assert kda.carry_tile("tpu", 8192, 16, 128, 64, jnp.bfloat16) == 4
     assert kda.carry_tile("tpu", 8192, 6, 128, 64, jnp.bfloat16) == 2
+    assert kda.carry_tile("tpu", 64, 1, 128, 16, jnp.bfloat16) == 1
+    # off the TPU; a sequence the chunk does not divide; a head the
+    # lanes do not divide; a chunk under the type's sublane tile; three
+    # sub-blocks, which do not pair up
     for backend, t, head_dim, chunk in (
             ("cpu", 8192, 128, 64), ("tpu", 8200, 128, 64),
-            ("tpu", 8192, 96, 64), ("tpu", 8192, 128, 8)):
+            ("tpu", 8192, 96, 64), ("tpu", 8192, 128, 8),
+            ("tpu", 8160, 128, 48)):
         assert kda.carry_tile(backend, t, 64, head_dim, chunk,
                               jnp.bfloat16) is None
 
 
-def test_the_kernels_compile_for_the_chip(one_chip):
-    """What interpret mode cannot show: the chip's compiler takes both
-    kernels at the cell's widths (64 heads of 128, chunks of 64), eight
-    heads a grid step."""
-    b, h, nc, c, width = 1, 64, 8, 64, 128
-    per = kda.carry_tile("tpu", nc * c, h, width, c, jnp.bfloat16)
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_the_kernels_compile_for_the_chip(one_chip, dtype):
+    """What interpret mode cannot show: the chip's compiler takes the
+    forward kernel (with and without the entering states) and the
+    backward kernel at the cell's block shape — a run of 16 heads of
+    128, chunks of 64, the rule's heads a grid step — and nothing of
+    the core is left for a loop."""
+    b, t, h, c, width = 1, 512, 16, 64, 128
+    per = kda.carry_tile("tpu", t, h, width, c, dtype)
 
-    def loss(w, u, kd, grown, a, e, f):
-        vp, entering, last = kda.kernel_carry(w, u, kd, grown, per)
-        return (vp.astype(jnp.float32) * a).sum() + (
-            entering.astype(jnp.float32) * e).sum() + (last * f).sum()
+    def loss(q, k, v, g, beta, a, f):
+        o, last = kda.kernel_delta(q, k, v, g, beta, h, c, per)
+        return (o.astype(jnp.float32) * a).sum() + (last * f).sum()
 
-    def arg(shape, dtype=jnp.bfloat16):
+    def arg(shape, dtype=dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    wide = (b, h, nc, c, width)
-    text = jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3))).lower(
-        arg(wide), arg(wide), arg(wide), arg((b, h, nc, width), jnp.float32),
-        arg(wide, jnp.float32), arg((b, h, nc, width, width), jnp.float32),
-        arg((b, h, width, width), jnp.float32)).compile().as_text()
-    assert "kda_carry_fwd" in text and "kda_carry_bwd" in text
+    wide = (b, t, h * width)
+    shapes = (arg(wide), arg(wide), arg(wide), arg(wide, jnp.float32),
+              arg((b, t, h), jnp.float32), arg(wide, jnp.float32),
+              arg((b, h, width, width), jnp.float32))
+    forward = jax.jit(loss).lower(*shapes).compile().as_text()
+    assert "kda_delta_fwd" in forward and "kda_delta_bwd" not in forward
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3, 4))).lower(
+        *shapes).compile().as_text()
+    assert "kda_delta_fwd" in text and "kda_delta_bwd" in text
     assert "while" not in text

@@ -1,16 +1,17 @@
 """The eighth published model of models/transformer.py at toy widths on
 the CPU: blocks whose first sub-layer is gated grouped-query attention
 without positions or Kimi Delta Attention (ops/kda.py: a per-channel
-gated delta rule in chunks, its chunk-to-chunk carry a `lax.scan` here
-and a Pallas kernel on the TPU), every layer with sigmoid-routed
+gated delta rule in chunks, `jax.numpy` around a `lax.scan` here and
+two Pallas kernels on the TPU), every layer with sigmoid-routed
 experts of which a share is held and a shared expert — the program
 against the recurrence written a second time here, token by token,
 against hand-written cases and against the float32 reference
 (benchmark/reference/solar2_decoder.py); tests/test_kda.py holds the
-mixer, its chunked core and the carry's kernels on their own."""
+mixer, its chunked core and the core's kernels on their own."""
 
 import json
 import os
+import re
 import sys
 
 import jax
@@ -316,7 +317,8 @@ def test_the_layers_kinds_and_layouts():
 # -- the step ------------------------------------------------------------------
 
 COUNTED = ("kda_layers", "kda_chunks", "kda_carry_kernel_layers",
-           "kda_carry_scan_layers", "attn_gated_layers", "attn_gqa_layers",
+           "kda_carry_scan_layers", "kda_core_kernel_layers",
+           "attn_gated_layers", "attn_gqa_layers",
            "attn_full_layers", "remat_whole_applications")
 
 
@@ -342,7 +344,7 @@ def test_the_steps_loops_are_the_carrys_scans(params, batch):
     assert counted == {
         "kda_layers": 3, "kda_chunks": 3 * (T // 16),
         "kda_carry_kernel_layers": 0, "kda_carry_scan_layers": 3,
-        "attn_gated_layers": 1, "attn_gqa_layers": 1, "attn_full_layers": 1,
+        "kda_core_kernel_layers": 0, "attn_gated_layers": 1, "attn_gqa_layers": 1, "attn_full_layers": 1,
         "remat_whole_applications": 4}
     assert 0 < _loops(text) <= 3 * 4
     for scope in ("kda_proj", "kda_conv", "kda_core", "kda_gate_norm",
@@ -355,21 +357,29 @@ def test_the_steps_loops_are_the_carrys_scans(params, batch):
 
 
 def test_no_loop_in_the_step_lowered_for_the_tpu(params, batch, monkeypatch):
-    """With the rule answering for the TPU the carry is the two kernels
-    and the step has no loop (interpret mode emulates a kernel's grid
-    with a loop, so this is read from the text lowered for the TPU)."""
+    """With the rule answering for the TPU the core — chunk-local work,
+    carry and read-out — is the two kernels and the step has no loop
+    (interpret mode emulates a kernel's grid with a loop, so this is
+    read from the text lowered for the TPU; the toy's heads are 8 wide,
+    so a grid step takes all four: a block as wide as its array)."""
     toks, labs = batch
     monkeypatch.setattr(kda, "carry_tile",
-                        lambda backend, *a: kda._HEADS_A_STEP[-1])
+                        lambda backend, t, heads, *a: heads)
     cfg = config(remat=True, dtype=jnp.bfloat16)
     step = jax.jit(tfm.make_train_step(cfg, AX, tfm.param_specs(cfg, AX)))
     s = pvar.session()
     text = step.trace(params, toks[0], labs[0]).lower(
         lowering_platforms=("tpu",)).as_text()
     assert (s.read("kda_carry_kernel_layers"),
-            s.read("kda_carry_scan_layers")) == (3, 0)
+            s.read("kda_core_kernel_layers"),
+            s.read("kda_carry_scan_layers")) == (3, 3, 0)
     assert "stablehlo.while" not in text
-    assert "kda_carry_fwd" in text and "kda_carry_bwd" in text
+    # a delta-rule layer is one function of the module: the core runs
+    # forward in the step and in the layer's recomputation (which keeps
+    # the entering states), NOT a third time in the run of heads' own,
+    # and backward once
+    assert [len(re.findall(f'kernel_name = "{name}"', text))
+            for name in ("kda_delta_fwd", "kda_delta_bwd")] == [2, 1]
 
 
 def test_the_step_on_the_kernels_is_the_step_on_the_scan(params, batch,
@@ -384,7 +394,15 @@ def test_the_step_on_the_kernels_is_the_step_on_the_scan(params, batch,
         close(g, r, 2e-4, atol=1e-9)
 
 
-def test_the_rule_prices_the_mixers_names():
+@pytest.mark.parametrize("core", ["numpy", "kernels"])
+def test_the_rule_prices_the_mixers_names(core, monkeypatch):
+    """What the gated output spares follows the core's form: on the
+    kernels a run of heads keeps the core's results from the layer's
+    recomputation, which runs the core whatever is kept."""
+    if core == "kernels":
+        rule = kda.carry_tile
+        monkeypatch.setattr(kda, "carry_tile",
+                            lambda backend, *a: rule("tpu", *a))
     sizes = solar2_train.model_sizes(_published())
     cfg = solar2_train.program_config(sizes)
     apps, fixed = tfm.step_costs(cfg, 1, 8192, 6_616_706_688)
@@ -393,8 +411,9 @@ def test_the_rule_prices_the_mixers_names():
     assert apps[1].sizes[kda.KDA_PROJ] == 3 * n * wide * 2
     assert apps[1].sizes[kda.KDA_OUT] == n * wide * 2
     assert apps[1].spared[kda.KDA_PROJ] == 2 * n * 4096 * 3 * wide
-    assert apps[1].spared[kda.KDA_OUT] == n * 64 * kda.core_flops_per_token(
-        128, 64)
+    assert apps[1].spared[kda.KDA_OUT] == (
+        0 if core == "kernels" else n * 64 * kda.core_flops_per_token(
+            128, 64))
     assert att.QKV in apps[0].sizes and kda.KDA_PROJ not in apps[0].sizes
 
 
