@@ -161,8 +161,42 @@ _run = functools.partial(_dispatch.run, "xla")
 
 #: the name ``_Ctx.compiled`` chose for the program being built, for
 #: ``_Ctx.smap`` to give to the jitted body (per thread: builds nest
-#: under whichever thread misses the cache)
+#: under whichever thread misses the cache); and, while a sink is up,
+#: ``launched``: the arguments of this thread's last ``launch`` span,
+#: for the request that is made of that launch (``_launched``)
 _naming = threading.local()
+
+
+def _launched(launch, *args, **kwargs):
+    """Run ``launch(*args, **kwargs)`` for a request. Returns (its
+    result, what the request remembers for its ``wait`` span): the
+    arguments of the LAST ``launch`` span under it — ``program``, and
+    the ``call`` of the API call it was made in, if it was made in
+    one — or None with no sink up or no device program launched (a
+    staged fallback)."""
+    if not _trace.active():
+        return launch(*args, **kwargs), None
+    _naming.launched = None
+    return launch(*args, **kwargs), _naming.launched
+
+
+def _wait(arrays, launched) -> None:
+    """Block until ``arrays`` (a pytree) are ready: every wait of the
+    device path. With no sink up it costs the ``active()`` guard;
+    with one it is span ``wait`` — the caller's share of a
+    nonblocking, persistent or partitioned collective, against the
+    ``launch`` it names by ``program`` and ``launch_call``
+    (``launched``: what ``_launched`` gave when it was launched)."""
+    import jax
+
+    if not _trace.active():
+        jax.block_until_ready(arrays)
+        return
+    args = {"program": launched["program"] if launched else "?"}
+    if launched and "call" in launched:
+        args["launch_call"] = launched["call"]
+    with _trace.span("wait", "coll_xla", **args):
+        jax.block_until_ready(arrays)
 
 
 def program_name(key) -> str:
@@ -406,7 +440,8 @@ class _Ctx:
         the fusion regression tests assert on it. With no sink up a
         warm launch costs the cold-set lookup and the ``active()``
         guard; with one, span ``launch`` covers DISPATCH time only —
-        PJRT execution is asynchronous.
+        PJRT execution is asynchronous — and leaves its arguments for
+        the request that is made of it (``_launched``).
 
         A program's FIRST launch is where jax compiles it or loads it
         from the persistent cache: it is always timed (two clock reads
@@ -423,7 +458,8 @@ class _Ctx:
             return fn(*args)
         with _trace.span("launch", "coll_xla",
                          program=self.programs.get(fn, "?"),
-                         nbytes=self._nbytes(args), cold=0):
+                         nbytes=self._nbytes(args), cold=0) as sp:
+            _naming.launched = sp.args
             return fn(*args)
 
     def _launch_cold(self, fn, args):
@@ -432,7 +468,8 @@ class _Ctx:
         t0 = _trace.now()
         with _trace.span("compile", "coll_xla", program=name), \
                 _trace.span("launch", "coll_xla", program=name,
-                            nbytes=self._nbytes(args), cold=1):
+                            nbytes=self._nbytes(args), cold=1) as sp:
+            _naming.launched = sp.args  # None with no sink up
             out = fn(*args)
         dt = _trace.now() - t0
         pvar.record("coll_xla_cold_launches")
@@ -1440,13 +1477,14 @@ class DeviceRequest:
     ``completed`` flag + non-blocking ``test()``).
     """
 
-    def __init__(self, array) -> None:
+    def __init__(self, array, launched=None) -> None:
         from ompi_tpu.pml import request as rq
 
         self.id = next(rq._req_ids)
         self.status = rq.Status()
         self.persistent = False
         self.array = array
+        self._launched = launched  # of its launch, for span `wait`
         self._done = array is None
 
     @property
@@ -1466,7 +1504,7 @@ class DeviceRequest:
                 # readiness polling degrades to blocking (the same
                 # guarantee the pre-property test() gave) — never
                 # report completion that has not happened
-                jax.block_until_ready(self.array)
+                _wait(self.array, self._launched)
                 self._done = True
         return self._done
 
@@ -1475,9 +1513,7 @@ class DeviceRequest:
 
     def wait(self, timeout=None):
         if not self._done:
-            import jax
-
-            jax.block_until_ready(self.array)
+            _wait(self.array, self._launched)
             self._done = True
         return self.status
 
@@ -1512,8 +1548,8 @@ def ibarrier_dev(comm):
     the request completes when every plane member has entered."""
     if comm.size == 1:
         return DeviceRequest(None)
-    return DeviceRequest(_run("barrier", comm, None,
-                              _barrier_prep(comm)))
+    return DeviceRequest(*_launched(_run, "barrier", comm, None,
+                                    _barrier_prep(comm)))
 
 
 class PersistentDeviceRequest:
@@ -1539,7 +1575,7 @@ class PersistentDeviceRequest:
                 errors.ERR_REQUEST,
                 "start: persistent request already freed (MPI calls "
                 "starting a freed request erroneous)")
-        self._inner = DeviceRequest(self._launch())
+        self._inner = DeviceRequest(*_launched(self._launch))
 
     def rebind(self, *args, **kwargs) -> None:
         """Rebind the request's operands to fresh values of the SAME
@@ -2024,17 +2060,16 @@ def _flush_bucket(req, b: int, trigger: Optional[int], op: str,
         flush = functools.partial(_run, op, req._comm, None, flush,
                                   nbytes=nb, ctx="part",
                                   dtype=req._metas[idxs[0]][1])
-    rec = _trace.RECORDER
-    if rec is None:
+    if not _trace.active():
         req._results[b] = flush()
-    else:
-        t0 = _trace.now()
-        req._results[b] = flush()
-        t1 = _trace.now()
-        rec.record(span, subsys, t0, t1,
-                   {"bucket": b, "trigger_partition": trigger,
-                    "overlap": overlap, "nbytes": nb})
-        _trace.hist(span, nb, t1 - t0)
+        return overlap
+    t0 = _trace.now()
+    with _trace.span(span, subsys, bucket=b, trigger_partition=trigger,
+                     overlap=overlap, nbytes=nb):
+        # the cycle's wait names the launch of its last flush
+        req._results[b], req._launched = _launched(flush)
+    if _trace.RECORDER is not None:
+        _trace.hist(span, nb, _trace.now() - t0)
     return overlap
 
 
@@ -2109,6 +2144,7 @@ class PartitionedAllreduceRequest:
         self._n_ready = 0
         self._pending = [len(idxs) for _fn, idxs in self._buckets]
         self._results = [None] * len(self._buckets)
+        self._launched = None
         self._fl_tok = _dispatch.cycle_enter(
             "pallreduce_cycle", self._comm, self.nbytes)
 
@@ -2136,9 +2172,8 @@ class PartitionedAllreduceRequest:
         self._ready[idx] = True
         self._n_ready += 1
         pvar.record("part_pready")
-        rec = _trace.RECORDER
-        if rec is not None:
-            rec.instant("pready", "part", {"partition": idx})
+        if _trace.active():
+            _trace.instant("pready", "part", partition=idx)
         b = self._leaf_bucket[idx]
         self._pending[b] -= 1
         if self._pending[b] == 0:
@@ -2177,7 +2212,7 @@ class PartitionedAllreduceRequest:
                        for r in self._results
                        for a in jax.tree.leaves(r))
         except AttributeError:  # backend without is_ready
-            jax.block_until_ready(self._results)
+            _wait(self._results, self._launched)
             return True
 
     def test(self) -> bool:
@@ -2193,7 +2228,7 @@ class PartitionedAllreduceRequest:
             res = self._results[b]
             for j, i in enumerate(idxs):
                 outs[i] = self._ctx.my_shard(res[j])
-        jax.block_until_ready(outs)
+        _wait(outs, self._launched)
         pvar.record("coll_xla_fused_bytes", self.nbytes)
         self._arr = jax.tree.unflatten(self._treedef, outs)
         self._ready = None  # cycle closed: back to inactive
@@ -2418,6 +2453,7 @@ class PartitionedReduceScatterRequest:
         self._n_ready = 0
         self._pending = [len(idxs) for _fn, idxs in self._buckets]
         self._results = [None] * len(self._buckets)
+        self._launched = None
         self._fl_tok = _dispatch.cycle_enter(
             "preduce_scatter_cycle", self._comm, self.nbytes)
 
@@ -2445,9 +2481,8 @@ class PartitionedReduceScatterRequest:
         self._ready[idx] = True
         self._n_ready += 1
         pvar.record("part_pready")
-        rec = _trace.RECORDER
-        if rec is not None:
-            rec.instant("pready", "zero", {"partition": idx})
+        if _trace.active():
+            _trace.instant("pready", "zero", partition=idx)
         b = self._leaf_bucket[idx]
         self._pending[b] -= 1
         if self._pending[b] == 0:
@@ -2480,7 +2515,7 @@ class PartitionedReduceScatterRequest:
                        for r in self._results
                        for a in jax.tree.leaves(r))
         except AttributeError:  # backend without is_ready
-            jax.block_until_ready(self._results)
+            _wait(self._results, self._launched)
             return True
 
     def test(self) -> bool:
@@ -2495,7 +2530,7 @@ class PartitionedReduceScatterRequest:
 
         shards = [self._ctx.my_shard(self._results[b])
                   for b in range(len(self._buckets))]
-        jax.block_until_ready(shards)
+        _wait(shards, self._launched)
         pvar.record("zero_fused_bytes", self.nbytes)
         pvar.record("zero_pad_bytes", self._plan.pad_bytes)
         self._arr = _zl.ShardedState(
@@ -2659,7 +2694,7 @@ def _irequest(fn):
     blocking slots already return un-awaited futures, so the i-form
     simply wraps them in a readiness-backed request."""
     def islot(*args, **kwargs):
-        return DeviceRequest(fn(*args, **kwargs))
+        return DeviceRequest(*_launched(fn, *args, **kwargs))
     islot.__name__ = "i" + fn.__name__
     islot.__doc__ = (f"Nonblocking {fn.__name__}: PJRT-async dispatch "
                      "wrapped in a DeviceRequest.")

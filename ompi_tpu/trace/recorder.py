@@ -4,10 +4,8 @@ Reference tradition: Score-P/OTF2 region records and the Chrome
 trace-event recorder — bounded memory, drop accounting, monotonic
 timestamps. Here the recorder is layered on the repo's existing MPI_T
 planes instead of a sidecar: drops surface as the ``trace_dropped``
-pvar, span completion optionally raises a ``trace_span`` MPI-4 event
-(guarded by ``events.active`` like every other emitter), and the
-log2 latency histogram (:func:`hist`) is plain pvar counters readable
-through ``pvar.snapshot()`` / ``mpit``.
+pvar, and the log2 latency histogram (:func:`hist`) is plain pvar
+counters readable through ``pvar.snapshot()`` / ``mpit``.
 
 ONE span source, two sinks. :func:`span` is the call every
 instrumented site on the device path and in ``mpi.Init()`` makes:
@@ -22,6 +20,7 @@ instrumented site on the device path and in ``mpi.Init()`` makes:
   operator's path to a Perfetto JSON) it records into the ring;
 - otherwise it hands back one shared no-op and constructs nothing.
 
+:func:`instant` is the same call for a marker with no duration.
 Spans of one API call carry the same ``call`` sequence number
 (:func:`api_span` opens the call; every span inside it inherits it).
 :func:`closed` feeds both sinks a span that someone else timed and
@@ -50,7 +49,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-from ompi_tpu.core import cvar, events, pvar
+from ompi_tpu.core import cvar, pvar
 from ompi_tpu.telemetry import clock as _clock
 
 _enable_var = cvar.register(
@@ -61,13 +60,6 @@ _cap_var = cvar.register(
     "trace_buffer_spans", 65536, int,
     help="Span ring-buffer capacity; overflow overwrites the oldest "
          "span and counts in the trace_dropped pvar.", level=5)
-
-#: span completion as an MPI-4 event (emitted only while a tool
-#: listens — the standard events.active guard)
-TRACE_SPAN = events.register_type(
-    "trace_span",
-    "a trace span closed (recorder plane)",
-    ("name", "subsys", "t0_ns", "dur_ns"))
 
 #: THE disabled guard. Instrumented sites do
 #: ``if recorder.RECORDER is not None: ...`` — module attribute load
@@ -126,9 +118,6 @@ class Recorder:
                 self._n += 1
             self._buf[self._head] = sp
             self._head = (self._head + 1) % self.capacity
-        if events.active("trace_span"):
-            events.emit("trace_span", name=name, subsys=subsys,
-                        t0_ns=t0, dur_ns=t1 - t0)
         return sp
 
     def instant(self, name: str, subsys: str,
@@ -189,6 +178,7 @@ class _Off:
     """What :func:`span` hands back while no sink is up."""
 
     __slots__ = ()
+    args = None  # where an open span has its arguments
 
     def __enter__(self):
         return self
@@ -291,6 +281,26 @@ def timed(name: str, subsys: str, counter: str):
             yield
     finally:
         pvar.record(counter, now() - t0)
+
+
+def instant(name: str, subsys: str, **args) -> None:
+    """A marker with no duration, on both sinks: the ring gets a span
+    whose start is its end, a live profiler session
+    ``ompi:<subsys>.<name>`` opened and closed at once. Like every
+    span it carries the ``call`` of the API call it happened in, if
+    any. Sites branch on :func:`active` first."""
+    rec = RECORDER
+    live = _profiler_live()
+    if rec is None and not live:
+        return
+    call = _call_of.get(_thread_id())
+    if call is not None:
+        args["call"] = call
+    if live:
+        with _annotation(PREFIX + subsys + "." + name, **args):
+            pass
+    if rec is not None:
+        rec.instant(name, subsys, args or None)
 
 
 def api_span(name: str):
