@@ -123,6 +123,11 @@ WELL_KNOWN = (
     # kernels of ops/ssm_scan.py, or as jax.numpy's batched products
     # (the rule ops/ssm.scan_tile)
     "ssm_scan_kernel_layers", "ssm_scan_product_layers",
+    # ops/ssm.causal_conv, once per TRACED call (a Mamba-2 mixer's one,
+    # a delta-rule run of heads' three): the convolution runs on the
+    # Pallas kernels of ops/causal_conv.py, or as jax.numpy's K shifted
+    # sums (the rule ops/ssm.conv_tile)
+    "conv_kernel_layers", "conv_shifted_layers",
     # models/transformer.py, once per TRACED delta-rule layer
     # (Config.attn_layers 'd') and the chunks its recurrence works a
     # sequence in; ops/kda.mixer, once per TRACED call: the

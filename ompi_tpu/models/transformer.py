@@ -102,8 +102,10 @@ under ``mlp``, its other two mixers' at ``layer_<i>``'s own level).
 Counted once per traced layer: ``ssm_layers``,
 ``ssm_chunks`` (chunks a layer), ``attn_gqa_layers`` and, by
 ``ops/ssm.mixer`` for the form its scan took, ``ssm_scan_kernel_layers``
-/ ``ssm_scan_product_layers``; the probe :func:`ssm_probe` counts
-``ssm_state_norm_micro``. Where a config mixes kinds of attention
+/ ``ssm_scan_product_layers`` (and by ``ops/ssm.causal_conv``, for this
+mixer's convolution and a delta-rule layer's three, once per traced
+call: ``conv_kernel_layers`` / ``conv_shifted_layers``); the probe
+:func:`ssm_probe` counts ``ssm_state_norm_micro``. Where a config mixes kinds of attention
 (``attn_layers``) — and nowhere else, so every other op path stands —
 the scores, softmax and values lie one scope further in:
 ``attn_core/attn_window`` on a layer under the sliding window,

@@ -16,7 +16,11 @@ along the sequence, from zero::
 whose eigenvalue along k is negative where beta > 1). Around it
 (:func:`mixer`): q, k, v each a product of the normed input, a causal
 depthwise convolution over time and a SiLU (``ops/ssm.causal_conv``
-without a bias), q and k then divided by their norm over a head; the
+without a bias: on the TPU the kernels of ``ops/causal_conv.py``, one
+pass over a run of heads' ``[B, T, h K]`` forward and one backward,
+time in the sublanes as the products wrote it and the core's kernels
+read it; off it K shifted float32 sums, by the rule
+``ssm.conv_tile``), q and k then divided by their norm over a head; the
 log-decay ``g = -exp(A_log) softplus((x W_fa) W_fb + dt_bias)`` and
 ``beta = 2 sigmoid(x W_b)``; after it an RMSNorm over each head with
 one gain for all heads, the gate ``sigmoid((x W_ga) W_gb)`` and the

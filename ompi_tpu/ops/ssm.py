@@ -17,6 +17,20 @@ the gate ``y * silu(z)``, an RMSNorm over each group's channels on its
 own (:func:`gated_group_norm`) and the product back to the model's
 width.
 
+:func:`causal_conv` — this mixer's and ``ops/kda.py``'s — runs in one
+of two forms chosen by the static rule :func:`conv_tile`: **on the
+TPU** two Pallas kernels behind one ``custom_vjp``
+(``ops/causal_conv.py``: ``causal_conv_fwd`` reads a block and the few
+entries in front of it and writes SiLU of the K-tap sum, one pass over
+the bytes; ``causal_conv_bwd`` makes the pre-activation again from the
+operand, which is all it keeps, and writes ``dx`` and the float32 sums
+``dw``, ``db``), each caller's layout read and written as it lies —
+here ``[B, C, T]``, the sequence in the lanes, what the scan's kernels
+read; **everywhere else**, and as the kernels' oracle,
+:func:`shifted_conv`: K shifted slices of a zero-padded float32 copy,
+multiplied and summed, the backward pass autodiff's. Both compute in
+float32 and round once.
+
 :func:`chunked_scan` computes the recurrence in chunks of L tokens
 (the "state-space duality" form of the Mamba-2 paper, arXiv:2405.21060
 section 6) and no ``while``, in one of two forms chosen by the static
@@ -90,11 +104,72 @@ SSM_Y = "ssm_y"
 F32 = jnp.float32
 
 
-def causal_conv(xbc, w, b=None):
+#: the lanes of a VMEM tile
+LANES = 128
+#: a grid step's block of the convolution's kernels: the largest of
+#: these entries along time, of these channels, that divide the
+#: operand's (a block of 2048 x 512 bfloat16 is 2 MiB; with time in
+#: the lanes the tile in front of a block is 128 entries of each of its
+#: channels, a DMA of short rows: the longer block pays it less often)
+_CONV_TIME = (2048, 1024, 512, 256, LANES)
+_CONV_CHANNELS = (512, 256, LANES)
+#: the entries in front of a block that a step of those kernels reads
+#: beside it (ops/causal_conv.HALO): K - 1 may not pass them
+_CONV_HALO = 8
+
+
+def conv_tile(backend: str, t: int, channels: int, taps: int, dtype):
+    """The rule that sends the convolution to the repo's own kernels
+    (ops/causal_conv.py), made of what the call can observe: a grid
+    step's block (entries along time, channels), or None — off the
+    TPU, a sequence or a channel count the lanes do not divide (a
+    block lies with time in the sublanes or in the lanes, as its
+    caller's arrays do: either extent has to fill the lanes), K - 1
+    over the entries a step reads in front of its block, a type that is
+    neither 2 nor 4 bytes wide."""
+    if (backend != "tpu" or t % LANES or channels % LANES
+            or not 1 <= taps <= _CONV_HALO + 1
+            or jnp.dtype(dtype).itemsize not in (2, 4)):
+        return None
+    return (next(n for n in _CONV_TIME if t % n == 0),
+            next(n for n in _CONV_CHANNELS if channels % n == 0))
+
+
+def causal_conv(xbc, w, b=None, *, time_last: bool = False, within=None):
     """``silu(b + sum_j w[:, j] * xbc[t - (K - 1) + j])``: a depthwise
     convolution over time with zeros before the sequence. xbc [B, T,
-    C], w [C, K], b [C] (None: no bias) -> [B, T, C] in xbc's type,
-    computed in float32 as K shifted sums."""
+    C], w [C, K], b [C] (None: no bias) -> [B, T, C] in xbc's type —
+    under `time_last` [B, C, T], the same values transposed, for a
+    reader that wants the sequence in the lanes. Computed in float32
+    and rounded once, in one of two forms chosen by :func:`conv_tile`:
+    on the TPU the kernels of ``ops/causal_conv.py`` (one pass over the
+    bytes forward, one backward, each caller's layout read and written
+    as it lies); everywhere else, and as the kernels' ORACLE, the K
+    shifted sums below with autodiff's backward pass. `within` = (a
+    wider array [B, T, wide], the column of it that xbc starts at),
+    where xbc is a slice: the kernels then read the columns where they
+    lie (a block's index takes the offset; a slice in front of a kernel
+    is a copy of its own) if their block divides the offset. Counted
+    once per traced call: ``conv_kernel_layers`` /
+    ``conv_shifted_layers``."""
+    t, k = xbc.shape[1], w.shape[1]
+    tile = conv_tile(jax.default_backend(), t, xbc.shape[2], k, xbc.dtype)
+    pvar.record("conv_shifted_layers" if tile is None
+                else "conv_kernel_layers")
+    if tile is not None:
+        from ompi_tpu.ops import causal_conv as kernels  # Pallas: as below
+
+        if within is not None and within[1] % tile[1] == 0:
+            return kernels.conv(within[0], w, b, tile, time_last,
+                                first=within[1])
+        return kernels.conv(xbc, w, b, tile, time_last)
+    out = shifted_conv(xbc, w, b)
+    return jnp.swapaxes(out, 1, 2) if time_last else out
+
+
+def shifted_conv(xbc, w, b=None):
+    """:func:`causal_conv` [B, T, C] -> [B, T, C] as K shifted sums of
+    a zero-padded float32 copy: the ``jax.numpy`` form."""
     t, k = xbc.shape[1], w.shape[1]
     padded = jnp.pad(xbc.astype(F32), ((0, 0), (k - 1, 0), (0, 0)))
     w = w.astype(F32)
@@ -171,11 +246,9 @@ def chunked_scan(x, dt, a, bm, cm, chunk: int):
     return y.astype(dtype), entering[:, -1].reshape(b, h, p, n)
 
 
-#: the lanes of a VMEM tile, and what a grid step's working set may
-#: take of VMEM (of the kernels' limit of 96 MiB: the blocks twice, the
-#: carried states and a head's [L, L] float32 temporaries; the cell's
-#: shapes take 4 MiB)
-LANES = 128
+#: what a grid step's working set may take of VMEM (of the kernels'
+#: limit of 96 MiB: the blocks twice, the carried states and a head's
+#: [L, L] float32 temporaries; the cell's shapes take 4 MiB)
 _SCAN_VMEM_BYTES = 48 * 1024 * 1024
 
 
@@ -209,7 +282,8 @@ def _scan_kernels(dims, interpret: bool):
     """The scan kernels for one set of sizes as a function (xbc [B, H P
     + 2 G N, T], dt, cum [B, T, H] float32, d [H] float32) -> (y [B, H
     P, T], last [B, H, P, N] float32): `ssm_scan.forward`, and behind a
-    ``custom_vjp`` that keeps the four operands `ssm_scan.states` and
+    ``custom_vjp`` that keeps the four operands (the convolved ``xbc``
+    under its name, :data:`SSM_CONV`) `ssm_scan.states` and
     `ssm_scan.backward`. The per-head vectors' two layouts are made and
     added up here."""
     from ompi_tpu.ops import ssm_scan as sk
@@ -249,7 +323,15 @@ def _scan_kernels(dims, interpret: bool):
                 back(dcum_r, dcum_c),
                 dd.reshape(b, dims.heads, -1).sum((0, 2)))
 
-    scan.defvjp(lambda *a: (run(*a), a), bwd)
+    def fwd(xbc, *small):
+        # The convolved xBC takes its name HERE, on the residual alone.
+        # Named in front of the kernel it would be a kept value that the
+        # forward pass reads too, and jax's remat rounds such a value
+        # behind its producer (``reduce_precision``): behind a kernel
+        # that is a pass of its own over the array (0.2 ms a layer).
+        return run(xbc, *small), (checkpoint_name(xbc, SSM_CONV), *small)
+
+    scan.defvjp(fwd, bwd)
     return scan
 
 
@@ -314,17 +396,18 @@ def mixer(lp, x, *, heads: int, head_dim: int, groups: int, state: int,
     pvar.record("ssm_scan_product_layers" if dims is None
                 else "ssm_scan_kernel_layers")
     # The kernels take the sequence LAST, as XLA lays these arrays out by
-    # itself: the two ``swapaxes`` are bitcasts. Each stands in the scope
-    # of the operation it is fused with (XLA names a fusion for its last
-    # operation: in the scan's scope they would book the convolution and
-    # the gate's backward pass to the scan), and the convolution's in
-    # front of its name: XLA makes a value that is kept under one shape
-    # and read under another TWICE (1.24 ms a layer on the chip).
+    # itself: the ``swapaxes`` below and the convolution's own are
+    # bitcasts. Each stands in the scope of the operation it is fused
+    # with (XLA names a fusion for its last operation: in the scan's
+    # scope they would book the convolution and the gate's backward pass
+    # to the scan), and the convolution's in front of its name: XLA
+    # makes a value that is kept under one shape and read under another
+    # TWICE (1.24 ms a layer on the chip).
     with jax.named_scope("ssm_conv"):
-        xbc = causal_conv(xbc, lp["conv_w"], lp["conv_b"])
-        if dims is not None:
-            xbc = jnp.swapaxes(xbc, 1, 2)
-        xbc = checkpoint_name(xbc, SSM_CONV)
+        xbc = causal_conv(xbc, lp["conv_w"], lp["conv_b"],
+                          time_last=dims is not None, within=(zxd, inner))
+        if dims is None:  # the scan's kernels name what they keep of it
+            xbc = checkpoint_name(xbc, SSM_CONV)
     y, last = (_scan_by_products(lp, xbc, dt, **sizes) if dims is None
                else _scan_by_kernels(lp, xbc, dt, dims))
     with jax.named_scope("ssm_gate_norm"):

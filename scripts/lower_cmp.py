@@ -6,7 +6,7 @@ runner's ``build_step`` for ``("tpu",)`` on abstract parameters and one
 abstract batch at the cell's full shape — the static rules that choose
 a kernel (``att.blockwise_tile``, ``segment_tile``, ``dsa_tile``,
 ``moe.grouped_tiles``, ``expert_width_pad``, ``ssm.scan_tile``,
-``kda.carry_tile``) and the
+``ssm.conv_tile``, ``kda.carry_tile``) and the
 device's memory limit answering as on a v5e — and print one line a cell:
 
     <cell> text <sha256> scopes <sha256> <characters> {counters}
@@ -42,7 +42,7 @@ RULES = (("ops.attention", "blockwise_tile"),
          ("ops.attention", "segment_tile"),
          ("ops.attention", "dsa_tile"), ("ops.moe", "grouped_tiles"),
          ("ops.moe", "expert_width_pad"), ("ops.ssm", "scan_tile"),
-         ("ops.kda", "carry_tile"))
+         ("ops.ssm", "conv_tile"), ("ops.kda", "carry_tile"))
 #: the pvars that say which path a traced layer took
 COUNTED = ("moe_bounded_layers", "moe_full_layers",
            "moe_row_sum_gather_layers", "moe_row_sum_product_layers",
@@ -50,7 +50,8 @@ COUNTED = ("moe_bounded_layers", "moe_full_layers",
            "attn_blockwise_layers", "attn_reference_layers",
            "attn_window_layers", "attn_full_layers", "attn_window_tiles",
            "attn_causal_tiles", "attn_dsa_kernel_layers", "ssm_scan_kernel_layers",
-           "ssm_scan_product_layers", "kda_carry_kernel_layers",
+           "ssm_scan_product_layers", "conv_kernel_layers",
+           "conv_shifted_layers", "kda_carry_kernel_layers",
            "kda_carry_scan_layers", "kda_core_kernel_layers",
            "attn_gated_layers",
            "remat_kept_applications",
