@@ -119,6 +119,14 @@ WELL_KNOWN = (
     # triangle would be at that tile (ops/attention.window_tiles)
     "attn_window_layers", "attn_full_layers", "attn_window_tiles",
     "attn_causal_tiles",
+    # models/transformer.py, once per TRACED attention layer: its q and
+    # k are normed per head (Config.qk_norm "head"); it takes no
+    # rotation in a config whose other kind of attention rotates
+    # (Config.rope_full / rope_window NO_ROPE); and once per TRACED
+    # multi-token-prediction module of a config that mixes kinds of
+    # attention, by the kind Config.mtp_attn gives it
+    "attn_head_norm_layers", "attn_unrotated_layers", "mtp_full_layers",
+    "mtp_window_layers",
     # ops/ssm.mixer, once per TRACED call: its scan runs on the Pallas
     # kernels of ops/ssm_scan.py, or as jax.numpy's batched products
     # (the rule ops/ssm.scan_tile)

@@ -1,10 +1,10 @@
 """What a Config's layers are made of, described once.
 
 Three questions of models/transformer.py have ONE answer here: which
-kind each layer is (`_is_moe`, `_layer_kind`: the block's two — each
-under a sliding window or not, or with a delta-rule mixer in
-attention's place, where a config mixes kinds of attention, `Block` —,
-a pattern's three letters); which sub-layers a layer of a kind has
+kind each layer is (`_is_moe`, `_layer_kind`, `_mtp_kind`: the block's
+two — each under a sliding window or not, or with a delta-rule mixer
+in attention's place, where a config mixes kinds of attention, `Block`
+—, a pattern's three letters); which sub-layers a layer of a kind has
 (`layout`: the rows `layer_forward` walks, the recomputation rule sums
 over and the parameter tree is built from); and every leaf of the
 parameter tree — its path, its shape, how it is initialised and how an
@@ -69,8 +69,32 @@ def _layer_kind(cfg, layer: int):
     return Block(_is_moe(cfg, layer), letter == WINDOWED, letter == DELTA)
 
 
+def _mtp_kind(cfg):
+    """What the layer of a multi-token-prediction module is: an expert
+    layer or a dense one as a layer after the last would be — and,
+    where the config mixes kinds of attention, a `Block` whose
+    attention is the kind `Config.mtp_attn` names (the source's
+    mtp_layer_types), whatever the trunk's last layer is."""
+    moe = _is_moe(cfg, cfg.n_layers)
+    if cfg.attn_layers is None:
+        return moe
+    _check_attn_layers(cfg)
+    return Block(moe, cfg.mtp_attn == WINDOWED)
+
+
+#: `Config.qk_norm` where each head of q and of k is normed alone
+#: (True: OLMoE's, over the whole projection)
+PER_HEAD = "head"
+
+
 def _check_attn_layers(cfg):
     kinds = cfg.attn_layers
+    if cfg.mtp_layers and cfg.mtp_attn not in (WINDOWED, FULL):
+        raise ValueError(
+            f"mtp_attn={cfg.mtp_attn!r}: where a config mixes kinds of "
+            "attention (Config.attn_layers) the multi-token-prediction "
+            f"module's own is {WINDOWED!r} or {FULL!r} (the source's "
+            "mtp_layer_types; a delta-rule module is not written)")
     if len(kinds) != cfg.n_layers or set(kinds) - {WINDOWED, FULL, DELTA}:
         raise ValueError(
             f"attn_layers={kinds!r}: expected n_layers = {cfg.n_layers} "
@@ -195,7 +219,10 @@ def _attention_leaves(cfg):
     yield Leaf(("wv",), (d, narrow), s_emb, COLUMN)
     yield Leaf(("wo",), (wide, d), 1.0 / math.sqrt(wide)
                / math.sqrt(2 * cfg.n_layers), ROW)
-    if cfg.qk_norm:  # over the WHOLE projection, gain only
+    if cfg.qk_norm == PER_HEAD:  # one gain for every head, gain only
+        yield Leaf(("q_norm", "g"), (cfg.head_dim,), ONES)
+        yield Leaf(("k_norm", "g"), (cfg.head_dim,), ONES)
+    elif cfg.qk_norm:  # over the WHOLE projection, gain only
         yield Leaf(("q_norm", "g"), (wide,), ONES)
         yield Leaf(("k_norm", "g"), (narrow,), ONES)
     if cfg.attn_gate:  # the output gate, one number a head and channel
@@ -322,10 +349,10 @@ def _top_leaves(cfg):
 
 
 def _mtp_leaves(cfg):
-    """A multi-token-prediction module: one more layer of the last
-    layers' kind, the two norms and the merging product in front."""
+    """A multi-token-prediction module: one more layer (`_mtp_kind`),
+    the two norms and the merging product in front."""
     d = cfg.d_model
-    yield from _layer_leaves(cfg, _is_moe(cfg, cfg.n_layers))
+    yield from _layer_leaves(cfg, _mtp_kind(cfg))
     yield from _norm_leaves(cfg, "enorm")
     yield from _norm_leaves(cfg, "hnorm")
     yield Leaf(("eh_proj",), (2 * d, d), 1.0 / math.sqrt(2 * d))

@@ -24,9 +24,10 @@ The model is a DESCRIPTION, read from a published config, not a fork
 per model: :class:`Config` says which norm, positions, attention and
 feed-forward part, how many passes and exits, whether a tower stands in
 front; the defaults are OPT's (pre-LN, ReLU MLP, learned positions,
-tied head). Eight published configurations run through it, each against
+tied head). Nine published configurations run through it, each against
 a float32 reference of its own under ``benchmark/reference/``: OPT,
-OLMoE, GLM-5, Ouro, Kimi-VL, Nemotron-3-Nano, Mellum2, Solar-Open2.
+OLMoE, GLM-5, Ouro, Kimi-VL, Nemotron-3-Nano, Mellum2, Solar-Open2,
+K-EXAONE.
 What the module holds:
 
 - :class:`Config`, :class:`Axes`, `_check_supported`: what a config
@@ -113,7 +114,13 @@ the scores, softmax and values lie one scope further in:
 stay under ``attn_proj/qk_rope``); counted once per traced layer there:
 ``attn_window_layers`` / ``attn_full_layers``, and by
 ``ops/attention.attention`` for a windowed attention that took the
-kernels ``attn_window_tiles`` / ``attn_causal_tiles``. A delta-rule
+kernels ``attn_window_tiles`` / ``attn_causal_tiles``. A kind may take
+no rotation while the other rotates (`NO_ROPE`: ``attn_unrotated_layers``),
+q and k may be normed per head (`PER_HEAD`: ``attn_head_norm_layers``),
+and the multi-token-prediction module of such a config is a layer of
+the kind ``mtp_attn`` names — its core under ``layer_<n_layers>/
+attn_core/attn_full`` or ``attn_window``, counted ``mtp_full_layers`` /
+``mtp_window_layers``. A delta-rule
 layer of such a config (``d``) is ``layer_<i>/{ln, kda, mlp}`` with
 ``kda/{kda_proj, kda_conv, kda_core, kda_gate_norm}`` (``kda_proj``:
 the q, k, v, decay, beta and gate products, the output product and the
@@ -145,9 +152,9 @@ from jax.ad_checkpoint import checkpoint_name
 from ompi_tpu.core import pvar
 from ompi_tpu.models import remat, vision
 from ompi_tpu.models.params import (  # noqa: F401 (the model's own names)
-    ATTENTION, DELTA, EXPERTS, FULL, SSM, WINDOWED, Block, _check_attn_layers,
-    _check_indexer, _check_pattern, _is_moe, _layer_kind, grad_extra_axes,
-    init_params, layout, param_specs)
+    ATTENTION, DELTA, EXPERTS, FULL, PER_HEAD, SSM, WINDOWED, Block,
+    _check_attn_layers, _check_indexer, _check_pattern, _is_moe, _layer_kind,
+    _mtp_kind, grad_extra_axes, init_params, layout, param_specs)
 from ompi_tpu.models.remat import (  # noqa: F401
     ATTN_PROJ_OUT, DSA_SELECT, MLA_LATENTS, MLP_OUT, MLP_UP, REMAT_SHARE)
 from ompi_tpu.ops import attention as att
@@ -173,6 +180,11 @@ class Rope:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+
+
+#: `Config.rope_full` / `Config.rope_window` where that kind of
+#: attention layer takes NO rotation while the other kind rotates
+NO_ROPE = "none"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -206,9 +218,13 @@ class Config:
     #: "none" (no table, no rotation)
     pos: str = "learned"
     rope_theta: float = 10000.0
-    #: RMSNorm of q and k over the WHOLE projection (width d_model),
-    #: before the split into heads
-    qk_norm: bool = False
+    #: RMSNorm of q and k in front of the rotation. True: over the
+    #: WHOLE projection (width d_model), before the split into heads
+    #: (OLMoE's; gains of the projections' widths). `PER_HEAD` "head":
+    #: over each head alone, after the split (gains q_norm / k_norm of
+    #: [head_dim], one for every head of q, one for every head of k).
+    #: False: none
+    qk_norm: Any = False
     #: the head is the embedding's transpose, or params["head"]
     tie_head: bool = True
     #: weights of the router's load-balancing loss E * sum_e f_e P_e
@@ -276,9 +292,13 @@ class Config:
     index_loss_weight: float = 1.0
     #: multi-token-prediction modules after the last layer
     #: (params["mtp"]; DeepSeek-V3's, depth 1 supported) and the weight
-    #: of their loss
+    #: of their loss; where `attn_layers` mixes kinds of attention, the
+    #: kind of the module's own (the source's mtp_layer_types: `FULL`
+    #: or `WINDOWED`, whatever the trunk's last layer is; with it the
+    #: kind's RoPE parameters, or none)
     mtp_layers: int = 0
     mtp_weight: float = 0.0
+    mtp_attn: Optional[str] = None
     #: recompute each layer application in the backward pass: from its
     #: input and the named values `remat_keep` chooses for this trace's
     #: shapes and the device's memory limit (`remat_order`,
@@ -340,9 +360,12 @@ class Config:
     #: RoPE's parameters where they are more than `rope_theta` says, by
     #: the layer's kind of attention (a `Rope`: a base of its own,
     #: YaRN): the full layers' — every layer's where `attn_layers` is
-    #: None — and the windowed ones'. None: `rope_theta`, unscaled
-    rope_full: Optional[Rope] = None
-    rope_window: Optional[Rope] = None
+    #: None — and the windowed ones'. None: `rope_theta`, unscaled.
+    #: `NO_ROPE`: the layers of that kind take no rotation at all while
+    #: the other kind's do (`pos` stays "rope"; where no layer rotates
+    #: `pos` is "none")
+    rope_full: Any = None
+    rope_window: Any = None
     #: the delta-rule linear mixer of the layers `attn_layers` marks
     #: `DELTA` "d" (Kimi Delta Attention, ops/kda.py): heads of
     #: kda_head_dim channels for keys and values alike, the taps of the
@@ -562,19 +585,30 @@ def _check_supported(cfg: Config, ax: Axes, is_moe, pos_offset, t=None):
                  "core and its sparse-attention indexer take no window"),
                 (cfg.layer_pattern is not None, "a layer pattern "
                  "(Config.layer_pattern): a pattern's attention layers "
-                 "are told apart by no letter yet"),
-                (cfg.mtp_layers, "multi-token prediction (mtp_layers): "
-                 "which kind of attention the module after the last "
-                 "layer has is not written")):
+                 "are told apart by no letter yet")):
             if on:
                 raise NotImplementedError(
                     "kinds of attention mixed by layer (Config.attn_layers) "
                     "under " + missing)
-    elif cfg.attn_window or cfg.rope_window is not None:
+    elif cfg.attn_window or cfg.rope_window is not None \
+            or cfg.mtp_attn is not None:
         raise ValueError(
             f"attn_window={cfg.attn_window}, rope_window="
-            f"{cfg.rope_window!r}: which layers are under the window is "
-            "for Config.attn_layers to say, and it is None")
+            f"{cfg.rope_window!r}, mtp_attn={cfg.mtp_attn!r}: which layers "
+            "are under the window is for Config.attn_layers to say, and "
+            "it is None")
+    if NO_ROPE in (cfg.rope_full, cfg.rope_window) and (
+            cfg.pos != "rope" or cfg.attn_layers is None
+            or cfg.rope_full == cfg.rope_window):
+        raise ValueError(
+            f"rope_full={cfg.rope_full!r}, rope_window={cfg.rope_window!r}: "
+            "NO_ROPE takes the rotation from ONE kind of attention layer "
+            "of a config that mixes two (Config.attn_layers) and rotates "
+            "the other (pos='rope'); a config no layer of which rotates "
+            "says pos='none'")
+    if cfg.qk_norm not in (False, True, PER_HEAD):
+        raise ValueError(f"qk_norm={cfg.qk_norm!r}: expected False, True "
+                         f"(over the whole projection) or {PER_HEAD!r}")
     if cfg.n_heads % (cfg.n_kv_heads or cfg.n_heads):
         raise ValueError(f"n_heads={cfg.n_heads} is no multiple of "
                          f"n_kv_heads={cfg.n_kv_heads}")
@@ -627,9 +661,15 @@ def _check_supported(cfg: Config, ax: Axes, is_moe, pos_offset, t=None):
             "(models/pipeline.py; ROADMAP R1b)")
     if cfg.qk_norm and ax.tp:
         raise NotImplementedError(
-            "QK-norm spans the whole projection, which tensor "
-            "parallelism (ax.tp) shards by columns: its sum of squares "
-            "over the tp axis is not written yet")
+            "QK-norm over the whole projection, which tensor parallelism "
+            "(ax.tp) shards by columns: its sum of squares over the tp "
+            "axis is not written yet" if cfg.qk_norm != PER_HEAD else
+            "QK-norm per head (qk_norm='head') under tensor parallelism "
+            "(ax.tp): a head's norm needs no sum across the axis, but the "
+            "two gains are replicated INSIDE the tp region and their "
+            "gradient arrives partial, one shard's heads a chip; the "
+            "psum over tp (as the router's, grad_extra_axes) is not "
+            "written yet (ROADMAP Queue 2a)")
 
 
 def _moe_sorted(flat, lp, cfg: Config, aux):
@@ -807,8 +847,14 @@ def _attention(lp, x, cfg: Config, ax: Axes, pos_offset,
     heads: ``attn_gqa_layers``. `windowed`: this layer's attention is
     under the config's sliding window (``attn_window`` keys, passed
     down to the entry) and turns by ``rope_window``; a full layer by
-    ``rope_full``. Where the config mixes the two kinds the core lies
-    under a scope of the kind's name and the layer is counted:
+    ``rope_full`` — a kind whose entry is `NO_ROPE` not at all (counted
+    ``attn_unrotated_layers``). QK-norm (``qk_norm``) is an RMSNorm of
+    q and of k in float32 under ``attn_proj/qk_rope``, in front of the
+    rotation: over the whole projection before the split into heads,
+    or per head after it (`PER_HEAD`, counted
+    ``attn_head_norm_layers``). Where the config mixes the two kinds
+    the core lies under a scope of the kind's name and the layer is
+    counted:
     ``attn_window_layers`` / ``attn_full_layers``. Where the config
     gates attention's output (``attn_gate``) the core's result is
     multiplied by ``sigmoid(x W_a)`` in float32 in front of the output
@@ -820,6 +866,7 @@ def _attention(lp, x, cfg: Config, ax: Axes, pos_offset,
     window = cfg.attn_window if windowed else None
     theta = (cfg.rope_window if windowed else cfg.rope_full) \
         or cfg.rope_theta
+    per_head = cfg.qk_norm == PER_HEAD
     # The blockwise kernel takes q already scaled. Where it will run
     # (the rule att.attention applies below), 1/sqrt(Dh) goes in where q
     # is still float32 — the projection's accumulator or the QK-norm —
@@ -834,8 +881,9 @@ def _attention(lp, x, cfg: Config, ax: Axes, pos_offset,
     # with shared key heads and no QK-norm (which reads the WHOLE
     # projection) each projection is split into heads as it is made —
     # nemotron-train-t8192's step, as PR 39 wrote it — else after all
-    # three, every other cell's. One order for both changes a cell's
-    # program text: measured work (ROADMAP D23), not PR 42's fold.
+    # three, every other cell's (a norm per head then follows the
+    # split). One order for both changes a cell's program text:
+    # measured work (ROADMAP D23), not PR 42's fold.
     at_once = not cfg.qk_norm and cfg.n_kv_heads not in (0, cfg.n_heads)
     first, later = (split, lambda a: a) if at_once else (lambda a: a, split)
     with jax.named_scope("attn_proj"):
@@ -850,15 +898,23 @@ def _attention(lp, x, cfg: Config, ax: Axes, pos_offset,
         q = first(q)
         k = first(x @ lp["wk"].astype(dt))
         v = first(x @ lp["wv"].astype(dt))
-        if cfg.qk_norm:
+        def qk_normed(q, k):  # 1/sqrt(Dh) where q is still float32
             with jax.named_scope("qk_rope"):
                 q = _rms(q.astype(jnp.float32), lp["q_norm"]["g"],
                          cfg.norm_eps)
-                q = (q * q_scale if q_scale else q).astype(dt)
-                k = _rms(k.astype(jnp.float32), lp["k_norm"]["g"],
-                         cfg.norm_eps).astype(dt)
+                return ((q * q_scale if q_scale else q).astype(dt),
+                        _rms(k.astype(jnp.float32), lp["k_norm"]["g"],
+                             cfg.norm_eps).astype(dt))
+
+        if cfg.qk_norm and not per_head:
+            q, k = qk_normed(q, k)
         q, k, v = later(q), later(k), later(v)
-        if cfg.pos == "rope":
+        if per_head:  # [.., Dh] against gains of [Dh]: each head alone
+            pvar.record("attn_head_norm_layers")
+            q, k = qk_normed(q, k)
+        if theta == NO_ROPE:
+            pvar.record("attn_unrotated_layers")
+        elif cfg.pos == "rope":
             with jax.named_scope("qk_rope"):
                 positions = jnp.arange(t) if pos_offset is None \
                     else pos_offset + jnp.arange(t)
@@ -1174,7 +1230,7 @@ def _application_kinds(cfg: Config):
     modules), or `VIT`: a block of the vision tower, which run first."""
     return [VIT] * (cfg.vision.n_layers if cfg.vision is not None else 0) \
         + [_layer_kind(cfg, i) for i in range(cfg.n_layers)] * cfg.loops \
-        + [_is_moe(cfg, cfg.n_layers)] * cfg.mtp_layers
+        + [_mtp_kind(cfg)] * cfg.mtp_layers
 
 
 def step_costs(cfg: Config, b: int, t: int, param_bytes: int = 0,
@@ -1379,9 +1435,16 @@ def _mtp_forward(mp, h, params, labels, cfg: Config, ax: Axes, pos_offset,
     """One multi-token-prediction module (DeepSeek-V3's): position i
     merges the trunk's h[i] with the embedding of token i + 1 (the
     label of i) — ``W_eh [norm(h) ; norm(emb)]`` — and runs one more
-    layer of the last layers' kind; the caller puts the SHARED final
-    norm and head on the result to predict token i + 2."""
+    layer (`_mtp_kind`: dense or experts as a layer after the last
+    would be; where the config mixes kinds of attention, of the kind
+    ``mtp_attn`` names, counted ``mtp_full_layers`` /
+    ``mtp_window_layers``); the caller puts the SHARED final norm and
+    head on the result to predict token i + 2."""
     dt = cfg.dtype
+    kind = _mtp_kind(cfg)
+    if isinstance(kind, Block):
+        pvar.record("mtp_window_layers" if kind.windowed
+                    else "mtp_full_layers")
     with jax.named_scope("attn_proj"), jax.named_scope("mtp_merge"):
         e = params["embed"].astype(dt)[jnp.maximum(labels, 0)]
         both = jnp.concatenate(
@@ -1389,7 +1452,7 @@ def _mtp_forward(mp, h, params, labels, cfg: Config, ax: Axes, pos_offset,
              _norm(e.astype(jnp.float32), mp["enorm"], cfg)], axis=-1)
         h = both.astype(dt) @ mp["eh_proj"].astype(dt)
     return _Recomputed(cfg, ax, _remat_names(params, labels, cfg))(
-        mp, h, _is_moe(cfg, cfg.n_layers), pos_offset, aux, index_aux)
+        mp, h, kind, pos_offset, aux, index_aux)
 
 
 def _label_hit(logits, labels):
@@ -1697,12 +1760,16 @@ def _embedded_for(params, tokens, cfg: Config, layer: int, delta: bool):
     """(block `layer`'s leaves, its first sub-layer's layout row, that
     sub-layer's normed input) on the EMBEDDED batch — the stream
     entering layer 0 — for a probe of a delta-rule mixer (`delta`) or
-    of attention: asked for the other kind of layer it raises."""
+    of attention: asked for the other kind of layer it raises. Layer
+    ``n_layers`` is the multi-token-prediction module's, where the
+    config has one."""
     dt = cfg.dtype
     h = params["embed"].astype(dt)[tokens]
     if cfg.pos == "learned":
         h = h + params["pos"][:tokens.shape[1]].astype(dt)[None]
-    lp, kind = params["layers"][layer], _layer_kind(cfg, layer)
+    lp, kind = (params["mtp"][0], _mtp_kind(cfg)) \
+        if cfg.mtp_layers and layer == cfg.n_layers \
+        else (params["layers"][layer], _layer_kind(cfg, layer))
     _check_supported(cfg, Axes(), kind, None, tokens.shape[1])
     sub = layout(cfg, kind)[0]
     if (sub.mixer == "kda") != delta:

@@ -180,6 +180,19 @@ def _whole(q_offset, k_offset) -> bool:
 #: 28.3 at 256); fused 23.7 ms at 1024, 30.7 at 512, 66.6 at 256,
 #: holding 2.1 / 4.3 / 8.6 GB of partials; the whole triangle 58.6 ms
 #: fused at 1024 (69.0 with two kernels).
+#: The rule was asked again about a window NARROWER than every tile
+#: (v5e, one core alone at B1 T8192 H64 D128 under a window of 128,
+#: 1,040,448 pairs kept a head, forward + backward with the layout
+#: changes, scripts/window_probe.py, PERF.md section 6, PR 50; tiles as
+#: query rows x keys, the pairs walked over the pairs kept in
+#: brackets): two kernels 13.76 ms at 512 x 512 (7.81), 14.62 at 256 x
+#: 256 (3.97), 14.85 at 512 x 256 (5.92), 18.57 at 128 x 128 (2.00),
+#: 18.58 at 256 x 128 (2.99), 19.89 at 512 x 128 (4.98); fused 19.85 /
+#: 35.08 / 28.16 / 87.13 / 59.72 / 46.74 ms. A tile of 128 keys walks a
+#: quarter of the pairs and runs each at less than a quarter of the
+#: rate: the list stands for every window, and what the windowed cores
+#: cost above their 1.04 M pairs a head is a kernel of the repo's own
+#: whose edge is finer than a tile (ROADMAP S15).
 _WINDOW_TILES = (512, 1024, 256)
 
 
@@ -194,7 +207,8 @@ def blockwise_tile(backend: str, t_q: int, t_k: int, head_dim: int,
     mask (a segment mask alone is :func:`segment_tile`'s; both at once,
     packed causal documents, is ROADMAP Queue 2a). Any `head_dim`
     passes: the kernel pads it to its lanes. Under a `window` the tiles
-    are `_WINDOW_TILES`'."""
+    are `_WINDOW_TILES`', whatever its width: the chip was asked at a
+    window of 1,024 and at one of 128, narrower than every tile."""
     if (backend != "tpu" or not causal or not _whole(q_offset, k_offset)
             or t_q != t_k or head_dim < 1):
         return None
@@ -202,14 +216,23 @@ def blockwise_tile(backend: str, t_q: int, t_k: int, head_dim: int,
                  if t_q % b == 0), None)
 
 
-def window_tiles(t: int, tile: int, window: Optional[int] = None) -> int:
-    """The (query tile, key tile) pairs of a [t, t] attention in square
-    tiles of `tile` that hold a pair the mask keeps — what the
-    blockwise kernels walk: the triangle's, or under a `window` those
-    of them whose nearest pair is fewer than `window` keys apart."""
-    n = t // tile
-    reach = n if not window else min(n, (window - 2) // tile + 2)
-    return sum(min(i + 1, reach) for i in range(n))
+def window_tiles(t: int, tile, window: Optional[int] = None) -> int:
+    """The tiles of a [t, t] attention that hold a pair the mask keeps
+    — what the blockwise kernels walk: the triangle's, or under a
+    `window` those of them whose nearest pair is fewer than `window`
+    keys apart. `tile`: the square tile's side (what
+    :func:`blockwise_tile` gives) or (query rows, keys) of a
+    rectangular one (what scripts/window_probe.py tries beside it),
+    then counted in SQUARES of its shorter side — a tile of 256 x 128
+    is two —, so that the count times that side squared is the pairs
+    walked whatever the tile's form."""
+    rows, keys = tile if isinstance(tile, tuple) else (tile, tile)
+    unit = min(rows, keys)
+    walked = 0
+    for first in range(0, t, rows):  # a query tile: its rows' keys
+        low = max(first - window + 1, 0) if window else 0
+        walked += (first + rows - 1) // keys - low // keys + 1
+    return walked * (rows // unit) * (keys // unit)
 
 
 def _block_sizes(tile: int, windowed: bool = False):

@@ -7,6 +7,7 @@ cell's full shape, the static rules answering as on a v5e
 (``lower_cmp.as_on_a_v5e``), and prints one line a cell:
 
     <cell> temp <bytes> arguments <bytes> pathless copies <n> <bytes>
+        code <bytes> fusions <n>
 
 ``temp`` + ``arguments`` is what the chip then reads as
 ``memory_peak_bytes`` (PR 44: 10.25 GB here, 10.26 there). ``pathless
@@ -15,9 +16,15 @@ and more that carry no ``jax.named_scope`` path (the scheduler's
 prefetches, ``copy-start``, are not counted: every step has dozens):
 what a trace books under ``unscoped_ms.train`` (PR 44: a sum written
 as a Python loop over slices added 31 of them and 0.5 GB, and the chip
-read +13 ms there). ``--out DIR`` keeps the compiled text.
+read +13 ms there). ``code`` is the executable's own size on the chip
+and ``fusions`` the fusion instructions of its text (PR 30: 1.36 GB and
+10,720 in ``glm5-train-t4096``). ``--keep none`` compiles the step
+with nothing kept by the recomputation rule (what a configuration is
+sized by before the rule is given the room that is left). ``--out DIR``
+keeps the compiled text.
 
-    python scripts/compile_cell.py [--root CHECKOUT] [--out DIR] cell ...
+    python scripts/compile_cell.py [--root CHECKOUT] [--out DIR]
+        [--keep none] cell ...
 
 ~1.5 min and ~4 GB of host memory a cell; ONE such process at a time
 (it holds the TPU library). Proves no time and no result.
@@ -53,6 +60,9 @@ def main():
     ap.add_argument("--root", default=os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), help="the checkout to compile")
     ap.add_argument("--out", help="keep each cell's compiled text here")
+    ap.add_argument("--keep", choices=["rule", "none"], default="rule",
+                    help="what a recomputed layer keeps: the rule's "
+                    "answer, or nothing")
     ap.add_argument("cells", nargs="+")
     args = ap.parse_args()
     root = os.path.abspath(args.root)
@@ -71,6 +81,10 @@ def main():
 
     manifest = mf.load()
     lower_cmp.as_on_a_v5e()
+    if args.keep == "none":
+        from ompi_tpu.models import remat
+
+        remat.remat_keep = lambda *a, **kw: ()
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
     for name in args.cells:
@@ -85,7 +99,9 @@ def main():
                 f.write(text)
         print(name, "temp", memory.temp_size_in_bytes, "arguments",
               memory.argument_size_in_bytes, "pathless copies",
-              *pathless_copies(text), flush=True)
+              *pathless_copies(text), "code",
+              memory.generated_code_size_in_bytes, "fusions",
+              len(re.findall(r" fusion\(", text)), flush=True)
 
 
 if __name__ == "__main__":

@@ -53,7 +53,8 @@ COUNTED = ("moe_bounded_layers", "moe_full_layers",
            "ssm_scan_product_layers", "conv_kernel_layers",
            "conv_shifted_layers", "kda_carry_kernel_layers",
            "kda_carry_scan_layers", "kda_core_kernel_layers",
-           "attn_gated_layers",
+           "attn_gated_layers", "attn_head_norm_layers",
+           "attn_unrotated_layers", "mtp_full_layers", "mtp_window_layers",
            "remat_kept_applications",
            "remat_whole_applications", "remat_kept_bytes")
 

@@ -545,6 +545,25 @@ def test_the_lowered_toy_steps_are_the_parents_text(name, dtype,
     assert lowered_text.canonical(named) == lowered_text.canonical(bare)
 
 
+def test_the_fields_of_pr_50_leave_this_configurations_toy_step_its_text():
+    """`mtp_attn`, `qk_norm` per head and `NO_ROPE` at their defaults:
+    the module after the last layer is `_is_moe(cfg, n_layers)` as it
+    was and the toy step lowers to the text of the parent a40151f
+    (canonical: tests/lowered_text.py)."""
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the recorded text is jax 0.9.0's")
+    sizes, cfg = _toy()
+    assert cfg.mtp_attn is None and tfm._mtp_kind(cfg) is True
+    with open(os.path.join(HERE, "benchmark", "configs",
+                           "glm-5.rehearsal.json")) as f:
+        sizes = gt.model_sizes(json.load(f))
+    toks, labs = weights.batches(sizes["vocab"], 1, 2, 64, 1)
+    text = gt.build_step(sizes, 0.01).lower(
+        weights_glm5.device_init(sizes, 1), toks[0], labs[0]).as_text()
+    assert lowered_text.sha256(lowered_text.canonical(text)) == \
+        "3fd6789d75120ad9ddc6d636112d144ed217465912693a85073ce6ded9532a4b"
+
+
 def test_the_seeded_tree_is_init_params_tree():
     sizes, cfg = _toy()
     lib = tfm.init_params(np.random.default_rng(0), cfg)

@@ -292,7 +292,9 @@ REFUSALS = [
     (dict(), dict(pp="x"), NotImplementedError, "pipeline parallelism"),
     (dict(attn="mla"), {}, NotImplementedError, "latent attention"),
     (dict(layer_pattern="E*E*"), {}, NotImplementedError, "a layer pattern"),
-    (dict(mtp_layers=1), {}, NotImplementedError, "multi-token prediction"),
+    (dict(mtp_layers=1), {}, ValueError, "multi-token-prediction module's"),
+    (dict(mtp_layers=1, mtp_attn="d"), {}, ValueError,
+     "a delta-rule module is not written"),
     (dict(attn_layers=None), {}, ValueError, "for Config.attn_layers to say"),
     (dict(attn_layers="wwf"), {}, ValueError, "expected n_layers = 4"),
     (dict(attn_layers="wwxf"), {}, ValueError, "letters of 'w'"),
@@ -494,8 +496,13 @@ def test_the_step_lowered_for_the_tpu_holds_the_windows_kernels(
 #: (OLMoE's layer, Nemotron's never-taken branch) moves its rows as
 #: the bounded one does: bc56a7fe... and 81d66122... before it. PR 45
 #: re-recorded all three: one loss body, the label's logit by a mask
-#: (64ef42df..., a9fb0944... and 5ece6908... before it)
+#: (64ef42df..., a9fb0944... and 5ece6908... before it). PR 50 added
+#: this file's own configuration at its parent a40151f: the fields that
+#: PR brought (`qk_norm` per head, `NO_ROPE`, `mtp_attn`) at their
+#: defaults leave the mixed step its text
 PARENT = {
+    "mellum2-12b-a2.5b":
+        "df7f73ea5fb1cf8ae2cf5895c1f7bd4aad8e527d00e960be1e083dfe6f6fb53d",
     "opt-30b":
         "c90271158275193b3843c20b3f0af0c5a528ab2fa0345c3d1e2ed33a29abe869",
     "olmoe-1b-7b":
@@ -503,7 +510,8 @@ PARENT = {
     "nemotron-3-nano-30b-a3b":
         "1224dbf654afbbb6e92872008c9fd886dbf0fc73203b79bd6482c755acc5ee74",
 }
-RUNNERS = {"opt-30b": (train_step, weights),
+RUNNERS = {"mellum2-12b-a2.5b": (mt, weights_mellum2),
+           "opt-30b": (train_step, weights),
            "olmoe-1b-7b": (olmoe_train, weights_olmoe),
            "nemotron-3-nano-30b-a3b": (nemotron_train, weights_nemotron)}
 

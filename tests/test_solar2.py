@@ -293,7 +293,9 @@ def test_the_mixer_under_an_axis_raises(params, axis, says):
 @pytest.mark.parametrize("kw, error, says", [
     (dict(attn="mla"), NotImplementedError, "latent attention"),
     (dict(layer_pattern="MEEM"), NotImplementedError, "a layer pattern"),
-    (dict(mtp_layers=1), NotImplementedError, "multi-token prediction"),
+    (dict(mtp_layers=1), ValueError, "multi-token-prediction module's"),
+    (dict(mtp_layers=1, mtp_attn="d"), ValueError,
+     "a delta-rule module is not written"),
     (dict(kda_heads=0), ValueError, "has delta-rule layers"),
     (dict(attn_layers="fdd"), ValueError, "expected n_layers = 4"),
     (dict(attn_layers="fdxd"), ValueError, "letters of 'w'")])
