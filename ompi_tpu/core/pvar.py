@@ -69,6 +69,12 @@ WELL_KNOWN = (
     # fetched by the sort's inverse and added, or summed by a 0/1
     # product on the MXU (the rule ops/moe.row_sum_gathers)
     "moe_row_sum_gather_layers", "moe_row_sum_product_layers",
+    # the same, once per TRACED layer that fetches a token's rows by
+    # the sort's inverse (the full layer; a bounded one on the gather's
+    # side): the sums are ops/grouped_matmul.row_reduce's, one DMA a
+    # held row from the packed layout the grouped matmuls write, or
+    # XLA's gather and reduction (the rule ops/moe.row_reduce_kernel)
+    "moe_row_reduce_kernel_layers", "moe_row_reduce_xla_layers",
     # models/transformer.py, once per TRACED layer: latent attention
     # (MLA); of those, the layers whose sparse-attention indexer
     # selects (the sequence is longer than index_topk)

@@ -35,7 +35,13 @@ in the sort's order and reads the cotangent from the TOKENS — on the
 chip a gather from the ``[T, D]`` tokens ran at 650 GB/s of rows
 written and one from the ``[T k, D]`` rows at ~122, PERF.md 5 —, and
 the weights and their gradient change order as scalars, by a sort:
-:func:`_by_key`). **A chip that holds a
+:func:`_by_key`). The two REDUCE forms — a token's k rows fetched from
+the ``[bound, D]`` rows and added — are XLA's gather and reduction, or,
+where the rule :func:`row_reduce_kernel` says so (the TPU, places whose
+rows are more bytes than XLA's gather moves cheaply), a Pallas kernel of
+one DMA a held row (ops/grouped_matmul.row_reduce) reading a packed
+layout that the ``w2`` product and the rows' gradient write in their
+epilogue (:func:`_reduced_rows`). **A chip that holds a
 share of the experts** carries a static BOUND of rows instead of all
 ``T * k`` (the rule :func:`held_rows_bound`: a few times its share):
 the first `bound` rows of the same sort are taken, multiplied by the
@@ -279,13 +285,19 @@ def _blocks(x: int):
     return [d for d in range(x, 0, -128) if x % d == 0]
 
 
-def _row_product_tiles(k: int, n: int, size: int) -> Optional[tuple]:
+def _row_product_tiles(k: int, n: int, size: int, pairs: int = 1,
+                       packed: bool = False) -> Optional[tuple]:
     """(tm, sub, tn) of ``[m, k] x [k, n]`` with the whole of K in one
     block: the widest block of columns whose weights, rows and result
-    fit, or None."""
+    fit, or None. `pairs`: that many products summed in one tile (each
+    pair's rows and weights held). `packed`: the result in the packed
+    layout (ops/grouped_matmul.packed_shape), whose blocks of columns
+    are whole tiles of 8 sublanes unless the block is all of N."""
     for tn in _blocks(n):
-        if (2 * size * (k * tn + _TM * k + _TM * tn) + 4 * _TM * tn
-                <= _VMEM_BLOCKS):
+        if packed and tn < n and tn % 1024:
+            continue
+        if (2 * size * (pairs * (k * tn + _TM * k) + _TM * tn)
+                + 4 * _TM * tn <= _VMEM_BLOCKS):
             return _TM, _SUB, tn
     return None
 
@@ -426,7 +438,11 @@ def held_rows_bound(t: int, k: int, count: int, n_experts: int) -> int:
 
 #: Operations of a 0/1 product on the MXU that take the time of one
 #: gathered byte: the v5e's 197 TFLOP/s over the ~100 GB/s at which
-#: XLA's row gather and the sum behind it run. On the chip (PR 40, one
+#: XLA's row gather and the sum behind it run — XLA's, not the rate of
+#: ops/grouped_matmul.row_reduce, which fetches the held rows alone
+#: (:func:`row_reduce_kernel`; PR 51 left this rule and its constant as
+#: they were: PERF.md 7 has the kernel's reading at glm5-train-t4096's
+#: shape for the PR that fits them again). On the chip (PR 40, one
 #: bounded layer, forward + backward, bfloat16): at nemotron-train-
 #: t8192's shapes (24,576 of 49,152 rows of 2,688) the product form
 #: 38.9 ms, the gather 18.8 — its two gathers of 264 MB 2.07 ms each
@@ -661,6 +677,182 @@ def _weigh_held_bwd(bound, res, g):
 _weigh_held.defvjp(_weigh_held_fwd, _weigh_held_bwd)
 
 
+class ReduceTiles(NamedTuple):
+    """The tiles of a layer whose per-token sums are the kernel's
+    (:func:`row_reduce_kernel`): the up and down products' own, and
+    those of the two products that WRITE the packed layout."""
+    up: GroupedTiles    # [bound, D] x [E, D, F]: w1, w3
+    down: GroupedTiles  # [bound, F] x [E, F, D]: w2
+    out: tuple          # (tm, sub, tn) the w2 product, packed
+    drows: tuple        # (tm, sub, tn) the rows' gradient, packed
+
+
+#: Bytes of the ``t * k`` places' rows — what XLA's gather moves, held
+#: or not, at ~100-125 GB/s whatever the shape (a reduce's source is a
+#: kernel's output: never in VMEM) — above which the kernel takes the
+#: sums. On the chip (PR 51, `scripts/row_reduce_probe.py`, one reduce,
+#: XLA's ms against the kernel's with the packed epilogue's; PERF.md
+#: 6): under a bound the kernel walks the held places alone — 805 MB
+#: of places (kexaone-train-t8192) 7.24 against 0.72, 537 MB (solar2-)
+#: 4.95 against 1.24, 264 MB (nemotron-) 2.56 against 0.73 —; with
+#: every place held it pays ~24 ns a copy's descriptor — 604 MB
+#: (mellum2-train-t16384) 6.13 against 5.67, 134 MB (olmoe-train-
+#: t4096) 1.38 against 1.67, 100 MB (kimivl-) 0.80 against 1.27. The
+#: geometric middle of the largest loss and the smallest gain.
+ROW_REDUCE_MIN_BYTES = 192 << 20
+
+
+def row_reduce_kernel(backend: str, t: int, k: int, bound: int, d: int,
+                      dtype) -> bool:
+    """The rule that says who adds a token's k rows where they are
+    fetched by the sort's inverse (:func:`row_sum_gathers`; the full
+    layer always), made of what the caller can observe: True where
+    ops/grouped_matmul.row_reduce does — one DMA a held row from the
+    packed layout the grouped matmuls write for it, the sum in VMEM —,
+    False where XLA's gather and float32 reduction stay
+    (:func:`_weigh_held`, :func:`_sum_held`): off the TPU, a width the
+    128 lanes do not divide, tokens that are not whole tiles of the
+    kernel's 128, rows the kernels' row tile does not divide, anything
+    but bfloat16 or float32, and places whose rows are
+    `ROW_REDUCE_MIN_BYTES` or less: XLA's gather is paid by the byte
+    of ALL ``t * k`` places, the kernel by the held row and by the
+    packed products' epilogue, and under that size the two draw. The
+    grouped kernels' own tiles must exist too (:func:`reduce_tiles`):
+    ``lax.ragged_dot`` cannot write the layout."""
+    return (backend == "tpu" and d % 128 == 0 and t % 128 == 0
+            and bound % _TM == 0
+            and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32)
+            and t * k * d * jnp.dtype(dtype).itemsize > ROW_REDUCE_MIN_BYTES)
+
+
+def reduce_tiles(backend: str, bound: int, d: int, f: int, dtype,
+                 gated: bool) -> Optional[ReduceTiles]:
+    """The tiles of the layer's six products where the kernel adds the
+    rows, or None where one of them has none."""
+    up = grouped_tiles(backend, bound, d, f, dtype)
+    down = grouped_tiles(backend, bound, f, d, dtype)
+    if not (up and down):
+        return None
+    size = jnp.dtype(dtype).itemsize
+    tiles = ReduceTiles(
+        up, down, _row_product_tiles(f, d, size, packed=True),
+        _row_product_tiles(f, d, size, pairs=1 + gated, packed=True))
+    return tiles if all(tiles) else None
+
+
+@functools.lru_cache(maxsize=None)
+def _reduced_units(tiles: ReduceTiles, k: int, bound: int, interpret: bool):
+    """The two differentiable units of a layer whose sums are the
+    kernel's, for one tile set. The packed array is uint32 words and
+    carries no cotangent, so it never leaves a unit: the dispatch goes
+    with the up products (whose rows' gradient is packed and summed
+    per token), the ``w2`` product with the combine (whose result is).
+    The transposes are the plain path's (:func:`_take_held_bwd`,
+    :func:`_weigh_held_bwd`, :func:`_grouped_kernels`)."""
+    from ompi_tpu.ops import grouped_matmul as gk
+
+    def places(inv):
+        return inv.reshape(-1, k).T
+
+    def held(counts):
+        return jnp.minimum(counts.sum(), bound)
+
+    @jax.custom_vjp
+    def taken_up(x, w1, w3, counts, order, inv):
+        return taken_up_fwd(x, w1, w3, counts, order, inv)[0]
+
+    def taken_up_fwd(x, w1, w3, counts, order, inv):
+        with jax.named_scope("moe_dispatch"):
+            rows = x[order[:bound] // k]
+        with jax.named_scope("moe_experts"):
+            up = tuple(None if w is None else gk.gmm(
+                rows, w, counts, tiles.up.fwd, interpret=interpret)
+                for w in (w1, w3))
+        return up, (rows, w1, w3, counts, inv)
+
+    def taken_up_bwd(res, g):
+        rows, w1, w3, counts, inv = res
+        pairs = [(d, w) for d, w in zip(g, (w1, w3)) if w is not None]
+        with jax.named_scope("moe_experts"):
+            # both pairs' products summed in the float32 tile and
+            # rounded once, in the layout the sum below reads
+            drows = gk.gmm(*pairs[0], counts, tiles.drows, transpose_rhs=True,
+                           out_dtype=rows.dtype, interpret=interpret,
+                           packed=True, **(dict(zip(("lhs2", "rhs2"),
+                                                    pairs[1]))
+                                           if len(pairs) > 1 else {}))
+            dw = [None if w is None else gk.tgmm(
+                rows, d, counts, tiles.up.dw, out_dtype=w.dtype,
+                interpret=interpret) for d, w in zip(g, (w1, w3))]
+        with jax.named_scope("moe_dispatch"):
+            # the rows past the held ones are zeros (the product's
+            # tail): under a bound they are not fetched either
+            dx = gk.row_reduce(drows, places(inv), None, held(counts),
+                               rows.shape[1], tiles.drows[2], rows.dtype,
+                               compact=bound < inv.size, interpret=interpret)
+        return dx, *dw, None, None, None
+
+    taken_up.defvjp(taken_up_fwd, taken_up_bwd)
+
+    @jax.custom_vjp
+    def down_weighed(hidden, w2, weights, counts, order, inv):
+        with jax.named_scope("moe_experts"):
+            out = gk.gmm(hidden, w2, counts, tiles.out, interpret=interpret,
+                         packed=True)
+        with jax.named_scope("moe_combine"):
+            return gk.row_reduce(
+                out, places(inv), weights.T, held(counts), w2.shape[2],
+                tiles.out[2], hidden.dtype, compact=bound < inv.size,
+                interpret=interpret)
+
+    def down_weighed_fwd(hidden, w2, weights, counts, order, inv):
+        return (down_weighed(hidden, w2, weights, counts, order, inv),
+                (hidden, w2, weights, counts, order, inv))
+
+    def down_weighed_bwd(res, g):
+        hidden, w2, weights, counts, order, inv = res
+        # the packed product is never kept: the weights' gradient reads
+        # `out` row against row of the cotangent in the PLAIN layout, so
+        # the backward pass makes the product again, plain — the one
+        # product a recomputed layer (every expert cell's) made again
+        # anyway, and dead code in its recomputed forward
+        with jax.named_scope("moe_experts"):
+            out = gk.gmm(hidden, w2, counts, tiles.down.fwd,
+                         interpret=interpret)
+        with jax.named_scope("moe_combine"):
+            dout, dweights = _weigh_held_bwd(
+                bound, (out, weights, order, inv, held(counts)), g)[:2]
+        with jax.named_scope("moe_experts"):
+            return (gk.gmm(dout, w2, counts, tiles.down.drows,
+                           transpose_rhs=True, out_dtype=hidden.dtype,
+                           interpret=interpret),
+                    gk.tgmm(hidden, dout, counts, tiles.down.dw,
+                            out_dtype=w2.dtype, interpret=interpret),
+                    dweights, None, None, None)
+
+    down_weighed.defvjp(down_weighed_fwd, down_weighed_bwd)
+    return taken_up, down_weighed
+
+
+def _reduced_rows(x, experts, weights, counts, w1, w3, w2, act: str,
+                  bound: int, interpret: bool = False):
+    """:func:`_held_rows` where the rule :func:`row_reduce_kernel` says
+    yes: the same rows through the same products, each token's sum —
+    the combine's and the dispatch's transpose — the kernel's."""
+    k = experts.shape[1]
+    tiles = reduce_tiles("tpu", bound, x.shape[1], w1.shape[2], x.dtype,
+                         w3 is not None)
+    taken_up, down_weighed = _reduced_units(tiles, k, bound, interpret)
+    with jax.named_scope("moe_dispatch"):
+        order, inv = _expert_order(experts)
+    up, gate = taken_up(x, w1, w3, counts, order, inv)
+    with jax.named_scope("moe_experts"):
+        hidden = activation(act)(up)
+        if gate is not None:
+            hidden = hidden * gate
+    return down_weighed(hidden, w2, weights, counts, order, inv)
+
+
 def _held_rows(x, experts, weights, counts, w1, w3, w2, act: str,
                bound: int, gather: bool, product=None):
     """The layer over the first `bound` assignments of the sort: all
@@ -708,33 +900,35 @@ def _fallback_rows(x, experts, weights, counts, w1, w3, w2, act: str):
                       experts.size, True, product=_ragged_dot_zero_tail)
 
 
-def _branches(act: str, bound: int, gather: bool):
-    return (functools.partial(_held_rows, act=act, bound=bound,
-                              gather=gather),
+def _branches(act: str, bound: int, gather: bool, kernel: bool):
+    return (functools.partial(_reduced_rows, act=act, bound=bound) if kernel
+            else functools.partial(_held_rows, act=act, bound=bound,
+                                   gather=gather),
             functools.partial(_fallback_rows, act=act))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
 def _held_or_all_rows(x, experts, weights, counts, w1, w3, w2, act: str,
-                      bound: int, gather: bool):
+                      bound: int, gather: bool, kernel: bool):
     """:func:`_held_rows` where the batch's held assignments fit the
     bound, else :func:`_fallback_rows`: ONE conditional a direction.
     The backward pass makes the taken branch's forward again inside its
     own conditional, so that neither branch's residuals are outputs of
     a conditional (jax fills the other branch's with zeros: ``T * k``
     rows of them)."""
-    return lax.cond(counts.sum() <= bound, *_branches(act, bound, gather),
+    return lax.cond(counts.sum() <= bound,
+                    *_branches(act, bound, gather, kernel),
                     x, experts, weights, counts, w1, w3, w2)
 
 
 def _held_or_all_rows_fwd(x, experts, weights, counts, w1, w3, w2, act,
-                          bound, gather):
+                          bound, gather, kernel):
     return (_held_or_all_rows(x, experts, weights, counts, w1, w3, w2, act,
-                              bound, gather),
+                              bound, gather, kernel),
             (x, experts, weights, counts, w1, w3, w2))
 
 
-def _held_or_all_rows_bwd(act, bound, gather, res, g):
+def _held_or_all_rows_bwd(act, bound, gather, kernel, res, g):
     x, experts, weights, counts, w1, w3, w2 = res
 
     def transposed(body):
@@ -749,7 +943,7 @@ def _held_or_all_rows_bwd(act, bound, gather, res, g):
     # converts and 0.6 GB)
     dx, dweights, dw1, dw3, dw2 = lax.optimization_barrier(lax.cond(
         counts.sum() <= bound,
-        *map(transposed, _branches(act, bound, gather)),
+        *map(transposed, _branches(act, bound, gather, kernel)),
         x, weights, w1, w3, w2))
     return dx, None, dweights, None, dw1, dw3, dw2
 
@@ -809,9 +1003,13 @@ def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
     at ``bound = T * k``, a token's rows fetched by the sort's inverse
     and added); and of the bounded ones ``moe_row_sum_gather_layers``
     (fetched and added likewise) / ``moe_row_sum_product_layers``
-    (summed by a 0/1 product: :func:`row_sum_gathers`). On the TPU a
-    width the kernels' lanes do not divide is padded with zeros on the
-    way in (:func:`expert_width_pad`)."""
+    (summed by a 0/1 product: :func:`row_sum_gathers`); and of those
+    that fetch, ``moe_row_reduce_kernel_layers`` (the sums are
+    ops/grouped_matmul.row_reduce's, from the packed layout the ``w2``
+    product and the rows' gradient write: :func:`row_reduce_kernel`,
+    :func:`_reduced_rows`) / ``moe_row_reduce_xla_layers`` (XLA's gather
+    and reduction). On the TPU a width the kernels' lanes do not divide
+    is padded with zeros on the way in (:func:`expert_width_pad`)."""
     t, k = route.experts.shape
     rows = t * k if bound is None else min(bound, t * k)
     w1, w3, w2 = _lane_padded(w1, w3, w2)
@@ -820,11 +1018,24 @@ def sorted_moe_ffn(x, route: TopKRoute, w1, w3: Optional[jnp.ndarray],
         jax.ShapeDtypeStruct((rows, x.shape[-1]), x.dtype), w1)
         else "moe_ragged_dot_layers")
     pvar.record("moe_bounded_layers" if rows < t * k else "moe_full_layers")
+    gather = rows == t * k or row_sum_gathers(t, k, rows, x.shape[-1],
+                                              x.dtype)
+    backend = jax.default_backend()
+    kernel = (gather and row_reduce_kernel(backend, t, k, rows, x.shape[-1],
+                                           x.dtype)
+              and w1.dtype == x.dtype == w2.dtype
+              and reduce_tiles(backend, rows, x.shape[-1], w1.shape[2],
+                               x.dtype, w3 is not None) is not None)
+    if gather:
+        pvar.record("moe_row_reduce_kernel_layers" if kernel
+                    else "moe_row_reduce_xla_layers")
     if rows < t * k:
-        gather = row_sum_gathers(t, k, rows, x.shape[-1], x.dtype)
         pvar.record("moe_row_sum_gather_layers" if gather
                     else "moe_row_sum_product_layers")
         return _held_or_all_rows(x, route.experts, route.weights,
-                                 route.counts, w1, w3, w2, act, rows, gather)
-    return _held_rows(x, route.experts, route.weights, route.counts, w1, w3,
-                      w2, act, rows, True)
+                                 route.counts, w1, w3, w2, act, rows, gather,
+                                 kernel)
+    return (_reduced_rows if kernel else functools.partial(
+        _held_rows, gather=True))(x, route.experts, route.weights,
+                                  route.counts, w1, w3, w2, act=act,
+                                  bound=rows)

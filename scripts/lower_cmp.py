@@ -5,7 +5,8 @@ For each train cell of ``BENCHMARK.json`` (or the cells named), lower the
 runner's ``build_step`` for ``("tpu",)`` on abstract parameters and one
 abstract batch at the cell's full shape — the static rules that choose
 a kernel (``att.blockwise_tile``, ``segment_tile``, ``dsa_tile``,
-``moe.grouped_tiles``, ``expert_width_pad``, ``ssm.scan_tile``,
+``moe.grouped_tiles``, ``expert_width_pad``, ``row_reduce_kernel``,
+``reduce_tiles``, ``ssm.scan_tile``,
 ``ssm.conv_tile``, ``kda.carry_tile``) and the
 device's memory limit answering as on a v5e — and print one line a cell:
 
@@ -41,11 +42,13 @@ V5E_LIMIT = 16_909_336_064
 RULES = (("ops.attention", "blockwise_tile"),
          ("ops.attention", "segment_tile"),
          ("ops.attention", "dsa_tile"), ("ops.moe", "grouped_tiles"),
-         ("ops.moe", "expert_width_pad"), ("ops.ssm", "scan_tile"),
+         ("ops.moe", "expert_width_pad"), ("ops.moe", "row_reduce_kernel"),
+         ("ops.moe", "reduce_tiles"), ("ops.ssm", "scan_tile"),
          ("ops.ssm", "conv_tile"), ("ops.kda", "carry_tile"))
 #: the pvars that say which path a traced layer took
 COUNTED = ("moe_bounded_layers", "moe_full_layers",
            "moe_row_sum_gather_layers", "moe_row_sum_product_layers",
+           "moe_row_reduce_kernel_layers", "moe_row_reduce_xla_layers",
            "moe_grouped_kernel_layers", "moe_ragged_dot_layers",
            "attn_blockwise_layers", "attn_reference_layers",
            "attn_window_layers", "attn_full_layers", "attn_window_tiles",

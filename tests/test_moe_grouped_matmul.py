@@ -888,3 +888,320 @@ def test_the_recomputed_full_layer_holds_five_row_gathers(target, request,
     assert not [path for _, path in gathers
                 if "rematted_computation" in path and "moe_combine" in path]
     assert sum("rematted_computation" in path for _, path in gathers) == 1
+
+
+# -- the per-token sum as a kernel of per-row DMAs (PR 51) -----------------------
+
+from ompi_tpu.ops import grouped_matmul as gk  # noqa: E402
+
+
+def _pack(v, tn=None):
+    """`v` [m, n] in the packed layout (`gk.packed_shape`), written here
+    word by word in numpy: the kernels' reader, not their code."""
+    m, n = v.shape
+    tn = tn or n
+    pack, lanes = gk.packed_rows(v.dtype), gk.packed_lanes(tn)
+    if pack == 2:
+        half = np.asarray(v.astype(jnp.float32)).view(np.uint32) >> 16
+        words = half[0::2] | (half[1::2] << 16)
+    else:
+        words = np.asarray(v).view(np.uint32)
+    out = np.full((n // tn, m // pack, lanes, 128), 0xffc00000, np.uint32)
+    for b in range(n // tn):  # the sublanes past a block's columns: NaNs
+        out[b, :, :tn // 128] = words[:, b * tn:(b + 1) * tn].reshape(
+            m // pack, tn // 128, 128)
+    return jnp.asarray(out.reshape(-1, 128))
+
+
+def _unpack(words, m, n, tn, dtype):
+    """The ``[m, n]`` rows of a packed array, as float32."""
+    pack, lanes = gk.packed_rows(dtype), gk.packed_lanes(tn)
+    words = np.asarray(words).reshape(n // tn, m // pack, lanes, 128)
+    words = np.concatenate([words[b, :, :tn // 128].reshape(m // pack, tn)
+                            for b in range(n // tn)], axis=1)
+    if pack == 1:
+        return words.view(np.float32)
+    out = np.empty((m, n), np.float32)
+    out[0::2] = (words << 16).view(np.float32)
+    out[1::2] = (words & np.uint32(0xffff0000)).view(np.float32)
+    return out
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("lanes", [16, 18, 21])
+@pytest.mark.parametrize("bound", ["full", "bounded"])
+@pytest.mark.parametrize("k", [6, 8])
+def test_the_reduce_kernel_is_the_weighted_and_the_plain_sum(k, bound, lanes,
+                                                             dtype):
+    """`gk.row_reduce` (interpret mode) on a source packed by numpy
+    against `_weigh_held` and `_sum_held` on the same rows in the plain
+    layout: k of 6 and 8, every row there or a bound with `held` under
+    it and places past it, widths of 16, 18 and 21 blocks of 128
+    columns (the last two pad to 24 sublanes, NaNs here), both types.
+    The rows past `held` are NOT finite, as are the words nobody wrote:
+    a skipped place adds nothing whatever it would have fetched. Where
+    every place has its row (`full`) both forms are read: the compact
+    one, and the one that fetches every place."""
+    rng = np.random.default_rng(k + lanes)
+    t, d = 256, 128 * lanes
+    rows = t * k if bound == "full" else t * k // 2
+    held = rows if bound == "full" else rows - 200
+    order, inv, _ = _sorted_assignments(rng, t, k, rows)
+    place = np.asarray(inv).reshape(t, k)
+    weights = jnp.asarray(np.where(place < held, rng.random((t, k)), 0.0),
+                          jnp.float32)
+    clean = rng.standard_normal((rows, d)) * (np.arange(rows) < held)[:, None]
+    out = jnp.asarray(np.where((np.arange(rows) < held)[:, None], clean,
+                               np.nan), dtype)
+    places = inv.reshape(-1, k).T
+    one_rounding = 4e-3 if dtype == jnp.bfloat16 else 1e-6
+    # both forms where every place has its row, the compact one alone
+    # under a bound; blocks of 1,024 columns where they divide the width
+    # (the plain sum: once a case, in the form the layer takes there)
+    forms = [(d, True)] + [(d, False)] * (bound == "full") + [
+        (1024, bound == "bounded")] * (lanes == 16)
+    for tn, compact in forms:
+        got = gk.row_reduce(_pack(out, tn), places, weights.T,
+                            jnp.int32(held), d, tn, dtype, compact=compact,
+                            interpret=True)
+        want = moe._weigh_held(out, weights, order, inv, jnp.int32(held),
+                               rows)
+        assert got.dtype == dtype and got.shape == (t, d)
+        assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+        assert _gap(got, want) <= one_rounding, (tn, compact, "weighted")
+    # the plain sum takes every place under the bound: clean rows
+    clean = jnp.asarray(clean, dtype)
+    got = gk.row_reduce(_pack(clean), places, None, jnp.int32(rows), d, d,
+                        dtype, compact=bound == "bounded", interpret=True)
+    assert got.dtype == dtype
+    assert _gap(got, moe._sum_held(clean, order, inv, k, rows)) <= one_rounding
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("product", ["gmm", "gmm_nt_two_pairs",
+                                     "gmm_nt_blocks_of_columns"])
+@pytest.mark.parametrize("groups", sorted(GROUPS))
+def test_the_packed_products_are_the_plain_kernels_unpacked(groups, product,
+                                                            dtype):
+    """`gk.gmm(packed=True)` unpacked by numpy against the plain
+    kernel's result, over every case of groups (whole tiles, edges
+    inside a tile and inside a WORD's pair of rows, empty groups, rows
+    past the last group: zeros): the ``w2`` product's form; the rows'
+    gradient's — two pairs summed in the float32 tile and rounded once,
+    against the two plain products added in float32 —; and that one in
+    blocks of 1,024 columns, which lead in the layout."""
+    rng = np.random.default_rng(len(groups))
+    sizes = jnp.asarray(GROUPS[groups], jnp.int32)
+    m, k = M, 128
+    n, tn = (2048, 1024) if product.endswith("columns") else (384, 384)
+    nt = product != "gmm"
+    tiles = (256, 128, tn)
+
+    def operands():
+        return (jnp.asarray(rng.standard_normal((m, k)), dtype),
+                jnp.asarray(rng.standard_normal((E, n, k) if nt
+                                                else (E, k, n)), dtype))
+
+    pairs = [operands() for _ in range(2 if nt else 1)]
+    more = dict(zip(("lhs2", "rhs2"), pairs[1])) if nt else {}
+    got = gk.gmm(*pairs[0], sizes, tiles, transpose_rhs=nt, packed=True,
+                 interpret=True, **more)
+    assert got.dtype == jnp.uint32
+    assert got.shape == gk.packed_shape(m, n, tn, dtype) == (
+        n // tn * (m // gk.packed_rows(dtype)) * gk.packed_lanes(tn), 128)
+    want = sum(gk.gmm(lhs, rhs, sizes, tiles, transpose_rhs=nt,
+                      out_dtype=jnp.float32, interpret=True)
+               for lhs, rhs in pairs).astype(dtype)
+    got = _unpack(got, m, n, tn, dtype)
+    # the same float32 tile rounded once; the edge blocks' products are
+    # made 128 columns at a time, so a float32 sum's order may differ
+    assert _gap(got, want) <= (4e-3 if dtype == jnp.bfloat16 else 1e-6)
+    assert not got[int(sizes.sum()):].any()
+
+
+@pytest.fixture
+def reduce_on_cpu(kernels_on_cpu, monkeypatch):
+    """`kernels_on_cpu`, and the rule `moe.row_reduce_kernel` asked as
+    on a TPU about a source of any size: the layer's sums are
+    `gk.row_reduce`'s, in interpret mode."""
+    for name in ("row_reduce_kernel", "reduce_tiles"):
+        monkeypatch.setattr(moe, name, functools.partial(
+            lambda rule, backend, *a: rule("tpu", *a), getattr(moe, name)))
+    monkeypatch.setattr(moe, "ROW_REDUCE_MIN_BYTES", 0)
+    monkeypatch.setattr(moe, "row_sum_gathers", lambda *a: True)
+    monkeypatch.setattr(moe, "_reduced_rows", functools.partial(
+        moe._reduced_rows, interpret=True))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("experts", ["gated_silu", "ungated_relu"])
+@pytest.mark.parametrize("rows", ["full", "bounded"])
+def test_the_layer_on_the_reduce_kernel_is_the_float64_layer(
+        rows, experts, dtype, reduce_on_cpu, pvar_clean):
+    """`test_the_full_layer_is_the_float64_layer`'s comparison with the
+    kernel path forced (`_reduced_rows`: the packed ``w2`` product and
+    rows' gradient, `gk.row_reduce` for the combine and the dispatch's
+    transpose, the backward pass's plain ``w2`` product): output and
+    all five gradients, over all the rows and under a bound that the
+    held assignments fit (2 of 8 experts held; the others' assignments
+    are nobody's, and their weights' gradient is zero)."""
+    act, gated = ("silu", True) if experts == "gated_silu" else ("relu",
+                                                                 False)
+    t, k, d, f, e = 128, 4, 128, 256, 8
+    held, bound = (e, None) if rows == "full" else (2, 256)
+    rng = np.random.default_rng(11)
+    x, g = (jnp.asarray(rng.standard_normal((t, d)), dtype) for _ in "xg")
+    w1, w3, w2 = (jnp.asarray(rng.standard_normal(s) / np.sqrt(s[1]), dtype)
+                  for s in ((e, d, f), (e, d, f), (e, f, d)))
+    route = moe.held_share(moe.topk_routing(jnp.asarray(
+        rng.standard_normal((t, e)), jnp.float32), k), 0, held)
+    assert int(route.counts.sum()) <= (bound or t * k)
+
+    def layer(x, weights, w1, w3, w2):
+        y = moe.sorted_moe_ffn(x, route._replace(weights=weights), w1[:held],
+                               w3[:held] if gated else None, w2[:held], act,
+                               bound)
+        return jnp.sum(y.astype(jnp.float32) * g.astype(jnp.float32)), y
+
+    with jax.default_matmul_precision("highest"):
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            jax.checkpoint(layer), argnums=(0, 1, 2, 3, 4), has_aux=True))(
+                x, route.weights, w1, w3, w2)
+    assert (pvar.read("moe_row_reduce_kernel_layers"),
+            pvar.read("moe_row_reduce_xla_layers")) == (1, 0)
+    assert pvar.read("moe_bounded_layers") == (rows == "bounded")
+    # numpy's layer over ALL the experts: an assignment to one that is
+    # not held weighs nothing, and has no gradient of its weight
+    here = np.asarray(route.experts) < held
+    want_y, want = _numpy_layer(
+        x, np.where(here, np.asarray(route.experts), held), route.weights,
+        w1, w3 if gated else None, w2, act, g)
+    want = list(want)
+    want[1] = np.where(here, want[1], 0)
+    tol = 1e-2 if dtype == jnp.bfloat16 else 2e-6
+    for name, got, ref in zip(("y", "x", "weights", "w1", "w3", "w2"),
+                              (y,) + grads, [want_y] + want):
+        if ref is None:
+            assert not np.asarray(got, np.float32).any()
+            continue
+        assert got.dtype == (jnp.float32 if name == "weights" else dtype)
+        gap = np.linalg.norm(np.asarray(got, np.float64) - ref)
+        assert np.linalg.norm(ref) > 0 and gap <= tol * np.linalg.norm(ref), (
+            name, gap, np.linalg.norm(ref))
+
+
+#: the seven expert cells: (t, k, experts held, experts routed, D), the
+#: experts' width as the kernels see it, gated, and what the rule says
+#: (of glm5-train-t4096 it is never asked: `row_sum_gathers` keeps that
+#: cell's sums on the 0/1 product)
+EXPERT_CELLS = {
+    "mellum2-train-t16384": ((16384, 8, 64, 64, 2304), 896, True, True),
+    "solar2-train-t8192": ((8192, 8, 40, 320, 4096), 1280, True, True),
+    "kexaone-train-t8192": ((8192, 8, 8, 128, 6144), 2048, True, True),
+    "nemotron-train-t8192": ((8192, 6, 16, 128, 2688), 1920, False, True),
+    "glm5-train-t4096": ((4096, 8, 8, 256, 6144), 2048, True, True),
+    "olmoe-train-t4096": ((4096, 8, 64, 64, 2048), 1024, True, False),
+    "kimivl-train-t4096": ((4096, 6, 64, 64, 2048), 1408, True, False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_the_reduce_rule_at_the_cells_shapes(cell):
+    """`moe.row_reduce_kernel`'s truth table at the seven expert cells'
+    shapes, and that it reads what it is given and nothing else: no on
+    every backend but the TPU, for a width the lanes do not divide, for
+    tokens that are not whole tiles of 128 and for a type the kernels
+    do not take; the grouped kernels' tiles exist wherever it says
+    yes."""
+    (t, k, held, n, d), f, gated, kernel = EXPERT_CELLS[cell]
+    bound = moe.held_rows_bound(t, k, held, n)
+    ask = functools.partial(moe.row_reduce_kernel, "tpu")
+    assert ask(t, k, bound, d, jnp.bfloat16) is kernel
+    for backend in ("cpu", "gpu"):
+        assert not moe.row_reduce_kernel(backend, t, k, bound, d,
+                                         jnp.bfloat16)
+    assert not ask(t, k, bound, d + 64, jnp.bfloat16)
+    assert not ask(t + 64, k, bound, d, jnp.bfloat16)
+    assert not ask(t, k, bound + 128, d, jnp.bfloat16)
+    assert not ask(t, k, bound, d, jnp.float16)
+    # the bytes of all the places' rows, held or not: they grow with t,
+    # k and the width; the bound moves nothing
+    assert ask(8 * t, k, bound, d, jnp.bfloat16)
+    assert ask(t, 8 * k, bound, d, jnp.bfloat16)
+    assert not ask(t // 8, k, moe._TM, d, jnp.bfloat16)
+    assert ask(t, k, moe._TM, d, jnp.bfloat16) is kernel
+    assert ask(t, k, bound, d, jnp.float32) is (
+        kernel or cell == "olmoe-train-t4096")
+    tiles = moe.reduce_tiles("tpu", bound, d, f, jnp.bfloat16, gated)
+    assert tiles is not None and moe.reduce_tiles(
+        "cpu", bound, d, f, jnp.bfloat16, gated) is None
+    for tm, sub, tn in (tiles.out, tiles.drows):
+        assert bound % tm == 0 and tm % sub == 0
+        assert tn == d or (d % tn == 0 and tn % 1024 == 0)
+
+
+@pytest.mark.parametrize("cell", ["mellum2-train-t16384",
+                                  "kexaone-train-t8192"])
+def test_the_reduced_layer_compiles_for_the_chip_without_a_row_gather(
+        cell, one_chip, monkeypatch, pvar_clean):
+    """One recomputed expert layer, value and gradient, at the two
+    cells' shapes for a described v5e, every rule asked as on the TPU:
+    the sums are `moe_row_reduce` (two a layer: the combine, the
+    dispatch's transpose); no XLA gather reads a ``[bound, D]`` source
+    on the taken path — what is gathered comes from the ``[T, D]``
+    tokens —; the packed arrays go from the kernel that writes them to
+    the kernel that reads them with no copy, convert or bitcast of
+    XLA's between; and the recomputed forward makes neither the packed
+    ``w2`` product nor its sum again."""
+    (t, k, held, n, d), f, gated, kernel = EXPERT_CELLS[cell]
+    assert kernel
+    for name in ("grouped_tiles", "row_reduce_kernel", "reduce_tiles",
+                 "expert_width_pad"):
+        monkeypatch.setattr(moe, name, functools.partial(
+            lambda rule, backend, *a: rule("tpu", *a), getattr(moe, name)))
+    bound = moe.held_rows_bound(t, k, held, n)
+
+    def loss(x, logits, w1, w3, w2, g):
+        route = moe.held_share(moe.sigmoid_routing(logits, None, k), 0, held)
+        y = moe.sorted_moe_ffn(x, route, w1, w3, w2, "silu", bound)
+        return jnp.sum(y.astype(jnp.float32) * g)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(jax.value_and_grad(
+        jax.checkpoint(loss), argnums=(0, 1, 2, 3, 4))).lower(
+        arg((t, d)), arg((t, n), jnp.float32), arg((held, d, f)),
+        arg((held, d, f)), arg((held, f, d)),
+        arg((t, d), jnp.float32)).compile().as_text()
+    assert (pvar.read("moe_row_reduce_kernel_layers"),
+            pvar.read("moe_row_reduce_xla_layers")) == (1, 0)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = [c.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for c in calls]
+    assert names.count("moe_row_reduce") == 2, names
+    packed = sorted((c for c in calls if " = u32[" in c),
+                    key=lambda c: "moe_gmm_nt" in c)
+    tiles = moe.reduce_tiles("tpu", bound, d, f, jnp.bfloat16, True)
+    assert len(packed) == 2  # the w2 product, the rows' gradient
+    for call, (_, _, tn) in zip(packed, (tiles.out, tiles.drows)):
+        words = gk.packed_shape(bound, d, tn, jnp.bfloat16)[0]
+        assert f" = u32[{words},128]" in call, call
+    # every u32 array of that size is a kernel's result or operand:
+    # XLA makes none of its own
+    for line in text.splitlines():
+        if re.search(r" = u32\[\d{6,},128\]", line):
+            assert "tpu_custom_call" in line or " parameter(" in line, line
+    shape_of = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
+    taken = "branch_1_fun" if bound < t * k else ""
+    for line in text.splitlines():
+        made = re.search(r"= \w+\[([\d,]*)\]\S* gather\("
+                         r"(?:\w+\[[\d,]*\]\S* )?%([\w.\-]+)", line)
+        if made and made.group(1).split(",")[-1] == str(d) and taken in line:
+            if "branch_0_fun" in line:
+                continue  # the fallback: the full layer on ragged_dot
+            assert shape_of[made.group(2)] == f"{t},{d}", line
